@@ -14,13 +14,15 @@ COPY chiaswarm_tpu ./chiaswarm_tpu
 # golden-image manifest (chiaswarm-tpu-golden --check against pinned hashes)
 COPY goldens ./goldens
 
-RUN pip install --no-cache-dir -e ".[media,download]" \
-    && pip install --no-cache-dir "jax[tpu]" \
+RUN pip install --no-cache-dir -e ".[media,download,tpu]" \
          -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
 
-# settings.json + logs; converted model weights; persistent XLA cache
+# settings.json + logs; converted model weights; persistent XLA cache.
+# The cache is placed from outside the program (compile_cache.py): its
+# directory is part of its key, so it is one fixed path on the volume.
 VOLUME ["/root/.sdaas"]
 ENV SDAAS_ROOT=/root/.sdaas
+ENV JAX_COMPILATION_CACHE_DIR=/root/.sdaas/xla_cache
 
 # first run: chiaswarm-tpu-init --download (prefetch + convert + check)
 CMD ["chiaswarm-tpu-worker"]

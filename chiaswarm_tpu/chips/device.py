@@ -44,7 +44,9 @@ _SLICE_GEOMETRY = telemetry.gauge(
     ("slice", "axis"),
 )
 
-# Known HBM per chip (GiB) by device kind; fallback is queried or 16.
+# HBM per chip (GiB) by device kind. A TPU kind missing here is an error
+# (add its row with the source), never a guessed 16: admission
+# (chips/requirements.py) and the advertised capacity both hang off it.
 _HBM_GB = {
     "TPU v2": 8,
     "TPU v3": 16,
@@ -57,16 +59,47 @@ _HBM_GB = {
 }
 
 
-def hbm_gb_of(device) -> int:
+def _table_gb(device) -> int | None:
     kind = getattr(device, "device_kind", "cpu")
     for prefix, gb in _HBM_GB.items():
         if kind.startswith(prefix):
             return gb
-    try:
-        stats = device.memory_stats()
-        return int(stats["bytes_limit"] / (1 << 30))
-    except Exception:
-        return 16
+    return None
+
+
+def hbm_gb_of(device) -> int:
+    gb = _table_gb(device)
+    if gb is None:
+        raise ValueError(
+            f"no HBM size known for device kind "
+            f"{getattr(device, 'device_kind', None)!r} "
+            f"(platform {device.platform}); add it to chips/device.py _HBM_GB"
+        )
+    return gb
+
+
+def runtime_report() -> dict:
+    """What this process actually serves on, as jax reports it, plus the
+    installed versions — the worker logs it at start-up and carries it on
+    /healthz, so a worker that found no chip says so instead of serving on
+    the CPU without a word."""
+    from importlib import metadata
+
+    def version(dist: str) -> str | None:
+        try:
+            return metadata.version(dist)
+        except metadata.PackageNotFoundError:
+            return None
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "jax": jax.__version__,
+        "jaxlib": version("jaxlib"),
+        "libtpu": version("libtpu"),
+    }
 
 
 def hbm_census() -> list[dict]:
@@ -87,15 +120,10 @@ def hbm_census() -> list[dict]:
             stats = {}
         limit = stats.get("bytes_limit")
         if not isinstance(limit, int) or limit <= 0:
-            limit = None
-            kind = getattr(device, "device_kind", "cpu")
-            for prefix, gb in _HBM_GB.items():
-                if kind.startswith(prefix) and prefix != "cpu":
-                    # the table is authoritative for known TPU kinds;
-                    # a CPU "limit" would fake headroom where none is
-                    # enforced
-                    limit = gb << 30
-                    break
+            # the table speaks for TPU kinds; a CPU "limit" would fake
+            # headroom where none is enforced
+            gb = _table_gb(device) if device.platform != "cpu" else None
+            limit = gb << 30 if gb else None
         row = {
             "device": f"{device.platform}:{device.id}",
             "kind": getattr(device, "device_kind", device.platform),

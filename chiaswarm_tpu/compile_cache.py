@@ -1,80 +1,108 @@
-"""Persistent XLA compilation cache plumbing (one knob, two consumers).
+"""Persistent XLA compilation cache, placed from outside the program.
 
 A cold slice pays the full XLA trace+compile on its first pass of every
-shape bucket (~12 s of the ~17.7 s tiny-smoke warmup on CPU, 369 s for
-the SDXL flagship on a v5e chip — BENCH_r02/r05). The compiled
+shape bucket (minutes for an SDXL denoise program). The compiled
 executables are deterministic per (HLO, backend), so JAX's persistent
-compilation cache can carry the compile half across process restarts:
-a rolling worker restart then pays only trace + cache deserialization.
+compilation cache carries the compile half across process restarts: a
+restarted worker then pays only trace + cache deserialization.
 
-`Settings.compile_cache_dir` / `CHIASWARM_COMPILE_CACHE_DIR` picks the
-directory: a relative value resolves under `$SDAAS_ROOT` (default
-`xla_cache` -> `$SDAAS_ROOT/xla_cache`), `~` expands, and an empty
-value (or "0"/"off") disables the cache entirely — the disabled path
-never imports jax or touches its config, so opting out is 0-cost. An
-unwritable directory degrades to a warning + disabled cache, never a
-worker failure (the cache is an optimization).
+The directory is part of the cache's key, so it must not move:
+
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, jax itself reads it and this
+  module sets no directory in code — whoever starts the process (the
+  Dockerfile, an operator, a test harness handing one directory to two
+  children) places the cache;
+- where it is not set, the cache is ``.jax_cache`` beside the package
+  (the root of a checkout, git-ignored): one fixed path, independent of
+  ``SDAAS_ROOT``, pid, time and temp names.
+
+A directory that cannot be written is an error, not a silently cold cache.
 
 Consumers: worker.startup() (min_compile_time 1.0 s, so thousands of
-trivial sub-programs don't spam the spool) and bench.py (the
-warm-restart probe uses 0.0 so the whole tiny pipeline caches).
+trivial sub-programs don't spam the cache) and bench.py (the warm-restart
+dry-run row uses 0.0 so the whole tiny pipeline caches).
 """
 
 from __future__ import annotations
 
-import logging
 import os
 from pathlib import Path
 
-logger = logging.getLogger(__name__)
+from . import telemetry
 
-_DISABLED_VALUES = {"", "0", "off", "none", "disabled"}
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = Path(__file__).resolve().parent.parent / ".jax_cache"
+
+# jax's own cache events: a "hit" is an executable read back from the
+# directory instead of compiled, a "miss" one compiled and written to it
+_LOOKUPS = telemetry.counter(
+    "swarm_xla_cache_total",
+    "Persistent XLA compilation cache lookups by outcome (hit = read "
+    "from the cache directory, miss = compiled and written there)",
+    ("event",),
+)
+_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# every program jax hands the backend — each jit site's and each eager
+# op's first use of a shape, whichever path it took there (the program
+# ledger sees only its own sites). "No compile inside the window" is this
+# counter standing still.
+_COMPILES = telemetry.counter(
+    "swarm_xla_compiles_total",
+    "Programs handed to the backend compiler or read back from the "
+    "persistent cache (every jit site and eager op)")
+_COMPILE_SECONDS = telemetry.counter(
+    "swarm_xla_compile_seconds_total",
+    "Seconds spent in backend compiles and persistent-cache reads")
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_listening = False
 
 
-def resolve_cache_dir(settings=None) -> Path | None:
-    """The configured cache directory, or None when disabled. Pure path
-    logic — no filesystem writes, no jax."""
-    if settings is None:
-        from .settings import load_settings
+def _count_event(event: str, **_) -> None:
+    outcome = _EVENTS.get(event)
+    if outcome:
+        _LOOKUPS.inc(event=outcome)
 
-        settings = load_settings()
-    raw = str(getattr(settings, "compile_cache_dir", "") or "").strip()
-    if raw.lower() in _DISABLED_VALUES:
-        return None
-    path = Path(os.path.expanduser(raw))
-    if not path.is_absolute():
-        from .settings import get_settings_dir
 
-        path = get_settings_dir() / path
+def _count_duration(event: str, duration: float, **_) -> None:
+    if event == _BACKEND_COMPILE:
+        _COMPILES.inc()
+        _COMPILE_SECONDS.inc(duration)
+
+
+def cache_dir() -> Path:
+    """Where the cache lives. Pure path logic — no writes, no jax."""
+    return Path(os.environ.get(ENV_VAR) or DEFAULT_DIR)
+
+
+def writable_cache_dir() -> Path:
+    """`cache_dir()`, created and proven writable. Raises OSError when the
+    directory cannot be created or written. No jax."""
+    path = cache_dir()
+    path.mkdir(parents=True, exist_ok=True)
+    probe = path / ".write_probe"
+    probe.write_text("ok")
+    probe.unlink()
     return path
 
 
-def enable_compile_cache(settings=None,
-                         min_compile_time_s: float = 1.0) -> Path | None:
-    """Point jax's persistent compilation cache at the configured
-    directory. Returns the active path, or None when disabled or the
-    directory can't be created/written (logged as a warning — the worker
-    keeps serving, it just recompiles on restart)."""
-    path = resolve_cache_dir(settings)
-    if path is None:
-        return None
-    try:
-        path.mkdir(parents=True, exist_ok=True)
-        probe = path / ".write_probe"
-        probe.write_text("ok")
-        probe.unlink()
-    except OSError as e:
-        logger.warning(
-            "compile cache dir %s is not writable (%s); persistent "
-            "compilation cache disabled for this run", path, e)
-        return None
-    try:
-        import jax
+def enable_compile_cache(min_compile_time_s: float = 1.0) -> Path:
+    """Turn jax's persistent compilation cache on at `cache_dir()` and
+    return that path. Raises OSError when the directory cannot be created
+    or written."""
+    path = writable_cache_dir()
 
+    import jax
+
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_listener(_count_event)
+        jax.monitoring.register_event_duration_secs_listener(_count_duration)
+        _listening = True
+    if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", str(path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_s))
-    except Exception as e:  # cache is an optimization, never fatal
-        logger.warning("persistent compilation cache unavailable: %s", e)
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_time_s))
     return path

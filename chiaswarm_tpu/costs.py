@@ -7,11 +7,10 @@ how many FLOPs it served or what MFU a pass achieved. This module is the
 shared cost vocabulary for both:
 
 - ``PEAK_TFLOPS`` / ``peak_tflops(device)``: the per-chip peak dense
-  bf16 table, hoisted out of bench.py (which imports it back), with the
-  same ``BENCH_PEAK_TFLOPS`` env override. A platform with no entry
-  (CPU smoke, an unknown TPU generation) yields None — MFU then reports
-  ``null`` while FLOPs are still counted, so the cost plane degrades to
-  pure work accounting instead of lying.
+  bf16 table, keyed by ``device_kind``. A TPU kind with no row is an
+  error; a non-TPU platform (CPU tests, dry runs) yields None — MFU then
+  reports ``null`` while FLOPs are still counted, so the cost plane
+  degrades to pure work accounting instead of lying.
 - ``pass_cost`` / ``job_cost``: the ``pipeline_config.cost`` stamp the
   pipeline attaches to every envelope (solo, batched, sharded, chunked
   — all four run through the two stamping sites in
@@ -33,14 +32,12 @@ runtime.
 
 from __future__ import annotations
 
-import os
-
 from . import telemetry
 
 # peak dense bf16 TFLOP/s per chip, by device kind prefix (the MFU
-# denominator's denominator). Hoisted from bench.py; extend it when a
-# new TPU generation lands — an unknown kind reports MFU null, never a
-# made-up ratio.
+# denominator's denominator; v5e: Google Cloud documentation, "TPU v5e").
+# Extend it when a new TPU generation lands — an unknown TPU kind is an
+# error, never a made-up ratio.
 PEAK_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,
@@ -76,16 +73,17 @@ _DIVERGENCE = telemetry.gauge(
 
 def peak_tflops(device) -> float | None:
     """Per-chip peak dense bf16 TFLOP/s for `device` (anything with a
-    ``device_kind``), or None when the platform has no table entry.
-    ``BENCH_PEAK_TFLOPS`` overrides — the knob the TPU bench windows
-    already use to pin a denominator."""
-    override = os.environ.get("BENCH_PEAK_TFLOPS")
-    if override:
-        return float(override)
+    ``device_kind``). A TPU whose kind has no table row is an error — add
+    the row with its source, never a default; any other platform (CPU
+    tests and dry runs) has no peak and yields None, so MFU reads null."""
     kind = getattr(device, "device_kind", "") or ""
     for prefix, tf in PEAK_TFLOPS.items():
         if kind.startswith(prefix):
             return tf
+    if getattr(device, "platform", "") == "tpu" or kind.startswith("TPU"):
+        raise ValueError(
+            f"no peak TFLOP/s known for TPU device kind {kind!r}; "
+            "add it to costs.PEAK_TFLOPS")
     return None
 
 

@@ -4,24 +4,38 @@ The reference leans on diffusers' attention slicing to fit VRAM
 (swarm/diffusion/diffusion_func.py:134-146); on TPU the lever is a fused
 flash kernel that never materializes the [S, S] score matrix in HBM
 (SURVEY §7 'Pallas attention kernel'). All shapes here are [B, S, H, D].
+
+The choice is a trace-time branch on platform and shape only
+(ops/platform.py): no environment toggle, and no fallback if the kernel
+fails to lower — a refused kernel fails the compile, loudly.
 """
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from .flash_attention import flash_attention
+from .platform import (
+    KERNEL_TRACES,
+    active_mesh,
+    batch_axis,
+    trace_platform,
+)
 
 # sequence length below which the plain XLA path is faster than paying
 # kernel launch + pipelining overheads
 _FLASH_MIN_SEQ = 1024
+# largest head dim the flash kernel's [block, D] tiles are sized for (the
+# VAE mid-block's single 512-wide head stays on the XLA path)
+_FLASH_MAX_HEAD_DIM = 128
 
 def _ring_min_seq() -> int:
     """Sequence length at which self-attention shards over the mesh seq
-    axis (ring attention) when a sequence_parallel_scope is active.
+    axis (ring attention) when a mesh_scope is active.
     Settings-backed (`ring_min_seq` / SDAAS_RING_MIN_SEQ) so tests and the
     multichip dryrun exercise the production routing through configuration
     rather than monkey-patching (VERDICT r04 weak #3). Read at trace time
@@ -40,43 +54,18 @@ def _ring_min_seq() -> int:
     except (AttributeError, TypeError, ValueError):
         return 2048
 
-_SEQ_SCOPE = threading.local()
-
-
-@contextlib.contextmanager
-def sequence_parallel_scope(mesh):
-    """Route long self-attention through ring attention over `mesh`'s seq
-    axis while tracing under this scope.
-
-    Pipelines wrap their jitted-program *invocation* in this scope: jit
-    traces lazily on the first call, so the routing decision (a trace-time
-    branch) lands in the compiled program; cached invocations are
-    unaffected. `mesh=None` or a mesh with seq size 1 makes the scope a
-    no-op, so call sites never need their own guard.
-    """
-    from ..parallel.mesh import SEQ_AXIS
-
-    prev = getattr(_SEQ_SCOPE, "mesh", None)
-    _SEQ_SCOPE.mesh = (
-        mesh if mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1 else None
-    )
-    try:
-        yield
-    finally:
-        _SEQ_SCOPE.mesh = prev
-
-
 def _ring_route(q, k, v, scale):
     """Ring attention under shard_map when the active scope's mesh can
     split this self-attention; None when it doesn't apply."""
-    mesh = getattr(_SEQ_SCOPE, "mesh", None)
-    if mesh is None:
+    from ..parallel.mesh import DATA_AXIS, SEQ_AXIS
+
+    mesh = active_mesh()
+    if mesh is None or mesh.shape.get(SEQ_AXIS, 1) <= 1:
         return None
     if q.shape[1] != k.shape[1]:  # cross-attention keeps the short KV local
         return None
     if q.shape[1] < _ring_min_seq():
         return None
-    from ..parallel.mesh import DATA_AXIS, SEQ_AXIS
     from ..parallel.ring import ring_shard_map
 
     n = mesh.shape[SEQ_AXIS]
@@ -87,6 +76,37 @@ def _ring_route(q, k, v, scale):
     data = mesh.shape.get(DATA_AXIS, 1)
     shard_batch = data > 1 and q.shape[0] % data == 0
     return ring_shard_map(mesh, scale, shard_batch=shard_batch)(q, k, v)
+
+
+def _flash_route(q, k, v, scale, interpret: bool = False):
+    """The flash kernel, split by hand over the active scope's mesh
+    (ops/platform.py mesh_scope). Attention is independent per batch row
+    and per head, so under a mesh the call runs in shard_map:
+    batch over `data`, heads over `tensor` where the head count divides
+    (SDXL's 20-head level on 4 chips), else query rows over `tensor` with
+    K/V whole on every chip (its 10-head level). Axes that divide nothing
+    stay replicated.
+    """
+    kernel = functools.partial(flash_attention, scale=scale,
+                               interpret=interpret)
+    mesh = active_mesh()
+    if mesh is None:
+        return kernel(q, k, v)
+    from ..parallel.mesh import TENSOR_AXIS
+
+    data = batch_axis(mesh, q.shape[0])
+    tensor = mesh.shape[TENSOR_AXIS]
+    if q.shape[2] % tensor == 0:
+        q_spec = kv_spec = P(data, None, TENSOR_AXIS, None)
+    elif q.shape[1] % tensor == 0:
+        q_spec = P(data, TENSOR_AXIS, None, None)
+        kv_spec = P(data, None, None, None)
+    else:
+        q_spec = kv_spec = P(data, None, None, None)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec, check_vma=False,
+    )(q, k, v)
 
 
 def reference_attention(q, k, v, scale: float | None = None):
@@ -106,47 +126,13 @@ def dot_product_attention(q, k, v, scale: float | None = None):
     On TPU with long latent sequences the Pallas flash kernel takes over;
     otherwise XLA's fused attention handles it.
     """
-    # trace-time platform check honoring an active `jax.default_device(...)`
-    # scope (e.g. param init pinned to CPU while the global backend is TPU);
-    # the override may be a Device or a platform string
-    override = jax.config.jax_default_device
-    if override is None:
-        platform = jax.default_backend()
-    elif isinstance(override, str):
-        platform = override
-    else:
-        platform = override.platform
     ring_out = _ring_route(q, k, v, scale)
     if ring_out is not None:
+        KERNEL_TRACES.inc(op="attention", path="ring")
         return ring_out
-    on_tpu = platform == "tpu"
-    if _flash_disabled():
-        on_tpu = False
-    if on_tpu and q.shape[1] >= _FLASH_MIN_SEQ and q.shape[-1] <= 128:
-        try:
-            from .flash_attention import flash_attention
-        except ImportError:
-            _warn_no_flash()
-        else:
-            return flash_attention(q, k, v, scale=scale)
+    if (trace_platform() == "tpu" and q.shape[1] >= _FLASH_MIN_SEQ
+            and q.shape[-1] <= _FLASH_MAX_HEAD_DIM):
+        KERNEL_TRACES.inc(op="attention", path="flash")
+        return _flash_route(q, k, v, scale)
+    KERNEL_TRACES.inc(op="attention", path="reference")
     return reference_attention(q, k, v, scale=scale)
-
-
-@functools.cache
-def _flash_disabled() -> bool:
-    """Operational escape hatch: CHIASWARM_DISABLE_FLASH=1 routes all
-    attention through XLA's fused path (A/B perf comparison, or a
-    suspected kernel miscompile on a new TPU generation)."""
-    import os
-
-    return os.environ.get("CHIASWARM_DISABLE_FLASH", "") == "1"
-
-
-@functools.cache
-def _warn_no_flash():
-    import logging
-
-    logging.getLogger(__name__).warning(
-        "Pallas flash-attention kernel unavailable; falling back to the "
-        "O(S^2)-memory XLA attention path."
-    )

@@ -9,12 +9,15 @@ Non-causal (diffusion attention has no causal mask), self- and cross-
 attention (padded + masked KV for ragged text lengths like 77).
 
 Layout: q [B, Sq, H, D], k/v [B, Skv, H, D] -> [B, Sq, H, D], matching
-ops.attention. Heads ride the GRID via BlockSpec index maps — unlike the
-round-2 kernel there is no [B,S,H,D] -> [B*H,S,D] transpose+reshape, which
-materialized full copies of Q, K, V and O in HBM around every attention
-call (~6 extra tensor round-trips of pure bandwidth per layer). The only
-remaining host-side data movement is S-axis padding, and the common
-diffusion sequence lengths (4096, 1024, 256) pad to nothing.
+ops.attention. The TPU lowering wants the last two dims of every block to
+be (multiple of 8, multiple of 128) or a full axis, so a block cannot
+carry a size-1 head axis second to last: the wrapper moves heads next to
+batch ([B, H, S, D]) and every block is (block, D) with D a full axis.
+That transpose is two extra HBM passes per operand; folding heads into
+the lane axis instead is a tuning job for a later PR. KV blocks ride the
+innermost grid axis with the running max / sum / accumulator in VMEM
+scratch, so VMEM use does not grow with the sequence (9216 tokens at
+SD2.1 768^2 costs what 1024 do).
 
 Block sizes are env-tunable for on-hardware sweeps:
 CHIASWARM_FLASH_BLOCK_Q / CHIASWARM_FLASH_BLOCK_K (default 512).
@@ -28,6 +31,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -41,50 +45,52 @@ def _env_blocks() -> tuple[int, int]:
     )
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, kv_len: int,
-                  scale: float):
-    """One (batch*head, q-block) program: stream KV blocks, online softmax.
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                  block_k: int, kv_len: int, scale: float):
+    """One (batch, head, q-block, kv-block) grid step of the online softmax.
 
-    q_ref [1, BQ, 1, D]; k_ref/v_ref [1, Skv_pad, 1, D]; o_ref [1, BQ, 1, D].
+    q_ref/o_ref [BQ, D]; k_ref/v_ref [BK, D]; scratch m/l [BQ, 1] and
+    acc [BQ, D] in f32, carried across the innermost (kv) grid axis.
     """
+    j = pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
     # QK^T runs in the INPUT dtype (bf16 on TPU) with f32 accumulation:
     # the MXU computes bf16 x bf16 -> f32 natively at full rate, while an
     # f32 x f32 matmul costs several passes. The softmax scale applies to
     # the f32 scores after the dot, so no precision is lost to scaling.
-    q = q_ref[0, :, 0, :]
-    block_q, head_dim = q.shape
-    padded_kv = k_ref.shape[1]
+    v = v_ref[...]
+    s = jax.lax.dot_general(
+        q_ref[...], k_ref[...],
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [BQ, BK] f32
+    # mask KV padding (ragged cross-attention lengths)
+    if kv_len % block_k:
+        col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col < kv_len, s, _NEG_INF)
+    m = m_ref[...]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[...] = m_new
 
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
-
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), 0, :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), 0, :]
-        s = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [BQ, BK] f32
-        # mask KV padding (ragged cross-attention lengths)
-        if kv_len % block_k:
-            col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col < kv_len, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
-
-    _, l, acc = jax.lax.fori_loop(0, padded_kv // block_k, body, (m0, l0, acc0))
-    o_ref[0, :, 0, :] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        o_ref[...] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        ).astype(o_ref.dtype)
 
 
 def _pad_to(x, length: int, axis: int):
@@ -125,38 +131,44 @@ def _flash_impl(q, k, v, scale: float | None, block_q: int, block_k: int,
     b, sq, h, d = q.shape
     skv = k.shape[1]
 
-    block_q = min(block_q, max(sq, 16))
-    block_k = min(block_k, max(_round_up(skv, 128), 128))
-
+    # blocks stay multiples of (16, 128) — the bf16 tile — or the whole
+    # padded axis
+    block_q = min(block_q, _round_up(sq, 16))
+    block_k = min(block_k, _round_up(skv, 128))
     sq_pad = _round_up(sq, block_q)
     skv_pad = _round_up(skv, block_k)
 
-    q = _pad_to(q, sq_pad, 1)
-    k = _pad_to(k, skv_pad, 1)
-    v = _pad_to(v, skv_pad, 1)
+    # [B, S, H, D] -> [B, H, S_pad, D]
+    q = _pad_to(q, sq_pad, 1).transpose(0, 2, 1, 3)
+    k = _pad_to(k, skv_pad, 1).transpose(0, 2, 1, 3)
+    v = _pad_to(v, skv_pad, 1).transpose(0, 2, 1, 3)
 
-    # heads fold into the grid via the index maps — no data movement. The
-    # grid order (bh outer, q-block inner) keeps each head's KV block
-    # resident in VMEM across its q-blocks (identical index -> no refetch).
-    grid = (b * h, sq_pad // block_q)
+    q_spec = pl.BlockSpec((None, None, block_q, d),
+                          lambda bi, hi, i, j: (bi, hi, i, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, d),
+                           lambda bi, hi, i, j: (bi, hi, j, 0))
     out = pl.pallas_call(
         functools.partial(
             _flash_kernel, block_k=block_k, kv_len=skv, scale=scale
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda bh, i: (bh // h, i, bh % h, 0)),
-            pl.BlockSpec((1, skv_pad, 1, d), lambda bh, i: (bh // h, 0, bh % h, 0)),
-            pl.BlockSpec((1, skv_pad, 1, d), lambda bh, i: (bh // h, 0, bh % h, 0)),
+        grid=(b, h, sq_pad // block_q, skv_pad // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, 1, d), lambda bh, i: (bh // h, i, bh % h, 0)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary"),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, sq_pad, h, d), q.dtype),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
 
-    return out[:, :sq]
+    return out[:, :, :sq].transpose(0, 2, 1, 3)
 
 
 def _round_up(n: int, m: int) -> int:

@@ -4,45 +4,39 @@ GroupNorm is the UNet families' highest-traffic non-matmul op (~60
 instances per SDXL UNet call). XLA's fused schedule is 2 HBM reads + 1
 write per GN (stats pass + apply pass); this Pallas kernel does the whole
 thing in VMEM — ONE read + one write — whenever a batch row's [N, C]
-input+output tiles fit the conservative on-chip budget (the 32x32-and-
-deeper UNet levels and the small VAE stages by default; the bigger
-levels fall back to the XLA path, which is already near-roofline for its
-schedule, until an on-hardware sweep raises CHIASWARM_FUSED_GN_MAX_BYTES
-with measured footprints). SiLU fuses into the same pass, as does the
-affine.
+tile fits the conservative on-chip budget (`fused_tile_bytes` against
+`_vmem_budget`); bigger tiles take the XLA path, which is already
+near-roofline for its schedule, until an on-hardware sweep raises
+CHIASWARM_FUSED_GN_MAX_BYTES with measured footprints. SiLU fuses into
+the same pass, as does the affine.
 
-The kernel keeps the tile in its serving dtype (bf16) and accumulates
-statistics in f32 via two [C]-vector reductions (sum, sum of squares), so
-the per-group math reduces to a [C] scale'/[C] bias' broadcast — no
-in-kernel [N, G, C/G] relayouts, which Mosaic would pay lane shuffles for.
-
-Dispatch: `group_norm(x, scale, bias, ...)` routes to the kernel on TPU
-unless CHIASWARM_DISABLE_FUSED_GN=1 (A/B escape hatch, mirroring
-CHIASWARM_DISABLE_FLASH); everywhere else — CPU, oversize tiles, ragged
-channel counts — it runs the f32-stats reference path XLA fuses itself.
+Dispatch: `group_norm(x, scale, bias, ...)` is a trace-time branch on
+platform and shape only (ops/platform.py): the kernel on TPU for tiles
+the budget admits, the f32-stats reference XLA fuses itself everywhere
+else — CPU, oversize tiles, channel counts the groups do not divide.
+There is no fallback if the kernel fails to lower: the admission rule is
+checked against the TPU compiler in tests/test_kernels_compile_tpu.py,
+and a refusal past it fails the compile, loudly.
 Numerics vs flax.linen.GroupNorm are pinned by tests/test_group_norm.py.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-logger = logging.getLogger(__name__)
+from jax.sharding import PartitionSpec as P
 
-# Per-tile VMEM budget: the kernel holds the input AND output blocks in
-# VMEM (counted below as 2x the row bytes); the f32 moments are computed
-# by reductions whose elementwise producers Mosaic fuses rather than
-# materializing. The default is deliberately conservative — it admits the
-# 32x32 (and deeper/VAE) levels and rejects 64x64+ — because a
-# VMEM-overflow here is a COMPILE-TIME crash in every UNet GN site, and
-# the hermetic suite (CPU interpret mode) cannot catch TPU allocation
-# failures. CHIASWARM_FUSED_GN_MAX_BYTES raises it for on-hardware
+from .platform import KERNEL_TRACES, active_mesh, batch_axis, trace_platform
+
+# Fast-memory budget for one batch row's tile (`fused_tile_bytes`). The
+# default is deliberately conservative: a VMEM overflow is a COMPILE-TIME
+# failure in every UNet GN site, and the compiler's own scoped limit on a
+# v5e is 16 MiB. CHIASWARM_FUSED_GN_MAX_BYTES raises it for on-hardware
 # sweeps once the kernel's real footprint is measured.
 _DEFAULT_VMEM_TILE_BYTES = 6 * 1024 * 1024
 
@@ -52,39 +46,83 @@ def _vmem_budget() -> int:
                               _DEFAULT_VMEM_TILE_BYTES))
 
 
-def _fused_disabled() -> bool:
-    return os.environ.get("CHIASWARM_DISABLE_FUSED_GN", "") == "1"
+def _chunk_rows(n: int, c: int) -> int:
+    """Rows of the [N, C] tile the kernel turns to f32 at a time: the
+    largest power of two that divides N and keeps one f32 chunk under
+    256 KiB (so the f32 intermediates stay a small, fixed part of the
+    footprint), or the whole tile when N has no such divisor."""
+    rows = 8
+    while n % (rows * 2) == 0 and rows * 2 * c * 4 <= 256 * 1024:
+        rows *= 2
+    return rows if n % rows == 0 else n
+
+
+def fused_tile_bytes(n: int, c: int, itemsize: int) -> int:
+    """Fast memory one [N, C] row of the batch costs the kernel: its input
+    and output blocks, each double-buffered by the Pallas pipeline (the
+    next row's DMA overlaps this row's compute) with channels padded to
+    the 128-lane tile, plus a few f32 chunks."""
+    c_pad = -(-c // 128) * 128
+    return 4 * n * c_pad * itemsize + 4 * _chunk_rows(n, c) * c_pad * 4
 
 
 def _gn_kernel(x_ref, scale_ref, bias_ref, o_ref, *, groups: int, eps: float,
                silu: bool):
-    """One batch row: x_ref [1, N, C] -> o_ref [1, N, C], stats in f32."""
-    x = x_ref[0]  # [N, C], serving dtype
-    n, c = x.shape
+    """One batch row: x_ref [N, C] -> o_ref [N, C], stats in f32.
+
+    The tile stays in its serving dtype in VMEM; both passes over it (sum
+    / sum of squares, then normalize) walk it in row chunks so only one
+    chunk is ever f32. Everything is kept 2-D: Mosaic has no layout for
+    the [C] -> [G, C/G] cast the obvious per-group fold needs, so groups
+    are folded and spread back through a [G, C] membership mask instead
+    (VPU selects and reductions, exact in f32).
+    """
+    n, c = x_ref.shape
     cg = c // groups
+    rows = _chunk_rows(n, c)
 
-    xf = x.astype(jnp.float32)
-    # [C]-vector moments over N, then tiny per-group folds
-    s1 = jnp.sum(xf, axis=0)            # [C]
-    s2 = jnp.sum(xf * xf, axis=0)       # [C]
-    g1 = jnp.sum(s1.reshape(groups, cg), axis=1, keepdims=True)  # [G,1]
-    g2 = jnp.sum(s2.reshape(groups, cg), axis=1, keepdims=True)
+    def chunk(i):
+        if rows == n:  # one chunk: a static slice needs no alignment proof
+            return slice(None)
+        return pl.ds(pl.multiple_of(i * rows, rows), rows)
+
+    def moments(i, carry):
+        s1, s2 = carry
+        xf = x_ref[chunk(i), :].astype(jnp.float32)
+        return (s1 + jnp.sum(xf, axis=0, keepdims=True),
+                s2 + jnp.sum(xf * xf, axis=0, keepdims=True))
+
+    zero = jnp.zeros((1, c), jnp.float32)
+    s1, s2 = jax.lax.fori_loop(0, n // rows, moments, (zero, zero))
+
+    channel = jax.lax.broadcasted_iota(jnp.int32, (groups, c), 1)
+    first = jax.lax.broadcasted_iota(jnp.int32, (groups, c), 0) * cg
+    member = (channel >= first) & (channel < first + cg)    # [G, C]
+
+    def fold(per_channel):      # [1, C] -> [G, 1]
+        return jnp.sum(jnp.where(member, per_channel, 0.0), axis=1,
+                       keepdims=True)
+
+    def spread(per_group):      # [G, 1] -> [1, C]
+        return jnp.sum(jnp.where(member, per_group, 0.0), axis=0,
+                       keepdims=True)
+
     count = jnp.float32(n * cg)
-    mean = g1 / count                                  # [G,1]
-    var = g2 / count - mean * mean
-    rstd = jax.lax.rsqrt(var + eps)                    # [G,1]
+    mean = fold(s1) / count
+    var = fold(s2) / count - mean * mean
+    rstd = jax.lax.rsqrt(var + eps)
 
-    gamma = scale_ref[...].astype(jnp.float32)         # [C]
-    beta = bias_ref[...].astype(jnp.float32)
-    mean_c = jnp.broadcast_to(mean, (groups, cg)).reshape(c)
-    rstd_c = jnp.broadcast_to(rstd, (groups, cg)).reshape(c)
-    scale_c = gamma * rstd_c                           # [C]
-    bias_c = beta - mean_c * scale_c
+    scale_c = scale_ref[...].astype(jnp.float32) * spread(rstd)   # [1, C]
+    bias_c = bias_ref[...].astype(jnp.float32) - spread(mean) * scale_c
 
-    y = xf * scale_c[None, :] + bias_c[None, :]
-    if silu:
-        y = y * jax.nn.sigmoid(y)
-    o_ref[0] = y.astype(o_ref.dtype)
+    def apply(i, carry):
+        y = x_ref[chunk(i), :].astype(jnp.float32) * scale_c + bias_c
+        if silu:
+            y = y * jax.nn.sigmoid(y)
+        o_ref[chunk(i), :] = y.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, n // rows, apply, 0)
 
 
 @functools.partial(
@@ -94,18 +132,17 @@ def _fused_group_norm(x3, scale, bias, groups: int, eps: float, silu: bool,
                       interpret: bool = False):
     """x3 [B, N, C] -> [B, N, C] via the single-pass kernel."""
     b, n, c = x3.shape
+    tile = pl.BlockSpec((None, n, c), lambda i: (i, 0, 0))
+    vec = pl.BlockSpec((1, c), lambda i: (0, 0))
     return pl.pallas_call(
         functools.partial(_gn_kernel, groups=groups, eps=eps, silu=silu),
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, n, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, n, c), lambda i: (i, 0, 0)),
+        in_specs=[tile, vec, vec],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((b, n, c), x3.dtype),
+        name="fused_group_norm",
         interpret=interpret,
-    )(x3, scale, bias)
+    )(x3, scale.reshape(1, c), bias.reshape(1, c))
 
 
 def _reference_group_norm(x, scale, bias, groups: int, eps: float,
@@ -143,40 +180,26 @@ def group_norm(x, scale, bias, *, groups: int = 32, eps: float = 1e-5,
     for d in x.shape[1:-1]:
         n *= d
     use_kernel = (
-        not _fused_disabled()
-        and (interpret or jax.default_backend() == "tpu")
+        (interpret or trace_platform() == "tpu")
         and x.ndim >= 3
         and c % groups == 0
-        # single-pass holds the [N, C] input AND output rows in VMEM plus
-        # the f32 intermediates (xf, and y before the final cast) — for
-        # bf16 inputs those are 2x each of the serving-dtype rows, so the
-        # budget charges them explicitly (ADVICE r05: the old 2x-row check
-        # under-counted by ~3x and a VMEM overflow is a compile-time crash
-        # at every serving-path GN site)
-        and 2 * _row_bytes(x) + 2 * 4 * n * c <= _vmem_budget()
+        and fused_tile_bytes(n, c, x.dtype.itemsize) <= _vmem_budget()
     )
     if not use_kernel:
+        KERNEL_TRACES.inc(op="group_norm", path="reference")
         return _reference_group_norm(x, scale, bias, groups, eps, silu, dtype)
 
-    b = x.shape[0]
-    x3 = x.reshape(b, n, c)
-    try:
-        out = _fused_group_norm(
-            x3, jnp.asarray(scale), jnp.asarray(bias), groups, eps, silu,
-            interpret=interpret,
-        )
-    except Exception as e:  # noqa: BLE001
-        # the admission check is an estimate; if Mosaic still refuses the
-        # tile (or the kernel fails to lower), the job must survive on the
-        # XLA path rather than die — the bench ladder has a
-        # kernels-disabled retry, the serving path gets this one
-        logger.warning("fused group_norm failed (%s); using XLA path", e)
-        return _reference_group_norm(x, scale, bias, groups, eps, silu, dtype)
+    KERNEL_TRACES.inc(op="group_norm", path="fused")
+    kernel = functools.partial(_fused_group_norm, groups=groups, eps=eps,
+                               silu=silu, interpret=interpret)
+    mesh = active_mesh()
+    if mesh is not None:
+        # opaque to the SPMD partitioner (ops/platform.py mesh_scope):
+        # batch rows over `data`; the activations a GroupNorm sees are
+        # whole on every chip of the tensor axis, so each normalizes its own
+        rows = P(batch_axis(mesh, x.shape[0]), None, None)
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(rows, P(), P()),
+                               out_specs=rows, check_vma=False)
+    out = kernel(x.reshape(x.shape[0], n, c), jnp.asarray(scale),
+                 jnp.asarray(bias))
     return out.reshape(x.shape).astype(dtype)
-
-
-def _row_bytes(x) -> int:
-    n = 1
-    for d in x.shape[1:-1]:
-        n *= d
-    return n * x.shape[-1] * x.dtype.itemsize

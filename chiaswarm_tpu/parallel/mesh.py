@@ -69,39 +69,6 @@ def pad_batch(n: int, parts: int) -> int:
     return math.ceil(n / parts) * parts
 
 
-def stack_rows(*blocks):
-    """Equal-shaped blocks -> one [k*B, ...] array; same values as
-    ``jnp.concatenate(blocks, axis=0)``.
-
-    Spelled stack+reshape on purpose: this container's jax (0.4.37) SPMD
-    partitioner miscompiles ``concatenate`` ALONG a dim that is sharded on
-    one mesh axis while the operand is replicated over a second non-trivial
-    axis — the concat output comes back multiplied by the replicated axis
-    size (each replica's contribution is summed instead of asserted equal).
-    Observed on [data>1, tensor>1] CPU meshes; stack+reshape lowers to pure
-    data movement and partitions correctly. Value-identical everywhere, so
-    single-chip programs (and their goldens) are unaffected.
-    """
-    import jax.numpy as jnp
-
-    if len(blocks) == 1:
-        return blocks[0]
-    first = blocks[0]
-    return jnp.stack(blocks, axis=0).reshape(
-        len(blocks) * first.shape[0], *first.shape[1:]
-    )
-
-
-def repeat_rows(x, n: int):
-    """``jnp.concatenate([x] * n, axis=0)`` as a tile — see stack_rows for
-    why concatenate itself is off-limits inside sharded programs."""
-    import jax.numpy as jnp
-
-    if n <= 1:
-        return x
-    return jnp.tile(x, (n,) + (1,) * (x.ndim - 1))
-
-
 def shard_batch(mesh: Mesh, tree):
     """Device_put a host pytree with dim-0 sharded over `data`.
 

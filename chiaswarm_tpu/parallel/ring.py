@@ -13,8 +13,6 @@ Shapes inside shard_map: q, k, v are the LOCAL blocks [B, S/n, H, D].
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -77,11 +75,6 @@ def _rowscale(x):
     return jnp.transpose(x, (0, 2, 1))[..., None]
 
 
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def _noop(x, mesh):  # pragma: no cover - placeholder for cache warmup
-    return x
-
-
 def ring_shard_map(mesh: Mesh, scale: float | None = None,
                    shard_batch: bool = False):
     """The shard_map'd ring-attention entry: [B,S,H,D] sequence-sharded on
@@ -95,15 +88,8 @@ def ring_shard_map(mesh: Mesh, scale: float | None = None,
     """
     from .mesh import DATA_AXIS
 
-    # jax moved shard_map out of experimental around 0.4.38; serve both
-    # (this container's 0.4.37 only has the experimental spelling)
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-
     spec = P(DATA_AXIS if shard_batch else None, SEQ_AXIS, None, None)
-    return shard_map(
+    return jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -115,7 +101,7 @@ def ring_self_attention_sharded(mesh: Mesh, q, k, v, scale: float | None = None)
     """Convenience wrapper: shard [B,S,H,D] host arrays over the seq axis and
     run ring attention under shard_map. For use outside an enclosing pjit
     (tests, standalone ops); pipelines route here via
-    `ops.attention.sequence_parallel_scope`.
+    `ops.platform.mesh_scope`.
     """
     sharding = NamedSharding(mesh, P(None, SEQ_AXIS, None, None))
     q, k, v = (jax.device_put(x, sharding) for x in (q, k, v))

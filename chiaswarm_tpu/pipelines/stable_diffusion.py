@@ -42,9 +42,7 @@ from ..models.vae import AutoencoderKL
 from ..parallel.mesh import (
     batch_sharding,
     make_mesh,
-    repeat_rows,
     replicated,
-    stack_rows,
 )
 from ..registry import register_family
 from ..schedulers import get_scheduler
@@ -189,10 +187,7 @@ def _config_prediction_type(model_name: str) -> str | None:
 
     from ..settings import load_settings
 
-    try:
-        root = Path(load_settings().model_root_dir).expanduser() / model_name
-    except Exception:
-        return None
+    root = Path(load_settings().model_root_dir).expanduser() / model_name
     p = root / "scheduler" / "scheduler_config.json"
     if p.is_file():
         try:
@@ -1346,9 +1341,9 @@ class SDPipeline:
                 if mode == "pix2pix":
                     # per-row channel conditioning: zeros for the uncond
                     # row so image guidance has a true no-image baseline
-                    cond_rows = stack_rows(
-                        jnp.zeros_like(image_latents), image_latents,
-                        image_latents,
+                    cond_rows = jnp.concatenate(
+                        [jnp.zeros_like(image_latents), image_latents,
+                         image_latents], axis=0,
                     ).astype(self.dtype)
                 if mode == "inpaint":
                     clean = image_latents
@@ -1356,15 +1351,19 @@ class SDPipeline:
                     # dedicated inpaint UNet: mask plane + masked-image
                     # latents ride the channel dim on both CFG rows
                     cond9 = jnp.concatenate([mask, image_latents], axis=-1)
-                    cond9 = repeat_rows(cond9, 2).astype(self.dtype)
+                    cond9 = jnp.concatenate(
+                        [cond9, cond9], axis=0).astype(self.dtype)
                 if cn_key is not None:
-                    control2 = repeat_rows(control_cond, 2).astype(self.dtype)
+                    control2 = jnp.concatenate(
+                        [control_cond, control_cond], axis=0
+                    ).astype(self.dtype)
                     _, cg_lo, cg_hi = cn_key
 
                 def body(carry, i):
                     latents, state = carry
                     inp = scheduler.scale_model_input(schedule, latents, i)
-                    model_in = repeat_rows(inp, cfg_rows).astype(self.dtype)
+                    model_in = jnp.concatenate(
+                        [inp] * cfg_rows, axis=0).astype(self.dtype)
                     if mode == "pix2pix":
                         # image latents join unscaled: the edit checkpoint was
                         # trained on raw latent-dist modes
@@ -1483,12 +1482,9 @@ class SDPipeline:
     @staticmethod
     def _program_cache_max() -> int:
         """Settings.program_cache_max at call time (env-overridable,
-        CHIASWARM_PROGRAM_CACHE_MAX); 0 = unbounded."""
-        try:
-            return max(int(getattr(
-                load_settings(), "program_cache_max", 64) or 0), 0)
-        except Exception:
-            return 64
+        CHIASWARM_PROGRAM_CACHE_MAX); 0 = unbounded. A malformed value
+        fails loudly, like every other setting."""
+        return max(int(load_settings().program_cache_max or 0), 0)
 
     def _trim_program_caches(self) -> None:
         """LRU-bound both variant caches to program_cache_max (caller
@@ -1601,11 +1597,7 @@ class SDPipeline:
     def _denoise_chunk_steps(self) -> int:
         """Settings.denoise_chunk_steps at call time (env-overridable per
         process, CHIASWARM_DENOISE_CHUNK_STEPS); 0 = single fused pass."""
-        try:
-            return max(int(getattr(
-                load_settings(), "denoise_chunk_steps", 0) or 0), 0)
-        except Exception:
-            return 0
+        return max(int(load_settings().denoise_chunk_steps or 0), 0)
 
     def _chunk_programs(self, key, controlnet_module, geo, mesh, chunk,
                         lora_sig=None, analytic_flops=None):
@@ -1781,7 +1773,7 @@ class SDPipeline:
                 # the happy-path cost is one host round trip per chunk,
                 # microseconds against a multi-second chunk. A pass
                 # with no probe (direct pipeline calls) runs free.
-                from ..ops.attention import sequence_parallel_scope
+                from ..ops.platform import mesh_scope
 
                 cur_geo, cur_mesh = geo, mesh
                 cur_chunks, cur_decode = chunk_progs, decode_prog
@@ -1839,7 +1831,7 @@ class SDPipeline:
                                 t0 = time.perf_counter()
                                 cur_mesh, geo_params = self._geometry_view(
                                     target)
-                                with sequence_parallel_scope(cur_mesh):
+                                with mesh_scope(cur_mesh):
                                     _, cur_chunks, cur_decode, _, _ = \
                                         self._chunk_programs(
                                             key, controlnet_module, target,
@@ -1869,11 +1861,11 @@ class SDPipeline:
                             # decides whether this boundary is due
                             def _decode(latents=latents, params=params,
                                         dec=cur_decode, m=cur_mesh):
-                                with sequence_parallel_scope(m):
+                                with mesh_scope(m):
                                     return dec(params, latents)
 
                             boundary_cb(at, latents, state, _decode)
-                    with sequence_parallel_scope(cur_mesh):
+                    with mesh_scope(cur_mesh):
                         latents, state = cur_chunks(n)(
                             params, latents, state, context, added,
                             guidance_scale, image_guidance, image_latents,
@@ -1884,7 +1876,7 @@ class SDPipeline:
                     jax.block_until_ready(latents)
                     cancel_probe()
                 self._last_reshards = resharded
-                with sequence_parallel_scope(cur_mesh):
+                with mesh_scope(cur_mesh):
                     return cur_decode(params, latents)
 
         with self._jit_lock:
@@ -1892,6 +1884,21 @@ class SDPipeline:
             self._runner_cache.move_to_end(cache_key)
             self._trim_program_caches()
         return runner
+
+    @staticmethod
+    def _split_compile_time(timings: dict, compiled_before: float,
+                            extra: float = 0.0) -> None:
+        """jit compiles lazily, on a program's first call — inside the
+        denoise span. Move those seconds (programs.compile_seconds since
+        `compiled_before`, plus `extra`) to the compile stage, so the
+        cost stamp and the straggler EWMAs see execution time and a warm
+        pass reports no compile."""
+        compile_s = programs.compile_seconds() - compiled_before + extra
+        if compile_s > 0.01:
+            timings["denoise_decode_s"] = round(max(
+                timings.get("denoise_decode_s", 0.0) - compile_s, 0.0), 3)
+            timings["trace_s"] = round(
+                timings.get("trace_s", 0.0) + compile_s, 3)
 
     @staticmethod
     def _solo_cancel_probe():
@@ -2304,7 +2311,7 @@ class SDPipeline:
         # long-sequence self-attention shards over the mesh seq axis (ring
         # attention) when this pass's mesh view carved one out; trace-time
         # routing, so it binds on the first (tracing) call of each bucket
-        from ..ops.attention import sequence_parallel_scope
+        from ..ops.platform import mesh_scope
 
         # a re-shard mid-pass must only swap between BASE-params views —
         # the same gate as the initial geometry above, ControlNet
@@ -2317,8 +2324,9 @@ class SDPipeline:
             reshard_probe = None
         self._last_reshards = []
         self._last_resume_step = None
+        compiled_before = programs.compile_seconds()
         with Span("denoise", timings, key="denoise_decode_s"):
-            with sequence_parallel_scope(pass_mesh):
+            with mesh_scope(pass_mesh):
                 pixels = runner(
                     job_params,
                     init_rng,
@@ -2346,17 +2354,12 @@ class SDPipeline:
                     resume=resume_state,
                 )
             pixels = jax.block_until_ready(pixels)
-        # a mid-pass re-shard that had to COMPILE its target program set
-        # did so inside the denoise span; move those seconds to the
-        # compile stage so the straggler EWMAs see honest denoise time
-        reshard_compile = sum(
-            entry[3] for entry in self._last_reshards if len(entry) > 3)
-        if reshard_compile > 0.01:
-            timings["denoise_decode_s"] = round(max(
-                timings.get("denoise_decode_s", 0.0) - reshard_compile,
-                0.0), 3)
-            timings["trace_s"] = round(
-                timings.get("trace_s", 0.0) + reshard_compile, 3)
+        # a mid-pass re-shard that had to resolve its target program set
+        # did so inside the denoise span, like every program's own XLA
+        # compile; move those seconds to the compile stage
+        self._split_compile_time(
+            timings, compiled_before, extra=sum(
+                entry[3] for entry in self._last_reshards if len(entry) > 3))
         pass_geometry = {
             "data": pass_mesh.shape.get("data", 1),
             "tensor": pass_mesh.shape.get("tensor", 1),
@@ -2460,7 +2463,7 @@ class SDPipeline:
                 denoise_s=timings.get("denoise_decode_s"),
                 chips=(self.chipset.chip_count() if self.chipset is not None
                        else 1),
-                device=jax.devices()[0] if jax.devices() else None,
+                device=pass_mesh.devices.flat[0],
                 geometry=geometry_label(pass_geometry["tensor"],
                                         pass_geometry["seq"]),
             ),
@@ -2858,10 +2861,11 @@ class SDPipeline:
                 if cancelled_rows and len(cancelled_rows) == len(requests):
                     raise JobCancelled([j for j in row_ids if j])
 
-        from ..ops.attention import sequence_parallel_scope
+        from ..ops.platform import mesh_scope
 
+        compiled_before = programs.compile_seconds()
         with Span("denoise", timings, key="denoise_decode_s"):
-            with sequence_parallel_scope(self.mesh):
+            with mesh_scope(self.mesh):
                 pixels = runner(
                     base_params,
                     init_rng,
@@ -2879,6 +2883,7 @@ class SDPipeline:
                     cancel_probe=probe,
                 )
             pixels = jax.block_until_ready(pixels)
+        self._split_compile_time(timings, compiled_before)
         _SHARDED_PASSES.inc(geometry=geometry_label(
             pass_geometry["tensor"], pass_geometry["seq"]))
         if self.chipset is not None:
@@ -2899,7 +2904,7 @@ class SDPipeline:
             denoise_s=timings.get("denoise_decode_s"),
             chips=(self.chipset.chip_count() if self.chipset is not None
                    else 1),
-            device=jax.devices()[0] if jax.devices() else None,
+            device=self.mesh.devices.flat[0],
             geometry=geometry_label(pass_geometry["tensor"],
                                     pass_geometry["seq"]),
         )

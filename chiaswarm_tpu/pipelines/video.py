@@ -501,10 +501,10 @@ class VideoPipeline:
         key = (lh, lw, frames, steps, scheduler_type)
         t0 = time.perf_counter()
         program = self._program(key)
-        from ..ops.attention import sequence_parallel_scope
+        from ..ops.platform import mesh_scope
 
         mesh = self.chipset.mesh() if self.chipset is not None else None
-        with sequence_parallel_scope(mesh):
+        with mesh_scope(mesh):
             pixels = jax.block_until_ready(
                 program(params, noise, context, jnp.float32(guidance_scale),
                         step_rng)

@@ -9,7 +9,10 @@ that population was a hit/miss counter. This module wraps each jitted
 program in a thin instrumented callable that:
 
 - times the FIRST call (trace + XLA compile + execute — the compile
-  cost an operator actually pays at that site);
+  cost an operator actually pays at that site), and adds the lower +
+  compile part of it to ``compile_seconds()``, the calling thread's
+  running total, which lets a pipeline take compile time out of the
+  span its first call happened to land in;
 - captures XLA's own ``cost_analysis()`` (flops, bytes accessed) from
   the lowered module and ``memory_analysis()`` (argument / output /
   temp / generated-code bytes) from the compiled executable, both
@@ -63,8 +66,8 @@ class ProgramEntry:
         self.key = repr(key) if key is not None else ""
         self.state = "registered"  # -> live (first call) -> evicted
         self.calls = 0
-        self.compile_s = None
-        self.analytic_flops = (None)
+        self.compile_s = None  # first call: trace + compile + execute
+        self.analytic_flops = None
         self.xla = None  # {"flops", "bytes_accessed"} from cost_analysis
         self.memory = None  # byte breakdown from memory_analysis
         self.divergence = None  # xla_flops / analytic_flops
@@ -92,6 +95,15 @@ class ProgramEntry:
 _LOCK = threading.Lock()
 _LEDGER: OrderedDict[int, ProgramEntry] = OrderedDict()
 _next_id = 0
+_COMPILING = threading.local()
+
+
+def compile_seconds() -> float:
+    """Seconds the calling thread has spent lowering + compiling
+    instrumented programs so far. jit compiles lazily, on a program's
+    first call, so a caller that timed a span takes the difference around
+    it to tell compile from execution."""
+    return getattr(_COMPILING, "seconds", 0.0)
 
 
 def _flops_of(analysis) -> float | None:
@@ -183,6 +195,8 @@ class InstrumentedProgram:
         if entry.calls == 0:
             t0 = time.perf_counter()
             compiled = _capture(entry, self._fn, args, kwargs)
+            _COMPILING.seconds = (
+                compile_seconds() + time.perf_counter() - t0)
             out = _SENTINEL = object()
             if compiled is not None:
                 try:

@@ -3,7 +3,7 @@
 Behavior parity with reference swarm/settings.py:7-76 — same file location
 ($SDAAS_ROOT or ~/.sdaas/settings.json), same field names, same env override
 keys (SDAAS_TOKEN / SDAAS_URI / SDAAS_WORKERNAME) — plus TPU-specific fields
-the reference has no analog for (mesh topology, compilation cache directory).
+the reference has no analog for (mesh topology, batching, the embedded hive).
 """
 
 from __future__ import annotations
@@ -38,12 +38,6 @@ class Settings:
     # attention level); configurable so tests and small-canvas deployments
     # exercise the exact production routing instead of monkey-patching
     ring_min_seq: int = 2048
-    # persistent XLA compilation cache (the TPU analog of the HF model
-    # cache): relative values resolve under $SDAAS_ROOT, "~" expands, ""
-    # (or "0"/"off") disables at zero cost — compile_cache.py. Survives a
-    # worker restart, so warm-restart warmup skips the XLA compile half.
-    # (Legacy settings.json key `compilation_cache_dir` still loads.)
-    compile_cache_dir: str = "xla_cache"
     # model weight root (converted Flax checkpoints / HF safetensors)
     model_root_dir: str = "~/.sdaas/models"
     # dtype policy for pipeline params: "bfloat16" | "float32"
@@ -357,7 +351,6 @@ _ENV_OVERRIDES = {
     "SDAAS_DTYPE": "dtype",
     "SDAAS_BATCH_LINGER_MS": "batch_linger_ms",
     "SDAAS_MAX_COALESCE": "max_coalesce",
-    "CHIASWARM_COMPILE_CACHE_DIR": "compile_cache_dir",
     "CHIASWARM_METRICS_PORT": "metrics_port",
     "CHIASWARM_METRICS_HOST": "metrics_host",
     "CHIASWARM_LOG_FORMAT": "log_format",
@@ -447,9 +440,6 @@ def load_settings() -> Settings:
         raw = {}
 
     known = {k: v for k, v in raw.items() if k in Settings.field_names()}
-    # pre-round-8 settings files spelled the cache knob compilation_cache_dir
-    if "compilation_cache_dir" in raw and "compile_cache_dir" not in raw:
-        known["compile_cache_dir"] = raw["compilation_cache_dir"]
     settings = Settings(**known)
 
     for env_key, attr in _ENV_OVERRIDES.items():

@@ -104,9 +104,12 @@ def diffusion_callback(device_identifier: str, model_name: str, **kwargs):
         if key in kwargs
     }
 
-    pipeline = get_pipeline(
-        model_name, pipeline_type=pipeline_type, chipset=chipset
-    )
+    # set-up seconds as their own figure: ~0 when the model is resident,
+    # the weight load (or seeded init) + placement when this job built it
+    with Span("load") as load:
+        pipeline = get_pipeline(
+            model_name, pipeline_type=pipeline_type, chipset=chipset
+        )
     if geometry is not None and hasattr(pipeline, "resolve_geometry"):
         kwargs["geometry"] = geometry
         if reshard_probe is not None:
@@ -114,6 +117,8 @@ def diffusion_callback(device_identifier: str, model_name: str, **kwargs):
     if ckpt_kwargs and getattr(pipeline, "supports_checkpoint", False):
         kwargs.update(ckpt_kwargs)
     images, pipeline_config = pipeline.run(pipeline_type=pipeline_type, **kwargs)
+    pipeline_config.setdefault("timings", {})["load_s"] = round(
+        load.elapsed, 3)
     if batch_capped:
         pipeline_config["batch_capped"] = batch_capped
     if degraded_preprocessors:

@@ -31,21 +31,13 @@ try:
 except ImportError:
     pass
 
-# Some environments import jax at interpreter startup (sitecustomize), which
-# freezes config before the env vars above can act — force via jax.config too.
+# The env vars above act only if they are set before jax is first imported;
+# say it through jax.config too, which also holds when something imported
+# jax earlier.
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # pre-0.4.38 jax has no such option; the XLA_FLAGS path above already
-    # provides the 8 virtual devices unless jax was imported before us —
-    # in which case fail loudly rather than run the mesh tests on 1 device
-    assert len(jax.devices()) == 8, (
-        "jax predates jax_num_cpu_devices and was imported before conftest "
-        "could set XLA_FLAGS; the 8-virtual-device test mesh is unavailable"
-    )
+jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
 
@@ -119,8 +111,9 @@ def _module_uses_torch(path: str) -> bool:
 # than the unit tests that happen to sort after "bench"
 # alphabetically). They still run exactly once, and still before the
 # torch group — a torch segfault must not eat them.
-_HEAVY_TAIL_MODULES = {"test_bench", "test_chaos_smoke", "test_dag_svd",
-                       "test_cascade", "test_deepfloyd", "test_depth"}
+_HEAVY_TAIL_MODULES = {"test_bench", "test_chaos_smoke", "test_chip_smoke",
+                       "test_dag_svd", "test_cascade", "test_deepfloyd",
+                       "test_depth"}
 
 
 def pytest_collection_modifyitems(config, items):

@@ -27,48 +27,52 @@ class FakeDevice:
         self.device_kind = kind
 
 
-def test_peak_tflops_prefix_match_and_unknown(monkeypatch):
-    monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
+def test_peak_tflops_prefix_match_and_unknown():
     assert costs.peak_tflops(FakeDevice("TPU v4")) == 275.0
     # generation suffixes ride the prefix: "TPU v5 lite" devices report
     # chip counts etc. after the kind
     assert costs.peak_tflops(FakeDevice("TPU v5 lite")) == 197.0
     assert costs.peak_tflops(FakeDevice("TPU v5p")) == 459.0
     assert costs.peak_tflops(FakeDevice("TPU v6 lite")) == 918.0
-    # an unknown platform reports None — MFU must read null, never a
-    # made-up ratio against the wrong denominator
+    # a platform with no peak (CPU) reports None — MFU must read null,
+    # never a made-up ratio against the wrong denominator
     assert costs.peak_tflops(FakeDevice("cpu")) is None
     assert costs.peak_tflops(FakeDevice("")) is None
     assert costs.peak_tflops(object()) is None
 
 
-def test_peak_tflops_env_override(monkeypatch):
+def test_peak_tflops_unknown_tpu_kind_is_an_error(monkeypatch):
+    # no default and no environment override: a TPU the table does not
+    # know must be added with its source, not guessed
     monkeypatch.setenv("BENCH_PEAK_TFLOPS", "123.5")
-    assert costs.peak_tflops(FakeDevice("cpu")) == 123.5
-    assert costs.peak_tflops(None) == 123.5
+    assert costs.peak_tflops(FakeDevice("cpu")) is None
+    with pytest.raises(ValueError, match="TPU v9"):
+        costs.peak_tflops(FakeDevice("TPU v9"))
+    unknown = FakeDevice("some-new-kind")
+    unknown.platform = "tpu"
+    with pytest.raises(ValueError, match="some-new-kind"):
+        costs.peak_tflops(unknown)
 
 
-def test_pass_cost_math_and_metrics(monkeypatch):
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "100")
+def test_pass_cost_math_and_metrics():
     flops_metric = telemetry.REGISTRY.get("swarm_pass_flops_total")
     before = flops_metric.value(model="m-test")
     figures = costs.pass_cost(
         model="m-test", pass_flops=2e12, denoise_s=4.0, chips=2,
-        device=FakeDevice("x"), geometry="tensor2")
+        device=FakeDevice("TPU v4"), geometry="tensor2")
     assert figures["pass_flops"] == 2_000_000_000_000
     assert figures["denoise_s"] == 4.0
-    # 2e12 flops / 4 s = 0.5 TFLOP/s achieved; 100 peak * 2 chips
+    # 2e12 flops / 4 s = 0.5 TFLOP/s achieved; 275 peak * 2 chips
     assert figures["tflops_per_s"] == 0.5
     assert figures["chips"] == 2
-    assert figures["peak_tflops_per_chip"] == 100.0
-    assert figures["mfu"] == 0.0025
+    assert figures["peak_tflops_per_chip"] == 275.0
+    assert figures["mfu"] == 0.0009
     assert flops_metric.value(model="m-test") == before + 2e12
     mfu_metric = telemetry.REGISTRY.get("swarm_pass_mfu")
-    assert mfu_metric.value(model="m-test", geometry="tensor2") == 0.0025
+    assert mfu_metric.value(model="m-test", geometry="tensor2") == 0.0009
 
 
-def test_pass_cost_degrades_without_span_or_peak(monkeypatch):
-    monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
+def test_pass_cost_degrades_without_span_or_peak():
     # a span that rounds to 0 on toy configs: no rate, no MFU, but the
     # FLOPs are still counted (pure work accounting)
     z = costs.pass_cost(model="m-z", pass_flops=1e9, denoise_s=0.0,

@@ -79,7 +79,7 @@ def test_reference_path_matches_flax():
 
 
 def test_kernel_matches_reference_path_exactly_shaped():
-    # dispatch-level agreement: the two implementations the env flag
+    # dispatch-level agreement: the two implementations the platform rule
     # switches between must agree on the same inputs
     x = _rand((2, 8, 8, 64), jnp.float32, 12)
     scale = _rand((64,), jnp.float32, 13)
@@ -110,15 +110,54 @@ def test_oversize_tile_falls_back(monkeypatch):
     assert out.shape == x.shape
 
 
-def test_disable_flag(monkeypatch):
+def test_dispatch_follows_platform_and_shape_only(monkeypatch):
+    """No toggle and no try-and-catch: off the TPU every call traces the
+    reference path, on it every admitted tile traces the kernel, and each
+    decision is counted where chip_smoke.py can read it."""
     import chiaswarm_tpu.ops.group_norm as gnmod
+    from chiaswarm_tpu.ops.platform import KERNEL_TRACES
 
-    monkeypatch.setenv("CHIASWARM_DISABLE_FUSED_GN", "1")
     calls = []
     monkeypatch.setattr(
         gnmod, "_fused_group_norm",
-        lambda *a, **k: calls.append(1) or a[0])
+        lambda x3, *a, **k: calls.append(1) or x3)
     x = _rand((1, 4, 4, 32), jnp.float32, 16)
-    out = group_norm(x, jnp.ones((32,)), jnp.zeros((32,)), groups=32,
-                     interpret=True)
+    args = (x, jnp.ones((32,)), jnp.zeros((32,)))
+
+    def counts():
+        return tuple(KERNEL_TRACES.value(op="group_norm", path=p)
+                     for p in ("reference", "fused"))
+
+    ref0, fused0 = counts()
+    out = group_norm(*args, groups=32)
     assert not calls and out.shape == x.shape
+    assert counts() == (ref0 + 1, fused0)
+
+    monkeypatch.setattr(gnmod, "trace_platform", lambda: "tpu")
+    group_norm(*args, groups=32)
+    assert calls == [1]
+    assert counts() == (ref0 + 1, fused0 + 1)
+    # ... but not params initialised on the host while the backend is TPU
+    monkeypatch.undo()
+    with jax.default_device(jax.devices("cpu")[0]):
+        assert gnmod.trace_platform() == "cpu"
+
+
+def test_kernel_splits_over_a_mesh_by_batch_row():
+    """Under a multi-chip mesh the kernel runs in shard_map (Mosaic calls
+    cannot be partitioned automatically): rows over `data`, whole on the
+    tensor axis. Same values as the unsplit kernel."""
+    from chiaswarm_tpu.ops.platform import mesh_scope
+    from chiaswarm_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(jax.devices(), tensor=4)  # data = 2
+    x = _rand((2, 8, 8, 64), jnp.float32, 17)
+    scale = _rand((64,), jnp.float32, 18)
+    bias = _rand((64,), jnp.float32, 19)
+    want = group_norm(x, scale, bias, groups=32, act="silu", interpret=True)
+    with mesh_scope(mesh):
+        got = jax.jit(lambda x: group_norm(
+            x, scale, bias, groups=32, act="silu", interpret=True))(x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-6)
+    assert got.sharding.spec[0] == "data"

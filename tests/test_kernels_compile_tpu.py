@@ -1,0 +1,102 @@
+"""Both Pallas kernels of the UNet, compiled for a described v5e chip at the
+published widths (no chip attached: the TPU compiler is installed here and
+refuses what the chip's would — block shapes the lowering cannot tile,
+casts Mosaic has no layout for, more fast memory than a kernel may use).
+Interpret mode checks none of that; every shape below was refused before
+the kernels were repaired for the chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from chiaswarm_tpu.ops.flash_attention import _flash_impl
+from chiaswarm_tpu.ops.group_norm import (
+    _fused_group_norm,
+    _vmem_budget,
+    fused_tile_bytes,
+)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"TPU topology cannot be described here: {e}")
+    # a described-topology compile is written to the persistent cache but
+    # cannot be read back without a chip (it warns and recompiles)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shape(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("b,sq,skv,h,d", [
+    pytest.param(2, 4096, 4096, 10, 64, id="sdxl-self-4096x10x64"),
+    pytest.param(2, 1024, 1024, 20, 64, id="sdxl-self-1024x20x64"),
+    pytest.param(2, 4096, 77, 10, 64, id="sdxl-cross-4096q-77kv"),
+    pytest.param(2, 9216, 9216, 5, 64, id="sd21-768-9216x5x64"),
+    pytest.param(1, 4608, 4608, 24, 128, id="flux-4608x24x128"),
+])
+def test_flash_attention_compiles_for_v5e(v5e, b, sq, skv, h, d):
+    q = _shape(v5e, (b, sq, h, d))
+    kv = _shape(v5e, (b, skv, h, d))
+    compiled = _flash_impl.lower(
+        q, kv, kv, scale=None, block_q=512, block_k=512, interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _largest_admitted_tile() -> tuple[int, int]:
+    """The [N, C] bf16 tile nearest the admission budget, over the UNet
+    levels' token counts and 128-lane channel counts."""
+    admitted = [
+        (fused_tile_bytes(n, c, 2), n, c)
+        for n in (64, 256, 1024, 4096)
+        for c in range(128, 4097, 128)
+        if fused_tile_bytes(n, c, 2) <= _vmem_budget()
+    ]
+    _, n, c = max(admitted)
+    return n, c
+
+
+@pytest.mark.parametrize("n,c", [
+    pytest.param(32 * 32, 1280, id="32x32x1280"),
+    pytest.param(32 * 32, 640, id="32x32x640"),
+    pytest.param(16 * 16, 1280, id="16x16x1280"),
+    pytest.param(*_largest_admitted_tile(), id="largest-admitted"),
+])
+def test_fused_group_norm_compiles_for_v5e(v5e, n, c):
+    vec = _shape(v5e, (c,), jnp.float32)
+    compiled = _fused_group_norm.lower(
+        _shape(v5e, (2, n, c)), vec, vec, groups=32, eps=1e-5, silu=True,
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_admission_rule_stays_inside_the_scoped_vmem_limit():
+    """What the dispatch rule admits must fit the compiler's own 16 MiB
+    scoped-VMEM default on a v5e with room to spare; 32x32x640 (SDXL's
+    first level-3 resnet norm) is in, 32x32x1280 is out."""
+    assert _vmem_budget() <= 8 * 1024 * 1024
+    n, c = _largest_admitted_tile()
+    assert fused_tile_bytes(n, c, 2) <= _vmem_budget()
+    assert fused_tile_bytes(32 * 32, 640, 2) <= _vmem_budget()
+    assert fused_tile_bytes(32 * 32, 1280, 2) > _vmem_budget()

@@ -11,6 +11,7 @@ import jax
 
 from chiaswarm_tpu.chips.device import ChipSet
 from chiaswarm_tpu.ops import attention as attention_ops
+from chiaswarm_tpu.ops.platform import mesh_scope
 from chiaswarm_tpu.pipelines.stable_diffusion import SDPipeline
 
 
@@ -33,12 +34,16 @@ def test_seq_parallel_sd_matches_replicated(monkeypatch):
     assert diff.max() <= 2, f"max pixel diff {diff.max()}"
 
 
-def test_scope_noop_without_seq_axis():
+def test_scope_noop_without_seq_axis(monkeypatch):
     # seq=1 mesh: scope must not reroute anything
     chipset = ChipSet(jax.devices())
     mesh = chipset.mesh()
-    with attention_ops.sequence_parallel_scope(mesh):
-        assert getattr(attention_ops._SEQ_SCOPE, "mesh", None) is None
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("SDAAS_RING_MIN_SEQ", "8")
+    q = jnp.zeros((1, 16, 2, 8))
+    with mesh_scope(mesh):
+        assert attention_ops._ring_route(q, q, q, 0.5) is None
 
 
 def test_ring_route_skips_cross_attention(monkeypatch):
@@ -46,7 +51,7 @@ def test_ring_route_skips_cross_attention(monkeypatch):
 
     monkeypatch.setenv("SDAAS_RING_MIN_SEQ", "8")
     chipset = ChipSet(jax.devices(), seq=2)
-    with attention_ops.sequence_parallel_scope(chipset.mesh()):
+    with mesh_scope(chipset.mesh()):
         q = jnp.zeros((1, 16, 2, 8))
         kv = jnp.zeros((1, 6, 2, 8))  # different KV length = cross
         assert attention_ops._ring_route(q, kv, kv, 0.5) is None

@@ -4,6 +4,9 @@ Parity targets: reference swarm/settings.py:19-43.
 """
 
 import json
+import os
+
+import pytest
 
 from chiaswarm_tpu.settings import (
     Settings,
@@ -170,56 +173,89 @@ def test_tpu_fields_roundtrip(sdaas_root):
     assert s.dtype == "float32"
 
 
-def test_compile_cache_knob_layering(sdaas_root, monkeypatch):
-    from chiaswarm_tpu.compile_cache import resolve_cache_dir
-
-    s = load_settings()
-    assert s.compile_cache_dir == "xla_cache"
-    # relative default resolves under $SDAAS_ROOT
-    assert resolve_cache_dir(s) == sdaas_root / "xla_cache"
-    # env override wins, absolute paths pass through untouched
-    monkeypatch.setenv("CHIASWARM_COMPILE_CACHE_DIR", "/somewhere/xla")
-    assert str(resolve_cache_dir(load_settings())) == "/somewhere/xla"
-    # empty / "0" disable at zero cost
-    for off in ("", "0", "off"):
-        monkeypatch.setenv("CHIASWARM_COMPILE_CACHE_DIR", off)
-        assert resolve_cache_dir(load_settings()) is None
-
-
-def test_compile_cache_legacy_settings_key_still_loads(sdaas_root):
-    get_settings_full_path().write_text(
-        json.dumps({"compilation_cache_dir": "/old/spelling"}))
-    assert load_settings().compile_cache_dir == "/old/spelling"
-
-
-def test_enable_compile_cache_set_disabled_unwritable(
-        sdaas_root, monkeypatch, caplog):
-    """The three contract cases: a writable dir activates (and is
-    created), "" disables silently, an unwritable dir degrades to a
-    warning + disabled — never an exception."""
-    import logging
-
-    from chiaswarm_tpu.compile_cache import enable_compile_cache
-
+@pytest.fixture()
+def jax_cache_config():
+    """Snapshot and restore jax's cache directory option: enable_* may set
+    it, and a later test must not keep writing where this one pointed."""
     import jax
 
-    target = sdaas_root / "xla_cache"
-    try:
-        assert enable_compile_cache(load_settings()) == target
-        assert target.is_dir()
-    finally:
-        # tmp_path dies with the test; jax must not keep spooling there
-        jax.config.update("jax_compilation_cache_dir", None)
+    was = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", was)
 
-    monkeypatch.setenv("CHIASWARM_COMPILE_CACHE_DIR", "")
-    assert enable_compile_cache(load_settings()) is None
 
-    blocker = sdaas_root / "blocked"
+def test_compile_cache_env_places_it_and_code_sets_nothing(
+        tmp_path, monkeypatch, jax_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is there, placed from
+    outside — jax reads the variable itself (in a fresh process) and the
+    program calls jax.config.update("jax_compilation_cache_dir", ...)
+    nowhere."""
+    import subprocess
+    import sys
+
+    from chiaswarm_tpu import compile_cache
+
+    outside = tmp_path / "placed-from-outside"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(outside))
+    assert compile_cache.cache_dir() == outside
+
+    updates = []
+    real_update = jax_cache_config.update
+    monkeypatch.setattr(
+        jax_cache_config, "update",
+        lambda name, value: (updates.append(name), real_update(name, value)))
+    assert compile_cache.enable_compile_cache() == outside
+    assert outside.is_dir()
+    assert "jax_compilation_cache_dir" not in updates
+
+    # what a process started with the variable sees: jax reports the
+    # environment's directory without any code having set it
+    code = ("from chiaswarm_tpu.compile_cache import enable_compile_cache;"
+            "import jax; enable_compile_cache();"
+            "print(jax.config.jax_compilation_cache_dir)")
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=str(compile_cache.DEFAULT_DIR.parent),
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-500:]
+    assert out.stdout.strip().splitlines()[-1] == str(outside)
+
+
+@pytest.mark.parametrize("root", ["sdaas-a", "somewhere/else/sdaas-b"])
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
+        tmp_path, monkeypatch, jax_cache_config, root):
+    """Variable unset: one fixed path inside the checkout whatever
+    SDAAS_ROOT is — the directory is part of the cache's key, so a cache
+    that moves with every drive's root never hits."""
+    import pathlib
+
+    import chiaswarm_tpu
+    from chiaswarm_tpu import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("SDAAS_ROOT", str(tmp_path / root))
+    checkout = pathlib.Path(chiaswarm_tpu.__file__).resolve().parent.parent
+    assert compile_cache.cache_dir() == checkout / ".jax_cache"
+    assert compile_cache.enable_compile_cache() == checkout / ".jax_cache"
+    assert jax_cache_config.jax_compilation_cache_dir == str(
+        checkout / ".jax_cache")
+    assert not hasattr(load_settings(), "compile_cache_dir")
+
+
+def test_compile_cache_unwritable_directory_is_an_error(
+        tmp_path, monkeypatch, jax_cache_config):
+    """No silent cold cache: a directory that cannot be created or written
+    raises (the worker stops at start-up, chip_smoke.py fails)."""
+    from chiaswarm_tpu.compile_cache import enable_compile_cache
+
+    blocker = tmp_path / "blocked"
     blocker.write_text("a file where the cache dir should go")
-    monkeypatch.setenv("CHIASWARM_COMPILE_CACHE_DIR", str(blocker))
-    with caplog.at_level(logging.WARNING, logger="chiaswarm_tpu.compile_cache"):
-        assert enable_compile_cache(load_settings()) is None
-    assert any("not writable" in r.message for r in caplog.records)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(blocker))
+    with pytest.raises(OSError):
+        enable_compile_cache()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(blocker / "under"))
+    with pytest.raises(OSError):
+        enable_compile_cache()
 
 
 def test_observability_knobs(sdaas_root, monkeypatch):
@@ -257,7 +293,7 @@ def test_tracing_and_profiler_knobs(sdaas_root, monkeypatch):
 EXPECTED_FIELDS = (
     "log_level", "log_filename", "sdaas_token", "sdaas_uri", "worker_name",
     "lora_root_dir", "chips_per_job", "tensor_parallelism",
-    "sequence_parallelism", "ring_min_seq", "compile_cache_dir",
+    "sequence_parallelism", "ring_min_seq",
     "model_root_dir", "dtype", "depth_model", "pose_model",
     "safety_checker_model", "profiler_port", "profiler_capture",
     "flux_streaming", "flux_stream_int8", "batch_linger_ms", "max_coalesce",
