@@ -1,0 +1,5 @@
+"""`device_idle_share` under the name that moves the latency metric."""
+
+from benchmark.harness import load_reader
+
+read = load_reader("layer_metrics", "device_idle_share")

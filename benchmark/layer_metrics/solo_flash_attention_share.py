@@ -1,0 +1,6 @@
+"""`flash_attention_device_share` under the name that moves the latency
+metric."""
+
+from benchmark.harness import load_reader
+
+read = load_reader("layer_metrics", "flash_attention_device_share")

@@ -1,0 +1,84 @@
+"""`BENCHMARK.json` against the parts of its contract that can be checked
+here, and the harness against its own rule: driven by data."""
+
+import json
+import re
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parents[2]
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[0-9A-Za-z_][0-9A-Za-z_.\-]{0,63}$")
+UNIT = re.compile(r"^[0-9A-Za-z_/%.\-]{1,16}$")
+
+
+def metrics():
+    return BENCH["end_to_end"] + BENCH["per_layer"]
+
+
+def test_keys_names_and_units():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    names = [m["name"] for m in metrics()]
+    assert len(names) == len(set(names))
+    for entry in metrics() + BENCH["configs"] + BENCH["workloads"]:
+        assert NAME.match(entry["name"]), entry["name"]
+    for metric in metrics():
+        assert UNIT.match(metric["unit"]) and metric["better"] in (
+            "lower", "higher")
+        assert metric["source"] in ("device_trace", "program_span",
+                                    "program_counter", "host_clock")
+    for metric in BENCH["end_to_end"]:
+        assert metric["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= metric["bound"] <= 0.1
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert len(json.dumps(BENCH)) < 64 * 1024
+
+
+def test_every_entry_has_its_file():
+    root = REPO / BENCH["paths"][0]
+    for config in BENCH["configs"]:
+        body = json.loads((REPO / config["file"]).read_text())
+        assert body["source"] == config["source"]
+        assert body["reduced"] == config["reduced"]
+        assert (root / "families" / f"{body['family']}.py").is_file()
+    for cell in BENCH["workloads"]:
+        traffic = json.loads(
+            (root / "traffic" / f"{cell['traffic']}.json").read_text())
+        assert (root / "generators" / f"{traffic['generator']}.py").is_file()
+        assert cell["chips"] in (1, 4) and len(cell["why"]) <= 200
+    for metric in BENCH["end_to_end"]:
+        assert (root / "end_to_end" / f"{metric['name']}.py").is_file()
+    for metric in BENCH["per_layer"]:
+        assert (root / "layer_metrics" / f"{metric['name']}.py").is_file()
+
+
+def test_every_cell_reports_enough():
+    from benchmark import harness
+
+    for cell in BENCH["workloads"]:
+        spec = harness.load_cell(cell["name"])
+        names = {m["name"] for m in spec["end_to_end"]}
+        assert "setup_s" in names and len(names) >= 2
+        assert spec["per_layer"], cell["name"]
+        for metric in spec["per_layer"]:
+            assert metric["moves"] in names
+
+
+def test_harness_names_no_cell_model_or_metric():
+    words = {c["name"] for c in BENCH["configs"]}
+    words |= {w["name"] for w in BENCH["workloads"]}
+    words |= {w["traffic"] for w in BENCH["workloads"]}
+    words |= {m["name"] for m in metrics()} - {"setup_s"}
+    for config in BENCH["configs"]:
+        words.add(json.loads(
+            (REPO / config["file"]).read_text())["job"]["model_name"])
+    root = REPO / BENCH["paths"][0]
+    for path in ("harness.py", "run.py", "rehearse.py", "measure.py",
+                 "breakdown.py", "generators/closed_loop.py",
+                 "trace/reduce.py"):
+        code = re.sub(r'""".*?"""', "", (root / path).read_text(), flags=re.S)
+        code = "\n".join(line.split("#")[0] for line in code.splitlines())
+        for word in words:
+            assert not re.search(
+                rf"(?<![\w.\-]){re.escape(word)}(?![\w.\-])", code), (
+                path, word)
