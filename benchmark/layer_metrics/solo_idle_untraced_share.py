@@ -1,0 +1,5 @@
+"""`idle_untraced_share` under the name that moves the latency metric."""
+
+from benchmark.harness import load_reader
+
+read = load_reader("layer_metrics", "idle_untraced_share")

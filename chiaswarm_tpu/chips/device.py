@@ -31,6 +31,17 @@ _EXECUTE_SECONDS = telemetry.histogram(
     ("kind",),
 )
 
+# seconds a slice sat free between two passes: at each acquisition of the
+# busy lock, the time since its last release (nothing before the first
+# pass). With swarm_slice_execute_seconds it is the slice's duty cycle
+# without a profiler, and it measures the poll quantisation directly.
+_FREE_SECONDS = telemetry.counter(
+    "swarm_slice_free_seconds_total",
+    "Seconds the slice's busy lock was free between one pass's release "
+    "and the next pass's acquisition",
+    ("slice",),
+)
+
 # the mesh view the slice's LAST pass ran under, one series per axis
 # (ISSUE 12): data = coalescing rows / CFG pair, tensor = Megatron-style
 # kernel sharding, seq = ring-attention blocks. A slice serving batch
@@ -163,6 +174,8 @@ class ChipSet:
         self.tensor = tensor
         self.seq = seq
         self._mutex = threading.Lock()
+        # when the last pass released the busy lock (monotonic)
+        self._released_at: float | None = None
         # geometry of the most recent pass (healthz / swarm_top column);
         # starts at the construction-time default
         self.last_geometry: tuple[int, int, int] = (
@@ -302,6 +315,18 @@ class ChipSet:
             seq=self.seq if seq is None else seq,
         )
 
+    def _acquire_for_pass(self) -> None:
+        if not self._mutex.acquire(blocking=False):
+            logger.error("ChipSet %s is busy but got invoked.", self.identifier())
+            raise Exception("busy")
+        if self._released_at is not None:
+            _FREE_SECONDS.inc(time.monotonic() - self._released_at,
+                              slice=str(self.slice_id))
+
+    def _release_after_pass(self) -> None:
+        self._released_at = time.monotonic()
+        self._mutex.release()
+
     def __call__(self, func, **kwargs):
         """Run one job on this slice under the busy lock.
 
@@ -311,9 +336,7 @@ class ChipSet:
         based `jax.random.key` (deterministic across chip counts) and the
         callback also receives this ChipSet for mesh placement.
         """
-        if not self._mutex.acquire(blocking=False):
-            logger.error("ChipSet %s is busy but got invoked.", self.identifier())
-            raise Exception("busy")
+        self._acquire_for_pass()
         try:
             # fault-injection point: a hung compile/denoise holds the busy
             # lock exactly like the real failure would (faults.py)
@@ -326,18 +349,20 @@ class ChipSet:
             kwargs["rng"] = jax.random.key(seed)
             kwargs["chipset"] = self
 
-            started = time.perf_counter()
-            artifacts, pipeline_config = func(self.identifier(), model_name, **kwargs)
-            elapsed = time.perf_counter() - started
-            _EXECUTE_SECONDS.observe(elapsed, kind="solo")
+            # span "pass": the slice held, parent of every span the
+            # callback stamps on this thread
+            with telemetry.Span("pass") as held:
+                artifacts, pipeline_config = func(
+                    self.identifier(), model_name, **kwargs)
+            _EXECUTE_SECONDS.observe(held.elapsed, kind="solo")
             pipeline_config["seed"] = seed
             # per-job timing breadcrumb (reference has none; SURVEY §5 asks for it)
             pipeline_config.setdefault("timings", {})["job_s"] = round(
-                elapsed, 3
+                held.elapsed, 3
             )
             return artifacts, pipeline_config
         finally:
-            self._mutex.release()
+            self._release_after_pass()
 
     def run_batched(self, func, requests: list[dict]):
         """Run a coalesced group of jobs on this slice under the busy lock.
@@ -352,9 +377,7 @@ class ChipSet:
         `func(identifier, requests)` must return one (artifacts,
         pipeline_config) pair per request, in order.
         """
-        if not self._mutex.acquire(blocking=False):
-            logger.error("ChipSet %s is busy but got invoked.", self.identifier())
-            raise Exception("busy")
+        self._acquire_for_pass()
         try:
             # fault-injection points: hang (watchdog path) and a coalesced
             # OOM raised before any request kwarg is mutated, so the
@@ -371,16 +394,15 @@ class ChipSet:
                 kw["rng"] = jax.random.key(seed)
                 kw["chipset"] = self
 
-            started = time.perf_counter()
-            results = func(self.identifier(), requests)
+            with telemetry.Span("pass") as held:
+                results = func(self.identifier(), requests)
             if len(results) != len(requests):
                 raise RuntimeError(
                     f"batched callback returned {len(results)} envelopes "
                     f"for {len(requests)} jobs"
                 )
-            _EXECUTE_SECONDS.observe(
-                time.perf_counter() - started, kind="batched")
-            elapsed = round(time.perf_counter() - started, 3)
+            _EXECUTE_SECONDS.observe(held.elapsed, kind="batched")
+            elapsed = round(held.elapsed, 3)
             for (artifacts, pipeline_config), seed in zip(results, seeds):
                 pipeline_config["seed"] = seed
                 timings = pipeline_config.setdefault("timings", {})
@@ -388,4 +410,4 @@ class ChipSet:
                 timings["job_s"] = elapsed
             return results
         finally:
-            self._mutex.release()
+            self._release_after_pass()

@@ -7,7 +7,8 @@ leases.py, redeliver/park in the reaper path, settle in app.py), the
 journal persists the timeline with every WAL event so it survives crash
 recovery, compaction, and standby promotion, and the worker's
 ``trace_job`` stage spans ride back inside the result envelope's
-``pipeline_config.timings`` (with the wire trace context echoed under
+``pipeline_config.spans`` (an older worker's durations in
+``pipeline_config.timings``; the wire trace context echoed under
 ``pipeline_config.trace``). This module is the read side: it merges
 those sources into the one answer nobody could give before —
 "where did job X spend its 40 seconds?" — served at
@@ -90,29 +91,97 @@ _GAP_LABELS = {
     ("resume_offer", "park"): "lease_lost",
 }
 
+# a span's end is its wall-clock start plus a perf_counter duration: a
+# child may appear to overhang its parent's end by this much and still
+# be inside it (starts are stamps of one clock and need no slack)
+_SPAN_SLACK_S = 0.0001
+
+
+def _envelope_config(result: dict | None) -> dict:
+    if isinstance(result, dict) and isinstance(
+            result.get("pipeline_config"), dict):
+        return result["pipeline_config"]
+    return {}
+
+
+def _envelope_spans(cfg: dict) -> list[dict]:
+    """The well-formed entries of ``pipeline_config.spans`` as
+    {stage, thread, start_wall, seconds}, in start order."""
+    spans = []
+    for span in cfg.get("spans") or ():
+        try:
+            spans.append({
+                "stage": str(span["name"]),
+                "thread": str(span.get("thread", "")),
+                "start_wall": float(span["start_wall"]),
+                "seconds": max(float(span["seconds"]), 0.0),
+            })
+        except (TypeError, KeyError, ValueError, AttributeError):
+            continue
+    return sorted(spans, key=lambda span: span["start_wall"])
+
+
+def _inside(child: dict, parent: dict) -> bool:
+    return (child is not parent
+            and child["thread"] == parent["thread"]
+            and child["start_wall"] >= parent["start_wall"]
+            and child["start_wall"] + child["seconds"]
+            <= parent["start_wall"] + parent["seconds"] + _SPAN_SLACK_S
+            # of two spans with one interval, the earlier entry is inside
+            and (child["seconds"] < parent["seconds"]
+                 or child["start_wall"] > parent["start_wall"]))
+
+
+def _union_seconds(spans: list[dict]) -> float:
+    total, reach = 0.0, float("-inf")
+    for span in sorted(spans, key=lambda span: span["start_wall"]):
+        start, end = span["start_wall"], span["start_wall"] + span["seconds"]
+        total += max(end - max(start, reach), 0.0)
+        reach = max(reach, end)
+    return total
+
+
 def worker_stages(result: dict | None) -> list[dict]:
-    """The worker's stage spans from a settled envelope, in the order
-    the worker recorded them: ``pipeline_config.timings``'s ``*_s``
-    entries (insertion order is stage order — JSON preserves it)."""
-    if not isinstance(result, dict):
-        return []
-    cfg = result.get("pipeline_config")
-    if not isinstance(cfg, dict):
-        return []
+    """The worker's stages from a settled envelope.
+
+    From ``pipeline_config.spans`` (wall-stamped, thread-aware) when the
+    worker sent them: the top level of what the job waited for, in start
+    order — ``queue_wait`` and the children of ``pass`` (the slice held;
+    it is the parent, never a stage), each with its ``start_wall``; a
+    span inside another on the same thread (``safety`` inside ``decode``)
+    is that stage's detail and would count its time twice.
+
+    From ``pipeline_config.timings``'s ``*_s`` entries for a worker that
+    sends no spans (insertion order is stage order — JSON preserves it),
+    ``job_s`` left out: it is the whole pass, not a stage of it."""
+    cfg = _envelope_config(result)
+    spans = [span for span in _envelope_spans(cfg) if span["stage"] != "pass"]
+    if spans:
+        return [span for span in spans
+                if not any(_inside(span, other) for other in spans)]
     timings = cfg.get("timings")
     if not isinstance(timings, dict):
         return []
     stages = []
-    # every *_s timing is a stage — queue_wait_s included: the worker-
-    # side handoff wait is a real slice of the execution window
+    # every other *_s timing is a stage — queue_wait_s included: the
+    # worker-side handoff wait is a real slice of the execution window
     for key, value in timings.items():
-        if not isinstance(key, str) or not key.endswith("_s"):
+        if not isinstance(key, str) or not key.endswith("_s") \
+                or key == "job_s":
             continue
         try:
             stages.append({"stage": key[:-2], "seconds": float(value)})
         except (TypeError, ValueError):
             continue
     return stages
+
+
+def worker_total_seconds(stages: list[dict]) -> float:
+    """Seconds the stages cover: the union of their intervals where they
+    carry wall stamps (two threads may overlap), else their sum."""
+    if stages and all("start_wall" in stage for stage in stages):
+        return _union_seconds(stages)
+    return sum(stage["seconds"] for stage in stages)
 
 
 def wire_trace_context(record, gang: dict | None = None) -> dict:
@@ -159,11 +228,8 @@ def wire_trace_context(record, gang: dict | None = None) -> dict:
 
 def envelope_trace(result: dict | None) -> dict:
     """The worker-echoed trace context from a settled envelope."""
-    if isinstance(result, dict):
-        cfg = result.get("pipeline_config")
-        if isinstance(cfg, dict) and isinstance(cfg.get("trace"), dict):
-            return cfg["trace"]
-    return {}
+    trace = _envelope_config(result).get("trace")
+    return trace if isinstance(trace, dict) else {}
 
 
 def build_trace(record, now_wall: float) -> dict[str, Any]:
@@ -181,7 +247,7 @@ def build_trace(record, now_wall: float) -> dict[str, Any]:
         event["t_s"] = round(float(event.get("wall", t0)) - t0, 3)
 
     stages = worker_stages(record.result)
-    worker_total = round(sum(s["seconds"] for s in stages), 3)
+    worker_total = round(worker_total_seconds(stages), 3)
     echoed = envelope_trace(record.result)
 
     gaps: list[dict] = []
@@ -196,8 +262,8 @@ def build_trace(record, now_wall: float) -> dict[str, Any]:
         }
         if gap["attribution"] == "executing" and stages:
             # the worker's own spans carve the execution window up;
-            # the remainder is wire + spool + upload overhead, reported
-            # rather than absorbed
+            # the remainder (wire, spool, upload, and any hole between
+            # the spans) is reported rather than absorbed
             gap["worker_stages"] = stages
             gap["worker_total_s"] = worker_total
             gap["unattributed_s"] = round(max(seconds - worker_total, 0.0), 3)

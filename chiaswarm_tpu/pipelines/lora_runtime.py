@@ -27,6 +27,7 @@ dict traces to the identical program (pinned bitwise by tests).
 from __future__ import annotations
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -182,17 +183,20 @@ def _path_interceptor(a_map: dict, b_map: dict, slots, gains, prefix: str):
             return next_fun(*args, **kwargs)
         y = next_fun(*args, **kwargs)
         stack_b = b_map[key]
-        a = jnp.take(stack_a, slots, axis=0)  # [rows, r, in]
-        b = jnp.take(stack_b, slots, axis=0)  # [rows, out, r]
-        if x.ndim == 2:
-            low = jnp.einsum("bi,bri->br", x, a)
-            delta = jnp.einsum("br,bor->bo", low, b)
-            delta = delta * gains[:, None]
-        else:
-            low = jnp.einsum("bsi,bri->bsr", x, a)
-            delta = jnp.einsum("bsr,bor->bso", low, b)
-            delta = delta * gains[:, None, None]
-        return y + delta.astype(y.dtype)
+        # the delta is no flax module: it gets its name in a device
+        # trace here, under the path of the Dense it corrects
+        with jax.named_scope("lora_row_delta"):
+            a = jnp.take(stack_a, slots, axis=0)  # [rows, r, in]
+            b = jnp.take(stack_b, slots, axis=0)  # [rows, out, r]
+            if x.ndim == 2:
+                low = jnp.einsum("bi,bri->br", x, a)
+                delta = jnp.einsum("br,bor->bo", low, b)
+                delta = delta * gains[:, None]
+            else:
+                low = jnp.einsum("bsi,bri->bsr", x, a)
+                delta = jnp.einsum("bsr,bor->bso", low, b)
+                delta = delta * gains[:, None, None]
+            return y + delta.astype(y.dtype)
 
     return interceptor
 

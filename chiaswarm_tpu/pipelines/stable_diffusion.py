@@ -1409,19 +1409,24 @@ class SDPipeline:
                         out = unet_apply(
                             *unet_in, added_cond=added, **residual_kw
                         ).astype(jnp.float32)
-                    if mode == "pix2pix":
-                        # dual guidance (InstructPix2Pix eq. 3): text guidance
-                        # pulls away from image-only, image guidance away from
-                        # the fully-unconditional row
-                        out_u, out_i, out_c = jnp.split(out, 3, axis=0)
-                        out = (
-                            out_u
-                            + guidance_scale * (out_c - out_i)
-                            + image_guidance * (out_i - out_u)
-                        )
-                    else:
-                        out_u, out_c = jnp.split(out, 2, axis=0)
-                        out = out_u + guidance_scale * (out_c - out_u)
+                    # named scopes: flax scopes every module's ops by its
+                    # path; the parts of the program that are no module
+                    # get a name here, so a device trace can say what a
+                    # `fusion` belongs to
+                    with jax.named_scope("cfg_combine"):
+                        if mode == "pix2pix":
+                            # dual guidance (InstructPix2Pix eq. 3): text
+                            # guidance pulls away from image-only, image
+                            # guidance away from the fully-unconditional row
+                            out_u, out_i, out_c = jnp.split(out, 3, axis=0)
+                            out = (
+                                out_u
+                                + guidance_scale * (out_c - out_i)
+                                + image_guidance * (out_i - out_u)
+                            )
+                        else:
+                            out_u, out_c = jnp.split(out, 2, axis=0)
+                            out = out_u + guidance_scale * (out_c - out_u)
 
                     if mode in ("batched", "batched_i2i"):
                         # per-row ancestral noise from per-job keys (same
@@ -1433,9 +1438,10 @@ class SDPipeline:
                         noise = draw_normal(
                             jax.random.fold_in(rng, i), latents.shape
                         )
-                    state, latents = scheduler.step(
-                        schedule, state, i, latents, out, noise
-                    )
+                    with jax.named_scope("scheduler_step"):
+                        state, latents = scheduler.step(
+                            schedule, state, i, latents, out, noise
+                        )
                     if mode == "inpaint":
                         # keep the unmasked region on the original image's
                         # noise trajectory (4-channel inpainting)
@@ -1458,6 +1464,7 @@ class SDPipeline:
 
             return run_steps
 
+        @jax.named_scope("vae_decode")
         def decode(params, latents):
             latents = latents.astype(self.dtype)
             if big_decode:

@@ -20,6 +20,8 @@ import threading
 from collections import OrderedDict
 from typing import Callable
 
+from .telemetry import Span
+
 logger = logging.getLogger(__name__)
 
 # wire-name -> family; the table covers every pipeline_type string the
@@ -166,7 +168,10 @@ def get_pipeline(model_name: str, pipeline_type: str, chipset=None, **variant):
                 _note_resident(model_name, slice_id)
             return pipeline
         logger.info("building pipeline %s/%s", model_name, family)
-        pipeline = factory(model_name, chipset, **variant)
+        # span "registry_build": shapes, weights and placement of one
+        # pipeline, on a miss only; fires at start-up too, outside any job
+        with Span("registry_build"):
+            pipeline = factory(model_name, chipset, **variant)
         if chipset is not None:
             # the load event feeding the placement layer: this model is
             # now warm on this slice, so the dispatch board routes the

@@ -11,10 +11,12 @@ exactly the per-stage latency breakdown this module provides. Design:
   deliberately NOT a prometheus_client dependency: the worker image must
   not grow a runtime dep for what is ~200 lines of dict arithmetic;
 - a `Span` / `trace_job` context-manager API that stamps per-stage wall
-  time into BOTH the process-wide `swarm_job_stage_seconds{stage=...}`
-  histogram and the per-job `timings` dict that rides the result envelope
-  (`pipeline_config`), so the hive and the local scrape see the same
-  numbers from the same measurement;
+  time into the process-wide `swarm_job_stage_seconds{stage=...}`
+  histogram, the per-job `timings` dict AND the pass's wall-stamped,
+  thread-aware `spans` list, both of which ride the result envelope
+  (`pipeline_config`), and shows each span in a profiler capture as
+  `swarm/<stage>` — so the hive, the local scrape and the device trace
+  see the same numbers from the same measurement;
 - an aiohttp app (`GET /metrics`, `GET /healthz`) the worker starts next
   to its jax.profiler server. `Settings.metrics_port` / the
   `CHIASWARM_METRICS_PORT` env knob picks the port; 0 disables the server
@@ -28,6 +30,7 @@ from __future__ import annotations
 
 import bisect
 import contextvars
+import sys
 import threading
 import time
 
@@ -308,76 +311,133 @@ def histogram(name, help="", labelnames=(), buckets=DEFAULT_BUCKETS) -> Histogra
 
 # --- spans -----------------------------------------------------------------
 
+# the name a span carries in the profiler's trace: "swarm/<stage>"
+ANNOTATION_PREFIX = "swarm/"
 
-def observe_stage(stage: str, seconds: float, registry: Registry | None = None
-                  ) -> None:
-    (registry or REGISTRY).histogram(
-        STAGE_METRIC, _STAGE_HELP, ("stage",)
-    ).observe(seconds, stage=stage)
+# the collector of the pass the current thread is working on (or any
+# thread running under a copy of its context); set by trace_job
+_current_trace: contextvars.ContextVar["JobTrace | None"] = (
+    contextvars.ContextVar("chiaswarm_job_trace", default=None))
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, once found
+
+
+def _annotation(stage: str):
+    """A `jax.profiler.TraceAnnotation` for the stage, so the span shows
+    on its thread's line of a profiler capture beside the device's ops.
+    jax is never imported here: a process that has not loaded it (the
+    hive) gets no annotation; one that has resolves the class once.
+    With no profiler session an annotation costs well under a
+    microsecond (PERF.md)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+        _TraceAnnotation = profiler.TraceAnnotation
+    return _TraceAnnotation(ANNOTATION_PREFIX + stage)
 
 
 class Span:
-    """Times one stage of a job; on exit the elapsed wall clock lands in
-    the stage histogram AND (when a timings dict is given) in
-    `timings[key or f"{stage}_s"]` rounded the way the existing envelope
-    timings are. Records on exception too — a failed denoise still spent
-    the time."""
+    """Times one stage — the one way a stage is stamped. As a context
+    manager it takes the wall clock and `perf_counter` on entry and shows
+    in the profiler's trace as `swarm/<stage>`; `record()` (which exit
+    calls, and which a stage measured elsewhere calls directly) puts the
+    seconds in the stage histogram, in `timings[key or f"{stage}_s"]`
+    when a timings dict is given (rounded the way the envelope's timings
+    are), and `{name, thread, start_wall, seconds}` (unrounded) in the
+    current `JobTrace`'s spans — or in `spans`, for a stage stamped
+    after the pass's trace has closed. Records on exception too: a
+    failed denoise still spent the time.
+
+    `thread` is "slice" on the thread that opened the JobTrace (the
+    executor thread holding the chip), else the thread's name, unless
+    the caller names it."""
 
     def __init__(self, stage: str, timings: dict | None = None, *,
-                 key: str | None = None, registry: Registry | None = None):
+                 key: str | None = None, registry: Registry | None = None,
+                 thread: str | None = None, spans: list | None = None):
         self.stage = stage
         self.timings = timings
         self.key = key or f"{stage}_s"
         self.registry = registry
+        self.thread = thread
+        self.spans = spans
+        self.start_wall: float | None = None
         self.elapsed: float | None = None
 
     def __enter__(self) -> "Span":
+        self._annotation = _annotation(self.stage)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self.start_wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.elapsed = time.perf_counter() - self._t0
-        observe_stage(self.stage, self.elapsed, self.registry)
+        seconds = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self.record(self.start_wall, seconds)
+
+    def record(self, start_wall: float, seconds: float) -> None:
+        self.start_wall, self.elapsed = start_wall, seconds
+        (self.registry or REGISTRY).histogram(
+            STAGE_METRIC, _STAGE_HELP, ("stage",)
+        ).observe(seconds, stage=self.stage)
         if self.timings is not None:
-            self.timings[self.key] = round(self.elapsed, 3)
+            self.timings[self.key] = round(seconds, 3)
+        spans, thread = self.spans, self.thread
+        if spans is None:
+            trace = _current_trace.get()
+            if trace is None:
+                return
+            spans, thread = trace.spans, thread or trace.thread_label()
+        spans.append({
+            "name": self.stage,
+            "thread": thread or threading.current_thread().name,
+            "start_wall": start_wall,
+            "seconds": seconds,
+        })
 
 
 class JobTrace:
-    """Per-job trace: a context manager that pins `current_job_id` for log
-    correlation and hands out `stage()` spans all writing into one shared
-    timings dict (the one that ends up in the job's pipeline_config)."""
+    """The collector of one pass: a context manager that pins
+    `current_job_id` for log correlation and gathers every `Span` that
+    ends while it is current — on the thread that opened it, or on one
+    running under a copy of its context — into `spans`, which the worker
+    copies into the envelopes of the pass (`pipeline_config.spans`)."""
 
-    def __init__(self, job_id: str | None = None, timings: dict | None = None,
-                 registry: Registry | None = None):
+    def __init__(self, job_id: str | None = None):
         self.job_id = job_id
-        self.timings = timings if timings is not None else {}
-        self.registry = registry
-        self._token = None
+        self.spans: list[dict] = []
+        self._thread: int | None = None
+        self._tokens = None
 
     def __enter__(self) -> "JobTrace":
-        if self.job_id is not None:
-            self._token = current_job_id.set(str(self.job_id))
+        self._thread = threading.get_ident()
+        self._tokens = (
+            current_job_id.set(str(self.job_id))
+            if self.job_id is not None else None,
+            _current_trace.set(self),
+        )
         return self
 
     def __exit__(self, *exc) -> None:
-        if self._token is not None:
-            current_job_id.reset(self._token)
-            self._token = None
+        id_token, trace_token = self._tokens
+        _current_trace.reset(trace_token)
+        if id_token is not None:
+            current_job_id.reset(id_token)
+        self._tokens = None
 
-    def stage(self, stage: str, key: str | None = None) -> Span:
-        return Span(stage, self.timings, key=key, registry=self.registry)
-
-    def record(self, stage: str, seconds: float, key: str | None = None
-               ) -> None:
-        """A stage measured elsewhere (e.g. queue wait stamped by the
-        scheduler) joins the same histogram + timings dict."""
-        observe_stage(stage, seconds, self.registry)
-        self.timings[key or f"{stage}_s"] = round(seconds, 3)
+    def thread_label(self) -> str:
+        if threading.get_ident() == self._thread:
+            return "slice"
+        return threading.current_thread().name
 
 
-def trace_job(job_id: str | None = None, timings: dict | None = None,
-              registry: Registry | None = None) -> JobTrace:
-    return JobTrace(job_id, timings, registry)
+def trace_job(job_id: str | None = None) -> JobTrace:
+    return JobTrace(job_id)
 
 
 # --- HTTP exposition -------------------------------------------------------

@@ -70,7 +70,7 @@ from .post_processors.output_processor import (
     fatal_exception_response,
 )
 from .settings import Settings, load_settings, resolve_path
-from .telemetry import observe_stage, trace_job
+from .telemetry import Span, trace_job
 
 logger = logging.getLogger(__name__)
 
@@ -926,7 +926,7 @@ class Worker:
             trace = job.pop("trace", None)
             job.pop("resume", None)
             stage_name = str((job.get("stage") or {}).get("name", ""))
-            queue_wait = ({job.get("id"): picked_up - enqueued}
+            queue_wait = ({job.get("id"): _waited(enqueued, picked_up)}
                           if enqueued is not None else {})
             traces = ({job.get("id"): trace}
                       if isinstance(trace, dict) else {})
@@ -1004,7 +1004,7 @@ class Worker:
             for job in batch:
                 enqueued = job.pop("_telemetry_enqueued", None)
                 if enqueued is not None and "id" in job:
-                    queue_wait[job["id"]] = picked_up - enqueued
+                    queue_wait[job["id"]] = _waited(enqueued, picked_up)
                 # hive trace context comes OFF the job before formatting
                 # and rides the envelope back (pipeline_config.trace) so
                 # the hive attaches this worker's stage spans to the
@@ -1113,10 +1113,13 @@ class Worker:
             # plus our receipt instant) back through the envelope
             cfg["trace"] = trace
         timings = cfg.setdefault("timings", {})
-        wait = queue_wait.get(result.get("id"))
-        if wait is not None:
-            observe_stage("queue_wait", wait)
-            timings["queue_wait_s"] = round(wait, 3)
+        waited = queue_wait.get(result.get("id"))
+        if waited is not None:
+            # span "queue_wait": poll reply -> slice pick-up, linger
+            # included; it ran on no thread, and the pass's trace closed
+            # with the pass, so it joins the envelope's spans directly
+            Span("queue_wait", timings, thread="wait",
+                 spans=cfg.setdefault("spans", [])).record(*waited)
         if result.get("fatal_error"):
             outcome = "fatal"
         elif "error" in cfg:
@@ -1546,8 +1549,11 @@ class Worker:
             f"on {chipset.descriptor()}"
         )
         try:
-            with trace_job(",".join(str(i) for i in ids)):
+            with trace_job(",".join(str(i) for i in ids)) as trace:
                 outs = chipset.run_batched(diffusion_batched_callback, requests)
+            for _, pipeline_config in outs:
+                # the pass was shared: so are its spans, as its timings
+                pipeline_config["spans"] = list(trace.spans)
             return [
                 None if pipeline_config.get("cancelled") else {
                     "id": job_id,
@@ -1601,9 +1607,11 @@ class Worker:
         print(f"Processing {job_id} on {chipset.descriptor()}")
 
         # trace_job pins the job id on this executor thread so every log
-        # line (and span) emitted during execution carries it (JSON logs)
+        # line emitted during execution carries it (JSON logs), and
+        # collects the pass's spans for the envelope
+        trace = trace_job(job_id)
         try:
-            with trace_job(job_id):
+            with trace:
                 artifacts, pipeline_config = chipset(worker_function, **kwargs)
         except JobCancelled:
             # aborted at a denoise chunk boundary: the hive revoked this
@@ -1624,6 +1632,8 @@ class Worker:
             else:
                 artifacts, pipeline_config = exception_message(e)
 
+        # a failed pass spent its time too: the error envelope says where
+        pipeline_config["spans"] = trace.spans
         return {
             "id": job_id,
             "artifacts": artifacts,
@@ -1646,8 +1656,14 @@ class Worker:
         # whoever holds the redelivered lease at arrival time
         result.setdefault("worker_name", self.settings.worker_name)
         entry = await asyncio.get_running_loop().run_in_executor(
-            None, self.outbox.spool, result)
+            None, self._spool, result)
         await self.result_queue.put(entry)
+
+    def _spool(self, result: dict) -> OutboxEntry:
+        # span "spool": the envelope's write (histogram and profiler
+        # only: the envelope it would ride in is what is being sealed)
+        with Span("spool"):
+            return self.outbox.spool(result)
 
     async def result_worker(self) -> None:
         while True:
@@ -1677,11 +1693,11 @@ class Worker:
         while True:
             err: Exception
             try:
-                t0 = time.perf_counter()
-                ack = await self.hive.submit_result(entry.result)
-                # stage "submit": successful upload latency (failures are
-                # counted per-endpoint by hive.py)
-                observe_stage("submit", time.perf_counter() - t0)
+                # span "submit": one POST of the envelope, on the event
+                # loop (histogram and profiler only; a failed attempt
+                # spent its time too, and hive.py counts it per endpoint)
+                with Span("submit"):
+                    ack = await self.hive.submit_result(entry.result)
                 faults.fire("kill_before_ack")
                 # disposition ACKs (ISSUE 10): the hive took the POST but
                 # will never store this result — the job was cancelled,
@@ -1760,6 +1776,12 @@ class _HostLane:
         pipeline_config.setdefault("timings", {})["job_s"] = round(
             time.perf_counter() - started, 3)
         return artifacts, pipeline_config
+
+
+def _waited(enqueued: float, picked_up: float) -> tuple[float, float]:
+    """(wall instant a job was enqueued, seconds until `picked_up`) from
+    the two monotonic stamps — what the `queue_wait` span records."""
+    return time.time() - (time.monotonic() - enqueued), picked_up - enqueued
 
 
 async def run_worker() -> None:

@@ -131,21 +131,34 @@ def diffusion_callback(device_identifier: str, model_name: str, **kwargs):
             packaged = {"raw": pack_raw(images)}
         return packaged, pipeline_config
 
-    # real NSFW detection on the decoded pixels (reference envelope parity:
-    # swarm/worker.py:166); auxiliary — never fails the job
-    from ..pipelines.safety import flag_images
-
-    # stage "decode": host-side postprocess (NSFW check + grid composite +
-    # encode) after the on-device decode that ends the denoise program
+    # stage "decode": host-side postprocess after the on-device decode
+    # that ends the denoise program, parent of "safety" (real NSFW
+    # detection on the decoded pixels — reference envelope parity:
+    # swarm/worker.py:166; auxiliary, never fails the job) and
+    # "artifact_encode" (grid composite + encode)
     with Span("decode", pipeline_config.setdefault("timings", {})):
-        nsfw, checked = flag_images(images)
+        nsfw, checked = _flag(images)
         pipeline_config["nsfw"] = nsfw
         pipeline_config["nsfw_checked"] = checked
+        results = _package(images, outputs, content_type)
+    return results, pipeline_config
 
+
+def _flag(images):
+    """Span "safety": the NSFW pass over the decoded pixels."""
+    from ..pipelines.safety import flag_images
+
+    with Span("safety"):
+        return flag_images(images)
+
+
+def _package(images, outputs, content_type):
+    """Span "artifact_encode": grid composite, PNG/JPEG encode, base64
+    and hash of one job's images — host work on the slice thread."""
+    with Span("artifact_encode"):
         processor = OutputProcessor(outputs, content_type)
         processor.add_outputs(images)
-        results = processor.get_results()
-    return results, pipeline_config
+        return processor.get_results()
 
 
 def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
@@ -163,7 +176,6 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
     """
     from ..chips.requirements import coalesced_fit, default_canvas
     from ..pipelines.common import chunk_by_rows
-    from ..pipelines.safety import flag_images
 
     shared = requests[0]
     model_name = shared["model_name"]
@@ -263,9 +275,10 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
             counts[i] = max_rows
             row_specs[i]["num_images_per_prompt"] = max_rows
 
-    pipeline = get_pipeline(
-        model_name, pipeline_type=pipeline_type, chipset=chipset
-    )
+    with Span("load"):  # the group's one model look-up
+        pipeline = get_pipeline(
+            model_name, pipeline_type=pipeline_type, chipset=chipset
+        )
     cn_kwargs = {}
     if cn_name:
         cn_kwargs = {
@@ -325,15 +338,13 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
             out.append((packaged, pipeline_config))
             continue
         with Span("decode", pipeline_config.setdefault("timings", {})):
-            nsfw, checked = flag_images(images)
+            nsfw, checked = _flag(images)
             pipeline_config["nsfw"] = nsfw
             pipeline_config["nsfw_checked"] = checked
             pipeline_config["batched_with"] = len(requests)
             if i in capped:
                 pipeline_config["batch_capped"] = capped[i]
-            processor = OutputProcessor(env["outputs"], env["content_type"])
-            processor.add_outputs(images)
-            packaged = processor.get_results()
+            packaged = _package(images, env["outputs"], env["content_type"])
         out.append((packaged, pipeline_config))
     return out
 

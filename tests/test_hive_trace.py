@@ -153,6 +153,85 @@ def test_settled_job_answers_complete_ordered_timeline(sdaas_root):
     asyncio.run(scenario())
 
 
+def _settled_record(pipeline_config: dict, lease: float, settle: float):
+    """A settled JobRecord as build_trace reads one."""
+    from types import SimpleNamespace
+
+    return SimpleNamespace(
+        job_id="spans-1", job_class="default", state="done", attempts=1,
+        placement="cold", queue_wait_s=0.5, job={},
+        result={"id": "spans-1", "pipeline_config": pipeline_config},
+        timeline=[{"event": "admit", "wall": lease - 0.5},
+                  {"event": "dispatch", "wall": lease, "outcome": "cold"},
+                  {"event": "lease", "wall": lease},
+                  {"event": "settle", "wall": settle}])
+
+
+def _span(name, start, seconds, thread="slice"):
+    return {"name": name, "thread": thread, "start_wall": start,
+            "seconds": seconds}
+
+
+def test_spans_carve_the_executing_gap_and_leave_the_hole_visible():
+    """The worker's wall-stamped spans are the stages: `pass` (and so
+    `job_s`) is their parent and never one of them, children of a stage
+    are its detail, and what no span covers is reported."""
+    from chiaswarm_tpu.hive_server.trace import build_trace
+
+    t = 1_000_000.0
+    config = {
+        # the durations an old hive summed: job_s alone fills the gap
+        "timings": {"queue_wait_s": 0.1, "load_s": 0.0, "text_encode_s": 0.2,
+                    "trace_s": 0.0, "denoise_decode_s": 2.0, "decode_s": 0.5,
+                    "job_s": 3.0},
+        "spans": [
+            _span("text_encode", t + 0.2, 0.2),
+            _span("denoise", t + 0.4, 2.0),
+            _span("safety", t + 2.7, 0.1),
+            _span("artifact_encode", t + 2.8, 0.4),
+            # a hole of 0.3 s between denoise and decode
+            _span("decode", t + 2.7, 0.5),
+            _span("pass", t + 0.2, 3.0),
+            _span("queue_wait", t + 0.1, 0.1, thread="wait"),
+            # another thread's span overlapping the pass is its own stage
+            _span("ship", t + 1.0, 0.5, thread="shipper_0"),
+            {"name": "torn"},
+        ],
+    }
+    trace = build_trace(_settled_record(config, t, t + 3.5), t + 4.0)
+    executing = trace["gaps"][-1]
+    assert executing["attribution"] == "executing"
+    assert executing["seconds"] == 3.5
+    stages = executing["worker_stages"]
+    assert [s["stage"] for s in stages] == [
+        "queue_wait", "text_encode", "denoise", "ship", "decode"]
+    assert [s["start_wall"] for s in stages] == sorted(
+        s["start_wall"] for s in stages)
+    # the union, not the sum: `ship` ran while `denoise` did
+    assert executing["worker_total_s"] == pytest.approx(2.8)
+    assert executing["worker_total_s"] <= executing["seconds"]
+    # 0.1 s lease -> poll reply, the 0.3 s hole, 0.3 s spool and upload
+    assert executing["unattributed_s"] == pytest.approx(0.7)
+    assert trace["worker"]["total_s"] == executing["worker_total_s"]
+    assert trace_missing(trace) == []
+
+
+def test_an_old_envelope_without_spans_still_builds():
+    from chiaswarm_tpu.hive_server.trace import build_trace
+
+    t = 1_000_000.0
+    config = {"timings": {"queue_wait_s": 0.1, "load_s": 0.05,
+                          "text_encode_s": 0.2, "denoise_decode_s": 2.0,
+                          "decode_s": 0.5, "job_s": 3.0, "rows": 4}}
+    trace = build_trace(_settled_record(config, t, t + 3.5), t + 4.0)
+    executing = trace["gaps"][-1]
+    assert [s["stage"] for s in executing["worker_stages"]] == [
+        "queue_wait", "load", "text_encode", "denoise_decode", "decode"]
+    assert executing["worker_total_s"] == pytest.approx(2.85)
+    assert executing["unattributed_s"] == pytest.approx(0.65)
+    assert trace_missing(trace) == []
+
+
 def test_shed_submission_is_traced_and_folds_into_admit(sdaas_root):
     from chiaswarm_tpu.hive_server import HiveServer
 

@@ -129,24 +129,168 @@ def test_span_records_histogram_and_timings_dict():
 
 def test_span_records_on_exception():
     reg = Registry()
-    with pytest.raises(RuntimeError):
-        with Span("compile", registry=reg):
-            raise RuntimeError("trace failed")
+    with trace_job("job-err") as trace:
+        with pytest.raises(RuntimeError):
+            with Span("compile", registry=reg):
+                raise RuntimeError("trace failed")
     assert reg.get(STAGE_METRIC).count(stage="compile") == 1
+    assert [s["name"] for s in trace.spans] == ["compile"]
 
 
-def test_trace_job_nested_stages_share_timings():
+def _ends(span):
+    return span["start_wall"] + span["seconds"]
+
+
+def test_span_records_when_where_and_how_long():
     reg = Registry()
-    with trace_job("job-42", registry=reg) as trace:
-        with trace.stage("outer"):
-            with trace.stage("inner"):
+    before = time.time()
+    with trace_job("job-1") as trace:
+        with Span("outer", registry=reg):
+            with Span("inner", registry=reg) as inner:
                 time.sleep(0.002)
-        trace.record("queue_wait", 1.25)
-    h = reg.get(STAGE_METRIC)
-    assert h.label_values("stage") == ["inner", "outer", "queue_wait"]
-    # nesting: outer wall clock includes inner's
-    assert trace.timings["outer_s"] >= trace.timings["inner_s"]
-    assert trace.timings["queue_wait_s"] == 1.25
+    after = time.time()
+    # children end first, so they are recorded first
+    assert [s["name"] for s in trace.spans] == ["inner", "outer"]
+    child, parent = trace.spans
+    assert set(child) == {"name", "thread", "start_wall", "seconds"}
+    # on the wall clock, unrounded, on the thread that opened the trace
+    assert before <= parent["start_wall"] <= child["start_wall"] <= after
+    assert child["seconds"] == inner.elapsed >= 0.002
+    assert child["seconds"] != round(child["seconds"], 3)
+    assert child["thread"] == parent["thread"] == "slice"
+    # a child lies inside its parent (an end is a wall start plus a
+    # perf_counter duration: 0.1 ms of slack)
+    assert _ends(child) <= _ends(parent) + 1e-4
+    # outside a trace a span still feeds the histogram, and nothing else
+    with Span("outer", registry=reg):
+        pass
+    assert reg.get(STAGE_METRIC).count(stage="outer") == 2
+    assert len(trace.spans) == 2
+
+
+@pytest.mark.parametrize("how", ["entered", "measured_elsewhere"])
+def test_trace_job_collects_stages_into_timings_and_spans(how):
+    """One primitive for a stage timed in place and for one measured
+    elsewhere (queue wait, stamped by the scheduler): histogram, timings
+    key and span alike."""
+    reg = Registry()
+    timings = {}
+    with trace_job("job-42") as trace:
+        if how == "entered":
+            with Span("queue_wait", timings, registry=reg):
+                time.sleep(0.002)
+            sealed = trace.spans
+        else:
+            sealed = []  # the envelope's list: the pass's trace is closed
+            Span("queue_wait", timings, registry=reg, thread="wait",
+                 spans=sealed).record(1000.5, 1.25)
+            assert trace.spans == []
+    assert reg.get(STAGE_METRIC).label_values("stage") == ["queue_wait"]
+    [span] = sealed
+    assert span["name"] == "queue_wait"
+    assert timings["queue_wait_s"] == round(span["seconds"], 3)
+    if how == "measured_elsewhere":
+        assert span == {"name": "queue_wait", "thread": "wait",
+                        "start_wall": 1000.5, "seconds": 1.25}
+
+
+def test_spans_reach_their_own_trace_across_threads():
+    """Two passes on two executor threads keep their spans apart; a helper
+    thread running under a copy of a pass's context joins that pass under
+    its own thread's name."""
+    import contextvars
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    reg = Registry()
+    gate = threading.Barrier(2)
+
+    def ship():
+        with Span("ship", registry=reg):
+            pass
+
+    def one_pass(job_id):
+        with trace_job(job_id) as trace:
+            gate.wait(timeout=5)
+            with Span(f"work-{job_id}", registry=reg):
+                helper = threading.Thread(
+                    name=f"helper-{job_id}",
+                    target=contextvars.copy_context().run, args=(ship,))
+                helper.start()
+                helper.join(timeout=5)
+            gate.wait(timeout=5)
+        return trace.spans
+
+    with ThreadPoolExecutor(2) as pool:
+        a, b = pool.map(one_pass, ["a", "b"])
+    assert [(s["name"], s["thread"]) for s in a] == [
+        ("ship", "helper-a"), ("work-a", "slice")]
+    assert [(s["name"], s["thread"]) for s in b] == [
+        ("ship", "helper-b"), ("work-b", "slice")]
+    # a thread that copied no context belongs to no pass
+    with trace_job("c") as trace:
+        stray = threading.Thread(target=ship)
+        stray.start()
+        stray.join(timeout=5)
+    assert trace.spans == []
+
+
+def test_span_needs_no_jax(monkeypatch):
+    """The hive's process never loads jax: no annotation, same record.
+    Where jax is loaded the span opens a `swarm/<stage>` annotation."""
+    import sys
+
+    from chiaswarm_tpu import telemetry
+
+    monkeypatch.setattr(telemetry, "_TraceAnnotation", None)
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
+    reg = Registry()
+    with trace_job("hive-side") as trace:
+        with Span("admit", registry=reg) as span:
+            assert span._annotation is None
+    assert [s["name"] for s in trace.spans] == ["admit"]
+    assert telemetry._TraceAnnotation is None
+    monkeypatch.undo()
+
+    import jax
+
+    opened = []
+
+    class Recorder(jax.profiler.TraceAnnotation):
+        def __init__(self, name):
+            opened.append(name)
+            super().__init__(name)
+
+    monkeypatch.setattr(telemetry, "_TraceAnnotation", Recorder)
+    with Span("denoise", registry=reg):
+        pass
+    assert opened == ["swarm/denoise"]
+
+
+def test_slice_free_seconds_counts_between_passes():
+    import jax
+
+    from chiaswarm_tpu import telemetry
+    from chiaswarm_tpu.chips.device import ChipSet
+
+    chipset = ChipSet(jax.devices()[:1], slice_id=9041)
+    free = telemetry.REGISTRY.get("swarm_slice_free_seconds_total")
+
+    def callback(_device, _model, **_kwargs):
+        return {}, {}
+
+    with trace_job("p1") as trace:
+        chipset(callback, model_name="m", seed=1)
+    # nothing before the first pass
+    assert free.value(slice="9041") == 0.0
+    assert [s["name"] for s in trace.spans] == ["pass"]
+    time.sleep(0.02)
+    _, config = chipset(callback, model_name="m", seed=1)
+    first = free.value(slice="9041")
+    assert 0.02 <= first < 1.0
+    assert config["timings"]["job_s"] >= 0.0
+    chipset.run_batched(lambda _device, requests: [({}, {})], [{"seed": 2}])
+    assert free.value(slice="9041") > first
 
 
 def test_trace_job_pins_current_job_id():

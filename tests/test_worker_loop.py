@@ -464,7 +464,8 @@ def test_job_stage_spans_recorded_end_to_end(sdaas_root):
     stages = telemetry.REGISTRY.get(STAGE_METRIC) or telemetry.histogram(
         STAGE_METRIC, "", ("stage",))
     completed = telemetry.REGISTRY.get("swarm_jobs_completed_total")
-    required = ("queue_wait", "compile", "denoise", "decode", "submit")
+    required = ("queue_wait", "compile", "denoise", "decode", "submit",
+                "pass", "load", "safety", "artifact_encode", "spool")
     before = {s: stages.count(stage=s) for s in required}
     ok_before = completed.value(outcome="ok") if completed else 0
 
@@ -498,6 +499,27 @@ def test_job_stage_spans_recorded_end_to_end(sdaas_root):
     timings = result["pipeline_config"]["timings"]
     for key in ("queue_wait_s", "trace_s", "denoise_decode_s", "decode_s"):
         assert key in timings, timings
+    # ... and the same spans wall-stamped, children inside their parents
+    # on the slice thread (the hive and the benchmark's readers rely on it)
+    spans = {s["name"]: s for s in result["pipeline_config"]["spans"]}
+    for name in ("pass", "queue_wait", "load", "text_encode", "compile",
+                 "denoise", "decode", "safety", "artifact_encode"):
+        assert name in spans, sorted(spans)
+    assert spans["queue_wait"]["thread"] == "wait"
+
+    def inside(child, parent, slack=1e-4):
+        child, parent = spans[child], spans[parent]
+        return (child["thread"] == parent["thread"] == "slice"
+                and parent["start_wall"] <= child["start_wall"]
+                and child["start_wall"] + child["seconds"]
+                <= parent["start_wall"] + parent["seconds"] + slack)
+
+    for child in ("load", "text_encode", "compile", "denoise", "decode"):
+        assert inside(child, "pass"), (child, spans)
+    for child in ("safety", "artifact_encode"):
+        assert inside(child, "decode"), (child, spans)
+    assert spans["queue_wait"]["start_wall"] <= spans["pass"]["start_wall"]
+    assert timings["job_s"] == round(spans["pass"]["seconds"], 3)
     # capability heartbeat folded in the live-load snapshot
     req = hive.work_requests[0]
     assert "jobs_in_flight" in req and "busy_slices" in req
