@@ -26,8 +26,13 @@ from .platform import (
     trace_platform,
 )
 
-# sequence length below which the plain XLA path is faster than paying
-# kernel launch + pipelining overheads
+# query length below which the plain XLA path is faster than paying
+# kernel launch + pipelining overheads. Any key length: 77-key
+# cross-attention stays on the kernel, which keeps the softmax weights'
+# sum in f32 where the XLA path rounds the weights to bf16 first (max
+# abs error 0.004-0.008 against 0.015 at the UNets' shapes; alone on a
+# v5e the kernel takes 0.10-0.26 ms a call plus its head transposes,
+# XLA's whole path 0.28-0.34 ms: PERF.md, PR 25)
 _FLASH_MIN_SEQ = 1024
 # largest head dim the flash kernel's [block, D] tiles are sized for (the
 # VAE mid-block's single 512-wide head stays on the XLA path)
@@ -85,7 +90,8 @@ def _flash_route(q, k, v, scale, interpret: bool = False):
     batch over `data`, heads over `tensor` where the head count divides
     (SDXL's 20-head level on 4 chips), else query rows over `tensor` with
     K/V whole on every chip (its 10-head level). Axes that divide nothing
-    stay replicated.
+    stay replicated. The kernel picks its blocks from the shapes it is
+    handed (`flash_blocks`), here the per-chip ones.
     """
     kernel = functools.partial(flash_attention, scale=scale,
                                interpret=interpret)

@@ -16,7 +16,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from chiaswarm_tpu.ops.flash_attention import _flash_impl
+from chiaswarm_tpu.ops.flash_attention import flash_attention
 from chiaswarm_tpu.ops.group_norm import (
     _fused_group_norm,
     _vmem_budget,
@@ -51,15 +51,22 @@ def _shape(sharding, shape, dtype=jnp.bfloat16):
     pytest.param(2, 4096, 4096, 10, 64, id="sdxl-self-4096x10x64"),
     pytest.param(2, 1024, 1024, 20, 64, id="sdxl-self-1024x20x64"),
     pytest.param(2, 4096, 77, 10, 64, id="sdxl-cross-4096q-77kv"),
+    pytest.param(2, 1024, 77, 20, 64, id="sdxl-cross-1024q-77kv"),
     pytest.param(2, 9216, 9216, 5, 64, id="sd21-768-9216x5x64"),
+    pytest.param(2, 2304, 2304, 10, 64, id="sd21-768-2304x10x64"),
+    pytest.param(2, 9216, 77, 5, 64, id="sd21-cross-9216q-77kv"),
+    pytest.param(8, 4096, 4096, 10, 64, id="gang-sdxl-self-4096x10x64"),
+    pytest.param(8, 9216, 9216, 5, 64, id="gang-sd21-9216x5x64"),
+    # what _flash_route hands one chip of four
+    pytest.param(2, 2304, 9216, 5, 64, id="per-chip-sd21-rows-2304q-9216kv"),
+    pytest.param(2, 1024, 1024, 5, 64, id="per-chip-sdxl-5-of-20-heads"),
     pytest.param(1, 4608, 4608, 24, 128, id="flux-4608x24x128"),
 ])
 def test_flash_attention_compiles_for_v5e(v5e, b, sq, skv, h, d):
+    """With the blocks the rule gives the shape, as the program calls it."""
     q = _shape(v5e, (b, sq, h, d))
     kv = _shape(v5e, (b, skv, h, d))
-    compiled = _flash_impl.lower(
-        q, kv, kv, scale=None, block_q=512, block_k=512, interpret=False
-    ).compile()
+    compiled = flash_attention.lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
