@@ -1,4 +1,6 @@
-"""On-device seeded weights for the `sd` / `sdxl` pipeline families.
+"""The `sd` / `sdxl` pipeline families: everything the benchmark knows of
+this network. On-device seeded weights, the denoiser's half of `correct` 5
+and the compile check's operands (README, "A family").
 
 The program initialises `test/*` models eagerly on the host (~950 XLA:CPU
 compiles, 150 s for SDXL: PERF.md section 5), a path no user pays. The
@@ -9,9 +11,13 @@ modules (`jax.eval_shape` over their `init`); values come from `--seed`,
 made on the device as windows of one seeded normal pool, in the dtype
 they are served in.
 
-Leans on two private names, `SDPipeline._load_params` and `_place`
-(PERF.md, Open questions: a public weight-provider seam). If either is
-gone this module fails loudly instead of paying the host init in silence.
+The weight factory leans on two private names, `SDPipeline._load_params`
+and `_place` (PERF.md, Open questions: a public weight-provider seam). If
+either is gone this module fails loudly instead of paying the host init in
+silence. The denoiser's half and the compile operands read the pipeline as
+it is (`unet`, `is_xl`, `_xl_time_ids`, `_denoise_program`'s key and
+arguments): a program change that moves those is repaired here, by a
+`benchmark` PR.
 """
 
 from __future__ import annotations
@@ -20,6 +26,14 @@ import math
 import time
 
 FAMILIES = ("sd", "sdxl")
+# Denoiser against the plain reference: relative L2 error of the predicted
+# noise. bfloat16 weights and activations with float32 accumulation read
+# 0.011-0.012 (SD2.1 768^2) and 0.013-0.015 (SDXL 1024^2) over nine seeds
+# (my chip runs, PR 23); the bound is twice the worst. Rounding to int8
+# (2^-7 a value against bfloat16's 2^-9) or accumulating in bfloat16 over
+# contractions of 1280-10240 terms would multiply that error several
+# times, by the same square-root-of-depth growth these readings show.
+DENOISER_REL_L2_TOL = 0.03
 
 
 def init_shapes(pipe):
@@ -185,3 +199,138 @@ def register(seed: int, record: dict) -> None:
 
     for family in FAMILIES:
         registry.register_family(family)(factory)
+
+
+# --- the denoiser's half of `correct` 5 --------------------------------------
+
+
+def denoiser_inputs(pipe, config: dict, seed: int) -> dict:
+    """One seeded CFG pair at the cell's latent shape, rounded to the
+    serving dtype (so the reference sees the values the system sees)."""
+    import jax
+    import jax.numpy as jnp
+
+    height, width = int(config["job"]["height"]), int(config["job"]["width"])
+    cfg = pipe.unet.config
+    lh, lw = height // pipe.latent_factor, width // pipe.latent_factor
+    keys = jax.random.split(jax.random.key(seed), 3)
+    inputs = {
+        "sample": jax.random.normal(
+            keys[0], (2, lh, lw, cfg.in_channels)).astype(pipe.dtype),
+        "timesteps": jnp.asarray([501.0, 501.0]),
+        "context": jax.random.normal(
+            keys[1], (2, 77, cfg.cross_attention_dim)).astype(pipe.dtype),
+        "added": None}
+    if pipe.is_xl:
+        pooled = cfg.addition_embed_dim - 6 * cfg.addition_time_embed_dim
+        inputs["added"] = {
+            "text_embeds": jax.random.normal(
+                keys[2], (2, pooled)).astype(pipe.dtype),
+            "time_ids": jnp.asarray(
+                [pipe._xl_time_ids(pooled, height, width)] * 2, jnp.float32)}
+    return inputs
+
+
+def denoiser_reference(pipe, inputs: dict):
+    """The plain reference's predicted noise for the pair's second
+    (conditional) row, computed on the host CPU. Rows of a batch are
+    independent in this network, so one row of the pair is compared: the
+    whole pair in float32 on the host takes a minute for SDXL, and every
+    run of every check would pay it."""
+    import jax
+
+    from ..reference.unet2d import unet_forward
+
+    def row(tree):
+        return jax.tree_util.tree_map(lambda x: x[1:], tree)
+
+    return unet_forward(
+        pipe.params["unet"], pipe.unet.config, row(inputs["sample"]),
+        row(inputs["timesteps"]), row(inputs["context"]),
+        None if inputs["added"] is None else row(inputs["added"]),
+        device=jax.local_devices(backend="cpu")[0])
+
+
+def denoiser_serve(pipe, inputs: dict):
+    """One evaluation of the resident UNet on the pair, in the serving
+    dtype with the kernels as dispatched; returns the row the reference
+    computed. The predicted noise is compared: a 30-step loop on random
+    weights would amplify rounding."""
+    import jax
+
+    from chiaswarm_tpu.ops.platform import mesh_scope
+
+    serve = jax.jit(lambda p, x, t, c, a: pipe.unet.apply(
+        {"params": p}, x, t, c, added_cond=a))
+    with mesh_scope(pipe.mesh):
+        got = serve(pipe.params["unet"], inputs["sample"],
+                    inputs["timesteps"], inputs["context"], inputs["added"])
+    return got[1:]
+
+
+# --- the compile check's operands --------------------------------------------
+
+
+def compile_operands(spec: dict, devices):
+    """The cell's denoise program (as `run_batched` / `run` would key it),
+    its arguments as shapes on the described `devices`, and its rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from chiaswarm_tpu.pipelines.stable_diffusion import (
+        SchedulerConfig,
+        dataclass_items,
+    )
+    from chiaswarm_tpu.settings import load_settings
+
+    if len(devices) != 1:
+        raise ValueError(
+            f"families/sd.py hands the compile check a one-chip program; "
+            f"the cell asks for {len(devices)} chips")
+    config, traffic = spec["config"], spec["traffic"]
+    job = {**config["job"], **traffic["job"]}
+    one = SingleDeviceSharding(devices[0])
+
+    class Shapes(make_pipeline_class()):
+        def _load_params(self):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, self.dtype,
+                                               sharding=one),
+                init_shapes(self))
+
+    pipe = Shapes(job["model_name"], dtype=jnp.dtype(config["kernel_dtype"]))
+    rows = min(int(traffic["clients"]),
+               int(load_settings().hive_max_jobs_per_poll))
+    lh = int(job["height"]) // pipe.latent_factor
+    lw = int(job["width"]) // pipe.latent_factor
+    scheduler = config["job"].get("parameters", {}).get(
+        "scheduler_type", "DPMSolverMultistepScheduler")
+    sched_cfg = SchedulerConfig(prediction_type=pipe.prediction_type,
+                                use_karras_sigmas=False)
+    sched_key = (scheduler, tuple(sorted(dataclass_items(sched_cfg))))
+    # a gang of one takes the worker's solo path (`run`), larger ones the
+    # batched one (`run_batched`): their programs are keyed differently
+    mode = "batched" if rows > 1 else "txt2img"
+    key = (mode, lh, lw, rows, int(job["num_inference_steps"]), sched_key,
+           0, None)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    rngs = shape((rows,) + rng.shape, rng.dtype) if rows > 1 \
+        else shape(rng.shape, rng.dtype)
+    cross = pipe.unet.config.cross_attention_dim
+    added = None
+    if pipe.is_xl:
+        pooled = (pipe.unet.config.addition_embed_dim
+                  - 6 * pipe.unet.config.addition_time_embed_dim)
+        added = {"text_embeds": shape((2 * rows, pooled), pipe.dtype),
+                 "time_ids": shape((2 * rows, 6), jnp.float32)}
+    scalar = shape((), jnp.float32)
+    args = (pipe.params, rngs, shape((2 * rows, 77, cross), pipe.dtype),
+            added, scalar, scalar, shape((1, 1, 1, 4), jnp.float32),
+            shape((1, 1, 1, 1), jnp.float32), rngs, {},
+            shape((1, 1, 1, 3), jnp.float32), scalar, {})
+    return pipe._denoise_program(key), args, rows

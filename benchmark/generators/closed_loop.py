@@ -7,12 +7,22 @@ does) and stops submitting when the window closes; a job still queued at
 the close is withdrawn (cancelled, counted apart), one already leased is
 awaited, so every job a worker took is checked.
 
+`think_s` is one number, or a list of them: then a client takes the
+list's values in an order shuffled from the seed (its first job's id
+carries it), every value once before any comes again, so every seed gets
+the same set of think times in another order. A lone awaiting client
+with no think time locks onto the worker's poll cadence (each submit
+falls at one fixed phase of it, so the wait for the next poll is one
+value all run long, set by the work's length modulo the poll period); a
+list that spans one poll period walks the phase through the period.
+
 Parameters (the traffic file): `clients`, `think_s`, `status_poll_s`.
 """
 
 from __future__ import annotations
 
 import asyncio
+import random
 import time
 
 from benchmark.harness import TERMINAL
@@ -23,13 +33,15 @@ async def run(client, traffic: dict, make_job, window, observe) -> list[dict]:
     in submit order. `observe(record)` is called as each job ends."""
     records: list[dict] = []
     poll_s = float(traffic.get("status_poll_s", 0.02))
-    think_s = float(traffic.get("think_s", 0.0))
+    think = traffic.get("think_s", 0.0)
+    thinks = [float(t) for t in (think if isinstance(think, list) else [think])]
 
     async def one_client(number: int) -> None:
-        previous = None
+        previous, thought, order, upcoming = None, 0.0, None, []
         while not window.closed():
             job = make_job()
             record = {"id": job["id"], "client": number, "previous": previous,
+                      "think_s": thought,
                       "submit_wall": time.time(), "withdrawn": False}
             records.append(record)
             await client.submit(job)
@@ -46,8 +58,13 @@ async def run(client, traffic: dict, make_job, window, observe) -> list[dict]:
             record["status"] = status
             observe(record)
             previous = job["id"]
-            if think_s:
-                await asyncio.sleep(think_s)
+            if order is None:
+                order = random.Random(f"think {number} {job['id']}")
+            if not upcoming:
+                upcoming = order.sample(thinks, len(thinks))
+            thought = upcoming.pop()
+            if thought:
+                await asyncio.sleep(thought)
 
     tasks = [asyncio.create_task(one_client(n), name=f"client_{n}")
              for n in range(int(traffic["clients"]))]

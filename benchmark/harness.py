@@ -3,12 +3,14 @@
 Driven by data: the cell names a configuration file and a traffic file;
 the metrics it reports are the entries of `BENCHMARK.json` that apply to
 it, each computed by the reader of that name under `end_to_end/` or
-`layer_metrics/`. Nothing here names a cell, a model or a metric.
+`layer_metrics/`. Nothing here names a cell, a model or a metric, and
+nothing knows a network: what does is the module under `families/` that
+the configuration names (`load_family`).
 
 The system under test runs in this process: a real `HiveServer` on a
-loopback socket and one pristine `Worker` with a one-chip slice
-(`hive_server.harness.LocalSwarm`), driven over HTTP from the client's
-side. See README.md for the order of events and why.
+loopback socket and one pristine `Worker` with one slice of the cell's
+chips (`hive_server.harness.LocalSwarm`), driven over HTTP from the
+client's side. See README.md for the order of events and why.
 """
 
 from __future__ import annotations
@@ -82,6 +84,32 @@ def apply_rehearsal(spec: dict) -> None:
                 spec[part][key] = {**spec[part][key], **value}
             else:
                 spec[part][key] = value
+
+
+# what a module under `families/` has to have (README, "A family")
+FAMILY_CONTRACT = ("register", "denoiser_inputs", "denoiser_reference",
+                   "denoiser_serve", "DENOISER_REL_L2_TOL",
+                   "compile_operands")
+
+
+def load_family(config: dict):
+    """The module that knows the configuration's network. A family that is
+    not there or lacks a name of the contract fails here, before anything
+    is built, and not after the window."""
+    name = config["family"]
+    module = f"benchmark.families.{name}"
+    try:
+        family = importlib.import_module(module)
+    except ModuleNotFoundError as error:
+        if error.name != module:
+            raise
+        raise RunFailure(f"no benchmark/families/{name}.py for family "
+                         f"{name!r}") from None
+    missing = [n for n in FAMILY_CONTRACT if not hasattr(family, n)]
+    if missing:
+        raise RunFailure(f"benchmark/families/{name}.py lacks "
+                         f"{', '.join(missing)}")
+    return family
 
 
 def load_reader(kind: str, name: str):
@@ -339,6 +367,7 @@ async def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     from . import checks
 
     config, traffic, cell = spec["config"], spec["traffic"], spec["cell"]
+    family = load_family(config)
     record: dict = {"spec": spec, "seed": seed, "rehearsal": rehearsal,
                     "failures": []}
     loop = asyncio.get_running_loop()
@@ -346,8 +375,6 @@ async def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     emit(phase="start", cache=str(enable_compile_cache()),
          since_start_s=time.monotonic() - started_mono)
 
-    family = importlib.import_module(
-        f"benchmark.families.{config['family']}")
     family.register(seed, record)
     from chiaswarm_tpu import registry
 
@@ -431,7 +458,7 @@ async def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         record["scrape_open"] = scrape()
         emit(phase="window_open", setup_s=record["setup_s"])
         closing = asyncio.create_task(
-            at_window_close(window, record, swarm.workers[0]))
+            at_window_close(window, record, swarm.workers[0], family))
         load = await load_task  # returns once the leased jobs have ended
         trace_file = await tracer.finish()
 
@@ -450,21 +477,19 @@ async def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         await swarm.stop()
 
     pipe, inputs, want, seconds = await closing
-    failures, reading = checks.denoiser(pipe, inputs, want)
+    failures, reading = checks.denoiser(family, pipe, inputs, want)
     record["failures"] += failures
     record["denoiser_reading"] = reading
     emit(phase="reference", reading=reading, host_seconds=seconds)
     return record
 
 
-async def at_window_close(window: Window, record: dict, worker):
+async def at_window_close(window: Window, record: dict, worker, family):
     """What happens the moment the window closes: counters and the memory
-    peak are read, and the plain reference's half of `correct` 5 starts —
-    on the host CPU, while the last leased pass drains and the artifacts
-    are checked, never inside the window."""
+    peak are read, and the plain reference's half of `correct` 5 starts
+    (the family's, on the host CPU) while the last leased pass drains and
+    the artifacts are checked, never inside the window."""
     from chiaswarm_tpu import registry
-
-    from . import checks
 
     await asyncio.sleep(max(window.close_wall - time.time(), 0.0))
     record["scrape_close"] = scrape()
@@ -475,10 +500,10 @@ async def at_window_close(window: Window, record: dict, worker):
         job["model_name"],
         job.get("parameters", {}).get("pipeline_type", "DiffusionPipeline"),
         chipset=worker.allocator.slices[0])
-    inputs = checks.denoiser_inputs(
-        pipe, int(job["height"]), int(job["width"]), record["seed"])
+    inputs = family.denoiser_inputs(
+        pipe, record["spec"]["config"], record["seed"])
     want = await asyncio.get_running_loop().run_in_executor(
-        None, checks.denoiser_reference, pipe, inputs)
+        None, family.denoiser_reference, pipe, inputs)
     return pipe, inputs, want, time.monotonic() - started
 
 
@@ -616,6 +641,10 @@ def report(record: dict, traced: bool) -> dict:
          before_window=sum(not j.get("in_window") for j in jobs),
          latency_samples=len([j for j in jobs if j.get("in_window")
                               and not j["withdrawn"]]),
+         submit_to_settle_s=[
+             round(measure.stamp(j, "settle") - j["submit_wall"], 3)
+             for j in measure.window_jobs(record)
+             if measure.stamp(j, "settle") is not None],
          probe_sha256=record.get("probe_sha256"),
          kernel_traces=record.get("kernel_traces"),
          weights_phases=record.get("weights_phases"),

@@ -2,8 +2,13 @@
 here, and the harness against its own rule: driven by data."""
 
 import json
+import os
 import re
+import subprocess
+import sys
 from pathlib import Path
+
+import pytest
 
 REPO = Path(__file__).resolve().parents[2]
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -64,6 +69,20 @@ def test_every_cell_reports_enough():
             assert metric["moves"] in names
 
 
+def code_of(path: Path) -> str:
+    """A file's code without its docstrings and comments."""
+    code = re.sub(r'""".*?"""', "", path.read_text(), flags=re.S)
+    return "\n".join(line.split("#")[0] for line in code.splitlines())
+
+
+# the files that may know no cell, model, metric or network
+DATA_DRIVEN = ("harness.py", "run.py", "rehearse.py", "measure.py",
+               "breakdown.py", "checks.py", "compile_check.py",
+               "generators/closed_loop.py", "trace/reduce.py")
+# one network's own names: they belong in its module under `families/`
+NETWORK_WORDS = ("unet", "is_xl", "unet2d", "SDPipeline", "_denoise_program")
+
+
 def test_harness_names_no_cell_model_or_metric():
     words = {c["name"] for c in BENCH["configs"]}
     words |= {w["name"] for w in BENCH["workloads"]}
@@ -73,12 +92,39 @@ def test_harness_names_no_cell_model_or_metric():
         words.add(json.loads(
             (REPO / config["file"]).read_text())["job"]["model_name"])
     root = REPO / BENCH["paths"][0]
-    for path in ("harness.py", "run.py", "rehearse.py", "measure.py",
-                 "breakdown.py", "generators/closed_loop.py",
-                 "trace/reduce.py"):
-        code = re.sub(r'""".*?"""', "", (root / path).read_text(), flags=re.S)
-        code = "\n".join(line.split("#")[0] for line in code.splitlines())
+    for path in DATA_DRIVEN:
+        code = code_of(root / path)
         for word in words:
             assert not re.search(
                 rf"(?<![\w.\-]){re.escape(word)}(?![\w.\-])", code), (
                 path, word)
+
+
+@pytest.mark.parametrize("path", DATA_DRIVEN)
+def test_harness_knows_no_network(path):
+    """Attribute or name, any case: `pipe.unet`, `UNET_TOL`, `unet2d`."""
+    code = code_of(REPO / BENCH["paths"][0] / path)
+    for word in NETWORK_WORDS:
+        found = re.search(rf"(?i)(?<![0-9a-z]){re.escape(word)}(?![0-9a-z])",
+                          code)
+        assert not found, (path, word)
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_rehearsal_gives_a_cell_one_host_device_a_chip(chips):
+    """`rehearse.host_devices` before jax is imported, in a process of its
+    own; no pipeline is built."""
+    script = (
+        "import os, sys\n"
+        f"sys.path.insert(0, {str(REPO)!r})\n"
+        "from benchmark import rehearse\n"
+        "assert 'jax' not in sys.modules\n"
+        "rehearse.host_devices({'cell': {'chips': int(sys.argv[1])}})\n"
+        "import jax\n"
+        "print(jax.devices()[0].platform, len(jax.devices()))\n")
+    out = subprocess.run(
+        [sys.executable, "-c", script, str(chips)], check=True,
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "XLA_FLAGS": "--xla_force_host_platform_device_count=2"})
+    assert out.stdout.split() == ["cpu", str(chips)]
