@@ -22,7 +22,13 @@ layer between the poll loop and the slice workers:
   work item. Jobs that arrive PRE-BATCHED from a gang-scheduling hive
   (trace.gang on the wire, ISSUE 9) skip the linger entirely via
   `put_gang()` — the hive already did the waiting — flushing as one
-  group with reason "gang". Groups cap at Settings.max_coalesce jobs and at the slice's
+  group with reason "gang". The linger is never shorter than
+  LINGER_PASS_SHARE of the key's shortest pass: a batchmate that is a poll
+  away is worth 1/40 of a ten-second pass, and a linger shorter than the
+  poll period can only ever join jobs of one reply. A group the linger
+  formed says so in each member's trace context (`trace.gang`, `by:
+  worker`), as a hive gang does, so whoever reads the envelopes sees the
+  passes that ran. Groups cap at Settings.max_coalesce jobs and at the slice's
   capacity limit in images (rows_limit, wired to
   chips/requirements.fit_batch by the worker), so a coalesced batch is
   always admissible without rejection.
@@ -50,6 +56,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import time
+import uuid
 from typing import Callable
 
 from . import telemetry
@@ -93,6 +100,13 @@ _GROUP_ROWS = telemetry.histogram(
     "Images per released coalesced group",
     buckets=(1, 2, 4, 8, 16, 32),
 )
+# a group may wait this share of its key's shortest pass for batchmates (never
+# less than Settings.batch_linger_ms): one more row costs a pass far less
+# than the pass again, so 1/40 of it is cheap to wait; under a 2 s pass the
+# fixed 50 ms linger still rules
+LINGER_PASS_SHARE = 0.025
+_PASS_KEYS_KEPT = 256
+
 _LINGER_WAIT = telemetry.histogram(
     "swarm_batch_linger_wait_seconds",
     "Open time of a coalescing group from first job to flush",
@@ -153,6 +167,11 @@ class BatchScheduler:
         # coalescing appetite, not 1
         self._ready_rows = 0
         self._executing_rows = 0  # rows claimed off the board, not done
+        # job id -> the instant its work item was claimed, and coalesce key
+        # -> [passes seen, the shortest's seconds from claim to task_done,
+        # the last one's claim instant]
+        self._claimed_at: dict[str, float] = {}
+        self._pass_s: dict[tuple, list] = {}
         self._closed = False  # drain mode: nothing lingers anymore
 
     # --- queue-compatible surface for the worker loop ---
@@ -179,6 +198,35 @@ class BatchScheduler:
         self._executing_rows = max(
             self._executing_rows - (job_rows(job) if job is not None else 1),
             0)
+        claimed = None if job is None else self._claimed_at.pop(
+            str(job.get("id")), None)
+        key = coalesce_key(job) if claimed is not None else None
+        if key is not None:
+            seen = self._pass_s.pop(key, None) or [0, float("inf"), None]
+            if seen[2] != claimed:  # a pass's jobs share their claim instant
+                seen = [seen[0] + 1,
+                        min(seen[1], time.monotonic() - claimed), claimed]
+            self._pass_s[key] = seen  # re-inserted: the newest key last
+            if len(self._pass_s) > _PASS_KEYS_KEPT:
+                self._pass_s.pop(next(iter(self._pass_s)))
+
+    def _claimed(self, entry: dict) -> None:
+        self._ready_jobs -= len(entry["jobs"])
+        self._ready_rows -= entry["rows"]
+        self._executing_rows += entry["rows"]
+        now = time.monotonic()
+        for job in entry["jobs"]:
+            self._claimed_at[str(job.get("id"))] = now
+
+    def linger_for(self, key: tuple) -> float:
+        """Seconds a new group of this key waits for batchmates: the fixed
+        linger, or LINGER_PASS_SHARE of the key's shortest pass if that is
+        longer. A key's first pass carries its compile, so the share
+        counts only once two passes have been seen."""
+        passes, least_s, _ = self._pass_s.get(key, (0, 0.0, None))
+        if passes < 2:
+            return self.linger_s
+        return max(self.linger_s, LINGER_PASS_SHARE * least_s)
 
     @property
     def pending_jobs(self) -> int:
@@ -225,9 +273,7 @@ class BatchScheduler:
         while not self._board:
             await self._wait_change()
         entry = self._board.pop(0)
-        self._ready_jobs -= len(entry["jobs"])
-        self._ready_rows -= entry["rows"]
-        self._executing_rows += entry["rows"]
+        self._claimed(entry)
         return entry["jobs"]
 
     async def claim(self, allocator) -> tuple[list[dict], object, str]:
@@ -257,9 +303,7 @@ class BatchScheduler:
 
         def take(idx: int, chipset, outcome: str):
             entry = self._board.pop(idx)
-            self._ready_jobs -= len(entry["jobs"])
-            self._ready_rows -= entry["rows"]
-            self._executing_rows += entry["rows"]
+            self._claimed(entry)
             _PLACEMENT.inc(outcome=outcome)
             return entry["jobs"], chipset, outcome
 
@@ -341,7 +385,8 @@ class BatchScheduler:
             loop = asyncio.get_running_loop()
             group = {"jobs": [], "rows": 0, "cap": cap, "adapters": set(),
                      "opened": time.monotonic()}
-            group["timer"] = loop.call_later(self.linger_s, self._flush, key)
+            group["timer"] = loop.call_later(
+                self.linger_for(key), self._flush, key)
             self._pending[key] = group
         group["jobs"].append(job)
         group["rows"] += rows
@@ -471,10 +516,17 @@ class BatchScheduler:
         # and "waiting for a slice" are different tuning knobs
         # (batch_linger_ms vs capacity), and the job's end-to-end
         # timeline should attribute them separately
-        for job in group["jobs"]:
+        gang_id = uuid.uuid4().hex[:12]
+        for index, job in enumerate(group["jobs"]):
             if isinstance(job.get("trace"), dict):
                 job["trace"]["lingered_s"] = round(lingered, 3)
                 job["trace"]["coalesced_with"] = len(group["jobs"]) - 1
+                if len(group["jobs"]) > 1:
+                    # the pass these jobs ride in, as the hive stamps a
+                    # gang it formed: the envelopes echo it
+                    job["trace"]["gang"] = {
+                        "id": gang_id, "size": len(group["jobs"]),
+                        "index": index, "by": "worker"}
         if len(group["jobs"]) > 1:
             logger.info(
                 "coalesced %d jobs (%d images) for %s [%s]",

@@ -85,6 +85,9 @@ _SAFE_PARAMETER_KEYS = frozenset({
     "num_inference_steps",
     "guidance_scale",
     "num_images_per_prompt",
+    # flux only: the T5 token budget is a static shape of the batched
+    # program, so it is a key dimension there (and keys to None elsewhere)
+    "max_sequence_length",
     "large_model",
     "use_karras_sigmas",
     "default_height",
@@ -106,6 +109,9 @@ DEFAULT_STEPS = 30
 DEFAULT_GUIDANCE = 7.5
 DEFAULT_SCHEDULER = "DPMSolverMultistepScheduler"
 DEFAULT_STRENGTH = 0.75
+# T5 tokens a flux job gets when it names no `max_sequence_length`
+# (pipelines/flux.py `run` / `run_batched`)
+DEFAULT_FLUX_TEXT_LEN = 512
 
 # --- stage-graph vocabulary (ISSUE 20) -------------------------------
 # Stage-typed placement needs one spelling of stage names on BOTH sides
@@ -401,6 +407,14 @@ def coalesce_key(job: dict) -> tuple | None:
         family = _auto_family(model)
         if family not in _BATCHABLE_FAMILIES:
             return None
+        text_len = None
+        if "max_sequence_length" in params:
+            if family != "flux":
+                return None
+            text_len = int(params["max_sequence_length"])
+            if text_len == DEFAULT_FLUX_TEXT_LEN:
+                # naming the default shares the bucket of leaving it out
+                text_len = None
         if family == "flux":
             # flow-matching txt2img only: no CFG pair, no adapter delta
             # path, no ControlNet branch in the MMDiT program. Steps and
@@ -476,7 +490,7 @@ def coalesce_key(job: dict) -> tuple | None:
         large = bool(params.get("large_model", False))
         return (model, family, height, width, steps, scheduler, guidance,
                 karras, tiny, large, workflow, strength, adapter, cn,
-                stage)
+                stage, text_len)
     except (TypeError, ValueError):
         # hive-controlled values that don't parse: let the single-job
         # path produce its usual fatal envelope for them

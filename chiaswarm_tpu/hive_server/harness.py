@@ -107,7 +107,13 @@ class LocalSwarm:
 
         worker = Worker(
             settings=dataclasses.replace(self.settings, **fields),
-            allocator=SliceAllocator(chips_per_job=self.chips_per_job),
+            # the slice geometry is the settings', as in `worker.main`
+            # (ISSUE 27: this allocator ignored them, so a swarm asked for
+            # `tensor_parallelism=4` got `[data=4, tensor=1]` slices)
+            allocator=SliceAllocator(
+                chips_per_job=self.chips_per_job,
+                tensor_parallelism=self.settings.tensor_parallelism,
+                sequence_parallelism=self.settings.sequence_parallelism),
             hive_uri=self.worker_endpoints(),
         )
         self.workers.append(worker)

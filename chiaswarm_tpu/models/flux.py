@@ -101,6 +101,113 @@ def apply_rope(x, cos, sin):
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape)
 
 
+
+# --- fused-kernel layout under tensor parallelism ---------------------------
+#
+# The checkpoint orders a fused qkv kernel's columns (3, heads, head_dim),
+# `linear1`'s as [q | k | v | mlp] and `linear2`'s rows as [attn | mlp]: a
+# contiguous 1/T of those columns is not 1/T of the heads. Placement on a
+# `tensor=T` mesh (pipelines/flux.py `_place`) permutes them ONCE into T
+# groups, group g holding heads g*H/T ... (g+1)*H/T - 1 (q, k and v of them)
+# and the matching 1/T of the MLP's hidden units, so that a plain
+# `P(None, "tensor")` gives every chip whole heads. The blocks undo it in
+# their split (`head_groups`); with `head_groups=1` the order is the
+# checkpoint's, reshape for reshape.
+
+
+def head_groups_for(config: "FluxConfig", tensor: int) -> int:
+    """How many groups the fused kernels are laid out in on a `tensor`-way
+    mesh: `tensor` when heads and MLP width divide, else 1 (the attention
+    kernels then stay whole on every chip)."""
+    mlp_dim = int(config.hidden_size * config.mlp_ratio)
+    if tensor > 1 and config.num_heads % tensor == 0 \
+            and mlp_dim % tensor == 0:
+        return tensor
+    return 1
+
+
+def _qkv_order(heads: int, head_dim: int, groups: int):
+    """Old column of each new column of a qkv kernel in group order, as
+    [groups, 3 * heads/groups * head_dim]."""
+    import numpy as np
+
+    old = np.arange(3 * heads * head_dim).reshape(
+        3, groups, heads // groups, head_dim)
+    return old.transpose(1, 0, 2, 3).reshape(groups, -1)
+
+
+def grouped_layout(flux_params: dict, config: "FluxConfig", groups: int,
+                   inverse: bool = False) -> dict:
+    """The transformer's tree with its fused kernels (and their biases) in
+    group order, from the checkpoint's order; `inverse=True` goes back.
+    Leaf by leaf on whatever device each leaf lives; other leaves are
+    passed through untouched. `groups=1` is the identity."""
+    import numpy as np
+
+    if groups <= 1:
+        return flux_params
+    h, hd = config.num_heads, config.head_dim
+    mlp_dim = int(config.hidden_size * config.mlp_ratio)
+    qkv_groups = _qkv_order(h, hd, groups)
+    qkv = qkv_groups.reshape(-1)
+    mlp = 3 * h * hd + np.arange(mlp_dim).reshape(groups, -1)
+    linear1 = np.concatenate([qkv_groups, mlp], axis=1).reshape(-1)
+    linear2 = np.concatenate(
+        [np.arange(h * hd).reshape(groups, -1), mlp - 2 * h * hd],
+        axis=1).reshape(-1)
+    if inverse:
+        qkv, linear1, linear2 = (np.argsort(o) for o in (qkv, linear1, linear2))
+
+    def columns(dense, order):
+        return {k: v[..., order] for k, v in dense.items()}
+
+    out = dict(flux_params)
+    for name, block in flux_params.items():
+        if name.startswith("double_blocks_"):
+            block = dict(block)
+            for stream in ("img", "txt"):
+                key = f"{stream}_attn_qkv"
+                block[key] = columns(block[key], qkv)
+            out[name] = block
+        elif name.startswith("single_blocks_"):
+            block = dict(block)
+            block["linear1"] = columns(block["linear1"], linear1)
+            block["linear2"] = {
+                k: (v[linear2] if k == "kernel" else v)
+                for k, v in block["linear2"].items()}
+            out[name] = block
+    return out
+
+
+def _whole(x):
+    """Under a `mesh_scope`: `x` whole on every tensor shard (rows stay on
+    `data` where they divide). The modulation kernels are column-parallel;
+    their output, a few kilobytes a row, is gathered HERE, once — left to
+    the partitioner, the six-way split travels by collective-permute and
+    the residual stream it scales ends up sharded by features, with an
+    activation-sized all-gather ahead of every matmul."""
+    from ..ops.platform import active_mesh, batch_axis
+
+    mesh = active_mesh()
+    if mesh is None:
+        return x
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    spec = P(batch_axis(mesh, x.shape[0]), *([None] * (x.ndim - 1)))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def _split_qkv(out, heads: int, head_dim: int, groups: int):
+    """[B, S, (groups, 3, heads/groups, head_dim)] -> q, k, v [B, S, H, D].
+    Splitting the (tensor-sharded) column axis by its leading `groups`
+    keeps every chip's heads on that chip."""
+    b, s = out.shape[:2]
+    out = out.reshape(b, s, groups, 3, heads // groups, head_dim)
+    return tuple(out[:, :, :, i].reshape(b, s, heads, head_dim)
+                 for i in range(3))
+
+
 class QKNorm(nn.Module):
     """Per-head RMS normalization of q and k (Flux stabilization)."""
 
@@ -138,9 +245,8 @@ class Modulation(nn.Module):
 
     @nn.compact
     def __call__(self, vec):
-        out = nn.Dense(self.n * self.hidden, dtype=self.dtype, name="lin")(
-            nn.silu(vec)
-        )
+        out = _whole(nn.Dense(
+            self.n * self.hidden, dtype=self.dtype, name="lin")(nn.silu(vec)))
         return jnp.split(out[:, None, :], self.n, axis=-1)
 
 
@@ -158,6 +264,7 @@ def _attention(q, k, v, cos, sin):
 class DoubleStreamBlock(nn.Module):
     config: FluxConfig
     dtype: jnp.dtype = jnp.float32
+    head_groups: int = 1  # layout of the fused kernels (grouped_layout)
 
     @nn.compact
     def __call__(self, img, txt, vec, cos, sin):
@@ -179,10 +286,8 @@ class DoubleStreamBlock(nn.Module):
             )(x)
 
         def qkv(x, name):
-            b, s, _ = x.shape
             out = nn.Dense(3 * h * hd, dtype=self.dtype, name=f"{name}_attn_qkv")(x)
-            q, k, v = jnp.split(out.reshape(b, s, 3, h, hd), 3, axis=2)
-            q, k, v = (t[:, :, 0] for t in (q, k, v))
+            q, k, v = _split_qkv(out, h, hd, self.head_groups)
             q, k = QKNorm(dtype=self.dtype, name=f"{name}_attn_norm")(q, k)
             return q, k, v
 
@@ -222,6 +327,7 @@ class DoubleStreamBlock(nn.Module):
 class SingleStreamBlock(nn.Module):
     config: FluxConfig
     dtype: jnp.dtype = jnp.float32
+    head_groups: int = 1  # layout of linear1 / linear2 (grouped_layout)
 
     @nn.compact
     def __call__(self, x, vec, cos, sin):
@@ -239,13 +345,20 @@ class SingleStreamBlock(nn.Module):
         fused = nn.Dense(
             3 * h * hd + mlp_dim, dtype=self.dtype, name="linear1"
         )(y)
-        qkv_part, mlp_part = jnp.split(fused, [3 * h * hd], axis=-1)
-        q, k, v = jnp.split(qkv_part.reshape(b, s, 3, h, hd), 3, axis=2)
-        q, k, v = (t[:, :, 0] for t in (q, k, v))
+        # columns: `head_groups` groups of [q | k | v | mlp], each a 1/groups
+        # of the heads and of the MLP's hidden units
+        g = self.head_groups
+        qkv_part, mlp_part = jnp.split(
+            fused.reshape(b, s, g, -1), [3 * h * hd // g], axis=-1)
+        q, k, v = _split_qkv(qkv_part.reshape(b, s, -1), h, hd, g)
         q, k = QKNorm(dtype=self.dtype, name="norm")(q, k)
         attn = _attention(q, k, v, cos, sin)
+        # rows of linear2 in the same groups: [attn | mlp] of each
         out = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="linear2")(
-            jnp.concatenate([attn, nn.gelu(mlp_part, approximate=True)], axis=-1)
+            jnp.concatenate(
+                [attn.reshape(b, s, g, -1),
+                 nn.gelu(mlp_part, approximate=True)], axis=-1,
+            ).reshape(b, s, -1)
         )
         return x + gate * out
 
@@ -253,6 +366,7 @@ class SingleStreamBlock(nn.Module):
 class FluxTransformer(nn.Module):
     config: FluxConfig
     dtype: jnp.dtype = jnp.float32
+    head_groups: int = 1  # layout of the blocks' fused kernels
 
     @nn.compact
     def __call__(self, img, img_ids, txt, txt_ids, timesteps, pooled,
@@ -282,19 +396,21 @@ class FluxTransformer(nn.Module):
 
         for i in range(cfg.depth_double):
             img, txt = DoubleStreamBlock(
-                cfg, dtype=self.dtype, name=f"double_blocks_{i}"
+                cfg, dtype=self.dtype, head_groups=self.head_groups,
+                name=f"double_blocks_{i}"
             )(img, txt, vec, cos, sin)
 
         x = jnp.concatenate([txt, img], axis=1)
         for i in range(cfg.depth_single):
             x = SingleStreamBlock(
-                cfg, dtype=self.dtype, name=f"single_blocks_{i}"
+                cfg, dtype=self.dtype, head_groups=self.head_groups,
+                name=f"single_blocks_{i}"
             )(x, vec, cos, sin)
         x = x[:, txt.shape[1]:]
 
         shift, scale = jnp.split(
-            nn.Dense(2 * cfg.hidden_size, dtype=self.dtype,
-                     name="final_layer_mod")(nn.silu(vec))[:, None, :],
+            _whole(nn.Dense(2 * cfg.hidden_size, dtype=self.dtype,
+                            name="final_layer_mod")(nn.silu(vec)))[:, None, :],
             2, axis=-1,
         )
         x = nn.LayerNorm(
@@ -348,8 +464,8 @@ class FluxFinal(nn.Module):
     def __call__(self, x, vec):
         cfg = self.config
         shift, scale = jnp.split(
-            nn.Dense(2 * cfg.hidden_size, dtype=self.dtype,
-                     name="final_layer_mod")(nn.silu(vec))[:, None, :],
+            _whole(nn.Dense(2 * cfg.hidden_size, dtype=self.dtype,
+                            name="final_layer_mod")(nn.silu(vec)))[:, None, :],
             2, axis=-1,
         )
         x = nn.LayerNorm(

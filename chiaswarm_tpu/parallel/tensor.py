@@ -48,6 +48,54 @@ def unet_partition_rules():
     return _UNET_RULES
 
 
+def _column(pattern: str) -> tuple[tuple[str, P], ...]:
+    """A column-parallel Dense: its kernel's columns and its bias."""
+    return ((rf".*{pattern}/kernel$", column_parallel()),
+            (rf".*{pattern}/bias$", P(TENSOR_AXIS)))
+
+
+def flux_partition_rules(head_aligned: bool = True):
+    """The MMDiT (models/flux.py), Megatron-style. Column-parallel: the
+    fused qkv kernels and `linear1`, the MLPs' first layer, and the
+    modulation kernels (a quarter of the transformer's parameters; their
+    output, a few kilobytes a row, is gathered). Row-parallel, one
+    all-reduce each: `*_attn_proj`, `*_mlp_2`, `linear2`. `img_in`,
+    `txt_in`, the embedders, the qk-norm scales and the last projection
+    stay whole on every chip.
+
+    The fused kernels' columns must be in group order
+    (`models.flux.grouped_layout`) for a contiguous share to be whole heads;
+    with `head_aligned=False` (a head count the tensor axis does not
+    divide) the attention-side kernels stay replicated."""
+    rules = (
+        *_column(r"(img|txt)_mlp_0"),
+        (r".*(img|txt)_mlp_2/kernel$", row_parallel()),
+        *_column(r"((img|txt)_mod|modulation)/lin"),
+        *_column(r"final_layer_mod"),
+    )
+    if head_aligned:
+        rules += (
+            *_column(r"(img|txt)_attn_qkv"),
+            (r".*(img|txt)_attn_proj/kernel$", row_parallel()),
+            *_column(r"single_blocks_\d+/linear1"),
+            (r".*single_blocks_\d+/linear2/kernel$", row_parallel()),
+        )
+    return rules
+
+
+def t5_partition_rules():
+    """The T5 encoder (models/t5.py): q / k / v and the gated FFN's two
+    input kernels by columns (heads are contiguous there), `o` and `wo` by
+    rows. The embedding table, the norms and the relative-position table
+    stay whole."""
+    return (
+        (r".*attention/(q|k|v)/kernel$", column_parallel()),
+        (r".*attention/o/kernel$", row_parallel()),
+        (r".*wi_[01]/kernel$", column_parallel()),
+        (r".*wo/kernel$", row_parallel()),
+    )
+
+
 def _spec_for(path: str, rules) -> P:
     for pattern, spec in rules:
         if re.fullmatch(pattern, path):
@@ -74,8 +122,9 @@ def partition_spec_tree(params, rules=_UNET_RULES):
     return jax.tree_util.tree_map_with_path(lookup, params)
 
 
-def shard_params(mesh: Mesh, params, rules=_UNET_RULES):
-    """Place a param tree on the mesh per the partition rules.
+def sharding_tree(mesh: Mesh, params, rules=_UNET_RULES):
+    """The tree of `NamedSharding`s the rules give `params` (arrays or
+    shapes) on `mesh`.
 
     A leaf whose dim doesn't divide the mesh axis falls back to replication
     (e.g. head dims not divisible by the tensor axis) instead of erroring
@@ -83,11 +132,27 @@ def shard_params(mesh: Mesh, params, rules=_UNET_RULES):
     """
     specs = partition_spec_tree(params, rules)
 
-    def place(x, spec):
+    def sharding(x, spec):
         for d, axis in enumerate(spec):
             if axis is not None and x.shape[d] % mesh.shape[axis] != 0:
                 spec = P()
                 break
-        return jax.device_put(x, NamedSharding(mesh, spec))
+        return NamedSharding(mesh, spec)
 
-    return jax.tree_util.tree_map(place, params, specs)
+    return jax.tree_util.tree_map(sharding, params, specs)
+
+
+def shard_params(mesh: Mesh, params, rules=_UNET_RULES):
+    """Place a param tree on the mesh per the partition rules."""
+    return jax.device_put(params, sharding_tree(mesh, params, rules))
+
+
+def largest_device_bytes(params) -> int:
+    """Bytes of a placed tree on the chip that holds most of it (this
+    process's shards): a tree whose rules fell through to replicated reads
+    its whole size here."""
+    held: dict = {}
+    for leaf in jax.tree_util.tree_leaves(params):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            held[shard.device] = held.get(shard.device, 0) + shard.data.nbytes
+    return max(held.values(), default=0)

@@ -25,6 +25,14 @@ PROGRAM_EVICTED = telemetry.counter(
     ("kind",),
 )
 
+RESIDENT_PARAM_BYTES = telemetry.gauge(
+    "swarm_resident_param_bytes",
+    "Bytes of a pipeline's placed parameter tree on the chip that holds "
+    "most of it, set at placement: a partition rule that falls through to "
+    "replicated reads the whole tree here, before it is an OOM",
+    ("model",),
+)
+
 
 def program_cache_cap() -> int:
     """Settings.program_cache_max at call time (env-overridable,

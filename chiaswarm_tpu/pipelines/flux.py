@@ -37,6 +37,8 @@ from ..models.flux import (
     FluxHead,
     FluxTransformer,
     SingleStreamBlock,
+    grouped_layout,
+    head_groups_for,
     patchify,
     rope_frequencies,
     unpatchify,
@@ -44,11 +46,13 @@ from ..models.flux import (
 from ..models.t5 import TINY_T5, T5Config, T5Encoder
 from ..models.tokenizer import load_tokenizer
 from ..models.vae import AutoencoderKL
+from ..ops.platform import mesh_scope
 from ..parallel.mesh import batch_sharding, make_mesh, replicated
 from ..registry import register_family
 from ..schedulers import FlowMatchEulerScheduler
 from ..schedulers.common import SchedulerConfig
 from ..settings import load_settings
+from ..telemetry import Span
 from ..weights import require_weights_present
 
 logger = logging.getLogger(__name__)
@@ -57,12 +61,16 @@ logger = logging.getLogger(__name__)
 def _flux_configs(model_name: str):
     """(flux_cfg, t5_cfg, clip_cfg, vae_cfg, default_size, default_steps,
     dynamic_shift). schnell is distilled on UNSHIFTED sigmas (shift=1);
-    dev uses resolution-dependent dynamic shifting (see _sigma_shift)."""
+    dev uses resolution-dependent dynamic shifting (see _sigma_shift).
+
+    Names with `tiny` are the tiny stand-ins; `test/FLUX.1-dev` and
+    `test/FLUX.1-schnell` are the published geometry with random weights
+    allowed (as `test/stable-diffusion-xl-base-1.0` is SDXL's)."""
     import dataclasses
 
     name = model_name.lower()
     schnell = "schnell" in name
-    if "tiny" in name or name.startswith("test/"):
+    if "tiny" in name:
         flux = TINY_FLUX
         if schnell:
             flux = dataclasses.replace(flux, guidance_embed=False)
@@ -98,7 +106,13 @@ class FluxPipeline:
 
     def __init__(self, model_name: str, chipset=None, dtype=None,
                  allow_random_init: bool = False,
-                 streaming: bool | None = None):
+                 streaming: bool | None = None, weights=None):
+        """`weights`, where given, takes the place of the checkpoint and
+        of the seeded host init: `weights(shapes, shardings)` returns the
+        parameter tree with every leaf already on this pipeline's mesh
+        (`param_shapes` / `param_shardings`), so a caller can make or load
+        the leaves shard by shard and the whole tree never sits on the
+        host or on one chip."""
         self.model_name = model_name
         self.chipset = chipset
         (self.config, t5_cfg, clip_cfg, vae_cfg, self.default_size,
@@ -107,17 +121,21 @@ class FluxPipeline:
             dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
         self.dtype = dtype
 
-        self.transformer = FluxTransformer(self.config, dtype=dtype)
-        self.t5 = T5Encoder(t5_cfg, dtype=dtype)
-        self.clip = CLIPTextEncoder(clip_cfg, dtype=dtype)
-        self.vae = AutoencoderKL(vae_cfg, dtype=dtype)
-        self.latent_factor = 2 ** (len(vae_cfg.block_out_channels) - 1)
-        self.latent_channels = vae_cfg.latent_channels
         self.mesh = (
             chipset.mesh() if chipset is not None else make_mesh(jax.devices()[:1])
         )
         self.data_parts = self.mesh.shape.get("data", 1)
         self.tensor_parts = self.mesh.shape.get("tensor", 1)
+        # the placed layout of the blocks' fused kernels: one group of
+        # heads (and of MLP units) a tensor shard (models/flux.py)
+        self.head_groups = head_groups_for(self.config, self.tensor_parts)
+        self.transformer = FluxTransformer(
+            self.config, dtype=dtype, head_groups=self.head_groups)
+        self.t5 = T5Encoder(t5_cfg, dtype=dtype)
+        self.clip = CLIPTextEncoder(clip_cfg, dtype=dtype)
+        self.vae = AutoencoderKL(vae_cfg, dtype=dtype)
+        self.latent_factor = 2 ** (len(vae_cfg.block_out_channels) - 1)
+        self.latent_channels = vae_cfg.latent_channels
 
         if streaming is None:
             # auto: page transformer blocks from host RAM when the model
@@ -140,7 +158,16 @@ class FluxPipeline:
         self._stream_int8 = False  # set for real in _place_streaming
 
         t0 = time.perf_counter()
-        self.params = self._load_params(allow_random_init)
+        if weights is not None:
+            if self.streaming:
+                raise ValueError(
+                    "a weight-streaming flux pages its blocks from the "
+                    "host: it takes no pre-placed tree")
+            shapes = self.param_shapes()
+            self.params = self._adopt(
+                weights(shapes, self.param_shardings(shapes)), shapes)
+        else:
+            self.params = self._load_params(allow_random_init)
         model_dir = self._model_dir()
         self.clip_tokenizer = load_tokenizer(model_dir, clip_cfg.vocab_size)
         self.t5_tokenizer = _load_t5_tokenizer(model_dir, t5_cfg.vocab_size)
@@ -158,22 +185,99 @@ class FluxPipeline:
         d = root / self.model_name
         return d if d.is_dir() else None
 
+    # --- the parameter tree: shapes, shardings, placement ---
+
+    def _init(self):
+        """The four modules' seeded init, as `_load_params` runs it on the
+        host and `param_shapes` traces it."""
+        cfg = self.config
+        seed = zlib.crc32(self.model_name.encode())
+        k1, k2, k3, k4 = jax.random.split(jax.random.key(seed), 4)
+        s_img, s_txt = 4, 8
+        # an init's values do not depend on `head_groups`: the tree reads
+        # as the checkpoint-order one, and `_place` regroups it
+        flux_params = self.transformer.init(
+            k1,
+            jnp.zeros((1, s_img, cfg.in_channels)),
+            jnp.zeros((1, s_img, 3), jnp.int32),
+            jnp.zeros((1, s_txt, cfg.context_dim)),
+            jnp.zeros((1, s_txt, 3), jnp.int32),
+            jnp.zeros((1,)),
+            jnp.zeros((1, cfg.pooled_dim)),
+            guidance=jnp.ones((1,)),
+        )["params"]
+        t5_params = self.t5.init(k2, jnp.zeros((1, 8), jnp.int32))["params"]
+        clip_params = self.clip.init(k3, jnp.zeros((1, 77), jnp.int32))["params"]
+        hw = 2 * self.latent_factor
+        vae_params = self.vae.init(k4, jnp.zeros((1, hw, hw, 3)))["params"]
+        return {"flux": flux_params, "t5": t5_params, "clip": clip_params,
+                "vae": vae_params}
+
+    def param_shapes(self):
+        """The resident parameter tree as `jax.ShapeDtypeStruct`s in the
+        serving dtype (`jax.eval_shape` of the modules' own init: nothing
+        is allocated). Leaves are in the placed layout: on a tensor mesh
+        the blocks' fused kernels are in group order
+        (`models.flux.grouped_layout(tree, config, head_groups)` takes a
+        checkpoint-order tree there, leaf by leaf)."""
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, self.dtype),
+            jax.eval_shape(self._init))
+
+    def param_shardings(self, shapes=None):
+        """The tree of `NamedSharding`s on this pipeline's mesh, leaf for
+        leaf of `param_shapes()`: the MMDiT and T5 by their partition
+        rules (parallel/tensor.py), CLIP-L and the VAE whole on every
+        chip."""
+        from ..parallel.tensor import (
+            flux_partition_rules,
+            sharding_tree,
+            t5_partition_rules,
+        )
+
+        shapes = self.param_shapes() if shapes is None else shapes
+        whole = replicated(self.mesh)
+        if self.tensor_parts <= 1:
+            return jax.tree_util.tree_map(lambda _: whole, shapes)
+        rules = {"flux": flux_partition_rules(self.head_groups > 1),
+                 "t5": t5_partition_rules()}
+        return {
+            key: (sharding_tree(self.mesh, tree, rules[key])
+                  if key in rules
+                  else jax.tree_util.tree_map(lambda _: whole, tree))
+            for key, tree in shapes.items()
+        }
+
+    def _adopt(self, params, shapes):
+        """A tree placed elsewhere (`weights=`): held to `param_shapes`,
+        then counted like one placed here."""
+        got = jax.tree_util.tree_map(lambda x: tuple(x.shape), params)
+        want = jax.tree_util.tree_map(lambda x: tuple(x.shape), shapes)
+        if got != want:
+            raise ValueError(
+                f"{self.model_name}: the weights handed in are not the "
+                "tree param_shapes() describes")
+        return self._count_resident(params)
+
+    def _count_resident(self, params):
+        from ..parallel.tensor import largest_device_bytes
+        from .common import RESIDENT_PARAM_BYTES
+
+        RESIDENT_PARAM_BYTES.set(
+            largest_device_bytes(params), model=self.model_name)
+        return params
+
     def _place(self, params):
+        """A host tree in the checkpoint's order -> the serving dtype, the
+        placed layout, this mesh."""
         if self.streaming:
-            return self._place_streaming(params)
+            return self._count_resident(self._place_streaming(params))
+        params = dict(params, flux=grouped_layout(
+            params["flux"], self.config, self.head_groups))
         cast = lambda x: jnp.asarray(x, self.dtype)
         params = jax.tree_util.tree_map(cast, params)
-        if self.tensor_parts <= 1:
-            return jax.device_put(params, replicated(self.mesh))
-        from ..parallel.tensor import shard_params
-
-        placed = {}
-        for key, tree in params.items():
-            if key == "vae":
-                placed[key] = jax.device_put(tree, replicated(self.mesh))
-            else:
-                placed[key] = shard_params(self.mesh, tree)
-        return placed
+        return self._count_resident(
+            jax.device_put(params, self.param_shardings(params)))
 
     def _place_streaming(self, params):
         """Resident tail (T5/CLIP/VAE + flux head/final) on the chip;
@@ -223,29 +327,11 @@ class FluxPipeline:
         else:
             require_weights_present(self.model_name, None, allow_random_init)
 
-        cfg = self.config
-        seed = zlib.crc32(self.model_name.encode())
-        k1, k2, k3, k4 = jax.random.split(jax.random.key(seed), 4)
         with jax.default_device(jax.local_devices(backend="cpu")[0]):
-            s_img, s_txt = 4, 8
-            flux_params = self.transformer.init(
-                k1,
-                jnp.zeros((1, s_img, cfg.in_channels)),
-                jnp.zeros((1, s_img, 3), jnp.int32),
-                jnp.zeros((1, s_txt, cfg.context_dim)),
-                jnp.zeros((1, s_txt, 3), jnp.int32),
-                jnp.zeros((1,)),
-                jnp.zeros((1, cfg.pooled_dim)),
-                guidance=jnp.ones((1,)),
-            )["params"]
-            t5_params = self.t5.init(k2, jnp.zeros((1, 8), jnp.int32))["params"]
-            clip_params = self.clip.init(k3, jnp.zeros((1, 77), jnp.int32))["params"]
-            hw = 2 * self.latent_factor
-            vae_params = self.vae.init(k4, jnp.zeros((1, hw, hw, 3)))["params"]
-        return self._place({
-            "flux": flux_params, "t5": t5_params, "clip": clip_params,
-            "vae": vae_params,
-        })
+            # one program, not an eager op at a time: seconds for a tiny
+            # stand-in where the eager init took a quarter of a minute
+            params = jax.jit(self._init)()
+        return self._place(params)
 
     def _convert_params(self, model_dir: Path) -> dict:
         from ..models.conversion import (
@@ -279,6 +365,32 @@ class FluxPipeline:
         context = self.t5.apply({"params": params["t5"]}, t5_ids)
         return context, pooled
 
+    def _encode(self, params, clip_ids, t5_ids):
+        with mesh_scope(self.mesh):
+            return self._encode_program(params, clip_ids, t5_ids)
+
+    def _decode_rows(self, vae_params, latents):
+        """Latents -> uint8 pixels. On a tensor slice every chip decodes
+        every row, and the decoder's 1024^2 stages in float32 with its
+        mid-block's 16384^2 scores are ~3.3 GB of temporaries a row: a
+        2-row gang decoded at once was 6.65 GB beside 9 GB of weights on a
+        16 GB chip (the compile for four described v5e chips, PR 27). So
+        there, and only there, the rows are decoded one at a time inside
+        the program; the decode is a percent of a pass. Elsewhere the rows
+        stay one batch, sharded over `data` as before."""
+        vae = self.vae
+
+        def pixels_of(latents):
+            pixels = vae.apply(
+                {"params": vae_params}, latents, method=vae.decode)
+            return (
+                (pixels.astype(jnp.float32) + 1.0) * 127.5
+            ).clip(0.0, 255.0).round().astype(jnp.uint8)
+
+        if self.tensor_parts <= 1:
+            return pixels_of(latents)
+        return jax.lax.map(lambda row: pixels_of(row[None])[0], latents)
+
     # --- sampling program ---
 
     def _program(self, key: tuple):
@@ -294,7 +406,6 @@ class FluxPipeline:
         schedule = scheduler.schedule(steps)
         sigmas = jnp.asarray(schedule.sigmas)
         transformer = self.transformer
-        vae = self.vae
         latent_c = self.latent_channels
 
         def run(params, init_rng, context, pooled, guidance):
@@ -324,12 +435,7 @@ class FluxPipeline:
             img, _ = jax.lax.scan(body, img.astype(jnp.float32),
                                   jnp.arange(steps))
             latents = unpatchify(img, lh, lw).astype(self.dtype)
-            pixels = vae.apply(
-                {"params": params["vae"]}, latents, method=vae.decode
-            )
-            return (
-                (pixels.astype(jnp.float32) + 1.0) * 127.5
-            ).clip(0.0, 255.0).round().astype(jnp.uint8)
+            return self._decode_rows(params["vae"], latents)
 
         program = jax.jit(run)
         with self._jit_lock:
@@ -341,6 +447,28 @@ class FluxPipeline:
                 self._programs.popitem(last=False)
                 PROGRAM_EVICTED.inc(kind="program")
         return program
+
+    def _canvas(self, height: int, width: int):
+        """(height, width, latent rows, latent columns): the latent grid
+        must patchify 2x2, so the canvas snaps to /16 of pixel space."""
+        snap = self.latent_factor * 2
+        height, width = (max(snap, (d // snap) * snap) for d in (height, width))
+        return (height, width, height // self.latent_factor,
+                width // self.latent_factor)
+
+    def denoise_program(self, height: int, width: int, rows: int,
+                        steps: int, txt_len: int = 512,
+                        batched: bool = True):
+        """The jitted denoise + decode program `run_batched` (`batched`:
+        operands `params, latents [rows, h/8, w/8, C] f32, context, pooled,
+        guidance`) or `run` (`params, rng key, context, pooled, guidance`)
+        calls for this canvas, row count and step count. Trace, lower or
+        call it under `mesh_scope(self.mesh)`."""
+        _, _, lh, lw = self._canvas(height, width)
+        if batched:
+            return self._batched_program(
+                ("batched", lh, lw, rows, steps, txt_len))
+        return self._program((lh, lw, rows, steps, txt_len))
 
     # --- weight-streaming sampler (host-RAM paged transformer blocks) ---
 
@@ -478,20 +606,16 @@ class FluxPipeline:
         kwargs.pop("chipset", None)
         kwargs.pop("scheduler_type", None)  # flow matching is the family's solver
 
-        height = int(kwargs.pop("height", None) or self.default_size)
-        width = int(kwargs.pop("width", None) or self.default_size)
-        # latent grid must patchify 2x2: canvas snaps to /16 of pixel space
-        snap = self.latent_factor * 2
-        height, width = (max(snap, (d // snap) * snap) for d in (height, width))
-        lh, lw = height // self.latent_factor, width // self.latent_factor
+        height, width, lh, lw = self._canvas(
+            int(kwargs.pop("height", None) or self.default_size),
+            int(kwargs.pop("width", None) or self.default_size))
 
-        t0 = time.perf_counter()
-        clip_ids = jnp.asarray(self.clip_tokenizer([prompt] * n_images))
-        t5_ids = jnp.asarray(
-            self.t5_tokenizer([prompt] * n_images, max_seq), jnp.int32
-        )
-        context, pooled = self._encode_program(params, clip_ids, t5_ids)
-        timings["text_encode_s"] = round(time.perf_counter() - t0, 3)
+        with Span("text_encode", timings):
+            clip_ids = jnp.asarray(self.clip_tokenizer([prompt] * n_images))
+            t5_ids = jnp.asarray(
+                self.t5_tokenizer([prompt] * n_images, max_seq), jnp.int32
+            )
+            context, pooled = self._encode(params, clip_ids, t5_ids)
 
         def place_b(x):
             if self.data_parts > 1 and x.shape[0] % self.data_parts == 0:
@@ -503,25 +627,25 @@ class FluxPipeline:
 
         rng, init_rng = jax.random.split(rng)
         if self.streaming:
-            t0 = time.perf_counter()
-            pixels = jax.block_until_ready(
-                self._run_streaming(
-                    lh, lw, n_images, steps, int(t5_ids.shape[1]),
-                    init_rng, context, pooled, guidance,
+            with Span("denoise", timings, key="denoise_decode_s"), \
+                    mesh_scope(self.mesh):
+                pixels = jax.block_until_ready(
+                    self._run_streaming(
+                        lh, lw, n_images, steps, int(t5_ids.shape[1]),
+                        init_rng, context, pooled, guidance,
+                    )
                 )
-            )
-            timings["denoise_decode_s"] = round(time.perf_counter() - t0, 3)
         else:
             key = (lh, lw, n_images, steps, int(t5_ids.shape[1]))
-            t0 = time.perf_counter()
-            program = self._program(key)
-            timings["trace_s"] = round(time.perf_counter() - t0, 3)
-
-            t0 = time.perf_counter()
-            pixels = jax.block_until_ready(
-                program(params, init_rng, context, pooled, guidance)
-            )
-            timings["denoise_decode_s"] = round(time.perf_counter() - t0, 3)
+            with Span("compile", timings, key="trace_s"):
+                program = self._program(key)
+            # jit traces on the first call: the kernel routing (a
+            # trace-time branch on the mesh) lands in the compiled program
+            with Span("denoise", timings, key="denoise_decode_s"), \
+                    mesh_scope(self.mesh):
+                pixels = jax.block_until_ready(
+                    program(params, init_rng, context, pooled, guidance)
+                )
 
         from PIL import Image
 
@@ -568,7 +692,6 @@ class FluxPipeline:
         )
         sigmas = jnp.asarray(scheduler.schedule(steps).sigmas)
         transformer = self.transformer
-        vae = self.vae
 
         def run(params, latents, context, pooled, guidance):
             img, img_ids = patchify(latents.astype(self.dtype))
@@ -594,12 +717,7 @@ class FluxPipeline:
             img, _ = jax.lax.scan(body, img.astype(jnp.float32),
                                   jnp.arange(steps))
             latents = unpatchify(img, lh, lw).astype(self.dtype)
-            pixels = vae.apply(
-                {"params": params["vae"]}, latents, method=vae.decode
-            )
-            return (
-                (pixels.astype(jnp.float32) + 1.0) * 127.5
-            ).clip(0.0, 255.0).round().astype(jnp.uint8)
+            return self._decode_rows(params["vae"], latents)
 
         program = jax.jit(run)
         with self._jit_lock:
@@ -614,6 +732,7 @@ class FluxPipeline:
 
     def run_batched(self, requests: list[dict], *, height=None, width=None,
                     num_inference_steps=None, guidance_scale=3.5,
+                    max_sequence_length: int = 512,
                     pipeline_type: str = "FluxPipeline", **_shared):
         """Coalesced flux txt2img: N independent requests, ONE padded
         jitted flow-matching pass (batching.py design; coalesce_key
@@ -648,12 +767,9 @@ class FluxPipeline:
         timings: dict[str, float] = {}
         steps = int(num_inference_steps or self.default_steps)
         guidance_scale = float(guidance_scale)
-        max_seq = 512
+        max_seq = int(max_sequence_length)
         height = int(height or self.default_size)
-        width = int(width or height)
-        snap = self.latent_factor * 2
-        height, width = (max(snap, (d // snap) * snap) for d in (height, width))
-        lh, lw = height // self.latent_factor, width // self.latent_factor
+        height, width, lh, lw = self._canvas(height, int(width or height))
 
         counts = [
             max(int(r.get("num_images_per_prompt", 1) or 1), 1)
@@ -665,15 +781,15 @@ class FluxPipeline:
 
         # --- conditioning: every row carries its own prompt; padding
         # rows are empty prompts whose outputs are discarded ---
-        t0 = time.perf_counter()
-        prompts: list[str] = []
-        for r, n in zip(requests, counts):
-            prompts.extend([str(r.get("prompt") or "")] * n)
-        prompts.extend([""] * pad_rows)
-        clip_ids = jnp.asarray(self.clip_tokenizer(prompts))
-        t5_ids = jnp.asarray(self.t5_tokenizer(prompts, max_seq), jnp.int32)
-        context, pooled = self._encode_program(params, clip_ids, t5_ids)
-        timings["text_encode_s"] = round(time.perf_counter() - t0, 3)
+        with Span("text_encode", timings):
+            prompts: list[str] = []
+            for r, n in zip(requests, counts):
+                prompts.extend([str(r.get("prompt") or "")] * n)
+            prompts.extend([""] * pad_rows)
+            clip_ids = jnp.asarray(self.clip_tokenizer(prompts))
+            t5_ids = jnp.asarray(
+                self.t5_tokenizer(prompts, max_seq), jnp.int32)
+            context, pooled = self._encode(params, clip_ids, t5_ids)
 
         def place_b(x):
             if self.data_parts > 1 and x.shape[0] % self.data_parts == 0:
@@ -701,15 +817,13 @@ class FluxPipeline:
         latents = place_b(jnp.concatenate(blocks, axis=0))
 
         key = ("batched", lh, lw, padded, steps, int(t5_ids.shape[1]))
-        t0 = time.perf_counter()
-        program = self._batched_program(key)
-        timings["trace_s"] = round(time.perf_counter() - t0, 3)
-
-        t0 = time.perf_counter()
-        pixels = jax.block_until_ready(
-            program(params, latents, context, pooled, guidance)
-        )
-        timings["denoise_decode_s"] = round(time.perf_counter() - t0, 3)
+        with Span("compile", timings, key="trace_s"):
+            program = self._batched_program(key)
+        with Span("denoise", timings, key="denoise_decode_s"), \
+                mesh_scope(self.mesh):
+            pixels = jax.block_until_ready(
+                program(params, latents, context, pooled, guidance)
+            )
 
         from PIL import Image
 

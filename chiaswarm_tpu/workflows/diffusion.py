@@ -291,6 +291,11 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
             "control_guidance_end": float(
                 shared.get("control_guidance_end", 1.0)),
         }
+    text_kwargs = {}
+    if shared.get("max_sequence_length") is not None:
+        # a flux group's shared T5 token budget (a coalesce-key dimension)
+        text_kwargs["max_sequence_length"] = int(
+            shared["max_sequence_length"])
     results = []
     chunks = list(chunk_by_rows(counts, max_rows))
     if len(chunks) > 1:
@@ -313,6 +318,7 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
             pipeline_type=pipeline_type,
             strength=strength,
             **cn_kwargs,
+            **text_kwargs,
         ))
 
     out = []

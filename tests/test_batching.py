@@ -120,6 +120,18 @@ def test_flux_guidance_and_steps_split_the_bucket():
     assert coalesce_key(flux_job(num_inference_steps=4)) != base
 
 
+def test_flux_text_budget_is_a_key_dimension():
+    """`max_sequence_length` is a static shape of flux's batched program:
+    naming the default (512) shares the bucket of leaving it out, another
+    budget is another bucket, and a UNet job that names it stays solo."""
+    base = coalesce_key(flux_job())
+    assert coalesce_key(
+        flux_job(parameters={"max_sequence_length": 512})) == base
+    short = coalesce_key(flux_job(parameters={"max_sequence_length": 256}))
+    assert short is not None and short != base
+    assert coalesce_key(job(parameters={"max_sequence_length": 256})) is None
+
+
 # --- ISSUE 13: adapter-aware coalescing ---
 
 
@@ -691,3 +703,63 @@ def test_flush_stamps_linger_split_into_trace_context():
         assert "trace" not in group[1]
 
     asyncio.run(scenario())
+
+
+def test_linger_grows_with_the_keys_pass_not_with_its_compile(monkeypatch):
+    """PR 27: a batchmate one poll away is worth 1/40 of a long pass. The
+    linger of a key is the fixed one until two passes have been seen (the
+    first carries the compile), then LINGER_PASS_SHARE of the SHORTEST,
+    never less than the fixed one; a pass's jobs count once."""
+    from chiaswarm_tpu import batching
+
+    async def one_pass(b, ids, seconds, clock):
+        for job_id in ids:
+            await b.put(job(id=job_id))
+        group = await asyncio.wait_for(b.get(), 1.0)
+        clock[0] += seconds
+        for member in group:
+            b.task_done(member)
+
+    async def scenario(monkeypatch_clock):
+        b = BatchScheduler(linger_s=0.01, max_coalesce=2)
+        key = coalesce_key(job())
+        assert b.linger_for(key) == 0.01
+        await one_pass(b, ["a", "b"], 100.0, monkeypatch_clock)  # compile
+        assert b.linger_for(key) == 0.01  # one pass of two jobs, not two
+        await one_pass(b, ["c", "d"], 8.0, monkeypatch_clock)
+        assert b.linger_for(key) == pytest.approx(
+            8.0 * batching.LINGER_PASS_SHARE)
+        await one_pass(b, ["e", "f"], 0.2, monkeypatch_clock)
+        assert b.linger_for(key) == 0.01  # a short pass: the fixed linger
+        assert not b._claimed_at
+
+    import types
+
+    clock = [1000.0]
+    monkeypatch.setattr(batching, "time", types.SimpleNamespace(
+        monotonic=lambda: clock[0]))  # the scheduler's clock only
+    run(scenario(clock))
+
+
+def test_a_group_the_linger_formed_says_which_jobs_rode_together():
+    """PR 27: jobs the worker joined itself carry one `trace.gang` (`by:
+    worker`) in their trace context, as a hive gang does, so the envelopes
+    tell the passes that ran; a lone job gets none."""
+    def traced(job_id):
+        return job(id=job_id, trace={"id": job_id, "attempt": 1})
+
+    async def scenario():
+        b = BatchScheduler(linger_s=0.02, max_coalesce=8)
+        await b.put(traced("p-1"))
+        await b.put(traced("p-2"))
+        pair = await asyncio.wait_for(b.get(), 1.0)
+        await b.put(traced("lone"))
+        lone = await asyncio.wait_for(b.get(), 1.0)
+        return pair, lone
+
+    pair, lone = run(scenario())
+    gangs = [member["trace"]["gang"] for member in pair]
+    assert gangs[0]["id"] == gangs[1]["id"]
+    assert [g["index"] for g in gangs] == [0, 1]
+    assert all(g["size"] == 2 and g["by"] == "worker" for g in gangs)
+    assert "gang" not in lone[0]["trace"]

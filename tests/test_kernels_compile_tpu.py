@@ -61,6 +61,10 @@ def _shape(sharding, shape, dtype=jnp.bfloat16):
     pytest.param(2, 2304, 9216, 5, 64, id="per-chip-sd21-rows-2304q-9216kv"),
     pytest.param(2, 1024, 1024, 5, 64, id="per-chip-sdxl-5-of-20-heads"),
     pytest.param(1, 4608, 4608, 24, 128, id="flux-4608x24x128"),
+    # FLUX.1-dev on [data=1, tensor=4]: six of its 24 heads a chip, a gang
+    # of two at 1024^2, and the benchmark's one-row evaluation at 512^2
+    pytest.param(2, 4608, 4608, 6, 128, id="per-chip-flux-6-of-24-heads"),
+    pytest.param(1, 1536, 1536, 6, 128, id="per-chip-flux-512sq-canvas"),
 ])
 def test_flash_attention_compiles_for_v5e(v5e, b, sq, skv, h, d):
     """With the blocks the rule gives the shape, as the program calls it."""
