@@ -167,8 +167,11 @@ class BatchScheduler:
         # coalescing appetite, not 1
         self._ready_rows = 0
         self._executing_rows = 0  # rows claimed off the board, not done
+        # jobs whose pass has ended and whose envelope is not spooled yet
+        # (the worker packages a pass's images after it frees the slice)
+        self._undelivered = 0
         # job id -> the instant its work item was claimed, and coalesce key
-        # -> [passes seen, the shortest's seconds from claim to task_done,
+        # -> [passes seen, the shortest's seconds from claim to pass_done,
         # the last one's claim instant]
         self._claimed_at: dict[str, float] = {}
         self._pass_s: dict[tuple, list] = {}
@@ -188,13 +191,30 @@ class BatchScheduler:
         """
         if self.ready_maxsize > 0 and self._ready_jobs >= self.ready_maxsize:
             return True
-        return self.maxsize > 0 and self._outstanding >= self.maxsize
+        # jobs whose pass has ended want no slice any more: they are
+        # outstanding until their envelope is spooled (drain, heartbeat,
+        # /healthz), but must not keep the next gang at the hive
+        return self.maxsize > 0 and (
+            self._outstanding - self._undelivered >= self.maxsize)
 
     def task_done(self, job: dict | None = None) -> None:
-        """One job finished executing. Pass the job dict so the row
-        accounting can subtract its true image count (a no-arg call keeps
-        the old signature and assumes one row)."""
+        """One job finished, pass and delivery at once (`pass_done` +
+        `job_delivered`; a no-arg call assumes one row)."""
+        self.pass_done(job)
+        self.job_delivered()
+
+    def job_delivered(self) -> None:
+        """A job whose pass had ended has its envelope spooled (or will
+        never have one): it is no longer outstanding."""
         self._outstanding -= 1
+        self._undelivered -= 1
+
+    def pass_done(self, job: dict | None = None) -> None:
+        """One job's pass ended and its slice is free: what the dispatch
+        board and the advertised row depth need to know. The job stays
+        outstanding until `job_delivered`. Pass the job dict so the row
+        accounting can subtract its true image count."""
+        self._undelivered += 1
         self._executing_rows = max(
             self._executing_rows - (job_rows(job) if job is not None else 1),
             0)

@@ -125,12 +125,15 @@ def _normalize_uris(obj, base: str):
 
 async def _run_job(name, job, chipset, settings):
     from .job_arguments import format_args
+    from .workflows.diffusion import packaged
 
     job = dict(job, id=f"golden-{name}")
     func, kwargs = await format_args(job, settings, chipset.identifier())
     kwargs.pop("id", None)
     loop = asyncio.get_running_loop()
-    return await loop.run_in_executor(None, lambda: chipset(func, **kwargs))
+    artifacts, config = await loop.run_in_executor(
+        None, lambda: chipset(func, **kwargs))
+    return packaged(artifacts), config
 
 
 async def amain(argv: list[str] | None = None) -> int:

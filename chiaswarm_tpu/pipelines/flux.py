@@ -649,7 +649,8 @@ class FluxPipeline:
 
         from PIL import Image
 
-        images = [Image.fromarray(img) for img in np.asarray(pixels)]
+        with Span("readback", timings):
+            images = [Image.fromarray(img) for img in np.asarray(pixels)]
         pipeline_config = {
             "model": self.model_name,
             "pipeline": pipeline_type,
@@ -827,8 +828,10 @@ class FluxPipeline:
 
         from PIL import Image
 
-        groups = split_by_counts(
-            [Image.fromarray(a) for a in np.asarray(pixels[:total])], counts)
+        with Span("readback", timings):
+            groups = split_by_counts(
+                [Image.fromarray(a) for a in np.asarray(pixels[:total])],
+                counts)
         results = []
         offset = 0
         for n, images in zip(counts, groups):

@@ -2380,7 +2380,10 @@ class SDPipeline:
 
         LORA_ROWS.inc(n_images, mode=lora_mode)
 
-        images = _to_pil(np.asarray(pixels))
+        # span "readback": device -> host copy of the decoded pixels and
+        # the PIL wrap; what leaves the pass is host memory only
+        with Span("readback", timings):
+            images = _to_pil(np.asarray(pixels))
 
         if refiner is not None:
             # SDXL refiner stage (reference pipeline_steps.py:40-68): the
@@ -2900,7 +2903,8 @@ class SDPipeline:
         for mode, n in zip(row_modes, counts):
             LORA_ROWS.inc(n, mode=mode)
 
-        groups = split_by_counts(_to_pil(np.asarray(pixels)), counts)
+        with Span("readback", timings):
+            groups = split_by_counts(_to_pil(np.asarray(pixels)), counts)
 
         # pass-level cost figures (ISSUE 17), counted ONCE for the
         # coalesced pass; each envelope below derives its own stamp with

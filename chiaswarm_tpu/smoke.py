@@ -363,6 +363,8 @@ def _save_artifacts(out_dir, family: str, artifacts: dict) -> list[str]:
 
 async def run_family(name: str, job: dict, chipset, settings,
                      out_dir: str | None) -> tuple[bool, float]:
+    from .workflows.diffusion import packaged
+
     job = dict(job, id=f"smoke-{name}")
     t0 = time.perf_counter()
     try:
@@ -372,6 +374,7 @@ async def run_family(name: str, job: dict, chipset, settings,
         artifacts, config = await loop.run_in_executor(
             None, lambda: chipset(func, **kwargs)
         )
+        artifacts = packaged(artifacts)
     except Exception as e:
         print(f"  {name}: FAILED {type(e).__name__}: {e} "
               f"({time.perf_counter() - t0:.1f}s)")

@@ -168,6 +168,20 @@ _RESUMES = telemetry.counter(
     "fetch_failed | unpack_failed degrade to a full pass)",
     ("outcome",),
 )
+_PACKAGE_SECONDS = telemetry.counter(
+    "swarm_package_seconds_total",
+    "Seconds a host thread spent packaging a pass's images (grid, "
+    "encode, base64, hash) after the pass freed its slice: overlapped = "
+    "yes while a later pass held the slice's busy lock, no while the "
+    "slice was free",
+    ("slice", "overlapped"),
+)
+_PACKAGE_BACKPRESSURE = telemetry.counter(
+    "swarm_package_backpressure_seconds_total",
+    "Seconds a claimed slice waited before a pass because two earlier "
+    "passes of its own were still unpackaged",
+    ("slice",),
+)
 _JOBS_CANCELLED = telemetry.counter(
     "swarm_jobs_cancelled_total",
     "Hive-revoked jobs this worker dropped, by where the cancel caught "
@@ -251,6 +265,10 @@ class Worker:
         self._stopping = asyncio.Event()
         self._draining = asyncio.Event()
         self._probe_tasks: set[asyncio.Task] = set()
+        # slice id -> its passes whose results are not spooled yet, oldest
+        # first: a pass's images are packaged after the slice is free,
+        # by a task that waits for the one before it (_pass_ended)
+        self._deliveries: dict[int, list[asyncio.Task]] = {}
         self._delivering = 0  # entries popped from result_queue, not yet acked
         # job ids currently claimed by a slice (the cancel router's
         # "executing" test: a hive revocation for one of these marks the
@@ -322,6 +340,7 @@ class Worker:
                     loop.remove_signal_handler(signal.SIGTERM)
                 except (NotImplementedError, RuntimeError, ValueError):
                     pass
+            tasks += [t for ts in self._deliveries.values() for t in ts]
             for t in [*tasks, *self._probe_tasks]:
                 t.cancel()
             await asyncio.gather(
@@ -975,6 +994,7 @@ class Worker:
             # the warm slice, stealing by an idle one when the warm slice
             # is busy — and the chipset arrives already acquired
             batch, chipset, outcome = await self.batcher.claim(self.allocator)
+            await self._wait_for_packaging(chipset)
             # queue_wait: hive handoff -> a slice actually starting the work
             picked_up = time.monotonic()
             # whole-pass slice occupancy feeds the "pass" stage EWMA for
@@ -1019,6 +1039,9 @@ class Worker:
                 if isinstance(offer, dict) and "id" in job:
                     resume_offers[str(job["id"])] = offer
             self._update_queue_gauges()
+            # the jobs no pass has ended for yet, by id
+            waiting = {str(job.get("id")): job for job in batch}
+            delivery = (queue_wait, outcome, traces)
             try:
                 prepared = []
                 for job in batch:
@@ -1030,36 +1053,22 @@ class Worker:
                 if len(prepared) > 1 and self._batchable(prepared):
                     results = await self.do_batched_work(
                         chipset, prepared, batch_cap)
-                    stats_folded = False
-                    for result in results:
-                        # a cancelled member's slot comes back as None:
-                        # no envelope exists and none is delivered — the
-                        # hive tombstoned the job, batchmates unharmed
-                        if result is None:
-                            continue
-                        self._finish_result(
-                            result, queue_wait, outcome, traces)
-                        if not stats_folded:
-                            # ONE coalesced pass = one stats sample; the
-                            # envelopes all carry the same copied timings
-                            self._note_stage_stats(
-                                result["pipeline_config"].get(
-                                    "timings") or {})
-                            stats_folded = True
-                        await self._enqueue_result(result)
+                    self._pass_ended(
+                        chipset,
+                        [waiting.pop(str(kw.get("id"))) for _, kw in prepared],
+                        results, *delivery)
                 else:
-                    jobs_by_id = {str(j.get("id")): j for j in batch
-                                  if "id" in j}
                     for worker_function, kwargs in prepared:
-                        solo_cap = caps_by_id.get(
-                            str(kwargs.get("id"))) or None
+                        # read now: the executor thread pops it
+                        job_id = str(kwargs.get("id"))
+                        solo_cap = caps_by_id.get(job_id) or None
                         # class-aware geometry (ISSUE 12): an interactive
                         # solo on a multi-chip slice fans ONE image over
                         # every chip as a sharded program; batch solos
                         # (and every coalesced pass) keep the default
                         # data-parallel view
                         self._apply_shard_geometry(
-                            jobs_by_id.get(str(kwargs.get("id"))),
+                            waiting.get(job_id),
                             worker_function, kwargs, chipset)
                         # mid-pass durability (ISSUE 18): arm the solo
                         # pass with checkpoint/preview callbacks and,
@@ -1067,27 +1076,28 @@ class Worker:
                         # rehydrated resume state
                         await self._apply_checkpointing(
                             worker_function, kwargs,
-                            resume_offers.get(str(kwargs.get("id"))))
+                            resume_offers.get(job_id))
+                        await self._wait_for_packaging(chipset)
+                        # None: the pass was aborted by a cancel
                         result = await self.do_work(
                             chipset, worker_function, kwargs, solo_cap
                         )
-                        if result is None:  # pass aborted by a cancel
-                            continue
-                        self._finish_result(
-                            result, queue_wait, outcome, traces)
-                        self._note_stage_stats(
-                            result["pipeline_config"].get("timings") or {})
-                        await self._enqueue_result(result)
+                        self._pass_ended(
+                            chipset, [waiting.pop(job_id)], [result],
+                            *delivery)
             except Exception as e:
                 logger.exception("slice_worker error")
                 print(f"slice_worker {e}")
             finally:
+                # the slice is free from here: what the passes produced
+                # is packaged and delivered by _deliver_pass while the
+                # next pass runs
                 self.allocator.release(chipset)
                 self._note_stage_stats(
                     {"pass_s": round(time.monotonic() - pass_started, 4)})
-                for job in batch:
-                    # pass the job so the row accounting (advertised
-                    # queue_depth) subtracts its true image count
+                for job in waiting.values():
+                    # no pass ended for these (refused by format_args,
+                    # or the loop above raised): nothing is delivered
                     self.batcher.task_done(job)
                 for job_id in batch_ids:
                     # tokens die with the pass: a later resubmission of
@@ -1095,6 +1105,105 @@ class Worker:
                     self._executing_ids.discard(job_id)
                     cancel_mod.discard(job_id)
                 self._update_queue_gauges()
+
+    # --- packaging off the slice's critical path ---
+
+    async def _wait_for_packaging(self, chipset) -> None:
+        """The one bound on what waits to be packaged, a rule and no
+        setting: a slice does not start a pass while two earlier passes
+        of its own are still undelivered; it waits for the older one."""
+        pending = self._deliveries.get(chipset.slice_id, ())
+        held_from = time.monotonic() if len(pending) >= 2 else None
+        while len(pending) >= 2:
+            await asyncio.wait({pending[0]})
+        _PACKAGE_BACKPRESSURE.inc(
+            0.0 if held_from is None else time.monotonic() - held_from,
+            slice=str(chipset.slice_id))
+
+    def _pass_ended(self, chipset, jobs: list[dict],
+                    results: list[dict | None], queue_wait: dict,
+                    placement: str, traces: dict) -> None:
+        """One executor call has returned with `results` for `jobs`
+        (None: a cancelled member, no envelope exists and none is
+        delivered — the hive tombstoned the job, batchmates unharmed).
+        The jobs want no slice any more; they stay outstanding until a
+        task of the pass's own has packaged, finished and spooled their
+        envelopes, behind the slice's earlier passes."""
+        for job in jobs:
+            # pass the job so the row accounting (advertised
+            # queue_depth) subtracts its true image count
+            self.batcher.pass_done(job)
+        results = [result for result in results if result is not None]
+        if results:
+            # ONE pass = one stats sample; a coalesced pass's envelopes
+            # all carry the same copied timings
+            self._note_stage_stats(
+                results[0]["pipeline_config"].get("timings") or {})
+        pending = self._deliveries.setdefault(chipset.slice_id, [])
+        task = asyncio.create_task(self._deliver_pass(
+            chipset, results, len(jobs), queue_wait, placement, traces,
+            after=pending[-1] if pending else None))
+        pending.append(task)
+        task.add_done_callback(pending.remove)
+
+    async def _deliver_pass(self, chipset, results: list[dict], n_jobs: int,
+                            queue_wait: dict, placement: str, traces: dict,
+                            after: asyncio.Task | None) -> None:
+        """Package one pass's images on a host thread, then finish and
+        enqueue its envelopes together and in order: a gang's clients
+        see their jobs settle together (two that resubmit at once are
+        one gang again; envelopes spaced by an encode would split them
+        over polls)."""
+        from .workflows.diffusion import Unpackaged
+
+        try:
+            if after is not None:
+                await asyncio.wait({after})  # per slice, in pass order
+            if any(isinstance(result["artifacts"], Unpackaged)
+                   for result in results):
+                results = await asyncio.get_running_loop().run_in_executor(
+                    None, self._package_pass, chipset, results)
+            for result in results:
+                self._finish_result(result, queue_wait, placement, traces)
+                await self._enqueue_result(result)
+        except Exception as e:
+            logger.exception("delivering a pass's results failed")
+            print(f"deliver_pass {e}")
+        finally:
+            for _ in range(n_jobs):
+                self.batcher.job_delivered()
+            self._update_queue_gauges()
+
+    def _package_pass(self, chipset, results: list[dict]) -> list[dict]:
+        """On a host thread: the `artifacts` of every result that left
+        its pass `Unpackaged`, in order. A failure is that job's own
+        error envelope; its batchmates are delivered and nothing is
+        denoised again."""
+        from .workflows.diffusion import Unpackaged
+
+        label = str(chipset.slice_id)
+        packaged = []
+        for result in results:
+            unpackaged = result["artifacts"]
+            if isinstance(unpackaged, Unpackaged):
+                spans = result["pipeline_config"].setdefault("spans", [])
+                started, held = time.monotonic(), chipset.held_seconds()
+                try:
+                    result["artifacts"] = unpackaged.package(spans)
+                except Exception as e:
+                    logger.exception(
+                        "packaging job %s failed", result["id"])
+                    result = _error_envelope(
+                        e, result["id"], unpackaged.content_type, spans)
+                seconds = time.monotonic() - started
+                # the job's own pass let the lock go before this began
+                overlapped = min(chipset.held_seconds() - held, seconds)
+                _PACKAGE_SECONDS.inc(
+                    overlapped, slice=label, overlapped="yes")
+                _PACKAGE_SECONDS.inc(
+                    seconds - overlapped, slice=label, overlapped="no")
+            packaged.append(result)
+        return packaged
 
     def _finish_result(self, result: dict, queue_wait: dict,
                        placement: str | None = None,
@@ -1421,25 +1530,13 @@ class Worker:
         self._probe_tasks.add(probe)
         probe.add_done_callback(self._probe_tasks.discard)
 
-        results = []
-        for meta in jobs_meta:
-            err = TimeoutError(
-                f"job execution exceeded the {deadline:g}s watchdog "
-                "deadline; the slice was quarantined and the job may be "
-                "resubmitted")
-            content_type = meta.get("content_type") or "image/jpeg"
-            if content_type.startswith("image/"):
-                artifacts, pipeline_config = exception_image(err, content_type)
-            else:
-                artifacts, pipeline_config = exception_message(err)
-            results.append({
-                "id": meta.get("id"),
-                "artifacts": artifacts,
-                "nsfw": False,
-                "worker_version": __version__,
-                "pipeline_config": pipeline_config,
-            })
-        return results
+        err = TimeoutError(
+            f"job execution exceeded the {deadline:g}s watchdog "
+            "deadline; the slice was quarantined and the job may be "
+            "resubmitted")
+        return [_error_envelope(err, meta.get("id"),
+                                meta.get("content_type") or "image/jpeg")
+                for meta in jobs_meta]
 
     async def _quarantine_probe(self, chipset) -> None:
         """Wait (bounded) for the wedged pass to release the slice, then
@@ -1624,15 +1721,13 @@ class Worker:
             # non-recoverable (e.g. incompatible adapter): fatal envelope
             return fatal_exception_response(e, job_id, kwargs)
         except Exception as e:
-            # transient: render the error as the artifact, job still "succeeds"
             logger.exception("job %s failed", job_id)
-            content_type = kwargs.get("content_type", "image/jpeg")
-            if content_type.startswith("image/"):
-                artifacts, pipeline_config = exception_image(e, content_type)
-            else:
-                artifacts, pipeline_config = exception_message(e)
+            # a failed pass spent its time too: the error envelope says
+            # where
+            return _error_envelope(
+                e, job_id, kwargs.get("content_type", "image/jpeg"),
+                trace.spans)
 
-        # a failed pass spent its time too: the error envelope says where
         pipeline_config["spans"] = trace.spans
         return {
             "id": job_id,
@@ -1776,6 +1871,31 @@ class _HostLane:
         pipeline_config.setdefault("timings", {})["job_s"] = round(
             time.perf_counter() - started, 3)
         return artifacts, pipeline_config
+
+
+def _error_envelope(e: Exception, job_id, content_type: str,
+                    spans: list | None = None) -> dict:
+    """The envelope of a job that failed, by the one attribution there
+    is: ValueError/TypeError are fatal (the hive must not resubmit),
+    anything else is transient — the error rendered as the artifact, the
+    job still "succeeds". `spans`: where the time went before it failed
+    (a transient envelope carries them; a fatal one never has)."""
+    if isinstance(e, (ValueError, TypeError)):
+        return fatal_exception_response(
+            e, job_id, {"content_type": content_type})
+    if content_type.startswith("image/"):
+        artifacts, pipeline_config = exception_image(e, content_type)
+    else:
+        artifacts, pipeline_config = exception_message(e)
+    if spans is not None:
+        pipeline_config["spans"] = spans
+    return {
+        "id": job_id,
+        "artifacts": artifacts,
+        "nsfw": False,
+        "worker_version": __version__,
+        "pipeline_config": pipeline_config,
+    }
 
 
 def _waited(enqueued: float, picked_up: float) -> tuple[float, float]:

@@ -128,20 +128,20 @@ def diffusion_callback(device_identifier: str, model_name: str, **kwargs):
         from .stages import pack_raw
 
         with Span("handoff", pipeline_config.setdefault("timings", {})):
-            packaged = {"raw": pack_raw(images)}
-        return packaged, pipeline_config
+            handoff = {"raw": pack_raw(images)}
+        return handoff, pipeline_config
 
     # stage "decode": host-side postprocess after the on-device decode
     # that ends the denoise program, parent of "safety" (real NSFW
     # detection on the decoded pixels — reference envelope parity:
-    # swarm/worker.py:166; auxiliary, never fails the job) and
-    # "artifact_encode" (grid composite + encode)
+    # swarm/worker.py:166; auxiliary, never fails the job). The pass
+    # ends here: the grid composite + encode ("artifact_encode") needs
+    # no chip, so the images leave unpackaged
     with Span("decode", pipeline_config.setdefault("timings", {})):
         nsfw, checked = _flag(images)
         pipeline_config["nsfw"] = nsfw
         pipeline_config["nsfw_checked"] = checked
-        results = _package(images, outputs, content_type)
-    return results, pipeline_config
+    return Unpackaged(images, outputs, content_type), pipeline_config
 
 
 def _flag(images):
@@ -153,12 +153,42 @@ def _flag(images):
 
 
 def _package(images, outputs, content_type):
-    """Span "artifact_encode": grid composite, PNG/JPEG encode, base64
-    and hash of one job's images — host work on the slice thread."""
-    with Span("artifact_encode"):
-        processor = OutputProcessor(outputs, content_type)
-        processor.add_outputs(images)
-        return processor.get_results()
+    """Grid composite, PNG/JPEG encode, base64 and hash of one job's
+    images: the hive `artifacts` dict."""
+    processor = OutputProcessor(outputs, content_type)
+    processor.add_outputs(images)
+    return processor.get_results()
+
+
+class Unpackaged:
+    """What the two callbacks return in the place of a job's `artifacts`:
+    its images (host memory only — the read-back ended inside the pass)
+    and what `_package` needs to turn them into one. Packaging needs no
+    chip, so it is the caller's to run once the slice is free; the worker
+    does, on a host thread, while the slice's next pass runs."""
+
+    __slots__ = ("images", "outputs", "content_type")
+
+    def __init__(self, images, outputs, content_type):
+        self.images = images
+        self.outputs = outputs
+        self.content_type = content_type
+
+    def package(self, spans: list | None = None) -> dict:
+        """Span "artifact_encode" on thread "host": the pass's trace has
+        closed, so the span joins `spans` (the job's own
+        `pipeline_config.spans`) directly."""
+        with Span("artifact_encode", thread="host", spans=spans):
+            return _package(self.images, self.outputs, self.content_type)
+
+
+def packaged(artifacts):
+    """A callback's artifacts as the hive's dict, for whoever drives one
+    without the worker (smoke, goldens, tests): what was `Unpackaged` is
+    packaged here and now."""
+    if isinstance(artifacts, Unpackaged):
+        return artifacts.package()
+    return artifacts
 
 
 def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
@@ -168,7 +198,8 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
     differs only per-row (prompt, negative, seed, start image, image
     count). Executes the group in as few padded jitted denoise+decode
     passes as capacity allows (usually one) and returns per-request
-    (artifacts, pipeline_config) envelopes in order.
+    (artifacts, pipeline_config) envelopes in order, the artifacts
+    `Unpackaged` as `diffusion_callback`'s are.
 
     Raising here (capacity, weights) is fine: the worker falls back to
     the single-job path, which reproduces the error per job with the
@@ -337,11 +368,11 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
             from .stages import pack_raw
 
             with Span("handoff", pipeline_config.setdefault("timings", {})):
-                packaged = {"raw": pack_raw(images)}
+                handoff = {"raw": pack_raw(images)}
             pipeline_config["batched_with"] = len(requests)
             if i in capped:
                 pipeline_config["batch_capped"] = capped[i]
-            out.append((packaged, pipeline_config))
+            out.append((handoff, pipeline_config))
             continue
         with Span("decode", pipeline_config.setdefault("timings", {})):
             nsfw, checked = _flag(images)
@@ -350,8 +381,8 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
             pipeline_config["batched_with"] = len(requests)
             if i in capped:
                 pipeline_config["batch_capped"] = capped[i]
-            packaged = _package(images, env["outputs"], env["content_type"])
-        out.append((packaged, pipeline_config))
+        out.append((Unpackaged(images, env["outputs"], env["content_type"]),
+                    pipeline_config))
     return out
 
 

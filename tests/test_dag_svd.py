@@ -25,6 +25,7 @@ import jax
 from chiaswarm_tpu.hive_server import dag
 from chiaswarm_tpu.job_arguments import format_args
 from chiaswarm_tpu.settings import Settings
+from chiaswarm_tpu.workflows.diffusion import packaged
 
 PAYLOAD = {
     "workflow": "img2vid",
@@ -73,7 +74,8 @@ def _run_stage(stage: dict, stages: list[dict], results: dict):
     if seed is not None:
         kwargs["rng"] = jax.random.key(int(seed))
     kwargs.pop("chipset", None)
-    return func("cpu", model_name, **kwargs)
+    artifacts, config = func("cpu", model_name, **kwargs)
+    return packaged(artifacts), config
 
 
 def _run_workflow(workflow_id: str):

@@ -15,6 +15,7 @@ from chiaswarm_tpu import registry
 from chiaswarm_tpu.chips.device import ChipSet
 from chiaswarm_tpu.job_arguments import format_args
 from chiaswarm_tpu.settings import Settings
+from chiaswarm_tpu.workflows.diffusion import packaged
 
 
 @pytest.fixture(autouse=True)
@@ -30,7 +31,7 @@ def run_job(job: dict) -> dict:
     callback, kwargs = asyncio.run(format_args(job, settings, "cpu:0"))
     chipset = ChipSet(jax.devices()[:1])
     artifacts, pipeline_config = chipset(callback, **kwargs)
-    return artifacts, pipeline_config
+    return packaged(artifacts), pipeline_config
 
 
 def test_txt2img_job_to_artifact():

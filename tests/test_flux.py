@@ -186,7 +186,10 @@ def test_flux_requires_weights(sdaas_root):
 
 
 def test_flux_tiny_job_through_callback():
-    from chiaswarm_tpu.workflows.diffusion import diffusion_callback
+    from chiaswarm_tpu.workflows.diffusion import (
+        diffusion_callback,
+        packaged,
+    )
 
     artifacts, config = diffusion_callback(
         "cpu:0",
@@ -200,7 +203,7 @@ def test_flux_tiny_job_through_callback():
         rng=jax.random.key(0),
     )
     assert config["model"] == "test/tiny-flux-schnell"
-    assert artifacts["primary"]["content_type"] == "image/jpeg"
+    assert packaged(artifacts)["primary"]["content_type"] == "image/jpeg"
 
 
 # --- conversion mapping (exact roundtrip through diffusers naming) ---

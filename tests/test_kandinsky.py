@@ -183,7 +183,10 @@ def test_real_kandinsky_requires_weights(sdaas_root):
 
 
 def test_kandinsky_job_through_callback():
-    from chiaswarm_tpu.workflows.diffusion import diffusion_callback
+    from chiaswarm_tpu.workflows.diffusion import (
+        diffusion_callback,
+        packaged,
+    )
 
     artifacts, config = diffusion_callback(
         "cpu:0",
@@ -198,7 +201,7 @@ def test_kandinsky_job_through_callback():
         rng=jax.random.key(0),
     )
     assert config["model"] == "test/tiny-kandinsky"
-    assert artifacts["primary"]["content_type"] == "image/jpeg"
+    assert packaged(artifacts)["primary"]["content_type"] == "image/jpeg"
 
 
 def test_img2img_conditions_on_init_image(tiny_decoder):

@@ -10,9 +10,12 @@ faults.py rather than sleeps-and-hope.
 
 import asyncio
 import base64
+import contextlib
 import os
 import signal
+import threading
 import time
+import types
 
 import pytest
 
@@ -464,8 +467,9 @@ def test_job_stage_spans_recorded_end_to_end(sdaas_root):
     stages = telemetry.REGISTRY.get(STAGE_METRIC) or telemetry.histogram(
         STAGE_METRIC, "", ("stage",))
     completed = telemetry.REGISTRY.get("swarm_jobs_completed_total")
-    required = ("queue_wait", "compile", "denoise", "decode", "submit",
-                "pass", "load", "safety", "artifact_encode", "spool")
+    required = ("queue_wait", "compile", "denoise", "readback", "decode",
+                "submit", "pass", "load", "safety", "artifact_encode",
+                "spool")
     before = {s: stages.count(stage=s) for s in required}
     ok_before = completed.value(outcome="ok") if completed else 0
 
@@ -503,21 +507,28 @@ def test_job_stage_spans_recorded_end_to_end(sdaas_root):
     # on the slice thread (the hive and the benchmark's readers rely on it)
     spans = {s["name"]: s for s in result["pipeline_config"]["spans"]}
     for name in ("pass", "queue_wait", "load", "text_encode", "compile",
-                 "denoise", "decode", "safety", "artifact_encode"):
+                 "denoise", "readback", "decode", "safety",
+                 "artifact_encode"):
         assert name in spans, sorted(spans)
     assert spans["queue_wait"]["thread"] == "wait"
 
-    def inside(child, parent, slack=1e-4):
-        child, parent = spans[child], spans[parent]
-        return (child["thread"] == parent["thread"] == "slice"
-                and parent["start_wall"] <= child["start_wall"]
-                and child["start_wall"] + child["seconds"]
-                <= parent["start_wall"] + parent["seconds"] + slack)
+    def end(name):
+        return spans[name]["start_wall"] + spans[name]["seconds"]
 
-    for child in ("load", "text_encode", "compile", "denoise", "decode"):
+    def inside(child, parent, slack=1e-4):
+        return (spans[child]["thread"] == spans[parent]["thread"] == "slice"
+                and spans[parent]["start_wall"] <= spans[child]["start_wall"]
+                and end(child) <= end(parent) + slack)
+
+    for child in ("load", "text_encode", "compile", "denoise", "readback",
+                  "decode", "safety"):
         assert inside(child, "pass"), (child, spans)
-    for child in ("safety", "artifact_encode"):
-        assert inside(child, "decode"), (child, spans)
+    assert inside("safety", "decode"), spans
+    assert end("denoise") <= spans["readback"]["start_wall"]
+    # the pass ends with the read-back and the safety pass: the images are
+    # packaged after the slice is let go, on a host thread
+    assert spans["artifact_encode"]["thread"] == "host"
+    assert spans["artifact_encode"]["start_wall"] >= end("pass") - 1e-4
     assert spans["queue_wait"]["start_wall"] <= spans["pass"]["start_wall"]
     assert timings["job_s"] == round(spans["pass"]["seconds"], 3)
     # capability heartbeat folded in the live-load snapshot
@@ -1004,3 +1015,285 @@ def test_envelope_echoes_hive_trace_context(sdaas_root):
     assert trace["received_wall"] >= trace["dispatched_wall"] - 1.0
     # stage timings still ride next to it
     assert "queue_wait_s" in result["pipeline_config"]["timings"]
+
+
+# --- packaging off the slice's critical path (ISSUE 28) ---------------------
+
+
+def tiny_png_jobs(tag: str, n: int) -> list[dict]:
+    return [{
+        "id": f"{tag}{i}", "workflow": "txt2img",
+        "model_name": "stabilityai/stable-diffusion-2-1",
+        "prompt": f"packaging probe {i}", "seed": 4000 + i,
+        "height": 64, "width": 64, "num_inference_steps": 2,
+        "content_type": "image/png",
+        "parameters": {"test_tiny_model": True},
+    } for i in range(n)]
+
+
+@contextlib.contextmanager
+def scenario_root(tmp_path_factory):
+    """What `sdaas_root` and `fast_poll` give a test, for a fixture that
+    runs ONE worker for several tests (a worker run costs seconds)."""
+    root = tmp_path_factory.mktemp("packaging")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("SDAAS_ROOT", str(root / "sdaas"))
+        for var in ("SDAAS_TOKEN", "SDAAS_URI", "SDAAS_WORKERNAME"):
+            patch.delenv(var, raising=False)
+        patch.setattr(worker_mod, "POLL_SECONDS", 0.05)
+        yield patch
+
+
+def stage_count(stage: str) -> int:
+    from chiaswarm_tpu import telemetry
+
+    return telemetry.REGISTRY.get(telemetry.STAGE_METRIC).count(stage=stage)
+
+
+def span_end(span: dict) -> float:
+    return span["start_wall"] + span["seconds"]
+
+
+def spans_named(result: dict, name: str) -> list[dict]:
+    return [span for span in result["pipeline_config"]["spans"]
+            if span["name"] == name]
+
+
+@pytest.fixture(scope="module")
+def two_gangs_slow_packaging(tmp_path_factory):
+    """Two gangs of two queued at the hive, one slice, `_package` slowed
+    to 0.4 s a job: what the worker did with them, for the tests below."""
+    from chiaswarm_tpu.workflows import diffusion
+
+    real = diffusion._package
+    calls, arrivals = [], []
+
+    def slow(images, outputs, content_type):
+        calls.append(([image.copy() for image in images], outputs,
+                      content_type))
+        time.sleep(0.4)
+        return real(images, outputs, content_type)
+
+    def overlapped():
+        return worker_mod._PACKAGE_SECONDS.value(slice="0", overlapped="yes")
+
+    async def scenario():
+        hive = await FakeHive().start()
+        hive.gang_max = 2
+        for job in tiny_png_jobs("gang-", 4):
+            hive.add_job(job)
+        w = Worker(settings=Settings(sdaas_token="t", worker_name="w"),
+                   allocator=SliceAllocator(chips_per_job=8),
+                   hive_uri=hive.uri)
+        put = w.result_queue.put
+
+        async def noting(entry):
+            arrivals.append((time.time(), entry.job_id))
+            await put(entry)
+
+        w.result_queue.put = noting
+        runner = asyncio.create_task(w.run())
+        try:
+            return list(await hive.wait_for_results(4, timeout=240.0))
+        finally:
+            w.stop()
+            await asyncio.wait_for(runner, 10)
+            await hive.stop()
+
+    with scenario_root(tmp_path_factory) as patch:
+        patch.setattr(diffusion, "_package", slow)
+        before = overlapped()
+        results = asyncio.run(scenario())
+        moved = overlapped() - before
+    by_id = {result["id"]: result for result in results}
+    return types.SimpleNamespace(
+        results=[by_id[f"gang-{i}"] for i in range(4)], calls=calls,
+        arrivals=arrivals, overlapped_s=moved, package=real)
+
+
+def test_next_pass_runs_while_the_last_is_packaged(two_gangs_slow_packaging):
+    """(a) The slice is let go before its pass's images are packaged: the
+    second gang's `pass` span starts before the first gang's last
+    `artifact_encode` span ends, and the counter says so."""
+    first, second = (two_gangs_slow_packaging.results[:2],
+                     two_gangs_slow_packaging.results[2:])
+    for gang in (first, second):
+        assert all(r["pipeline_config"]["batched_with"] == 2 for r in gang)
+        assert len({r["pipeline_config"]["trace"]["gang"]["id"]
+                    for r in gang}) == 1
+    [next_pass] = spans_named(second[0], "pass")
+    encodes = [span for r in first for span in spans_named(r, "artifact_encode")]
+    assert len(encodes) == 2
+    assert all(span["thread"] == "host" for span in encodes)
+    assert next_pass["start_wall"] < max(map(span_end, encodes))
+    [first_pass] = spans_named(first[0], "pass")
+    assert span_end(first_pass) <= next_pass["start_wall"]
+    assert two_gangs_slow_packaging.overlapped_s > 0
+
+
+def test_artifacts_are_what_package_gives_for_the_same_images(
+        two_gangs_slow_packaging):
+    """(b) Same grid, same PNG parameters, same thumbnail, same hash: the
+    envelope's artifacts are `_package`'s for the images the pass handed
+    back, byte for byte."""
+    run = two_gangs_slow_packaging
+    assert len(run.calls) == 4  # packaged in pass order, one call a job
+    for result, (images, outputs, content_type) in zip(run.results, run.calls):
+        want = run.package(images, outputs, content_type)["primary"]
+        got = result["artifacts"]["primary"]
+        assert content_type == got["content_type"] == "image/png"
+        for key in ("blob", "thumbnail", "sha256_hash"):
+            assert got[key] == want[key], (result["id"], key)
+        assert base64.b64decode(got["blob"]).startswith(b"\x89PNG")
+
+
+def test_a_gangs_envelopes_are_enqueued_together_and_in_order(
+        two_gangs_slow_packaging):
+    """(e) A pass's envelopes reach the result queue after the last of its
+    jobs is packaged, in order and together, never one encode apart."""
+    run = two_gangs_slow_packaging
+    assert [job_id for _, job_id in run.arrivals] == [
+        f"gang-{i}" for i in range(4)]
+    for gang in (0, 2):
+        (first_at, _), (second_at, _) = run.arrivals[gang:gang + 2]
+        last_encoded = max(
+            span_end(span) for r in run.results[gang:gang + 2]
+            for span in spans_named(r, "artifact_encode"))
+        assert first_at >= last_encoded - 1e-3
+        assert second_at - first_at < 0.2  # an encode is 0.4 s here
+
+
+@pytest.fixture(scope="module")
+def drained_over_a_failed_encode(tmp_path_factory):
+    """One gang of three; the second member's `_package` raises; a drain
+    is asked for while the first member is being packaged."""
+    from chiaswarm_tpu.workflows import diffusion
+
+    real = diffusion._package
+    calls = []
+    packaging = threading.Event()
+
+    def failing(images, outputs, content_type):
+        calls.append(len(calls))
+        packaging.set()
+        time.sleep(0.5)
+        if calls[-1] == 1:
+            raise RuntimeError("injected: the encoder failed")
+        return real(images, outputs, content_type)
+
+    async def scenario():
+        hive = await FakeHive().start()
+        for job in tiny_png_jobs("enc-", 3):
+            hive.add_job(job)
+        w = Worker(settings=Settings(sdaas_token="t", worker_name="w",
+                                     drain_deadline_s=60.0),
+                   allocator=SliceAllocator(chips_per_job=8),
+                   hive_uri=hive.uri)
+        runner = asyncio.create_task(w.run())
+        try:
+            for _ in range(4800):
+                if packaging.is_set():
+                    break
+                await asyncio.sleep(0.05)
+            assert packaging.is_set()
+            delivered_before = len(hive.results)
+            in_flight = w._health()["jobs_in_flight"]
+            w.stop(drain=True)
+            await asyncio.wait_for(runner, 60.0)  # exits by itself
+            return types.SimpleNamespace(
+                results=list(hive.results), in_flight=in_flight,
+                delivered_before=delivered_before,
+                spooled_left=w.outbox.depth)
+        finally:
+            if not runner.done():
+                w.stop()
+                await asyncio.wait_for(runner, 10)
+            await hive.stop()
+
+    with scenario_root(tmp_path_factory) as patch:
+        patch.setattr(diffusion, "_package", failing)
+        before = {stage: stage_count(stage) for stage in ("denoise", "pass")}
+        run = asyncio.run(scenario())
+        run.passes = {stage: stage_count(stage) - n
+                      for stage, n in before.items()}
+    return run
+
+
+def test_drain_waits_for_packaging_in_flight(drained_over_a_failed_encode):
+    """(c) A job is outstanding until its envelope is spooled: a drain
+    asked for over unencoded images delivers every envelope first."""
+    run = drained_over_a_failed_encode
+    assert run.delivered_before == 0 and run.in_flight == 3
+    assert [r["id"] for r in run.results] == ["enc-0", "enc-1", "enc-2"]
+    assert run.spooled_left == 0
+
+
+def test_a_failed_encode_is_that_jobs_own_envelope(
+        drained_over_a_failed_encode):
+    """(d) An exception while packaging one member is that member's error
+    envelope by the usual attribution; its batchmates are delivered and
+    nothing is denoised again (before, the whole callback failed and every
+    member was rerun solo)."""
+    run = drained_over_a_failed_encode
+    failed, fine = run.results[1], [run.results[0], run.results[2]]
+    assert failed["pipeline_config"]["error"] == "injected: the encoder failed"
+    assert not failed.get("fatal_error")
+    assert base64.b64decode(
+        failed["artifacts"]["primary"]["blob"]).startswith(b"\x89PNG")
+    # the failed job's envelope still says where its pass went
+    assert spans_named(failed, "denoise") and spans_named(failed, "pass")
+    for result in fine:
+        config = result["pipeline_config"]
+        assert "error" not in config and config["batched_with"] == 3
+        assert len(spans_named(result, "artifact_encode")) == 1
+    assert run.passes == {"denoise": 1, "pass": 1}
+
+
+def test_a_slice_waits_while_two_of_its_passes_are_undelivered(sdaas_root):
+    """(f) The one bound on what waits, a rule and no setting: with two
+    earlier passes of a slice undelivered, its third pass does not start
+    until the older one is; the wait is counted."""
+    from chiaswarm_tpu.chips.device import _EXECUTE_SECONDS
+
+    def held_s():
+        return worker_mod._PACKAGE_BACKPRESSURE.value(slice="0")
+
+    async def scenario():
+        hive = await FakeHive().start()
+        for i in range(3):
+            hive.add_job(echo_job(f"job-q{i}"))
+        w = Worker(settings=Settings(sdaas_token="t", worker_name="w"),
+                   allocator=SliceAllocator(chips_per_job=8),
+                   hive_uri=hive.uri)
+        gate = asyncio.Event()
+        enqueue = w._enqueue_result
+
+        async def gated(result):
+            await gate.wait()
+            await enqueue(result)
+
+        w._enqueue_result = gated
+        passes_before = _EXECUTE_SECONDS.count(kind="solo")
+        held_before = held_s()
+        runner = asyncio.create_task(w.run())
+        try:
+            for _ in range(200):
+                if _EXECUTE_SECONDS.count(kind="solo") - passes_before >= 2:
+                    break
+                await asyncio.sleep(0.05)
+            await asyncio.sleep(0.5)  # room for a third pass to start
+            assert _EXECUTE_SECONDS.count(kind="solo") - passes_before == 2
+            assert hive.results == [] and held_s() == held_before
+            assert w._health()["jobs_in_flight"] == 3
+            gate.set()
+            results = await hive.wait_for_results(3, timeout=60.0)
+            assert [r["id"] for r in results] == [
+                "job-q0", "job-q1", "job-q2"]
+            assert _EXECUTE_SECONDS.count(kind="solo") - passes_before == 3
+            assert held_s() - held_before >= 0.4
+        finally:
+            w.stop()
+            await asyncio.wait_for(runner, 10)
+            await hive.stop()
+
+    asyncio.run(scenario())
