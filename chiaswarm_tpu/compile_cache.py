@@ -18,9 +18,10 @@ The directory is part of the cache's key, so it must not move:
 
 A directory that cannot be written is an error, not a silently cold cache.
 
-Consumers: worker.startup() (min_compile_time 1.0 s, so thousands of
-trivial sub-programs don't spam the cache) and bench.py (the warm-restart
-dry-run row uses 0.0 so the whole tiny pipeline caches).
+Consumers: worker.startup() and the benchmark's harness. A process that
+wants every program cached (tests/test_compile_cache.py; the benchmark's
+families around their warm-up) lowers jax's own
+``jax_persistent_cache_min_compile_time_secs`` after enabling the cache.
 """
 
 from __future__ import annotations
@@ -57,6 +58,10 @@ _COMPILE_SECONDS = telemetry.counter(
     "swarm_xla_compile_seconds_total",
     "Seconds spent in backend compiles and persistent-cache reads")
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# programs that compile faster than this are not written to the cache: a
+# spam guard (a worker compiles thousands of trivial eager sub-programs),
+# not a correctness knob
+_MIN_COMPILE_TIME_S = 1.0
 _listening = False
 
 
@@ -88,7 +93,7 @@ def writable_cache_dir() -> Path:
     return path
 
 
-def enable_compile_cache(min_compile_time_s: float = 1.0) -> Path:
+def enable_compile_cache() -> Path:
     """Turn jax's persistent compilation cache on at `cache_dir()` and
     return that path. Raises OSError when the directory cannot be created
     or written."""
@@ -104,5 +109,5 @@ def enable_compile_cache(min_compile_time_s: float = 1.0) -> Path:
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_s))
+                      _MIN_COMPILE_TIME_S)
     return path

@@ -1,10 +1,8 @@
 """Serving-path cost plane: per-pass FLOPs, achieved TFLOP/s, MFU.
 
-ISSUE 17. The roofline contract (0.33 img/s/chip ~= 70% UNet MFU) rested
-on an analytic FLOP denominator (models/flops.py) that surfaced only in
-bench.py — the serving path billed tenants in chip-seconds with no idea
-how many FLOPs it served or what MFU a pass achieved. This module is the
-shared cost vocabulary for both:
+ISSUE 17. The serving path billed tenants in chip-seconds with no idea how
+many FLOPs (the analytic count of models/flops.py) it served or what MFU a
+pass achieved. This module is its cost vocabulary:
 
 - ``PEAK_TFLOPS`` / ``peak_tflops(device)``: the per-chip peak dense
   bf16 table, keyed by ``device_kind``. A TPU kind with no row is an
@@ -25,9 +23,8 @@ shared cost vocabulary for both:
   and publishes the ratio, closing the "denominator is uncorroborated"
   gap without waiting for a TPU window.
 
-Import-time jax-free (telemetry only): the hive-side tools and the
-bench subprocess parser read these stamps without an accelerator
-runtime.
+Import-time jax-free (telemetry only): the hive-side tools read these
+stamps without an accelerator runtime.
 """
 
 from __future__ import annotations

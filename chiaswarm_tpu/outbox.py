@@ -84,6 +84,9 @@ class OutboxEntry:
     spooled_at: float
     retries: int = 0
     parked: bool = False
+    # envelopes of the same pass queued right behind this one: the
+    # uploader takes them with it (worker.result_worker)
+    followers: int = 0
 
 
 class Outbox:

@@ -231,8 +231,6 @@ class VideoPipeline:
         self._programs: OrderedDict = OrderedDict()
         # param trees with motion-LoRAs merged, keyed by (ref, scale);
         # bounded — each entry pins a full UNet copy
-        from collections import OrderedDict
-
         self._lora_cache: OrderedDict[tuple, dict] = OrderedDict()
 
     def _adapter_params(self, params: dict, motion_adapter) -> dict:

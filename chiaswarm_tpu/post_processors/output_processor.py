@@ -51,7 +51,10 @@ class OutputProcessor:
     def _package(self, images: list[Image.Image]) -> dict:
         composite = post_process(images)
         buffer = image_to_buffer(composite, self.main_content_type)
-        return make_result(buffer, buffer, self.main_content_type)
+        # PNG is lossless: the composite is what decoding `buffer` gives,
+        # so the thumbnail is made from it and the bytes are the same
+        thumb = composite if self.main_content_type == "image/png" else buffer
+        return make_result(buffer, thumb, self.main_content_type)
 
 
 def post_process(image_list: list[Image.Image]) -> Image.Image:
@@ -94,10 +97,14 @@ def image_to_buffer(
     return buffer
 
 
-def make_thumbnail(buffer) -> io.BytesIO:
-    if not isinstance(buffer, io.BytesIO):
-        buffer = io.BytesIO(buffer)
-    image = Image.open(buffer).convert("RGB")
+def make_thumbnail(source) -> io.BytesIO:
+    """A 100 px JPEG of `source`: an image, or the bytes or buffer of an
+    encoded one."""
+    if not isinstance(source, Image.Image):
+        if not isinstance(source, io.BytesIO):
+            source = io.BytesIO(source)
+        source = Image.open(source)
+    image = source.convert("RGB")  # a copy: thumbnail() works in place
     image.thumbnail(THUMBNAIL_SIZE, Image.Resampling.LANCZOS)
     return image_to_buffer(image, "image/jpeg", "web_low")
 

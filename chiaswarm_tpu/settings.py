@@ -78,8 +78,7 @@ class Settings:
     # jitted program (one resident base UNet, adapters as stacked
     # factors) instead of merging each adapter into a full param-tree
     # copy. Off restores the merged-tree path everywhere (and makes
-    # adapter jobs uncoalesceable again) — the A/B knob the lora_coalesce
-    # bench flips for its solo-merged baseline
+    # adapter jobs uncoalesceable again)
     lora_runtime_delta: bool = True
     # byte cap (MiB) for the process-wide raw adapter-factor LRU
     # (lora_cache.py); 0 disables caching (adapters reload per pass)

@@ -1165,7 +1165,7 @@ class Worker:
                     None, self._package_pass, chipset, results)
             for result in results:
                 self._finish_result(result, queue_wait, placement, traces)
-                await self._enqueue_result(result)
+            await self._enqueue_result(*results)
         except Exception as e:
             logger.exception("delivering a pass's results failed")
             print(f"deliver_pass {e}")
@@ -1739,20 +1739,29 @@ class Worker:
 
     # --- uploader (durable outbox, outbox.py) ---
 
-    async def _enqueue_result(self, result: dict) -> None:
-        """Spool the envelope to disk, then queue it for delivery — the
-        write-ahead half of the outbox contract. From this point the job
+    async def _enqueue_result(self, *results: dict) -> None:
+        """Spool the envelopes to disk, then queue them for delivery — the
+        write-ahead half of the outbox contract. From this point a job
         cannot be silently lost: only a hive ACK unlinks the file. The
-        write runs off-loop: a multi-MB artifact envelope on a slow disk
-        must not stall timers, polls, or the drain watcher."""
-        # the sender's identity rides the envelope (legacy hives ignore
-        # unknown keys): a lease-tracking hive needs it to attribute a
-        # LATE result to the worker that actually produced it, not to
-        # whoever holds the redelivered lease at arrival time
-        result.setdefault("worker_name", self.settings.worker_name)
-        entry = await asyncio.get_running_loop().run_in_executor(
-            None, self._spool, result)
-        await self.result_queue.put(entry)
+        writes run off-loop: a multi-MB artifact envelope on a slow disk
+        must not stall timers, polls, or the drain watcher. Envelopes
+        given together (a pass's) are queued together, and the uploader
+        takes them together."""
+        for result in results:
+            # the sender's identity rides the envelope (legacy hives
+            # ignore unknown keys): a lease-tracking hive needs it to
+            # attribute a LATE result to the worker that actually
+            # produced it, not to whoever holds the redelivered lease at
+            # arrival time
+            result.setdefault("worker_name", self.settings.worker_name)
+        loop = asyncio.get_running_loop()
+        entries = await asyncio.gather(*(
+            loop.run_in_executor(None, self._spool, result)
+            for result in results))
+        entries[0].followers = len(entries) - 1
+        for entry in entries:
+            # unbounded: put never waits, so the uploader wakes to all
+            await self.result_queue.put(entry)
 
     def _spool(self, result: dict) -> OutboxEntry:
         # span "spool": the envelope's write (histogram and profiler
@@ -1762,23 +1771,32 @@ class Worker:
 
     async def result_worker(self) -> None:
         while True:
-            entry = await self.result_queue.get()
-            self._delivering += 1
+            entries = [await self.result_queue.get()]
+            # a pass's envelopes go up together, not each after the
+            # other's ACK: a gang's clients see their jobs settle within
+            # milliseconds (two that resubmit at once are one gang again)
+            for _ in range(entries[0].followers):
+                entries.append(self.result_queue.get_nowait())
+            self._delivering += len(entries)
             try:
-                await self._deliver(entry)
-            except FaultInjected:
-                # fault harness only: a simulated crash after upload,
-                # before ACK — the envelope stays spooled for redelivery
-                logger.error(
-                    "injected crash before ack for %s", entry.job_id)
-                raise
-            except Exception as e:
-                logger.exception("result_worker error")
-                print(f"result_worker {e}")
+                outcomes = await asyncio.gather(
+                    *map(self._deliver, entries), return_exceptions=True)
             finally:
-                self._delivering -= 1
-                self.result_queue.task_done()
+                self._delivering -= len(entries)
+                for _ in entries:
+                    self.result_queue.task_done()
                 self._update_queue_gauges()
+            for entry, outcome in zip(entries, outcomes):
+                if isinstance(outcome, FaultInjected):
+                    # fault harness only: a simulated crash after upload,
+                    # before ACK — the envelope stays spooled for
+                    # redelivery
+                    logger.error(
+                        "injected crash before ack for %s", entry.job_id)
+                    raise outcome
+                if isinstance(outcome, Exception):
+                    logger.error("result_worker error", exc_info=outcome)
+                    print(f"result_worker {outcome}")
 
     async def _deliver(self, entry: OutboxEntry) -> None:
         """Upload one spooled envelope until the hive ACKs (capped

@@ -99,8 +99,8 @@ def _module_uses_torch(path: str) -> bool:
         return False
 
 
-# Modules whose tests spawn whole child processes (bench rows, chaos
-# scenarios: each a fresh interpreter + jax compile set) or compile a
+# Modules whose tests spawn whole child processes (chaos scenarios,
+# restarts: each a fresh interpreter + jax compile set) or compile a
 # whole pipeline family in-process from scratch (the IF cascade pair
 # and the svd golden-workflow module each jit multi-minute program
 # sets on a 1-core box; ~50 s per test, versus ~2 s for the median
@@ -108,12 +108,12 @@ def _module_uses_torch(path: str) -> bool:
 # clock; they sort AFTER the in-process tests (same rationale as the
 # torch ordering below: bank the hundreds of cheap results first, so
 # an external timeout chops the expensive integration tail rather
-# than the unit tests that happen to sort after "bench"
-# alphabetically). They still run exactly once, and still before the
-# torch group — a torch segfault must not eat them.
-_HEAVY_TAIL_MODULES = {"test_bench", "test_chaos_smoke", "test_chip_smoke",
-                       "test_dag_svd", "test_cascade", "test_deepfloyd",
-                       "test_depth"}
+# than the unit tests that happen to sort after them alphabetically).
+# They still run exactly once, and still before the torch group — a
+# torch segfault must not eat them.
+_HEAVY_TAIL_MODULES = {"test_chaos_smoke", "test_chip_smoke",
+                       "test_compile_cache", "test_dag_svd", "test_cascade",
+                       "test_deepfloyd", "test_depth"}
 
 
 def pytest_collection_modifyitems(config, items):

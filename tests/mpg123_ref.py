@@ -122,9 +122,12 @@ def roundtrip_snr_db(original: np.ndarray, decoded: np.ndarray) -> float:
     x = np.asarray(original, np.float64).ravel()
     y = np.asarray(decoded, np.float64).ravel()
     n = min(len(x), len(y))
-    corr = np.correlate(y[: n + 1024], x[:n], "full")
-    delay = int(np.argmax(np.abs(corr))) - (n - 1)
-    delay = max(delay, 0)
+    # the decoder's delay is the lag 0..1024 at which y best matches x.
+    # y is zero-padded to n + 1024 (the decoded stream ends a few hundred
+    # samples after x does), so "valid" is exactly those 1025 lags
+    head = y[: n + 1024]
+    head = np.pad(head, (0, n + 1024 - len(head)))
+    delay = int(np.argmax(np.abs(np.correlate(head, x[:n], "valid"))))
     m = min(len(x), len(y) - delay) - 1200
     if m <= 0:
         return float("-inf")

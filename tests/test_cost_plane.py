@@ -239,6 +239,31 @@ def test_analytic_flops_cross_check_against_real_xla():
                                                 abs=1e-3)
 
 
+def test_every_envelope_of_a_coalesced_pass_carries_its_own_cost_stamp():
+    """What the hive bills from: each job of one batched pass stamps its
+    OWN rows' FLOPs beside the shared pass figures, and on a platform
+    with no peak entry the work is still counted while MFU reads null."""
+    jax = pytest.importorskip("jax")
+    from chiaswarm_tpu.pipelines.stable_diffusion import SDPipeline
+
+    pipe = SDPipeline("test/tiny-sd")
+    outs = pipe.run_batched(
+        [dict(prompt="one row", rng=jax.random.key(1)),
+         dict(prompt="two rows", rng=jax.random.key(2),
+              num_images_per_prompt=2)],
+        height=64, width=64, num_inference_steps=2)
+    one, two = (cfg["cost"] for _, cfg in outs)
+    assert isinstance(one["flops"], int) and one["flops"] > 0
+    assert abs(two["flops"] - 2 * one["flops"]) <= 1
+    # padding rows are nobody's bill
+    assert one["flops"] + two["flops"] <= one["pass_flops"]
+    for stamp in (one, two):
+        assert stamp["pass_flops"] == one["pass_flops"]
+        assert stamp["tflops_per_s"] > 0
+        assert stamp["peak_tflops_per_chip"] is None  # CPU: no peak entry
+        assert stamp["mfu"] is None
+
+
 # --- memory_census.py --------------------------------------------------------
 
 

@@ -62,12 +62,16 @@ def _hydrated_inputs(stage: dict, stages: list[dict], results: dict) -> list:
 
 
 def _run_stage(stage: dict, stages: list[dict], results: dict):
-    """Execute one stage-job the way a worker would: format, then call
-    the routed callback with the ChipSet seed contract (pop `seed`,
-    inject `rng`) but no chip — every tiny model runs on CPU."""
     job = dict(stage["job"])
     job["stage"] = dict(job["stage"])
     job["stage"]["inputs"] = _hydrated_inputs(stage, stages, results)
+    return _run_job(job)
+
+
+def _run_job(job: dict):
+    """Execute one job the way a worker would: format, then call the
+    routed callback with the ChipSet seed contract (pop `seed`, inject
+    `rng`) but no chip — every tiny model runs on CPU."""
     func, kwargs = asyncio.run(format_args(job, Settings(), "cpu"))
     model_name = kwargs.pop("model_name", None)
     seed = kwargs.pop("seed", None)
@@ -78,8 +82,8 @@ def _run_stage(stage: dict, stages: list[dict], results: dict):
     return packaged(artifacts), config
 
 
-def _run_workflow(workflow_id: str):
-    stages = dag.expand_workflow(dict(PAYLOAD), workflow_id)
+def _run_workflow(workflow_id: str, payload: dict = PAYLOAD):
+    stages = dag.expand_workflow(dict(payload), workflow_id)
     results, configs = {}, {}
     for stage in stages:  # expansion order is topological
         artifacts, config = _run_stage(stage, stages, results)
@@ -163,3 +167,23 @@ def test_dag_workflow_is_deterministic(dag_run):
         b = {k: v.get("sha256_hash") for k, v in rerun[index].items()
              if isinstance(v, dict)}
         assert a == b, f"stage {index} drifted across runs"
+
+
+def test_txt2img_graph_hands_raw_rows_to_a_host_decode():
+    """The txt2img chain as a graph: encode and decode are host stages, the
+    denoise stage hands its rows over raw instead of packaging them, and
+    what the decode stage packages from them is the monolithic job's
+    artifact, byte for byte."""
+    job = dict(PAYLOAD["image_stage"], workflow="txt2img",
+               content_type="image/png")
+    stages, results, configs = _run_workflow("wft", job)
+    assert [(s["name"], s["needs"], s["handoff"]) for s in stages] == [
+        ("encode", [], None), ("denoise", [0], "raw"), ("decode", [1], "raw")]
+    assert configs[0]["stage"] == "encode"
+    assert set(results[1]) == {"raw"}  # rows, not an envelope
+    assert configs[2]["stage"] == "decode" and configs[2]["rows"] == 1
+
+    whole, _ = _run_job(dict(job, id="mono"))
+    assert results[2]["primary"]["content_type"] == "image/png"
+    assert results[2]["primary"]["sha256_hash"] == \
+        whole["primary"]["sha256_hash"]

@@ -20,6 +20,7 @@ from chiaswarm_tpu.post_processors.output_processor import (
     image_to_buffer,
     is_nsfw,
     make_text_result,
+    make_thumbnail,
     post_process,
 )
 
@@ -46,6 +47,30 @@ def test_single_image_result_envelope():
 
     thumb = Image.open(io.BytesIO(base64.b64decode(primary["thumbnail"])))
     assert max(thumb.size) <= 100
+
+
+@pytest.mark.parametrize("mode,n", [("RGB", 1), ("RGB", 3), ("RGBA", 1),
+                                    ("L", 1)])
+def test_png_thumbnail_is_the_one_decoding_the_png_gives(mode, n):
+    """A PNG artifact's thumbnail is made from the composite, without
+    decoding the PNG just written: the bytes are those of the decode."""
+    import numpy as np
+
+    rng = np.random.default_rng(7)
+    channels = {"RGB": 3, "RGBA": 4, "L": 1}[mode]
+    images = [Image.fromarray(np.squeeze(rng.integers(
+        0, 256, (96, 160, channels), dtype=np.uint8)), mode)
+        for _ in range(n)]
+    kept = [image.copy() for image in images]
+    proc = OutputProcessor(["primary"], "image/png")
+    proc.add_outputs(images)
+    primary = proc.get_results()["primary"]
+    png = base64.b64decode(primary["blob"])
+    assert base64.b64decode(primary["thumbnail"]) == make_thumbnail(
+        png).getvalue()
+    # made on a copy: the caller's images are as they were
+    assert all(a.size == b.size and a.tobytes() == b.tobytes()
+               for a, b in zip(images, kept))
 
 
 @pytest.mark.parametrize(

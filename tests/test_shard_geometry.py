@@ -84,19 +84,24 @@ def test_geometry_label():
 # --- per-pass geometry selection -------------------------------------------
 
 
-def test_sharded_pass_matches_replicated_and_stamps(pipe8):
+@pytest.mark.parametrize("tensor", [2, 4])
+def test_sharded_pass_matches_replicated_and_stamps(pipe8, tensor):
     ref, cfg0 = pipe8.run(rng=jax.random.key(3), **KW)
     assert cfg0["geometry"] == {"data": 8, "tensor": 1, "seq": 1}
 
+    data = 8 // tensor
     imgs, cfg = pipe8.run(rng=jax.random.key(3),
-                          geometry={"tensor": 2}, **KW)
-    assert cfg["geometry"] == {"data": 4, "tensor": 2, "seq": 1}
+                          geometry={"tensor": tensor}, **KW)
+    assert cfg["geometry"] == {"data": data, "tensor": tensor, "seq": 1}
     diff = np.abs(np.asarray(ref[0], np.int16)
                   - np.asarray(imgs[0], np.int16))
     assert diff.max() <= 2, f"max pixel diff {diff.max()}"
+    # the same work over the same slice, whatever the view
+    assert cfg["cost"]["flops"] == cfg0["cost"]["flops"] > 0
+    assert cfg["cost"]["chips"] == 8 and cfg["cost"]["mfu"] is None
     # the slice remembers the view its latest pass ran under
-    assert pipe8.chipset.last_geometry == (4, 2, 1)
-    assert pipe8.chipset.geometry_str() == "data4·tensor2·seq1"
+    assert pipe8.chipset.last_geometry == (data, tensor, 1)
+    assert pipe8.chipset.geometry_str() == f"data{data}·tensor{tensor}·seq1"
 
 
 def test_unmeshable_geometry_falls_back_to_default(pipe8):

@@ -136,7 +136,7 @@ def test_allow_random_init_policy():
     assert random_init_permitted("test/tiny-sd", False)
     assert random_init_permitted("segmind/tiny-sd", False)
     assert not random_init_permitted("stabilityai/stable-diffusion-2-1", False)
-    # the bench's explicit opt-in (bench.py) overrides the policy
+    # an explicit opt-in overrides the policy
     assert random_init_permitted("stabilityai/stable-diffusion-2-1", True)
 
 

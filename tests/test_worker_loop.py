@@ -1066,7 +1066,12 @@ def two_gangs_slow_packaging(tmp_path_factory):
     from chiaswarm_tpu.workflows import diffusion
 
     real = diffusion._package
-    calls, arrivals = [], []
+    calls, arrivals, settled = [], [], []
+
+    class Stamped(list):
+        def append(self, envelope):
+            settled.append(time.time())
+            super().append(envelope)
 
     def slow(images, outputs, content_type):
         calls.append(([image.copy() for image in images], outputs,
@@ -1080,6 +1085,8 @@ def two_gangs_slow_packaging(tmp_path_factory):
     async def scenario():
         hive = await FakeHive().start()
         hive.gang_max = 2
+        hive.slow_results_s = 0.3  # a POST apart would be this far apart
+        hive.results = Stamped()
         for job in tiny_png_jobs("gang-", 4):
             hive.add_job(job)
         w = Worker(settings=Settings(sdaas_token="t", worker_name="w"),
@@ -1106,9 +1113,11 @@ def two_gangs_slow_packaging(tmp_path_factory):
         results = asyncio.run(scenario())
         moved = overlapped() - before
     by_id = {result["id"]: result for result in results}
+    settled_at = {result["id"]: at for result, at in zip(results, settled)}
     return types.SimpleNamespace(
         results=[by_id[f"gang-{i}"] for i in range(4)], calls=calls,
-        arrivals=arrivals, overlapped_s=moved, package=real)
+        arrivals=arrivals, overlapped_s=moved, package=real,
+        settled_at=[settled_at[f"gang-{i}"] for i in range(4)])
 
 
 def test_next_pass_runs_while_the_last_is_packaged(two_gangs_slow_packaging):
@@ -1161,6 +1170,16 @@ def test_a_gangs_envelopes_are_enqueued_together_and_in_order(
             for span in spans_named(r, "artifact_encode"))
         assert first_at >= last_encoded - 1e-3
         assert second_at - first_at < 0.2  # an encode is 0.4 s here
+
+
+def test_a_gangs_envelopes_are_uploaded_together(two_gangs_slow_packaging):
+    """(g) A pass's envelopes are posted at once, not one after the other's
+    ACK: the hive (0.3 s a POST here) settles a gang's jobs at one instant,
+    so its clients see them together."""
+    at = two_gangs_slow_packaging.settled_at
+    for gang in (0, 2):
+        assert abs(at[gang + 1] - at[gang]) < 0.15
+    assert at[2] - at[0] > 0.15  # the two passes are apart all the same
 
 
 @pytest.fixture(scope="module")
