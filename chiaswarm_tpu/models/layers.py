@@ -12,6 +12,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..ops import dot_product_attention
+from ..ops.activations import gelu_erf
 from ..ops.group_norm import group_norm
 
 
@@ -147,7 +148,8 @@ class GEGLU(nn.Module):
     def __call__(self, x):
         h = nn.Dense(self.dim * 2, dtype=self.dtype, name="proj")(x)
         h, gate = jnp.split(h, 2, axis=-1)
-        return h * nn.gelu(gate, approximate=False)  # erf gelu, diffusers parity
+        # erf gelu, diffusers parity: float32 inside, one rounding
+        return (h * gelu_erf(gate)).astype(self.dtype)
 
 
 class FeedForward(nn.Module):

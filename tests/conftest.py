@@ -52,6 +52,7 @@ _QUICK_MODULES = {
     "test_external_resources",
     "test_faults",
     "test_flash_attention",
+    "test_geglu_numerics",
     "test_hive_protocol",
     "test_hive_replication",
     "test_job_arguments",
