@@ -1,17 +1,21 @@
 """The `breakdown` of a traced run: the device operations that took most
 time, under the names the trace gives, and the idle gaps by what the host
-was doing — as far as the benchmark can see from outside the program: the
-hive timeline's wall stamps and the envelope's stage durations. Finer
-labels need the program's spans in the profiler's trace (PERF.md, Open
-questions)."""
+was doing, in the program's own words: each gap is put on the wall clock
+and takes the name of the span the program stamped over its middle
+(`pipeline_config.spans` of the envelopes, `spans.py`). No stage of any
+pipeline is named here; a program that stamps other stages gets other
+labels, and one that stamps none gets `unknown`."""
 
 from __future__ import annotations
 
 import re
 
-from . import measure
+from . import measure, spans
 
 TOP = 10
+# where the worker stamps what it does for a pass after letting its slice
+# go (the packaging of the pass's artifacts): work, not a wait
+HOST_THREAD = "host"
 
 
 def clock(trace: dict):
@@ -25,41 +29,61 @@ def clock(trace: dict):
     return None
 
 
-def host_phases(record: dict) -> list[tuple[float, float, str]]:
-    """(from, to, label) in wall seconds for every pass, rebuilt from the
-    envelope: the worker's receipt stamp, then the stage durations laid end
-    to end, then the upload until the pass's last settle."""
-    jobs = [job for job in record["jobs"] if measure.done(job)]
-    by_pass: dict[str, list[dict]] = {}
+def covers(span: dict, wall: float) -> bool:
+    return span["start_wall"] <= wall <= spans.end(span)
+
+
+def innermost(found: list[dict], wall: float) -> dict | None:
+    """The shortest of `found` over `wall`: of nested spans the inner."""
+    over = [span for span in found if covers(span, wall)]
+    return min(over, key=lambda span: span["seconds"]) if over else None
+
+
+def passes_of(jobs: list[dict]) -> list[dict]:
+    """What labels a gap, for every pass that stamped spans: each `pass`
+    span (the slice held) with its children on its thread, the spans of
+    the host thread, and the stretch from the slice let go to the pass's
+    last settle at the hive."""
+    grouped: dict[str, list[dict]] = {}
     for job in jobs:
-        by_pass.setdefault(measure.pass_id(job), []).append(job)
-    phases = []
-    for members in by_pass.values():
-        job = members[0]
-        received = (measure.envelope(job).get("trace") or {}).get(
-            "received_wall")
-        if received is None:
+        grouped.setdefault(measure.pass_id(job), []).append(job)
+    out = []
+    for members in grouped.values():
+        found = spans.of_pass(members)
+        if not found:
             continue
-        t = received + (measure.timing(job, "queue_wait_s") or 0.0)
-        end = t + (measure.timing(job, "job_s") or 0.0)
-        for stage, label in (("text_encode_s", "text encode"),
-                             ("trace_s", "program lookup"),
-                             ("denoise_decode_s", "inside denoise + decode")):
-            seconds = measure.timing(job, stage) or 0.0
-            phases.append((t, t + seconds, label))
-            t += seconds
-        phases.append((t, end, "artifact encode"))
-        settled = max(measure.stamp(m, "settle") or end for m in members)
-        phases.append((end, settled, "upload + settle"))
-    return phases
+        held = spans.named(found, "pass")
+        settles = [s for s in (measure.stamp(m, "settle") for m in members)
+                   if s is not None]
+        out.append({
+            "held": [(parent, spans.children(found, parent))
+                     for parent in held],
+            "host": [s for s in found if s["thread"] == HOST_THREAD],
+            "settling": ((max(map(spans.end, held)), max(settles))
+                         if held and settles else None)})
+    return out
 
 
-def label_gap(phases, lo: float, hi: float) -> str:
-    middle = (lo + hi) / 2
-    for start, end, label in phases:
-        if start <= middle <= end:
-            return label
-    return "between passes (poll + hive)" if phases else "unknown"
+def label_gap(passes: list[dict], wall: float) -> str:
+    """The name of what the host was doing at `wall`: under a `pass`, its
+    innermost child there; with the slice free, the host thread's span, or
+    the settling of a pass, or neither."""
+    if not passes:
+        return "unknown"
+    for entry in passes:
+        for parent, children in entry["held"]:
+            if covers(parent, wall):
+                child = innermost(children, wall)
+                return child["name"] if child else "pass (no child span)"
+    for entry in passes:
+        span = innermost(entry["host"], wall)
+        if span:
+            return span["name"]
+    for entry in passes:
+        if entry["settling"] and \
+                entry["settling"][0] <= wall <= entry["settling"][1]:
+            return "upload + settle"
+    return "between passes (poll + hive)"
 
 
 def build(record: dict) -> dict | None:
@@ -68,14 +92,14 @@ def build(record: dict) -> dict | None:
         return None
     ops = sorted(trace["op_seconds"].items(), key=lambda kv: -kv[1])[:TOP]
     to_wall = clock(trace)
-    phases = host_phases(record)
+    passes = passes_of(record["jobs"])
     gaps: dict[str, list[float]] = {}
     for lo, hi in trace["gaps_ns"]:
         label = ("unknown" if to_wall is None
-                 else label_gap(phases, to_wall(lo), to_wall(hi)))
+                 else label_gap(passes, to_wall((lo + hi) / 2)))
         gaps.setdefault(label, []).append((hi - lo) / 1e9)
-    idle = sorted(((f"{label} x{len(spans)}", sum(spans))
-                   for label, spans in gaps.items()),
+    idle = sorted(((f"{label} x{len(found)}", sum(found))
+                   for label, found in gaps.items()),
                   key=lambda kv: -kv[1])[:TOP]
     return {"device_ops": [[name, seconds] for name, seconds in ops],
             "idle_gaps": [[label, seconds] for label, seconds in idle]}

@@ -1,14 +1,13 @@
 """The comparisons that decide `correct` (README, "What correct means").
 
 Each returns a list of failure strings; an empty list passes. Tolerances
-are written with their reason: the kernels' here, the denoiser's in the
-family module that knows the network (`families/<family>.py`).
+are written with their reason: the shared kernels' here, the denoiser's in
+the family module that knows the network (`families/<family>.py`). What a
+job's artifact has to be is the family's too (`check_artifact`): the harness
+knows no kind of artifact, so nothing here opens one.
 """
 
 from __future__ import annotations
-
-import hashlib
-import io
 
 # Kernels against the float32 reference, max abs error on unit-normal
 # bfloat16 inputs. The chip read 0.0005-0.0065 for attention (the largest
@@ -22,10 +21,12 @@ GROUP_NORM_TOL = 0.05
 
 
 def kernels(config: dict, dtype, interpret: bool = False) -> tuple[list, list]:
-    """correct 4: the program's attention and GroupNorm dispatch against
-    the plain references at the configuration's own shapes (either list
-    may be absent: a network without GroupNorm has none). Returns
-    (failures, readings)."""
+    """correct 4, the part families share (a family's `kernel_checks`
+    returns this, or adds the comparison of a kernel of its own): the
+    program's attention and GroupNorm dispatch against the plain references
+    at the configuration's own shapes (either list may be absent: a network
+    without GroupNorm has none). Returns (failures, readings), a reading
+    `{<kernel>: shape, "max_abs": number, "limit": its tolerance}`."""
     import jax
     import jax.numpy as jnp
 
@@ -44,7 +45,8 @@ def kernels(config: dict, dtype, interpret: bool = False) -> tuple[list, list]:
         got = jax.jit(dot_product_attention)(q, k, v)
         err = float(jnp.max(jnp.abs(
             jnp.asarray(got, jnp.float32) - ref.attention(q, k, v))))
-        readings.append({"attention": [sq, skv, heads, dim], "max_abs": err})
+        readings.append({"attention": [sq, skv, heads, dim], "max_abs": err,
+                         "limit": ATTENTION_TOL})
         if not err <= ATTENTION_TOL:
             failures.append(f"attention {sq}x{skv}x{heads}x{dim}: max abs "
                             f"error {err:.4f} over {ATTENTION_TOL}")
@@ -59,7 +61,8 @@ def kernels(config: dict, dtype, interpret: bool = False) -> tuple[list, list]:
         err = float(jnp.max(jnp.abs(
             jnp.asarray(got, jnp.float32)
             - ref.group_norm_silu(x, scale, bias))))
-        readings.append({"group_norm": [h, w, c], "max_abs": err})
+        readings.append({"group_norm": [h, w, c], "max_abs": err,
+                         "limit": GROUP_NORM_TOL})
         if not err <= GROUP_NORM_TOL:
             failures.append(f"group_norm {h}x{w}x{c}: max abs error "
                             f"{err:.4f} over {GROUP_NORM_TOL}")
@@ -80,26 +83,11 @@ def denoiser(family, pipe, inputs, want) -> tuple[list, dict]:
     rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     reading = {"rel_l2": rel,
                "max_abs": float(jnp.max(jnp.abs(got - want))),
-               "ref_rms": float(jnp.sqrt(jnp.mean(want ** 2)))}
+               "ref_rms": float(jnp.sqrt(jnp.mean(want ** 2))),
+               "limit": family.DENOISER_REL_L2_TOL}
     failures = []
     if not rel <= family.DENOISER_REL_L2_TOL:
         failures.append(f"denoiser differs from the plain reference by "
                         f"{rel:.4f} relative L2, over "
                         f"{family.DENOISER_REL_L2_TOL}")
     return failures, reading
-
-
-def artifact(blob: bytes, ref: dict, height: int, width: int) -> str | None:
-    """correct 1 for one job's primary artifact: hashes to its name,
-    decodes to the canvas, is not constant."""
-    import numpy as np
-    from PIL import Image
-
-    if hashlib.sha256(blob).hexdigest() != ref.get("sha256"):
-        return "artifact does not hash to its name"
-    pixels = np.asarray(Image.open(io.BytesIO(blob)).convert("RGB"))
-    if pixels.shape != (height, width, 3):
-        return f"image decodes to {pixels.shape}, not {height}x{width}"
-    if pixels.min() == pixels.max():
-        return f"image is constant ({pixels.min()})"
-    return None

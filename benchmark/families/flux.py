@@ -1,6 +1,7 @@
 """The `flux` pipeline family: everything the benchmark knows of FLUX.1's
-MMDiT. Seeded weights made already sharded, the denoiser's half of `correct`
-5 and the compile check's operands (README, "A family").
+MMDiT. Seeded weights made already sharded, what a job carries and how its
+artifact is judged (`pictures.py`), the kernels' comparison, the denoiser's
+half of `correct` 5 and the compile check's operands (README, "A family").
 
 It reads the program through public names only: `FluxPipeline(...,
 weights=)`, `param_shapes()` / `param_shardings()` (the placed tree as
@@ -48,7 +49,19 @@ import math
 import time
 import weakref
 
+from .. import checks
+from . import pictures
+
 FAMILY = "flux"
+# the wire name the registry resolves this family by (the configuration's
+# jobs send the same in `parameters`)
+PIPELINE_TYPE = "FluxPipeline"
+# a job is a prompt and returns a picture at the configuration's canvas
+check_artifact = pictures.check_artifact
+job_fields = pictures.job_fields
+# `correct` 4: the shared attention dispatch at `attention_shapes`, no
+# kernel of this network's own
+kernel_checks = checks.kernels
 # Velocity against the plain reference, relative L2, one row, every block.
 # The rule of two readings (my chip runs, PR 27, four chips, one weight seed
 # and three input seeds at 512^2, one at 1024^2): sound bfloat16 runs (bf16

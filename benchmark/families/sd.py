@@ -1,6 +1,7 @@
 """The `sd` / `sdxl` pipeline families: everything the benchmark knows of
-this network. On-device seeded weights, the denoiser's half of `correct` 5
-and the compile check's operands (README, "A family").
+this network. On-device seeded weights, what a job carries and how its
+artifact is judged (`pictures.py`), the kernels' comparison, the denoiser's
+half of `correct` 5 and the compile check's operands (README, "A family").
 
 The program initialises `test/*` models eagerly on the host (~950 XLA:CPU
 compiles, 150 s for SDXL: PERF.md section 5), a path no user pays. The
@@ -25,7 +26,19 @@ from __future__ import annotations
 import math
 import time
 
+from .. import checks
+from . import pictures
+
 FAMILIES = ("sd", "sdxl")
+# the wire name the registry resolves these models by, from the model's
+# name, where a job's `parameters` give none
+PIPELINE_TYPE = "DiffusionPipeline"
+# a job is a prompt and returns a picture at the configuration's canvas
+check_artifact = pictures.check_artifact
+job_fields = pictures.job_fields
+# `correct` 4: the shared attention and GroupNorm dispatch, no kernel of
+# this network's own
+kernel_checks = checks.kernels
 # Denoiser against the plain reference: relative L2 error of the predicted
 # noise. bfloat16 weights and activations with float32 accumulation read
 # 0.011-0.012 (SD2.1 768^2) and 0.013-0.015 (SDXL 1024^2) over nine seeds

@@ -87,9 +87,10 @@ def apply_rehearsal(spec: dict) -> None:
 
 
 # what a module under `families/` has to have (README, "A family")
-FAMILY_CONTRACT = ("register", "denoiser_inputs", "denoiser_reference",
-                   "denoiser_serve", "DENOISER_REL_L2_TOL",
-                   "compile_operands")
+FAMILY_CONTRACT = ("register", "PIPELINE_TYPE", "job_fields",
+                   "check_artifact", "kernel_checks", "denoiser_inputs",
+                   "denoiser_reference", "denoiser_serve",
+                   "DENOISER_REL_L2_TOL", "compile_operands")
 
 
 def load_family(config: dict):
@@ -249,36 +250,37 @@ class Window:
 
 class JobMaker:
     """Jobs of the cell's traffic, drawn from the seed: the configuration's
-    `job` block, the traffic's `job` block, and per job a distinct prompt
-    (subject x style x a running number) and seed."""
+    `job` block, the traffic's `job` block, a running id, and per job a
+    seed and what the family's `job_fields` says differs from job to job.
+    One generator makes every draw: the family's first, then the seed's."""
 
-    def __init__(self, spec: dict, seed: int):
+    def __init__(self, spec: dict, seed: int, family):
         import random
 
         self.config, self.traffic = spec["config"], spec["traffic"]
         self.tag = f"{spec['cell']['name']}-{seed}"
+        self.fields = family.job_fields
         self.rng = random.Random(seed)
         self.count = 0
 
-    def _job(self, prompt: str, seed: int) -> dict:
+    def _job(self, probe: bool) -> dict:
+        fields = self.fields(self.rng, self.traffic, self.count, probe)
+        seed = (int(self.traffic["probe"]["seed"]) if probe
+                else self.rng.getrandbits(31))
         self.count += 1
         job = {**self.config["job"], **self.traffic["job"]}
         job["parameters"] = {**self.config["job"].get("parameters", {}),
                              **self.traffic["job"].get("parameters", {})}
-        job.update(id=f"{self.tag}-{self.count:05d}", prompt=prompt,
-                   seed=seed)
+        job.update(id=f"{self.tag}-{self.count:05d}", **fields, seed=seed)
         return job
 
     def next(self) -> dict:
-        prompts = self.traffic["prompts"]
-        prompt = (f"{self.rng.choice(prompts['subjects'])}, "
-                  f"{self.rng.choice(prompts['styles'])}, "
-                  f"take {self.count}")
-        return self._job(prompt, self.rng.getrandbits(31))
+        return self._job(probe=False)
 
     def probe(self) -> dict:
-        probe = self.traffic["probe"]
-        return self._job(probe["prompt"], int(probe["seed"]))
+        """One job that is the same whatever the running count: the
+        traffic's `probe` block gives its seed, the family its fields."""
+        return self._job(probe=True)
 
 
 # --- the run ----------------------------------------------------------------
@@ -380,12 +382,17 @@ async def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
 
     for model in config.get("resident_models", ()):
         # a worker keeps several models resident (MAX_RESIDENT_PIPELINES):
-        # weights only, nothing compiled, never asked for
+        # weights only, nothing compiled, never asked for. A name is a
+        # model of the cell's own family; one of another says its type
+        if isinstance(model, str):
+            model = {"model_name": model,
+                     "pipeline_type": family.PIPELINE_TYPE}
         await loop.run_in_executor(
-            None, registry.get_pipeline, model, "DiffusionPipeline")
+            None, registry.get_pipeline, model["model_name"],
+            model["pipeline_type"])
     dtype = jax.numpy.dtype(config["kernel_dtype"])
     failures, readings = await loop.run_in_executor(
-        None, checks.kernels, config, dtype, rehearsal)
+        None, family.kernel_checks, config, dtype, rehearsal)
     record["failures"] += failures
     record["kernel_readings"] = readings
     emit(phase="kernels", readings=readings,
@@ -398,7 +405,7 @@ async def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     session = aiohttp.ClientSession()
     try:
         client = Client(session, swarm.hive.uri, settings.sdaas_token)
-        jobs = JobMaker(spec, seed)
+        jobs = JobMaker(spec, seed, family)
         gang = min(int(traffic["clients"]),
                    int(settings.hive_max_jobs_per_poll))
         window = Window(seconds)
@@ -468,7 +475,7 @@ async def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         record["jobs"] = load
         record["window"] = {"open_wall": window.open_wall,
                             "close_wall": window.close_wall}
-        await check_jobs(client, record, config, probes)
+        await check_jobs(client, record, family, probes)
         record["scrape_end"] = scrape()
         check_kernel_paths_and_compiles(record, config)
         record["trace_file"] = trace_file
@@ -498,7 +505,7 @@ async def at_window_close(window: Window, record: dict, worker, family):
     job = record["spec"]["config"]["job"]
     pipe = registry.get_pipeline(  # resident: the worker built it
         job["model_name"],
-        job.get("parameters", {}).get("pipeline_type", "DiffusionPipeline"),
+        job.get("parameters", {}).get("pipeline_type", family.PIPELINE_TYPE),
         chipset=worker.allocator.slices[0])
     inputs = family.denoiser_inputs(
         pipe, record["spec"]["config"], record["seed"])
@@ -517,13 +524,12 @@ def memory_stats() -> dict:
     return {"peak_bytes": peak}
 
 
-async def check_jobs(client: Client, record: dict, config: dict,
+async def check_jobs(client: Client, record: dict, family,
                      probes: list[str]) -> None:
     """correct 1 and 3: every job of the window, and the probe's two
-    rides."""
-    from . import checks
-
-    height, width = int(config["job"]["height"]), int(config["job"]["width"])
+    rides. What a primary artifact has to be is the family's to say
+    (`check_artifact`); that the probe's two are the same bytes is not."""
+    config = record["spec"]["config"]
     open_wall = record["window"]["open_wall"]
     attempted = failed = 0
     for job in record["jobs"]:
@@ -542,8 +548,8 @@ async def check_jobs(client: Client, record: dict, config: dict,
                    f"{status.get('error')}")
         else:
             ref = result["artifacts"]["primary"]
-            why = checks.artifact(await client.artifact(ref["href"]), ref,
-                                  height, width)
+            why = family.check_artifact(
+                await client.artifact(ref["href"]), ref, config)
         if why:
             failed += 1
             job["failure"] = why
@@ -557,15 +563,15 @@ async def check_jobs(client: Client, record: dict, config: dict,
         status = await client.status(job_id)
         ref = status["result"]["artifacts"]["primary"]
         blob = await client.artifact(ref["href"])
-        why = checks.artifact(blob, ref, height, width)
+        why = family.check_artifact(blob, ref, config)
         if why:
             record["failures"].append(f"probe {job_id}: {why}")
         digests.append(hashlib.sha256(blob).hexdigest())
     record["probe_sha256"] = digests
     if len(set(digests)) != 1:
         record["failures"].append(
-            f"the probe (one prompt, one seed) gave {len(set(digests))} "
-            f"images among different batchmates: {digests}")
+            f"the probe (one job, one seed) gave {len(set(digests))} "
+            f"artifacts among different batchmates: {digests}")
 
 
 def check_kernel_paths_and_compiles(record: dict, config: dict) -> None:
@@ -576,10 +582,12 @@ def check_kernel_paths_and_compiles(record: dict, config: dict) -> None:
               - before.get("swarm_kernel_traces_total", {}).get(labels, 0.0)
               for labels in end.get("swarm_kernel_traces_total", {})}
     record["kernel_traces"] = traced
-    for labels in config["expected_kernel_paths"]:
-        if not traced.get(labels, 0.0) > 0:
-            record["failures"].append(
-                f"the worker's programs never traced {labels}: {traced}")
+    missing = [labels for labels in config["expected_kernel_paths"]
+               if not traced.get(labels, 0.0) > 0]
+    record["kernel_paths_missing"] = len(missing)
+    for labels in missing:
+        record["failures"].append(
+            f"the worker's programs never traced {labels}: {traced}")
     moved = (counter(record["scrape_close"], "swarm_xla_compiles_total")
              - counter(record["scrape_open"], "swarm_xla_compiles_total"))
     record["window_compiles"] = moved
@@ -599,6 +607,24 @@ def device_block(record: dict) -> dict:
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
     return device
+
+
+def compared(record: dict) -> dict:
+    """Every number `correct` compared, as `[number, limit]` under a short
+    name: what the result line carries last and stderr ends with."""
+    out = {}
+    for reading in record["kernel_readings"]:
+        kernel = next(iter(reading))  # {<kernel>: shape, max_abs, limit}
+        shape = "x".join(str(n) for n in reading[kernel])
+        out[f"{kernel}_{shape}_max_abs"] = [reading["max_abs"],
+                                           reading["limit"]]
+    reading = record["denoiser_reading"]
+    out["denoiser_rel_l2"] = [reading["rel_l2"], reading["limit"]]
+    out["jobs_failed"] = [record["failed"], 0]
+    out["probe_distinct_sha256"] = [len(set(record["probe_sha256"])), 1]
+    out["kernel_paths_missing"] = [record["kernel_paths_missing"], 0]
+    out["window_compiles"] = [record["window_compiles"], 0]
+    return out
 
 
 # what a CPU rehearsal may print: counts. Everything else it computes (to
@@ -655,10 +681,10 @@ def report(record: dict, traced: bool) -> dict:
     if record["rehearsal"]:
         result["device"] = {**record["device"], "rehearsal":
                             "CPU rehearsal: counts only, no device number"}
-        return result
-    result["device"] = device_block(record)
-    if traced:
-        built = breakdown.build(record)
+    else:
+        result["device"] = device_block(record)
+        built = breakdown.build(record) if traced else None
         if built:
             result["breakdown"] = built
+    result["compared"] = compared(record)  # last in the line
     return result

@@ -5,8 +5,10 @@
 
 One process, which holds the chip: it stands the swarm up, warms it,
 measures for `--seconds`, checks the outputs, and prints as its last line
-one JSON object (`correct`, `attempted`, `failed`, `metrics`, `device`, and
-with `--trace 1` `breakdown`). Earlier lines are one JSON object each.
+one JSON object (`correct`, `attempted`, `failed`, `metrics`, `device`, with
+`--trace 1` `breakdown`, and last `compared`: every number `correct`
+compared beside its limit, which are also the last lines of stderr).
+Earlier lines are one JSON object each.
 Fails (no result, exit code other than 0) without a TPU, with another
 number of chips than the cell asks for, or outside a whole checkout.
 `benchmark/rehearse.py` is the CPU rehearsal; this command never falls
@@ -72,6 +74,8 @@ def main(argv=None, platform: str = "tpu", rehearsal: bool = False) -> int:
     except harness.RunFailure as failure:
         print(f"benchmark: {failure}", file=sys.stderr)
         return 3
+    for name, (number, limit) in result["compared"].items():
+        print(f"compared {name}: {number} limit {limit}", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -79,8 +79,18 @@ def code_of(path: Path) -> str:
 DATA_DRIVEN = ("harness.py", "run.py", "rehearse.py", "measure.py",
                "breakdown.py", "checks.py", "compile_check.py",
                "generators/closed_loop.py", "trace/reduce.py")
-# one network's own names: they belong in its module under `families/`
-NETWORK_WORDS = ("unet", "is_xl", "unet2d", "SDPipeline", "_denoise_program")
+# one network's own names, and one kind of artifact's (a canvas, the library
+# that opens a picture, a pipeline's stage keys, a wire name of the
+# registry): they belong in a module under `families/`
+NETWORK_WORDS = ("unet", "is_xl", "unet2d", "SDPipeline", "_denoise_program",
+                 "height", "width", "PIL", "denoise_decode_s",
+                 "text_encode_s", "DiffusionPipeline")
+# the same as whole words, case kept, docstrings and comments included:
+# what the harness proper may not even mention
+PICTURE_WORDS = ("height", "width", "PIL", "Image", "denoise_decode_s",
+                 "text_encode_s", "DiffusionPipeline")
+HARNESS_PROPER = ("harness.py", "checks.py", "breakdown.py", "measure.py",
+                  "run.py")
 
 
 def test_harness_names_no_cell_model_or_metric():
@@ -108,6 +118,13 @@ def test_harness_knows_no_network(path):
         found = re.search(rf"(?i)(?<![0-9a-z]){re.escape(word)}(?![0-9a-z])",
                           code)
         assert not found, (path, word)
+
+
+@pytest.mark.parametrize("path", HARNESS_PROPER)
+def test_the_harness_proper_mentions_no_picture(path):
+    text = (REPO / BENCH["paths"][0] / path).read_text()
+    found = re.findall(rf"\b(?:{'|'.join(PICTURE_WORDS)})\b", text)
+    assert not found, (path, found)
 
 
 @pytest.mark.parametrize("chips", [4, 1])

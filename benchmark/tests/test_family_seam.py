@@ -1,9 +1,13 @@
-"""The harness, the checks and the compile check know no network: they call
-through the module that `config["family"]` names. Shown on `stub_family.py`,
-which is no diffusion UNet."""
+"""The harness, the checks and the compile check know no network and no
+kind of artifact: they call through the module that `config["family"]`
+names. Shown on `stub_family.py`, which is no diffusion UNet and whose jobs
+return text."""
 
 import asyncio
+import hashlib
 import importlib.util
+import io
+import json
 import os
 import sys
 import time
@@ -56,11 +60,13 @@ def test_the_three_calls_go_through_the_configurations_family(
     got_pipe, inputs, want, _ = asyncio.run(
         harness.at_window_close(closed_window(), record, worker, family))
     assert got_pipe is pipe
-    assert asked == [("test/stub", "DiffusionPipeline", "the slice")]
+    # no type in the job's `parameters`: the family's own
+    assert asked == [("test/stub", stub.PIPELINE_TYPE, "the slice")]
     assert inputs["x"].shape == (4, stub.WIDTH)
     assert "peak_bytes" in record["memory"] and record["scrape_close"]
     failures, reading = checks.denoiser(family, pipe, inputs, want)
-    assert failures == [] and set(reading) == {"rel_l2", "max_abs", "ref_rms"}
+    assert failures == [] and set(reading) == {
+        "rel_l2", "max_abs", "ref_rms", "limit"}
     assert stub.CALLS == ["denoiser_inputs", "denoiser_reference",
                           "denoiser_serve"]
 
@@ -95,6 +101,114 @@ def test_a_family_without_a_name_fails_before_the_swarm_starts(
     with pytest.raises(harness.RunFailure, match=name):
         asyncio.run(harness.run_cell(spec, 1, 1.0, False, time.monotonic()))
     assert "register" not in stub.CALLS
+
+
+def png(height: int, width: int) -> bytes:
+    import numpy as np
+    from PIL import Image
+
+    pixels = np.random.default_rng(1).integers(
+        0, 255, (height, width, 3), np.uint8)
+    out = io.BytesIO()
+    Image.fromarray(pixels).save(out, format="PNG")
+    return out.getvalue()
+
+
+def caption(text: str) -> bytes:
+    return json.dumps({"caption": text}).encode()
+
+
+class Hive:
+    """The client's two calls `check_jobs` makes, over artifacts in hand:
+    job `n` is done and its primary artifact is `blobs[n]`, named by its
+    own sha256 unless `misnamed`."""
+
+    def __init__(self, blobs: list[bytes], misnamed: int | None = None):
+        self.blobs = {f"/a/{n}": blob for n, blob in enumerate(blobs)}
+        self.misnamed = misnamed
+
+    def _status(self, n: int) -> dict:
+        digest = hashlib.sha256(
+            b"other" if n == self.misnamed else self.blobs[f"/a/{n}"])
+        return {"status": "done", "attempts": 1, "result": {
+            "pipeline_config": {}, "artifacts": {"primary": {
+                "href": f"/a/{n}", "sha256": digest.hexdigest()}}}}
+
+    async def status(self, job_id: str) -> dict:
+        return self._status(int(job_id))
+
+    async def artifact(self, href: str) -> bytes:
+        return self.blobs[href]
+
+    def record(self, config: dict, window_jobs: int) -> dict:
+        jobs = [{"id": str(n), "submit_wall": 10.0 + n, "withdrawn": False,
+                 "status": self._status(n)} for n in range(window_jobs)]
+        return {"spec": {"config": config}, "jobs": jobs, "failures": [],
+                "window": {"open_wall": 10.0, "close_wall": 60.0}}
+
+
+SD_CONFIG = {"family": "sd", "job": {"height": 64, "width": 64}}
+
+
+@pytest.mark.parametrize("config, blobs, misnamed, words", [
+    (CONFIG, [caption("a"), caption("b"), caption("p"), caption("p")],
+     None, None),
+    (SD_CONFIG, [png(64, 64)] * 4, None, None),
+    (CONFIG, [caption("a"), caption("b"), caption("p"), caption("p")],
+     1, "job 1: text artifact does not hash to its name"),
+    (CONFIG, [caption("a"), b"not json", caption("p"), caption("p")],
+     None, "job 1: text artifact is no JSON object with a caption"),
+    (CONFIG, [caption("a"), caption(" "), caption("p"), caption("p")],
+     None, "job 1: caption is ' ', not a line of text"),
+    (SD_CONFIG, [png(64, 64), png(32, 64), png(64, 64), png(64, 64)],
+     None, "job 1: image decodes to (32, 64, 3), not 64x64"),
+    (SD_CONFIG, [png(64, 64), png(64, 64), png(64, 32), png(64, 32)],
+     None, "probe 2: image decodes to (64, 32, 3), not 64x64"),
+    (SD_CONFIG, [png(64, 64), png(64, 64), png(64, 64), png(64, 64)],
+     2, "probe 2: artifact does not hash to its name"),
+    (CONFIG, [caption("a"), caption("b"), caption("p"), caption("q")],
+     None, "the probe (one job, one seed) gave 2 artifacts"),
+], ids=["text", "picture", "text-misnamed", "text-no-json", "text-empty",
+        "picture-other-canvas", "probe-other-canvas", "probe-misnamed",
+        "probe-two-answers"])
+def test_every_artifact_is_judged_in_the_familys_own_words(
+        stub, config, blobs, misnamed, words):
+    """Two window jobs and the probe's two rides: the run is correct when
+    the family takes all four and the probe's are the same bytes, and
+    fails with what the family said of the one it refused."""
+    family = harness.load_family(config)
+    hive = Hive(blobs, misnamed)
+    record = hive.record(config, window_jobs=2)
+    asyncio.run(harness.check_jobs(hive, record, family, ["2", "3"]))
+    assert (record["attempted"], len(record["probe_sha256"])) == (2, 2)
+    if words is None:
+        assert record["failures"] == [] and record["failed"] == 0
+    else:
+        assert record["failures"][0].startswith(words), record["failures"]
+        assert record["failed"] == int(words.startswith("job"))
+
+
+@pytest.mark.parametrize("seed", range(300, 312))
+def test_the_stub_s_own_kernel_is_held_to_its_own_tolerance(stub, seed):
+    """Sound float32 reads under a third of the tolerance, the bfloat16
+    control over three times it, and fails in the family's words."""
+    import jax.numpy as jnp
+
+    config = {"stub_matmul_shapes": [[64, 32, 16]] * (seed - 299)}
+    failures, readings = stub.kernel_checks(config, jnp.float32)
+    assert failures == [] and len(readings) == seed - 299
+    assert readings[-1]["max_abs"] < stub.STUB_MATMUL_TOL / 3
+    failures, readings = stub.kernel_checks(config, jnp.bfloat16)
+    assert readings[-1]["max_abs"] > 3 * stub.STUB_MATMUL_TOL
+    assert failures[-1].startswith("stub matmul 64x32x16: max abs error")
+
+
+def test_the_benchmarks_families_return_the_shared_kernel_checks():
+    from benchmark.families import flux, sd
+
+    assert sd.kernel_checks is checks.kernels is flux.kernel_checks
+    assert (sd.PIPELINE_TYPE, flux.PIPELINE_TYPE) == (
+        "DiffusionPipeline", "FluxPipeline")
 
 
 def test_a_family_that_is_not_there_is_a_run_failure():
