@@ -59,6 +59,35 @@ FAMILY_ACT_GB_PER_IMAGE: dict[str, float] = {
     "deepfloyd_if": 1.5,
 }
 
+# Families whose rows are sequences, not canvases (a text job's rows:
+# pipelines/text_generation.py). Admission is the weights the chip holds, a
+# working set that does not grow with the rows (a prefill chunk's
+# activations and worst-case expert buffer, the logits), and a row's cache:
+# `size` is then the positions a row keeps (prompt slots + new tokens), and
+# a row costs `size * cache_bytes_per_position` (every layer's entry).
+# kimi_k2 is one chip's share of a 32-chip expert-parallel deployment
+# (models/kimi.py KIMI_K2_EP32): 4.85 B parameters in bf16 = 9.70 GB; a
+# position is 576 values x 2 bytes x 7 layers; the working set is what the
+# compile for a described v5e counted for the 256-row programs beside
+# weights and cache (benchmark/compile_check.py, PERF.md).
+SEQUENCE_FAMILIES: dict[str, dict[str, float]] = {
+    "kimi_k2": {"params_gb": 9.04, "working_gb": 3.0,
+                "cache_bytes_per_position": 8064.0},
+}
+# the positions a family's advertised appetite is reckoned at (the worker
+# tells the hive one number a family; its own batcher holds each group to
+# the job's true positions)
+SEQUENCE_REFERENCE_POSITIONS = 512
+
+
+
+def sequence_family_positions() -> dict[str, int]:
+    """{family: the positions its advertised appetite is reckoned at}; a
+    family's key resolves to itself as a model name (`_family_key`)."""
+    return {family: SEQUENCE_REFERENCE_POSITIONS
+            for family in SEQUENCE_FAMILIES}
+
+
 # native serving canvas per family (everything else serves 1024)
 _FAMILY_CANVAS: dict[str, int] = {
     "sd15": 512,
@@ -78,6 +107,8 @@ def _family_key(model_name: str) -> str:
     name = model_name.lower()
     if "flux" in name:
         return "flux"
+    if "kimi" in name:
+        return "kimi_k2"
     if "kandinsky-3" in name or "kandinsky3" in name:
         return "kandinsky3"
     if "kandinsky" in name:
@@ -94,7 +125,7 @@ def _family_key(model_name: str) -> str:
 # (stable_diffusion.py `_family_configs`, flux.py `_flux_configs`); every
 # other pipeline gives any `test/` name its tiny preset
 _PUBLISHED_TEST_FAMILIES = frozenset(
-    {"sd15", "sd21", "sdxl", "sdxl_refiner", "flux"})
+    {"sd15", "sd21", "sdxl", "sdxl_refiner", "flux", "kimi_k2"})
 
 
 def _is_stand_in(model_name: str) -> bool:
@@ -117,10 +148,23 @@ def _area_scale(height: int, width: int | None = None) -> float:
     return max((height * width) / (1024.0 * 1024.0), 0.05)
 
 
+def _sequence_costs(fam: str, positions: int) -> tuple[float, float]:
+    """(GiB that do not grow with the rows, GiB a row) of a sequence
+    family: weights + working set, and a row's cache at `positions`."""
+    costs = SEQUENCE_FAMILIES[fam]
+    return (costs["params_gb"] + costs["working_gb"],
+            max(int(positions), 1) * costs["cache_bytes_per_position"]
+            / (1 << 30))
+
+
 def required_hbm_gb(model_name: str, batch: int, size: int,
                     width: int | None = None) -> float:
-    """Estimated HBM for `batch` images at size x (width or size)."""
+    """Estimated HBM for `batch` images at size x (width or size); for a
+    sequence family, `batch` rows of `size` positions."""
     fam = _family_key(model_name)
+    if fam in SEQUENCE_FAMILIES:
+        fixed, per_row = _sequence_costs(fam, size)
+        return fixed + batch * per_row
     params = FAMILY_PARAMS_GB.get(fam, _DEFAULT_PARAMS_GB)
     act = FAMILY_ACT_GB_PER_IMAGE.get(fam, _DEFAULT_ACT_GB)
     return params + batch * act * _area_scale(size, width)
@@ -225,6 +269,12 @@ def fit_batch(chipset, model_name: str, batch: int, size: int,
     # shard holds ceil(batch/data) images, so the largest admissible
     # batch is floor(free / per_image) * data.
     fam = _family_key(model_name)
+    if fam in SEQUENCE_FAMILIES:
+        # one chip's share of a deployment is one chip's: nothing of it
+        # is divided over a slice's chips, and every chip sees every row
+        fixed, per_row = _sequence_costs(fam, size)
+        free = per_chip_hbm - fixed
+        return min(batch, int(free / per_row)) if free >= per_row else 0
     params = FAMILY_PARAMS_GB.get(fam, _DEFAULT_PARAMS_GB)
     act = FAMILY_ACT_GB_PER_IMAGE.get(fam, _DEFAULT_ACT_GB)
     tensor = max(getattr(chipset, "tensor", 1), 1)
@@ -301,6 +351,13 @@ def check_capacity(chipset, model_name: str, batch: int, size: int,
         hbm_gb = chipset.hbm_bytes() / (1 << 30)
         per_chip = hbm_gb / max(chipset.chip_count(), 1)
         fam = _family_key(model_name)
+        if fam in SEQUENCE_FAMILIES:
+            raise ValueError(
+                f"{model_name} does not fit on this {chipset.chip_count()}"
+                f"-chip slice ({per_chip:.0f} GB HBM a chip): its weights, "
+                f"working set and one row of {size} cached positions need "
+                f"about {required_hbm_gb(model_name, 1, size):.1f} GB on "
+                "every chip. Serve it from higher-HBM chips.")
         act = FAMILY_ACT_GB_PER_IMAGE.get(fam, _DEFAULT_ACT_GB)
         one_image = act * _area_scale(size, width)
         base = (

@@ -17,7 +17,8 @@ Everything here operates on plain wire-format job dicts:
 
 - `coalesce_key(job)` -> tuple | None: the compatibility bucket; None
   means "not batchable, single-job path".
-- `job_rows(job)`: images the job contributes to a coalesced batch.
+- `job_rows(job)`: rows the job contributes to a coalesced batch: its
+  images, or for a text job its sequences.
 - `is_interactive(job)`: the latency-sensitive marker both the hive's
   priority classes and the worker's linger fast-path read.
 - `placement_model(job)`: the model name residency maps know the job
@@ -105,6 +106,17 @@ _BATCHABLE_CN_PIPELINE_TYPES = {
     "StableDiffusionXLControlNetPipeline",
 }
 
+# families whose jobs carry token ids and whose rows are sequences
+# (pipelines/text_generation.py)
+_TEXT_FAMILIES = {"kimi_k2"}
+# the only `parameters` keys a batchable text job may carry
+_SAFE_TEXT_PARAMETER_KEYS = frozenset(
+    {"pipeline_type", "max_new_tokens", "temperature"})
+DEFAULT_NEW_TOKENS = 256
+DEFAULT_TEMPERATURE = 1.0
+# the smallest bucket a prompt is padded to
+TEXT_MIN_PROMPT_SLOTS = 16
+
 DEFAULT_STEPS = 30
 DEFAULT_GUIDANCE = 7.5
 DEFAULT_SCHEDULER = "DPMSolverMultistepScheduler"
@@ -152,8 +164,57 @@ def is_interactive(job: dict) -> bool:
     )
 
 
+def prompt_slots(longest: int) -> int:
+    """The bucket a pass pads its prompts to: the next power of two at or
+    over the longest row, `TEXT_MIN_PROMPT_SLOTS` at least. The program
+    is compiled a bucket, so it is a key dimension of a text job."""
+    return max(_pow2_bucket(max(int(longest), 1)), TEXT_MIN_PROMPT_SLOTS)
+
+
+def text_shape(job: dict) -> tuple[int, int] | None:
+    """(prompt slots, new tokens) of a `txt2txt` job, None where its
+    `prompt_ids` are not rows of ids (the formatter's error to raise)."""
+    rows = job.get("prompt_ids")
+    if not isinstance(rows, list) or not rows or not all(
+            isinstance(row, list) and row for row in rows):
+        return None
+    params = job.get("parameters")
+    params = params if isinstance(params, dict) else {}
+    new = int(params.get("max_new_tokens",
+                         job.get("max_new_tokens", DEFAULT_NEW_TOKENS)))
+    return prompt_slots(max(len(row) for row in rows)), new
+
+
+def _text_key(job: dict) -> tuple | None:
+    """The bucket of a `txt2txt` job: everything the two jitted programs
+    close over (model, prompt bucket, new tokens, sampling); the ids, the
+    seed and how many sequences a job has ride per row."""
+    model = job.get("model_name")
+    if not isinstance(model, str) or not model:
+        return None
+    params = job.get("parameters") or {}
+    if not isinstance(params, dict) \
+            or not set(params) <= _SAFE_TEXT_PARAMETER_KEYS:
+        return None
+    shape = text_shape(job)
+    if shape is None or shape[1] < 1:
+        return None
+    from .registry import _auto_family
+
+    family = _auto_family(model)
+    if family not in _TEXT_FAMILIES:
+        return None
+    temperature = round(float(params.get(
+        "temperature", job.get("temperature", DEFAULT_TEMPERATURE))), 4)
+    return (model, family, "txt2txt", *shape, temperature)
+
+
 def job_rows(job: dict) -> int:
-    """Images this job contributes to a coalesced batch."""
+    """Rows this job contributes to a coalesced batch: its images, or the
+    sequences of a text job."""
+    if job.get("workflow") == "txt2txt":
+        rows = job.get("prompt_ids")
+        return max(len(rows), 1) if isinstance(rows, list) else 1
     params = job.get("parameters") or {}
     try:
         n = int(params.get("num_images_per_prompt",
@@ -366,6 +427,8 @@ def coalesce_key(job: dict) -> tuple | None:
     """
     try:
         workflow = job.get("workflow")
+        if workflow == "txt2txt":
+            return _text_key(job)
         if workflow not in ("txt2img", "img2img"):
             return None
         # stage-jobs (ISSUE 20): only the denoise stage is the padded

@@ -101,6 +101,18 @@ def _to_int(value, default: int = 0) -> int:
         return default
 
 
+def _parse_family_rows(raw) -> dict:
+    """"family:rows,family:rows" -> {family: rows >= 1}; anything that
+    does not parse is left out (the family then has the default
+    appetite)."""
+    out = {}
+    for part in str(raw or "").split(","):
+        family, _, rows = part.partition(":")
+        if family.strip() and _to_int(rows) >= 1:
+            out[family.strip()] = _to_int(rows)
+    return out
+
+
 @dataclasses.dataclass
 class WorkerInfo:
     """One worker's latest self-advertisement, parsed from /work query
@@ -124,6 +136,11 @@ class WorkerInfo:
     # also reports queue_depth in ROWS incl. executing (ISSUE 9); a
     # legacy poller keeps the pre-gang budget contract
     gang_aware: bool = False
+    # families whose rows are not canvases (a text job's rows are its
+    # sequences): rows ONE pass of the family admits on a slice of this
+    # worker, from its own admission (`family_gang_rows`, "family:rows"
+    # csv); a family that is not here has the `gang_rows` appetite
+    family_rows: dict = dataclasses.field(default_factory=dict)
     # per-stage EWMA stats blob from the `stats` poll param (fleet.py):
     # {stage: (ewma_seconds, samples)}; empty for legacy pollers
     stats: dict = dataclasses.field(default_factory=dict)
@@ -154,6 +171,13 @@ class WorkerInfo:
     def free_slices(self) -> int:
         return max(self.slices - self.busy_slices, 0)
 
+    def rows_per_pass(self, coalesce: tuple | None) -> int:
+        """The per-slice row appetite for a job of this coalesce key (its
+        second element is the family, coalesce.coalesce_key)."""
+        if coalesce is not None and len(coalesce) > 1:
+            return self.family_rows.get(coalesce[1], self.gang_rows)
+        return self.gang_rows
+
     def can_run(self, model: str | None) -> bool:
         """Capability gate from the honesty key: never hand a worker a
         model family it advertised as unconverted (it can only fail)."""
@@ -172,6 +196,7 @@ class WorkerInfo:
             "busy_slices": self.busy_slices,
             "queue_depth": self.queue_depth,
             "gang_rows": self.gang_rows,
+            "family_gang_rows": dict(self.family_rows),
             "chips_per_slice": self.chips_per_slice,
             "shard_capable": self.shard_capable,
             "resume_capable": self.resume_capable,
@@ -213,6 +238,7 @@ class WorkerDirectory:
             queue_depth=_to_int(query.get("queue_depth")),
             gang_rows=max(_to_int(query.get("gang_rows"), 1), 1),
             gang_aware="gang_rows" in query,
+            family_rows=_parse_family_rows(query.get("family_gang_rows")),
             stats=parse_stats(query.get("stats")),
             chips_per_slice=_to_int(query.get("chips_per_slice")),
             shard_capable=_to_int(query.get("shard_capable")) > 0,
@@ -286,8 +312,15 @@ class Dispatcher:
         # apart into solo fallbacks at the slice
         self.lora_slots = max(int(lora_slots), 1)
 
-    def _budget(self, worker: WorkerInfo) -> tuple[int, int]:
-        """(work items, image rows) to hand this poll.
+    def _budget(self, worker: WorkerInfo,
+                per_slice: int | None = None) -> tuple[int, int]:
+        """(work items, rows) to hand this poll, for jobs whose per-slice
+        appetite is `per_slice` rows (the worker's `gang_rows` unless the
+        job's family advertised its own: `WorkerInfo.rows_per_pass`).
+        `queue_depth` is rows of whatever kind the worker holds: exact
+        where a worker serves one kind, and where kinds mix it errs
+        towards handing out less (256 sequences in flight leave an image
+        job nothing; 4 images in flight leave a text gang 252).
 
         Gang-aware workers (they sent `gang_rows`): work items are
         slice-grained — each solo job or gang lands on ONE slice, so at
@@ -309,7 +342,7 @@ class Dispatcher:
         if not worker.gang_aware:
             free = max(worker.free_slices - worker.queue_depth, 0)
             return free, free
-        per_slice = max(worker.gang_rows, 1)
+        per_slice = max(per_slice or worker.gang_rows, 1)
         free_rows = max(worker.slices * per_slice - worker.queue_depth, 0)
         items = min(worker.free_slices, math.ceil(free_rows / per_slice))
         return max(items, 0), free_rows
@@ -345,7 +378,10 @@ class Dispatcher:
         classes (the peers index is per-class). `gang` is
         {id, size, index} for gang members, None for solo dispatches."""
         handed: list[tuple[JobRecord, str, dict | None]] = []
-        items, free_rows = self._budget(worker)
+        # what this reply may still hand, by per-slice appetite: the
+        # budget at the reply's start less what it has handed so far
+        budgets: dict[int, tuple[int, int]] = {}
+        spent_items = spent_rows = 0
         now = CLOCK.mono()
         taken: set[str] = set()
         # straggler + shard-capability view for this poll: ONE live
@@ -360,6 +396,11 @@ class Dispatcher:
         if self.flap_threshold > 0 and self.flapping_fn is not None:
             flapping = set(self.flapping_fn() or ())
         for record in queue.iter_queued():
+            appetite = worker.rows_per_pass(record.coalesce)
+            if appetite not in budgets:
+                budgets[appetite] = self._budget(worker, appetite)
+            items = budgets[appetite][0] - spent_items
+            free_rows = budgets[appetite][1] - spent_rows
             if (items <= 0 or free_rows <= 0
                     or len(handed) >= self.max_jobs_per_poll):
                 break
@@ -497,12 +538,12 @@ class Dispatcher:
             # a 4-image job must not eat 4 of a legacy worker's job slots
             rows = job_rows(record.job) if worker.gang_aware else 1
             if (record.coalesce is not None and self.gang_max > 1
-                    and worker.gang_rows > 1):
+                    and appetite > 1):
                 # one gang = one slice pass: its rows must fit the
                 # per-slice appetite AND the poll's remaining row budget
                 cap_jobs = min(self.gang_max,
                                self.max_jobs_per_poll - len(handed))
-                cap_rows = min(worker.gang_rows, free_rows)
+                cap_rows = min(appetite, free_rows)
                 # adapter-aware gangs (ISSUE 13): mixed-adapter members
                 # share one pass as stacked per-row deltas, capped at
                 # lora_slots DISTINCT adapters (the worker program's
@@ -535,8 +576,8 @@ class Dispatcher:
                     rows += peer_rows
                     if peer_adapter is not None:
                         adapters.add(peer_adapter)
-            items -= 1
-            free_rows -= rows
+            spent_items += 1
+            spent_rows += rows
             taken.update(m.job_id for m in members)
             if len(members) > 1:
                 gang_id = uuid.uuid4().hex[:12]

@@ -77,6 +77,9 @@ async def format_args(job: dict, settings, device_identifier: str):
     if workflow == "img2txt":
         return await format_img2txt_args(args)
 
+    if workflow == "txt2txt":
+        return format_txt2txt_args(args)
+
     if workflow == "vid2vid":
         from .workflows.video import vid2vid_callback
 
@@ -120,6 +123,32 @@ async def format_img2txt_args(args: dict):
     if "start_image_uri" in args:
         args["image"] = await get_image(args.pop("start_image_uri"), None)
     return caption_callback, args
+
+
+def format_txt2txt_args(args: dict):
+    """Text completion from token ids: `model_name`, `prompt_ids` (a list
+    of rows, each a list of ids of the model's vocabulary),
+    `max_new_tokens`, `temperature`, `seed`. The result is JSON."""
+    from .coalesce import DEFAULT_NEW_TOKENS, DEFAULT_TEMPERATURE
+    from .workflows.text import txt2txt_callback
+
+    parameters = args.pop("parameters", None) or {}
+    rows = args.get("prompt_ids")
+    if not isinstance(rows, list) or not rows or not all(
+            isinstance(row, list) and row
+            and all(isinstance(i, int) and not isinstance(i, bool)
+                    for i in row) for row in rows):
+        raise ValueError(
+            "txt2txt needs prompt_ids: a list of rows, each a list of "
+            "token ids with at least one id")
+    args["pipeline_type"] = parameters.pop(
+        "pipeline_type", "AutoModelForCausalLM")
+    args["max_new_tokens"] = int(parameters.pop(
+        "max_new_tokens", args.get("max_new_tokens", DEFAULT_NEW_TOKENS)))
+    args["temperature"] = float(parameters.pop(
+        "temperature", args.get("temperature", DEFAULT_TEMPERATURE)))
+    args["content_type"] = "application/json"
+    return txt2txt_callback, args
 
 
 def format_txt2audio_args(args: dict):

@@ -1,0 +1,509 @@
+"""Kimi-K2's language model (`model_type` `kimi_k2`): latent attention with
+a latent cache, sigmoid routing over sparse experts, as pure functions over
+a parameter tree.
+
+The block, with `h = RMSNorm(x)` (benchmark/reference/mla_moe.py is the
+plain float32 statement of the same equations):
+
+- latent attention: `c_q = RMSNorm(h W_qa)`, `q = c_q W_qb` a head
+  `[q_nope | q_rope]`; `[c_kv | k_rope] = h W_kva`, `c_kv = RMSNorm(c_kv)`;
+  `[k_nope | v]` a head `= c_kv W_kvb`; rotary with YaRN-scaled frequencies
+  on `q_rope` and on the one `k_rope` all heads share; scores scaled by
+  `(nope + rope)^-1/2 * mscale^2`. The cache holds `[c_kv | k_rope]` a
+  position and nothing else. Prefill expands keys and values
+  (`ops.attention`, causal, values narrower than keys); a decode step
+  absorbs `W_kvb` into the query and the context
+  (`ops.latent_attention`): the same function.
+- experts: `s = sigmoid(h W_g)` in float32 over ALL the model's experts,
+  the `k` largest of `s + b` chosen, weights `s_i / sum_chosen s * scale`
+  (normalised over all the chosen, held here or not); the layer is told
+  which experts it holds (`KimiConfig.experts_held`) and adds
+  `sum_{chosen and held} w_i E_i(h) + E_shared(h)`. What the absent
+  experts would add is left out: on a chip that is one of many sharing the
+  layer, the exchange that brings it is not run and nothing stands in for
+  it. The held experts' part is one grouped matmul over the pairs sorted
+  by expert (`ops.expert_matmul`): no dropped token, no capacity factor.
+- the leading `first_k_dense_replace` layers have a dense SwiGLU instead.
+
+Rotary pairs: the checkpoint interleaves the two halves of each rotary
+pair and the published code permutes them apart before rotating; the
+weights here are taken as already permuted (a converter would permute the
+columns once), so rotation is over the two halves of the rotary width.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import dot_product_attention
+from ..ops.expert_matmul import expert_matmul, plan, row_tile
+from ..ops.latent_attention import latent_decode_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiConfig:
+    """The published sizes (huggingface.co/moonshotai/Kimi-K2.6
+    config.json), and which share of them is held here."""
+
+    hidden_size: int = 7168
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    num_attention_heads: int = 64
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 384  # the router's width, whatever is held
+    num_experts_per_tok: int = 8
+    routed_scaling_factor: float = 2.827
+    n_shared_experts: int = 1
+    first_k_dense_replace: int = 1
+    num_hidden_layers: int = 61
+    vocab_size: int = 163840  # rows of the vocabulary held here
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 50000.0
+    rope_factor: float = 64.0
+    rope_original_positions: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    # (first, count): the routed experts this chip holds of every layer
+    experts_held: tuple[int, int] = (0, 384)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """Values the cache holds a position a layer: `c_kv | k_rope`."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def expert_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5 * yarn_mscale(
+            self.rope_factor, self.rope_mscale_all_dim) ** 2
+
+
+# one chip's share of a 32-chip expert-parallel deployment: the leading
+# dense layer and six expert layers (the other 54 would lie on further
+# pipeline stages), experts 0-11 of each layer's 384 (rank 0 of the 32
+# chips that share a layer), rows 0-20479 of the vocabulary (an eighth)
+KIMI_K2_EP32 = KimiConfig(num_hidden_layers=7, experts_held=(0, 12),
+                          vocab_size=20480)
+KIMI_TINY = KimiConfig(
+    hidden_size=64, q_lora_rank=32, kv_lora_rank=16, num_attention_heads=4,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    intermediate_size=128, moe_intermediate_size=32, n_routed_experts=32,
+    num_experts_per_tok=4, num_hidden_layers=3, vocab_size=128,
+    rope_original_positions=16, experts_held=(0, 8))
+
+
+# --- the parameter tree ------------------------------------------------------
+
+
+def param_shapes(cfg: KimiConfig, dtype) -> dict:
+    """The tree as `jax.ShapeDtypeStruct`s: `embed`, `layers` (a list: each
+    `attn`, two norms, and `mlp` or `moe`), `final_norm`, `head`. Matrices
+    are `[in, out]`; the held experts' are stacked `[held, in, out]`."""
+    h, heads = cfg.hidden_size, cfg.num_attention_heads
+
+    def s(*dims):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    def swiglu(width, *lead):
+        return {"gate": s(*lead, h, width), "up": s(*lead, h, width),
+                "down": s(*lead, width, h)}
+
+    layers = []
+    for index in range(cfg.num_hidden_layers):
+        layer = {
+            "input_norm": s(h), "post_norm": s(h),
+            "attn": {
+                "q_a": s(h, cfg.q_lora_rank), "q_norm": s(cfg.q_lora_rank),
+                "q_b": s(cfg.q_lora_rank, heads * cfg.qk_head_dim),
+                "kv_a": s(h, cfg.cache_width),
+                "kv_norm": s(cfg.kv_lora_rank),
+                "kv_b": s(cfg.kv_lora_rank,
+                          heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                "o": s(heads * cfg.v_head_dim, h)}}
+        if index < cfg.first_k_dense_replace:
+            layer["mlp"] = swiglu(cfg.intermediate_size)
+        else:
+            layer["moe"] = {
+                "router": s(h, cfg.n_routed_experts),
+                "router_bias": s(cfg.n_routed_experts),
+                "experts": swiglu(cfg.moe_intermediate_size,
+                                  cfg.experts_held[1]),
+                "shared": swiglu(
+                    cfg.moe_intermediate_size * cfg.n_shared_experts)}
+        layers.append(layer)
+    return {"embed": s(cfg.vocab_size, h), "layers": layers,
+            "final_norm": s(h), "head": s(h, cfg.vocab_size)}
+
+
+def leaf_rule(path, shape) -> tuple[float, float]:
+    """(std, shift) of one leaf's seeded values from its name: ones for a
+    norm, N(0, 0.01^2) for the router's correction bias, the embedding by
+    its width, else a normal scaled by fan-in (the rows of the one matrix:
+    a stack of experts is scaled expert by expert)."""
+    name = str(getattr(path[-1], "key", path[-1]))
+    if name.endswith("norm"):
+        return 0.0, 1.0
+    if name == "router_bias":
+        return 0.01, 0.0
+    if name == "embed":
+        return 1.0, 0.0
+    return 1.0 / math.sqrt(shape[-2]), 0.0
+
+
+def init_params(cfg: KimiConfig, key, dtype) -> dict:
+    """Seeded values for every leaf, on whatever device is the default:
+    the tiny presets' init (a full-size tree is made leaf by leaf on the
+    chip, benchmark/families/kimi.py)."""
+    shapes = param_shapes(cfg, dtype)
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    out = []
+    for (path, leaf), k in zip(leaves, jax.random.split(key, len(leaves))):
+        std, shift = leaf_rule(path, leaf.shape)
+        out.append((jax.random.normal(k, leaf.shape, jnp.float32) * std
+                    + shift).astype(dtype))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+# --- rotary ------------------------------------------------------------------
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: KimiConfig):
+    """YaRN's frequencies: the pairs that turn more than `beta_fast` times
+    over the original context keep theirs, those that turn less than
+    `beta_slow` times are slowed by `factor`, a linear ramp between."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    extra = 1.0 / base ** exponent
+    inter = extra / cfg.rope_factor
+
+    def correction(turns: float) -> float:
+        return dim * math.log(cfg.rope_original_positions
+                              / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(correction(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction(cfg.rope_beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def rope_tables(cfg: KimiConfig, positions):
+    """cos and sin `[..., rope / 2]` of whole-number `positions`."""
+    angles = positions.astype(jnp.float32)[..., None] * yarn_inv_freq(cfg)
+    scale = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def apply_rope(x, cos, sin):
+    """Rotate the two halves of the last axis; `cos` / `sin` broadcast."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
+# --- the block's parts -------------------------------------------------------
+
+
+def rms_norm(x, weight, eps: float):
+    x32 = x.astype(jnp.float32)
+    scaled = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (scaled * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def _dot(x, w):
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def swiglu(p, x):
+    return _dot(jax.nn.silu(_dot(x, p["gate"])) * _dot(x, p["up"]), p["down"])
+
+
+def _queries(p, cfg: KimiConfig, h):
+    c_q = rms_norm(_dot(h, p["q_a"]), p["q_norm"], cfg.rms_norm_eps)
+    q = _dot(c_q, p["q_b"]).reshape(
+        *h.shape[:-1], cfg.num_attention_heads, cfg.qk_head_dim)
+    return q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
+
+
+def _latents(p, cfg: KimiConfig, h, cos, sin):
+    """What the cache holds of `h`'s positions: `c_kv | k_rope`, the key
+    already rotated."""
+    kv = _dot(h, p["kv_a"])
+    c_kv = rms_norm(kv[..., :cfg.kv_lora_rank], p["kv_norm"],
+                    cfg.rms_norm_eps)
+    k_rope = apply_rope(kv[..., cfg.kv_lora_rank:], cos, sin)
+    return jnp.concatenate([c_kv, k_rope], axis=-1)
+
+
+def _kv_up(p, cfg: KimiConfig):
+    """`W_kvb` as `[latent, heads, nope | v]`."""
+    return p["kv_b"].reshape(cfg.kv_lora_rank, cfg.num_attention_heads,
+                             cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def attention_prefill(p, cfg: KimiConfig, h, positions):
+    """Causal latent attention over whole rows `h` [R, S, hidden]; returns
+    the output and the rows' cache entries [R, S, cache_width]."""
+    cos, sin = rope_tables(cfg, positions)
+    q_nope, q_rope = _queries(p, cfg, h)
+    q_rope = apply_rope(q_rope, cos[..., None, :], sin[..., None, :])
+    entry = _latents(p, cfg, h, cos, sin)
+    heads = cfg.num_attention_heads
+    kv = jnp.einsum("rsc,chd->rshd", entry[..., :cfg.kv_lora_rank],
+                    _kv_up(p, cfg),
+                    preferred_element_type=jnp.float32).astype(h.dtype)
+    k_rope = jnp.broadcast_to(
+        entry[..., None, cfg.kv_lora_rank:],
+        (*entry.shape[:-1], heads, cfg.qk_rope_head_dim))
+    out = dot_product_attention(
+        jnp.concatenate([q_nope, q_rope], axis=-1),
+        jnp.concatenate([kv[..., :cfg.qk_nope_head_dim], k_rope], axis=-1),
+        kv[..., cfg.qk_nope_head_dim:], scale=cfg.softmax_scale, causal=True)
+    return _dot(out.reshape(*h.shape[:-1], -1), p["o"]), entry
+
+
+def attention_decode(p, cfg: KimiConfig, h, positions, cache, column, mask,
+                     absorb: bool = True):
+    """One new token a row: `h` [R, hidden] at rotary `positions` [R],
+    written to `cache` [R, S, cache_width] at `column`; `mask` [R, S] are
+    the positions each row may see, its own included. `absorb` false
+    expands the cache's keys and values instead (tests: the same
+    function)."""
+    cos, sin = rope_tables(cfg, positions)
+    q_nope, q_rope = _queries(p, cfg, h)
+    q_rope = apply_rope(q_rope, cos[:, None, :], sin[:, None, :])
+    entry = _latents(p, cfg, h, cos, sin)
+    cache = jax.lax.dynamic_update_slice(
+        cache, entry[:, None, :].astype(cache.dtype), (0, column, 0))
+    up = _kv_up(p, cfg)
+    w_k, w_v = up[..., :cfg.qk_nope_head_dim], up[..., cfg.qk_nope_head_dim:]
+    if absorb:
+        q_lat = jnp.einsum("rhd,chd->rhc", q_nope, w_k,
+                           preferred_element_type=jnp.float32).astype(h.dtype)
+        context = latent_decode_attention(q_lat, q_rope, cache, mask,
+                                          cfg.softmax_scale)
+        out = jnp.einsum("rhc,chd->rhd", context, w_v,
+                         preferred_element_type=jnp.float32).astype(h.dtype)
+    else:
+        latent = cache[..., :cfg.kv_lora_rank]
+        k_nope = jnp.einsum("rsc,chd->rshd", latent, w_k)
+        values = jnp.einsum("rsc,chd->rshd", latent, w_v)
+        scores = (jnp.einsum("rhd,rshd->rhs", q_nope, k_nope)
+                  + jnp.einsum("rhd,rsd->rhs", q_rope,
+                               cache[..., cfg.kv_lora_rank:]))
+        scores = jnp.where(mask[:, None, :],
+                           scores.astype(jnp.float32) * cfg.softmax_scale,
+                           -jnp.inf)
+        out = jnp.einsum("rhs,rshd->rhd",
+                         jax.nn.softmax(scores, -1).astype(h.dtype), values)
+    return _dot(out.reshape(h.shape[0], -1), p["o"]), cache
+
+
+def route(p, cfg: KimiConfig, h):
+    """The `k` experts of every token of `h` [T, hidden] and their weights
+    [T, k], float32: sigmoid scores over all the experts, the choice by
+    score plus correction bias, the weights from the scores alone."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(
+        scores + p["router_bias"].astype(jnp.float32),
+        cfg.num_experts_per_tok)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / jnp.sum(picked, -1, keepdims=True)
+    return chosen, weights * cfg.routed_scaling_factor
+
+
+def held_experts(experts, h, local, interpret: bool = False):
+    """What the held experts give for every (token, choice) pair: `h`
+    [T, hidden], `local` [T, k] each pair's expert as an index into the
+    held ones (`held` or more: not here, or a token that is padding).
+    Returns [T, k, hidden], zero for a pair that is not here, and the
+    pairs of each held expert [held]. The pairs are sorted by expert into
+    one row buffer (`ops.expert_matmul.plan`), two grouped matmuls run
+    over it, and each pair reads its own row back: a token's part does
+    not depend on its batchmates."""
+    tokens, hidden = h.shape
+    held = experts["gate"].shape[0]
+    here = (local >= 0) & (local < held)
+    tm = row_tile(tokens)
+    where = plan(jnp.where(here, local, held), held, tm)
+    rows = jnp.concatenate([h, jnp.zeros((1, hidden), h.dtype)])[
+        where.row_token]
+    inner = expert_matmul(rows, (experts["gate"], experts["up"]),
+                          where.tile_expert, where.n_tiles, tm=tm,
+                          interpret=interpret)
+    outs = expert_matmul(inner, (experts["down"],), where.tile_expert,
+                         where.n_tiles, tm=tm, interpret=interpret)
+    mine = outs[jnp.minimum(where.pair_row, outs.shape[0] - 1)]
+    return jnp.where(here[..., None], mine, 0), where.sizes
+
+
+def expert_layer(p, cfg: KimiConfig, h, valid=None, interpret: bool = False):
+    """The held experts' part and the shared expert's for tokens `h`
+    [T, hidden] (`valid` [T]: padding is routed nowhere). Returns the sum
+    and how the routing fell: pairs of each held expert [held], and
+    (routed pairs, the fullest expert's pairs, experts with pairs)."""
+    first, held = cfg.experts_held
+    chosen, weights = route(p, cfg, h)
+    local = chosen - first
+    if valid is not None:
+        local = jnp.where(valid[:, None], local, held)
+    parts, sizes = held_experts(p["experts"], h, local, interpret)
+    # in the order of the token's own choices, in float32
+    routed = jnp.sum(parts.astype(jnp.float32) * weights[..., None], axis=1)
+    count = (jnp.int32(h.shape[0]) if valid is None
+             else jnp.sum(valid.astype(jnp.int32)))
+    stats = jnp.stack([count * cfg.num_experts_per_tok, jnp.max(sizes),
+                       jnp.sum((sizes > 0).astype(jnp.int32))])
+    return routed.astype(h.dtype) + swiglu(p["shared"], h), (sizes, stats)
+
+
+def _feed_forward(layer, cfg: KimiConfig, h, valid, interpret):
+    """A layer's second half for tokens `h` [T, hidden]."""
+    if "mlp" in layer:
+        return swiglu(layer["mlp"], h), None
+    return expert_layer(layer["moe"], cfg, h, valid, interpret)
+
+
+def empty_load(cfg: KimiConfig):
+    """The routing's tally of a pass, all zero: pairs of each held expert
+    of each expert layer, and (routed, fullest, active) summed over the
+    expert layers' calls."""
+    return (jnp.zeros((cfg.expert_layers, cfg.experts_held[1]), jnp.int32),
+            jnp.zeros((3,), jnp.int32))
+
+
+def _tally(load, index: int, cfg: KimiConfig, told):
+    if told is None:
+        return load
+    sizes, stats = told
+    pairs, sums = load
+    return (pairs.at[index - cfg.first_k_dense_replace].add(sizes),
+            sums + stats)
+
+
+# --- prefill and decode ------------------------------------------------------
+
+
+def new_cache(cfg: KimiConfig, rows: int, positions: int, dtype):
+    return tuple(jnp.zeros((rows, positions, cfg.cache_width), dtype)
+                 for _ in range(cfg.num_hidden_layers))
+
+
+def prefill_rows(params, cfg: KimiConfig, ids, lengths, load,
+                 interpret: bool = False):
+    """Whole rows `ids` [R, S] (a row's prompt first, padding after: with
+    a causal mask no real token sees padding) through every layer. Returns
+    the hidden state of each row's last prompt token [R, hidden], each
+    layer's cache entries [R, S, cache_width], and the tally."""
+    rows, slots = ids.shape
+    x = params["embed"][ids]
+    positions = jnp.broadcast_to(jnp.arange(slots), (rows, slots))
+    valid = (positions < lengths[:, None]).reshape(-1)
+    entries = []
+    for index, layer in enumerate(params["layers"]):
+        h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+        out, entry = attention_prefill(layer["attn"], cfg, h, positions)
+        entries.append(entry)
+        x = x + out
+        h = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
+        out, told = _feed_forward(layer, cfg, h.reshape(rows * slots, -1),
+                                  valid, interpret)
+        x = x + out.reshape(x.shape)
+        load = _tally(load, index, cfg, told)
+    last = jnp.take_along_axis(
+        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+    return last, entries, load
+
+
+def logits_of(params, cfg: KimiConfig, x):
+    """Final norm and the head over the held rows of the vocabulary,
+    float32."""
+    h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+
+
+def prefill(params, cfg: KimiConfig, ids, lengths, positions: int,
+            chunk_rows: int, interpret: bool = False):
+    """`ids` [R, S] in chunks of `chunk_rows` rows (what bounds the widest
+    layer's activations). Returns the last prompt position's logits [R,
+    vocab], the cache (a `[R, positions, cache_width]` a layer, the first
+    S columns written) and the tally."""
+    rows, slots = ids.shape
+    dtype = params["embed"].dtype
+    assert rows % chunk_rows == 0, (rows, chunk_rows)
+
+    def chunk(number, carry):
+        last, cache, load = carry
+        at = number * chunk_rows
+        x, entries, load = prefill_rows(
+            params, cfg,
+            jax.lax.dynamic_slice(ids, (at, 0), (chunk_rows, slots)),
+            jax.lax.dynamic_slice(lengths, (at,), (chunk_rows,)),
+            load, interpret)
+        cache = tuple(
+            jax.lax.dynamic_update_slice(whole, entry.astype(dtype),
+                                         (at, 0, 0))
+            for whole, entry in zip(cache, entries))
+        return (jax.lax.dynamic_update_slice(last, x, (at, 0)), cache, load)
+
+    last, cache, load = jax.lax.fori_loop(
+        0, rows // chunk_rows, chunk,
+        (jnp.zeros((rows, cfg.hidden_size), dtype),
+         new_cache(cfg, rows, positions, dtype), empty_load(cfg)))
+    return logits_of(params, cfg, last), cache, load
+
+
+def decode_step(params, cfg: KimiConfig, tokens, positions, cache, column,
+                mask, load, valid=None, absorb: bool = True,
+                interpret: bool = False):
+    """One token a row through every layer and the cache: `tokens` [R] at
+    rotary `positions` [R], cached at `column` (`valid` [R]: a row that
+    only pads the pass is routed nowhere). Returns the logits [R, vocab]
+    (float32), the cache and the tally."""
+    x = params["embed"][tokens]
+    cache = list(cache)
+    for index, layer in enumerate(params["layers"]):
+        h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
+        out, cache[index] = attention_decode(
+            layer["attn"], cfg, h, positions, cache[index], column, mask,
+            absorb)
+        x = x + out
+        h = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
+        out, told = _feed_forward(layer, cfg, h, valid, interpret)
+        x = x + out
+        load = _tally(load, index, cfg, told)
+    return logits_of(params, cfg, x), tuple(cache), load
+
+
+def decode_mask(lengths, prompt_slots: int, positions: int, column):
+    """[R, positions]: a row sees its own prompt (the first `lengths`
+    columns) and the generated columns up to `column`, which is being
+    written."""
+    columns = jnp.arange(positions)[None, :]
+    return (columns < lengths[:, None]) | (
+        (columns >= prompt_slots) & (columns <= column))

@@ -115,30 +115,44 @@ def _flash_route(q, k, v, scale, interpret: bool = False):
     )(q, k, v)
 
 
-def reference_attention(q, k, v, scale: float | None = None):
-    """Readable O(S^2)-memory reference; also the CPU/test path."""
+def reference_attention(q, k, v, scale: float | None = None,
+                        causal: bool = False):
+    """Readable O(S^2)-memory reference; also the CPU/test path. `v` may
+    be narrower or wider than `q` and `k`; `causal` lets query i see the
+    keys up to i (the last query is the last key)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
+    logits = (jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale).astype(jnp.float32)
+    if causal:
+        sq, skv = q.shape[1], k.shape[1]
+        seen = (jnp.arange(skv)[None, :]
+                <= jnp.arange(sq)[:, None] + (skv - sq))
+        logits = jnp.where(seen, logits, -jnp.inf)
+    weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
 @functools.partial(jax.named_call, name="attention")
-def dot_product_attention(q, k, v, scale: float | None = None):
-    """[B, S_q, H, D] x [B, S_kv, H, D] -> [B, S_q, H, D].
+def dot_product_attention(q, k, v, scale: float | None = None,
+                          causal: bool = False):
+    """[B, S_q, H, D] x [B, S_kv, H, D] -> [B, S_q, H, D_v].
 
     Self- and cross-attention both route here (cross: S_kv = text length).
     On TPU with long latent sequences the Pallas flash kernel takes over;
-    otherwise XLA's fused attention handles it.
+    otherwise XLA's fused attention handles it. The flash and ring kernels
+    have no mask and one head width for q, k and v: a `causal` call, or one
+    whose values are not as wide as its keys (latent attention's prefill:
+    192-wide q.k, 128-wide v), is the XLA path's whatever its length.
     """
-    ring_out = _ring_route(q, k, v, scale)
+    plain = not causal and v.shape[-1] == q.shape[-1]
+    ring_out = _ring_route(q, k, v, scale) if plain else None
     if ring_out is not None:
         KERNEL_TRACES.inc(op="attention", path="ring")
         return ring_out
-    if (trace_platform() == "tpu" and q.shape[1] >= _FLASH_MIN_SEQ
+    if (plain and trace_platform() == "tpu"
+            and q.shape[1] >= _FLASH_MIN_SEQ
             and q.shape[-1] <= _FLASH_MAX_HEAD_DIM):
         KERNEL_TRACES.inc(op="attention", path="flash")
         return _flash_route(q, k, v, scale)
     KERNEL_TRACES.inc(op="attention", path="reference")
-    return reference_attention(q, k, v, scale=scale)
+    return reference_attention(q, k, v, scale=scale, causal=causal)

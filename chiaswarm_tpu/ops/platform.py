@@ -22,7 +22,8 @@ from .. import telemetry
 KERNEL_TRACES = telemetry.counter(
     "swarm_kernel_traces_total",
     "Kernel dispatch decisions taken while tracing, by op (attention | "
-    "group_norm) and path (flash | ring | fused | reference)",
+    "group_norm | expert_matmul | latent_attention) and path (flash | "
+    "ring | fused | grouped | absorbed | reference)",
     ("op", "path"),
 )
 

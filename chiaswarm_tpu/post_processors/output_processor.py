@@ -147,6 +147,23 @@ def make_text_result(string: str) -> dict:
     }
 
 
+def make_token_result(token_ids) -> dict:
+    """A text completion's artifact: `{"token_ids": [[...], ...]}`, a row
+    a sequence, as JSON; `sha256_hash` is over the blob."""
+    blob = json.dumps({"token_ids": [[int(i) for i in row]
+                                     for row in token_ids]},
+                      separators=(",", ":")).encode("utf-8")
+    thumb = image_to_buffer(
+        image_from_text("application/json", THUMBNAIL_SIZE, 1),
+        "image/jpeg", "web_low")
+    return {
+        "blob": base64.b64encode(blob).decode("UTF-8"),
+        "content_type": "application/json",
+        "thumbnail": base64.b64encode(thumb.getvalue()).decode("UTF-8"),
+        "sha256_hash": hashlib.sha256(blob).hexdigest(),
+    }
+
+
 def exception_image(e: Exception, content_type: str):
     message = e.args[0] if e.args else "error generating image"
     buffer = image_to_buffer(image_from_text(str(message)), content_type)

@@ -70,6 +70,7 @@ PIPELINE_FAMILIES: dict[str, str] = {
     "StableVideoDiffusionPipeline": "svd",
     "BlipForConditionalGeneration": "blip",
     "BlipForQuestionAnswering": "blip",
+    "KimiK2ForCausalLM": "kimi_k2",
 }
 
 # family -> factory(model_name, chipset, **variant) -> pipeline bundle.
@@ -114,6 +115,8 @@ def _auto_family(model_name: str) -> str:
         return "cascade_prior" if "prior" in name else "cascade"
     if "flux" in name:
         return "flux"
+    if "kimi" in name:
+        return "kimi_k2"
     if name.startswith("deepfloyd/") or "tiny-if" in name:
         return "deepfloyd_if"
     if "latent-upscaler" in name or "tiny-upscaler" in name:
@@ -126,7 +129,8 @@ def _auto_family(model_name: str) -> str:
 def get_pipeline(model_name: str, pipeline_type: str, chipset=None, **variant):
     """Resolve (and cache) a resident pipeline for this model on this mesh."""
     _ensure_builtin_families()
-    if pipeline_type.startswith("AutoPipeline") or pipeline_type == "DiffusionPipeline":
+    if pipeline_type.startswith(("AutoPipeline", "AutoModel")) \
+            or pipeline_type == "DiffusionPipeline":
         family = _auto_family(model_name)
     else:
         family = family_of(pipeline_type)
@@ -238,7 +242,7 @@ def _ensure_builtin_families() -> None:
     _BUILTINS_LOADED = True
     for module in ("stable_diffusion", "video", "svd", "i2vgen", "audio",
                    "audioldm2",
-                   "captioning", "flux", "kandinsky", "kandinsky3", "cascade",
+                   "captioning", "text_generation", "flux", "kandinsky", "kandinsky3", "cascade",
                    "upscale", "deepfloyd", "bark"):
         try:
             __import__(f"{__package__}.pipelines.{module}")

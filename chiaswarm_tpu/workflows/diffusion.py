@@ -386,6 +386,11 @@ def diffusion_batched_callback(device_identifier: str, requests: list[dict]):
     return out
 
 
+# the worker runs a group of jobs that all formatted to one callback as one
+# pass through that callback's `batched` (worker.py synchronous_do_batch)
+diffusion_callback.batched = diffusion_batched_callback
+
+
 def deepfloyd_if_callback(device_identifier: str, model_name: str, **kwargs):
     """DeepFloyd IF jobs dispatch early (job_arguments.py:78-81, mirroring
     reference :49-50), so the raw job `parameters` still ride in kwargs.

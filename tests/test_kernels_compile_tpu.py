@@ -1,5 +1,5 @@
-"""Both Pallas kernels of the UNet, compiled for a described v5e chip at the
-published widths (no chip attached: the TPU compiler is installed here and
+"""The Pallas kernels (the UNet's two and the grouped expert matmul),
+compiled for a described v5e chip at the published widths (no chip attached: the TPU compiler is installed here and
 refuses what the chip's would — block shapes the lowering cannot tile,
 casts Mosaic has no layout for, more fast memory than a kernel may use).
 Interpret mode checks none of that; every shape below was refused before
@@ -111,3 +111,31 @@ def test_admission_rule_stays_inside_the_scoped_vmem_limit():
     assert fused_tile_bytes(n, c, 2) <= _vmem_budget()
     assert fused_tile_bytes(32 * 32, 640, 2) <= _vmem_budget()
     assert fused_tile_bytes(32 * 32, 1280, 2) > _vmem_budget()
+
+
+@pytest.mark.parametrize("tokens,width,n,gated", [
+    # Kimi-K2's 12 held experts (hidden 7168, expert width 2048): a decode
+    # step's 256 tokens in 16-row tiles, a prefill chunk's 4096 in 128-row
+    pytest.param(256, 7168, 2048, True, id="kimi-decode-gate-up"),
+    pytest.param(256, 2048, 7168, False, id="kimi-decode-down"),
+    pytest.param(4096, 7168, 2048, True, id="kimi-prefill-gate-up"),
+    pytest.param(4096, 2048, 7168, False, id="kimi-prefill-down"),
+])
+def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated):
+    """The grouped kernel at the row buffer's worst-case size, its grid's
+    first extent a traced number (only the tiles that hold rows)."""
+    from chiaswarm_tpu.ops.expert_matmul import (
+        _grouped,
+        buffer_rows,
+        row_tile,
+    )
+
+    tm = row_tile(tokens)
+    rows = buffer_rows(tokens, 8, 12, tm)
+    weights = tuple(_shape(v5e, (12, width, n)) for _ in range(1 + gated))
+    compiled = jax.jit(
+        lambda x, w, tiles, count: _grouped(x, w, tiles, count, tm=tm)
+    ).lower(_shape(v5e, (rows, width)), weights,
+            _shape(v5e, (rows // tm,), jnp.int32),
+            _shape(v5e, (), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
