@@ -26,10 +26,15 @@ so conversion is mechanical (models/conversion.py convert_flux).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+from typing import Callable
 
 import flax.linen as nn
 import jax.numpy as jnp
+
+from ..ops.platform import KERNEL_TRACES, active_mesh
+from ..parallel import tensor
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,11 +96,16 @@ def rope_frequencies(ids, axes_dims: tuple[int, ...], theta: int):
 
 
 def apply_rope(x, cos, sin):
-    """x [B, S, H, D] with rotation pairs on the last dim."""
+    """x [B, S, H, D] with rotation pairs on the last dim; cos, sin
+    [B, S, D/2], or [B, S, G, D/2] where each of G groups of heads has its
+    tokens in an order of its own (`parallel.tensor.ring_rows`)."""
     x1 = x[..., 0::2]
     x2 = x[..., 1::2]
-    cos = cos[:, :, None, :]
-    sin = sin[:, :, None, :]
+    if cos.ndim == 3:
+        cos, sin = cos[:, :, None], sin[:, :, None]
+    else:
+        cos, sin = (jnp.repeat(t, x.shape[2] // t.shape[2], axis=2)
+                    for t in (cos, sin))
     out1 = x1 * cos - x2 * sin
     out2 = x1 * sin + x2 * cos
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape)
@@ -198,6 +208,44 @@ def _whole(x):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+class _ProductDense(nn.Module):
+    """`nn.Dense`, its parameters under the same names, with `product` (one
+    of `parallel.tensor`'s pair) as its matmul."""
+
+    features: int
+    product: Callable
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.linear.default_kernel_init,
+                            (x.shape[-1], self.features))
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,))
+        return self.product(*nn.dtypes.promote_dtype(
+            x, kernel, bias, dtype=self.dtype))
+
+
+def _parallel_dense(mesh, dtype, product, features: int, name: str):
+    """A block's column-parallel (`tensor.gather_matmul`) or row-parallel
+    (`tensor.matmul_scatter`) Dense. On the `mesh` that
+    `tensor.overlap_mesh` found, the product itself: the residual stream
+    between a row-parallel kernel and the next column-parallel one is then
+    sharded by tokens, and with it the gate, the LayerNorm and the
+    modulation; between a column-parallel kernel and the next row-parallel
+    one each chip has its tokens in its ring order. With `mesh` None a
+    plain Dense, behind whose row-parallel kernel XLA puts one all-reduce
+    if the weights are sharded at all."""
+    scope = active_mesh()
+    if scope is not None and scope.shape[tensor.TENSOR_AXIS] > 1:
+        KERNEL_TRACES.inc(op="tensor_matmul",
+                          path="reduced" if mesh is None else "overlapped")
+    if mesh is None:
+        return nn.Dense(features, dtype=dtype, name=name)
+    return _ProductDense(features, functools.partial(product, mesh),
+                         dtype=dtype, name=name)
+
+
 def _split_qkv(out, heads: int, head_dim: int, groups: int):
     """[B, S, (groups, 3, heads/groups, head_dim)] -> q, k, v [B, S, H, D].
     Splitting the (tensor-sharded) column axis by its leading `groups`
@@ -271,6 +319,11 @@ class DoubleStreamBlock(nn.Module):
         cfg = self.config
         h, hd = cfg.num_heads, cfg.head_dim
         mlp_dim = int(cfg.hidden_size * cfg.mlp_ratio)
+        mesh = tensor.overlap_mesh(self.head_groups, img.shape[1], txt.shape[1])
+        dense = functools.partial(_parallel_dense, mesh, self.dtype)
+        if mesh is not None:  # q, k, v come in each chip's ring order
+            cos, sin = (tensor.ring_rows(mesh, t, (txt.shape[1], img.shape[1]))
+                        for t in (cos, sin))
 
         def stream(name):
             mod = Modulation(cfg.hidden_size, 6, dtype=self.dtype,
@@ -286,7 +339,8 @@ class DoubleStreamBlock(nn.Module):
             )(x)
 
         def qkv(x, name):
-            out = nn.Dense(3 * h * hd, dtype=self.dtype, name=f"{name}_attn_qkv")(x)
+            out = dense(tensor.gather_matmul, 3 * h * hd,
+                        f"{name}_attn_qkv")(x)
             q, k, v = _split_qkv(out, h, hd, self.head_groups)
             q, k = QKNorm(dtype=self.dtype, name=f"{name}_attn_norm")(q, k)
             return q, k, v
@@ -305,18 +359,17 @@ class DoubleStreamBlock(nn.Module):
         txt_len = txt.shape[1]
         txt_attn, img_attn = attn[:, :txt_len], attn[:, txt_len:]
 
-        img = img + img_mod[2] * nn.Dense(
-            cfg.hidden_size, dtype=self.dtype, name="img_attn_proj"
-        )(img_attn)
-        txt = txt + txt_mod[2] * nn.Dense(
-            cfg.hidden_size, dtype=self.dtype, name="txt_attn_proj"
-        )(txt_attn)
+        img = img + img_mod[2] * dense(
+            tensor.matmul_scatter, cfg.hidden_size, "img_attn_proj")(img_attn)
+        txt = txt + txt_mod[2] * dense(
+            tensor.matmul_scatter, cfg.hidden_size, "txt_attn_proj")(txt_attn)
 
         def mlp(x, mod_shift, mod_scale, mod_gate, name):
             y = norm(x) * (1 + mod_scale) + mod_shift
-            y = nn.Dense(mlp_dim, dtype=self.dtype, name=f"{name}_mlp_0")(y)
+            y = dense(tensor.gather_matmul, mlp_dim, f"{name}_mlp_0")(y)
             y = nn.gelu(y, approximate=True)
-            y = nn.Dense(cfg.hidden_size, dtype=self.dtype, name=f"{name}_mlp_2")(y)
+            y = dense(tensor.matmul_scatter, cfg.hidden_size,
+                      f"{name}_mlp_2")(y)
             return x + mod_gate * y
 
         img = mlp(img, img_mod[3], img_mod[4], img_mod[5], "img")
@@ -342,9 +395,12 @@ class SingleStreamBlock(nn.Module):
         )(x)
         y = y * (1 + scale) + shift
         b, s, _ = y.shape
-        fused = nn.Dense(
-            3 * h * hd + mlp_dim, dtype=self.dtype, name="linear1"
-        )(y)
+        mesh = tensor.overlap_mesh(self.head_groups, s)
+        dense = functools.partial(_parallel_dense, mesh, self.dtype)
+        if mesh is not None:  # q, k, v come in each chip's ring order
+            cos, sin = (tensor.ring_rows(mesh, t, (s,)) for t in (cos, sin))
+        fused = dense(tensor.gather_matmul, 3 * h * hd + mlp_dim,
+                      "linear1")(y)
         # columns: `head_groups` groups of [q | k | v | mlp], each a 1/groups
         # of the heads and of the MLP's hidden units
         g = self.head_groups
@@ -354,7 +410,7 @@ class SingleStreamBlock(nn.Module):
         q, k = QKNorm(dtype=self.dtype, name="norm")(q, k)
         attn = _attention(q, k, v, cos, sin)
         # rows of linear2 in the same groups: [attn | mlp] of each
-        out = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="linear2")(
+        out = dense(tensor.matmul_scatter, cfg.hidden_size, "linear2")(
             jnp.concatenate(
                 [attn.reshape(b, s, g, -1),
                  nn.gelu(mlp_part, approximate=True)], axis=-1,
@@ -400,13 +456,27 @@ class FluxTransformer(nn.Module):
                 name=f"double_blocks_{i}"
             )(img, txt, vec, cos, sin)
 
-        x = jnp.concatenate([txt, img], axis=1)
+        # the blocks leave both streams sharded by tokens where their
+        # matmuls overlap their collectives: each chip then keeps its chunk
+        # of `txt` beside its chunk of `img`, and RoPE follows that order
+        mesh = tensor.overlap_mesh(self.head_groups, txt.shape[1],
+                                   img.shape[1])
+        if mesh is None:
+            x = jnp.concatenate([txt, img], axis=1)
+        else:
+            x = tensor.join_token_shards(mesh, txt, img)
+            order = tensor.token_shard_order(
+                mesh.shape[tensor.TENSOR_AXIS], txt.shape[1], img.shape[1])
+            cos, sin = cos[:, order], sin[:, order]
         for i in range(cfg.depth_single):
             x = SingleStreamBlock(
                 cfg, dtype=self.dtype, head_groups=self.head_groups,
                 name=f"single_blocks_{i}"
             )(x, vec, cos, sin)
-        x = x[:, txt.shape[1]:]
+        if mesh is None:
+            x = x[:, txt.shape[1]:]
+        else:
+            x = tensor.last_token_shards(mesh, x, img.shape[1])
 
         shift, scale = jnp.split(
             _whole(nn.Dense(2 * cfg.hidden_size, dtype=self.dtype,
@@ -417,9 +487,11 @@ class FluxTransformer(nn.Module):
             use_bias=False, use_scale=False, epsilon=1e-6, dtype=self.dtype
         )(x)
         x = x * (1 + scale) + shift
-        return nn.Dense(
+        out = nn.Dense(
             cfg.in_channels, dtype=self.dtype, name="final_layer_linear"
         )(x)
+        # from each chip's quarter of the image tokens: kilobytes
+        return out if mesh is None else _whole(out)
 
 
 class FluxHead(nn.Module):

@@ -136,7 +136,18 @@ def _collectives(hlo: str, op: str) -> list[list[int]]:
     return found
 
 
-def test_sharded_forward_matches_reference_and_really_shards(tp_case):
+@pytest.mark.parametrize("ways,ring", [
+    (2, None), (2, [0, 1, 3, 2]), (1, None),
+], ids=["two-ways", "two-ways-ring-0-1-3-2", "one-way"])
+def test_sharded_forward_matches_reference_and_really_shards(
+        tp_case, ways, ring, monkeypatch):
+    from chiaswarm_tpu.parallel import tensor
+
+    if ways == 2:  # as chunks of 256 tokens and more travel: in two halves
+        monkeypatch.setattr(tensor, "_TWO_WAY_TOKENS", 2)
+    if ring:  # chips where a v5e 2x2 has them: the ring is not the axis
+        monkeypatch.setattr(tensor, "_ring_order", lambda mesh: ring)
+    jax.clear_caches()  # the pair's products are jitted by mesh and shape
     inputs, params, want = tp_case
     cfg = TP_FLUX
     mesh = make_mesh(jax.devices()[:4], tensor=4)
@@ -156,27 +167,37 @@ def test_sharded_forward_matches_reference_and_really_shards(tp_case):
     with mesh_scope(mesh):
         compiled = step.lower(placed).compile()
         got = step(placed)
+    jax.clear_caches()
     # (b2) float32, the row-parallel sums taken in another order
     assert float(np.max(np.abs(np.asarray(got) - np.asarray(want)))) <= 1e-4
 
-    # (b3) the step's collectives: one all-reduce of a [rows, tokens,
-    # hidden] activation per row-parallel kernel (attention projection and
-    # MLP of each stream in a double block, `linear2` in a single one) ...
+    # (b3) the step's collectives. No all-reduce is left: the sum behind a
+    # row-parallel kernel (attention projection and MLP of each stream in a
+    # double block, `linear2` in a single one) and the gather ahead of a
+    # column-parallel one (qkv and MLP of each stream; `linear1`) each go
+    # round the ring in three hops of a chip's token chunk: each way half
+    # of it, or (a short chunk, as `txt`'s 128 tokens are) one way whole ...
     hlo = compiled.as_text()
     rows, n_img, n_txt = 2, inputs["img"].shape[1], inputs["txt"].shape[1]
-    reduced = sorted(n for op in _collectives(hlo, "all-reduce") for n in op)
-    img, txt = rows * n_img * cfg.hidden_size, rows * n_txt * cfg.hidden_size
-    assert reduced == sorted(
-        [img, txt] * 2 * cfg.depth_double + [img + txt] * cfg.depth_single)
-    # ... and one gather of each modulation vector; nothing the size of a
-    # weight travels, and nothing is reshuffled ahead of attention
+    assert _collectives(hlo, "all-reduce") == []
+    hops = sorted(n for op in _collectives(hlo, "collective-permute")
+                  for n in op)
+    piece = lambda tokens: rows * tokens // 4 // ways * cfg.hidden_size
+    assert hops == sorted(
+        [piece(n_img), piece(n_txt)] * 3 * ways * 4 * cfg.depth_double
+        + [piece(n_img + n_txt)] * 3 * ways * 2 * cfg.depth_single)
+    # ... with one gather of each modulation vector, and of the result from
+    # each chip's quarter of the image tokens; nothing the size of a weight
+    # or of a [rows, tokens, hidden] activation travels in one piece
     gathered = sorted(n for op in _collectives(hlo, "all-gather") for n in op)
     vec = rows * cfg.hidden_size
-    assert gathered == sorted([6 * vec] * 2 * cfg.depth_double
-                              + [3 * vec] * cfg.depth_single + [2 * vec])
+    assert gathered == sorted(
+        [6 * vec] * 2 * cfg.depth_double + [3 * vec] * cfg.depth_single
+        + [2 * vec] + [rows * n_img * cfg.in_channels])
     smallest_sharded_kernel = cfg.hidden_size * cfg.hidden_size
-    assert max(gathered) < smallest_sharded_kernel
-    for op in ("all-to-all", "collective-permute", "reduce-scatter"):
+    assert max(gathered + hops) < min(
+        smallest_sharded_kernel, rows * n_txt * cfg.hidden_size)
+    for op in ("all-to-all", "reduce-scatter"):
         assert _collectives(hlo, op) == [], op
 
 

@@ -1,4 +1,5 @@
-"""The Pallas kernels (the UNet's two and the grouped expert matmul),
+"""The Pallas kernels (the UNet's two and the grouped expert matmul), and
+one MMDiT block across the four chips of the slice,
 compiled for a described v5e chip at the published widths (no chip attached: the TPU compiler is installed here and
 refuses what the chip's would — block shapes the lowering cannot tile,
 casts Mosaic has no layout for, more fast memory than a kernel may use).
@@ -25,7 +26,8 @@ from chiaswarm_tpu.ops.group_norm import (
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def v5e_slice():
+    """The four described chips of a v5e 2x2."""
     from jax.experimental import topologies
 
     try:
@@ -38,9 +40,14 @@ def v5e():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_slice):
+    return SingleDeviceSharding(v5e_slice[0])
 
 
 def _shape(sharding, shape, dtype=jnp.bfloat16):
@@ -139,3 +146,63 @@ def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated):
             _shape(v5e, (rows // tm,), jnp.int32),
             _shape(v5e, (), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flux_double_block_overlaps_its_collectives_on_four_v5e(
+        v5e_slice, monkeypatch):
+    """FLUX.1-dev's double block at `flux-backlog`'s shapes on [data=1,
+    tensor=4]: the chip's compiler makes each ring hop of the pair's
+    products a `collective-permute-start` / `-done`, and its scheduler
+    puts matmuls between the two (ISSUE 33)."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from chiaswarm_tpu.models.flux import DoubleStreamBlock, FluxConfig
+    from chiaswarm_tpu.ops import attention, platform
+    from chiaswarm_tpu.parallel import tensor
+    from chiaswarm_tpu.parallel.mesh import make_mesh
+
+    for module in (platform, attention):  # the trace is for the chip
+        monkeypatch.setattr(module, "trace_platform", lambda: "tpu")
+    cfg, rows, n_img, n_txt = FluxConfig(), 2, 4096, 512
+    mesh = make_mesh(v5e_slice, tensor=4)
+    assert tensor._ring_order(mesh) == [0, 1, 3, 2]  # never a diagonal
+    block = DoubleStreamBlock(cfg, dtype=jnp.bfloat16, head_groups=4)
+    whole = NamedSharding(mesh, P())
+    args = [_shape(whole, dims) for dims in (
+        (rows, n_img, cfg.hidden_size), (rows, n_txt, cfg.hidden_size),
+        (rows, cfg.hidden_size), (rows, n_img + n_txt, cfg.head_dim // 2),
+        (rows, n_img + n_txt, cfg.head_dim // 2))]
+    params = jax.eval_shape(
+        lambda *a: block.init(jax.random.key(0), *a), *args)["params"]
+    shardings = tensor.sharding_tree(
+        mesh, {"double_blocks_0": params}, tensor.flux_partition_rules())
+    params = jax.tree_util.tree_map(
+        lambda s, place: _shape(place, s.shape), params,
+        shardings["double_blocks_0"])
+    jax.clear_caches()  # the products are jitted by mesh and shape
+    with platform.mesh_scope(mesh):
+        hlo = jax.jit(lambda p, *a: block.apply({"params": p}, *a)).lower(
+            params, *args).compile().as_text()
+    jax.clear_caches()
+    assert "tpu_custom_call" in hlo  # the flash kernel, six heads a chip
+    assert not re.search(r" all-reduce(-start)?\(", hlo)
+    # the scheduled entry computation, in order: what runs between a hop's
+    # start and its done (kOutput: a fusion rooted at a matmul)
+    entry = hlo[hlo.index("\nENTRY "):].splitlines()
+    started, straddled, hops = {}, 0, 0
+    for at, line in enumerate(entry):
+        start = re.match(r"\s*%(collective-permute-start[\w.]*) = ", line)
+        done = re.search(r"collective-permute-done\(%([\w.\-]+)\)", line)
+        if start:
+            started[start.group(1)] = at
+        elif done:
+            hops += 1
+            between = entry[started[done.group(1)]:at]
+            straddled += any("kind=kOutput" in other for other in between)
+    # 8 products of 3 hops, each way for `img`'s chunks and one way for
+    # `txt`'s; the first hop of a gather is waited for ahead of its first
+    # matmul, the others travel under one
+    assert hops == 4 * 6 + 4 * 3
+    assert straddled >= hops // 2, (straddled, hops)
