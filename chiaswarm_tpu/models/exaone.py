@@ -41,6 +41,7 @@ Rotary pairs are the two halves of the head's dims (`rope_type`
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -272,27 +273,39 @@ def cache_bytes(cfg: ExaoneConfig, rows: int, positions: int,
 POSITION_CHUNKS = True
 
 
+def span_runs(lengths, start: int):
+    """Whether a chunk's span of positions from `start` is run: some row
+    of the chunk has a prompt token at `start` or past it. `lengths` are
+    the chunk's own rows' (the device's in `prefill_rows`, the host's
+    where a pass counts what it left out). A row's tokens are its first
+    `lengths` positions, so once a span is not run no later one is."""
+    return (lengths > start).any()
+
+
 def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
                  load, interpret: bool = False):
     """Rows `ids` [R, S] (a row's prompt first, padding after: under a
     causal mask no real token sees padding) through every layer,
-    `chunk_slots` positions at a time. Returns the hidden state of each
-    row's last prompt token [R, hidden], a layer's cache entries ((keys,
-    values): `[R, S, ...]` on a full layer, the ring `[R, window, ...]` on
-    a sliding one) and the tally."""
+    `chunk_slots` positions at a time; a span no row reaches is not run
+    (`span_runs`: a conditional on the device, the one program whatever
+    the lengths). Returns the hidden state of each row's last prompt
+    token [R, hidden], a layer's cache entries ((keys, values): `[R, S,
+    ...]` on a full layer, the ring `[R, window, ...]` on a sliding one)
+    and the tally."""
     rows, slots = ids.shape
     assert slots % chunk_slots == 0, (slots, chunk_slots)
     dtype = params["embed"].dtype
     scale = cfg.head_dim ** -0.5
-    # what the earlier chunks left: a full layer's keys and values whole,
-    # a sliding layer's last `window` and its ring
-    kept = [[[], []] if not window else [None, None]
-            for window in cfg.windows]
-    rings = [None if not window else [
-        jnp.zeros((rows, window, cfg.num_key_value_heads, cfg.head_dim),
-                  dtype) for _ in range(2)] for window in cfg.windows]
-    last = jnp.zeros((rows, cfg.hidden_size), dtype)
-    for start in range(0, slots, chunk_slots):
+
+    def zeros(columns):
+        return jnp.zeros((rows, columns, cfg.num_key_value_heads,
+                          cfg.head_dim), dtype)
+
+    def run(start, rings, kept, last, load):
+        """The span from `start` through every layer. `kept` is read only;
+        what the span adds to it comes back a layer: a full layer's keys
+        and values of these positions, a sliding layer's last `window`."""
+        rings, added = list(rings), []
         positions = jnp.broadcast_to(
             start + jnp.arange(chunk_slots), (rows, chunk_slots))
         valid = (positions < lengths[:, None]).reshape(-1)
@@ -301,20 +314,15 @@ def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
                 zip(params["layers"], cfg.windows)):
             h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
             q, k, v = _heads(layer["attn"], cfg, h, positions, window)
+            keys, values = (jnp.concatenate([*old, entry], 1)
+                            for old, entry in zip(kept[index], (k, v)))
             if window:
-                for n, entry in enumerate((k, v)):
-                    rings[index][n] = ring_fill(rings[index][n], entry,
-                                                start, lengths)
-                tail = kept[index]
-                keys, values = (
-                    entry if old is None else jnp.concatenate([old, entry], 1)
-                    for old, entry in zip(tail, (k, v)))
-                kept[index] = [keys[:, -window:], values[:, -window:]]
+                rings[index] = tuple(
+                    ring_fill(ring, entry, start, lengths)
+                    for ring, entry in zip(rings[index], (k, v)))
+                added.append((keys[:, -window:], values[:, -window:]))
             else:
-                kept[index][0].append(k)
-                kept[index][1].append(v)
-                keys, values = (jnp.concatenate(part, 1) if len(part) > 1
-                                else part[0] for part in kept[index])
+                added.append((k, v))
             out = dot_product_attention(q, keys, values, scale=scale,
                                         causal=True, window=window)
             x = x + dot(out.reshape(rows, chunk_slots, -1),
@@ -329,12 +337,40 @@ def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
         mine = (at >= 0) & (at < chunk_slots)
         picked = jnp.take_along_axis(
             x, jnp.clip(at, 0, chunk_slots - 1)[:, None, None], axis=1)[:, 0]
-        last = jnp.where(mine[:, None], picked, last)
+        return (rings, added, jnp.where(mine[:, None], picked, last), load)
+
+    def skip(start, rings, kept, last, load):
+        """What `run` gives where no row reaches `start`, wherever anything
+        reads it. Rings, `last` and the tally as they came: `run` finds no
+        position of its own for them. A full layer's keys and values
+        zero: columns past every row's length, which `decode_masks` shows
+        to nobody, written and not left to the buffer (`_whole_rows`). A
+        sliding layer's tail zero too: only a later span would read it,
+        and none runs."""
+        added = [(zeros(min(start + chunk_slots, window)
+                        if window else chunk_slots),) * 2
+                 for window in cfg.windows]
+        return rings, added, last, load
+
+    # what the earlier spans left, (keys, values) a layer: a sliding
+    # layer's ring, and in `kept` its last `window` and a full layer's
+    # whole, span by span
+    rings = [(zeros(window),) * 2 if window else None
+             for window in cfg.windows]
+    kept = [([], []) for _ in cfg.windows]
+    last = jnp.zeros((rows, cfg.hidden_size), dtype)
+    for start in range(0, slots, chunk_slots):
+        # a branch is handed what it reads and gives back what the span
+        # adds: the full layer's keys so far go in and do not come out
+        rings, added, last, load = jax.lax.cond(
+            span_runs(lengths, start), functools.partial(run, start),
+            functools.partial(skip, start), rings, kept, last, load)
+        kept = [tuple(([] if window else old) + [new]
+                      for old, new in zip(before, after))
+                for window, before, after in zip(cfg.windows, kept, added)]
     entries = [
-        tuple(rings[index]) if window else tuple(
-            jnp.concatenate(part, 1) if len(part) > 1 else part[0]
-            for part in kept[index])
-        for index, window in enumerate(cfg.windows)]
+        ring if window else tuple(jnp.concatenate(part, 1) for part in whole)
+        for window, ring, whole in zip(cfg.windows, rings, kept)]
     return last, entries, load
 
 

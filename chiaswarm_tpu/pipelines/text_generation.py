@@ -26,12 +26,13 @@ a position a layer, K-EXAONE keys and values a position on its full
 layers and a ring of its window on the others): the whole is
 `swarm_pass_cache_bytes{model}`, the rings' part
 `swarm_pass_window_cache_bytes{model}`. A pass counts its prompt slots
-(`swarm_prefill_slots_total{model, kind}`: `real` ids and the `padding`
-that fills rows to the bucket, computed all the same); how the routing
-fell comes back with the ids (`swarm_expert_pairs_total`,
-`swarm_routed_tokens_total`, `swarm_expert_pairs_max_total`; the
-envelope's `routing` has the same a program, with the experts that had a
-pair and the calls).
+(`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
+that fills rows to the bucket and was computed all the same, and the
+padding `skipped`: chunks the model's prefill did not run because none of
+their rows reached them, `span_runs`); how the routing fell comes back
+with the ids (`swarm_expert_pairs_total`, `swarm_routed_tokens_total`,
+`swarm_expert_pairs_max_total`; the envelope's `routing` has the same a
+program, with the experts that had a pair and the calls).
 
 No tokenizer: ids travel on the wire, and there is no stop token, every
 row generates `max_new_tokens`. `test/` names are seeded weights: `tiny` in
@@ -72,7 +73,8 @@ PREFILL_CHUNK_TOKENS = 4096
 
 # family -> the module that has the model: `config_for`, `param_shapes`,
 # `init_params`, `prefill`, `step`, `empty_load`, `cache_bytes`,
-# `POSITION_CHUNKS`
+# `POSITION_CHUNKS` and, where its prefill leaves out a chunk that is all
+# padding, the rule it goes by: `span_runs`
 _MODELS = {"kimi_k2": kimi, "exaone_moe": exaone}
 
 EXPERT_PAIRS = telemetry.counter(
@@ -99,9 +101,10 @@ PASS_WINDOW_CACHE_BYTES = telemetry.gauge(
     ("model",))
 PREFILL_SLOTS = telemetry.counter(
     "swarm_prefill_slots_total",
-    "Prompt slots the prefill programs computed, by model and kind (real: "
+    "Prompt slots of the prefill programs' passes, by model and kind (real: "
     "a prompt's ids; padding: what fills a row to its bucket and a pass "
-    "to its rows)", ("model", "kind"))
+    "to its rows, and was computed; skipped: the padding of chunks that "
+    "were not run)", ("model", "kind"))
 
 
 def prefill_chunk(rows: int, slots: int, spans: bool = True
@@ -328,9 +331,16 @@ class TextGenerationPipeline:
         chunk_rows, chunk_slots = prefill_chunk(
             rows, slots, self.model.POSITION_CHUNKS)
         chunks = (rows // chunk_rows) * (slots // chunk_slots)
+        # chunks the prefill program did not run, by the model's own rule
+        # on the lengths it was given
+        runs = getattr(self.model, "span_runs", None)
+        skipped = 0 if runs is None else sum(
+            not runs(lengths[at:at + chunk_rows], start)
+            for at in range(0, rows, chunk_rows)
+            for start in range(0, slots, chunk_slots))
         routing = {
-            **tally(pairs, sums, chunks + new_tokens - 1),
-            "prefill": tally(before, before_sums, chunks),
+            **tally(pairs, sums, chunks - skipped + new_tokens - 1),
+            "prefill": tally(before, before_sums, chunks - skipped),
             "decode": tally(pairs - before, sums - before_sums,
                             new_tokens - 1),
             "pairs_by_expert": pairs.sum(axis=0).tolist()}
@@ -340,8 +350,10 @@ class TextGenerationPipeline:
         EXPERT_PAIRS_MAX.inc(routing["pairs_max"], **label)
         prompt_tokens = int(lengths.sum())
         PREFILL_SLOTS.inc(prompt_tokens, kind="real", **label)
-        PREFILL_SLOTS.inc(rows * slots - prompt_tokens, kind="padding",
-                          **label)
+        skipped_slots = skipped * chunk_rows * chunk_slots
+        PREFILL_SLOTS.inc(rows * slots - prompt_tokens - skipped_slots,
+                          kind="padding", **label)
+        PREFILL_SLOTS.inc(skipped_slots, kind="skipped", **label)
         cache_bytes, cache_bytes_window = self.cache_bytes(rows, positions)
         results, at = [], 0
         for request in requests:
@@ -359,6 +371,7 @@ class TextGenerationPipeline:
                 "decode_steps": new_tokens - 1,
                 "temperature": float(temperature),
                 "prefill_chunks": chunks,
+                "prefill_chunks_skipped": skipped,
                 "cache_bytes": cache_bytes,
                 "cache_bytes_window": cache_bytes_window,
                 "routing": routing,

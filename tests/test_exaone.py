@@ -127,6 +127,85 @@ def test_prefill_writes_every_element_of_the_cache(params, monkeypatch):
     assert not np.asarray(cache[3][1][:, 16:]).any()
 
 
+def _prefill_from_nan(every_span: bool, positions: int, chunk_rows: int,
+                      chunk_slots: int, p, ids, lengths):
+    """`exaone.prefill` traced with the cache started from NaN and, for
+    `every_span`, a rule that runs a span whatever the lengths (a device
+    value all the same: the compiler is to keep the one program, and with
+    it the order of every sum)."""
+    rule, made = exaone.span_runs, exaone.new_cache
+    exaone.new_cache = lambda *a: jax.tree_util.tree_map(
+        lambda x: jnp.full_like(x, jnp.nan), made(*a))
+    if every_span:
+        exaone.span_runs = lambda lengths, start: (lengths > -1).any()
+    try:
+        return exaone.prefill(p, CFG, ids, lengths, positions, chunk_rows,
+                              chunk_slots)
+    finally:
+        exaone.span_runs, exaone.new_cache = rule, made
+
+
+_PREFILL = jax.jit(_prefill_from_nan, static_argnums=(0, 1, 2, 3))
+_STEP = jax.jit(
+    lambda p, t, n, number, slots, c, tally: exaone.step(
+        p, CFG, t, n, number, slots, c, tally, valid=n > 0),
+    static_argnums=4)
+
+
+@pytest.mark.parametrize("slots, chunk_rows, lengths, skipped", [
+    (24, 1, [8, 16, 8, 16], 6),   # a row ends where a span starts
+    (24, 1, [9, 17, 9, 17], 2),   # ... and one id into it
+    (24, 1, [1, 1, 1, 1], 8),
+    (24, 1, [24, 24, 24, 24], 0),  # the whole bucket: every span runs
+    (24, 1, [24, 5, 0, 0], 8),    # rows that only pad the pass: no span
+    (8, 2, [8, 3, 0, 0], 1),      # whole rows a chunk: the padding rows'
+], ids=["at_a_start", "one_past_a_start", "one_id", "whole_bucket",
+        "rows_that_pad_the_pass", "whole_rows"])
+def test_a_span_no_row_reaches_is_left_out_and_nothing_read_changes(
+        params, slots, chunk_rows, lengths, skipped):
+    """Spans of 8 positions: the conditional against every span run.
+    Logits, rings, tally and six greedy decode steps (every ring wraps) are
+    the same to the bit; of the full layer's cache the columns a mask
+    shows are, and a span left out is zeros where the buffer held NaN."""
+    rows, new, span = 4, 6, 8
+    rng = np.random.default_rng(slots + sum(lengths))
+    lengths = np.array(lengths, np.int32)
+    # every slot an id, padding too: a span run on padding leaves keys
+    ids = rng.integers(1, CFG.vocab_size, (rows, slots)).astype(np.int32)
+    got, want = (
+        _PREFILL(every, slots + new, chunk_rows, span, params, ids, lengths)
+        for every in (False, True))
+    left_out = np.zeros((rows, slots + new), bool)
+    for at in range(0, rows, chunk_rows):
+        for start in range(0, slots, span):
+            if not exaone.span_runs(lengths[at:at + chunk_rows], start):
+                left_out[at:at + chunk_rows, start:start + span] = True
+    assert left_out.sum() == skipped * chunk_rows * span
+    seen, _ = exaone.decode_masks(CFG, lengths, new - 1, slots, slots + new)
+    assert not (np.asarray(seen) & left_out).any()
+    for step in range(new + 1):
+        (logits, cache, load), (logits_, cache_, load_) = got, want
+        assert np.array_equal(logits, logits_)
+        assert np.isfinite(np.asarray(logits)).all()
+        for index, window in enumerate(CFG.windows):
+            for mine, theirs in zip(cache[index], cache_[index]):
+                mine, theirs = np.asarray(mine), np.asarray(theirs)
+                assert np.isfinite(mine).all()
+                if window:
+                    assert np.array_equal(mine, theirs)
+                    continue
+                assert np.array_equal(mine[~left_out], theirs[~left_out])
+                assert not mine[left_out].any()
+                assert theirs[left_out].all() or not left_out.any()
+        for mine, theirs in zip(load, load_):
+            assert np.array_equal(mine, theirs)
+        if step < new:
+            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            got, want = (
+                _STEP(params, tokens, lengths, step, slots, cache, load)
+                for _, cache, load in (got, want))
+
+
 def test_the_references_visibility_and_the_tokens_a_logit_needs(params):
     """The reference itself: who sees whom, which tokens each layer has to
     put out for the last logits (everything below the full layer, a window

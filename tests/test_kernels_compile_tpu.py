@@ -1,5 +1,6 @@
-"""The Pallas kernels (the UNet's two and the grouped expert matmul), and
-one MMDiT block across the four chips of the slice,
+"""The Pallas kernels (the UNet's two and the grouped expert matmul), one
+MMDiT block across the four chips of the slice, and K-EXAONE's prefill
+program at its cell's shape,
 compiled for a described v5e chip at the published widths (no chip attached: the TPU compiler is installed here and
 refuses what the chip's would — block shapes the lowering cannot tile,
 casts Mosaic has no layout for, more fast memory than a kernel may use).
@@ -223,3 +224,66 @@ def test_flux_double_block_overlaps_its_collectives_on_four_v5e(
     # matmul, the others travel under one
     assert hops == 4 * 6 + 4 * 3
     assert straddled >= hops // 2, (straddled, hops)
+
+
+def test_exaone_prefill_keeps_a_conditional_a_span_on_v5e(v5e, monkeypatch):
+    """`exaone-long-documents`' prefill program (4 rows of 16384 slots, a
+    chunk one row's 4096 positions, the published widths): the chip's
+    compiler keeps each span's `conditional` (ISSUE 37), the branch that
+    runs holds the span's five attention calls and its four expert
+    layers' two grouped matmuls each, the other no kernel at all."""
+    import re
+
+    from chiaswarm_tpu.models import exaone
+    from chiaswarm_tpu.ops import attention, platform
+    from chiaswarm_tpu.pipelines.text_generation import prefill_chunk
+
+    for module in (platform, attention):  # the trace is for the chip
+        monkeypatch.setattr(module, "trace_platform", lambda: "tpu")
+    cfg, rows, slots, new = exaone.EXAONE_236B_EP8, 4, 16384, 128
+    chunk = prefill_chunk(rows, slots, exaone.POSITION_CHUNKS)
+    assert chunk == (1, 4096)
+    params = jax.tree_util.tree_map(
+        lambda leaf: _shape(v5e, leaf.shape),
+        exaone.param_shapes(cfg, jnp.bfloat16))
+    compiled = jax.jit(lambda p, ids, lengths: exaone.prefill(
+        p, cfg, ids, lengths, slots + new, *chunk)).lower(
+            params, _shape(v5e, (rows, slots), jnp.int32),
+            _shape(v5e, (rows,), jnp.int32)).compile()
+    # the compiled module, a computation's lines under its name
+    bodies, name = {}, None
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+
+    def kernels(name, seen):
+        """(banded_attention, expert_matmul) calls of a computation and
+        of every computation it calls."""
+        if name in seen or name not in bodies:
+            return 0, 0
+        seen.add(name)
+        counts = [sum(bool(re.match(rf"\s*%{kernel}[.\d]* = .*custom-call\(",
+                                    line)) for line in bodies[name])
+                  for kernel in ("banded_attention", "expert_matmul")]
+        for called in re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                "\n".join(bodies[name])):
+            more = kernels(called, seen)
+            counts = [a + b for a, b in zip(counts, more)]
+        return tuple(counts)
+
+    spans = [re.findall(r"%?([\w.\-]+)", branches)
+             for lines in bodies.values() for line in lines
+             for branches in re.findall(
+                 r" conditional\(.*branch_computations=\{([^}]*)\}", line)]
+    assert len(spans) == slots // chunk[1]
+    for skipped, run in spans:
+        assert kernels(skipped, set()) == (0, 0)
+        assert kernels(run, set()) == (len(cfg.windows),
+                                       2 * cfg.expert_layers)
+    # the scoped branches' temporaries: 2.16 GB where every span ran
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

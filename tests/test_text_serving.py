@@ -337,6 +337,9 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
     assert coalesce_key(job) == (
         "test/tiny-exaone", "exaone_moe", "txt2txt", 64, 7, 0.0)
 
+    label = {"model": "test/tiny-exaone"}
+    before = {kind: text_generation.PREFILL_SLOTS.value(kind=kind, **label)
+              for kind in ("real", "padding", "skipped")}
     pipe = text_generation.TextGenerationPipeline(
         "test/tiny-exaone", allow_random_init=True)
     assert text_generation.prefill_chunk(1, 64) == (1, 64)
@@ -344,6 +347,7 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
         [{"prompt_ids": rows, "rng": jax.random.key(3)}],
         max_new_tokens=7, temperature=0.0)
     assert whole["prefill_chunks"] == 1
+    assert whole["prefill_chunks_skipped"] == 0
 
     monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", 16)
     assert text_generation.prefill_chunk(1, 64) == (1, 16)
@@ -369,6 +373,11 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
     config = status["result"]["pipeline_config"]
     assert json.loads(blob)["token_ids"] == want.tolist()
     assert config["prefill_chunks"] == 4 and config["prompt_slots"] == 64
+    # the span from 48 on is past the row's 41 ids and was not run: three
+    # calls of each of the four expert layers, and six decode steps'
+    assert config["prefill_chunks_skipped"] == 1
+    assert config["routing"]["prefill"]["calls"] == 3 * 4
+    assert config["routing"]["calls"] == (3 + 6) * 4
     assert config["padded_rows"] == 1 and config["prompt_tokens"] == 41
     # one full layer of 71 positions and four rings of 4: keys and values
     # of 2 heads x 16, float32
@@ -377,10 +386,58 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
     assert config["cache_bytes"] == (71 + 4 * 4) * a_position
     from chiaswarm_tpu import telemetry
 
-    label = {"model": "test/tiny-exaone"}
     assert text_generation.PASS_WINDOW_CACHE_BYTES.value(**label) == 4096
     assert text_generation.PASS_CACHE_BYTES.value(**label) == 87 * 256
-    # twice 41 real ids and 23 of padding: this run and the one above
-    assert text_generation.PREFILL_SLOTS.value(kind="real", **label) >= 82
-    assert text_generation.PREFILL_SLOTS.value(kind="padding", **label) >= 46
+    # twice 41 real ids; 23 of padding computed in the run above, and in
+    # this one 7 computed and the last span's 16 left out
+    moved = {kind: text_generation.PREFILL_SLOTS.value(kind=kind, **label)
+             - was for kind, was in before.items()}
+    assert moved == {"real": 82, "padding": 23 + 7, "skipped": 16}
     assert "swarm_pass_window_cache_bytes" in telemetry.REGISTRY.render()
+
+
+@pytest.mark.parametrize(
+    "model, chunk_tokens, lengths, chunks, skipped, skipped_slots", [
+        # spans of 16 of a 64-slot row, a row a chunk: 1 + 2 + 3 spans past
+        # the rows' ends and the 4 of the row that pads the pass to 4
+        ("test/tiny-exaone", 16, [41, 17, 5], 16, 10, 160),
+        # whole rows, two a chunk, 5 rows in a pass of 8: rows 6 and 7
+        ("test/tiny-exaone", 32, [9, 3, 16, 2, 7], 4, 1, 32),
+        # every span has a row's token
+        ("test/tiny-exaone", 16, [64, 49], 8, 0, 0),
+        # Kimi's chunks are whole rows and all of them are run
+        ("test/tiny-kimi", 32, [9, 3, 16, 2, 7], 4, 0, 0),
+    ], ids=["spans", "whole_rows", "nothing_to_skip", "kimi"])
+def test_a_pass_counts_real_padding_and_skipped_slots(
+        monkeypatch, model, chunk_tokens, lengths, chunks, skipped,
+        skipped_slots):
+    import jax
+
+    from chiaswarm_tpu.pipelines import text_generation
+
+    monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", chunk_tokens)
+    pipe = text_generation.TextGenerationPipeline(
+        model, allow_random_init=True)
+    rng = np.random.default_rng(len(lengths))
+    prompts = [rng.integers(0, 128, n).tolist() for n in lengths]
+    kinds = ("real", "padding", "skipped")
+    before = [text_generation.PREFILL_SLOTS.value(kind=kind, model=model)
+              for kind in kinds]
+    ((ids, config),) = pipe.run_batched(
+        [{"prompt_ids": prompts, "rng": jax.random.key(1)}],
+        max_new_tokens=3, temperature=0.0)
+    real, padding, left_out = (
+        text_generation.PREFILL_SLOTS.value(kind=kind, model=model) - was
+        for kind, was in zip(kinds, before))
+    rows, slots = config["padded_rows"], config["prompt_slots"]
+    assert ids.shape == (len(lengths), 3)
+    assert (real, left_out) == (sum(lengths), skipped_slots)
+    assert real + padding + left_out == rows * slots
+    assert config["prefill_chunks"] == chunks
+    assert config["prefill_chunks_skipped"] == skipped
+    layers = pipe.config.expert_layers
+    assert config["routing"]["prefill"]["calls"] == (chunks - skipped) * layers
+    assert config["routing"]["calls"] == (chunks - skipped + 2) * layers
+    # padding is routed nowhere, run or not
+    assert config["routing"]["prefill"]["routed"] == (
+        sum(lengths) * pipe.config.num_experts_per_tok * layers)
