@@ -107,11 +107,12 @@ _GROUP_ROWS = telemetry.histogram(
 LINGER_PASS_SHARE = 0.025
 _PASS_KEYS_KEPT = 256
 
-_LINGER_WAIT = telemetry.histogram(
-    "swarm_batch_linger_wait_seconds",
-    "Open time of a coalescing group from first job to flush",
-    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0),
-)
+# what a job carries from the poll that brought it to the pass that runs
+# it, beside what the hive sent (the slice worker takes both off before the
+# arguments are formatted): the wall instant it arrived, where `queue_wait`
+# and `linger` start, and the spans stamped on it since, for its envelope
+ARRIVED, SPANS = "_telemetry_arrived", "_telemetry_spans"
+
 # the tentpole metric: where each claimed work item landed relative to
 # its model's warm state. affinity = the resident slice took it; steal =
 # the resident slice was busy and an idle slice took it anyway; cold =
@@ -234,9 +235,14 @@ class BatchScheduler:
         self._ready_jobs -= len(entry["jobs"])
         self._ready_rows -= entry["rows"]
         self._executing_rows += entry["rows"]
-        now = time.monotonic()
+        now, released = time.monotonic(), entry["released"]
+        waited = time.time() - released
         for job in entry["jobs"]:
             self._claimed_at[str(job.get("id"))] = now
+            # span "claim": on the board until a slice took the work item
+            # (a busy slice, placement); a gang's members share it
+            telemetry.Span("claim", thread="wait", spans=job.setdefault(
+                SPANS, [])).record(released, waited)
 
     def linger_for(self, key: tuple) -> float:
         """Seconds a new group of this key waits for batchmates: the fixed
@@ -359,9 +365,19 @@ class BatchScheduler:
         rows = sum(job_rows(j) for j in jobs)
         self._ready_jobs += len(jobs)
         self._ready_rows += rows
+        released = time.time()
+        for job in jobs:
+            # span "linger": from the job's arrival to its group's release
+            # to the board, the wait for batchmates (microseconds for a
+            # hive gang or a solo: nobody waited); "waiting for batchmates"
+            # and "waiting for a slice" (`claim`) are different knobs
+            arrived = job.setdefault(ARRIVED, released)
+            telemetry.Span("linger", thread="wait", spans=job.setdefault(
+                SPANS, [])).record(arrived, released - arrived)
         self._board.append({
             "jobs": jobs,
             "rows": rows,
+            "released": released,
             "model": placement_model(jobs[0]),
             "interactive": any(is_interactive(j) for j in jobs),
         })
@@ -369,6 +385,7 @@ class BatchScheduler:
 
     async def put(self, job: dict) -> None:
         self._outstanding += 1
+        job.setdefault(ARRIVED, time.time())
         if self._closed or self.max_coalesce <= 1 or self.linger_s <= 0:
             self._release_solo(job)
             return
@@ -403,8 +420,7 @@ class BatchScheduler:
                 except Exception:  # capacity probe is advisory, never fatal
                     logger.exception("rows_limit probe failed")
             loop = asyncio.get_running_loop()
-            group = {"jobs": [], "rows": 0, "cap": cap, "adapters": set(),
-                     "opened": time.monotonic()}
+            group = {"jobs": [], "rows": 0, "cap": cap, "adapters": set()}
             group["timer"] = loop.call_later(
                 self.linger_for(key), self._flush, key)
             self._pending[key] = group
@@ -481,10 +497,8 @@ class BatchScheduler:
         _FLUSHES.inc(reason="gang")
         _GROUP_JOBS.observe(len(jobs))
         _GROUP_ROWS.observe(rows)
-        _LINGER_WAIT.observe(0.0)
         for job in jobs:
             if isinstance(job.get("trace"), dict):
-                job["trace"]["lingered_s"] = 0.0
                 job["trace"]["coalesced_with"] = len(jobs) - 1
         if len(jobs) > 1:
             logger.info("hive gang of %d jobs (%d images) for %s",
@@ -529,17 +543,9 @@ class BatchScheduler:
         _FLUSHES.inc(reason=reason)
         _GROUP_JOBS.observe(len(group["jobs"]))
         _GROUP_ROWS.observe(group["rows"])
-        lingered = time.monotonic() - group["opened"]
-        _LINGER_WAIT.observe(lingered)
-        # split the linger window out of the worker-side queue_wait in
-        # each job's trace context (ISSUE 8): "waiting for batchmates"
-        # and "waiting for a slice" are different tuning knobs
-        # (batch_linger_ms vs capacity), and the job's end-to-end
-        # timeline should attribute them separately
         gang_id = uuid.uuid4().hex[:12]
         for index, job in enumerate(group["jobs"]):
             if isinstance(job.get("trace"), dict):
-                job["trace"]["lingered_s"] = round(lingered, 3)
                 job["trace"]["coalesced_with"] = len(group["jobs"]) - 1
                 if len(group["jobs"]) > 1:
                     # the pass these jobs ride in, as the hive stamps a

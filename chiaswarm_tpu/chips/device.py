@@ -176,11 +176,6 @@ class ChipSet:
         self._mutex = threading.Lock()
         # when the last pass released the busy lock (monotonic)
         self._released_at: float | None = None
-        # the busy lock's own clock: seconds passes have held it, and
-        # since when the current one does (`held_seconds`)
-        self._held_clock = threading.Lock()
-        self._held_total = 0.0
-        self._held_since: float | None = None
         # geometry of the most recent pass (healthz / swarm_top column);
         # starts at the construction-time default
         self.last_geometry: tuple[int, int, int] = (
@@ -324,29 +319,13 @@ class ChipSet:
         if not self._mutex.acquire(blocking=False):
             logger.error("ChipSet %s is busy but got invoked.", self.identifier())
             raise Exception("busy")
-        now = time.monotonic()
         if self._released_at is not None:
-            _FREE_SECONDS.inc(now - self._released_at,
+            _FREE_SECONDS.inc(time.monotonic() - self._released_at,
                               slice=str(self.slice_id))
-        with self._held_clock:
-            self._held_since = now
 
     def _release_after_pass(self) -> None:
         self._released_at = time.monotonic()
-        with self._held_clock:
-            self._held_total += self._released_at - self._held_since
-            self._held_since = None
         self._mutex.release()
-
-    def held_seconds(self) -> float:
-        """Seconds passes have held the busy lock so far, the running
-        pass included. Read twice from another thread, it says how much
-        of the interval between a pass overlapped (the worker's
-        packaging thread does: swarm_package_seconds_total)."""
-        with self._held_clock:
-            running = (0.0 if self._held_since is None
-                       else time.monotonic() - self._held_since)
-            return self._held_total + running
 
     def __call__(self, func, **kwargs):
         """Run one job on this slice under the busy lock.

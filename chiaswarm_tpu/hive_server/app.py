@@ -662,6 +662,10 @@ class HiveServer:
         return web.json_response(reply, headers=self._epoch_headers())
 
     async def _results(self, request: web.Request) -> web.Response:
+        # before the body is read and parsed: what follows until the
+        # settle stamp is the hive's own work, what came before it the
+        # worker's spool, its queue to the uploader and the wire
+        received_wall = self.queue.clock.wall()
         if not self._authorized(request):
             return self._unauthorized()
         refused = self._refused(request)
@@ -762,6 +766,7 @@ class HiveServer:
         settle_event = {
             "event": "settle", "wall": self.queue.clock.wall(),
             "worker": record.completed_by, "disposition": status,
+            "received_wall": received_wall,
         }
         # the worker echoes the wire trace context; its attempt number
         # ties the envelope's stage spans to the dispatch that produced
@@ -1150,8 +1155,11 @@ class HiveServer:
     async def _job_trace(self, request: web.Request) -> web.Response:
         """One ordered, gap-attributed timeline per job: hive lifecycle
         events (admit/shed/dispatch/lease/redeliver/settle, WAL-durable)
-        merged with the worker's stage spans from the settled envelope.
-        See hive_server/trace.py for the assembly contract."""
+        merged with the worker's stage spans from the settled envelope
+        (a job's life between passes included: `tick_wait`, `poll`,
+        `queue_wait`, `format_args`, `handoff`; the `settle` event's
+        `received_wall` parts what they leave into the wire's and the
+        hive's). See hive_server/trace.py for the assembly contract."""
         if not self._authorized(request):
             return self._unauthorized()
         job_id = request.match_info["job_id"]

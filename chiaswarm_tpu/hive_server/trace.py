@@ -23,10 +23,22 @@ The timeline contract:
 - every inter-event gap is attributed (hive_queue, executing,
   lease_lost, resubmit_backoff, ...) so the sum of gaps IS the job's
   hive wall clock, with nothing hidden;
-- the final executing gap is broken down further with the worker's own
-  stage spans; whatever the spans do not cover (network hops, envelope
-  spooling, result upload) is reported honestly as ``unattributed_s``
-  rather than silently absorbed.
+- an executing gap is broken down further with the worker's own stage
+  spans, each clipped to the gap: a worker that stamps its life between
+  passes sends ``tick_wait`` and ``poll`` (they begin before the
+  dispatch they brought: what lies before it is the hive's queue, the
+  gap before), ``queue_wait`` (``linger``, ``claim`` and
+  ``package_wait`` inside it are its detail), ``format_args``, the
+  children of ``pass``, ``handoff`` and ``artifact_encode``; whatever
+  the spans do not cover (the envelope's spool write, its wait for the
+  uploader, the POST, the hive's own bookkeeping, any hole between the
+  spans) is reported honestly as ``unattributed_s`` rather than
+  silently absorbed, and where the ``settle`` event carries
+  ``received_wall`` (the instant the result's POST reached its handler)
+  split at it into ``unattributed_wire_s`` (before: the worker's and
+  the wire's) and ``unattributed_hive_s`` (after: parse, spool, settle);
+- ``worker.stages`` is the same list unclipped, every span on the one
+  wall clock.
 """
 
 from __future__ import annotations
@@ -146,10 +158,13 @@ def worker_stages(result: dict | None) -> list[dict]:
 
     From ``pipeline_config.spans`` (wall-stamped, thread-aware) when the
     worker sent them: the top level of what the job waited for, in start
-    order — ``queue_wait`` and the children of ``pass`` (the slice held;
+    order — what the worker stamps between passes (``tick_wait``,
+    ``poll``, ``queue_wait``, ``format_args``, ``handoff``,
+    ``artifact_encode``) and the children of ``pass`` (the slice held;
     it is the parent, never a stage), each with its ``start_wall``; a
-    span inside another on the same thread (``safety`` inside ``decode``)
-    is that stage's detail and would count its time twice.
+    span inside another on the same thread (``safety`` inside ``decode``,
+    ``linger`` inside ``queue_wait``) is that stage's detail and would
+    count its time twice.
 
     From ``pipeline_config.timings``'s ``*_s`` entries for a worker that
     sends no spans (insertion order is stage order — JSON preserves it),
@@ -174,6 +189,22 @@ def worker_stages(result: dict | None) -> list[dict]:
         except (TypeError, ValueError):
             continue
     return stages
+
+
+def clipped_stages(stages: list[dict], lo: float, hi: float) -> list[dict]:
+    """The part of each wall-stamped stage that lies inside [lo, hi] (the
+    gap the stages carve), stages outside it left out; stages without
+    stamps (an old worker's `timings`) as they are."""
+    carved = []
+    for stage in stages:
+        if "start_wall" not in stage:
+            return stages
+        start = max(stage["start_wall"], lo)
+        end = min(stage["start_wall"] + stage["seconds"], hi)
+        if end > start or (end == start and not stage["seconds"]):
+            carved.append({**stage, "start_wall": start,
+                           "seconds": end - start})
+    return carved
 
 
 def worker_total_seconds(stages: list[dict]) -> float:
@@ -261,12 +292,23 @@ def build_trace(record, now_wall: float) -> dict[str, Any]:
                 (prev.get("event"), nxt.get("event")), "other"),
         }
         if gap["attribution"] == "executing" and stages:
-            # the worker's own spans carve the execution window up;
-            # the remainder (wire, spool, upload, and any hole between
-            # the spans) is reported rather than absorbed
-            gap["worker_stages"] = stages
-            gap["worker_total_s"] = worker_total
-            gap["unattributed_s"] = round(max(seconds - worker_total, 0.0), 3)
+            # the worker's own spans carve the execution window up, as
+            # far as they lie in it; the remainder (spool, upload, the
+            # hive's bookkeeping, and any hole between the spans) is
+            # reported rather than absorbed
+            carved = clipped_stages(
+                stages, float(prev["wall"]), float(nxt["wall"]))
+            carved_total = round(worker_total_seconds(carved), 3)
+            unattributed = round(max(seconds - carved_total, 0.0), 3)
+            gap["worker_stages"] = carved
+            gap["worker_total_s"] = carved_total
+            gap["unattributed_s"] = unattributed
+            received = nxt.get("received_wall")
+            if isinstance(received, (int, float)):
+                hive_s = round(min(max(
+                    float(nxt["wall"]) - received, 0.0), unattributed), 3)
+                gap["unattributed_hive_s"] = hive_s
+                gap["unattributed_wire_s"] = round(unattributed - hive_s, 3)
         gaps.append(gap)
 
     terminal = events[-1].get("event") if events else None

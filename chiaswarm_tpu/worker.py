@@ -56,7 +56,7 @@ from concurrent.futures import ThreadPoolExecutor
 from . import __version__, faults, telemetry
 from . import cancel as cancel_mod
 from . import outbox as outbox_mod
-from .batching import BatchScheduler
+from .batching import ARRIVED, SPANS, BatchScheduler
 from .cancel import JobCancelled
 from .chips.allocator import SliceAllocator
 from .faults import FaultInjected
@@ -168,20 +168,11 @@ _RESUMES = telemetry.counter(
     "fetch_failed | unpack_failed degrade to a full pass)",
     ("outcome",),
 )
-_PACKAGE_SECONDS = telemetry.counter(
-    "swarm_package_seconds_total",
-    "Seconds a host thread spent packaging a pass's images (grid, "
-    "encode, base64, hash) after the pass freed its slice: overlapped = "
-    "yes while a later pass held the slice's busy lock, no while the "
-    "slice was free",
-    ("slice", "overlapped"),
-)
-_PACKAGE_BACKPRESSURE = telemetry.counter(
-    "swarm_package_backpressure_seconds_total",
-    "Seconds a claimed slice waited before a pass because two earlier "
-    "passes of its own were still unpackaged",
-    ("slice",),
-)
+_POLL_OVERSHOOT = telemetry.counter(
+    "swarm_poll_overshoot_seconds_total",
+    "Seconds the poll loop's sleeps lasted beyond what was asked: the lag "
+    "of the event loop that also carries the uploads, measured where it "
+    "delays a poll (per poll: swarm_job_stage_seconds_count{stage=\"poll\"})")
 _JOBS_CANCELLED = telemetry.counter(
     "swarm_jobs_cancelled_total",
     "Hive-revoked jobs this worker dropped, by where the cancel caught "
@@ -250,6 +241,12 @@ class Worker:
         # so a board entry blocked on "no slice free" dispatches the
         # moment release()/reinstate() happens
         self.allocator.add_free_listener(self.batcher.notify)
+        # since when (wall) this worker could have taken work and had not
+        # asked, None while it cannot; and when its last poll ended: what
+        # `tick_wait` is reckoned from (_note_capacity, poll_loop)
+        self._able_since: float | None = None
+        self._polled_at = time.time()
+        self.allocator.add_free_listener(self._note_capacity)
         self.result_queue: asyncio.Queue = asyncio.Queue()
         # durable result spool: envelopes land here BEFORE the first
         # upload attempt and are unlinked only on hive ACK (outbox.py)
@@ -789,97 +786,133 @@ class Worker:
 
     # --- producer: poll the hive ---
 
+    def _note_capacity(self) -> None:
+        """Called wherever the answer to "could this worker take work"
+        may have changed (a slice released or claimed, a batch done, a
+        held job cancelled, every tick of the poll): keeps the instant it
+        last became yes."""
+        if (self._draining.is_set() or self.batcher.full()
+                or not self.allocator.has_free_slice()):
+            self._able_since = None
+        elif self._able_since is None:
+            self._able_since = time.time()
+
     async def poll_loop(self) -> None:
         sleep_seconds = POLL_SECONDS
         while True:
-            can_take = (not self._draining.is_set() and not self.batcher.full()
-                        and self.allocator.has_free_slice())
-            # cancel-only heartbeat (ISSUE 10): a worker whose every
-            # slice is busy used to go silent for the whole denoise —
-            # exactly the window in which a cancel matters most. It now
-            # keeps polling with `cancel_only=1`: the hive skips dispatch
-            # (and a legacy hive that hands jobs anyway just feeds the
-            # batcher early), keeps the worker live in its directory,
-            # and piggybacks lease revocations for the executing slices.
-            heartbeat = (not can_take and not self._draining.is_set()
-                         and self.batcher.outstanding_jobs > 0)
-            if can_take or heartbeat:
-                try:
-                    caps = self._capabilities()
-                    if heartbeat:
-                        caps["cancel_only"] = 1
-                    jobs = await self.hive.ask_for_work(caps)
-                    self._last_poll_monotonic = time.monotonic()
-                    _LAST_POLL.set(time.time())
-                    # a gang-scheduling hive groups same-key jobs in one
-                    # reply and marks them with trace.gang; same-gang
-                    # jobs enter the BatchScheduler as ONE pre-formed
-                    # group (immediate flush, no linger — the hive
-                    # already did the waiting). Everything else takes
-                    # the classic per-job put() path.
-                    gangs: dict[str, list[dict]] = {}
-                    intake: list[tuple[str, object]] = []
-                    for job in jobs:
-                        print(f"Got job {job['id']}")
-                        _JOBS_POLLED.inc()
-                        # queue_wait stage starts here; the slice worker
-                        # pops the stamp when it picks the job up
-                        job["_telemetry_enqueued"] = time.monotonic()
-                        # hive-stamped trace context (hive_server wire
-                        # contract): note the receipt instant so the
-                        # settled timeline can place the worker handoff;
-                        # a legacy hive sends none and nothing is added
-                        gang_id = None
-                        if isinstance(job.get("trace"), dict):
-                            job["trace"].setdefault(
-                                "received_wall", round(time.time(), 3))
-                            gang = job["trace"].get("gang")
-                            if isinstance(gang, dict) and gang.get("id"):
-                                gang_id = str(gang["id"])
-                        # stage-jobs (ISSUE 20): hydrate the predecessor
-                        # handoff artifacts through the authed client,
-                        # then route host stages to the stage lane — they
-                        # never touch the batcher or claim a chip slice
-                        if isinstance(job.get("stage"), dict):
-                            await self._resolve_stage_inputs(job)
-                        if self._is_host_stage(job):
-                            intake.append(("stage", job))
-                        elif gang_id is None:
-                            intake.append(("job", job))
-                        else:
-                            if gang_id not in gangs:
-                                intake.append(("gang", gang_id))
-                            gangs.setdefault(gang_id, []).append(job)
-                    for kind, item in intake:
-                        if kind == "gang":
-                            await self.batcher.put_gang(gangs[item])
-                        elif kind == "stage":
-                            self._stage_queued_ids.add(str(item.get("id")))
-                            self._stage_queue.put_nowait(item)
-                        else:
-                            await self.batcher.put(item)
-                    # lease revocations piggybacked on this reply: route
-                    # each to wherever the job currently lives (batcher
-                    # -> dropped outright; executing slice -> cancel
-                    # token probed at the next denoise chunk boundary)
-                    for job_id in self.hive.last_cancels:
-                        self._cancel_job(job_id)
-                    sleep_seconds = POLL_SECONDS
-                except asyncio.TimeoutError:
-                    # a timeout IS a poll failure: back off like one (the
-                    # round-6 branch forgot, re-polling a struggling hive
-                    # at the full cadence)
-                    logger.warning("hive poll timeout")
-                    _POLL_ERRORS.inc()
-                    sleep_seconds = _next_backoff(sleep_seconds)
-                except Exception as e:
-                    logger.exception("ask_for_work error")
-                    print(f"ask_for_work error {e}")
-                    _POLL_ERRORS.inc()
-                    sleep_seconds = _next_backoff(sleep_seconds)
+            sleep_seconds = await self._poll_once(sleep_seconds)
             self._poll_backoff_s = sleep_seconds
             self._update_queue_gauges()
+            asked = time.monotonic()
             await asyncio.sleep(sleep_seconds)
+            _POLL_OVERSHOOT.inc(
+                max(time.monotonic() - asked - sleep_seconds, 0.0))
+
+    async def _poll_once(self, sleep_seconds: float) -> float:
+        """One tick of the poll: ask the hive if there is reason to, feed
+        what comes to the batcher; returns the seconds to sleep next."""
+        self._note_capacity()
+        can_take = self._able_since is not None
+        # cancel-only heartbeat (ISSUE 10): a worker whose every
+        # slice is busy used to go silent for the whole denoise —
+        # exactly the window in which a cancel matters most. It now
+        # keeps polling with `cancel_only=1`: the hive skips dispatch
+        # (and a legacy hive that hands jobs anyway just feeds the
+        # batcher early), keeps the worker live in its directory,
+        # and piggybacks lease revocations for the executing slices.
+        heartbeat = (not can_take and not self._draining.is_set()
+                     and self.batcher.outstanding_jobs > 0)
+        if not (can_take or heartbeat):
+            return sleep_seconds
+        try:
+            caps = self._capabilities()
+            if heartbeat:
+                caps["cancel_only"] = 1
+            sent = time.time()
+            # span "tick_wait": the worker could have asked and was
+            # asleep, from the later of its last poll's end and the
+            # instant it became able to take work to this request (what
+            # the loop's sleep overshot included); stamped once the
+            # poll has brought jobs, on them
+            idle_from = (sent if self._able_since is None
+                         else min(max(self._polled_at, self._able_since),
+                                  sent))
+            jobs = await self.hive.ask_for_work(caps)
+            received = self._polled_at = time.time()
+            self._last_poll_monotonic = time.monotonic()
+            _LAST_POLL.set(received)
+            # span "poll": request sent -> reply parsed, the hive's
+            # dispatch and gang-forming inside; it ran across an await, so
+            # it is recorded and opens no annotation. Every poll lands in
+            # the stage histogram, one that brought jobs in their
+            # envelopes too
+            brought: list[dict] = []
+            Span("poll", thread="poll",
+                 spans=brought if jobs else None).record(
+                     sent, received - sent)
+            if jobs:
+                Span("tick_wait", thread="wait", spans=brought).record(
+                    idle_from, sent - idle_from)
+            # a gang-scheduling hive groups same-key jobs in one
+            # reply and marks them with trace.gang; same-gang
+            # jobs enter the BatchScheduler as ONE pre-formed
+            # group (immediate flush, no linger — the hive
+            # already did the waiting). Everything else takes
+            # the classic per-job put() path.
+            gangs: dict[str, list[dict]] = {}
+            intake: list[tuple[str, object]] = []
+            for job in jobs:
+                print(f"Got job {job['id']}")
+                _JOBS_POLLED.inc()
+                # queue_wait starts where the poll ended; the slice
+                # worker takes the stamp and the spans off the job when
+                # it picks it up
+                job[ARRIVED], job[SPANS] = received, list(brought)
+                gang_id = None
+                if isinstance(job.get("trace"), dict):
+                    gang = job["trace"].get("gang")
+                    if isinstance(gang, dict) and gang.get("id"):
+                        gang_id = str(gang["id"])
+                # stage-jobs (ISSUE 20): hydrate the predecessor
+                # handoff artifacts through the authed client,
+                # then route host stages to the stage lane — they
+                # never touch the batcher or claim a chip slice
+                if isinstance(job.get("stage"), dict):
+                    await self._resolve_stage_inputs(job)
+                if self._is_host_stage(job):
+                    intake.append(("stage", job))
+                elif gang_id is None:
+                    intake.append(("job", job))
+                else:
+                    if gang_id not in gangs:
+                        intake.append(("gang", gang_id))
+                    gangs.setdefault(gang_id, []).append(job)
+            for kind, item in intake:
+                if kind == "gang":
+                    await self.batcher.put_gang(gangs[item])
+                elif kind == "stage":
+                    self._stage_queued_ids.add(str(item.get("id")))
+                    self._stage_queue.put_nowait(item)
+                else:
+                    await self.batcher.put(item)
+            # lease revocations piggybacked on this reply: route
+            # each to wherever the job currently lives (batcher
+            # -> dropped outright; executing slice -> cancel
+            # token probed at the next denoise chunk boundary)
+            for job_id in self.hive.last_cancels:
+                self._cancel_job(job_id)
+            return POLL_SECONDS
+        except asyncio.TimeoutError:
+            # a timeout IS a poll failure: back off like one (the
+            # round-6 branch forgot, re-polling a struggling hive
+            # at the full cadence)
+            logger.warning("hive poll timeout")
+            _POLL_ERRORS.inc()
+        except Exception as e:
+            logger.exception("ask_for_work error")
+            print(f"ask_for_work error {e}")
+            _POLL_ERRORS.inc()
+        return _next_backoff(sleep_seconds)
 
     def _cancel_job(self, job_id: str) -> None:
         """Route one hive-revoked job id. Held (lingering / on the
@@ -905,6 +938,7 @@ class Worker:
         else:
             stage = "unknown"
         _JOBS_CANCELLED.inc(stage=stage)
+        self._note_capacity()
         self._update_queue_gauges()
 
     # --- host-path stage lane (ISSUE 20) ---
@@ -952,7 +986,7 @@ class Worker:
         of pass N+1 on the chip slices."""
         while True:
             job = await self._stage_queue.get()
-            picked_up = time.monotonic()
+            picked_up = time.time()
             job_id = str(job.get("id"))
             self._stage_queued_ids.discard(job_id)
             if job_id in self._stage_cancelled:
@@ -961,12 +995,11 @@ class Worker:
                 continue
             self._stage_inflight += 1
             self._executing_ids.add(job_id)
-            enqueued = job.pop("_telemetry_enqueued", None)
+            queue_wait = {}
+            self._picked_up(job, picked_up, queue_wait)
             trace = job.pop("trace", None)
             job.pop("resume", None)
             stage_name = str((job.get("stage") or {}).get("name", ""))
-            queue_wait = ({job.get("id"): _waited(enqueued, picked_up)}
-                          if enqueued is not None else {})
             traces = ({job.get("id"): trace}
                       if isinstance(trace, dict) else {})
             self._update_queue_gauges()
@@ -1022,15 +1055,16 @@ class Worker:
             # the warm slice, stealing by an idle one when the warm slice
             # is busy — and the chipset arrives already acquired
             batch, chipset, outcome = await self.batcher.claim(self.allocator)
+            self._note_capacity()
             await self._wait_for_packaging(chipset)
             # queue_wait: hive handoff -> a slice actually starting the work
-            picked_up = time.monotonic()
+            picked_up = time.time()
             # whole-pass slice occupancy feeds the "pass" stage EWMA for
             # the hive's straggler detector: unlike the envelope's
             # job_s, this wall clock covers EVERYTHING that holds the
             # slice (arg formatting, a wedged busy lock, an injected
             # hang) — exactly the time a silently sick slice inflates
-            pass_started = picked_up
+            pass_started = time.monotonic()
             queue_wait = {}
             traces = {}
             resume_offers = {}
@@ -1050,9 +1084,7 @@ class Worker:
             batch_cap = max(caps) if caps and all(
                 c > 0 for c in caps) else None
             for job in batch:
-                enqueued = job.pop("_telemetry_enqueued", None)
-                if enqueued is not None and "id" in job:
-                    queue_wait[job["id"]] = _waited(enqueued, picked_up)
+                self._picked_up(job, picked_up, queue_wait)
                 # hive trace context comes OFF the job before formatting
                 # and rides the envelope back (pipeline_config.trace) so
                 # the hive attaches this worker's stage spans to the
@@ -1070,6 +1102,9 @@ class Worker:
             # the jobs no pass has ended for yet, by id
             waiting = {str(job.get("id")): job for job in batch}
             delivery = (queue_wait, outcome, traces)
+            # span "format_args" starts here for the pass's jobs, and for
+            # a later solo of the batch where the solo before it ended
+            since = picked_up
             try:
                 prepared = []
                 for job in batch:
@@ -1084,7 +1119,7 @@ class Worker:
                     self._pass_ended(
                         chipset,
                         [waiting.pop(str(kw.get("id"))) for _, kw in prepared],
-                        results, *delivery)
+                        results, since, *delivery)
                 else:
                     for worker_function, kwargs in prepared:
                         # read now: the executor thread pops it
@@ -1111,8 +1146,9 @@ class Worker:
                             chipset, worker_function, kwargs, solo_cap
                         )
                         self._pass_ended(
-                            chipset, [waiting.pop(job_id)], [result],
+                            chipset, [waiting.pop(job_id)], [result], since,
                             *delivery)
+                        since = time.time()
             except Exception as e:
                 logger.exception("slice_worker error")
                 print(f"slice_worker {e}")
@@ -1132,6 +1168,7 @@ class Worker:
                     # the same id must start with a clean slate
                     self._executing_ids.discard(job_id)
                     cancel_mod.discard(job_id)
+                self._note_capacity()
                 self._update_queue_gauges()
 
     # --- packaging off the slice's critical path ---
@@ -1139,29 +1176,61 @@ class Worker:
     async def _wait_for_packaging(self, chipset) -> None:
         """The one bound on what waits to be packaged, a rule and no
         setting: a slice does not start a pass while two earlier passes
-        of its own are still undelivered; it waits for the older one."""
+        of its own are still undelivered; it waits for the older one
+        (span `package_wait`, stamped at pick-up)."""
         pending = self._deliveries.get(chipset.slice_id, ())
-        held_from = time.monotonic() if len(pending) >= 2 else None
         while len(pending) >= 2:
             await asyncio.wait({pending[0]})
-        _PACKAGE_BACKPRESSURE.inc(
-            0.0 if held_from is None else time.monotonic() - held_from,
-            slice=str(chipset.slice_id))
+
+    def _picked_up(self, job: dict, picked_up: float,
+                   queue_wait: dict) -> None:
+        """Take what the poll and the batcher stamped off a job a slice
+        (or the stage lane) has just picked up, and close its wait: span
+        `package_wait` from the end of `claim` (the wait for the slice's
+        earlier passes to be delivered; none on the stage lane, which has
+        no claim), and `queue_wait` around `linger`, `claim` and it. Kept
+        by job id for the envelope (`_finish_result`), with the timings
+        the wait adds to it."""
+        arrived, spans = job.pop(ARRIVED, None), job.pop(SPANS, [])
+        if arrived is None or "id" not in job:
+            return
+        claimed = [_span_end(s) for s in spans if s["name"] == "claim"]
+        if claimed:
+            Span("package_wait", thread="wait", spans=spans).record(
+                claimed[-1], picked_up - claimed[-1])
+        timings: dict = {}
+        # span "queue_wait": poll reply -> slice pick-up; it ran on no
+        # thread, and the pass's trace closes with the pass, so it joins
+        # the envelope's spans directly
+        Span("queue_wait", timings, thread="wait", spans=spans).record(
+            arrived, picked_up - arrived)
+        queue_wait[job["id"]] = (spans, timings)
 
     def _pass_ended(self, chipset, jobs: list[dict],
-                    results: list[dict | None], queue_wait: dict,
-                    placement: str, traces: dict) -> None:
+                    results: list[dict | None], since: float,
+                    queue_wait: dict, placement: str, traces: dict) -> None:
         """One executor call has returned with `results` for `jobs`
         (None: a cancelled member, no envelope exists and none is
         delivered — the hive tombstoned the job, batchmates unharmed).
         The jobs want no slice any more; they stay outstanding until a
         task of the pass's own has packaged, finished and spooled their
-        envelopes, behind the slice's earlier passes."""
+        envelopes, behind the slice's earlier passes. `since` is where
+        the jobs' arguments began to be formatted."""
+        ended = time.time()
         for job in jobs:
             # pass the job so the row accounting (advertised
             # queue_depth) subtracts its true image count
             self.batcher.pass_done(job)
         results = [result for result in results if result is not None]
+        for result in results:
+            waited = queue_wait.get(result.get("id"))
+            held = _pass_span(result)
+            if waited is not None and held is not None:
+                # span "format_args": pick-up -> the `pass` span opens
+                # (the arguments formatted, the executor's pick-up, the
+                # busy lock taken and the job's key drawn)
+                Span("format_args", thread="wait", spans=waited[0]).record(
+                    since, max(held["start_wall"] - since, 0.0))
         if results:
             # ONE pass = one stats sample; a coalesced pass's envelopes
             # all carry the same copied timings
@@ -1169,19 +1238,20 @@ class Worker:
                 results[0]["pipeline_config"].get("timings") or {})
         pending = self._deliveries.setdefault(chipset.slice_id, [])
         task = asyncio.create_task(self._deliver_pass(
-            chipset, results, len(jobs), queue_wait, placement, traces,
+            results, len(jobs), ended, queue_wait, placement, traces,
             after=pending[-1] if pending else None))
         pending.append(task)
         task.add_done_callback(pending.remove)
 
-    async def _deliver_pass(self, chipset, results: list[dict], n_jobs: int,
-                            queue_wait: dict, placement: str, traces: dict,
-                            after: asyncio.Task | None) -> None:
+    async def _deliver_pass(self, results: list[dict], n_jobs: int,
+                            ended: float, queue_wait: dict, placement: str,
+                            traces: dict, after: asyncio.Task | None) -> None:
         """Package one pass's images on a host thread, then finish and
         enqueue its envelopes together and in order: a gang's clients
         see their jobs settle together (two that resubmit at once are
         one gang again; envelopes spaced by an encode would split them
-        over polls)."""
+        over polls). `ended`: when the worker's loop learned the pass was
+        over, where `handoff` starts for a result without a `pass` span."""
         from .workflows.diffusion import Unpackaged
 
         try:
@@ -1190,7 +1260,10 @@ class Worker:
             if any(isinstance(result["artifacts"], Unpackaged)
                    for result in results):
                 results = await asyncio.get_running_loop().run_in_executor(
-                    None, self._package_pass, chipset, results)
+                    None, self._package_pass, results, ended)
+            else:
+                for result in results:
+                    _stamp_handoff(result, ended)
             for result in results:
                 self._finish_result(result, queue_wait, placement, traces)
             await self._enqueue_result(*results)
@@ -1202,20 +1275,19 @@ class Worker:
                 self.batcher.job_delivered()
             self._update_queue_gauges()
 
-    def _package_pass(self, chipset, results: list[dict]) -> list[dict]:
+    def _package_pass(self, results: list[dict], ended: float) -> list[dict]:
         """On a host thread: the `artifacts` of every result that left
         its pass `Unpackaged`, in order. A failure is that job's own
         error envelope; its batchmates are delivered and nothing is
         denoised again."""
         from .workflows.diffusion import Unpackaged
 
-        label = str(chipset.slice_id)
         packaged = []
         for result in results:
+            _stamp_handoff(result, ended)
             unpackaged = result["artifacts"]
             if isinstance(unpackaged, Unpackaged):
                 spans = result["pipeline_config"].setdefault("spans", [])
-                started, held = time.monotonic(), chipset.held_seconds()
                 try:
                     result["artifacts"] = unpackaged.package(spans)
                 except Exception as e:
@@ -1223,13 +1295,6 @@ class Worker:
                         "packaging job %s failed", result["id"])
                     result = _error_envelope(
                         e, result["id"], unpackaged.content_type, spans)
-                seconds = time.monotonic() - started
-                # the job's own pass let the lock go before this began
-                overlapped = min(chipset.held_seconds() - held, seconds)
-                _PACKAGE_SECONDS.inc(
-                    overlapped, slice=label, overlapped="yes")
-                _PACKAGE_SECONDS.inc(
-                    seconds - overlapped, slice=label, overlapped="no")
             packaged.append(result)
         return packaged
 
@@ -1246,17 +1311,18 @@ class Worker:
             cfg["placement"] = placement
         trace = (traces or {}).get(result.get("id"))
         if isinstance(trace, dict):
-            # echo the hive's trace context (attempt, dispatch instant,
-            # plus our receipt instant) back through the envelope
+            # echo the hive's trace context (attempt, dispatch instant)
+            # back through the envelope
             cfg["trace"] = trace
         timings = cfg.setdefault("timings", {})
         waited = queue_wait.get(result.get("id"))
         if waited is not None:
-            # span "queue_wait": poll reply -> slice pick-up, linger
-            # included; it ran on no thread, and the pass's trace closed
-            # with the pass, so it joins the envelope's spans directly
-            Span("queue_wait", timings, thread="wait",
-                 spans=cfg.setdefault("spans", [])).record(*waited)
+            # what the job's life held before its pass (`_picked_up`,
+            # `_pass_ended`): tick_wait, poll, linger, claim, package_wait,
+            # queue_wait, format_args
+            spans, waits = waited
+            cfg.setdefault("spans", []).extend(spans)
+            timings.update(waits)
         if result.get("fatal_error"):
             outcome = "fatal"
         elif "error" in cfg:
@@ -1948,10 +2014,27 @@ def _error_envelope(e: Exception, job_id, content_type: str,
     }
 
 
-def _waited(enqueued: float, picked_up: float) -> tuple[float, float]:
-    """(wall instant a job was enqueued, seconds until `picked_up`) from
-    the two monotonic stamps — what the `queue_wait` span records."""
-    return time.time() - (time.monotonic() - enqueued), picked_up - enqueued
+def _span_end(span: dict) -> float:
+    return span["start_wall"] + span["seconds"]
+
+
+def _pass_span(result: dict) -> dict | None:
+    """The `pass` span of a result's envelope (the slice held for it)."""
+    spans = (result.get("pipeline_config") or {}).get("spans") or ()
+    return next((s for s in spans if s["name"] == "pass"), None)
+
+
+def _stamp_handoff(result: dict, ended: float) -> None:
+    """Span "handoff", a job's own: from its `pass` span's end (`ended`
+    where it has none) to now, which is its turn on the packaging thread
+    (behind the slice's earlier passes, the executor's pick-up and its
+    batchmates' encodes) or, with nothing to package, its envelope being
+    finished. It ran across threads, so it is recorded."""
+    held = _pass_span(result)
+    start = ended if held is None else _span_end(held)
+    Span("handoff", thread="deliver", spans=result.setdefault(
+        "pipeline_config", {}).setdefault("spans", [])).record(
+            start, max(time.time() - start, 0.0))
 
 
 async def run_worker() -> None:

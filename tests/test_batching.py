@@ -673,11 +673,12 @@ def test_unbatchable_interactive_job_also_preempts():
     run(scenario())
 
 
-def test_flush_stamps_linger_split_into_trace_context():
-    """ISSUE 8: a coalesced job's trace context gains the linger split
-    (lingered_s + coalesced_with), so the end-to-end timeline can tell
-    waiting-for-batchmates apart from waiting-for-a-slice; jobs without
-    a hive trace context (legacy hives) are untouched."""
+def test_flush_stamps_linger_and_claim_spans_on_each_job():
+    """ISSUE 8, ISSUE 38: waiting for batchmates and waiting for a slice
+    are told apart by two spans the batcher stamps on every job of a
+    group (legacy hive or not): `linger` from the job's own arrival to
+    the group's release, `claim` from there to the claim, end to start;
+    the trace context keeps `coalesced_with` and nothing else of it."""
     import asyncio
 
     from chiaswarm_tpu.batching import BatchScheduler
@@ -692,15 +693,35 @@ def test_flush_stamps_linger_split_into_trace_context():
         return job
 
     async def scenario():
+        from chiaswarm_tpu import telemetry
+        from chiaswarm_tpu.batching import ARRIVED, SPANS
+
+        stages = telemetry.REGISTRY.histogram(
+            telemetry.STAGE_METRIC, labelnames=("stage",))
+        before = stages.count(stage="linger")
         sched = BatchScheduler(linger_s=10.0, max_coalesce=2)
         await sched.put(tiny("t-1"))
+        await asyncio.sleep(0.05)
         await sched.put(tiny("t-2", with_trace=False))  # size flush at 2
+        await asyncio.sleep(0.02)
         group = await sched.get()
         assert [j["id"] for j in group] == ["t-1", "t-2"]
         trace = group[0]["trace"]
-        assert trace["lingered_s"] >= 0.0
-        assert trace["coalesced_with"] == 1
+        assert trace["coalesced_with"] == 1 and "lingered_s" not in trace
         assert "trace" not in group[1]
+        first, second = ({s["name"]: s for s in j[SPANS]} for j in group)
+        for found, job in ((first, group[0]), (second, group[1])):
+            assert list(found) == ["linger", "claim"]
+            assert found["linger"]["start_wall"] == job[ARRIVED]
+            assert found["claim"]["start_wall"] == pytest.approx(
+                found["linger"]["start_wall"] + found["linger"]["seconds"],
+                abs=1e-6)
+            assert found["claim"]["seconds"] >= 0.02
+        # the first job waited for its batchmate, the second for nobody
+        assert first["linger"]["seconds"] >= 0.05
+        assert second["linger"]["seconds"] < 0.02
+        assert first["claim"] == second["claim"]  # the group's, shared
+        assert stages.count(stage="linger") == before + 2
 
     asyncio.run(scenario())
 
@@ -736,8 +757,11 @@ def test_linger_grows_with_the_keys_pass_not_with_its_compile(monkeypatch):
     import types
 
     clock = [1000.0]
+    import time
+
+    # the scheduler's clock only (its spans' wall stamps stay real)
     monkeypatch.setattr(batching, "time", types.SimpleNamespace(
-        monotonic=lambda: clock[0]))  # the scheduler's clock only
+        monotonic=lambda: clock[0], time=time.time))
     run(scenario(clock))
 
 

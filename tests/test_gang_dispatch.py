@@ -376,9 +376,20 @@ def test_put_gang_flushes_immediately_with_gang_reason():
         group = await asyncio.wait_for(b.get(), 1.0)
         assert [j["id"] for j in group] == ["g0", "g1", "g2"]
         assert _FLUSHES.value(reason="gang") == before + 1
-        # trace carries the no-linger attribution
-        assert all(j["trace"]["lingered_s"] == 0.0 for j in group)
+        # the no-linger attribution: one `linger` span the members share
+        # (nobody waited for batchmates), then one `claim` span
+        from chiaswarm_tpu.batching import SPANS
+
+        for name in ("linger", "claim"):
+            found = [[s for s in j[SPANS] if s["name"] == name]
+                     for j in group]
+            assert all(len(spans) == 1 for spans in found)
+            assert len({(s["start_wall"], s["seconds"])
+                        for [s] in found}) == 1
+            assert found[0][0]["thread"] == "wait"
+        assert all(s["seconds"] < 0.01 for j in group for s in j[SPANS])
         assert all(j["trace"]["coalesced_with"] == 2 for j in group)
+        assert all("lingered_s" not in j["trace"] for j in group)
 
     run(scenario())
 

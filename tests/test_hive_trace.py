@@ -70,15 +70,15 @@ def _echo(job_id: str, **extra) -> dict:
 
 
 def _envelope(job, timings=None) -> dict:
-    """A worker-shaped result envelope: stage timings + the echoed wire
-    trace context, exactly what Worker._finish_result produces."""
-    trace = dict(job.get("trace") or {})
-    trace.setdefault("received_wall", 0.0)
+    """A worker-shaped result envelope: stage timings + the wire trace
+    context echoed as it came, what Worker._finish_result produces (the
+    worker's receipt instant is the end of its `poll` span, not a key of
+    the context)."""
     return {
         "id": job["id"], "artifacts": {}, "nsfw": False,
         "worker_name": "trace-w",
         "pipeline_config": {
-            "trace": trace,
+            "trace": dict(job.get("trace") or {}),
             "timings": timings or {"queue_wait_s": 0.01,
                                    "denoise_s": 0.2, "decode_s": 0.05},
         },
@@ -126,6 +126,11 @@ def test_settled_job_answers_complete_ordered_timeline(sdaas_root):
             settle = trace["events"][-1]
             assert settle["worker"] == "trace-w"
             assert settle["attempt"] == 1
+            # the handler's entry, before the body was read: what follows
+            # it up to the settle stamp is the hive's own work
+            lease = trace["events"][2]
+            assert lease["wall"] <= settle["received_wall"] <= settle["wall"]
+            assert trace["worker"]["trace"] == job["trace"]
             # every inter-event gap is attributed; the executing gap
             # carries the worker's stage breakdown + honest remainder
             assert [g["attribution"] for g in trace["gaps"]] == \
@@ -214,6 +219,55 @@ def test_spans_carve_the_executing_gap_and_leave_the_hole_visible():
     assert executing["unattributed_s"] == pytest.approx(0.7)
     assert trace["worker"]["total_s"] == executing["worker_total_s"]
     assert trace_missing(trace) == []
+
+
+def test_spans_are_clipped_to_the_gap_and_the_rest_is_split_at_receipt():
+    """ISSUE 38: a worker's `tick_wait` and `poll` begin before the
+    dispatch they brought. Unclipped they would push `worker_total_s`
+    past the executing gap and `unattributed_s` to a clamped 0; clipped,
+    what the spans leave is real, and the `settle` event's
+    `received_wall` parts it into the wire's and the hive's."""
+    from chiaswarm_tpu.hive_server.trace import build_trace
+
+    t = 1_000_000.0
+    config = {"spans": [
+        _span("tick_wait", t - 0.09, 0.05, thread="wait"),  # all before
+        _span("poll", t - 0.04, 0.06, thread="poll"),  # dispatch inside
+        _span("queue_wait", t + 0.02, 0.08, thread="wait"),
+        _span("linger", t + 0.02, 0.05, thread="wait"),  # its detail
+        _span("claim", t + 0.07, 0.03, thread="wait"),
+        _span("format_args", t + 0.1, 0.1, thread="wait"),
+        _span("denoise", t + 0.2, 2.0),
+        _span("pass", t + 0.2, 2.0),
+        _span("handoff", t + 2.2, 0.1, thread="deliver"),
+        _span("artifact_encode", t + 2.3, 0.2, thread="host"),
+    ]}
+    record = _settled_record(config, t, t + 3.0)
+    record.timeline[-1]["received_wall"] = t + 2.9
+    trace = build_trace(record, t + 4.0)
+    executing = trace["gaps"][-1]
+    stages = executing["worker_stages"]
+    assert [s["stage"] for s in stages] == [
+        "poll", "queue_wait", "format_args", "denoise", "handoff",
+        "artifact_encode"]
+    # `poll` keeps what lies after the lease, 0.02 of its 0.06 s
+    assert stages[0]["start_wall"] == t
+    assert stages[0]["seconds"] == pytest.approx(0.02)
+    assert executing["worker_total_s"] == pytest.approx(2.5)
+    assert executing["unattributed_s"] == pytest.approx(0.5)
+    assert executing["unattributed_wire_s"] == pytest.approx(0.4)
+    assert executing["unattributed_hive_s"] == pytest.approx(0.1)
+    # the operator's list is unclipped: every span on the one clock
+    assert [s["stage"] for s in trace["worker"]["stages"]][:2] == [
+        "tick_wait", "poll"]
+    assert trace["worker"]["stages"][1]["seconds"] == 0.06
+    assert trace["worker"]["total_s"] == pytest.approx(2.59)
+
+    # a settle stamp without the receipt (an older hive's WAL): no split
+    del record.timeline[-1]["received_wall"]
+    executing = build_trace(record, t + 4.0)["gaps"][-1]
+    assert executing["unattributed_s"] == pytest.approx(0.5)
+    assert "unattributed_wire_s" not in executing
 
 
 def test_an_old_envelope_without_spans_still_builds():

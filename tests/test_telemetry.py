@@ -267,6 +267,45 @@ def test_span_needs_no_jax(monkeypatch):
     assert opened == ["swarm/denoise"]
 
 
+def test_a_span_recorded_across_an_await_opens_no_annotation(monkeypatch):
+    """A stage that runs across an `await` on the event loop (the poll's
+    round trip, a job's waits) is stamped with `record()` once it is
+    over: histogram and envelope as any span, and no annotation, which
+    opened on the loop's thread would nest wrongly across coroutines."""
+    import jax
+
+    from chiaswarm_tpu import telemetry
+
+    opened = []
+
+    class Recorder(jax.profiler.TraceAnnotation):
+        def __init__(self, name):
+            opened.append(name)
+            super().__init__(name)
+
+    monkeypatch.setattr(telemetry, "_TraceAnnotation", Recorder)
+    reg = Registry()
+    envelope = []
+
+    async def other():  # runs on the loop inside the stage
+        with Span("other", registry=reg):
+            await asyncio.sleep(0)
+
+    async def stage():
+        sent = time.time()
+        await asyncio.gather(asyncio.sleep(0.01), other())
+        span = Span("poll", registry=reg, thread="poll", spans=envelope)
+        span.record(sent, time.time() - sent)
+        return sent, span
+
+    sent, span = asyncio.run(stage())
+    assert opened == ["swarm/other"]
+    assert reg.get(STAGE_METRIC).count(stage="poll") == 1
+    assert reg.get(STAGE_METRIC).sum(stage="poll") == span.elapsed >= 0.01
+    assert envelope == [{"name": "poll", "thread": "poll",
+                         "start_wall": sent, "seconds": span.elapsed}]
+
+
 def test_slice_free_seconds_counts_between_passes():
     import jax
 

@@ -1002,17 +1002,25 @@ def test_compatible_img2img_jobs_coalesce_into_one_batch(sdaas_root):
 def test_envelope_echoes_hive_trace_context(sdaas_root):
     """ISSUE 8: the /work reply's trace context (stamped by the hive;
     the fake stamps the same field set, pinned by the conformance
-    suite) rides back inside pipeline_config.trace — with the worker's
-    receipt instant added — so the hive can merge this worker's stage
-    spans into the job's timeline at the right dispatch attempt."""
+    suite) rides back inside pipeline_config.trace, as it came, so the
+    hive can merge this worker's stage spans into the job's timeline at
+    the right dispatch attempt; the worker's receipt instant is the end
+    of the `poll` span that brought the job (ISSUE 38), on the same
+    wall clock, and `queue_wait` starts there."""
     hive, results = run_jobs([echo_job("traced-1")], sdaas_root)
     [result] = results
     trace = result["pipeline_config"]["trace"]
     assert trace["id"] == "traced-1"
     assert trace["attempt"] == 1
     assert isinstance(trace["dispatched_wall"], float)
-    assert isinstance(trace["received_wall"], float)
-    assert trace["received_wall"] >= trace["dispatched_wall"] - 1.0
+    assert "received_wall" not in trace and "lingered_s" not in trace
+    [poll] = spans_named(result, "poll")
+    assert poll["thread"] == "poll"
+    # the fake hive rounds its stamp to a millisecond
+    assert (poll["start_wall"] - 0.001 <= trace["dispatched_wall"]
+            <= span_end(poll) + 0.001)
+    [waited] = spans_named(result, "queue_wait")
+    assert waited["start_wall"] == pytest.approx(span_end(poll), abs=1e-6)
     # stage timings still ride next to it
     assert "queue_wait_s" in result["pipeline_config"]["timings"]
 
@@ -1079,9 +1087,6 @@ def two_gangs_slow_packaging(tmp_path_factory):
         time.sleep(0.4)
         return real(images, outputs, content_type)
 
-    def overlapped():
-        return worker_mod._PACKAGE_SECONDS.value(slice="0", overlapped="yes")
-
     async def scenario():
         hive = await FakeHive().start()
         hive.gang_max = 2
@@ -1109,21 +1114,20 @@ def two_gangs_slow_packaging(tmp_path_factory):
 
     with scenario_root(tmp_path_factory) as patch:
         patch.setattr(diffusion, "_package", slow)
-        before = overlapped()
         results = asyncio.run(scenario())
-        moved = overlapped() - before
     by_id = {result["id"]: result for result in results}
     settled_at = {result["id"]: at for result, at in zip(results, settled)}
     return types.SimpleNamespace(
         results=[by_id[f"gang-{i}"] for i in range(4)], calls=calls,
-        arrivals=arrivals, overlapped_s=moved, package=real,
+        arrivals=arrivals, package=real,
         settled_at=[settled_at[f"gang-{i}"] for i in range(4)])
 
 
 def test_next_pass_runs_while_the_last_is_packaged(two_gangs_slow_packaging):
     """(a) The slice is let go before its pass's images are packaged: the
     second gang's `pass` span starts before the first gang's last
-    `artifact_encode` span ends, and the counter says so."""
+    `artifact_encode` span ends; how much of the packaging ran under the
+    later pass is the overlap of the two kinds of span, on one clock."""
     first, second = (two_gangs_slow_packaging.results[:2],
                      two_gangs_slow_packaging.results[2:])
     for gang in (first, second):
@@ -1137,7 +1141,11 @@ def test_next_pass_runs_while_the_last_is_packaged(two_gangs_slow_packaging):
     assert next_pass["start_wall"] < max(map(span_end, encodes))
     [first_pass] = spans_named(first[0], "pass")
     assert span_end(first_pass) <= next_pass["start_wall"]
-    assert two_gangs_slow_packaging.overlapped_s > 0
+    overlapped_s = sum(
+        max(min(span_end(span), span_end(next_pass))
+            - max(span["start_wall"], next_pass["start_wall"]), 0.0)
+        for span in encodes)
+    assert 0 < overlapped_s <= sum(span["seconds"] for span in encodes)
 
 
 def test_artifacts_are_what_package_gives_for_the_same_images(
@@ -1271,11 +1279,15 @@ def test_a_failed_encode_is_that_jobs_own_envelope(
 def test_a_slice_waits_while_two_of_its_passes_are_undelivered(sdaas_root):
     """(f) The one bound on what waits, a rule and no setting: with two
     earlier passes of a slice undelivered, its third pass does not start
-    until the older one is; the wait is counted."""
+    until the older one is; the wait is the third job's `package_wait`
+    span, in the stage histogram and in its envelope."""
+    from chiaswarm_tpu import telemetry
     from chiaswarm_tpu.chips.device import _EXECUTE_SECONDS
 
     def held_s():
-        return worker_mod._PACKAGE_BACKPRESSURE.value(slice="0")
+        return telemetry.REGISTRY.histogram(
+            telemetry.STAGE_METRIC, labelnames=("stage",)).sum(
+                stage="package_wait")
 
     async def scenario():
         hive = await FakeHive().start()
@@ -1302,7 +1314,9 @@ def test_a_slice_waits_while_two_of_its_passes_are_undelivered(sdaas_root):
                 await asyncio.sleep(0.05)
             await asyncio.sleep(0.5)  # room for a third pass to start
             assert _EXECUTE_SECONDS.count(kind="solo") - passes_before == 2
-            assert hive.results == [] and held_s() == held_before
+            # the two passes that ran waited microseconds; the third's
+            # wait is stamped when it ends
+            assert hive.results == [] and held_s() - held_before < 0.05
             assert w._health()["jobs_in_flight"] == 3
             gate.set()
             results = await hive.wait_for_results(3, timeout=60.0)
@@ -1310,6 +1324,13 @@ def test_a_slice_waits_while_two_of_its_passes_are_undelivered(sdaas_root):
                 "job-q0", "job-q1", "job-q2"]
             assert _EXECUTE_SECONDS.count(kind="solo") - passes_before == 3
             assert held_s() - held_before >= 0.4
+            waits = [spans_named(r, "package_wait")[0] for r in results]
+            assert waits[0]["seconds"] < 0.05 and waits[1]["seconds"] < 0.05
+            assert waits[2]["seconds"] == pytest.approx(
+                held_s() - held_before, abs=0.05)
+            [claim] = spans_named(results[2], "claim")
+            assert waits[2]["start_wall"] == pytest.approx(
+                span_end(claim), abs=1e-6)
         finally:
             w.stop()
             await asyncio.wait_for(runner, 10)
