@@ -1,6 +1,8 @@
 """Worker runtime: poll the hive, fan jobs out to chip slices, upload results.
 
-Loop-shape parity with reference swarm/worker.py:38-196 — 11 s poll cadence,
+Loop-shape parity with reference swarm/worker.py:38-196 — 11 s poll cadence
+(the longest a worker with room goes without asking: it also asks the
+instant it becomes able to take work, `poll_loop`),
 121 s backoff on poll errors, bounded work queue, per-slice consumer tasks,
 a result-upload task, and the same error policy (transient exceptions become
 error-image artifacts and the job "succeeds"; ValueError/TypeError mark the
@@ -74,9 +76,10 @@ from .telemetry import Span, trace_job
 
 logger = logging.getLogger(__name__)
 
-# reference cadence is 11 s; the env knob exists for worker SUBPROCESSES
-# driven by the bench/e2e harness, which cannot monkeypatch the module
-# the way the in-process tests do
+# reference cadence is 11 s: the longest a worker that could take work goes
+# without asking, not the only time it asks (poll_loop); the env knob
+# exists for worker SUBPROCESSES driven by the bench/e2e harness, which
+# cannot monkeypatch the module the way the in-process tests do
 
 
 def _env_poll_seconds() -> float:
@@ -170,9 +173,19 @@ _RESUMES = telemetry.counter(
 )
 _POLL_OVERSHOOT = telemetry.counter(
     "swarm_poll_overshoot_seconds_total",
-    "Seconds the poll loop's sleeps lasted beyond what was asked: the lag "
-    "of the event loop that also carries the uploads, measured where it "
-    "delays a poll (per poll: swarm_job_stage_seconds_count{stage=\"poll\"})")
+    "Seconds the poll loop's timed-out waits lasted beyond what was asked "
+    "(a wait cut short by new capacity overshoots nothing): the lag of the "
+    "event loop that also carries the uploads, measured where it delays a "
+    "poll (per poll: swarm_job_stage_seconds_count{stage=\"poll\"})")
+_POLLS = telemetry.counter(
+    "swarm_polls_total",
+    "Hive /work polls sent, by what ended the wait before them (capacity = "
+    "the worker became able to take work: a slice released or reinstated, "
+    "the batcher no longer full, a held job cancelled; timer = the cadence "
+    "or an error back-off ran out with room for work; heartbeat = the "
+    "timer's cancel_only poll of a worker with none)",
+    ("cause",),
+)
 _JOBS_CANCELLED = telemetry.counter(
     "swarm_jobs_cancelled_total",
     "Hive-revoked jobs this worker dropped, by where the cancel caught "
@@ -246,6 +259,11 @@ class Worker:
         # `tick_wait` is reckoned from (_note_capacity, poll_loop)
         self._able_since: float | None = None
         self._polled_at = time.time()
+        # set where `_able_since` goes from None to an instant: what ends
+        # the poll loop's wait ahead of its timer (_wait_to_poll)
+        self._capacity = asyncio.Event()
+        # the last poll raised: its back-off is slept whole
+        self._poll_failed = False
         self.allocator.add_free_listener(self._note_capacity)
         self.result_queue: asyncio.Queue = asyncio.Queue()
         # durable result spool: envelopes land here BEFORE the first
@@ -789,29 +807,60 @@ class Worker:
     def _note_capacity(self) -> None:
         """Called wherever the answer to "could this worker take work"
         may have changed (a slice released or claimed, a batch done, a
-        held job cancelled, every tick of the poll): keeps the instant it
-        last became yes."""
+        held job cancelled, every tick of the poll), always on the event
+        loop: keeps the instant it last became yes, and on becoming yes
+        wakes the poll loop (once a transition, however many slices came
+        free with it; never while draining)."""
         if (self._draining.is_set() or self.batcher.full()
                 or not self.allocator.has_free_slice()):
             self._able_since = None
         elif self._able_since is None:
             self._able_since = time.time()
+            self._capacity.set()
 
     async def poll_loop(self) -> None:
-        sleep_seconds = POLL_SECONDS
+        sleep_seconds, cause = POLL_SECONDS, "timer"
         while True:
-            sleep_seconds = await self._poll_once(sleep_seconds)
+            sleep_seconds = await self._poll_once(sleep_seconds, cause)
             self._poll_backoff_s = sleep_seconds
             self._update_queue_gauges()
-            asked = time.monotonic()
-            await asyncio.sleep(sleep_seconds)
-            _POLL_OVERSHOOT.inc(
-                max(time.monotonic() - asked - sleep_seconds, 0.0))
+            cause = await self._wait_to_poll(sleep_seconds)
 
-    async def _poll_once(self, sleep_seconds: float) -> float:
-        """One tick of the poll: ask the hive if there is reason to, feed
-        what comes to the batcher; returns the seconds to sleep next."""
+    async def _wait_to_poll(self, seconds: float) -> str:
+        """The wait between two polls: over at the timer ("timer") or at
+        the instant the worker becomes able to take work ("capacity"),
+        whichever comes first, so the cadence is the longest a worker
+        with room goes without asking. A poll that brings nothing leaves
+        `_able_since` set, so no second wake-up follows it: an empty hive
+        sees one poll a capacity transition and then the timer. A poll
+        error's back-off is slept whole (the hive is struggling), and a
+        wake-up whose capacity is gone again by the time the loop runs
+        (the board had work for the slice) leaves the timer as it was,
+        which keeps a busy worker's heartbeat on its cadence."""
+        asked = time.monotonic()
+        if self._poll_failed:
+            await asyncio.sleep(seconds)
+        else:
+            while (left := asked + seconds - time.monotonic()) > 0:
+                try:
+                    await asyncio.wait_for(self._capacity.wait(), left)
+                except asyncio.TimeoutError:
+                    break
+                self._capacity.clear()
+                self._note_capacity()
+                if self._able_since is not None:
+                    return "capacity"
+        _POLL_OVERSHOOT.inc(max(time.monotonic() - asked - seconds, 0.0))
+        return "timer"
+
+    async def _poll_once(self, sleep_seconds: float,
+                         cause: str = "timer") -> float:
+        """One turn of the poll: ask the hive if there is reason to, feed
+        what comes to the batcher; returns the seconds to wait next.
+        `cause`: what ended the wait before it (`swarm_polls_total`)."""
         self._note_capacity()
+        # this poll answers every wake-up so far, its own included
+        self._capacity.clear()
         can_take = self._able_since is not None
         # cancel-only heartbeat (ISSUE 10): a worker whose every
         # slice is busy used to go silent for the whole denoise —
@@ -828,12 +877,14 @@ class Worker:
             caps = self._capabilities()
             if heartbeat:
                 caps["cancel_only"] = 1
+            _POLLS.inc(cause="heartbeat" if heartbeat else cause)
             sent = time.time()
-            # span "tick_wait": the worker could have asked and was
-            # asleep, from the later of its last poll's end and the
-            # instant it became able to take work to this request (what
-            # the loop's sleep overshot included); stamped once the
-            # poll has brought jobs, on them
+            # span "tick_wait": the worker could have asked and had not,
+            # from the later of its last poll's end and the instant it
+            # became able to take work to this request: the loop's
+            # wake-up latency where new capacity ended the wait, the
+            # sleep (and what it overshot) where the timer did; stamped
+            # once the poll has brought jobs, on them
             idle_from = (sent if self._able_since is None
                          else min(max(self._polled_at, self._able_since),
                                   sent))
@@ -901,6 +952,7 @@ class Worker:
             # token probed at the next denoise chunk boundary)
             for job_id in self.hive.last_cancels:
                 self._cancel_job(job_id)
+            self._poll_failed = False
             return POLL_SECONDS
         except asyncio.TimeoutError:
             # a timeout IS a poll failure: back off like one (the
@@ -912,6 +964,7 @@ class Worker:
             logger.exception("ask_for_work error")
             print(f"ask_for_work error {e}")
             _POLL_ERRORS.inc()
+        self._poll_failed = True
         return _next_backoff(sleep_seconds)
 
     def _cancel_job(self, job_id: str) -> None:

@@ -8,6 +8,8 @@ by `linger`, `claim`, `package_wait`), `format_args`, `pass`, `handoff`,
 with nothing of the job's admit -> settle left unnamed."""
 
 import asyncio
+import threading
+import time
 
 import pytest
 
@@ -232,3 +234,61 @@ def test_tick_wait_is_the_sleep_a_free_slice_spent_not_the_busy_time(
         slept["poll"]["start_wall"], abs=1e-6)
     assert at_once["tick_wait"]["seconds"] < 0.1  # not the 0.2 s it was busy
     assert list(at_once) == ["poll", "tick_wait", "linger", "claim"]
+
+
+def test_a_gang_standing_at_the_hive_is_fetched_the_instant_a_slice_is_free(
+        sdaas_root, monkeypatch):
+    """ISSUE 39: with the cadence at half a minute, the job that stood at
+    the hive while the slice was held is asked for as the slice is let
+    go: its `tick_wait` is the poll loop's wake-up, begun where the pass
+    before it ended, and the poll is counted under `capacity`."""
+    monkeypatch.setattr(worker_mod, "POLL_SECONDS", 30.0)
+    gate = threading.Event()
+
+    async def scenario():
+        hive = await FakeHive().start()
+        hive.add_job({"id": "holds-the-slice", "workflow": "echo",
+                      "model_name": "none", "prompt": "first"})
+        w = Worker(settings=Settings(sdaas_token="t", worker_name="w",
+                                     metrics_port=0),
+                   allocator=SliceAllocator(chips_per_job=8),
+                   hive_uri=hive.uri)
+        work = w.synchronous_do_work
+
+        def gated(chipset, function, kwargs):
+            if kwargs.get("id") == "holds-the-slice":
+                gate.wait(30.0)
+            return work(chipset, function, kwargs)
+
+        w.synchronous_do_work = gated
+        by_capacity = worker_mod._POLLS.value(cause="capacity")
+        runner = asyncio.create_task(w.run())
+        try:
+            while not w._executing_ids:  # the start-up poll's job, held
+                await asyncio.sleep(0.01)
+            hive.add_job({"id": "stood-at-the-hive", "workflow": "echo",
+                          "model_name": "none", "prompt": "second"})
+            await asyncio.sleep(0.3)
+            assert len(hive.pending_jobs) == 1
+            opened = time.monotonic()
+            gate.set()
+            results = await hive.wait_for_results(2, timeout=20.0)
+            return (results, time.monotonic() - opened,
+                    worker_mod._POLLS.value(cause="capacity") - by_capacity)
+        finally:
+            gate.set()
+            w.stop()
+            await asyncio.wait_for(runner, 10)
+            await hive.stop()
+
+    results, took, by_capacity = asyncio.run(scenario())
+    first, second = sorted(results, key=lambda r: r["id"] != "holds-the-slice")
+    assert second["id"] == "stood-at-the-hive"
+    # a poll a pass: the second's brought nothing and no third followed
+    assert took < 5.0 and by_capacity == 2
+    tick_wait, poll = named(second, "tick_wait"), named(second, "poll")
+    assert tick_wait["seconds"] < 0.25
+    assert end(tick_wait) == pytest.approx(poll["start_wall"], abs=1e-6)
+    # the slice came free where the first job's pass ended (the loop
+    # learning of it in between)
+    assert 0 <= tick_wait["start_wall"] - end(named(first, "pass")) < 0.25

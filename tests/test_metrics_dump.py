@@ -209,6 +209,23 @@ def test_resume_line_from_synthetic_text():
     assert tool.resume_summary([]) is None
 
 
+def test_polls_line_from_synthetic_text():
+    """ISSUE 39: what ended the wait before each hive poll, under the
+    stage table and in the machine-readable twin; a worker that has not
+    polled yet renders nothing."""
+    tool = _load_tool()
+    samples = tool.parse_metrics(
+        'swarm_polls_total{cause="timer"} 7\n'
+        'swarm_polls_total{cause="capacity"} 23\n'
+        'swarm_polls_total{cause="heartbeat"} 41\n')
+    assert tool.polls_line(samples) == (
+        "polls          capacity=23 heartbeat=41 timer=7")
+    assert tool.polls_summary(samples) == {
+        "capacity": 23, "heartbeat": 41, "timer": 7}
+    assert tool.polls_line([]) is None
+    assert tool.polls_summary([]) is None
+
+
 HIVE_SYNTHETIC = """\
 # TYPE swarm_hive_dispatch_total counter
 swarm_hive_dispatch_total{outcome="affinity"} 6
@@ -417,6 +434,7 @@ def test_json_mode_emits_machine_readable_twin(monkeypatch, capsys):
     assert payload["hive"]["dag"]["stages"]["denoise"]["done"] == 3
     # the synthetic worker never checkpointed: the twin is null, not {}
     assert payload["worker"]["resume"] is None
+    assert payload["worker"]["polls"] is None
     stages = {r["stage"]: r for r in payload["worker"]["stages"]}
     assert stages["denoise"]["count"] == 4
     assert stages["denoise"]["p90_le_s"] == "+Inf"  # inf spelled safely

@@ -22,6 +22,7 @@ import pytest
 from chiaswarm_tpu import faults
 from chiaswarm_tpu import outbox as outbox_mod
 from chiaswarm_tpu import worker as worker_mod
+from chiaswarm_tpu.batching import SPANS
 from chiaswarm_tpu.chips.allocator import SliceAllocator
 from chiaswarm_tpu.settings import Settings
 from chiaswarm_tpu.worker import Worker
@@ -1145,7 +1146,10 @@ def test_next_pass_runs_while_the_last_is_packaged(two_gangs_slow_packaging):
         max(min(span_end(span), span_end(next_pass))
             - max(span["start_wall"], next_pass["start_wall"]), 0.0)
         for span in encodes)
-    assert 0 < overlapped_s <= sum(span["seconds"] for span in encodes)
+    # all of it where the next gang is fetched the instant the slice is
+    # free; a span's end is a sum of two floats, hence the slack
+    assert 0 < overlapped_s <= sum(
+        span["seconds"] for span in encodes) + 1e-6
 
 
 def test_artifacts_are_what_package_gives_for_the_same_images(
@@ -1337,3 +1341,230 @@ def test_a_slice_waits_while_two_of_its_passes_are_undelivered(sdaas_root):
             await hive.stop()
 
     asyncio.run(scenario())
+
+
+# --- the poll's wait ends at the timer or at new capacity (ISSUE 39) ---
+
+
+def polls_by_cause() -> dict:
+    return {cause: worker_mod._POLLS.value(cause=cause)
+            for cause in ("capacity", "timer", "heartbeat")}
+
+
+def polls_since(before: dict) -> dict:
+    return {cause: n - before[cause]
+            for cause, n in polls_by_cause().items()}
+
+
+@contextlib.asynccontextmanager
+async def polling_worker(slices: int = 1, hold: bool = True):
+    """A worker of which only `poll_loop` runs, against a fake hive: the
+    test plays the slice workers (`hold`: every slice taken before the
+    loop starts, so nothing is asked until the test lets one go). Yields
+    the hive, the worker, the held slices and `sent`, the monotonic
+    instant and `cancel_only` flag of every poll the worker sends."""
+    hive = await FakeHive().start()
+    w = Worker(settings=Settings(sdaas_token="t", worker_name="w",
+                                 metrics_port=0),
+               allocator=SliceAllocator(chips_per_job=8 // slices),
+               hive_uri=hive.uri)
+    assert len(w.allocator) == slices
+    sent: list[tuple[float, bool]] = []
+    ask = w.hive.ask_for_work
+
+    async def noting(caps):
+        sent.append((time.monotonic(), bool(caps.get("cancel_only"))))
+        return await ask(caps)
+
+    w.hive.ask_for_work = noting
+    held = [w.allocator.try_acquire() for _ in range(slices)] if hold else []
+    w._note_capacity()
+    w._capabilities()  # a process's first costs seconds of imports
+    poll = asyncio.create_task(w.poll_loop())
+    await asyncio.sleep(0.01)  # the loop's first turn is behind it
+    try:
+        yield hive, w, held, sent
+    finally:
+        poll.cancel()
+        await asyncio.gather(poll, return_exceptions=True)
+        await w.hive.close()
+        w._executor.shutdown(wait=False)
+        await hive.stop()
+
+
+async def until(condition, timeout: float = 2.0) -> float:
+    """Seconds until `condition()` held (asked every millisecond)."""
+    started = time.monotonic()
+    while not condition():
+        assert time.monotonic() - started < timeout, "never happened"
+        await asyncio.sleep(0.001)
+    return time.monotonic() - started
+
+
+# what "at once" may cost on a busy test host; every cadence below is
+# several times this, so a timer cannot be what passed a test
+AT_ONCE_S = 0.25
+
+
+def test_a_release_with_a_job_at_the_hive_is_followed_by_a_poll_at_once(
+        sdaas_root, monkeypatch):
+    monkeypatch.setattr(worker_mod, "POLL_SECONDS", 30.0)
+
+    async def scenario():
+        async with polling_worker() as (hive, w, [chipset], sent):
+            hive.add_job(echo_job("standing"))
+            await asyncio.sleep(0.3)
+            assert sent == []  # no room: nothing asked, no heartbeat owed
+            before = polls_by_cause()
+            released = time.time()
+            w.allocator.release(chipset)
+            assert await until(lambda: sent) < AT_ONCE_S
+            [job] = await asyncio.wait_for(w.batcher.get(), 1.0)
+            w.batcher.task_done(job)
+            await asyncio.sleep(0.2)
+            assert len(sent) == 1  # and the timer's turn is 30 s away
+            return job, released, polls_since(before)
+
+    job, released, polls = asyncio.run(scenario())
+    assert job["id"] == "standing"
+    spans = {span["name"]: span for span in job[SPANS]}
+    # the wait for the tick is the loop's wake-up, begun at the release
+    assert spans["tick_wait"]["seconds"] < AT_ONCE_S
+    assert spans["tick_wait"]["start_wall"] == pytest.approx(
+        released, abs=0.01)
+    assert polls == {"capacity": 1, "timer": 0, "heartbeat": 0}
+
+
+def test_a_release_does_not_cut_a_poll_errors_back_off_short(
+        sdaas_root, monkeypatch):
+    # cadence == cap, so the jittered back-off is exactly one second
+    monkeypatch.setattr(worker_mod, "POLL_SECONDS", 1.0)
+    monkeypatch.setattr(worker_mod, "ERROR_BACKOFF_SECONDS", 1.0)
+
+    async def scenario():
+        async with polling_worker() as (hive, w, [chipset], sent):
+            hive.refuse_with = "not now"
+            before = polls_by_cause()
+            w.allocator.release(chipset)
+            await until(lambda: w._poll_failed)
+            assert w._poll_backoff_s == 1.0
+            hive.refuse_with = None
+            chipset = w.allocator.try_acquire()
+            w._note_capacity()
+            assert w._able_since is None
+            hive.add_job(echo_job("behind-the-back-off"))
+            w.allocator.release(chipset)  # capacity, new, mid back-off
+            assert w._capacity.is_set()
+            await asyncio.sleep(0.7)
+            assert len(sent) == 1
+            await until(lambda: len(sent) == 2, timeout=1.0)
+            [job] = await asyncio.wait_for(w.batcher.get(), 1.0)
+            w.batcher.task_done(job)
+            # the back-off's own poll answered the wake-up: no second one
+            await asyncio.sleep(0.5)
+            assert len(sent) == 2 and not w._poll_failed
+            return sent, polls_since(before)
+
+    sent, polls = asyncio.run(scenario())
+    assert sent[1][0] - sent[0][0] >= 1.0
+    assert polls == {"capacity": 1, "timer": 1, "heartbeat": 0}
+
+
+def test_a_release_with_nothing_at_the_hive_makes_one_poll_then_the_timer(
+        sdaas_root, monkeypatch):
+    monkeypatch.setattr(worker_mod, "POLL_SECONDS", 1.0)
+
+    async def scenario():
+        async with polling_worker() as (hive, w, [chipset], sent):
+            await asyncio.sleep(0.3)
+            assert sent == []
+            before = polls_by_cause()
+            w.allocator.release(chipset)
+            assert await until(lambda: sent) < AT_ONCE_S
+            # it brought nothing: the worker is as able as it was, so
+            # nothing wakes the loop again and the hive is left alone
+            # until the timer, which counts from the early poll
+            await asyncio.sleep(0.8)
+            assert len(sent) == 1 and w._able_since is not None
+            assert not w._capacity.is_set()
+            await until(lambda: len(sent) == 2, timeout=1.0)
+            await asyncio.sleep(0.8)
+            assert len(sent) == 2
+            return sent, polls_since(before), len(hive.work_requests)
+
+    sent, polls, requests = asyncio.run(scenario())
+    assert 1.0 <= sent[1][0] - sent[0][0] < 1.0 + AT_ONCE_S
+    assert polls == {"capacity": 1, "timer": 1, "heartbeat": 0}
+    assert requests == 2
+
+
+def test_two_slices_released_together_make_one_poll(sdaas_root, monkeypatch):
+    monkeypatch.setattr(worker_mod, "POLL_SECONDS", 30.0)
+
+    async def scenario():
+        async with polling_worker(slices=2) as (hive, w, held, sent):
+            before = polls_by_cause()
+            for chipset in held:
+                w.allocator.release(chipset)
+            assert await until(lambda: sent) < AT_ONCE_S
+            await asyncio.sleep(0.3)
+            assert len(sent) == 1
+            # one slice busy and free again while the other stayed free:
+            # the worker never stopped being able, nothing new to ask
+            chipset = w.allocator.try_acquire()
+            w._note_capacity()
+            w.allocator.release(chipset)
+            await asyncio.sleep(0.3)
+            assert len(sent) == 1
+            return polls_since(before)
+
+    assert asyncio.run(scenario()) == {
+        "capacity": 1, "timer": 0, "heartbeat": 0}
+
+
+def test_a_draining_worker_is_not_woken(sdaas_root, monkeypatch):
+    monkeypatch.setattr(worker_mod, "POLL_SECONDS", 30.0)
+
+    async def scenario():
+        async with polling_worker() as (hive, w, [chipset], sent):
+            hive.add_job(echo_job("not-for-a-leaver"))
+            w.stop(drain=True)
+            w.allocator.release(chipset)
+            assert w._able_since is None and not w._capacity.is_set()
+            await asyncio.sleep(0.4)
+            return sent, len(hive.pending_jobs)
+
+    assert asyncio.run(scenario()) == ([], 1)
+
+
+def test_a_busy_workers_heartbeat_keeps_the_timers_cadence(
+        sdaas_root, monkeypatch):
+    """A wake-up whose capacity is gone by the time the loop runs (the
+    slice went straight to work that was waiting on the board) asks
+    nothing and leaves the timer as it was: the `cancel_only` heartbeat
+    comes a cadence after the one before, neither early nor late."""
+    monkeypatch.setattr(worker_mod, "POLL_SECONDS", 0.6)
+
+    async def scenario():
+        async with polling_worker(hold=False) as (hive, w, _, sent):
+            # one job in a slice's hands: outstanding, nothing ready
+            await w.batcher.put(echo_job("in-a-pass"))
+            _, chipset, _ = await asyncio.wait_for(
+                w.batcher.claim(w.allocator), 2.0)
+            w._note_capacity()
+            await until(lambda: sent and sent[-1][1])  # a heartbeat
+            first = len(sent)
+            before = polls_by_cause()
+            await asyncio.sleep(0.2)
+            w.allocator.release(chipset)  # free ...
+            assert w._capacity.is_set()
+            assert w.allocator.try_acquire() is chipset  # ... and taken
+            w._note_capacity()
+            await until(lambda: len(sent) == first + 2, timeout=3.0)
+            return sent[first - 1:], polls_since(before)
+
+    sent, polls = asyncio.run(scenario())
+    assert all(heartbeat for _, heartbeat in sent)
+    for (earlier, _), (later, _) in zip(sent, sent[1:]):
+        assert 0.6 <= later - earlier < 0.6 + AT_ONCE_S
+    assert polls == {"capacity": 0, "timer": 0, "heartbeat": 2}

@@ -675,6 +675,26 @@ def resume_line(samples) -> str | None:
     return "resume         " + "  ".join(parts)
 
 
+def polls_summary(samples) -> dict | None:
+    """What ended the wait before each hive poll (ISSUE 39): `capacity`
+    (the worker became able to take work: a slice came free), `timer`
+    (the cadence or a back-off ran out) or `heartbeat` (the timer's
+    `cancel_only` poll of a busy worker). None before the first poll."""
+    polls = _label_counts(samples, "swarm_polls_total", "cause")
+    if not polls:
+        return None
+    return {k: int(v) for k, v in sorted(polls.items())}
+
+
+def polls_line(samples) -> str | None:
+    """Human-readable twin of polls_summary."""
+    summary = polls_summary(samples)
+    if summary is None:
+        return None
+    return "polls          " + " ".join(
+        f"{cause}={n}" for cause, n in summary.items())
+
+
 async def _run_smoke_job() -> None:
     """One tiny-model txt2img job through the REAL worker path (the same
     code a hive job takes minus the HTTP hop), populating the stage spans."""
@@ -801,6 +821,7 @@ def main(argv: list[str] | None = None) -> int:
         "geometry": geometry_summary(samples),
         "cost": cost_summary(samples),
         "resume": resume_summary(samples),
+        "polls": polls_summary(samples),
         "healthz": health,
     }
     if args.json:
@@ -822,6 +843,9 @@ def main(argv: list[str] | None = None) -> int:
         resume = resume_line(samples)
         if resume:
             print(resume)
+        polls = polls_line(samples)
+        if polls:
+            print(polls)
     return 0 if rows else 1
 
 
