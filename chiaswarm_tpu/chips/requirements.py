@@ -22,6 +22,7 @@ measured fits (SDXL batch 4 @ 1024^2 runs on one 16 GB v5e chip with
 
 from __future__ import annotations
 
+from ..coalesce import text_family_of
 from ..models.configs import model_family
 
 # static parameter + resident-state footprint, GiB (bf16, incl. text/vae)
@@ -80,11 +81,25 @@ FAMILY_ACT_GB_PER_IMAGE: dict[str, float] = {
 # full layer and as a ring of 128 on the four sliding ones; the working set
 # is a 4096-token chunk's activations, its expert buffer and a 16384-slot
 # row's keys beside them.
+# sdar_moe is one stage of an 8-stage pipeline with every expert held
+# (models/sdar.py SDAR_30B_PP8): 4.36 B parameters = 8.72 GB; a position is
+# a key and a value on 4 heads of 128 x 2 bytes = 2048 B on each of 6
+# layers, all kept whole; the working set is what the compile for a
+# described v5e counted for the 256-row block decode beside weights and
+# cache (benchmark/compile_check.py, PERF.md: 4.14 GB of temporaries, a
+# block step's float32 logits over the whole vocabulary, the sampler's
+# copies of them and its random bits, the expert buffer; the columns a
+# pass's last committed block may overhang `prompt slots + new tokens` by
+# are in it too).
+# The families are coalesce.py `TEXT_FAMILIES`' (the jax-free table of
+# their names), one entry each.
 SEQUENCE_FAMILIES: dict[str, dict] = {
     "kimi_k2": {"params_gb": 9.04, "working_gb": 3.0,
                 "cache_layers": ((8064.0, 0),)},
     "exaone_moe": {"params_gb": 6.91, "working_gb": 3.0,
                    "cache_layers": ((4096.0, 0), (16384.0, 128))},
+    "sdar_moe": {"params_gb": 8.12, "working_gb": 3.9,
+                 "cache_layers": ((12288.0, 0),)},
 }
 # the cached positions one pass holds at most, whatever the memory left:
 # 256 rows of 512 positions. A pass is budgeted in positions and not in
@@ -149,10 +164,9 @@ def _family_key(model_name: str) -> str:
     name = model_name.lower()
     if "flux" in name:
         return "flux"
-    if "kimi" in name:
-        return "kimi_k2"
-    if "exaone" in name:
-        return "exaone_moe"
+    text = text_family_of(name)
+    if text is not None:
+        return text
     if "kandinsky-3" in name or "kandinsky3" in name:
         return "kandinsky3"
     if "kandinsky" in name:

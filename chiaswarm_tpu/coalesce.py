@@ -107,11 +107,25 @@ _BATCHABLE_CN_PIPELINE_TYPES = {
 }
 
 # families whose jobs carry token ids and whose rows are sequences
-# (pipelines/text_generation.py)
-_TEXT_FAMILIES = {"kimi_k2", "exaone_moe"}
-# the only `parameters` keys a batchable text job may carry
+# (pipelines/text_generation.py): the word of a model's name that tells the
+# family, the wire name of its pipeline type and, where the family decodes
+# a block of positions at a time, the block's length. Here because this
+# module is the one every side imports (the registry's `PIPELINE_FAMILIES`
+# and `_auto_family`, chips/requirements.py `_family_key` and
+# `SEQUENCE_FAMILIES`, the pipeline's `_MODELS` read or repeat its keys;
+# tests/test_text_serving.py holds them equal)
+TEXT_FAMILIES: dict[str, dict] = {
+    "kimi_k2": {"name": "kimi", "wire": "KimiK2ForCausalLM"},
+    "exaone_moe": {"name": "exaone", "wire": "ExaoneMoeForCausalLM"},
+    "sdar_moe": {"name": "sdar", "wire": "SdarMoeForCausalLM",
+                 "block_length": 4},
+}
+# the only `parameters` keys a batchable text job may carry, and those a
+# job of a family that decodes by blocks may carry besides
 _SAFE_TEXT_PARAMETER_KEYS = frozenset(
     {"pipeline_type", "max_new_tokens", "temperature"})
+_BLOCK_TEXT_PARAMETER_KEYS = frozenset(
+    {"denoising_steps", "confidence_threshold"})
 DEFAULT_NEW_TOKENS = 256
 DEFAULT_TEMPERATURE = 1.0
 # the smallest bucket a prompt is padded to
@@ -185,28 +199,78 @@ def text_shape(job: dict) -> tuple[int, int] | None:
     return prompt_slots(max(len(row) for row in rows)), new
 
 
+def text_family_of(model_name: str) -> str | None:
+    """The text family a model's name tells, None for any other model."""
+    name = model_name.lower()
+    for family, what in TEXT_FAMILIES.items():
+        if what["name"] in name:
+            return family
+    return None
+
+
+def checked_denoising_steps(steps, length: int) -> int:
+    """The denoise forwards a block of `length` positions gets at most:
+    `steps`, a divisor of `length` (none given: `length`), else a
+    ValueError."""
+    steps = length if steps is None else steps
+    if isinstance(steps, bool) or not isinstance(steps, int) \
+            or not 1 <= steps <= length or length % steps:
+        raise ValueError(
+            f"denoising_steps must be a divisor of the block length "
+            f"{length}, not {steps!r}")
+    return steps
+
+
+def block_denoising(job: dict, family: str) -> tuple:
+    """What a job says of a block decode, `(denoising_steps,
+    confidence_threshold or None)`; `()` for a family that decodes a token
+    a step and a job that says nothing of it. Raises ValueError where the
+    job carries either for such a family, or steps that are not a divisor
+    of the family's block length."""
+    params = job.get("parameters")
+    params = params if isinstance(params, dict) else {}
+    given = {key: params.get(key, job.get(key))
+             for key in _BLOCK_TEXT_PARAMETER_KEYS}
+    length = TEXT_FAMILIES[family].get("block_length")
+    if not length:
+        if any(value is not None for value in given.values()):
+            raise ValueError(
+                f"{family} decodes a token a step: a job of it takes no "
+                "denoising_steps or confidence_threshold")
+        return ()
+    threshold = given["confidence_threshold"]
+    return (checked_denoising_steps(given["denoising_steps"], length),
+            None if threshold is None else round(float(threshold), 6))
+
+
 def _text_key(job: dict) -> tuple | None:
     """The bucket of a `txt2txt` job: everything the two jitted programs
-    close over (model, prompt bucket, new tokens, sampling); the ids, the
-    seed and how many sequences a job has ride per row."""
+    close over (model, prompt bucket, new tokens, sampling and, for a
+    family that decodes by blocks, the denoise forwards a block gets and
+    the threshold, behind the elements the hive reads by place); the ids,
+    the seed and how many sequences a job has ride per row."""
     model = job.get("model_name")
     if not isinstance(model, str) or not model:
         return None
+    family = text_family_of(model)
+    if family is None:
+        return None
     params = job.get("parameters") or {}
-    if not isinstance(params, dict) \
-            or not set(params) <= _SAFE_TEXT_PARAMETER_KEYS:
+    allowed = _SAFE_TEXT_PARAMETER_KEYS | (
+        _BLOCK_TEXT_PARAMETER_KEYS
+        if TEXT_FAMILIES[family].get("block_length") else frozenset())
+    if not isinstance(params, dict) or not set(params) <= allowed:
         return None
     shape = text_shape(job)
     if shape is None or shape[1] < 1:
         return None
-    from .registry import _auto_family
-
-    family = _auto_family(model)
-    if family not in _TEXT_FAMILIES:
-        return None
+    try:
+        blocks = block_denoising(job, family)
+    except (TypeError, ValueError):
+        return None  # the formatter's error to raise
     temperature = round(float(params.get(
         "temperature", job.get("temperature", DEFAULT_TEMPERATURE))), 4)
-    return (model, family, "txt2txt", *shape, temperature)
+    return (model, family, "txt2txt", *shape, temperature, *blocks)
 
 
 def job_rows(job: dict) -> int:

@@ -128,8 +128,15 @@ async def format_img2txt_args(args: dict):
 def format_txt2txt_args(args: dict):
     """Text completion from token ids: `model_name`, `prompt_ids` (a list
     of rows, each a list of ids of the model's vocabulary),
-    `max_new_tokens`, `temperature`, `seed`. The result is JSON."""
-    from .coalesce import DEFAULT_NEW_TOKENS, DEFAULT_TEMPERATURE
+    `max_new_tokens`, `temperature`, `seed` and, for a model that decodes
+    by blocks, `denoising_steps` and `confidence_threshold`. The result is
+    JSON."""
+    from .coalesce import (
+        DEFAULT_NEW_TOKENS,
+        DEFAULT_TEMPERATURE,
+        block_denoising,
+        text_family_of,
+    )
     from .workflows.text import txt2txt_callback
 
     parameters = args.pop("parameters", None) or {}
@@ -147,6 +154,14 @@ def format_txt2txt_args(args: dict):
         "max_new_tokens", args.get("max_new_tokens", DEFAULT_NEW_TOKENS)))
     args["temperature"] = float(parameters.pop(
         "temperature", args.get("temperature", DEFAULT_TEMPERATURE)))
+    family = text_family_of(str(args.get("model_name", "")))
+    if family is not None:
+        blocks = block_denoising({**args, "parameters": parameters}, family)
+        for key in ("denoising_steps", "confidence_threshold"):
+            parameters.pop(key, None)
+            args.pop(key, None)
+        if blocks:
+            args["denoising_steps"], args["confidence_threshold"] = blocks
     args["content_type"] = "application/json"
     return txt2txt_callback, args
 

@@ -72,6 +72,7 @@ class ExaoneConfig:
     moe_intermediate_size: int = 2048
     num_experts: int = 128  # the router's width, whatever is held
     num_experts_per_tok: int = 8
+    scoring_func: str = "sigmoid"
     routed_scaling_factor: float = 2.5
     num_shared_experts: int = 1
     first_k_dense_replace: int = 1

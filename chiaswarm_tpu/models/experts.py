@@ -1,19 +1,24 @@
-"""What the language models with sigmoid-routed sparse experts share
-(models/kimi.py, models/exaone.py): the norm, the SwiGLU, the router, the
-held experts' grouped matmul, the tally of how the routing fell, the seeded
-init of a parameter tree and the head.
+"""What the language models with routed sparse experts share
+(models/kimi.py, models/exaone.py, models/sdar.py): the norm, the SwiGLU,
+the router under either of two rules, the held experts' grouped matmul, the
+tally of how the routing fell, the seeded init of a parameter tree and the
+head.
 
 `cfg` is the model's own config dataclass; what is read of it here is
-`num_experts_per_tok`, `routed_scaling_factor`, `experts_held` ((first,
-count): the routed experts this chip holds of every layer),
-`expert_layers`, `first_k_dense_replace` and `rms_norm_eps`. A layer's
-`moe` holds `router` [hidden, all the experts], `router_bias`, `experts`
-(`gate`, `up`, `down`, the held ones stacked) and `shared`.
+`scoring_func`, `num_experts_per_tok`, `routed_scaling_factor`,
+`experts_held` ((first, count): the routed experts this chip holds of
+every layer), `expert_layers`, `first_k_dense_replace` and `rms_norm_eps`.
+A layer's `moe` holds `router` [hidden, all the experts], `experts`
+(`gate`, `up`, `down`, the held ones stacked) and, where the model has
+them, `router_bias` and `shared`.
 
-The experts: `s = sigmoid(h W_g)` in float32 over ALL the model's experts,
-the `k` largest of `s + b` chosen, weights `s_i / sum_chosen s * scale`
-(normalised over all the chosen, held here or not); the layer adds
-`sum_{chosen and held} w_i E_i(h) + E_shared(h)`. What the absent experts
+The experts: scores `s` in float32 over ALL the model's experts, by the
+config's `scoring_func`: `sigmoid(h W_g)`, the `k` largest of `s + b`
+chosen (`b` the layer's correction bias: Kimi-K2, K-EXAONE), or
+`softmax(h W_g)`, the `k` largest chosen (SDAR: no bias). Either way the
+weights are `s_i / sum_chosen s * scale` (normalised over all the chosen,
+held here or not), and the layer adds `sum_{chosen and held} w_i E_i(h)`
+and, where it has a shared expert, `E_shared(h)`. What the absent experts
 would add is left out: on a chip that is one of many sharing the layer, the
 exchange that brings it is not run and nothing stands in for it. The held
 experts' part is one grouped matmul over the pairs sorted by expert
@@ -75,14 +80,18 @@ def swiglu(p, x):
 
 def route(p, cfg, h):
     """The `k` experts of every token of `h` [T, hidden] and their weights
-    [T, k], float32: sigmoid scores over all the experts, the choice by
-    score plus correction bias, the weights from the scores alone."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    [T, k], float32: scores over all the experts by the config's rule
+    (sigmoid, or softmax over them), the choice by score plus the
+    correction bias where the layer has one, the weights from the scores
+    alone."""
+    logits = jnp.dot(
         h.astype(jnp.float32), p["router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(
-        scores + p["router_bias"].astype(jnp.float32),
-        cfg.num_experts_per_tok)
+        precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.softmax(logits, axis=-1)
+              if cfg.scoring_func == "softmax" else jax.nn.sigmoid(logits))
+    biased = (scores + p["router_bias"].astype(jnp.float32)
+              if "router_bias" in p else scores)
+    _, chosen = jax.lax.top_k(biased, cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = picked / jnp.sum(picked, -1, keepdims=True)
     return chosen, weights * cfg.routed_scaling_factor
@@ -114,8 +123,9 @@ def held_experts(experts, h, local, interpret: bool = False):
 
 
 def expert_layer(p, cfg, h, valid=None, interpret: bool = False):
-    """The held experts' part and the shared expert's for tokens `h`
-    [T, hidden] (`valid` [T]: padding is routed nowhere). Returns the sum
+    """The held experts' part and, where the layer has one, the shared
+    expert's for tokens `h` [T, hidden] (`valid` [T]: padding is routed
+    nowhere). Returns the sum
     and how the routing fell: pairs of each held expert [held], and
     (routed pairs, the fullest expert's pairs, experts with pairs)."""
     first, held = cfg.experts_held
@@ -130,7 +140,10 @@ def expert_layer(p, cfg, h, valid=None, interpret: bool = False):
              else jnp.sum(valid.astype(jnp.int32)))
     stats = jnp.stack([count * cfg.num_experts_per_tok, jnp.max(sizes),
                        jnp.sum((sizes > 0).astype(jnp.int32))])
-    return routed.astype(h.dtype) + swiglu(p["shared"], h), (sizes, stats)
+    out = routed.astype(h.dtype)
+    if "shared" in p:
+        out = out + swiglu(p["shared"], h)
+    return out, (sizes, stats)
 
 
 def feed_forward(layer, cfg, h, valid, interpret):

@@ -67,6 +67,7 @@ class KimiConfig:
     moe_intermediate_size: int = 2048
     n_routed_experts: int = 384  # the router's width, whatever is held
     num_experts_per_tok: int = 8
+    scoring_func: str = "sigmoid"
     routed_scaling_factor: float = 2.827
     n_shared_experts: int = 1
     first_k_dense_replace: int = 1

@@ -117,11 +117,14 @@ def _flash_route(q, k, v, scale, interpret: bool = False):
 
 
 def reference_attention(q, k, v, scale: float | None = None,
-                        causal: bool = False, window: int = 0):
+                        causal: bool = False, window: int = 0,
+                        span: int = 0):
     """Readable O(S^2)-memory reference; also the CPU/test path. `v` may
     be narrower or wider than `q` and `k`; `causal` lets query i see the
     keys up to i (the last query is the last key), of them the last
-    `window` where one is given; with fewer key heads than query heads,
+    `window` where one is given; under a `span` the query at position t
+    sees key u iff `u // span <= t // span`: every earlier span whole and
+    its own in both directions; with fewer key heads than query heads,
     query head j reads key head j // (Hq / Hkv)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -134,8 +137,12 @@ def reference_attention(q, k, v, scale: float | None = None,
     logits = (logits * scale).astype(jnp.float32)
     if causal:
         sq, skv = q.shape[1], k.shape[1]
-        seen = (jnp.arange(skv)[None, :]
-                <= jnp.arange(sq)[:, None] + (skv - sq))
+        if span:
+            seen = (jnp.arange(skv)[None, :] // span
+                    <= (jnp.arange(sq)[:, None] + (skv - sq)) // span)
+        else:
+            seen = (jnp.arange(skv)[None, :]
+                    <= jnp.arange(sq)[:, None] + (skv - sq))
         if window:
             seen = seen & (jnp.arange(sq)[:, None] + (skv - sq)
                            - jnp.arange(skv)[None, :] < window)
@@ -149,7 +156,8 @@ def reference_attention(q, k, v, scale: float | None = None,
 
 @functools.partial(jax.named_call, name="attention")
 def dot_product_attention(q, k, v, scale: float | None = None,
-                          causal: bool = False, window: int = 0):
+                          causal: bool = False, window: int = 0,
+                          span: int = 0):
     """[B, S_q, H, D] x [B, S_kv, H_kv, D] -> [B, S_q, H, D_v].
 
     Self- and cross-attention both route here (cross: S_kv = text length).
@@ -158,22 +166,28 @@ def dot_product_attention(q, k, v, scale: float | None = None,
     have no mask, one head width for q, k and v and a key head a query
     head. A `causal` call (the queries are the last S_q positions of the
     keys; `window`: of the keys up to its own a query sees that many;
-    H_kv may divide H) is the banded kernel's on a TPU from
+    `span`: the mask is causal between spans of that many positions and
+    bidirectional inside one, and takes no window; H_kv may divide H) is
+    the banded kernel's on a TPU from
     `_FLASH_MIN_SEQ` queries on, where keys and values are as wide as the
     queries and a head is whole lanes (ops/banded_attention.py; one chip:
     under a mesh scope it is not split and the XLA path runs). Any other
     causal call is the XLA path's whatever its length (latent attention's
     prefill: 192-wide q.k, 128-wide v).
     """
-    if window and not causal:
-        raise ValueError("a window is a causal band's: causal=True")
+    if (window or span) and not causal:
+        raise ValueError("a window or a span is a causal band's: "
+                         "causal=True")
+    if window and span:
+        raise ValueError("a span's mask has no window")
     if (causal and trace_platform() == "tpu" and active_mesh() is None
             and q.shape[1] >= _FLASH_MIN_SEQ
             # one width, and a head the 128 lanes the kernel's blocks are
             and k.shape[-1] == v.shape[-1] == q.shape[-1]
             == _FLASH_MAX_HEAD_DIM):
         KERNEL_TRACES.inc(op="attention", path="banded")
-        return banded_attention(q, k, v, scale=scale, window=window)
+        return banded_attention(q, k, v, scale=scale, window=window,
+                                span=span)
     plain = not causal and v.shape[-1] == q.shape[-1]
     ring_out = _ring_route(q, k, v, scale) if plain else None
     if ring_out is not None:
@@ -186,4 +200,4 @@ def dot_product_attention(q, k, v, scale: float | None = None,
         return _flash_route(q, k, v, scale)
     KERNEL_TRACES.inc(op="attention", path="reference")
     return reference_attention(q, k, v, scale=scale, causal=causal,
-                               window=window)
+                               window=window, span=span)

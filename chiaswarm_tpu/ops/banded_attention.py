@@ -5,7 +5,11 @@ Query `i` of `Sq` stands at key position `t = Skv - Sq + i` (the queries
 are the last `Sq` positions of the keys: a prefill chunk against what the
 cache holds so far, or `Sq = Skv`), and sees key `u` iff `u <= t` and, with
 a `window`, `t - u < window` (`window` keys, its own among them; 0: every
-key up to its own). Query head `j` reads key head `j // G`, `G = Hq / Hkv`.
+key up to its own). Under a `span` (and no window) the query's edge moves
+from `t` to the last position of its span, `(t // span + 1) * span - 1`:
+the mask is causal between spans of that many positions and bidirectional
+inside one (models/sdar.py: a block of a block-diffusion model). Query
+head `j` reads key head `j // G`, `G = Hq / Hkv`.
 
 Layout: q [B, Sq, Hq, D], k / v [B, Skv, Hkv, D] -> [B, Sq, Hq, D], as
 ops.attention has them, and no operand is moved: with `D` a multiple of the
@@ -69,14 +73,21 @@ def banded_blocks(sq: int, skv: int, window: int, dtype) -> tuple[int, int]:
             min(_round_up(skv, _LANES), largest))
 
 
+def span_edge(t, span: int):
+    """The last key the query at position `t` sees: `t` itself, under a
+    span the last position of the span `t` lies in."""
+    return (t // span + 1) * span - 1 if span else t
+
+
 def band_blocks(i, sq: int, skv: int, window: int, block_q: int,
-                block_k: int):
+                block_k: int, span: int = 0):
     """(first, last): the key blocks query block `i` visits, both
     inclusive. `i` may be traced (the index map), a number or an array."""
     start = skv - sq + i * block_q  # the block's first query's position
     first = (jnp.maximum(start - (window - 1), 0) // block_k if window
              else 0 * i)
-    last = jnp.minimum(start + block_q - 1, skv - 1) // block_k
+    last = jnp.minimum(span_edge(start + block_q - 1, span),
+                       skv - 1) // block_k
     return first, last
 
 
@@ -93,15 +104,15 @@ def step_vmem_bytes(block_q: int, block_k: int, group: int, head_dim: int,
 
 
 def _banded_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                   sq: int, skv: int, window: int, block_k: int,
-                   scale: float, fold_scale: bool):
+                   sq: int, skv: int, window: int, span: int,
+                   block_k: int, scale: float, fold_scale: bool):
     """One (batch, key head, query block, visited key block) step: q_ref /
     o_ref [BQ, G * D], k_ref / v_ref [BK, D]; state [G, BQ, .]."""
     block_q = q_ref.shape[0]
     head_dim = k_ref.shape[1]
     group = q_ref.shape[1] // head_dim
     i, j = pl.program_id(2), pl.program_id(3)
-    first, last = band_blocks(i, sq, skv, window, block_q, block_k)
+    first, last = band_blocks(i, sq, skv, window, block_q, block_k, span)
     block = first + j
     start = skv - sq + i * block_q
 
@@ -118,7 +129,7 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                 jnp.int32, (block_q, block_k), 1)
             row = start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            seen = col <= row
+            seen = col <= span_edge(row, span)
             if window:
                 seen = seen & (row - col < window)
         for g in range(group):
@@ -147,9 +158,9 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                     preferred_element_type=jnp.float32))
 
     # every query of the step sees every key of the block: the block's
-    # last key is no later than the first query, and with a window its
-    # first key no further than the window from the last query
-    whole = (block + 1) * block_k - 1 <= start
+    # last key is no later than the first query's edge, and with a window
+    # its first key no further than the window from the last query
+    whole = (block + 1) * block_k - 1 <= span_edge(start, span)
     if window:
         whole = whole & (start + block_q - 1 - block * block_k < window)
     inside = block <= last
@@ -169,13 +180,14 @@ def _banded_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                 acc_ref[g] / _lanes(l_ref[g], head_dim)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "blocks",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "window", "span",
+                                             "blocks", "interpret"))
 def banded_attention(q, k, v, scale: float | None = None, window: int = 0,
-                     blocks: tuple[int, int] | None = None,
+                     span: int = 0, blocks: tuple[int, int] | None = None,
                      interpret: bool = False):
     """[B, Sq, Hq, D] x [B, Skv, Hkv, D] -> [B, Sq, Hq, D], causal, the
-    queries the last `Sq` positions of the keys; `window` 0 is no window.
+    queries the last `Sq` positions of the keys; `window` 0 is no window,
+    `span` 0 no span (a call has one of the two at most).
 
     `blocks` (block_q, block_k) is for tests that force a branch at a
     small size; the program never passes it, and the rule decides.
@@ -184,6 +196,9 @@ def banded_attention(q, k, v, scale: float | None = None, window: int = 0,
     skv, hkv = k.shape[1], k.shape[2]
     assert sq <= skv and hq % hkv == 0 and v.shape == k.shape, (
         q.shape, k.shape, v.shape)
+    # the keys end with a span, so no query's edge lies in the padding
+    assert not (window and span) and (not span or skv % span == 0), (
+        window, span, skv)
     # a head is a block of lanes (the interpreter takes any width)
     assert interpret or d % _LANES == 0, d
     group = hq // hkv
@@ -194,7 +209,7 @@ def banded_attention(q, k, v, scale: float | None = None, window: int = 0,
     n_q = sq_pad // block_q
     with jax.ensure_compile_time_eval():  # the longest band, a number
         first, last = band_blocks(jnp.arange(n_q), sq, skv, window,
-                                  block_q, block_k)
+                                  block_q, block_k, span)
         visited = int(jnp.max(last - first + 1))
     vmem = step_vmem_bytes(block_q, block_k, group, d,
                            jnp.dtype(q.dtype).itemsize)
@@ -207,7 +222,8 @@ def banded_attention(q, k, v, scale: float | None = None, window: int = 0,
     v = _pad_to(v, skv_pad, 1).reshape(b, skv_pad, hkv * d)
 
     def kv_index(bi, hi, i, j):
-        first, last = band_blocks(i, sq, skv, window, block_q, block_k)
+        first, last = band_blocks(i, sq, skv, window, block_q, block_k,
+                                  span)
         return bi, jnp.minimum(first + j, last), hi
 
     q_spec = pl.BlockSpec((None, block_q, group * d),
@@ -215,8 +231,9 @@ def banded_attention(q, k, v, scale: float | None = None, window: int = 0,
     kv_spec = pl.BlockSpec((None, block_k, d), kv_index)
     out = pl.pallas_call(
         functools.partial(
-            _banded_kernel, sq=sq, skv=skv, window=window, block_k=block_k,
-            scale=scale, fold_scale=math.frexp(scale)[0] == 0.5),
+            _banded_kernel, sq=sq, skv=skv, window=window, span=span,
+            block_k=block_k, scale=scale,
+            fold_scale=math.frexp(scale)[0] == 0.5),
         grid=(b, hkv, n_q, visited),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,

@@ -1,6 +1,10 @@
 """Text completion from token ids: one jitted prefill and one jitted cached
 decode over a resident language model, whichever family the name resolves
-to (`_MODELS`: Kimi-K2, models/kimi.py; K-EXAONE, models/exaone.py).
+to (`_MODELS`: Kimi-K2, models/kimi.py; K-EXAONE, models/exaone.py; SDAR,
+models/sdar.py). There are two ways to decode, and the model's module says
+which is its own by what it has: a `step` (a token a row a forward, below)
+or a `block_step` (a block of tokens a row over several forwards, "By
+blocks" further down).
 
 A pass is a set of rows (sequences), each a prompt of token ids, all
 generating the same number of new tokens. Rows are padded to a power-of-two
@@ -21,9 +25,30 @@ padding after it), so a pass is keyed by (rows, prompt slots, new tokens):
 - **step** is the decode step alone, given tokens in, logits out: what a
   comparison with the plain reference needs.
 
+**By blocks** (a model with `block_step`: block diffusion). Prefill caches
+each row's whole prompt blocks and returns no logits. The decode program
+(`block_decode_program`) is a scan over blocks of `block_length` positions;
+a block starts as the mask id (the first one behind the `L mod B` ids of
+the prompt that did not fill a block), and inside the scan a bounded loop
+of **denoise** forwards runs the block's `rows x block_length` tokens
+against the cache **without writing it**, draws an id a position from the
+position's own logits (key: job, row, block, forward), and fixes the
+`block_length / denoising_steps` still-masked positions it is surest of
+(with a `confidence_threshold`: every one over it, if those are as many);
+when no row has a mask left in the block, or after `denoising_steps`
+forwards, one **commit** forward of the finished block writes its keys and
+values. So what a forward yields is a number to count: the program counts
+the forwards of either kind and the positions that took an id or were
+already fixed, on the device, and they come back with the ids
+(`swarm_block_forward_rows_total`, `swarm_block_slots_total`,
+`swarm_generated_tokens_total`; the envelope's `decode_steps` are the
+forwards the decode made, `forwards` says of which kind). `block_program`
+is one such forward with given ids, logits out: the comparison's.
+
 What a pass caches is the model's to say (`cache_bytes`: Kimi-K2 a latent
 a position a layer, K-EXAONE keys and values a position on its full
-layers and a ring of its window on the others): the whole is
+layers and a ring of its window on the others, SDAR keys and values a
+position on every layer): the whole is
 `swarm_pass_cache_bytes{model}`, the rings' part
 `swarm_pass_window_cache_bytes{model}`. A pass counts its prompt slots
 (`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
@@ -37,7 +62,8 @@ program, with the experts that had a pair and the calls).
 No tokenizer: ids travel on the wire, and there is no stop token, every
 row generates `max_new_tokens`. `test/` names are seeded weights: `tiny` in
 the name is the family's tiny preset, any other the chip's share of the
-deployment at the published widths (`KIMI_K2_EP32`, `EXAONE_236B_EP8`;
+deployment at the published widths (`KIMI_K2_EP32`, `EXAONE_236B_EP8`,
+`SDAR_30B_PP8`;
 `weights=` hands the tree in already on the chip, as `FluxPipeline` takes
 it: the host init of billions of parameters is minutes).
 """
@@ -55,8 +81,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry
-from ..coalesce import prompt_slots
-from ..models import exaone, kimi
+from ..coalesce import checked_denoising_steps, prompt_slots
+from ..models import exaone, kimi, sdar
 from ..ops import platform
 from ..parallel.mesh import make_mesh, replicated
 from ..registry import _auto_family, register_family
@@ -72,10 +98,15 @@ logger = logging.getLogger(__name__)
 PREFILL_CHUNK_TOKENS = 4096
 
 # family -> the module that has the model: `config_for`, `param_shapes`,
-# `init_params`, `prefill`, `step`, `empty_load`, `cache_bytes`,
-# `POSITION_CHUNKS` and, where its prefill leaves out a chunk that is all
-# padding, the rule it goes by: `span_runs`
-_MODELS = {"kimi_k2": kimi, "exaone_moe": exaone}
+# `init_params`, `prefill`, `empty_load`, `cache_bytes`, `POSITION_CHUNKS`,
+# where its prefill leaves out a chunk that is all padding the rule it goes
+# by (`span_runs`), and one of the two ways to decode: `step` (prefill
+# returns the last prompt position's logits, a forward feeds a row one
+# token and yields one) or `block_step` with `first_block`, `unmask`,
+# `blocks_of` and `cache_positions` (prefill returns no logits, a forward
+# feeds a row a block). The families are chips/requirements.py
+# `SEQUENCE_FAMILIES`' (tests/test_text_serving.py holds the lists equal)
+_MODELS = {"kimi_k2": kimi, "exaone_moe": exaone, "sdar_moe": sdar}
 
 EXPERT_PAIRS = telemetry.counter(
     "swarm_expert_pairs_total",
@@ -99,6 +130,22 @@ PASS_WINDOW_CACHE_BYTES = telemetry.gauge(
     "The part of swarm_pass_cache_bytes that is rings of a window (rows "
     "x window, the layers that attend to a window only), by model",
     ("model",))
+BLOCK_FORWARD_ROWS = telemetry.counter(
+    "swarm_block_forward_rows_total",
+    "Real rows x forwards of a block decode, by model and kind (denoise: "
+    "a forward that reads the cache and may fix positions; commit: the "
+    "forward that writes a finished block's keys and values and yields "
+    "nothing)", ("model", "kind"))
+GENERATED_TOKENS = telemetry.counter(
+    "swarm_generated_tokens_total",
+    "Ids a block decode handed back (real rows x new tokens), by model",
+    ("model",))
+BLOCK_SLOTS = telemetry.counter(
+    "swarm_block_slots_total",
+    "Positions of real rows that a block decode's denoise forwards "
+    "computed, by model and kind (unmasked: took an id in the forward; "
+    "idle: were fixed before it, a prompt's given tail included)",
+    ("model", "kind"))
 PREFILL_SLOTS = telemetry.counter(
     "swarm_prefill_slots_total",
     "Prompt slots of the prefill programs' passes, by model and kind (real: "
@@ -130,6 +177,8 @@ class TextGenerationPipeline:
         self.chipset = chipset
         self.model = _MODELS[_auto_family(model_name)]
         self.config = self.model.config_for(model_name)
+        # the way this model decodes: a block a row, or a token a row
+        self.by_blocks = hasattr(self.model, "block_step")
         if dtype is None:
             dtype = (jnp.bfloat16 if jax.default_backend() == "tpu"
                      else jnp.float32)
@@ -197,9 +246,18 @@ class TextGenerationPipeline:
                 self._programs.popitem(last=False)
             return program
 
+    def cache_positions(self, slots: int, new_tokens: int) -> int:
+        """Columns of the cache of a pass of `slots` prompt slots and
+        `new_tokens` ids a row (by blocks: every block but the last whole,
+        at most `block_length - 2` columns over `slots + new_tokens`)."""
+        if self.by_blocks:
+            return self.model.cache_positions(self.config, slots, new_tokens)
+        return slots + new_tokens
+
     def prefill_program(self, rows: int, slots: int, positions: int):
         """`(params, ids [rows, slots], lengths [rows]) -> (logits of each
-        row's last prompt token [rows, vocab], cache, tally)`."""
+        row's last prompt token [rows, vocab], cache, tally)`; a model
+        that decodes by blocks returns `(cache, tally)`."""
         cfg, model = self.config, self.model
         chunk = prefill_chunk(rows, slots, model.POSITION_CHUNKS)
 
@@ -265,18 +323,142 @@ class TextGenerationPipeline:
             ("decode", rows, slots, new_tokens),
             lambda: jax.jit(decode, donate_argnums=donate))
 
+    def block_program(self, rows: int, slots: int, positions: int,
+                      commit: bool):
+        """One forward of a block with given ids: `(params, cache, ids
+        [rows, block_length], lengths [rows], block) -> (logits [rows,
+        block_length, vocab], cache)`, the cache written under `commit`
+        and else as it came (on a chip donated and handed back as the
+        same buffers either way, as the decode programs')."""
+        cfg, model = self.config, self.model
+
+        def forward(params, cache, ids, lengths, block):
+            logits, cache, _ = model.block_step(
+                params, cfg, ids, lengths, block, slots, cache,
+                model.empty_load(cfg), valid=lengths > 0, commit=commit)
+            return logits, cache
+
+        donate = (1,) if platform.trace_platform() == "tpu" else ()
+        return self._program(
+            ("block", rows, slots, positions, commit),
+            lambda: jax.jit(forward, donate_argnums=donate))
+
+    def block_decode_program(self, rows: int, slots: int, new_tokens: int,
+                             denoising_steps: int, thresholded: bool):
+        """`(params, cache, ids [rows, slots], lengths, job_keys,
+        job_of_row, row_in_job, temperature, threshold, tally) -> (ids
+        [rows, new_tokens], tally, counts, cache)`: the scan over blocks,
+        in each the bounded loop of denoise forwards and the commit (the
+        last block is not committed: nobody reads it). `counts` are the
+        pass's (denoise forwards, commit forwards, positions of real rows
+        unmasked, positions of real rows computed though fixed), counted
+        on the device: under a threshold the forwards are data. The cache
+        is donated, as the other decode's."""
+        cfg, model = self.config, self.model
+        length = cfg.block_length
+        blocks = model.blocks_of(cfg, new_tokens)
+        count = length // denoising_steps
+
+        def draw(logits, keys, temperature):
+            """An id a position from its own logits, and the probability
+            it was drawn with (at temperature 0 the largest logit's)."""
+            scaled = logits / jnp.where(temperature > 0, temperature, 1.0)
+            drawn = jnp.where(
+                temperature > 0,
+                jax.vmap(jax.random.categorical)(keys, scaled),
+                jnp.argmax(logits, axis=-1)).astype(jnp.int32)
+            picked = jnp.take_along_axis(scaled, drawn[..., None], -1)[..., 0]
+            return drawn, jnp.exp(
+                picked - jax.nn.logsumexp(scaled, axis=-1))
+
+        def decode(params, cache, ids, lengths, job_keys, job_of_row,
+                   row_in_job, temperature, threshold, load):
+            valid = lengths > 0
+            keys = jax.vmap(jax.random.fold_in)(
+                jax.random.wrap_key_data(job_keys)[job_of_row], row_in_job)
+            first_ids, first_masked = model.first_block(cfg, ids, lengths)
+
+            def block(number, cache, load, counts, commit: bool):
+                block_keys = jax.vmap(
+                    lambda key: jax.random.fold_in(key, number))(keys)
+                opening = number == 0
+                state = (jnp.int32(0),
+                         jnp.where(opening, first_ids, cfg.mask_token_id),
+                         jnp.where(opening, first_masked, True)
+                         & valid[:, None], load, counts)
+
+                def unfinished(state):
+                    forward, _, masked, _, _ = state
+                    return (forward < denoising_steps) & jnp.any(masked)
+
+                def denoise(state):
+                    forward, tokens, masked, load, counts = state
+                    logits, _, load = model.block_step(
+                        params, cfg, tokens, lengths, number, slots, cache,
+                        load, valid=valid)
+                    drawn, confidence = draw(logits, jax.vmap(
+                        lambda key: jax.random.fold_in(key, forward))(
+                            block_keys), temperature)
+                    tokens, left = model.unmask(
+                        tokens, masked, drawn, confidence, count,
+                        threshold if thresholded else None)
+                    took = jnp.sum(masked & ~left)
+                    fixed = jnp.sum(valid[:, None] & ~masked)
+                    return (forward + 1, tokens, left, load,
+                            counts + jnp.stack([1, 0, took, fixed]))
+
+                _, tokens, _, load, counts = jax.lax.while_loop(
+                    unfinished, denoise, state)
+                if commit:
+                    _, cache, load = model.block_step(
+                        params, cfg, tokens, lengths, number, slots, cache,
+                        load, valid=valid, commit=True, head=False)
+                    counts = counts + jnp.array([0, 1, 0, 0])
+                return tokens, cache, load, counts
+
+            def committed(carry, number):
+                tokens, *carry = block(number, *carry, commit=True)
+                return tuple(carry), tokens
+
+            (cache, load, counts), out = jax.lax.scan(
+                committed, (cache, load, jnp.zeros((4,), jnp.int32)),
+                jnp.arange(blocks - 1))
+            last, cache, load, counts = block(
+                jnp.int32(blocks - 1), cache, load, counts, commit=False)
+            out = jnp.concatenate([out, last[None]]).transpose(1, 0, 2)
+            # a row's first new id stands behind its prompt's given tail
+            at = (lengths % length)[:, None] + jnp.arange(new_tokens)
+            out = jnp.take_along_axis(out.reshape(rows, -1), at, axis=1)
+            return out, load, counts, cache
+
+        donate = (1,) if platform.trace_platform() == "tpu" else ()
+        return self._program(
+            ("block_decode", rows, slots, new_tokens, denoising_steps,
+             thresholded),
+            lambda: jax.jit(decode, donate_argnums=donate))
+
     # --- a pass ---
 
     def run_batched(self, requests: list[dict], *, max_new_tokens: int,
-                    temperature: float = 1.0):
+                    temperature: float = 1.0,
+                    denoising_steps: int | None = None,
+                    confidence_threshold: float | None = None):
         """One pass over every row of `requests` (each `prompt_ids`: a
         list of rows of ids, and `rng`: the job's key). Returns per
         request its ids `[rows, max_new_tokens]` (numpy) and the pass's
-        `pipeline_config`."""
+        `pipeline_config`. `denoising_steps` (the denoise forwards a block
+        gets at most: a divisor of the block length, which is the default)
+        and `confidence_threshold` are a block decode's."""
         cfg = self.config
         new_tokens = int(max_new_tokens)
         if new_tokens < 1:
             raise ValueError("max_new_tokens must be at least 1")
+        if self.by_blocks:
+            steps = checked_denoising_steps(denoising_steps, cfg.block_length)
+        elif denoising_steps is not None or confidence_threshold is not None:
+            raise ValueError(
+                f"{self.model_name} decodes a token a step: it takes no "
+                "denoising_steps or confidence_threshold")
         prompts = [row for request in requests
                    for row in request["prompt_ids"]]
         if not prompts or any(len(row) < 1 for row in prompts):
@@ -304,16 +486,28 @@ class TextGenerationPipeline:
                 at += 1
         job_keys = jnp.stack([jax.random.key_data(request["rng"])
                               for request in requests])
-        positions = slots + new_tokens
+        positions = self.cache_positions(slots, new_tokens)
+        prefill = self.prefill_program(rows, slots, positions)
         timings: dict = {}
+        counts = None
         with Span("prefill", timings):
-            logits, cache, filled = self.prefill_program(
-                rows, slots, positions)(self.params, ids, lengths)
-            jax.block_until_ready(logits)
+            # the last prompt position's logits first, where the model
+            # decodes a token a step
+            *logits, cache, filled = prefill(self.params, ids, lengths)
+            jax.block_until_ready(filled)
         with Span("decode", timings):
-            out, load, cache = self.decode_program(rows, slots, new_tokens)(
-                self.params, cache, logits, lengths, job_keys, job_of_row,
-                row_in_job, jnp.float32(temperature), filled)
+            sampling = (job_keys, job_of_row, row_in_job,
+                        jnp.float32(temperature))
+            if self.by_blocks:
+                out, load, counts, cache = self.block_decode_program(
+                    rows, slots, new_tokens, steps,
+                    confidence_threshold is not None)(
+                    self.params, cache, ids, lengths, *sampling,
+                    jnp.float32(confidence_threshold or 0.0), filled)
+            else:
+                out, load, cache = self.decode_program(
+                    rows, slots, new_tokens)(
+                    self.params, cache, *logits, lengths, *sampling, filled)
             del cache
             jax.block_until_ready(out)
         with Span("readback", timings):
@@ -321,10 +515,14 @@ class TextGenerationPipeline:
             (pairs, sums), (before, before_sums) = (
                 tuple(np.asarray(x) for x in tally)
                 for tally in (load, filled))
+            if counts is not None:
+                denoise, commit, unmasked, idle = (
+                    int(x) for x in np.asarray(counts))
+
         def tally(pairs, sums, calls):
             return {"pairs": int(pairs.sum()), "routed": int(sums[0]),
                     "pairs_max": int(sums[1]), "active": int(sums[2]),
-                    "calls": cfg.expert_layers * calls}
+                    "calls": calls}
 
         # the whole pass, and its two programs apart (decode's tally began
         # where prefill's ended)
@@ -338,13 +536,37 @@ class TextGenerationPipeline:
             not runs(lengths[at:at + chunk_rows], start)
             for at in range(0, rows, chunk_rows)
             for start in range(0, slots, chunk_slots))
+        # expert-layer calls of either program: a forward whose logits
+        # nobody reads (a block model's prefill chunk and commit) stops
+        # before its last layer's experts
+        layers = cfg.expert_layers
+        if self.by_blocks:
+            forwards = denoise + commit
+            prefill_calls = (layers - 1) * (chunks - skipped)
+            decode_calls = layers * denoise + (layers - 1) * commit
+        else:
+            forwards = new_tokens - 1
+            prefill_calls = layers * (chunks - skipped)
+            decode_calls = layers * forwards
         routing = {
-            **tally(pairs, sums, chunks - skipped + new_tokens - 1),
-            "prefill": tally(before, before_sums, chunks - skipped),
+            **tally(pairs, sums, prefill_calls + decode_calls),
+            "prefill": tally(before, before_sums, prefill_calls),
             "decode": tally(pairs - before, sums - before_sums,
-                            new_tokens - 1),
+                            decode_calls),
             "pairs_by_expert": pairs.sum(axis=0).tolist()}
         label = {"model": self.model_name}
+        blocks = {}
+        if self.by_blocks:
+            BLOCK_FORWARD_ROWS.inc(real * denoise, kind="denoise", **label)
+            BLOCK_FORWARD_ROWS.inc(real * commit, kind="commit", **label)
+            GENERATED_TOKENS.inc(real * new_tokens, **label)
+            BLOCK_SLOTS.inc(unmasked, kind="unmasked", **label)
+            BLOCK_SLOTS.inc(idle, kind="idle", **label)
+            blocks = {"block_length": cfg.block_length,
+                      "denoising_steps": steps,
+                      "confidence_threshold": confidence_threshold,
+                      "blocks": self.model.blocks_of(cfg, new_tokens),
+                      "forwards": {"denoise": denoise, "commit": commit}}
         EXPERT_PAIRS.inc(routing["pairs"], **label)
         ROUTED_TOKENS.inc(routing["routed"], **label)
         EXPERT_PAIRS_MAX.inc(routing["pairs_max"], **label)
@@ -368,8 +590,9 @@ class TextGenerationPipeline:
                 "prompt_tokens": int(sum(
                     len(row) for row in request["prompt_ids"])),
                 "max_new_tokens": new_tokens,
-                "decode_steps": new_tokens - 1,
+                "decode_steps": forwards,
                 "temperature": float(temperature),
+                **blocks,
                 "prefill_chunks": chunks,
                 "prefill_chunks_skipped": skipped,
                 "cache_bytes": cache_bytes,

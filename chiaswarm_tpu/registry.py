@@ -20,6 +20,7 @@ import threading
 from collections import OrderedDict
 from typing import Callable
 
+from .coalesce import TEXT_FAMILIES, text_family_of
 from .telemetry import Span
 
 logger = logging.getLogger(__name__)
@@ -70,8 +71,8 @@ PIPELINE_FAMILIES: dict[str, str] = {
     "StableVideoDiffusionPipeline": "svd",
     "BlipForConditionalGeneration": "blip",
     "BlipForQuestionAnswering": "blip",
-    "KimiK2ForCausalLM": "kimi_k2",
-    "ExaoneMoeForCausalLM": "exaone_moe",
+    # the text families' wire names (coalesce.py `TEXT_FAMILIES`)
+    **{what["wire"]: family for family, what in TEXT_FAMILIES.items()},
 }
 
 # family -> factory(model_name, chipset, **variant) -> pipeline bundle.
@@ -116,10 +117,9 @@ def _auto_family(model_name: str) -> str:
         return "cascade_prior" if "prior" in name else "cascade"
     if "flux" in name:
         return "flux"
-    if "kimi" in name:
-        return "kimi_k2"
-    if "exaone" in name:
-        return "exaone_moe"
+    text = text_family_of(name)
+    if text is not None:
+        return text
     if name.startswith("deepfloyd/") or "tiny-if" in name:
         return "deepfloyd_if"
     if "latent-upscaler" in name or "tiny-upscaler" in name:

@@ -4,7 +4,8 @@ ids a sequence, as JSON. No tokenizer on either side of the wire.
 
 `txt2txt_callback` is one job alone; `txt2txt_batched_callback` a gang of
 jobs that share a coalesce key (model, prompt bucket, new tokens,
-temperature: coalesce.py) as one pass of all their rows. Both return their
+temperature and, for a model that decodes by blocks, `denoising_steps` and
+`confidence_threshold`: coalesce.py) as one pass of all their rows. Both return their
 ids `Unpackaged`: the JSON is written off the slice, as a pass's images
 are (span `artifact_encode`, thread `host`).
 """
@@ -56,11 +57,15 @@ def txt2txt_batched_callback(device_identifier: str, requests: list[dict]):
         pipeline = get_pipeline(
             model_name, shared.get("pipeline_type", "AutoModelForCausalLM"),
             chipset=chipset)
+    # a block decode's two parameters, where the formatter found the
+    # family to take them
+    blocks = {key: shared[key] for key in (
+        "denoising_steps", "confidence_threshold") if key in shared}
     results = pipeline.run_batched(
         [{"prompt_ids": request["prompt_ids"], "rng": request["rng"]}
          for request in requests],
         max_new_tokens=new_tokens,
-        temperature=float(shared.get("temperature", 1.0)))
+        temperature=float(shared.get("temperature", 1.0)), **blocks)
     out = []
     for token_ids, pipeline_config in results:
         pipeline_config["batched_with"] = len(requests)

@@ -112,6 +112,20 @@ def test_banded_attention_compiles_for_v5e(v5e, sq, skv, window):
     assert "banded_attention" in compiled.as_text()
 
 
+@pytest.mark.parametrize("sq,skv", [
+    pytest.param(4096, 8192, id="sdar-second-chunk"),
+    pytest.param(1024, 1024, id="sdar-short-rows"),
+])
+def test_banded_attention_under_a_span_compiles_for_v5e(v5e, sq, skv):
+    """SDAR's prefill chunk: 32 query heads on 4 key heads of 128 under a
+    mask that is bidirectional inside a span of 4 (the edge's whole-number
+    division, in the mask and in the block rule, is the chip's to take)."""
+    q = _shape(v5e, (1, sq, 32, 128))
+    kv = _shape(v5e, (1, skv, 4, 128))
+    compiled = banded_attention.lower(q, kv, kv, span=4).compile()
+    assert "banded_attention" in compiled.as_text()
+
+
 @pytest.mark.parametrize("n,c", [
     pytest.param(32 * 32, 1280, id="32x32x1280"),
     pytest.param(32 * 32, 640, id="32x32x640"),
@@ -138,15 +152,20 @@ def test_admission_rule_stays_inside_the_scoped_vmem_limit():
     assert fused_tile_bytes(32 * 32, 1280, 2) > _vmem_budget()
 
 
-@pytest.mark.parametrize("tokens,width,n,gated", [
+@pytest.mark.parametrize("tokens,width,n,gated,held", [
     # Kimi-K2's 12 held experts (hidden 7168, expert width 2048): a decode
     # step's 256 tokens in 16-row tiles, a prefill chunk's 4096 in 128-row
-    pytest.param(256, 7168, 2048, True, id="kimi-decode-gate-up"),
-    pytest.param(256, 2048, 7168, False, id="kimi-decode-down"),
-    pytest.param(4096, 7168, 2048, True, id="kimi-prefill-gate-up"),
-    pytest.param(4096, 2048, 7168, False, id="kimi-prefill-down"),
+    pytest.param(256, 7168, 2048, True, 12, id="kimi-decode-gate-up"),
+    pytest.param(256, 2048, 7168, False, 12, id="kimi-decode-down"),
+    pytest.param(4096, 7168, 2048, True, 12, id="kimi-prefill-gate-up"),
+    pytest.param(4096, 2048, 7168, False, 12, id="kimi-prefill-down"),
+    # SDAR's 128 held experts (hidden 2048, expert width 768): a block
+    # step's 256 rows x 4 positions in 128-row tiles, a prefill chunk's
+    pytest.param(1024, 2048, 768, True, 128, id="sdar-block-gate-up"),
+    pytest.param(1024, 768, 2048, False, 128, id="sdar-block-down"),
+    pytest.param(4096, 2048, 768, True, 128, id="sdar-prefill-gate-up"),
 ])
-def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated):
+def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated, held):
     """The grouped kernel at the row buffer's worst-case size, its grid's
     first extent a traced number (only the tiles that hold rows)."""
     from chiaswarm_tpu.ops.expert_matmul import (
@@ -156,8 +175,8 @@ def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated):
     )
 
     tm = row_tile(tokens)
-    rows = buffer_rows(tokens, 8, 12, tm)
-    weights = tuple(_shape(v5e, (12, width, n)) for _ in range(1 + gated))
+    rows = buffer_rows(tokens, 8, held, tm)
+    weights = tuple(_shape(v5e, (held, width, n)) for _ in range(1 + gated))
     compiled = jax.jit(
         lambda x, w, tiles, count: _grouped(x, w, tiles, count, tm=tm)
     ).lower(_shape(v5e, (rows, width)), weights,

@@ -63,6 +63,95 @@ def test_a_text_job_that_cannot_batch_has_no_key(broken):
     assert coalesce_key({**_job(1, 5), **broken}) is None
 
 
+def _block_job(**extra):
+    return {**_job(1, 5), "model_name": "test/tiny-sdar", **extra}
+
+
+def test_a_block_decodes_key_carries_its_denoising_steps_behind_the_rest():
+    """Behind the elements the hive reads by place (`rows_per_pass`:
+    prompt slots at 3, new tokens at 4), and for this family only."""
+    assert coalesce_key(_block_job()) == (
+        "test/tiny-sdar", "sdar_moe", "txt2txt", 16, 6, 1.0, 4, None)
+    assert coalesce_key(_block_job(denoising_steps=2)) == (
+        "test/tiny-sdar", "sdar_moe", "txt2txt", 16, 6, 1.0, 2, None)
+    assert coalesce_key(_block_job(
+        parameters={"denoising_steps": 2, "confidence_threshold": 0.9})) == (
+        "test/tiny-sdar", "sdar_moe", "txt2txt", 16, 6, 1.0, 2, 0.9)
+
+
+@pytest.mark.parametrize("change, same", [
+    ({"denoising_steps": 4}, True),  # the default, said
+    ({"denoising_steps": 2}, False),
+    ({"confidence_threshold": 0.9}, False),
+    ({"seed": 77}, True),
+], ids=["default_steps", "other_steps", "threshold", "seed"])
+def test_rows_that_differ_in_denoising_steps_do_not_share_a_pass(change,
+                                                                 same):
+    assert (coalesce_key(_block_job(**change))
+            == coalesce_key(_block_job())) is same
+
+
+@pytest.mark.parametrize("broken", [
+    {"denoising_steps": 3}, {"denoising_steps": 0}, {"denoising_steps": 8},
+    {"denoising_steps": "2"}, {"denoising_steps": True},
+    {"confidence_threshold": "high"},
+    {"model_name": "test/tiny-kimi", "denoising_steps": 2},
+    {"model_name": "test/tiny-exaone",
+     "parameters": {"confidence_threshold": 0.9}},
+], ids=["no_divisor", "zero", "over_the_block", "a_string", "a_bool",
+        "threshold_no_number", "kimi_takes_none", "exaone_takes_none"])
+def test_denoising_steps_that_cannot_be_have_no_key_and_the_formatters_error(
+        broken):
+    from chiaswarm_tpu.job_arguments import format_txt2txt_args
+
+    job = _block_job(**broken)
+    assert coalesce_key(job) is None
+    with pytest.raises((TypeError, ValueError)):
+        format_txt2txt_args(dict(job))
+
+
+def test_the_formatter_hands_a_block_decode_its_two_parameters():
+    from chiaswarm_tpu.job_arguments import format_txt2txt_args
+
+    _, args = format_txt2txt_args(_block_job(
+        parameters={"denoising_steps": 2}, confidence_threshold=0.5))
+    assert (args["denoising_steps"], args["confidence_threshold"]) == (2, 0.5)
+    _, args = format_txt2txt_args(_block_job())
+    assert (args["denoising_steps"], args["confidence_threshold"]) == (
+        4, None)
+    _, args = format_txt2txt_args(_job(1, 5))
+    assert "denoising_steps" not in args
+
+
+def test_the_lists_of_text_families_agree():
+    """One family missing from one of them would fail the first job of it:
+    the name table every side imports (coalesce.py), the costs
+    (chips/requirements.py), the models (the pipeline), and what the
+    registry and the admission make of a name."""
+    from chiaswarm_tpu import coalesce, registry
+    from chiaswarm_tpu.pipelines import text_generation
+
+    families = set(coalesce.TEXT_FAMILIES)
+    assert families == set(requirements.SEQUENCE_FAMILIES) == set(
+        text_generation._MODELS) == {"kimi_k2", "exaone_moe", "sdar_moe"}
+    registry._ensure_builtin_families()
+    assert families <= set(registry._FACTORIES)
+    for family, what in coalesce.TEXT_FAMILIES.items():
+        name = f"test/tiny-{what['name']}"
+        assert registry.family_of(what["wire"]) == family
+        assert registry._auto_family(name) == family
+        assert requirements._family_key(name) == family
+        assert coalesce.text_family_of(name.upper()) == family
+        # a family decodes by blocks in both tables or in neither
+        model = text_generation._MODELS[family]
+        assert hasattr(model, "block_step") == ("block_length" in what)
+        assert hasattr(model, "block_step") != hasattr(model, "step")
+        if "block_length" in what:
+            assert model.config_for(name).block_length == model.config_for(
+                "test/whole").block_length == what["block_length"]
+    assert coalesce.text_family_of("test/tiny-sd") is None
+
+
 def test_prompt_slots_are_powers_of_two_from_sixteen():
     assert [prompt_slots(n) for n in (1, 16, 17, 128, 129, 256)] == [
         16, 16, 32, 128, 256, 256]
@@ -192,7 +281,9 @@ def test_the_dispatcher_sizes_a_text_gang_by_the_familys_own_appetite():
         ("exaone_moe", "test/tiny-exaone", 16384, 128, 1, 4, 4),
         ("exaone_moe", "test/tiny-exaone", 32768, 128, 1, 2, 2),  # 3.98 fit
         ("kimi_k2", "test/tiny-kimi", 16, 6, 3, 256, 8),  # the poll's cap
-    ], ids=["kimi_batch_decode", "exaone_long_documents", "longer", "short"])
+        ("sdar_moe", "test/tiny-sdar", 256, 256, 64, 256, 4),
+    ], ids=["kimi_batch_decode", "exaone_long_documents", "longer", "short",
+            "sdar_block_decode"])
 def test_the_hives_gang_is_reckoned_at_the_jobs_own_positions(
         family, model, slots, new, job_rows_, want_rows, want_jobs):
     from chiaswarm_tpu.hive_server.dispatch import (
@@ -213,12 +304,14 @@ def test_the_hives_gang_is_reckoned_at_the_jobs_own_positions(
     info = directory.observe({
         "worker_name": "w", "slices": "1", "busy_slices": "0",
         "queue_depth": "0", "gang_rows": "8",
-        "family_gang_rows": "kimi_k2:256,exaone_moe:256",
-        "family_gang_positions": "kimi_k2:131072,exaone_moe:131072"})
-    assert info.family_positions == {"kimi_k2": 131072,
-                                     "exaone_moe": 131072}
-    key = (model, family, "txt2txt", slots, new, 1.0)
-    assert coalesce_key(jobs[0]) == key
+        "family_gang_rows": "kimi_k2:256,exaone_moe:256,sdar_moe:256",
+        "family_gang_positions":
+            "kimi_k2:131072,exaone_moe:131072,sdar_moe:131072"})
+    assert info.family_positions == {
+        "kimi_k2": 131072, "exaone_moe": 131072, "sdar_moe": 131072}
+    # a block decode's key goes on behind these (its steps, its threshold)
+    key = coalesce_key(jobs[0])
+    assert key[:6] == (model, family, "txt2txt", slots, new, 1.0)
     assert info.rows_per_pass(key) == want_rows
     handed = dispatcher.select(info, queue)
     assert len(handed) == want_jobs
@@ -238,9 +331,10 @@ def test_a_worker_advertises_the_sequence_families_appetite(sdaas_root):
                     hive_uri="http://127.0.0.1:1")
     caps = worker._capabilities()
     # not HBM on the CPU: the ceiling; the job cap stays what it was
-    assert caps["family_gang_rows"] == "kimi_k2:256,exaone_moe:256"
+    assert caps["family_gang_rows"] == (
+        "kimi_k2:256,exaone_moe:256,sdar_moe:256")
     assert caps["family_gang_positions"] == (
-        "kimi_k2:131072,exaone_moe:131072")
+        "kimi_k2:131072,exaone_moe:131072,sdar_moe:131072")
     assert caps["gang_rows"] == 8
     # the batcher's own budget is the job's true positions
     assert worker._coalesce_rows_limit(_job(1, 2)) == 256
@@ -313,6 +407,68 @@ def test_four_text_jobs_are_one_gang_one_pass_and_seeded(sdaas_root,
     # one job, one seed: the same bytes in a pass of 9 rows as of 12
     assert configs[4]["pass_rows"] == 9
     assert blobs[4] == blobs[0]
+
+
+def test_block_decode_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
+                                                               monkeypatch):
+    """`test/tiny-sdar`: three jobs of two denoise forwards a block are
+    one gang and one pass, a fourth of four forwards a block rides alone;
+    JSON artifacts of `max_new_tokens` ids a row, the envelope says what
+    the forwards were, and one seed gives one answer among other
+    batchmates."""
+    from chiaswarm_tpu import worker as worker_module
+    from chiaswarm_tpu.hive_server.harness import LocalSwarm
+    from chiaswarm_tpu.settings import Settings
+
+    monkeypatch.setattr(worker_module, "POLL_SECONDS", 0.1)
+
+    async def scenario():
+        swarm = LocalSwarm(n_workers=0, settings=Settings(
+            sdaas_token="t", worker_name="w", hive_port=0, metrics_port=0))
+        await swarm.start()
+        try:
+            ids = [await swarm.submit(_block_job(
+                id=f"block-{n}", seed=100 + n, denoising_steps=2))
+                for n in range(3)]
+            ids.append(await swarm.submit(_block_job(id="block-3", seed=100)))
+            swarm.add_worker("text-worker")
+            done = [await swarm.wait_done(i, timeout=300) for i in ids]
+            again = await swarm.submit(_block_job(
+                id="again", seed=100, denoising_steps=2))
+            done.append(await swarm.wait_done(again, timeout=300))
+            blobs = [await swarm.artifact(
+                status["result"]["artifacts"]["primary"]["href"])
+                for status in done]
+            return done, blobs
+        finally:
+            await swarm.stop()
+
+    done, blobs = asyncio.run(scenario())
+    configs = [status["result"]["pipeline_config"] for status in done]
+    assert all(status["status"] == "done" and status["attempts"] == 1
+               for status in done)
+    assert [config["pass_rows"] for config in configs] == [9, 9, 9, 3, 3]
+    assert [config["denoising_steps"] for config in configs] == [
+        2, 2, 2, 4, 2]
+    blocks = 3  # six ids behind a tail of up to three
+    for config in configs:
+        steps = config["denoising_steps"]
+        # a first block behind a given tail may need fewer forwards
+        forwards = config["forwards"]
+        assert forwards["commit"] == blocks - 1
+        assert (blocks - 1) * steps < forwards["denoise"] <= blocks * steps
+        assert config["decode_steps"] == sum(forwards.values())
+        assert (config["block_length"], config["blocks"]) == (4, blocks)
+        names = {span["name"] for span in config["spans"]}
+        assert {"pass", "prefill", "decode", "readback",
+                "artifact_encode"} <= names
+        # every expert held: all that is routed is computed here
+        assert config["routing"]["pairs"] == config["routing"]["routed"] > 0
+    for blob in blobs:
+        rows = json.loads(blob)["token_ids"]
+        assert len(rows) == 3 and all(len(row) == 6 for row in rows)
+        assert all(0 <= i < 128 for row in rows for i in row)
+    assert blobs[4] == blobs[0] and blobs[3] != blobs[0]
 
 
 def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
