@@ -23,8 +23,16 @@ row's whole prompt blocks `[0, L // B * B)` are prefilled under the mask
 and cached; the prompt's last `L mod B` ids open the first generated block
 as given, the rest of it and every later block start as the mask id; a
 block is run through `block_step` against the cache, which is not written,
-until no position of it is masked, and once more with `commit`, which
-writes the block's keys and values.
+until no position of it is masked. Its keys and values are written by the
+forward that first needs them: the first forward of block `g + 1` is a
+**fused** one (`block_step(finished=)`), `2B` positions a row, block `g`'s
+final ids in front of block `g + 1`'s. Under the block mask the first `B`
+see the cache and each other, which is all a forward of block `g` alone
+sees, so their keys and values are that forward's; the last `B` see the
+cache and all `2B`, which is the cache as it stands once block `g` is in
+it. `block_step(commit=True)` is the forward of a block alone that writes
+its own keys and values: what the comparison with the reference runs, and
+what a fused forward is held against.
 
 The cache (`new_cache`): keys and values a layer `[rows, positions, key
 heads, head_dim]`, as attention reads them (normed and rotated); a row's
@@ -33,10 +41,11 @@ whole prompt blocks in the first columns, generated block `g` at columns
 layer. The columns between a row's whole blocks and `slots` (its prompt's
 tail and the padding) are written by prefill and shown to nobody.
 
-A forward whose logits nobody reads (prefill, a commit inside the decode)
-stops at the last layer's keys and values: its attention output, its
-experts and the head are not run, and the routing's tally does not count
-them.
+Positions whose logits nobody reads (a prefill's, the finished block's in
+a fused forward) stop at the last layer's keys and values: their
+attention output, their experts and the head are not run, and the
+routing's tally does not count them; the head and the sampler see a fused
+forward's last `B` positions alone.
 """
 
 from __future__ import annotations
@@ -46,6 +55,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import dot_product_attention
 from .exaone import _whole_rows, rope_tables, span_runs
@@ -151,38 +161,54 @@ def init_params(cfg: SdarConfig, key, dtype) -> dict:
 # --- a forward ---------------------------------------------------------------
 
 
-def _heads(p, cfg: SdarConfig, h, positions):
-    """`h` [..., hidden] at `positions` [...] as queries [..., heads,
-    head_dim] and the keys and values [..., key heads, head_dim] the cache
-    holds of them: normed and rotated."""
-    d = cfg.head_dim
-    q = dot(h, p["q"]).reshape(*h.shape[:-1], cfg.num_attention_heads, d)
-    k = dot(h, p["k"]).reshape(*h.shape[:-1], cfg.num_key_value_heads, d)
-    v = dot(h, p["v"]).reshape(*h.shape[:-1], cfg.num_key_value_heads, d)
+def _rotated(x, weight, cfg: SdarConfig, positions):
+    """A head's dims `x` [..., heads, head_dim] RMS-normed under `weight`
+    and rotated at `positions` [...]."""
     cos, sin = rope_tables(cfg, positions)
-    cos, sin = cos[..., None, :], sin[..., None, :]
-    q = apply_rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), cos, sin)
-    k = apply_rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), cos, sin)
-    return q, k, v
+    return apply_rope(rms_norm(x, weight, cfg.rms_norm_eps),
+                      cos[..., None, :], sin[..., None, :])
+
+
+def _queries(p, cfg: SdarConfig, h, positions):
+    """`h` [..., hidden] at `positions` [...] as queries [..., heads,
+    head_dim], normed and rotated."""
+    q = dot(h, p["q"]).reshape(*h.shape[:-1], cfg.num_attention_heads,
+                               cfg.head_dim)
+    return _rotated(q, p["q_norm"], cfg, positions)
+
+
+def _keys_values(p, cfg: SdarConfig, h, positions):
+    """The keys and values [..., key heads, head_dim] the cache holds of
+    `h` [..., hidden] at `positions` [...]: the keys normed and rotated."""
+    k, v = (dot(h, p[name]).reshape(*h.shape[:-1], cfg.num_key_value_heads,
+                                    cfg.head_dim) for name in ("k", "v"))
+    return _rotated(k, p["k_norm"], cfg, positions), v
 
 
 def _forward(params, cfg: SdarConfig, ids, positions, attend, load, valid,
-             head: bool, interpret: bool):
+             read: int, interpret: bool):
     """Tokens `ids` [R, C] at `positions` [R, C] through every layer;
     `attend(index, q, k, v)` is the layer's attention over whatever the
     caller keeps, [R, C, heads, head_dim]; `valid` [R, C]: the tokens that
-    are routed. Returns the last hidden state [R, C, hidden], a layer's
-    keys and values of these tokens, and the tally; without `head` the
-    last layer stops at its keys and values and the state is None."""
+    are routed. `read`: the last positions of a row whose hidden state
+    somebody reads. The others stop at the last layer's keys and values:
+    there `attend` gets the queries of the last `read` positions alone
+    (and every position's keys and values), and only those are routed.
+    Returns the last hidden state [R, read, hidden] (None where `read` is
+    0), a layer's keys and values of all the tokens, and the tally."""
     x = params["embed"][ids]
     entries = []
     for index, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
-        q, k, v = _heads(layer["attn"], cfg, h, positions)
+        k, v = _keys_values(layer["attn"], cfg, h, positions)
         entries.append((k, v))
-        if not head and index == len(params["layers"]) - 1:
-            return None, entries, load
-        out = attend(index, q, k, v)
+        if index == len(params["layers"]) - 1 and read < ids.shape[1]:
+            if not read:
+                return None, entries, load
+            x, h, positions, valid = (
+                None if part is None else part[:, -read:]
+                for part in (x, h, positions, valid))
+        out = attend(index, _queries(layer["attn"], cfg, h, positions), k, v)
         x = x + dot(out.reshape(*x.shape[:-1], -1), layer["attn"]["o"])
         h = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
         out, told = feed_forward(
@@ -262,7 +288,7 @@ def prefill_rows(params, cfg: SdarConfig, ids, lengths, chunk_slots: int,
 
         _, added, load = _forward(
             params, cfg, ids[:, start:start + chunk_slots], positions,
-            attend, load, positions < whole[:, None], False, interpret)
+            attend, load, positions < whole[:, None], 0, interpret)
         return added, load
 
     def skip(start, kept, load):
@@ -321,64 +347,98 @@ def prefill(params, cfg: SdarConfig, ids, lengths, positions: int,
 # --- a block -----------------------------------------------------------------
 
 
-def block_attention(q, k, v, keys, values, seen, scale: float):
-    """A block's `B` queries a row against the cache and the block's own
-    keys: `q` [R, B, heads, D], `k` / `v` [R, B, key heads, D] the block's
-    own (every query sees all of them), `keys` / `values` [R, S, key
-    heads, D] the cache, `seen` [R, S] its columns the row may see. A
-    group's query heads meet their one cached head in one batched matmul
-    where the cache lies; one softmax over both."""
+def block_attention(q, k, v, keys, values, seen, scale: float, span: int):
+    """A row's queries of its own positions against the cache and those
+    positions' own keys: `k` / `v` [R, C, key heads, D] the forward's own,
+    `q` [R, Q, heads, D] the queries of the last `Q` of those `C`
+    positions, `keys` / `values` [R, S, key heads, D] the cache, `seen`
+    [R, S] its columns the row may see. Own position `t` sees own key `u`
+    iff `u // span <= t // span`: the block mask between the two blocks of
+    a fused forward, and no mask where every query sees every own key (one
+    block; the last block's queries alone). A group's query heads meet
+    their one cached head in one batched matmul where the cache lies; one
+    softmax over both parts, which are never laid side by side (the
+    cache's columns stay a whole number of lane tiles): each leaves as
+    `exp(score - the largest of both)`, rounded as the values are, and the
+    sum of both divides the output."""
     rows, block, heads, d = q.shape
-    kv_heads, columns = k.shape[2], keys.shape[1]
+    kv_heads, own_keys = k.shape[2], k.shape[1]
     q = q.reshape(rows, block, kv_heads, heads // kv_heads, d)
     past = jnp.einsum("rbhgd,rshd->rhgbs", q, keys,
                       preferred_element_type=jnp.float32) * scale
     past = jnp.where(seen[:, None, None, None, :], past, -jnp.inf)
     own = jnp.einsum("rbhgd,rchd->rhgbc", q, k,
                      preferred_element_type=jnp.float32) * scale
-    weights = jax.nn.softmax(jnp.concatenate([past, own], -1), axis=-1)
-    weights = weights.astype(values.dtype)
-    out = jnp.einsum("rhgbs,rshd->rbhgd", weights[..., :columns], values,
+    at = np.arange(own_keys - block, own_keys)[:, None] // span
+    visible = np.arange(own_keys)[None, :] // span <= at
+    if not visible.all():
+        own = jnp.where(visible, own, -jnp.inf)
+    top = jnp.maximum(jnp.max(past, -1), jnp.max(own, -1))[..., None]
+    past, own = jnp.exp(past - top), jnp.exp(own - top)
+    total = jnp.sum(past, -1) + jnp.sum(own, -1)
+    out = jnp.einsum("rhgbs,rshd->rbhgd", past.astype(values.dtype), values,
                      preferred_element_type=jnp.float32) + jnp.einsum(
-        "rhgbc,rchd->rbhgd", weights[..., columns:], v.astype(values.dtype),
-        preferred_element_type=jnp.float32)
+        "rhgbc,rchd->rbhgd", own.astype(values.dtype),
+        v.astype(values.dtype), preferred_element_type=jnp.float32)
+    out = out / jnp.transpose(total, (0, 3, 1, 2))[..., None]
     return out.astype(values.dtype).reshape(rows, block, heads, d)
 
 
 def block_step(params, cfg: SdarConfig, ids, lengths, block, slots: int,
-               cache, load, valid=None, commit: bool = False,
-               head: bool = True, interpret: bool = False):
+               cache, load, valid=None, commit: bool = False, finished=None,
+               interpret: bool = False):
     """Every row's block number `block` through every layer: `ids` [R, B]
     at the row's positions `L // B * B + block * B` on, against the row's
     whole prompt blocks and the committed blocks before this one (`valid`
     [R]: a row that only pads the pass is routed nowhere). The cache is
-    written only under `commit`, at columns `slots + block * B` on. Returns
-    the logits [R, B, vocab] (float32; position `j`'s predict position
-    `j`'s own token), the cache and the tally; without `head` (a commit
-    whose logits nobody reads) the logits are None."""
-    assert head or commit, "a forward with neither logits nor a commit"
+    written only under `commit`, at columns `slots + block * B` on, and
+    with `finished`.
+
+    `finished` [R, B] makes the forward a **fused** one: the final ids of
+    block `block - 1`, which the cache does not hold yet, run in front of
+    `ids` as eight positions a row under the block mask (the first four
+    see the cache and each other, the last four the cache and all eight),
+    and the first four's keys and values of every layer are written at
+    columns `slots + (block - 1) * B` on: what a forward of that block
+    alone under `commit` writes. Their positions yield nothing else: in
+    the last layer they stop at their keys and values, and the head never
+    sees them.
+
+    Returns the logits [R, B, vocab] of `ids`' positions (float32;
+    position `j`'s predict position `j`'s own token), the cache and the
+    tally."""
     rows, length = ids.shape
-    at = slots + block * length
+    given = 0 if finished is None else length
+    first = slots + block * length - given
     start = whole_blocks(cfg, lengths)
-    positions = (start + block * length)[:, None] + jnp.arange(length)
+    tokens = ids if finished is None else jnp.concatenate([finished, ids], 1)
+    positions = (start + block * length - given)[:, None] + jnp.arange(
+        given + length)
     columns = jnp.arange(cache[0][0].shape[1])[None, :]
-    seen = (columns < start[:, None]) | ((columns >= slots) & (columns < at))
+    seen = (columns < start[:, None]) | (
+        (columns >= slots) & (columns < first))
     scale = cfg.head_dim ** -0.5
 
     def attend(index, q, k, v):
-        return block_attention(q, k, v, *cache[index], seen, scale)
+        return block_attention(q, k, v, *cache[index], seen, scale, length)
 
     x, entries, load = _forward(
-        params, cfg, ids, positions, attend, load,
+        params, cfg, tokens, positions, attend, load,
         None if valid is None else jnp.broadcast_to(
-            valid[:, None], ids.shape), head, interpret)
-    if commit:
+            valid[:, None], tokens.shape), length, interpret)
+    written = given + (length if commit else 0)
+    if written:
+        # the writes wait for the forward's last read of the cache: left
+        # to itself the chip's compiler copies a whole layer's keys
+        # before a fused forward's write (two copies a block, PR 41)
+        x, cache = jax.lax.optimization_barrier((x, cache))
         cache = tuple(
             tuple(jax.lax.dynamic_update_slice(
-                whole, entry.astype(whole.dtype), (0, at, 0, 0))
-                  for whole, entry in zip(layer, written))
-            for layer, written in zip(cache, entries))
-    return (logits_of(params, cfg, x) if head else None), cache, load
+                whole, entry[:, :written].astype(whole.dtype),
+                (0, first, 0, 0))
+                  for whole, entry in zip(layer, added))
+            for layer, added in zip(cache, entries))
+    return logits_of(params, cfg, x), cache, load
 
 
 # --- the generation's rule ---------------------------------------------------

@@ -31,19 +31,25 @@ each row's whole prompt blocks and returns no logits. The decode program
 a block starts as the mask id (the first one behind the `L mod B` ids of
 the prompt that did not fill a block), and inside the scan a bounded loop
 of **denoise** forwards runs the block's `rows x block_length` tokens
-against the cache **without writing it**, draws an id a position from the
-position's own logits (key: job, row, block, forward), and fixes the
-`block_length / denoising_steps` still-masked positions it is surest of
-(with a `confidence_threshold`: every one over it, if those are as many);
-when no row has a mask left in the block, or after `denoising_steps`
-forwards, one **commit** forward of the finished block writes its keys and
-values. So what a forward yields is a number to count: the program counts
-the forwards of either kind and the positions that took an id or were
-already fixed, on the device, and they come back with the ids
-(`swarm_block_forward_rows_total`, `swarm_block_slots_total`,
-`swarm_generated_tokens_total`; the envelope's `decode_steps` are the
-forwards the decode made, `forwards` says of which kind). `block_program`
-is one such forward with given ids, logits out: the comparison's.
+against the cache, draws an id a position from the position's own logits
+(key: job, row, block, forward), and fixes the `block_length /
+denoising_steps` still-masked positions it is surest of (with a
+`confidence_threshold`: every one over it, if those are as many), until no
+row has a mask left in the block, or after `denoising_steps` forwards. A
+finished block's keys and values are not in the cache yet, and no forward
+of its own puts them there: the next block's first denoise forward is a
+**fused** one, which takes the finished ids beside its own as `2 x
+block_length` positions a row, writes the finished block's keys and values
+and yields logits for its own positions only (models/sdar.py
+`block_step`, `finished=`); the pass's last block is never written, nobody
+reads it. So what a forward yields is a number to count: the program counts
+the forwards, those of them that were fused, and the positions that took an
+id or were already fixed, on the device, and they come back with the ids
+(`swarm_block_forward_rows_total`, `swarm_block_fused_commit_rows_total`,
+`swarm_block_slots_total`, `swarm_generated_tokens_total`; the envelope's
+`decode_steps` are the forwards the decode made, `forwards` says of which
+kind). `block_program` is one forward of a block alone with given ids,
+logits out, the cache written under `commit`: the comparison's.
 
 What a pass caches is the model's to say (`cache_bytes`: Kimi-K2 a latent
 a position a layer, K-EXAONE keys and values a position on its full
@@ -133,9 +139,15 @@ PASS_WINDOW_CACHE_BYTES = telemetry.gauge(
 BLOCK_FORWARD_ROWS = telemetry.counter(
     "swarm_block_forward_rows_total",
     "Real rows x forwards of a block decode, by model and kind (denoise: "
-    "a forward that reads the cache and may fix positions; commit: the "
-    "forward that writes a finished block's keys and values and yields "
-    "nothing)", ("model", "kind"))
+    "a forward that reads the cache and may fix positions, a fused one "
+    "included; commit: a forward of its own that writes a finished "
+    "block's keys and values and yields nothing, which the decode no "
+    "longer makes: it stays 0)", ("model", "kind"))
+BLOCK_FUSED_COMMIT_ROWS = telemetry.counter(
+    "swarm_block_fused_commit_rows_total",
+    "Real rows x the denoise forwards of a block decode that were fused: "
+    "the first forward of a block, which also writes the keys and values "
+    "of the finished block before it, by model", ("model",))
 GENERATED_TOKENS = telemetry.counter(
     "swarm_generated_tokens_total",
     "Ids a block decode handed back (real rows x new tokens), by model",
@@ -347,10 +359,12 @@ class TextGenerationPipeline:
                              denoising_steps: int, thresholded: bool):
         """`(params, cache, ids [rows, slots], lengths, job_keys,
         job_of_row, row_in_job, temperature, threshold, tally) -> (ids
-        [rows, new_tokens], tally, counts, cache)`: the scan over blocks,
-        in each the bounded loop of denoise forwards and the commit (the
-        last block is not committed: nobody reads it). `counts` are the
-        pass's (denoise forwards, commit forwards, positions of real rows
+        [rows, new_tokens], tally, counts, cache)`: the opening block's
+        bounded loop of denoise forwards, then the scan over the blocks
+        behind it, in each the fused forward that commits the block before
+        and the bounded loop of the later ones (the last block is not
+        committed: nobody reads it). `counts` are the pass's (denoise
+        forwards, those of them that were fused, positions of real rows
         unmasked, positions of real rows computed though fixed), counted
         on the device: under a threshold the forwards are data. The cache
         is donated, as the other decode's."""
@@ -376,56 +390,59 @@ class TextGenerationPipeline:
             valid = lengths > 0
             keys = jax.vmap(jax.random.fold_in)(
                 jax.random.wrap_key_data(job_keys)[job_of_row], row_in_job)
-            first_ids, first_masked = model.first_block(cfg, ids, lengths)
 
-            def block(number, cache, load, counts, commit: bool):
-                block_keys = jax.vmap(
-                    lambda key: jax.random.fold_in(key, number))(keys)
-                opening = number == 0
-                state = (jnp.int32(0),
-                         jnp.where(opening, first_ids, cfg.mask_token_id),
-                         jnp.where(opening, first_masked, True)
-                         & valid[:, None], load, counts)
+            def denoise(number, cache, state, finished=None):
+                """One forward of block `number` and what it fixes; with
+                `finished` a fused one, which writes the cache."""
+                forward, tokens, masked, load, counts = state
+                logits, cache, load = model.block_step(
+                    params, cfg, tokens, lengths, number, slots, cache,
+                    load, valid=valid, finished=finished)
+                forward_keys = jax.vmap(lambda key: jax.random.fold_in(
+                    jax.random.fold_in(key, number), forward))(keys)
+                drawn, confidence = draw(logits, forward_keys, temperature)
+                tokens, left = model.unmask(
+                    tokens, masked, drawn, confidence, count,
+                    threshold if thresholded else None)
+                took = jnp.sum(masked & ~left)
+                fixed = jnp.sum(valid[:, None] & ~masked)
+                return (forward + 1, tokens, left, load, counts + jnp.stack(
+                    [1, int(finished is not None), took, fixed])), cache
 
-                def unfinished(state):
-                    forward, _, masked, _, _ = state
-                    return (forward < denoising_steps) & jnp.any(masked)
+            def unfinished(state):
+                forward, _, masked, _, _ = state
+                return (forward < denoising_steps) & jnp.any(masked)
 
-                def denoise(state):
-                    forward, tokens, masked, load, counts = state
-                    logits, _, load = model.block_step(
-                        params, cfg, tokens, lengths, number, slots, cache,
-                        load, valid=valid)
-                    drawn, confidence = draw(logits, jax.vmap(
-                        lambda key: jax.random.fold_in(key, forward))(
-                            block_keys), temperature)
-                    tokens, left = model.unmask(
-                        tokens, masked, drawn, confidence, count,
-                        threshold if thresholded else None)
-                    took = jnp.sum(masked & ~left)
-                    fixed = jnp.sum(valid[:, None] & ~masked)
-                    return (forward + 1, tokens, left, load,
-                            counts + jnp.stack([1, 0, took, fixed]))
-
+            def block(number, tokens, masked, cache, load, counts,
+                      finished=None):
+                """Block `number` from `tokens` until no row has a mask
+                left in it or it has had its forwards. With `finished`
+                (the block before it, which the cache lacks) the first
+                forward is the fused one and runs whatever the masks: a
+                block behind another starts all masked. No later forward
+                writes the cache."""
+                state = (jnp.int32(0), tokens, masked & valid[:, None],
+                         load, counts)
+                if finished is not None:
+                    state, cache = denoise(number, cache, state, finished)
                 _, tokens, _, load, counts = jax.lax.while_loop(
-                    unfinished, denoise, state)
-                if commit:
-                    _, cache, load = model.block_step(
-                        params, cfg, tokens, lengths, number, slots, cache,
-                        load, valid=valid, commit=True, head=False)
-                    counts = counts + jnp.array([0, 1, 0, 0])
+                    unfinished,
+                    lambda state: denoise(number, cache, state)[0], state)
                 return tokens, cache, load, counts
 
-            def committed(carry, number):
-                tokens, *carry = block(number, *carry, commit=True)
-                return tuple(carry), tokens
+            def later(carry, number):
+                finished, *rest = carry
+                tokens, *rest = block(
+                    number, jnp.full_like(finished, cfg.mask_token_id),
+                    jnp.ones_like(finished, bool), *rest, finished=finished)
+                return (tokens, *rest), tokens
 
-            (cache, load, counts), out = jax.lax.scan(
-                committed, (cache, load, jnp.zeros((4,), jnp.int32)),
-                jnp.arange(blocks - 1))
-            last, cache, load, counts = block(
-                jnp.int32(blocks - 1), cache, load, counts, commit=False)
-            out = jnp.concatenate([out, last[None]]).transpose(1, 0, 2)
+            first, *rest = block(
+                jnp.int32(0), *model.first_block(cfg, ids, lengths), cache,
+                load, jnp.zeros((4,), jnp.int32))
+            (_, cache, load, counts), out = jax.lax.scan(
+                later, (first, *rest), jnp.arange(1, blocks))
+            out = jnp.concatenate([first[None], out]).transpose(1, 0, 2)
             # a row's first new id stands behind its prompt's given tail
             at = (lengths % length)[:, None] + jnp.arange(new_tokens)
             out = jnp.take_along_axis(out.reshape(rows, -1), at, axis=1)
@@ -516,7 +533,7 @@ class TextGenerationPipeline:
                 tuple(np.asarray(x) for x in tally)
                 for tally in (load, filled))
             if counts is not None:
-                denoise, commit, unmasked, idle = (
+                denoise, fused, unmasked, idle = (
                     int(x) for x in np.asarray(counts))
 
         def tally(pairs, sums, calls):
@@ -536,18 +553,15 @@ class TextGenerationPipeline:
             not runs(lengths[at:at + chunk_rows], start)
             for at in range(0, rows, chunk_rows)
             for start in range(0, slots, chunk_slots))
-        # expert-layer calls of either program: a forward whose logits
-        # nobody reads (a block model's prefill chunk and commit) stops
-        # before its last layer's experts
+        # expert-layer calls of either program: a block model's prefill
+        # chunk, whose logits nobody reads, stops before its last layer's
+        # experts; every forward of either decode runs them all (a fused
+        # forward's last layer for its own block)
         layers = cfg.expert_layers
-        if self.by_blocks:
-            forwards = denoise + commit
-            prefill_calls = (layers - 1) * (chunks - skipped)
-            decode_calls = layers * denoise + (layers - 1) * commit
-        else:
-            forwards = new_tokens - 1
-            prefill_calls = layers * (chunks - skipped)
-            decode_calls = layers * forwards
+        forwards = denoise if self.by_blocks else new_tokens - 1
+        prefill_calls = (layers - 1 if self.by_blocks else layers) * (
+            chunks - skipped)
+        decode_calls = layers * forwards
         routing = {
             **tally(pairs, sums, prefill_calls + decode_calls),
             "prefill": tally(before, before_sums, prefill_calls),
@@ -558,7 +572,9 @@ class TextGenerationPipeline:
         blocks = {}
         if self.by_blocks:
             BLOCK_FORWARD_ROWS.inc(real * denoise, kind="denoise", **label)
-            BLOCK_FORWARD_ROWS.inc(real * commit, kind="commit", **label)
+            # no commit is a forward of its own: the label stays, at 0
+            BLOCK_FORWARD_ROWS.inc(0, kind="commit", **label)
+            BLOCK_FUSED_COMMIT_ROWS.inc(real * fused, **label)
             GENERATED_TOKENS.inc(real * new_tokens, **label)
             BLOCK_SLOTS.inc(unmasked, kind="unmasked", **label)
             BLOCK_SLOTS.inc(idle, kind="idle", **label)
@@ -566,7 +582,8 @@ class TextGenerationPipeline:
                       "denoising_steps": steps,
                       "confidence_threshold": confidence_threshold,
                       "blocks": self.model.blocks_of(cfg, new_tokens),
-                      "forwards": {"denoise": denoise, "commit": commit}}
+                      "forwards": {"denoise": denoise, "commit": 0,
+                                   "fused": fused}}
         EXPERT_PAIRS.inc(routing["pairs"], **label)
         ROUTED_TOKENS.inc(routing["routed"], **label)
         EXPERT_PAIRS_MAX.inc(routing["pairs_max"], **label)

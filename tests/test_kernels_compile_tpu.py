@@ -164,6 +164,10 @@ def test_admission_rule_stays_inside_the_scoped_vmem_limit():
     pytest.param(1024, 2048, 768, True, 128, id="sdar-block-gate-up"),
     pytest.param(1024, 768, 2048, False, 128, id="sdar-block-down"),
     pytest.param(4096, 2048, 768, True, 128, id="sdar-prefill-gate-up"),
+    # a fused forward's 256 rows x 8 positions (ISSUE 41: the finished
+    # block beside the new one), 128 pairs an expert on average
+    pytest.param(2048, 2048, 768, True, 128, id="sdar-fused-gate-up"),
+    pytest.param(2048, 768, 2048, False, 128, id="sdar-fused-down"),
 ])
 def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated, held):
     """The grouped kernel at the row buffer's worst-case size, its grid's

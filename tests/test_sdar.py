@@ -9,7 +9,9 @@ layers), against the plain reference
     the reference's one full forward pass, by logits: lengths with every `L
     mod B`, whole rows a chunk and position chunks;
 (b) a forward without `commit` leaves the cache, and the next block's
-    logits, as they were;
+    logits, as they were; a fused forward (ISSUE 41: the finished block
+    beside the new one, eight positions a row) writes what a commit of
+    the finished block writes and yields the new block's logits;
 (c) the un-masking rule against the plain one: static at 1, 2 and 4
     forwards a block, under a threshold with and without enough positions
     over it, a first block with a given tail;
@@ -169,6 +171,67 @@ def test_a_forward_without_commit_changes_nothing_a_later_block_reads(pipe):
     assert np.array_equal(run(noise=True), run(noise=False))
 
 
+@pytest.mark.parametrize("block", [1, 3], ids=["first_fused", "a_later_one"])
+def test_a_fused_forward_is_a_commit_and_a_forward_apart(pipe, block):
+    """`block_step(finished=)` on block `block`: the cache columns of
+    block `block - 1` of every layer are what `block_program(commit=True)`
+    writes for it, the logits those of `block_program(commit=False)` run
+    after that commit; rows of every tail, one shorter than a block and a
+    padding row; no other column is written, and the tally is the two
+    forwards' summed."""
+    rows, slots, blocks = 6, 16, 4
+    positions = slots + blocks * B
+    rng = np.random.default_rng(40 + block)
+    lengths = np.array([16, 13, 10, 7, 2, 0], np.int32)
+    ids = rng.integers(0, CFG.vocab_size, (rows, slots)).astype(np.int32)
+    given, _ = _given(rng, ids, lengths, blocks)
+    peek, commit = (pipe.block_program(rows, slots, positions, flag)
+                    for flag in (False, True))
+    cache, _ = pipe.prefill_program(rows, slots, positions)(
+        pipe.params, ids, lengths)
+    for earlier in range(block - 1):
+        _, cache = commit(pipe.params, cache, given[:, earlier], lengths,
+                          earlier)
+    # columns nobody wrote hold a number a write would change
+    cache = jax.tree_util.tree_map(
+        lambda x: x.at[:, slots + (block - 1) * B:].set(7.0), cache)
+    before = jax.tree_util.tree_map(np.asarray, cache)
+    valid = jnp.asarray(lengths > 0)
+
+    fused = jax.jit(lambda cache, finished, tokens: sdar.block_step(
+        pipe.params, CFG, tokens, jnp.asarray(lengths), block, slots, cache,
+        sdar.empty_load(CFG), valid=valid, finished=finished))
+    got_logits, got_cache, got_load = fused(
+        cache, given[:, block - 1], given[:, block])
+    _, want_cache = commit(pipe.params, cache, given[:, block - 1], lengths,
+                           block - 1)
+    want_logits, _ = peek(pipe.params, want_cache, given[:, block], lengths,
+                          block)
+    real = lengths > 0
+    got, want = np.asarray(got_logits)[real], np.asarray(want_logits)[real]
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 2e-5
+    written = slice(slots + (block - 1) * B, slots + block * B)
+    for layer in range(CFG.num_hidden_layers):
+        for old, new, wanted in zip(before[layer], got_cache[layer],
+                                    want_cache[layer]):
+            new, wanted = np.asarray(new), np.asarray(wanted)
+            np.testing.assert_allclose(new[:, written], wanted[:, written],
+                                       atol=2e-5)
+            assert not np.array_equal(new[:, written], old[:, written])
+            # what the rows see already, and what no row may see yet
+            assert np.array_equal(new[:, :written.start],
+                                  old[:, :written.start])
+            assert np.array_equal(new[:, written.stop:],
+                                  old[:, written.stop:])
+    # the tally: the finished block's positions are routed on every layer
+    # but the last, the new block's on every layer
+    pairs, sums = (np.asarray(x) for x in got_load)
+    routed = int(real.sum()) * B * CFG.num_experts_per_tok
+    layers = CFG.expert_layers
+    assert int(sums[0]) == routed * (2 * layers - 1) == int(pairs.sum())
+    assert int(pairs[-1].sum()) == routed
+
+
 # --- the un-masking rule -----------------------------------------------------
 
 
@@ -299,21 +362,29 @@ def test_the_served_ids_are_a_plain_loop_over_the_block_program(
     assert got == want
     config = served[0][1]
     blocks = sdar.blocks_of(CFG, new_tokens)
-    assert config["forwards"] == {"denoise": forwards, "commit": blocks - 1}
-    assert config["decode_steps"] == forwards + blocks - 1
+    # no commit is a forward of its own: every block behind the opening
+    # one commits the block before it inside its first forward
+    assert config["forwards"] == {"denoise": forwards, "commit": 0,
+                                  "fused": blocks - 1}
+    assert config["decode_steps"] == forwards
     assert (config["block_length"], config["denoising_steps"],
             config["blocks"]) == (B, steps, blocks)
     if threshold is None:
         assert forwards == blocks * steps
     else:
         assert forwards < blocks * steps  # the threshold saved forwards
-    # a commit stops before its last layer's experts
+    # a fused forward's last layer runs its experts for the new block
     layers = CFG.expert_layers
-    assert config["routing"]["decode"]["calls"] == (
-        layers * forwards + (layers - 1) * (blocks - 1))
-    # every expert is held: every routed pair is computed
+    assert config["routing"]["decode"]["calls"] == layers * forwards
+    # every expert is held: every routed pair is computed; the finished
+    # blocks' positions are routed on every layer but the last, as a
+    # commit's were
     routing = config["routing"]
     assert routing["pairs"] == routing["routed"] > 0
+    real = sum(len(request["prompt_ids"]) for request in requests)
+    assert routing["decode"]["routed"] == real * B * (
+        layers * forwards + (layers - 1) * (blocks - 1)
+    ) * CFG.num_experts_per_tok
     # alone, among other batchmates, the same ids for the same key
     alone = pipe.run_batched(
         requests[1:], max_new_tokens=new_tokens, temperature=temperature,

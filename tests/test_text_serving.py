@@ -414,8 +414,11 @@ def test_block_decode_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
     """`test/tiny-sdar`: three jobs of two denoise forwards a block are
     one gang and one pass, a fourth of four forwards a block rides alone;
     JSON artifacts of `max_new_tokens` ids a row, the envelope says what
-    the forwards were, and one seed gives one answer among other
-    batchmates."""
+    the forwards were (no commit is a forward of its own: every block
+    behind the first commits the one before it inside its first forward),
+    the benchmark's three readers read the same from the scrape, and one
+    seed gives one answer among other batchmates."""
+    from benchmark import harness
     from chiaswarm_tpu import worker as worker_module
     from chiaswarm_tpu.hive_server.harness import LocalSwarm
     from chiaswarm_tpu.settings import Settings
@@ -427,6 +430,7 @@ def test_block_decode_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
             sdaas_token="t", worker_name="w", hive_port=0, metrics_port=0))
         await swarm.start()
         try:
+            opened = harness.scrape()
             ids = [await swarm.submit(_block_job(
                 id=f"block-{n}", seed=100 + n, denoising_steps=2))
                 for n in range(3)]
@@ -439,11 +443,12 @@ def test_block_decode_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
             blobs = [await swarm.artifact(
                 status["result"]["artifacts"]["primary"]["href"])
                 for status in done]
-            return done, blobs
+            return done, blobs, {"scrape_open": opened,
+                                 "scrape_close": harness.scrape()}
         finally:
             await swarm.stop()
 
-    done, blobs = asyncio.run(scenario())
+    done, blobs, record = asyncio.run(scenario())
     configs = [status["result"]["pipeline_config"] for status in done]
     assert all(status["status"] == "done" and status["attempts"] == 1
                for status in done)
@@ -455,9 +460,9 @@ def test_block_decode_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
         steps = config["denoising_steps"]
         # a first block behind a given tail may need fewer forwards
         forwards = config["forwards"]
-        assert forwards["commit"] == blocks - 1
+        assert (forwards["commit"], forwards["fused"]) == (0, blocks - 1)
         assert (blocks - 1) * steps < forwards["denoise"] <= blocks * steps
-        assert config["decode_steps"] == sum(forwards.values())
+        assert config["decode_steps"] == forwards["denoise"]
         assert (config["block_length"], config["blocks"]) == (4, blocks)
         names = {span["name"] for span in config["spans"]}
         assert {"pass", "prefill", "decode", "readback",
@@ -469,6 +474,32 @@ def test_block_decode_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
         assert len(rows) == 3 and all(len(row) == 6 for row in rows)
         assert all(0 <= i < 128 for row in rows for i in row)
     assert blobs[4] == blobs[0] and blobs[3] != blobs[0]
+    # the three passes (of 9, 3 and 3 rows) as the benchmark's readers see
+    # them in what `/metrics` prints
+    passes = [configs[0], configs[3], configs[4]]
+    forward_rows = sum(config["pass_rows"] * config["forwards"]["denoise"]
+                       for config in passes)
+    read = {name: harness.load_reader("layer_metrics", name) for name in (
+        "tokens_per_forward", "commit_forward_share", "idle_slot_share")}
+    assert read["commit_forward_share"](record) == 0.0
+    assert read["tokens_per_forward"](record) == pytest.approx(
+        15 * 6 / forward_rows)
+    assert 0.0 < read["idle_slot_share"](record) < 100.0
+    model = configs[0]["model_name"]
+    close = record["scrape_close"]
+    assert f"{model},commit" in close["swarm_block_forward_rows_total"]
+    assert harness.counter(
+        close, "swarm_block_fused_commit_rows_total", model) - harness.counter(
+            record["scrape_open"], "swarm_block_fused_commit_rows_total",
+            model) == 15 * (blocks - 1)
+    # the denoised block's positions alone (each takes its id once): a
+    # fused forward's finished positions are in neither kind
+    unmasked = harness.counter(
+        close, "swarm_block_slots_total", f"{model},unmasked"
+    ) - harness.counter(record["scrape_open"], "swarm_block_slots_total",
+                        f"{model},unmasked")
+    assert unmasked == 5 * sum(
+        4 * blocks - len(row) % 4 for row in _block_job()["prompt_ids"])
 
 
 def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
