@@ -68,7 +68,9 @@ FAMILY_ACT_GB_PER_IMAGE: dict[str, float] = {
 # a row costs, a kind of layer, `min(size, the kind's window or size)`
 # positions of `bytes a position` (`cache_layers`: (bytes a position summed
 # over the layers of the kind, the window they keep or 0 for every
-# position)).
+# position)), and, where the family has layers that keep a recurrent state
+# and no keys, `row_bytes`: what those layers hold a row whatever its
+# positions, so that a row never costs nothing however short it is.
 # kimi_k2 is one chip's share of a 32-chip expert-parallel deployment
 # (models/kimi.py KIMI_K2_EP32): 4.85 B parameters in bf16 = 9.70 GB; a
 # position is 576 values x 2 bytes on each of 7 layers, all kept whole; the
@@ -91,6 +93,18 @@ FAMILY_ACT_GB_PER_IMAGE: dict[str, float] = {
 # copies of them and its random bits, the expert buffer; the columns a
 # pass's last committed block may overhang `prompt slots + new tokens` by
 # are in it too).
+# qwen3_next is one of 4 chips that share each layer, one of 6 pipeline
+# stages (models/qwen3_next.py QWEN3_NEXT_80B_EP4): 3.667 B parameters =
+# 7.33 GB; each of the 6 linear layers holds a row a float32 state of 32
+# heads x 128 x 128 (2,097,152 B) and a convolution's tail of 3 x 8192
+# values x 2 bytes (49,152 B): 12,877,824 B a row whatever its positions; a
+# position is a key and a value on 2 heads of 256 x 2 bytes = 2048 B on
+# each of the 2 full layers, kept whole; the working set is what the
+# compile for a described v5e counted beside weights and cache for the
+# 256-row programs (1.36 GB of temporaries for the prefill, a chunk's
+# float32 operands of the chunk rule among them, 0.60 GB for the decode:
+# benchmark/compile_check.py, PERF.md; the chip's own peak lies 0.12 GB
+# over weights and cache).
 # The families are coalesce.py `TEXT_FAMILIES`' (the jax-free table of
 # their names), one entry each.
 SEQUENCE_FAMILIES: dict[str, dict] = {
@@ -100,6 +114,9 @@ SEQUENCE_FAMILIES: dict[str, dict] = {
                    "cache_layers": ((4096.0, 0), (16384.0, 128))},
     "sdar_moe": {"params_gb": 8.12, "working_gb": 3.9,
                  "cache_layers": ((12288.0, 0),)},
+    "qwen3_next": {"params_gb": 6.83, "working_gb": 1.5,
+                   "cache_layers": ((4096.0, 0),),
+                   "row_bytes": 12877824.0},
 }
 # the cached positions one pass holds at most, whatever the memory left:
 # 256 rows of 512 positions. A pass is budgeted in positions and not in
@@ -116,12 +133,13 @@ SEQUENCE_REFERENCE_POSITIONS = 512
 
 def sequence_row_bytes(family: str, positions: int) -> float:
     """What one row of `positions` cached positions holds, every layer by
-    its kind."""
+    its kind: the positions it keeps, and what it holds a row whatever
+    they are."""
     positions = max(int(positions), 1)
-    return sum(per_position * (min(positions, window) if window
-                               else positions)
-               for per_position, window
-               in SEQUENCE_FAMILIES[family]["cache_layers"])
+    costs = SEQUENCE_FAMILIES[family]
+    return costs.get("row_bytes", 0.0) + sum(
+        per_position * (min(positions, window) if window else positions)
+        for per_position, window in costs["cache_layers"])
 
 
 def sequence_family_positions() -> dict[str, int]:
@@ -184,7 +202,7 @@ def _family_key(model_name: str) -> str:
 # other pipeline gives any `test/` name its tiny preset
 _PUBLISHED_TEST_FAMILIES = frozenset(
     {"sd15", "sd21", "sdxl", "sdxl_refiner", "flux", "kimi_k2",
-     "exaone_moe"})
+     "exaone_moe", "sdar_moe", "qwen3_next"})
 
 
 def _is_stand_in(model_name: str) -> bool:

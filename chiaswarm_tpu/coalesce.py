@@ -119,6 +119,7 @@ TEXT_FAMILIES: dict[str, dict] = {
     "exaone_moe": {"name": "exaone", "wire": "ExaoneMoeForCausalLM"},
     "sdar_moe": {"name": "sdar", "wire": "SdarMoeForCausalLM",
                  "block_length": 4},
+    "qwen3_next": {"name": "qwen3-next", "wire": "Qwen3NextForCausalLM"},
 }
 # the only `parameters` keys a batchable text job may carry, and those a
 # job of a family that decodes by blocks may carry besides
@@ -203,7 +204,9 @@ def text_family_of(model_name: str) -> str | None:
     """The text family a model's name tells, None for any other model."""
     name = model_name.lower()
     for family, what in TEXT_FAMILIES.items():
-        if what["name"] in name:
+        # the family's word, or its key (a worker reckons a family's
+        # appetite with the key as the model's name)
+        if what["name"] in name or family in name:
             return family
     return None
 

@@ -1,16 +1,19 @@
 """What the language models with routed sparse experts share
-(models/kimi.py, models/exaone.py, models/sdar.py): the norm, the SwiGLU,
-the router under either of two rules, the held experts' grouped matmul, the
-tally of how the routing fell, the seeded init of a parameter tree and the
-head.
+(models/kimi.py, models/exaone.py, models/sdar.py, models/qwen3_next.py):
+the norm, the SwiGLU, the router under either of two rules, the held
+experts' grouped matmul, the tally of how the routing fell, the seeded init
+of a parameter tree and the head.
 
 `cfg` is the model's own config dataclass; what is read of it here is
 `scoring_func`, `num_experts_per_tok`, `routed_scaling_factor`,
 `experts_held` ((first, count): the routed experts this chip holds of
-every layer), `expert_layers`, `first_k_dense_replace` and `rms_norm_eps`.
-A layer's `moe` holds `router` [hidden, all the experts], `experts`
-(`gate`, `up`, `down`, the held ones stacked) and, where the model has
-them, `router_bias` and `shared`.
+every layer), `expert_layers`, `first_k_dense_replace`, `rms_norm_eps` and,
+where it has one, `zero_centred_norms` (the norms' weights are offsets from
+one: Qwen3-Next). A layer's `moe` holds `router` [hidden, all the
+experts], `experts` (`gate`, `up`, `down`, the held ones stacked) and,
+where the model has them, `router_bias`, `shared` and `shared_gate`
+([hidden, 1]: the shared expert's output is multiplied by
+`sigmoid(h . w)`, Qwen3-Next).
 
 The experts: scores `s` in float32 over ALL the model's experts, by the
 config's `scoring_func`: `sigmoid(h W_g)`, the `k` largest of `s + b`
@@ -18,9 +21,10 @@ chosen (`b` the layer's correction bias: Kimi-K2, K-EXAONE), or
 `softmax(h W_g)`, the `k` largest chosen (SDAR: no bias). Either way the
 weights are `s_i / sum_chosen s * scale` (normalised over all the chosen,
 held here or not), and the layer adds `sum_{chosen and held} w_i E_i(h)`
-and, where it has a shared expert, `E_shared(h)`. What the absent experts
-would add is left out: on a chip that is one of many sharing the layer, the
-exchange that brings it is not run and nothing stands in for it. The held
+and, where it has a shared expert, `E_shared(h)` (times its gate where
+it has one). What the absent experts would add is left out: on a chip that
+is one of many sharing the layer, the exchange that brings it is not run
+and nothing stands in for it. The held
 experts' part is one grouped matmul over the pairs sorted by expert
 (`ops.expert_matmul`): no dropped token, no capacity factor.
 """
@@ -35,19 +39,41 @@ import jax.numpy as jnp
 from ..ops.expert_matmul import expert_matmul, plan, row_tile
 
 
+def leaf_name(path) -> str:
+    return str(getattr(path[-1], "key", path[-1]))
+
+
 def leaf_rule(path, shape) -> tuple[float, float]:
     """(std, shift) of one leaf's seeded values from its name: ones for a
-    norm, N(0, 0.01^2) for the router's correction bias, the embedding by
-    its width, else a normal scaled by fan-in (the rows of the one matrix:
+    norm's weight and N(0, 0.1^2) for a norm's offset from one (a
+    zero-centred norm multiplies by `1 + offset`: seeded at zero, a
+    forgotten `1 +` would pass every test), N(0, 0.01^2) for the router's
+    correction bias, the embedding by its width, a unit normal for `A_log`
+    (which `finish_leaf` maps to the published `log U(0, 16)`), ones for
+    `dt_bias`, else a normal scaled by fan-in (the rows of the one matrix:
     a stack of experts is scaled expert by expert)."""
-    name = str(getattr(path[-1], "key", path[-1]))
-    if name.endswith("norm"):
+    name = leaf_name(path)
+    if name.endswith("norm") or name == "dt_bias":
         return 0.0, 1.0
+    if name.endswith("norm_offset"):
+        return 0.1, 0.0
     if name == "router_bias":
         return 0.01, 0.0
+    if name == "A_log":
+        return 1.0, 0.0
     if name == "embed":
         return 1.0, 0.0
     return 1.0 / math.sqrt(shape[-2]), 0.0
+
+
+def finish_leaf(path, value):
+    """What `leaf_rule`'s scaled normal cannot say: `A_log` is `log A`
+    with `A` uniform over (0, 16), the published init (a unit normal
+    through its own distribution function is uniform over (0, 1))."""
+    if leaf_name(path) != "A_log":
+        return value
+    uniform = jax.scipy.stats.norm.cdf(value.astype(jnp.float32))
+    return jnp.log(16.0 * jnp.clip(uniform, 1e-6, 1.0)).astype(value.dtype)
 
 
 def init_leaves(shapes, key) -> dict:
@@ -59,15 +85,20 @@ def init_leaves(shapes, key) -> dict:
     out = []
     for (path, leaf), k in zip(leaves, jax.random.split(key, len(leaves))):
         std, shift = leaf_rule(path, leaf.shape)
-        out.append((jax.random.normal(k, leaf.shape, jnp.float32) * std
-                    + shift).astype(leaf.dtype))
+        out.append(finish_leaf(path, (
+            jax.random.normal(k, leaf.shape, jnp.float32) * std
+            + shift).astype(leaf.dtype)))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def rms_norm(x, weight, eps: float):
+def rms_norm(x, weight, eps: float, zero_centred: bool = False):
+    """`zero_centred`: `weight` is the offset from one, the norm multiplies
+    by `1 + weight` (the model's config says which its norms are)."""
     x32 = x.astype(jnp.float32)
     scaled = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (scaled * weight.astype(jnp.float32)).astype(x.dtype)
+    weight = weight.astype(jnp.float32)
+    return (scaled * (1.0 + weight if zero_centred else weight)).astype(
+        x.dtype)
 
 
 def dot(x, w):
@@ -142,7 +173,12 @@ def expert_layer(p, cfg, h, valid=None, interpret: bool = False):
                        jnp.sum((sizes > 0).astype(jnp.int32))])
     out = routed.astype(h.dtype)
     if "shared" in p:
-        out = out + swiglu(p["shared"], h)
+        shared = swiglu(p["shared"], h)
+        if "shared_gate" in p:
+            gate = jnp.dot(h, p["shared_gate"],
+                           preferred_element_type=jnp.float32)
+            shared = (shared * jax.nn.sigmoid(gate)).astype(h.dtype)
+        out = out + shared
     return out, (sizes, stats)
 
 
@@ -173,5 +209,8 @@ def tally(load, index: int, cfg, told):
 def logits_of(params, cfg, x):
     """Final norm and the head over the held rows of the vocabulary,
     float32."""
-    h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    if getattr(cfg, "zero_centred_norms", False):
+        h = rms_norm(x, params["final_norm_offset"], cfg.rms_norm_eps, True)
+    else:
+        h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)

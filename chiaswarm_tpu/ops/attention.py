@@ -173,7 +173,8 @@ def dot_product_attention(q, k, v, scale: float | None = None,
     queries and a head is whole lanes (ops/banded_attention.py; one chip:
     under a mesh scope it is not split and the XLA path runs). Any other
     causal call is the XLA path's whatever its length (latent attention's
-    prefill: 192-wide q.k, 128-wide v).
+    prefill: 192-wide q.k, 128-wide v; Qwen3-Next's gated attention: heads
+    of 256, which the banded kernel's blocks of 128 lanes cannot tile).
     """
     if (window or span) and not causal:
         raise ValueError("a window or a span is a causal band's: "

@@ -1,8 +1,9 @@
 """Text completion from token ids: one jitted prefill and one jitted cached
 decode over a resident language model, whichever family the name resolves
 to (`_MODELS`: Kimi-K2, models/kimi.py; K-EXAONE, models/exaone.py; SDAR,
-models/sdar.py). There are two ways to decode, and the model's module says
-which is its own by what it has: a `step` (a token a row a forward, below)
+models/sdar.py; Qwen3-Next, models/qwen3_next.py). There are two ways to
+decode, and the model's module says which is its own by what it has: a
+`step` (a token a row a forward, below)
 or a `block_step` (a block of tokens a row over several forwards, "By
 blocks" further down).
 
@@ -54,9 +55,12 @@ logits out, the cache written under `commit`: the comparison's.
 What a pass caches is the model's to say (`cache_bytes`: Kimi-K2 a latent
 a position a layer, K-EXAONE keys and values a position on its full
 layers and a ring of its window on the others, SDAR keys and values a
-position on every layer): the whole is
+position on every layer, Qwen3-Next keys and values a position on every
+fourth layer and on the others a recurrent state and a convolution's tail
+a row, which do not grow with the positions): the whole is
 `swarm_pass_cache_bytes{model}`, the rings' part
-`swarm_pass_window_cache_bytes{model}`. A pass counts its prompt slots
+`swarm_pass_window_cache_bytes{model}`, the states' part
+`swarm_pass_state_bytes{model}`. A pass counts its prompt slots
 (`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
 that fills rows to the bucket and was computed all the same, and the
 padding `skipped`: chunks the model's prefill did not run because none of
@@ -69,7 +73,7 @@ No tokenizer: ids travel on the wire, and there is no stop token, every
 row generates `max_new_tokens`. `test/` names are seeded weights: `tiny` in
 the name is the family's tiny preset, any other the chip's share of the
 deployment at the published widths (`KIMI_K2_EP32`, `EXAONE_236B_EP8`,
-`SDAR_30B_PP8`;
+`SDAR_30B_PP8`, `QWEN3_NEXT_80B_EP4`;
 `weights=` hands the tree in already on the chip, as `FluxPipeline` takes
 it: the host init of billions of parameters is minutes).
 """
@@ -88,7 +92,7 @@ import numpy as np
 
 from .. import telemetry
 from ..coalesce import checked_denoising_steps, prompt_slots
-from ..models import exaone, kimi, sdar
+from ..models import exaone, kimi, qwen3_next, sdar
 from ..ops import platform
 from ..parallel.mesh import make_mesh, replicated
 from ..registry import _auto_family, register_family
@@ -112,7 +116,8 @@ PREFILL_CHUNK_TOKENS = 4096
 # `blocks_of` and `cache_positions` (prefill returns no logits, a forward
 # feeds a row a block). The families are chips/requirements.py
 # `SEQUENCE_FAMILIES`' (tests/test_text_serving.py holds the lists equal)
-_MODELS = {"kimi_k2": kimi, "exaone_moe": exaone, "sdar_moe": sdar}
+_MODELS = {"kimi_k2": kimi, "exaone_moe": exaone, "sdar_moe": sdar,
+           "qwen3_next": qwen3_next}
 
 EXPERT_PAIRS = telemetry.counter(
     "swarm_expert_pairs_total",
@@ -136,6 +141,11 @@ PASS_WINDOW_CACHE_BYTES = telemetry.gauge(
     "The part of swarm_pass_cache_bytes that is rings of a window (rows "
     "x window, the layers that attend to a window only), by model",
     ("model",))
+PASS_STATE_BYTES = telemetry.gauge(
+    "swarm_pass_state_bytes",
+    "The part of swarm_pass_cache_bytes that is recurrent state and "
+    "convolution tail (rows x the linear-attention layers: it does not "
+    "grow with the positions), by model", ("model",))
 BLOCK_FORWARD_ROWS = telemetry.counter(
     "swarm_block_forward_rows_total",
     "Real rows x forwards of a block decode, by model and kind (denoise: "
@@ -242,10 +252,12 @@ class TextGenerationPipeline:
 
     # --- programs ---
 
-    def cache_bytes(self, rows: int, positions: int) -> tuple[int, int]:
-        """(bytes of a pass's cache, the rings' part of it)."""
-        return self.model.cache_bytes(self.config, rows, positions,
-                                      self.dtype.itemsize)
+    def cache_bytes(self, rows: int, positions: int) -> tuple[int, int, int]:
+        """(bytes of a pass's cache, the rings' part of it, the recurrent
+        states' part of it: none where the model's module names none)."""
+        whole, rings, *state = self.model.cache_bytes(
+            self.config, rows, positions, self.dtype.itemsize)
+        return whole, rings, (state[0] if state else 0)
 
     def _program(self, key: tuple, build):
         with self._jit_lock:
@@ -274,9 +286,10 @@ class TextGenerationPipeline:
         chunk = prefill_chunk(rows, slots, model.POSITION_CHUNKS)
 
         def build():
-            whole, rings = self.cache_bytes(rows, positions)
+            whole, rings, state = self.cache_bytes(rows, positions)
             PASS_CACHE_BYTES.set(whole, model=self.model_name)
             PASS_WINDOW_CACHE_BYTES.set(rings, model=self.model_name)
+            PASS_STATE_BYTES.set(state, model=self.model_name)
             return jax.jit(lambda params, ids, lengths: model.prefill(
                 params, cfg, ids, lengths, positions, *chunk))
 
@@ -593,7 +606,8 @@ class TextGenerationPipeline:
         PREFILL_SLOTS.inc(rows * slots - prompt_tokens - skipped_slots,
                           kind="padding", **label)
         PREFILL_SLOTS.inc(skipped_slots, kind="skipped", **label)
-        cache_bytes, cache_bytes_window = self.cache_bytes(rows, positions)
+        cache_bytes, cache_bytes_window, cache_bytes_state = self.cache_bytes(
+            rows, positions)
         results, at = [], 0
         for request in requests:
             n = len(request["prompt_ids"])
@@ -614,6 +628,7 @@ class TextGenerationPipeline:
                 "prefill_chunks_skipped": skipped,
                 "cache_bytes": cache_bytes,
                 "cache_bytes_window": cache_bytes_window,
+                "cache_bytes_state": cache_bytes_state,
                 "routing": routing,
                 "timings": dict(timings)}))
             at += n

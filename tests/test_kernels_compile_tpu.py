@@ -1,4 +1,5 @@
-"""The Pallas kernels (the UNet's two and the grouped expert matmul), one
+"""The Pallas kernels (the UNet's two, the grouped expert matmul and the
+gated delta rule's step), one
 MMDiT block across the four chips of the slice, and K-EXAONE's prefill
 program at its cell's shape,
 compiled for a described v5e chip at the published widths (no chip attached: the TPU compiler is installed here and
@@ -187,6 +188,31 @@ def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated, held):
             _shape(v5e, (rows // tm,), jnp.int32),
             _shape(v5e, (), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,heads,keys,values", [
+    # Qwen3-Next's linear layers at the cell's pass: a row's 32 matrices of
+    # [128, 128] float32 a grid step, 2 MB in and 2 MB out
+    pytest.param(256, 32, 128, 128, id="qwen3next-decode-256x32x128x128"),
+    pytest.param(8, 32, 128, 128, id="qwen3next-decode-8-rows"),
+])
+def test_gated_delta_step_compiles_for_v5e(v5e, rows, heads, keys, values):
+    """The step kernel as the decode calls it: float32 state in and out
+    under one buffer, a head's query and key as columns."""
+    from chiaswarm_tpu.ops.gated_delta_rule import _step_pallas
+
+    f32 = jnp.float32
+    compiled = _step_pallas.lower(
+        _shape(v5e, (rows, heads, keys), f32),
+        _shape(v5e, (rows, heads, keys), f32),
+        _shape(v5e, (rows, heads, values), f32),
+        _shape(v5e, (rows, heads), f32), _shape(v5e, (rows, heads), f32),
+        _shape(v5e, (rows, heads, keys, values), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_step" in text
+    # nothing of the state's size beside the state itself
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * (
+        heads * keys * values) // 8
 
 
 def test_flux_double_block_overlaps_its_collectives_on_four_v5e(

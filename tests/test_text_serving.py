@@ -98,8 +98,10 @@ def test_rows_that_differ_in_denoising_steps_do_not_share_a_pass(change,
     {"model_name": "test/tiny-kimi", "denoising_steps": 2},
     {"model_name": "test/tiny-exaone",
      "parameters": {"confidence_threshold": 0.9}},
+    {"model_name": "test/tiny-qwen3-next", "denoising_steps": 2},
 ], ids=["no_divisor", "zero", "over_the_block", "a_string", "a_bool",
-        "threshold_no_number", "kimi_takes_none", "exaone_takes_none"])
+        "threshold_no_number", "kimi_takes_none", "exaone_takes_none",
+        "qwen3_next_takes_none"])
 def test_denoising_steps_that_cannot_be_have_no_key_and_the_formatters_error(
         broken):
     from chiaswarm_tpu.job_arguments import format_txt2txt_args
@@ -133,7 +135,8 @@ def test_the_lists_of_text_families_agree():
 
     families = set(coalesce.TEXT_FAMILIES)
     assert families == set(requirements.SEQUENCE_FAMILIES) == set(
-        text_generation._MODELS) == {"kimi_k2", "exaone_moe", "sdar_moe"}
+        text_generation._MODELS) == {"kimi_k2", "exaone_moe", "sdar_moe",
+                                     "qwen3_next"}
     registry._ensure_builtin_families()
     assert families <= set(registry._FACTORIES)
     for family, what in coalesce.TEXT_FAMILIES.items():
@@ -142,6 +145,13 @@ def test_the_lists_of_text_families_agree():
         assert registry._auto_family(name) == family
         assert requirements._family_key(name) == family
         assert coalesce.text_family_of(name.upper()) == family
+        # the key resolves to itself as a model's name: a worker reckons
+        # the appetite it advertises with it (on a chip by the family's
+        # own table, and not as a 1.8 GB diffusion model's)
+        assert requirements._family_key(family) == family
+        assert requirements.coalesce_rows_limit(
+            _Slice(), family, requirements.SEQUENCE_REFERENCE_POSITIONS
+        ) == 256
         # a family decodes by blocks in both tables or in neither
         model = text_generation._MODELS[family]
         assert hasattr(model, "block_step") == ("block_length" in what)
@@ -232,6 +242,77 @@ def test_a_rows_bytes_are_reckoned_by_layer_kind():
     # a chip with little left beside the weights holds fewer positions
     assert 0 < requirements.pass_positions_limit(
         _Slice(gib=10.5), "exaone_moe") < 131072
+
+
+def test_a_row_of_a_recurrent_state_costs_bytes_whatever_its_positions():
+    """Qwen3-Next's share: six linear layers hold a row a float32 state
+    and a convolution's tail whatever the row's length, two full layers
+    4096 B a position."""
+    name = "test/Qwen3-Next-80B-A3B-Instruct"
+    assert requirements._family_key(name) == "qwen3_next"
+    costs = requirements.SEQUENCE_FAMILIES["qwen3_next"]
+    state = 6 * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
+    assert costs["row_bytes"] == state == 12877824
+    assert costs["cache_layers"] == ((2 * 2 * 2 * 256 * 2, 0),)
+    for positions in (0, 1, 16, 512, 16512, 262144):
+        assert requirements.sequence_row_bytes("qwen3_next", positions) == (
+            state + 4096 * max(positions, 1)) > state
+    # the other families keep nothing a row: their tables read as before
+    assert requirements.sequence_row_bytes("kimi_k2", 1) == 8064
+    # what the model's own module counts for the cell's pass
+    from chiaswarm_tpu.models import qwen3_next
+
+    cfg = qwen3_next.QWEN3_NEXT_80B_EP4
+    assert qwen3_next.cache_bytes(cfg, 256, 512, 2)[0] == 256 * (
+        requirements.sequence_row_bytes("qwen3_next", 512))
+    import jax
+
+    shapes = qwen3_next.param_shapes(cfg, jax.numpy.bfloat16)
+    held = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(shapes))
+    assert costs["params_gb"] == pytest.approx(held / 2 ** 30, abs=0.01)
+    # 256 rows of 512 fit beside weights and working set, and the pass is
+    # budgeted in positions as any family's
+    free = 15.75 - costs["params_gb"] - costs["working_gb"]
+    per_row = (state + 4096 * 512) / (1 << 30)
+    assert requirements.fit_batch(_Slice(), name, 10 ** 9, 512) == int(
+        free / per_row) >= 256
+    assert requirements.fit_batch(_Slice(), name, 256, 512) == 256
+    assert requirements.coalesce_rows_limit(_Slice(), name, 512) == 256
+    assert requirements.coalesce_rows_limit(_Slice(), name, 16512) == 4
+    assert requirements.pass_positions_limit(_Slice(), "qwen3_next") == 131072
+    # a row of one position still costs its state: a chip with 1 GiB left
+    # beside the weights holds 83 of them, not any number
+    tight = _Slice(gib=costs["params_gb"] + costs["working_gb"] + 1.0)
+    assert requirements.fit_batch(tight, name, 10 ** 9, 1) == int(
+        (1 << 30) / (state + 4096))
+    assert 0 < requirements.pass_positions_limit(tight, "qwen3_next") < 131072
+    # what 16.9 GB cannot hold is refused: 1024 rows of 512 would be 14 GiB
+    assert requirements.fit_batch(_Slice(), name, 1024, 512) < 1024
+    assert requirements.fit_batch(_Slice(gib=8), name, 4, 512) == 0
+    with pytest.raises(ValueError, match="does not fit"):
+        requirements.check_capacity(_Slice(gib=8), name, 4, 512)
+    assert requirements.fit_batch(_Slice(gib=1), "test/tiny-qwen3-next", 64,
+                                  512) == 64
+
+
+@pytest.mark.parametrize("name, family", [
+    ("test/Kimi-K2.6", "kimi_k2"),
+    ("test/K-EXAONE-236B-A23B", "exaone_moe"),
+    ("test/SDAR-30B-A3B-Chat", "sdar_moe"),
+    ("test/Qwen3-Next-80B-A3B-Instruct", "qwen3_next"),
+])
+def test_a_full_size_test_name_is_no_stand_in(name, family):
+    """Every text family gives a `test/` name its published widths, so
+    admission reckons it by the table (`sdar_moe` was missing from the
+    list before PR 42, and `fit_batch` admitted any batch of it)."""
+    assert requirements._family_key(name) == family
+    assert family in requirements._PUBLISHED_TEST_FAMILIES
+    assert not requirements._is_stand_in(name)
+    assert requirements._is_stand_in(f"test/tiny-{family}")
+    fit = requirements.fit_batch(_Slice(), name, 10 ** 9, 512)
+    assert 256 <= fit < 10 ** 9
+    assert requirements.fit_batch(_Slice(gib=6), name, 4, 512) == 0
 
 
 # --- the appetite between worker and hive ------------------------------------
@@ -332,9 +413,10 @@ def test_a_worker_advertises_the_sequence_families_appetite(sdaas_root):
     caps = worker._capabilities()
     # not HBM on the CPU: the ceiling; the job cap stays what it was
     assert caps["family_gang_rows"] == (
-        "kimi_k2:256,exaone_moe:256,sdar_moe:256")
+        "kimi_k2:256,exaone_moe:256,sdar_moe:256,qwen3_next:256")
     assert caps["family_gang_positions"] == (
-        "kimi_k2:131072,exaone_moe:131072,sdar_moe:131072")
+        "kimi_k2:131072,exaone_moe:131072,sdar_moe:131072,"
+        "qwen3_next:131072")
     assert caps["gang_rows"] == 8
     # the batcher's own budget is the job's true positions
     assert worker._coalesce_rows_limit(_job(1, 2)) == 256
