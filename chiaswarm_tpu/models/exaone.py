@@ -57,6 +57,7 @@ from .experts import (
     tally,
 )
 from .kimi import apply_rope
+from .prefill_chunks import whole_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -345,7 +346,7 @@ def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
         reads it. Rings, `last` and the tally as they came: `run` finds no
         position of its own for them. A full layer's keys and values
         zero: columns past every row's length, which `decode_masks` shows
-        to nobody, written and not left to the buffer (`_whole_rows`). A
+        to nobody, written and not left to the buffer (`whole_rows`). A
         sliding layer's tail zero too: only a later span would read it,
         and none runs."""
         added = [(zeros(min(start + chunk_slots, window)
@@ -375,19 +376,6 @@ def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
     return last, entries, load
 
 
-def _whole_rows(entry, columns: int):
-    """`entry` [R, S, ...] with zeros behind it up to `columns` columns: a
-    row of a full layer's cache as it is written, the prompt's slots and
-    the generated tokens' columns still empty. Written so and not left to
-    the cache's initial zeros: where a loop's counter is the row it
-    writes, the TPU compiler (libtpu 0.0.34) takes the loop to write the
-    whole buffer and drops the zeros it started from, and the columns
-    past the prompt are then whatever the memory held. A decode step gives
-    them a weight of zero, and zero times a NaN is a NaN."""
-    return jnp.pad(entry, ((0, 0), (0, columns - entry.shape[1]))
-                   + ((0, 0),) * (entry.ndim - 2))
-
-
 def prefill(params, cfg: ExaoneConfig, ids, lengths, positions: int,
             chunk_rows: int, chunk_slots: int | None = None,
             interpret: bool = False):
@@ -411,10 +399,10 @@ def prefill(params, cfg: ExaoneConfig, ids, lengths, positions: int,
             jax.lax.dynamic_slice(lengths, (at,), (chunk_rows,)),
             chunk_slots, load, interpret)
         # whole rows: the loop writes every element of the cache, so what
-        # the buffer held before does not matter (`_whole_rows`)
+        # the buffer held before does not matter (`whole_rows`)
         cache = tuple(
             tuple(jax.lax.dynamic_update_slice(
-                whole, _whole_rows(entry.astype(dtype), whole.shape[1]),
+                whole, whole_rows(entry.astype(dtype), whole.shape[1]),
                 (at, 0, 0, 0))
                   for whole, entry in zip(layer, written))
             for layer, written in zip(cache, entries))

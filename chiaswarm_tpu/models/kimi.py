@@ -49,6 +49,7 @@ from .experts import (  # noqa: F401  (this module's public names too)
     swiglu,
     tally,
 )
+from .prefill_chunks import chunk_widths, prefill_by_length
 
 
 @dataclasses.dataclass(frozen=True)
@@ -322,15 +323,23 @@ def prefill_rows(params, cfg: KimiConfig, ids, lengths, load,
     positions = jnp.broadcast_to(jnp.arange(slots), (rows, slots))
     valid = (positions < lengths[:, None]).reshape(-1)
     entries = []
-    for index, layer in enumerate(params["layers"]):
+
+    # `prefill` traces this once a width: under a `jit` of its own, layers
+    # of one shape are traced once and not once each (the compiler inlines
+    # the calls: the same program, a second less of tracing a width)
+    @jax.jit
+    def through(layer, x, positions, valid):
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
         out, entry = attention_prefill(layer["attn"], cfg, h, positions)
-        entries.append(entry)
         x = x + out
         h = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
         out, told = feed_forward(layer, cfg, h.reshape(rows * slots, -1),
                                  valid, interpret)
-        x = x + out.reshape(x.shape)
+        return x + out.reshape(x.shape), entry, told
+
+    for index, layer in enumerate(params["layers"]):
+        x, entry, told = through(layer, x, positions, valid)
+        entries.append(entry)
         load = tally(load, index, cfg, told)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
@@ -342,36 +351,35 @@ def prefill_rows(params, cfg: KimiConfig, ids, lengths, load,
 POSITION_CHUNKS = False
 
 
+def prefill_widths(slots: int, chunk_slots: int | None = None):
+    """The widths a chunk of `prefill` may have, widest first: the bucket
+    and its halvings (two more traced copies of the layers: a worker's
+    start is ~4 s longer and every pass of ragged rows 0.8 s shorter;
+    PERF.md section 6, PR 43)."""
+    return chunk_widths(slots, chunk_slots)
+
+
 def prefill(params, cfg: KimiConfig, ids, lengths, positions: int,
             chunk_rows: int, chunk_slots: int | None = None,
             interpret: bool = False):
     """`ids` [R, S] in chunks of `chunk_rows` whole rows (what bounds the
-    widest layer's activations; `chunk_slots` is S). Returns the last
-    prompt position's logits [R, vocab], the cache (a `[R, positions,
+    widest layer's activations; `chunk_slots` is S), a row at the narrowest
+    width that holds it (models/prefill_chunks.py). Returns the last prompt
+    position's logits [R, vocab], the cache (a `[R, positions,
     cache_width]` a layer, the first S columns written) and the tally."""
     rows, slots = ids.shape
     assert chunk_slots in (None, slots), (chunk_slots, slots)
     dtype = params["embed"].dtype
-    assert rows % chunk_rows == 0, (rows, chunk_rows)
 
-    def chunk(number, carry):
-        last, cache, load = carry
-        at = number * chunk_rows
-        x, entries, load = prefill_rows(
-            params, cfg,
-            jax.lax.dynamic_slice(ids, (at, 0), (chunk_rows, slots)),
-            jax.lax.dynamic_slice(lengths, (at,), (chunk_rows,)),
-            load, interpret)
-        cache = tuple(
-            jax.lax.dynamic_update_slice(whole, entry.astype(dtype),
-                                         (at, 0, 0))
-            for whole, entry in zip(cache, entries))
-        return (jax.lax.dynamic_update_slice(last, x, (at, 0)), cache, load)
+    def run(ids, lengths, load):
+        last, entries, load = prefill_rows(params, cfg, ids, lengths, load,
+                                           interpret)
+        return (last, tuple(entries)), load
 
-    last, cache, load = jax.lax.fori_loop(
-        0, rows // chunk_rows, chunk,
+    (last, cache), load = prefill_by_length(
+        ids, lengths, chunk_rows, prefill_widths(slots), run,
         (jnp.zeros((rows, cfg.hidden_size), dtype),
-         new_cache(cfg, rows, positions, dtype), empty_load(cfg)))
+         new_cache(cfg, rows, positions, dtype)), empty_load(cfg))
     return logits_of(params, cfg, last), cache, load
 
 

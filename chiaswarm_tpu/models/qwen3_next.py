@@ -58,7 +58,7 @@ import jax.numpy as jnp
 
 from ..ops import dot_product_attention
 from ..ops.gated_delta_rule import gated_delta_chunks, gated_delta_step
-from .exaone import _whole_rows, cached_attention
+from .exaone import cached_attention
 from .experts import (
     dot,
     empty_load,
@@ -69,6 +69,7 @@ from .experts import (
     tally,
 )
 from .kimi import apply_rope
+from .prefill_chunks import prefill_by_length
 
 
 @dataclasses.dataclass(frozen=True)
@@ -441,45 +442,37 @@ def prefill_rows(params, cfg: Qwen3NextConfig, ids, lengths, chunk_slots: int,
     return last, entries, load
 
 
+def prefill_widths(slots: int, chunk_slots: int | None = None):
+    """The widths a chunk of `prefill` may have: the bucket alone. A
+    narrower width is one more traced copy of the layers, and two of them
+    made a worker's start 12 s (15 %) longer and the program's temporaries
+    0.05 GB larger (PERF.md section 6, PR 43)."""
+    return (slots,)
+
+
 def prefill(params, cfg: Qwen3NextConfig, ids, lengths, positions: int,
             chunk_rows: int, chunk_slots: int | None = None,
             interpret: bool = False):
     """`ids` [R, S] in chunks of `chunk_rows` rows x `chunk_slots`
     positions (whole rows where rows are short, a span of one row's
-    positions where a row is longer). Returns the last prompt position's
+    positions where a row is longer), rows of no length left out
+    (models/prefill_chunks.py). Returns the last prompt position's
     logits [R, vocab], the cache (`new_cache`: a linear layer's state and
     tail at each row's own length, a full layer's first S columns
     written) and the tally."""
     rows, slots = ids.shape
     dtype = params["embed"].dtype
     chunk_slots = slots if chunk_slots is None else chunk_slots
-    assert rows % chunk_rows == 0, (rows, chunk_rows)
 
-    def chunk(number, carry):
-        last, cache, load = carry
-        at = number * chunk_rows
-        x, entries, load = prefill_rows(
-            params, cfg,
-            jax.lax.dynamic_slice(ids, (at, 0), (chunk_rows, slots)),
-            jax.lax.dynamic_slice(lengths, (at,), (chunk_rows,)),
-            chunk_slots, load, interpret)
-        # whole rows: the loop writes every element of the cache
-        # (models/exaone.py `_whole_rows`)
-        cache = tuple(
-            tuple(jax.lax.dynamic_update_slice(
-                whole,
-                (entry if linear else _whole_rows(
-                    entry, whole.shape[1])).astype(whole.dtype),
-                (at,) + (0,) * (whole.ndim - 1))
-                  for whole, entry in zip(layer, written))
-            for linear, layer, written in zip(cfg.linear_layers, cache,
-                                              entries))
-        return (jax.lax.dynamic_update_slice(last, x, (at, 0)), cache, load)
+    def run(ids, lengths, load):
+        last, entries, load = prefill_rows(params, cfg, ids, lengths,
+                                           chunk_slots, load, interpret)
+        return (last, tuple(entries)), load
 
-    last, cache, load = jax.lax.fori_loop(
-        0, rows // chunk_rows, chunk,
-        (jnp.zeros((rows, cfg.hidden_size), dtype),
-         new_cache(cfg, rows, positions, dtype), empty_load(cfg)))
+    (last, cache), load = prefill_by_length(
+        ids, lengths, chunk_rows, prefill_widths(slots, chunk_slots),
+        run, (jnp.zeros((rows, cfg.hidden_size), dtype),
+              new_cache(cfg, rows, positions, dtype)), empty_load(cfg))
     return logits_of(params, cfg, last), cache, load
 
 

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import dot_product_attention
-from .exaone import _whole_rows, rope_tables, span_runs
+from .exaone import rope_tables, span_runs
 from .experts import (
     dot,
     empty_load,
@@ -69,6 +69,7 @@ from .experts import (
     tally,
 )
 from .kimi import apply_rope
+from .prefill_chunks import prefill_by_length
 
 
 @dataclasses.dataclass(frozen=True)
@@ -293,7 +294,7 @@ def prefill_rows(params, cfg: SdarConfig, ids, lengths, chunk_slots: int,
 
     def skip(start, kept, load):
         """What `run` gives where no row reaches `start`: zeros, written
-        and not left to the buffer (models/exaone.py `_whole_rows`)."""
+        and not left to the buffer (models/prefill_chunks.py `whole_rows`)."""
         zeros = jnp.zeros((rows, chunk_slots, cfg.num_key_value_heads,
                            cfg.head_dim), dtype)
         return [(zeros, zeros)] * cfg.num_hidden_layers, load
@@ -309,39 +310,34 @@ def prefill_rows(params, cfg: SdarConfig, ids, lengths, chunk_slots: int,
             for layer in kept], load
 
 
+def prefill_widths(slots: int, chunk_slots: int | None = None):
+    """The widths a chunk of `prefill` may have: the bucket alone. A
+    narrower width is one more traced copy of the layers, and two of them
+    made a worker's start 8 s (13 %) longer where a pass got 0.18 s
+    shorter (PERF.md section 6, PR 43)."""
+    return (slots,)
+
+
 def prefill(params, cfg: SdarConfig, ids, lengths, positions: int,
             chunk_rows: int, chunk_slots: int | None = None,
             interpret: bool = False):
     """`ids` [R, S] in chunks of `chunk_rows` rows x `chunk_slots`
-    positions. Returns the cache (`new_cache`: the first S columns written)
-    and the tally. No logits: the last prompt position's predict nothing
-    here (a generated position's own logits predict its token)."""
+    positions, rows of no length left out (models/prefill_chunks.py).
+    Returns the cache (`new_cache`: the first S columns written) and the
+    tally. No logits: the last prompt position's predict nothing here (a
+    generated position's own logits predict its token)."""
     rows, slots = ids.shape
     dtype = params["embed"].dtype
     chunk_slots = slots if chunk_slots is None else chunk_slots
-    assert rows % chunk_rows == 0, (rows, chunk_rows)
 
-    def chunk(number, carry):
-        cache, load = carry
-        at = number * chunk_rows
-        entries, load = prefill_rows(
-            params, cfg,
-            jax.lax.dynamic_slice(ids, (at, 0), (chunk_rows, slots)),
-            jax.lax.dynamic_slice(lengths, (at,), (chunk_rows,)),
-            chunk_slots, load, interpret)
-        # whole rows, so that the loop writes every element of the cache
-        # (`_whole_rows`)
-        cache = tuple(
-            tuple(jax.lax.dynamic_update_slice(
-                whole, _whole_rows(entry.astype(dtype), whole.shape[1]),
-                (at, 0, 0, 0))
-                  for whole, entry in zip(layer, written))
-            for layer, written in zip(cache, entries))
-        return cache, load
+    def run(ids, lengths, load):
+        entries, load = prefill_rows(params, cfg, ids, lengths, chunk_slots,
+                                     load, interpret)
+        return tuple(entries), load
 
-    return jax.lax.fori_loop(
-        0, rows // chunk_rows, chunk,
-        (new_cache(cfg, rows, positions, dtype), empty_load(cfg)))
+    return prefill_by_length(
+        ids, lengths, chunk_rows, prefill_widths(slots, chunk_slots),
+        run, new_cache(cfg, rows, positions, dtype), empty_load(cfg))
 
 
 # --- a block -----------------------------------------------------------------

@@ -17,7 +17,15 @@ padding after it), so a pass is keyed by (rows, prompt slots, new tokens):
   fit (`prefill_chunk`: whole rows where rows are short, a span of one
   row's positions where a row is longer, each span attending to what the
   spans before it cached), writes each layer's cache and returns the
-  logits of every row's last prompt token;
+  logits of every row's last prompt token. A row of a chunk of whole
+  rows goes through at the narrowest of the widths its model offers that
+  holds it (Kimi: the bucket and its halvings; SDAR and Qwen3-Next: the
+  bucket), and a chunk takes rows of one width (models/prefill_chunks.py:
+  read from `lengths` on the device, so it is one program whatever a pass
+  brings, and a row's bits do not depend on its batchmates); a pass hands
+  its rows over longest first, so that rows of a width stand together,
+  runs prefill and decode in that order and puts the ids back in the
+  jobs' order at the read-back;
 - **decode** is a `lax.scan` of `new tokens - 1` steps through the cache
   (the first token comes from prefill's logits, the last one is never fed):
   a step feeds every row its last token, and samples the next on the device
@@ -62,10 +70,12 @@ a row, which do not grow with the positions): the whole is
 `swarm_pass_window_cache_bytes{model}`, the states' part
 `swarm_pass_state_bytes{model}`. A pass counts its prompt slots
 (`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
-that fills rows to the bucket and was computed all the same, and the
-padding `skipped`: chunks the model's prefill did not run because none of
-their rows reached them, `span_runs`); how the routing fell comes back
-with the ids (`swarm_expert_pairs_total`, `swarm_routed_tokens_total`,
+that was computed all the same, and the slots of the bucket `skipped`:
+what lies past a chunk's width, and chunks the model's prefill did not
+run because none of their rows reached them, `chunk_plan` and
+`span_runs`) and the chunks it ran at each width
+(`swarm_prefill_chunks_total{model, width}`); how the routing fell comes
+back with the ids (`swarm_expert_pairs_total`, `swarm_routed_tokens_total`,
 `swarm_expert_pairs_max_total`; the envelope's `routing` has the same a
 program, with the experts that had a pair and the calls).
 
@@ -93,6 +103,7 @@ import numpy as np
 from .. import telemetry
 from ..coalesce import checked_denoising_steps, prompt_slots
 from ..models import exaone, kimi, qwen3_next, sdar
+from ..models.prefill_chunks import chunk_plan
 from ..ops import platform
 from ..parallel.mesh import make_mesh, replicated
 from ..registry import _auto_family, register_family
@@ -109,13 +120,15 @@ PREFILL_CHUNK_TOKENS = 4096
 
 # family -> the module that has the model: `config_for`, `param_shapes`,
 # `init_params`, `prefill`, `empty_load`, `cache_bytes`, `POSITION_CHUNKS`,
-# where its prefill leaves out a chunk that is all padding the rule it goes
-# by (`span_runs`), and one of the two ways to decode: `step` (prefill
-# returns the last prompt position's logits, a forward feeds a row one
-# token and yields one) or `block_step` with `first_block`, `unmask`,
-# `blocks_of` and `cache_positions` (prefill returns no logits, a forward
-# feeds a row a block). The families are chips/requirements.py
-# `SEQUENCE_FAMILIES`' (tests/test_text_serving.py holds the lists equal)
+# where a chunk of its prefill is as wide as its rows need the widths it
+# may have (`prefill_widths`), where its prefill leaves out a span that is
+# all padding the rule it goes by (`span_runs`), and one of the two ways to
+# decode: `step` (prefill returns the last prompt position's logits, a
+# forward feeds a row one token and yields one) or `block_step` with
+# `first_block`, `unmask`, `blocks_of` and `cache_positions` (prefill
+# returns no logits, a forward feeds a row a block). The families are
+# chips/requirements.py `SEQUENCE_FAMILIES`' (tests/test_text_serving.py
+# holds the lists equal)
 _MODELS = {"kimi_k2": kimi, "exaone_moe": exaone, "sdar_moe": sdar,
            "qwen3_next": qwen3_next}
 
@@ -171,9 +184,13 @@ BLOCK_SLOTS = telemetry.counter(
 PREFILL_SLOTS = telemetry.counter(
     "swarm_prefill_slots_total",
     "Prompt slots of the prefill programs' passes, by model and kind (real: "
-    "a prompt's ids; padding: what fills a row to its bucket and a pass "
-    "to its rows, and was computed; skipped: the padding of chunks that "
-    "were not run)", ("model", "kind"))
+    "a prompt's ids; padding: slots that hold no id and were computed; "
+    "skipped: slots of the bucket no chunk computed, past a chunk's "
+    "width or in a chunk that was not run)", ("model", "kind"))
+PREFILL_CHUNKS = telemetry.counter(
+    "swarm_prefill_chunks_total",
+    "Chunks the prefill programs ran, by model and the width the chunk was "
+    "run at (the slots of a row it computed)", ("model", "width"))
 
 
 def prefill_chunk(rows: int, slots: int, spans: bool = True
@@ -514,6 +531,13 @@ class TextGenerationPipeline:
                 lengths[at] = len(row)
                 job_of_row[at], row_in_job[at] = job, number
                 at += 1
+        # longest first, the rows that only pad the pass last: a prefill
+        # chunk takes rows of one width, and the whole pass runs in this
+        # order (a row's keys are its job's and its number in the job, so
+        # it draws the same ids wherever it stands)
+        order = np.argsort(-lengths, kind="stable")
+        ids, lengths, job_of_row, row_in_job = (
+            x[order] for x in (ids, lengths, job_of_row, row_in_job))
         job_keys = jnp.stack([jax.random.key_data(request["rng"])
                               for request in requests])
         positions = self.cache_positions(slots, new_tokens)
@@ -541,7 +565,8 @@ class TextGenerationPipeline:
             del cache
             jax.block_until_ready(out)
         with Span("readback", timings):
-            out = np.asarray(out)
+            # back in the jobs' order
+            out = np.asarray(out)[np.argsort(order)]
             (pairs, sums), (before, before_sums) = (
                 tuple(np.asarray(x) for x in tally)
                 for tally in (load, filled))
@@ -558,22 +583,37 @@ class TextGenerationPipeline:
         # where prefill's ended)
         chunk_rows, chunk_slots = prefill_chunk(
             rows, slots, self.model.POSITION_CHUNKS)
-        chunks = (rows // chunk_rows) * (slots // chunk_slots)
-        # chunks the prefill program did not run, by the model's own rule
-        # on the lengths it was given
+        # the chunks the prefill program ran and those it did not, by the
+        # model's own rules on the lengths it was given: which rows a
+        # chunk of whole rows takes and how wide it is (`chunk_plan` over
+        # its `prefill_widths`; else `chunk_rows` rows whatever their
+        # lengths), and of a chunk that is spans of positions those some
+        # row reaches (`span_runs`)
         runs = getattr(self.model, "span_runs", None)
-        skipped = 0 if runs is None else sum(
-            not runs(lengths[at:at + chunk_rows], start)
-            for at in range(0, rows, chunk_rows)
-            for start in range(0, slots, chunk_slots))
+        if hasattr(self.model, "prefill_widths"):
+            plan = chunk_plan(lengths, chunk_rows,
+                              self.model.prefill_widths(slots, chunk_slots))
+        else:
+            plan = [(at, chunk_rows, slots)
+                    for at in range(0, rows, chunk_rows)]
+        widths, skipped, computed = {}, 0, 0
+        for at, take, width in plan:
+            span = min(chunk_slots, width or slots)
+            for start in range(0, width or slots, span):
+                if width and (runs is None
+                              or runs(lengths[at:at + take], start)):
+                    widths[str(span)] = widths.get(str(span), 0) + 1
+                    computed += take * span
+                else:
+                    skipped += 1
+        chunks = sum(widths.values())
         # expert-layer calls of either program: a block model's prefill
         # chunk, whose logits nobody reads, stops before its last layer's
         # experts; every forward of either decode runs them all (a fused
         # forward's last layer for its own block)
         layers = cfg.expert_layers
         forwards = denoise if self.by_blocks else new_tokens - 1
-        prefill_calls = (layers - 1 if self.by_blocks else layers) * (
-            chunks - skipped)
+        prefill_calls = (layers - 1 if self.by_blocks else layers) * chunks
         decode_calls = layers * forwards
         routing = {
             **tally(pairs, sums, prefill_calls + decode_calls),
@@ -602,10 +642,10 @@ class TextGenerationPipeline:
         EXPERT_PAIRS_MAX.inc(routing["pairs_max"], **label)
         prompt_tokens = int(lengths.sum())
         PREFILL_SLOTS.inc(prompt_tokens, kind="real", **label)
-        skipped_slots = skipped * chunk_rows * chunk_slots
-        PREFILL_SLOTS.inc(rows * slots - prompt_tokens - skipped_slots,
-                          kind="padding", **label)
-        PREFILL_SLOTS.inc(skipped_slots, kind="skipped", **label)
+        PREFILL_SLOTS.inc(computed - prompt_tokens, kind="padding", **label)
+        PREFILL_SLOTS.inc(rows * slots - computed, kind="skipped", **label)
+        for width, count in widths.items():
+            PREFILL_CHUNKS.inc(count, width=width, **label)
         cache_bytes, cache_bytes_window, cache_bytes_state = self.cache_bytes(
             rows, positions)
         results, at = [], 0
@@ -626,6 +666,7 @@ class TextGenerationPipeline:
                 **blocks,
                 "prefill_chunks": chunks,
                 "prefill_chunks_skipped": skipped,
+                "prefill_chunk_widths": dict(widths),
                 "cache_bytes": cache_bytes,
                 "cache_bytes_window": cache_bytes_window,
                 "cache_bytes_state": cache_bytes_state,

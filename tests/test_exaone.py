@@ -115,8 +115,8 @@ def test_chunked_prefill_and_two_caches_give_the_references_logits(
 def test_prefill_writes_every_element_of_the_cache(params, monkeypatch):
     """Whatever the cache's buffer held: the TPU compiler drops a carried
     buffer's initial zeros where it takes the loop to write it whole, so
-    the loop has to (`exaone._whole_rows`). Started from NaN, the columns
-    past the prompt come back zero."""
+    the loop has to (`prefill_chunks.whole_rows`). Started from NaN, the
+    columns past the prompt come back zero."""
     made = exaone.new_cache
     monkeypatch.setattr(exaone, "new_cache", lambda *a: jax.tree_util.tree_map(
         lambda x: jnp.full_like(x, jnp.nan), made(*a)))

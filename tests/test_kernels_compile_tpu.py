@@ -336,3 +336,84 @@ def test_exaone_prefill_keeps_a_conditional_a_span_on_v5e(v5e, monkeypatch):
                                        2 * cfg.expert_layers)
     # the scoped branches' temporaries: 2.16 GB where every span ran
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("module, preset, widths, temp", [
+    # the program's temporaries compiled here before ISSUE 43 (one chunk
+    # width): 1.182, 0.469 and 1.357 GB; a cell's `hbm_peak_gb` is held to
+    # 1 % of 12 GB
+    ("kimi", "KIMI_K2_EP32", (256, 128, 64), 1.20e9),
+    ("sdar", "SDAR_30B_PP8", (256,), 0.50e9),
+    ("qwen3_next", "QWEN3_NEXT_80B_EP4", (256,), 1.40e9),
+])
+def test_a_batch_decode_cells_prefill_has_a_branch_a_width_on_v5e(
+        v5e, monkeypatch, module, preset, widths, temp):
+    """The three batch-decode cells' prefill program (256 rows of 256
+    slots, 512 positions, the published widths; ISSUE 43): a copy of the
+    layers a width the model's module offers and no more; where it offers
+    several (Kimi: 256, 128, 64 slots) the chip's compiler keeps one
+    `conditional` in the loop over the chunks, a branch a width that holds
+    every layer's two grouped matmuls and one that runs no kernel, and the
+    branches' temporaries do not add up: no more of them than the one
+    width took."""
+    import importlib
+    import re
+
+    from chiaswarm_tpu.ops import attention, platform
+    from chiaswarm_tpu.pipelines.text_generation import prefill_chunk
+
+    for patched in (platform, attention):  # the trace is for the chip
+        monkeypatch.setattr(patched, "trace_platform", lambda: "tpu")
+    model = importlib.import_module(f"chiaswarm_tpu.models.{module}")
+    cfg, rows, slots, positions = getattr(model, preset), 256, 256, 512
+    chunk = prefill_chunk(rows, slots, model.POSITION_CHUNKS)
+    assert chunk == (16, 256)
+    assert model.prefill_widths(slots, chunk[1]) == widths
+    params = jax.tree_util.tree_map(
+        lambda leaf: _shape(v5e, leaf.shape, leaf.dtype),
+        model.param_shapes(cfg, jnp.bfloat16))
+    compiled = jax.jit(lambda p, ids, lengths: model.prefill(
+        p, cfg, ids, lengths, positions, *chunk)).lower(
+            params, _shape(v5e, (rows, slots), jnp.int32),
+            _shape(v5e, (rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    branches = [re.findall(r"%?([\w.\-]+)", found) for found in re.findall(
+        r" conditional\(.*branch_computations=\{([^}]*)\}", text)]
+    kernel = r"\s*%expert_matmul[.\d]* = .*custom-call\("
+    # a block model's prefill stops before its last layer's experts
+    layers = cfg.expert_layers - hasattr(model, "block_step")
+    assert sum(bool(re.match(kernel, line)) for line in text.splitlines()
+               ) == 2 * layers * len(widths)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp
+    if len(widths) == 1:
+        return
+    wide = [found for found in branches if len(found) == len(widths) + 1]
+    assert len(wide) == 1, branches
+    # a computation's lines under its name, and the grouped matmuls of a
+    # computation and of every computation it calls
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+
+    def matmuls(name, seen):
+        if name in seen or name not in bodies:
+            return 0
+        seen.add(name)
+        lines = "\n".join(bodies[name])
+        called = re.findall(
+            r"(?:calls|to_apply|body|condition|true_computation|"
+            r"false_computation)=%?([\w.\-]+)", lines) + [
+            branch for found in re.findall(
+                r"branch_computations=\{([^}]*)\}", lines)
+            for branch in re.findall(r"%?([\w.\-]+)", found)]
+        return sum(bool(re.match(kernel, line))
+                   for line in bodies[name]) + sum(
+            matmuls(other, seen) for other in called)
+
+    assert [matmuls(branch, set()) for branch in wide[0]] == [0] + [
+        2 * layers] * len(widths)

@@ -79,6 +79,70 @@ def test_prefill_and_cached_decode_give_the_references_logits(params, absorb):
     assert 0 < pairs.sum() < routed
 
 
+def _ragged(order, lengths, vocabulary, seed=5):
+    """`lengths` longest first or shuffled (rows of no length among the
+    others then), and ids behind them."""
+    rng = np.random.default_rng(seed)
+    lengths = np.array(lengths, np.int32)
+    if order == "shuffled":
+        lengths = lengths[rng.permutation(len(lengths))]
+    ids = np.zeros((len(lengths), max(lengths)), np.int32)
+    for row, length in enumerate(lengths):
+        ids[row, :length] = rng.integers(0, vocabulary, length)
+    return ids, lengths
+
+
+@pytest.mark.parametrize("order", ["ordered", "shuffled"])
+def test_chunks_as_wide_as_their_rows_leave_what_one_wide_chunk_leaves(
+        params, monkeypatch, order):
+    """ISSUE 43: two rows a chunk at most, each row at the narrowest of
+    16, 8 or 4 slots that holds it, rows that only pad the pass not run:
+    the logits, the cache (started from NaN: an element no chunk wrote
+    would show) and the pairs of the same rows in one chunk of 16 slots."""
+    ids, lengths = _ragged(order, [16, 13, 8, 7, 4, 2, 0, 0], CFG.vocab_size)
+    (rows, slots), positions = ids.shape, 19
+    made = kimi.new_cache
+    monkeypatch.setattr(
+        kimi, "new_cache", lambda *args: jax.tree_util.tree_map(
+            lambda x: x + jnp.nan, made(*args)))
+    logits, cache, load = jax.jit(lambda p, i, n: kimi.prefill(
+        p, CFG, i, n, positions, 2))(params, ids, lengths)
+    last, entries, told = jax.jit(lambda p, i, n: kimi.prefill_rows(
+        p, CFG, i, n, kimi.empty_load(CFG)))(params, ids, lengths)
+    real = lengths > 0
+    np.testing.assert_allclose(
+        np.asarray(logits)[real],
+        np.asarray(kimi.logits_of(params, CFG, last))[real], atol=2e-5)
+    seen = (np.arange(slots)[None, :] < lengths[:, None])[..., None]
+    for mine, entry in zip(cache, entries):
+        mine = np.asarray(mine)
+        assert np.isfinite(mine).all() and not mine[:, slots:].any()
+        np.testing.assert_allclose(np.where(seen, mine[:, :slots], 0),
+                                   np.where(seen, entry, 0), atol=2e-5)
+    assert np.array_equal(np.asarray(load[0]), np.asarray(told[0]))
+    assert int(load[1][0]) == int(told[1][0]) == int(
+        lengths.sum()) * CFG.num_experts_per_tok * CFG.expert_layers
+
+
+def test_a_rows_bits_do_not_depend_on_its_batchmates(params):
+    """ISSUE 43: a row's width is its own, so its logits and its cache
+    entries are the same bits wherever it stands and whoever shares its
+    chunk (a sampler turns the last bit of a logit into another id sooner
+    or later, and a job's ids may not depend on its batchmates)."""
+    ids, lengths = _ragged("ordered", [16, 13, 8, 7, 4, 2, 0, 0],
+                           CFG.vocab_size)
+    prefill = jax.jit(lambda p, i, n: kimi.prefill(p, CFG, i, n, 19, 2))
+    logits, cache, _ = prefill(params, ids, lengths)
+    order = np.array([5, 0, 7, 3, 1, 6, 2, 4])
+    moved, moved_cache, _ = prefill(params, ids[order], lengths[order])
+    real = lengths[order] > 0
+    assert np.array_equal(np.asarray(moved)[real],
+                          np.asarray(logits)[order][real])
+    for mine, other in zip(moved_cache, cache):
+        assert np.array_equal(np.asarray(mine)[real],
+                              np.asarray(other)[order][real])
+
+
 def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(params):
     """Four chips of eight experts each: their parts, the shared expert
     counted once, are the reference's layer with all 32 experts."""

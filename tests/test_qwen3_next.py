@@ -213,6 +213,60 @@ def test_a_prompt_in_spans_leaves_the_cache_of_one_prefilled_whole(pipe):
                 rtol=1e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("order", ["ordered", "shuffled"])
+def test_chunks_as_wide_as_their_rows_leave_what_one_wide_chunk_leaves(
+        pipe, monkeypatch, order):
+    """ISSUE 43: two rows a chunk at most, taken by `prefill_by_length`
+    (this model offers the bucket's width alone), rows that only pad the
+    pass not run: the logits, state and tail at each row's own length, a
+    full layer's keys and values (the cache started from NaN: an element
+    no chunk wrote would show) and the pairs of the same rows in one chunk
+    of all eight."""
+    rng = np.random.default_rng(5)
+    lengths = np.array([256, 130, 128, 65, 64, 3, 0, 0], np.int32)
+    if order == "shuffled":
+        lengths = lengths[rng.permutation(len(lengths))]
+    rows, slots, positions = len(lengths), 256, 260
+    ids = np.zeros((rows, slots), np.int32)
+    for row, length in enumerate(lengths):
+        ids[row, :length] = rng.integers(0, CFG.vocab_size, length)
+    assert qwen3_next.prefill_widths(slots) == (256,)
+    made = qwen3_next.new_cache
+    monkeypatch.setattr(
+        qwen3_next, "new_cache", lambda cfg, rows, positions, dtype:
+        jax.tree_util.tree_map(lambda x: x + jnp.nan,
+                               made(cfg, rows, positions, dtype))
+        if positions else made(cfg, rows, positions, dtype))
+    logits, cache, load = jax.jit(lambda p, i, n: qwen3_next.prefill(
+        p, CFG, i, n, positions, 2))(pipe.params, ids, lengths)
+    last, entries, told = jax.jit(lambda p, i, n: qwen3_next.prefill_rows(
+        p, CFG, i, n, slots, qwen3_next.empty_load(CFG)))(
+            pipe.params, ids, lengths)
+    real = lengths > 0
+    np.testing.assert_allclose(
+        np.asarray(logits)[real],
+        np.asarray(experts.logits_of(pipe.params, CFG, last))[real],
+        atol=5e-5)
+    seen = (np.arange(slots)[None, :] < lengths[:, None])[..., None, None]
+    for linear, layer, written in zip(CFG.linear_layers, cache, entries):
+        for mine, entry in zip(layer, written):
+            mine, entry = np.asarray(mine), np.asarray(entry)
+            assert np.isfinite(mine).all()
+            if linear:
+                # a row of padding leaves zeros
+                assert not mine[~real].any()
+                np.testing.assert_allclose(mine[real], entry[real],
+                                           rtol=1e-4, atol=2e-5)
+            else:
+                assert not mine[:, slots:].any()
+                np.testing.assert_allclose(
+                    np.where(seen, mine[:, :slots], 0),
+                    np.where(seen, entry, 0), rtol=1e-4, atol=2e-5)
+    assert np.array_equal(np.asarray(load[0]), np.asarray(told[0]))
+    assert int(load[1][0]) == int(told[1][0]) == int(
+        lengths.sum()) * CFG.num_experts_per_tok * CFG.expert_layers
+
+
 def test_padded_slots_and_padded_rows_change_no_state(pipe):
     """The state and tail a row leaves are those at its own last id: the
     same row alone in a bucket as long as itself leaves them too. A row

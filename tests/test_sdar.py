@@ -75,6 +75,41 @@ def test_the_tiny_preset_is_the_cut_in_small():
         CFG, 16, 6) == 24
 
 
+@pytest.mark.parametrize("order", ["ordered", "shuffled"])
+def test_chunks_as_wide_as_their_rows_leave_what_one_wide_chunk_leaves(
+        pipe, monkeypatch, order):
+    """ISSUE 43: two rows a chunk at most, taken by `prefill_by_length`
+    (this model offers the bucket's width alone), rows that only pad the
+    pass not run: every layer's keys and values of each row's whole blocks
+    (the cache started from NaN: an element no chunk wrote would show) and
+    the pairs of the same rows in one chunk of all eight."""
+    rng = np.random.default_rng(5)
+    lengths = np.array([16, 13, 8, 7, 4, 2, 0, 0], np.int32)
+    if order == "shuffled":
+        lengths = lengths[rng.permutation(len(lengths))]
+    rows, slots, positions = len(lengths), 16, 24
+    ids = rng.integers(0, CFG.vocab_size, (rows, slots)).astype(np.int32)
+    assert sdar.prefill_widths(slots) == (16,)
+    made = sdar.new_cache
+    monkeypatch.setattr(
+        sdar, "new_cache", lambda *args: jax.tree_util.tree_map(
+            lambda x: x + jnp.nan, made(*args)))
+    cache, load = jax.jit(lambda p, i, n: sdar.prefill(
+        p, CFG, i, n, positions, 2))(pipe.params, ids, lengths)
+    entries, told = jax.jit(lambda p, i, n: sdar.prefill_rows(
+        p, CFG, i, n, slots, sdar.empty_load(CFG)))(pipe.params, ids, lengths)
+    seen = (np.arange(slots)[None, :] < (lengths // B * B)[:, None])[
+        ..., None, None]
+    for layer, written in zip(cache, entries):
+        for mine, entry in zip(layer, written):
+            mine = np.asarray(mine)
+            assert np.isfinite(mine).all() and not mine[:, slots:].any()
+            np.testing.assert_allclose(np.where(seen, mine[:, :slots], 0),
+                                       np.where(seen, entry, 0), atol=2e-5)
+    assert np.array_equal(np.asarray(load[0]), np.asarray(told[0]))
+    assert int(load[1][0]) == int(told[1][0]) > 0
+
+
 def _given(rng, ids, lengths, blocks):
     """Given ids for `blocks` blocks a row, the first behind the prompt's
     tail, and each row's whole sequence for the reference."""

@@ -617,6 +617,7 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
         max_new_tokens=7, temperature=0.0)
     assert whole["prefill_chunks"] == 1
     assert whole["prefill_chunks_skipped"] == 0
+    assert whole["prefill_chunk_widths"] == {"64": 1}
 
     monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", 16)
     assert text_generation.prefill_chunk(1, 64) == (1, 16)
@@ -641,10 +642,12 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
     assert status["status"] == "done" and status["attempts"] == 1
     config = status["result"]["pipeline_config"]
     assert json.loads(blob)["token_ids"] == want.tolist()
-    assert config["prefill_chunks"] == 4 and config["prompt_slots"] == 64
-    # the span from 48 on is past the row's 41 ids and was not run: three
-    # calls of each of the four expert layers, and six decode steps'
+    # the chunks that ran (ISSUE 43): the span from 48 on is past the
+    # row's 41 ids and was not run: three calls of each of the four expert
+    # layers, and six decode steps'
+    assert config["prefill_chunks"] == 3 and config["prompt_slots"] == 64
     assert config["prefill_chunks_skipped"] == 1
+    assert config["prefill_chunk_widths"] == {"16": 3}
     assert config["routing"]["prefill"]["calls"] == 3 * 4
     assert config["routing"]["calls"] == (3 + 6) * 4
     assert config["padded_rows"] == 1 and config["prompt_tokens"] == 41
@@ -666,19 +669,33 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
 
 
 @pytest.mark.parametrize(
-    "model, chunk_tokens, lengths, chunks, skipped, skipped_slots", [
+    "model, chunk_tokens, lengths, widths, skipped, skipped_slots", [
         # spans of 16 of a 64-slot row, a row a chunk: 1 + 2 + 3 spans past
         # the rows' ends and the 4 of the row that pads the pass to 4
-        ("test/tiny-exaone", 16, [41, 17, 5], 16, 10, 160),
-        # whole rows, two a chunk, 5 rows in a pass of 8: rows 6 and 7
-        ("test/tiny-exaone", 32, [9, 3, 16, 2, 7], 4, 1, 32),
+        ("test/tiny-exaone", 16, [41, 17, 5], {"16": 6}, 10, 160),
+        # whole rows, two a chunk, 5 rows in a pass of 8: the two rows that
+        # only pad the pass come last and share the chunk that is not run
+        ("test/tiny-exaone", 32, [9, 3, 16, 2, 7], {"16": 3}, 1, 32),
         # every span has a row's token
-        ("test/tiny-exaone", 16, [64, 49], 8, 0, 0),
-        # Kimi's chunks are whole rows and all of them are run
-        ("test/tiny-kimi", 32, [9, 3, 16, 2, 7], 4, 0, 0),
-    ], ids=["spans", "whole_rows", "nothing_to_skip", "kimi"])
+        ("test/tiny-exaone", 16, [64, 49], {"16": 8}, 0, 0),
+        # ISSUE 43: Kimi's chunks are whole rows, two a chunk at most,
+        # longest first, each row at its own width: (16, 9) at 16 slots,
+        # (7) at 8, (3, 2) at 4, and the three rows that only pad the pass
+        # are not run
+        ("test/tiny-kimi", 32, [9, 3, 16, 2, 7], {"16": 1, "8": 1, "4": 1},
+         2, 128 - 32 - 8 - 8),
+        # ... a pass smaller than a chunk: its rows of a width a chunk
+        ("test/tiny-kimi", 4096, [3, 7, 5], {"8": 1, "4": 1}, 1, 64 - 16 - 4),
+        ("test/tiny-kimi", 4096, [3, 4, 2], {"4": 1}, 1, 64 - 12),
+        # SDAR and Qwen3-Next offer the bucket's width alone: chunks of
+        # their real rows, and those of the rows that only pad not run
+        ("test/tiny-sdar", 32, [13, 2, 6, 5, 4], {"16": 3}, 2, 128 - 5 * 16),
+        ("test/tiny-qwen3-next", 32, [16, 1, 9, 3, 2, 2], {"16": 3}, 1,
+         128 - 6 * 16),
+    ], ids=["spans", "whole_rows", "nothing_to_skip", "kimi", "one_chunk",
+            "narrowest", "sdar", "qwen3_next"])
 def test_a_pass_counts_real_padding_and_skipped_slots(
-        monkeypatch, model, chunk_tokens, lengths, chunks, skipped,
+        monkeypatch, model, chunk_tokens, lengths, widths, skipped,
         skipped_slots):
     import jax
 
@@ -692,6 +709,8 @@ def test_a_pass_counts_real_padding_and_skipped_slots(
     kinds = ("real", "padding", "skipped")
     before = [text_generation.PREFILL_SLOTS.value(kind=kind, model=model)
               for kind in kinds]
+    ran = {width: text_generation.PREFILL_CHUNKS.value(
+        width=width, model=model) for width in widths}
     ((ids, config),) = pipe.run_batched(
         [{"prompt_ids": prompts, "rng": jax.random.key(1)}],
         max_new_tokens=3, temperature=0.0)
@@ -699,14 +718,131 @@ def test_a_pass_counts_real_padding_and_skipped_slots(
         text_generation.PREFILL_SLOTS.value(kind=kind, model=model) - was
         for kind, was in zip(kinds, before))
     rows, slots = config["padded_rows"], config["prompt_slots"]
+    chunks = sum(widths.values())
     assert ids.shape == (len(lengths), 3)
     assert (real, left_out) == (sum(lengths), skipped_slots)
     assert real + padding + left_out == rows * slots
+    # the chunks that ran, each at its width, and those that did not
     assert config["prefill_chunks"] == chunks
+    assert config["prefill_chunk_widths"] == widths
     assert config["prefill_chunks_skipped"] == skipped
-    layers = pipe.config.expert_layers
-    assert config["routing"]["prefill"]["calls"] == (chunks - skipped) * layers
-    assert config["routing"]["calls"] == (chunks - skipped + 2) * layers
-    # padding is routed nowhere, run or not
-    assert config["routing"]["prefill"]["routed"] == (
-        sum(lengths) * pipe.config.num_experts_per_tok * layers)
+    assert {width: text_generation.PREFILL_CHUNKS.value(
+        width=width, model=model) - was for width, was in ran.items()
+            } == widths
+    # a block model's prefill stops before its last layer's experts
+    layers = pipe.config.expert_layers - pipe.by_blocks
+    prefill = config["routing"]["prefill"]
+    assert prefill["calls"] == chunks * layers
+    assert config["routing"]["calls"] == (
+        prefill["calls"] + config["decode_steps"] * pipe.config.expert_layers)
+    # the device's own tally: a call counts the held experts that had a
+    # pair, so no more of them than the calls made hold
+    assert 0 < prefill["active"] <= (
+        prefill["calls"] * pipe.config.experts_held[1])
+    # padding is routed nowhere, run or not; SDAR routes whole blocks
+    whole = [n // 4 * 4 for n in lengths] if pipe.by_blocks else lengths
+    assert prefill["routed"] == (
+        sum(whole) * pipe.config.num_experts_per_tok * layers)
+
+
+@pytest.mark.parametrize("model, extra", [
+    ("test/tiny-kimi", {}), ("test/tiny-sdar", {"denoising_steps": 2})],
+    ids=["a_token_a_step", "by_blocks"])
+def test_a_jobs_ids_do_not_depend_on_where_its_rows_stand(monkeypatch, model,
+                                                          extra):
+    """ISSUE 43: a pass runs its rows longest first and hands the ids back
+    in the jobs' order. A job of a long, a short and a middling row draws
+    the same ids alone, first and last among batchmates of other lengths
+    (two rows a prefill chunk, so its rows share chunks with others')."""
+    import jax
+
+    from chiaswarm_tpu.pipelines import text_generation
+
+    monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", 32)
+    pipe = text_generation.TextGenerationPipeline(
+        model, allow_random_init=True)
+    rng = np.random.default_rng(43)
+
+    def job(seed, *lengths):
+        return {"prompt_ids": [rng.integers(0, 128, n).tolist()
+                               for n in lengths], "rng": jax.random.key(seed)}
+
+    mine, long, short = job(7, 3, 14, 6), job(8, 16, 11), job(9, 2, 5, 1)
+
+    def served(*requests):
+        results = pipe.run_batched(list(requests), max_new_tokens=6,
+                                   temperature=1.0, **extra)
+        return {id(request): (ids.tolist(), config)
+                for request, (ids, config) in zip(requests, results)}
+
+    alone, config = served(mine)[id(mine)]
+    assert np.asarray(alone).shape == (3, 6) and config["batch_rows"] == [0, 3]
+    for requests in ((mine, long, short), (short, long, mine)):
+        got = served(*requests)
+        assert got[id(mine)][0] == alone, requests.index(mine)
+        # the envelope's rows are the jobs' order, not the pass's
+        assert [got[id(request)][1]["batch_rows"] for request in requests] == [
+            [sum(len(before["prompt_ids"]) for before in requests[:n]),
+             len(request["prompt_ids"])]
+            for n, request in enumerate(requests)]
+        widths = got[id(mine)][1]["prefill_chunk_widths"]
+        assert sum(widths.values()) > 1 and (len(widths) > 1) == (
+            not pipe.by_blocks)  # Kimi's chunks have a width each
+    # and every job its own ids, whoever else was in the pass
+    assert got[id(long)][0] == served(long)[id(long)][0]
+
+
+def test_one_prefill_program_serves_passes_of_any_lengths(monkeypatch):
+    """ISSUE 43: a chunk's width is data. Two passes of one bucket whose
+    rows differ in length run the one compiled program, each at its own
+    widths, and the envelope's chunks, widths and calls are the device's:
+    with eight dense rows a chunk all but one or two of the held experts
+    have a pair in every call, so the tally's `active` tells the calls."""
+    import jax
+
+    from chiaswarm_tpu.pipelines import text_generation
+
+    monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", 128)
+    model = "test/tiny-kimi"
+    pipe = text_generation.TextGenerationPipeline(
+        model, allow_random_init=True)
+    rng = np.random.default_rng(2)
+    kinds = ("real", "padding", "skipped")
+
+    def served(lengths):
+        before = [text_generation.PREFILL_SLOTS.value(kind=kind, model=model)
+                  for kind in kinds]
+        ((_, config),) = pipe.run_batched(
+            [{"prompt_ids": [rng.integers(0, 128, n).tolist()
+                             for n in lengths], "rng": jax.random.key(0)}],
+            max_new_tokens=2, temperature=0.0)
+        return config, dict(zip(kinds, (
+            text_generation.PREFILL_SLOTS.value(kind=kind, model=model) - was
+            for kind, was in zip(kinds, before))))
+
+    ragged = list(range(9, 17)) + [8, 7, 8, 7, 8, 7, 8, 7]
+    first, slots = served(ragged)
+    assert (first["padded_rows"], first["prompt_slots"]) == (16, 16)
+    assert first["prefill_chunk_widths"] == {"16": 1, "8": 1}
+    assert slots == {"real": sum(ragged), "skipped": 8 * 8,
+                     "padding": 8 * 16 + 8 * 8 - sum(ragged)}
+    program = pipe.prefill_program(16, 16, 16 + 2)
+    assert program._cache_size() == 1
+    programs = len(pipe._programs)
+    second, slots = served([16] * 15 + [12])
+    assert second["prefill_chunk_widths"] == {"16": 2}
+    assert slots["skipped"] == 0
+    third, slots = served([4, 3] * 4 + [1])  # seven rows only pad the pass
+    assert third["prefill_chunk_widths"] == {"4": 2}
+    assert third["prefill_chunks_skipped"] == 1
+    assert slots["skipped"] == 16 * 16 - 9 * 4
+    assert program._cache_size() == 1 and len(pipe._programs) == programs
+    layers, held = pipe.config.expert_layers, pipe.config.experts_held[1]
+    for config in (first, second, third):
+        prefill = config["routing"]["prefill"]
+        assert prefill["calls"] == config["prefill_chunks"] * layers == sum(
+            config["prefill_chunk_widths"].values()) * layers
+        assert prefill["active"] <= prefill["calls"] * held
+    for config in (first, second):
+        prefill = config["routing"]["prefill"]
+        assert (prefill["calls"] - 1) * held < prefill["active"]
