@@ -158,7 +158,9 @@ def expert_layer(p, cfg, h, valid=None, interpret: bool = False):
     expert's for tokens `h` [T, hidden] (`valid` [T]: padding is routed
     nowhere). Returns the sum
     and how the routing fell: pairs of each held expert [held], and
-    (routed pairs, the fullest expert's pairs, experts with pairs)."""
+    (routed pairs, the fullest expert's pairs, experts with pairs, the
+    row tiles the grouped matmul visited: `ops.expert_matmul.plan`'s
+    `n_tiles`, reckoned here from the pairs)."""
     first, held = cfg.experts_held
     chosen, weights = route(p, cfg, h)
     local = chosen - first
@@ -169,8 +171,10 @@ def expert_layer(p, cfg, h, valid=None, interpret: bool = False):
     routed = jnp.sum(parts.astype(jnp.float32) * weights[..., None], axis=1)
     count = (jnp.int32(h.shape[0]) if valid is None
              else jnp.sum(valid.astype(jnp.int32)))
+    tm = row_tile(h.shape[0])
     stats = jnp.stack([count * cfg.num_experts_per_tok, jnp.max(sizes),
-                       jnp.sum((sizes > 0).astype(jnp.int32))])
+                       jnp.sum((sizes > 0).astype(jnp.int32)),
+                       jnp.sum((sizes + tm - 1) // tm)])
     out = routed.astype(h.dtype)
     if "shared" in p:
         shared = swiglu(p["shared"], h)
@@ -191,10 +195,10 @@ def feed_forward(layer, cfg, h, valid, interpret):
 
 def empty_load(cfg):
     """The routing's tally of a pass, all zero: pairs of each held expert
-    of each expert layer, and (routed, fullest, active) summed over the
-    expert layers' calls."""
+    of each expert layer, and (routed, fullest, active, row tiles) summed
+    over the expert layers' calls."""
     return (jnp.zeros((cfg.expert_layers, cfg.experts_held[1]), jnp.int32),
-            jnp.zeros((3,), jnp.int32))
+            jnp.zeros((4,), jnp.int32))
 
 
 def tally(load, index: int, cfg, told):

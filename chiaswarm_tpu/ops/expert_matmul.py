@@ -12,6 +12,19 @@ rows are visited (the grid's first extent is a traced number, as in
 so no tile is masked and a row's result does not depend on where the sort
 put it or on who its batchmates are: the same K-order of the same products.
 
+Blocks (`blocks`): the grid runs row tile -> N -> K and Pallas moves a block
+again only when its index changed from the step before. A weight block's
+index is `(expert, k, j)`, so it stays put between an expert's consecutive
+row tiles exactly when both K and N are one block: then an expert's
+matrices cross from HBM once a call, however many row tiles the expert
+has, and a later tile's step moves only its rows in and its result out.
+N is one block up to `_MAX_TN`; K is taken whole wherever N is one block
+and a step's buffers (two of the row block, of each weight's block and of
+the output, and the float32 products) fit `_VMEM_SHARE` of the VMEM the
+kernel declares, else cut to `_MAX_TK` as a stream of blocks that fit
+(matrices of tens of MB: each row tile fetches its expert's again). The
+rule reads the shapes alone.
+
 Routing (ops/platform.py): on a TPU the Pallas kernel (`grouped`), else a
 gather of each tile's matrix and one einsum (`reference`, the CPU path of
 the tiny presets). `interpret=True` runs the kernel interpreted, for tests.
@@ -34,11 +47,16 @@ from . import platform
 # MXU); a prefill chunk hands it a hundred and more
 _TILE_SMALL, _TILE_LARGE = 16, 128
 _LARGE_FROM_TOKENS = 1024
-# block extents over K and N: the largest divisor that is a multiple of a
-# lane tile and at most this (a [1024, 2048] bf16 block is 4 MB, two in
-# flight; a smaller matrix is one block)
+# block extents over K and N where a matrix is streamed: the largest
+# divisor that is a multiple of a lane tile and at most this (a
+# [1024, 2048] bf16 block is 4 MB, two in flight). N up to `_MAX_TN` is one
+# block, and K is then whole if the step fits (`blocks`): the weight
+# block's index `(expert, 0, 0)` stays put between an expert's row tiles,
+# where with two K blocks it alternates and every tile fetches them again
 _MAX_TK, _MAX_TN = 1024, 2048
 _VMEM_LIMIT = 48 * 1024 * 1024
+# what a step's blocks may take of it: the rest is the compiler's own
+_VMEM_SHARE = 0.5
 
 
 def row_tile(tokens: int) -> int:
@@ -104,40 +122,67 @@ def _block(extent: int, most: int) -> int:
     return extent
 
 
+def blocks(width: int, n: int, n_weights: int, tm: int,
+           itemsize: int) -> tuple[int, int]:
+    """Block extents (tk, tn) of a `[tm, width] x [width, n]` step with
+    `n_weights` matrices at once. K whole where N is one block (only then
+    does the weight block's index stay put between an expert's row tiles)
+    and the step's buffers fit: two each of the row block, every weight's
+    block and the output, and each product in float32."""
+    tn = _block(n, _MAX_TN)
+    step = (2 * itemsize * (tm * width + n_weights * width * tn + tm * tn)
+            + 4 * n_weights * tm * tn)
+    if tn == n and step <= _VMEM_SHARE * _VMEM_LIMIT:
+        return width, tn
+    return _block(width, _MAX_TK), tn
+
+
 def _kernel(tile_expert_ref, n_tiles_ref, x_ref, *refs, gated: bool,
             k_blocks: int):
     """One (row tile, N block, K block) step: the tile's rows times its
-    expert's block, accumulated in float32 over K; with `gated` two
-    matrices at once and SiLU(gate) * up on the way out."""
+    expert's block, summed in float32 over K; with `gated` two matrices at
+    once and SiLU(gate) * up on the way out. With one K block the product
+    is the sum and goes straight out; with several it is added up in
+    scratch accumulators (`refs` past the output)."""
     del tile_expert_ref
     weights = refs[:2] if gated else refs[:1]
     out_ref = refs[len(weights)]
     accs = refs[len(weights) + 1:]
     k = pl.program_id(2)
 
+    def product(x, w_ref):
+        return jax.lax.dot_general(
+            x, w_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def write(sums):
+        if gated:
+            gate, up = sums
+            out = gate * jax.nn.sigmoid(gate) * up
+        else:
+            out, = sums
+        out_ref[...] = out.astype(out_ref.dtype)
+
     # a static grid (the interpreter's) also walks the tiles past the last
     # that holds rows: nothing is computed there
     @pl.when(pl.program_id(0) < n_tiles_ref[0])
     def _():
+        x = x_ref[...]
+        if k_blocks == 1:
+            write([product(x, w_ref) for w_ref in weights])
+            return
+
         @pl.when(k == 0)
         def _():
             for acc in accs:
                 acc[...] = jnp.zeros_like(acc)
 
-        x = x_ref[...]
         for w_ref, acc in zip(weights, accs):
-            acc[...] += jax.lax.dot_general(
-                x, w_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            acc[...] += product(x, w_ref)
 
         @pl.when(k == k_blocks - 1)
         def _():
-            if gated:
-                gate, up = accs[0][...], accs[1][...]
-                out = gate * jax.nn.sigmoid(gate) * up
-            else:
-                out = accs[0][...]
-            out_ref[...] = out.astype(out_ref.dtype)
+            write([acc[...] for acc in accs])
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
@@ -145,7 +190,8 @@ def _grouped(x, weights, tile_expert, n_tiles, *, tm: int,
              interpret: bool = False):
     rows, width = x.shape
     n = weights[0].shape[-1]
-    tk, tn = _block(width, _MAX_TK), _block(n, _MAX_TN)
+    itemsize = jnp.dtype(x.dtype).itemsize
+    tk, tn = blocks(width, n, len(weights), tm, itemsize)
     k_blocks = width // tk
     tiles = rows // tm
     n_tiles = jnp.reshape(n_tiles, (1,)).astype(jnp.int32)
@@ -160,7 +206,6 @@ def _grouped(x, weights, tile_expert, n_tiles, *, tm: int,
     out_spec = pl.BlockSpec((tm, tn),
                             lambda i, j, k, te, nt: (tile(i, nt), j))
     gated = len(weights) == 2
-    itemsize = jnp.dtype(x.dtype).itemsize
     return pl.pallas_call(
         functools.partial(_kernel, gated=gated, k_blocks=k_blocks),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -169,8 +214,9 @@ def _grouped(x, weights, tile_expert, n_tiles, *, tm: int,
             grid=(tiles if interpret else n_tiles[0], n // tn, k_blocks),
             in_specs=[x_spec] + [w_spec] * len(weights),
             out_specs=out_spec,
+            # accumulators only where K is summed over several blocks
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)
-                            for _ in weights]),
+                            for _ in weights] if k_blocks > 1 else []),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),

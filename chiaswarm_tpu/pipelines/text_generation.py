@@ -76,8 +76,11 @@ run because none of their rows reached them, `chunk_plan` and
 `span_runs`) and the chunks it ran at each width
 (`swarm_prefill_chunks_total{model, width}`); how the routing fell comes
 back with the ids (`swarm_expert_pairs_total`, `swarm_routed_tokens_total`,
-`swarm_expert_pairs_max_total`; the envelope's `routing` has the same a
-program, with the experts that had a pair and the calls).
+`swarm_expert_pairs_max_total`, `swarm_expert_row_tiles_total`; the
+envelope's `routing` has the same a program, with the experts that had a
+pair, `active`, and the calls: `tiles / active` is the row tiles an
+expert's matrices serve in a call, for which they cross from HBM once
+where the grouped kernel holds them in one block, `ops/expert_matmul.py`).
 
 No tokenizer: ids travel on the wire, and there is no stop token, every
 row generates `max_new_tokens`. `test/` names are seeded weights: `tiny` in
@@ -144,6 +147,12 @@ EXPERT_PAIRS_MAX = telemetry.counter(
     "swarm_expert_pairs_max_total",
     "The fullest held expert's pairs, summed over expert-layer calls, by "
     "model", ("model",))
+EXPERT_ROW_TILES = telemetry.counter(
+    "swarm_expert_row_tiles_total",
+    "Row tiles the grouped matmul visited (each held expert's pairs in "
+    "whole tiles), summed over expert-layer calls, by model: over the "
+    "experts that had a pair, the tiles an expert's matrices serve",
+    ("model",))
 PASS_CACHE_BYTES = telemetry.gauge(
     "swarm_pass_cache_bytes",
     "Bytes of the cache one pass holds (every layer's, rows x the "
@@ -577,7 +586,7 @@ class TextGenerationPipeline:
         def tally(pairs, sums, calls):
             return {"pairs": int(pairs.sum()), "routed": int(sums[0]),
                     "pairs_max": int(sums[1]), "active": int(sums[2]),
-                    "calls": calls}
+                    "tiles": int(sums[3]), "calls": calls}
 
         # the whole pass, and its two programs apart (decode's tally began
         # where prefill's ended)
@@ -640,6 +649,7 @@ class TextGenerationPipeline:
         EXPERT_PAIRS.inc(routing["pairs"], **label)
         ROUTED_TOKENS.inc(routing["routed"], **label)
         EXPERT_PAIRS_MAX.inc(routing["pairs_max"], **label)
+        EXPERT_ROW_TILES.inc(routing["tiles"], **label)
         prompt_tokens = int(lengths.sum())
         PREFILL_SLOTS.inc(prompt_tokens, kind="real", **label)
         PREFILL_SLOTS.inc(computed - prompt_tokens, kind="padding", **label)

@@ -106,10 +106,12 @@ def test_chunked_prefill_and_two_caches_give_the_references_logits(
             params, SIZES, np.concatenate([ids[row, :length], given[row]]),
             held=CFG.experts_held))[length - 1:]
         assert np.linalg.norm(got[row] - want) / np.linalg.norm(want) < 1e-5
-    pairs, (routed, _, _) = (np.asarray(x) for x in load)
+    pairs, (routed, _, active, tiles) = (np.asarray(x) for x in load)
     tokens = int(lengths.sum()) + rows * new
     assert routed == tokens * CFG.num_experts_per_tok * CFG.expert_layers
     assert 0 < pairs.sum() < routed
+    # an expert with a pair has a row tile, 16 pairs fill one
+    assert active <= tiles < active + pairs.sum() / 16
 
 
 def test_prefill_writes_every_element_of_the_cache(params, monkeypatch):

@@ -169,12 +169,28 @@ def test_admission_rule_stays_inside_the_scoped_vmem_limit():
     # block beside the new one), 128 pairs an expert on average
     pytest.param(2048, 2048, 768, True, 128, id="sdar-fused-gate-up"),
     pytest.param(2048, 768, 2048, False, 128, id="sdar-fused-down"),
+    # Qwen3-Next's 128 held experts (hidden 2048, expert width 512): a
+    # decode step's 256 tokens in 16-row tiles, a prefill chunk's 4096
+    pytest.param(256, 2048, 512, True, 128, id="qwen3next-decode-gate-up"),
+    pytest.param(256, 512, 2048, False, 128, id="qwen3next-decode-down"),
+    pytest.param(4096, 2048, 512, True, 128, id="qwen3next-prefill-gate-up"),
+    pytest.param(4096, 512, 2048, False, 128, id="qwen3next-prefill-down"),
+    # K-EXAONE's 16 held experts (hidden 6144, expert width 2048): a
+    # prefill span's 4096 tokens
+    pytest.param(4096, 6144, 2048, True, 16, id="exaone-prefill-gate-up"),
+    pytest.param(4096, 2048, 6144, False, 16, id="exaone-prefill-down"),
 ])
 def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated, held):
     """The grouped kernel at the row buffer's worst-case size, its grid's
-    first extent a traced number (only the tiles that hold rows)."""
+    first extent a traced number (only the tiles that hold rows), under
+    the VMEM it declares. ISSUE 44: SDAR's and Qwen3-Next's matrices are
+    one block (K whole: an expert's matrices stay in VMEM over its row
+    tiles, and no accumulator is kept), Kimi's and K-EXAONE's are streamed
+    in the blocks they had."""
     from chiaswarm_tpu.ops.expert_matmul import (
+        _VMEM_LIMIT,
         _grouped,
+        blocks,
         buffer_rows,
         row_tile,
     )
@@ -187,7 +203,15 @@ def test_expert_matmul_compiles_for_v5e(v5e, tokens, width, n, gated, held):
     ).lower(_shape(v5e, (rows, width)), weights,
             _shape(v5e, (rows // tm,), jnp.int32),
             _shape(v5e, (), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    one_block = blocks(width, n, len(weights), tm, 2) == (width, n)
+    assert one_block == (min(width, n) <= 768)
+    # the scoped memory the call was compiled under is what the kernel
+    # declares: a step over it is refused by the compile above
+    call, = (line for line in text.splitlines()
+             if "custom-call(" in line and "expert_matmul" in line)
+    assert f'"size":"{_VMEM_LIMIT}"' in call
 
 
 @pytest.mark.parametrize("rows,heads,keys,values", [

@@ -73,10 +73,12 @@ def test_prefill_and_cached_decode_give_the_references_logits(params, absorb):
             held=CFG.experts_held))[length - 1:]
         assert np.linalg.norm(got[row] - want) / np.linalg.norm(want) < 1e-5
     # what the routing's tally counted: every real token, k pairs a layer
-    pairs, (routed, _, _) = (np.asarray(x) for x in load)
+    pairs, (routed, _, active, tiles) = (np.asarray(x) for x in load)
     tokens = int(lengths.sum()) + rows * new
     assert routed == tokens * CFG.num_experts_per_tok * CFG.expert_layers
     assert 0 < pairs.sum() < routed
+    # an expert with a pair has a row tile, 16 pairs fill one
+    assert active <= tiles < active + pairs.sum() / 16
 
 
 def _ragged(order, lengths, vocabulary, seed=5):

@@ -746,6 +746,69 @@ def test_a_pass_counts_real_padding_and_skipped_slots(
 
 
 @pytest.mark.parametrize("model, extra", [
+    ("test/tiny-kimi", {}), ("test/tiny-sdar", {"denoising_steps": 2}),
+    ("test/tiny-qwen3-next", {})],
+    ids=["kimi", "sdar", "qwen3_next"])
+def test_the_tally_counts_the_row_tiles_plan_reports(monkeypatch, model,
+                                                     extra):
+    """ISSUE 44: the routing's fourth sum. `routing.prefill.tiles` and
+    `routing.decode.tiles` are the row tiles `ops.expert_matmul.plan` told
+    the grouped kernel to visit (`n_tiles` of every expert-layer call, read
+    off the device as the programs run), an expert that had a pair has at
+    least one, and the counter moves by the pass's."""
+    import jax
+
+    from chiaswarm_tpu.models import experts
+    from chiaswarm_tpu.pipelines import text_generation
+
+    seen, program = {"prefill": 0, "decode": 0}, ["prefill"]
+
+    def count(n_tiles):
+        seen[program[0]] += int(n_tiles)
+
+    def counted(local, groups, tm):
+        where = plan(local, groups, tm)
+        jax.debug.callback(count, where.n_tiles)
+        return where
+
+    plan = experts.plan
+    monkeypatch.setattr(experts, "plan", counted)
+    pipe = text_generation.TextGenerationPipeline(
+        model, allow_random_init=True)
+    name = "block_decode_program" if pipe.by_blocks else "decode_program"
+    decode_program = getattr(pipe, name)
+
+    def after_prefill(*key):
+        # `run_batched` asks for it once the prefill's tally is ready
+        jax.effects_barrier()
+        program[0] = "decode"
+        return decode_program(*key)
+
+    monkeypatch.setattr(pipe, name, after_prefill)
+    rng = np.random.default_rng(44)
+    prompts = [rng.integers(0, 128, n).tolist()
+               for n in [16, 15, 16, 12, 16, 14, 16, 13, 16, 16, 9, 16]]
+    was = text_generation.EXPERT_ROW_TILES.value(model=model)
+    ((_, config),) = pipe.run_batched(
+        [{"prompt_ids": prompts, "rng": jax.random.key(3)}],
+        max_new_tokens=8, temperature=1.0, **extra)
+    jax.effects_barrier()
+    routing = config["routing"]
+    assert {part: routing[part]["tiles"] for part in seen} == seen
+    assert min(seen.values()) > 0
+    assert routing["tiles"] == sum(seen.values()) == (
+        text_generation.EXPERT_ROW_TILES.value(model=model) - was)
+    for part in (routing, routing["prefill"], routing["decode"]):
+        # 16-row tiles at this size: no fewer than the pairs fill, and no
+        # more than one partly filled tile an expert that had a pair
+        assert part["active"] <= part["tiles"] < (
+            part["active"] + part["pairs"] / 16)
+        assert part["tiles"] >= part["pairs"] / 16
+    # a chunk of sixteen rows hands an expert more pairs than a tile holds
+    assert routing["prefill"]["tiles"] > routing["prefill"]["active"]
+
+
+@pytest.mark.parametrize("model, extra", [
     ("test/tiny-kimi", {}), ("test/tiny-sdar", {"denoising_steps": 2})],
     ids=["a_token_a_step", "by_blocks"])
 def test_a_jobs_ids_do_not_depend_on_where_its_rows_stand(monkeypatch, model,
