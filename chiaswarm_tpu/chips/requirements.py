@@ -22,7 +22,7 @@ measured fits (SDXL batch 4 @ 1024^2 runs on one 16 GB v5e chip with
 
 from __future__ import annotations
 
-from ..coalesce import text_family_of
+from ..text_families import TEXT_FAMILIES, text_family_of
 from ..models.configs import model_family
 
 # static parameter + resident-state footprint, GiB (bf16, incl. text/vae)
@@ -61,63 +61,12 @@ FAMILY_ACT_GB_PER_IMAGE: dict[str, float] = {
 }
 
 # Families whose rows are sequences, not canvases (a text job's rows:
-# pipelines/text_generation.py). Admission is the weights the chip holds, a
-# working set that does not grow with the rows (a prefill chunk's
-# activations and worst-case expert buffer, the logits), and a row's cache:
-# `size` is then the positions a row keeps (prompt slots + new tokens), and
-# a row costs, a kind of layer, `min(size, the kind's window or size)`
-# positions of `bytes a position` (`cache_layers`: (bytes a position summed
-# over the layers of the kind, the window they keep or 0 for every
-# position)), and, where the family has layers that keep a recurrent state
-# and no keys, `row_bytes`: what those layers hold a row whatever its
-# positions, so that a row never costs nothing however short it is.
-# kimi_k2 is one chip's share of a 32-chip expert-parallel deployment
-# (models/kimi.py KIMI_K2_EP32): 4.85 B parameters in bf16 = 9.70 GB; a
-# position is 576 values x 2 bytes on each of 7 layers, all kept whole; the
-# working set is what the compile for a described v5e counted for the
-# 256-row programs beside weights and cache (benchmark/compile_check.py,
-# PERF.md).
-# exaone_moe is one chip's share of an 8-chip deployment (models/exaone.py
-# EXAONE_236B_EP8): 3.71 B parameters = 7.42 GB; a position is a key and a
-# value on 8 heads of 128 x 2 bytes = 4096 B a layer, kept whole on the one
-# full layer and as a ring of 128 on the four sliding ones; the working set
-# is a 4096-token chunk's activations, its expert buffer and a 16384-slot
-# row's keys beside them.
-# sdar_moe is one stage of an 8-stage pipeline with every expert held
-# (models/sdar.py SDAR_30B_PP8): 4.36 B parameters = 8.72 GB; a position is
-# a key and a value on 4 heads of 128 x 2 bytes = 2048 B on each of 6
-# layers, all kept whole; the working set is what the compile for a
-# described v5e counted for the 256-row block decode beside weights and
-# cache (benchmark/compile_check.py, PERF.md: 4.14 GB of temporaries, a
-# block step's float32 logits over the whole vocabulary, the sampler's
-# copies of them and its random bits, the expert buffer; the columns a
-# pass's last committed block may overhang `prompt slots + new tokens` by
-# are in it too).
-# qwen3_next is one of 4 chips that share each layer, one of 6 pipeline
-# stages (models/qwen3_next.py QWEN3_NEXT_80B_EP4): 3.667 B parameters =
-# 7.33 GB; each of the 6 linear layers holds a row a float32 state of 32
-# heads x 128 x 128 (2,097,152 B) and a convolution's tail of 3 x 8192
-# values x 2 bytes (49,152 B): 12,877,824 B a row whatever its positions; a
-# position is a key and a value on 2 heads of 256 x 2 bytes = 2048 B on
-# each of the 2 full layers, kept whole; the working set is what the
-# compile for a described v5e counted beside weights and cache for the
-# 256-row programs (1.36 GB of temporaries for the prefill, a chunk's
-# float32 operands of the chunk rule among them, 0.60 GB for the decode:
-# benchmark/compile_check.py, PERF.md; the chip's own peak lies 0.12 GB
-# over weights and cache).
-# The families are coalesce.py `TEXT_FAMILIES`' (the jax-free table of
-# their names), one entry each.
-SEQUENCE_FAMILIES: dict[str, dict] = {
-    "kimi_k2": {"params_gb": 9.04, "working_gb": 3.0,
-                "cache_layers": ((8064.0, 0),)},
-    "exaone_moe": {"params_gb": 6.91, "working_gb": 3.0,
-                   "cache_layers": ((4096.0, 0), (16384.0, 128))},
-    "sdar_moe": {"params_gb": 8.12, "working_gb": 3.9,
-                 "cache_layers": ((12288.0, 0),)},
-    "qwen3_next": {"params_gb": 6.83, "working_gb": 1.5,
-                   "cache_layers": ((4096.0, 0),),
-                   "row_bytes": 12877824.0},
-}
+# pipelines/text_generation.py) are text_families.py `TEXT_FAMILIES`': a
+# row of it has the weights the chip holds, the working set and what a row's
+# cache costs (`params_gb`, `working_gb`, `cache_layers`, `row_bytes`, said
+# there with where each number came from); `size` is then the positions a
+# row keeps (prompt slots + new tokens).
+
 # the cached positions one pass holds at most, whatever the memory left:
 # 256 rows of 512 positions. A pass is budgeted in positions and not in
 # rows, at the job's own `prompt slots + new tokens`: a pass of long rows
@@ -136,17 +85,10 @@ def sequence_row_bytes(family: str, positions: int) -> float:
     its kind: the positions it keeps, and what it holds a row whatever
     they are."""
     positions = max(int(positions), 1)
-    costs = SEQUENCE_FAMILIES[family]
+    costs = TEXT_FAMILIES[family]
     return costs.get("row_bytes", 0.0) + sum(
         per_position * (min(positions, window) if window else positions)
         for per_position, window in costs["cache_layers"])
-
-
-def sequence_family_positions() -> dict[str, int]:
-    """{family: the positions its advertised appetite is reckoned at}; a
-    family's key resolves to itself as a model name (`_family_key`)."""
-    return {family: SEQUENCE_REFERENCE_POSITIONS
-            for family in SEQUENCE_FAMILIES}
 
 
 def pass_positions_limit(chipset, family: str) -> int:
@@ -155,7 +97,7 @@ def pass_positions_limit(chipset, family: str) -> int:
     weights holds of them (a position reckoned as a reference row's)."""
     if chipset is None or chipset.platform != "tpu":
         return SEQUENCE_PASS_POSITIONS
-    costs = SEQUENCE_FAMILIES[family]
+    costs = TEXT_FAMILIES[family]
     free = (chipset.hbm_bytes() / max(chipset.chip_count(), 1)
             - (costs["params_gb"] + costs["working_gb"]) * (1 << 30))
     a_position = (sequence_row_bytes(family, SEQUENCE_REFERENCE_POSITIONS)
@@ -201,8 +143,7 @@ def _family_key(model_name: str) -> str:
 # (stable_diffusion.py `_family_configs`, flux.py `_flux_configs`); every
 # other pipeline gives any `test/` name its tiny preset
 _PUBLISHED_TEST_FAMILIES = frozenset(
-    {"sd15", "sd21", "sdxl", "sdxl_refiner", "flux", "kimi_k2",
-     "exaone_moe", "sdar_moe", "qwen3_next"})
+    {"sd15", "sd21", "sdxl", "sdxl_refiner", "flux", *TEXT_FAMILIES})
 
 
 def _is_stand_in(model_name: str) -> bool:
@@ -228,7 +169,7 @@ def _area_scale(height: int, width: int | None = None) -> float:
 def _sequence_costs(fam: str, positions: int) -> tuple[float, float]:
     """(GiB that do not grow with the rows, GiB a row) of a sequence
     family: weights + working set, and a row's cache at `positions`."""
-    costs = SEQUENCE_FAMILIES[fam]
+    costs = TEXT_FAMILIES[fam]
     return (costs["params_gb"] + costs["working_gb"],
             sequence_row_bytes(fam, positions) / (1 << 30))
 
@@ -238,7 +179,7 @@ def required_hbm_gb(model_name: str, batch: int, size: int,
     """Estimated HBM for `batch` images at size x (width or size); for a
     sequence family, `batch` rows of `size` positions."""
     fam = _family_key(model_name)
-    if fam in SEQUENCE_FAMILIES:
+    if fam in TEXT_FAMILIES:
         fixed, per_row = _sequence_costs(fam, size)
         return fixed + batch * per_row
     params = FAMILY_PARAMS_GB.get(fam, _DEFAULT_PARAMS_GB)
@@ -345,7 +286,7 @@ def fit_batch(chipset, model_name: str, batch: int, size: int,
     # shard holds ceil(batch/data) images, so the largest admissible
     # batch is floor(free / per_image) * data.
     fam = _family_key(model_name)
-    if fam in SEQUENCE_FAMILIES:
+    if fam in TEXT_FAMILIES:
         # one chip's share of a deployment is one chip's: nothing of it
         # is divided over a slice's chips, and every chip sees every row
         fixed, per_row = _sequence_costs(fam, size)
@@ -392,7 +333,7 @@ def coalesce_rows_limit(chipset, model_name: str, size: int,
     every admissible group's PADDED pass fit too.
     """
     allowed = fit_batch(chipset, model_name, ceiling, size, width)
-    if _family_key(model_name) in SEQUENCE_FAMILIES and allowed >= 1:
+    if _family_key(model_name) in TEXT_FAMILIES and allowed >= 1:
         # a pass of sequences is budgeted in cached positions too, at the
         # job's own (`size`), on any platform: what a pass is does not
         # depend on the memory beside it
@@ -435,7 +376,7 @@ def check_capacity(chipset, model_name: str, batch: int, size: int,
         hbm_gb = chipset.hbm_bytes() / (1 << 30)
         per_chip = hbm_gb / max(chipset.chip_count(), 1)
         fam = _family_key(model_name)
-        if fam in SEQUENCE_FAMILIES:
+        if fam in TEXT_FAMILIES:
             raise ValueError(
                 f"{model_name} does not fit on this {chipset.chip_count()}"
                 f"-chip slice ({per_chip:.0f} GB HBM a chip): its weights, "

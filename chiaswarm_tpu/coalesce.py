@@ -29,6 +29,10 @@ from __future__ import annotations
 
 import os
 
+# the text families' table and the family a model's name tells: theirs, handed
+# on because this module is the one every side imports
+from .text_families import TEXT_FAMILIES, text_family_of  # noqa: F401
+
 # wire pipeline_type strings whose txt2img semantics the batched program
 # reproduces exactly (plain prompt-conditioned CFG denoise + decode)
 _BATCHABLE_PIPELINE_TYPES = {
@@ -106,21 +110,6 @@ _BATCHABLE_CN_PIPELINE_TYPES = {
     "StableDiffusionXLControlNetPipeline",
 }
 
-# families whose jobs carry token ids and whose rows are sequences
-# (pipelines/text_generation.py): the word of a model's name that tells the
-# family, the wire name of its pipeline type and, where the family decodes
-# a block of positions at a time, the block's length. Here because this
-# module is the one every side imports (the registry's `PIPELINE_FAMILIES`
-# and `_auto_family`, chips/requirements.py `_family_key` and
-# `SEQUENCE_FAMILIES`, the pipeline's `_MODELS` read or repeat its keys;
-# tests/test_text_serving.py holds them equal)
-TEXT_FAMILIES: dict[str, dict] = {
-    "kimi_k2": {"name": "kimi", "wire": "KimiK2ForCausalLM"},
-    "exaone_moe": {"name": "exaone", "wire": "ExaoneMoeForCausalLM"},
-    "sdar_moe": {"name": "sdar", "wire": "SdarMoeForCausalLM",
-                 "block_length": 4},
-    "qwen3_next": {"name": "qwen3-next", "wire": "Qwen3NextForCausalLM"},
-}
 # the only `parameters` keys a batchable text job may carry, and those a
 # job of a family that decodes by blocks may carry besides
 _SAFE_TEXT_PARAMETER_KEYS = frozenset(
@@ -198,17 +187,6 @@ def text_shape(job: dict) -> tuple[int, int] | None:
     new = int(params.get("max_new_tokens",
                          job.get("max_new_tokens", DEFAULT_NEW_TOKENS)))
     return prompt_slots(max(len(row) for row in rows)), new
-
-
-def text_family_of(model_name: str) -> str | None:
-    """The text family a model's name tells, None for any other model."""
-    name = model_name.lower()
-    for family, what in TEXT_FAMILIES.items():
-        # the family's word, or its key (a worker reckons a family's
-        # appetite with the key as the model's name)
-        if what["name"] in name or family in name:
-            return family
-    return None
 
 
 def checked_denoising_steps(steps, length: int) -> int:

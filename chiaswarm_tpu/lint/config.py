@@ -30,6 +30,7 @@ ACCELERATOR_PACKAGES = ("jax", "jaxlib", "flax", "torch", "transformers",
 JAXFREE_ROOTS = (
     "chiaswarm_tpu/hive_server",
     "chiaswarm_tpu/coalesce.py",
+    "chiaswarm_tpu/text_families.py",
     "chiaswarm_tpu/telemetry.py",
     "chiaswarm_tpu/outbox.py",
     "chiaswarm_tpu/settings.py",

@@ -25,7 +25,7 @@ sliding layer keeps a ring of `sliding_window` columns, position `p` at
 column `p mod window`, so it holds the window's keys whatever the length.
 A key is cached as attention reads it: normed and, on a sliding layer,
 rotated. What a row sees of either follows from its own length and the
-step (`decode_masks`).
+step (`decode_mask`, `ring_mask`).
 
 Prefill goes in chunks of positions (`prefill`): a chunk's queries attend
 to the keys cached so far and its own through `ops.attention` (causal,
@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import dot_product_attention
+from . import prefill_chunks
 from .experts import (
     dot,
     empty_load,
@@ -56,8 +57,7 @@ from .experts import (
     rms_norm,
     tally,
 )
-from .kimi import apply_rope
-from .prefill_chunks import whole_rows
+from .text_model import apply_rope, cached_attention, decode_mask, rope_tables
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,14 +175,6 @@ def init_params(cfg: ExaoneConfig, key, dtype) -> dict:
 # --- attention ---------------------------------------------------------------
 
 
-def rope_tables(cfg: ExaoneConfig, positions):
-    """cos and sin `[..., head_dim / 2]` of whole-number `positions`."""
-    exponent = jnp.arange(0, cfg.head_dim, 2, dtype=jnp.float32) / cfg.head_dim
-    angles = (positions.astype(jnp.float32)[..., None]
-              / cfg.rope_theta ** exponent)
-    return jnp.cos(angles), jnp.sin(angles)
-
-
 def _heads(p, cfg: ExaoneConfig, h, positions, window: int):
     """`h` [..., hidden] at `positions` [...] as queries [..., heads,
     head_dim] and the keys and values [..., key heads, head_dim] the cache
@@ -194,27 +186,10 @@ def _heads(p, cfg: ExaoneConfig, h, positions, window: int):
     q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
     k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
     if window:
-        cos, sin = rope_tables(cfg, positions)
+        cos, sin = rope_tables(cfg.head_dim, cfg.rope_theta, positions)
         q = apply_rope(q, cos[..., None, :], sin[..., None, :])
         k = apply_rope(k, cos[..., None, :], sin[..., None, :])
     return q, k, v
-
-
-def cached_attention(q, keys, values, mask, scale: float):
-    """One new token a row against its cache: `q` [R, heads, D], `keys` /
-    `values` [R, S, key heads, D], `mask` [R, S] the columns the row may
-    see. A group's query heads meet their one cached head in one batched
-    matmul; the cache is not repeated."""
-    rows, heads, d = q.shape
-    kv_heads = keys.shape[2]
-    q = q.reshape(rows, kv_heads, heads // kv_heads, d)
-    scores = jnp.einsum("rhgd,rshd->rhgs", q, keys,
-                        preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
-    weights = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
-    out = jnp.einsum("rhgs,rshd->rhgd", weights, values,
-                     preferred_element_type=jnp.float32)
-    return out.astype(values.dtype).reshape(rows, heads * d)
 
 
 def ring_fill(ring, entry, start: int, lengths):
@@ -232,20 +207,14 @@ def ring_fill(ring, entry, start: int, lengths):
     return jnp.where(mine[:, :, None, None], picked, ring)
 
 
-def decode_masks(cfg: ExaoneConfig, lengths, number, slots: int,
-                 positions: int):
-    """What a row sees at generated token `number`, a kind of cache:
-    (full [R, positions]: its prompt's first `lengths` columns and the
-    generated columns up to `slots + number`, which is being written; ring
-    [R, window]: column `j` holds the last position congruent to `j` up to
-    the token's own, seen if there is one)."""
-    columns = jnp.arange(positions)[None, :]
-    full = (columns < lengths[:, None]) | (
-        (columns >= slots) & (columns <= slots + number))
+def ring_mask(cfg: ExaoneConfig, lengths, number):
+    """[R, window]: what a row sees of a ring at generated token `number`:
+    column `j` holds the last position congruent to `j` up to the token's
+    own, seen if there is one (of a full layer's cache it sees what
+    models/text_model.py `decode_mask` says)."""
     at = (lengths + number)[:, None]
-    ring = at - jnp.mod(at - jnp.arange(cfg.sliding_window)[None, :],
+    return at - jnp.mod(at - jnp.arange(cfg.sliding_window)[None, :],
                         cfg.sliding_window) >= 0
-    return full, ring
 
 
 # --- prefill and decode ------------------------------------------------------
@@ -261,27 +230,27 @@ def new_cache(cfg: ExaoneConfig, rows: int, positions: int, dtype):
 
 
 def cache_bytes(cfg: ExaoneConfig, rows: int, positions: int,
-                itemsize: int) -> tuple[int, int]:
-    """(bytes of a pass's cache, the part of it that is rings of a
-    window)."""
+                itemsize: int) -> tuple[int, int, int]:
+    """(bytes of a pass's cache, the part of it that is rings of a window,
+    the part that is recurrent state: none)."""
     rings = sum(rows * window * cfg.position_bytes * itemsize
                 for window in cfg.windows if window)
     whole = sum(rows * positions * cfg.position_bytes * itemsize
                 for window in cfg.windows if not window)
-    return whole + rings, rings
+    return whole + rings, rings, 0
 
 
 # a prefill chunk may be a span of one row's positions
 POSITION_CHUNKS = True
 
 
-def span_runs(lengths, start: int):
-    """Whether a chunk's span of positions from `start` is run: some row
-    of the chunk has a prompt token at `start` or past it. `lengths` are
-    the chunk's own rows' (the device's in `prefill_rows`, the host's
-    where a pass counts what it left out). A row's tokens are its first
-    `lengths` positions, so once a span is not run no later one is."""
-    return (lengths > start).any()
+def prefill_account(lengths, slots: int, chunk_rows: int, chunk_slots: int):
+    """The host's account of what `prefill` ran (models/text_model.py):
+    `chunk_rows` rows a chunk whatever their lengths, of its spans those
+    some row of it reaches."""
+    return prefill_chunks.chunk_account(
+        lengths, slots, chunk_rows, chunk_slots,
+        runs=prefill_chunks.span_runs)
 
 
 def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
@@ -289,11 +258,12 @@ def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
     """Rows `ids` [R, S] (a row's prompt first, padding after: under a
     causal mask no real token sees padding) through every layer,
     `chunk_slots` positions at a time; a span no row reaches is not run
-    (`span_runs`: a conditional on the device, the one program whatever
-    the lengths). Returns the hidden state of each row's last prompt
-    token [R, hidden], a layer's cache entries ((keys, values): `[R, S,
-    ...]` on a full layer, the ring `[R, window, ...]` on a sliding one)
-    and the tally."""
+    (`prefill_chunks.span_runs`, asked through its module so that a test
+    can put another rule in its place: a conditional on the device, the one
+    program whatever the lengths). Returns the hidden state of each row's
+    last prompt token [R, hidden], a layer's cache entries ((keys, values):
+    `[R, S, ...]` on a full layer, the ring `[R, window, ...]` on a sliding
+    one) and the tally."""
     rows, slots = ids.shape
     assert slots % chunk_slots == 0, (slots, chunk_slots)
     dtype = params["embed"].dtype
@@ -345,7 +315,7 @@ def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
         """What `run` gives where no row reaches `start`, wherever anything
         reads it. Rings, `last` and the tally as they came: `run` finds no
         position of its own for them. A full layer's keys and values
-        zero: columns past every row's length, which `decode_masks` shows
+        zero: columns past every row's length, which `decode_mask` shows
         to nobody, written and not left to the buffer (`whole_rows`). A
         sliding layer's tail zero too: only a later span would read it,
         and none runs."""
@@ -365,7 +335,8 @@ def prefill_rows(params, cfg: ExaoneConfig, ids, lengths, chunk_slots: int,
         # a branch is handed what it reads and gives back what the span
         # adds: the full layer's keys so far go in and do not come out
         rings, added, last, load = jax.lax.cond(
-            span_runs(lengths, start), functools.partial(run, start),
+            prefill_chunks.span_runs(lengths, start),
+            functools.partial(run, start),
             functools.partial(skip, start), rings, kept, last, load)
         kept = [tuple(([] if window else old) + [new]
                       for old, new in zip(before, after))
@@ -402,7 +373,8 @@ def prefill(params, cfg: ExaoneConfig, ids, lengths, positions: int,
         # the buffer held before does not matter (`whole_rows`)
         cache = tuple(
             tuple(jax.lax.dynamic_update_slice(
-                whole, whole_rows(entry.astype(dtype), whole.shape[1]),
+                whole, prefill_chunks.whole_rows(entry.astype(dtype),
+                                                 whole.shape[1]),
                 (at, 0, 0, 0))
                   for whole, entry in zip(layer, written))
             for layer, written in zip(cache, entries))
@@ -429,8 +401,8 @@ def step(params, cfg: ExaoneConfig, tokens, lengths, number, slots: int,
     full_positions = next(
         (layer[0].shape[1] for layer, window in zip(cache, cfg.windows)
          if not window), slots + 1)
-    see_full, see_ring = decode_masks(cfg, lengths, number, slots,
-                                      full_positions)
+    see_full = decode_mask(lengths, slots, full_positions, number)
+    see_ring = ring_mask(cfg, lengths, number)
     cache = list(cache)
     for index, (layer, window) in enumerate(zip(params["layers"],
                                                 cfg.windows)):

@@ -14,7 +14,7 @@ plain float32 statement of the same equations):
   (`ops.attention`, causal, values narrower than keys); a decode step
   absorbs `W_kvb` into the query and the context
   (`ops.latent_attention`): the same function.
-- experts: models/experts.py (shared with models/exaone.py): sigmoid
+- experts: models/experts.py (every text family's): sigmoid
   scores over ALL the model's experts, the layer told which it holds
   (`KimiConfig.experts_held`), one grouped matmul over the held pairs.
 - the leading `first_k_dense_replace` layers have a dense SwiGLU instead.
@@ -35,21 +35,19 @@ import jax.numpy as jnp
 
 from ..ops import dot_product_attention
 from ..ops.latent_attention import latent_decode_attention
-from .experts import (  # noqa: F401  (this module's public names too)
+from .experts import (  # noqa: F401  (the benchmark takes three from here)
     dot,
     empty_load,
-    expert_layer,
     feed_forward,
     held_experts,
     init_leaves,
     leaf_rule,
     logits_of,
     rms_norm,
-    route,
-    swiglu,
     tally,
 )
-from .prefill_chunks import chunk_widths, prefill_by_length
+from .prefill_chunks import chunk_account, chunk_widths, prefill_by_length
+from .text_model import apply_rope, decode_mask
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,11 +121,12 @@ def config_for(model_name: str) -> KimiConfig:
 
 
 def cache_bytes(cfg: KimiConfig, rows: int, positions: int,
-                itemsize: int) -> tuple[int, int]:
-    """(bytes of a pass's cache, the part of it that is rings of a window:
-    none here, every layer keeps every position)."""
+                itemsize: int) -> tuple[int, int, int]:
+    """(bytes of a pass's cache, the part of it that is rings of a window,
+    the part that is recurrent state: none of either here, every layer
+    keeps every position)."""
     return (rows * positions * cfg.cache_width * itemsize
-            * cfg.num_hidden_layers, 0)
+            * cfg.num_hidden_layers, 0, 0)
 
 
 # --- the parameter tree ------------------------------------------------------
@@ -210,14 +209,6 @@ def rope_tables(cfg: KimiConfig, positions):
     scale = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
              / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
-
-
-def apply_rope(x, cos, sin):
-    """Rotate the two halves of the last axis; `cos` / `sin` broadcast."""
-    half = x.shape[-1] // 2
-    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
-                           axis=-1).astype(x.dtype)
 
 
 # --- the block's parts -------------------------------------------------------
@@ -359,6 +350,13 @@ def prefill_widths(slots: int, chunk_slots: int | None = None):
     return chunk_widths(slots, chunk_slots)
 
 
+def prefill_account(lengths, slots: int, chunk_rows: int, chunk_slots: int):
+    """The host's account of what `prefill` ran (models/text_model.py):
+    whole rows, a chunk at its rows' width."""
+    return chunk_account(lengths, slots, chunk_rows, chunk_slots,
+                         prefill_widths(slots, chunk_slots))
+
+
 def prefill(params, cfg: KimiConfig, ids, lengths, positions: int,
             chunk_rows: int, chunk_slots: int | None = None,
             interpret: bool = False):
@@ -412,14 +410,5 @@ def step(params, cfg: KimiConfig, tokens, lengths, number, slots: int, cache,
     column = slots + number
     return decode_step(
         params, cfg, tokens, lengths + number, cache, column,
-        decode_mask(lengths, slots, cache[0].shape[1], column), load,
+        decode_mask(lengths, slots, cache[0].shape[1], number, column), load,
         valid=valid)
-
-
-def decode_mask(lengths, prompt_slots: int, positions: int, column):
-    """[R, positions]: a row sees its own prompt (the first `lengths`
-    columns) and the generated columns up to `column`, which is being
-    written."""
-    columns = jnp.arange(positions)[None, :]
-    return (columns < lengths[:, None]) | (
-        (columns >= prompt_slots) & (columns <= column))

@@ -1,6 +1,7 @@
 """How a pass's rows go through a prefill (models/kimi.py, models/sdar.py,
 models/qwen3_next.py): in chunks of whole rows, each chunk as wide as its
-rows need.
+rows need (models/exaone.py has fixed chunks of its own, and shares the
+rule for a span and the host's account).
 
 A chunk is up to `chunk_rows` rows x `W` slots: `W` one of the widths the
 model's module offers (`prefill_widths`: Kimi's the pass's slot bucket and
@@ -18,8 +19,12 @@ pass brings). It is right for rows in any order and fastest for rows
 ordered by length, which is how pipelines/text_generation.py hands them
 over: rows of a width then stand together, and a pass has one chunk that
 is not full a width at most. Rows of length 0 are not run. `chunk_plan`
-is the same rule without jax: what the host counts a pass's chunks,
-widths and slots by.
+is the same rule without jax, `span_runs` the rule by which a chunk that is
+spans of a row's positions leaves out the spans no row reaches (one
+function for the device and the host), and `chunk_account` what the host
+counts a pass's chunks, widths and slots by, given a module's plan and its
+rule for a span (a family's `prefill_account`, models/text_model.py: the
+rule the device applies and the rule the host counts by sit in one module).
 
 Every chunk is traced at `chunk_rows` rows (the rows it does not take go
 through as rows of length 0, and what they leave is not written), so what
@@ -83,6 +88,41 @@ def chunk_plan(lengths, chunk_rows: int, widths
         plan.append((at, take, kinds[at]))
         at += take
     return plan
+
+
+def span_runs(lengths, start: int):
+    """Whether a chunk's span of positions from `start` is run: some row
+    of the chunk has a prompt token at `start` or past it. `lengths` are
+    the chunk's own rows' (the device's in a module's `prefill_rows`, the
+    host's where a pass counts what it left out). A row's tokens are its
+    first `lengths` positions, so once a span is not run no later one is."""
+    return (lengths > start).any()
+
+
+def chunk_account(lengths, slots: int, chunk_rows: int, chunk_slots: int,
+                  widths=None, runs=None) -> tuple[dict[str, int], int, int]:
+    """What a prefill ran of a pass of `slots` prompt slots whose rows have
+    `lengths`, reckoned on the host: ({width: the chunks run at it}, the
+    chunks not run, the slots computed). The chunks are `chunk_plan`'s over
+    the module's `widths` (none: `chunk_rows` rows a chunk whatever their
+    lengths, at the bucket's width), a chunk going through in spans of
+    `chunk_slots` positions where it is wider; a chunk of width 0 is not
+    run, and of the others a span that `runs(the chunk's lengths, start)`
+    refuses (`span_runs`; None: every span runs)."""
+    lengths = np.asarray(lengths)
+    plan = (chunk_plan(lengths, chunk_rows, widths) if widths else
+            [(at, chunk_rows, slots)
+             for at in range(0, len(lengths), chunk_rows)])
+    ran, skipped, computed = {}, 0, 0
+    for at, take, width in plan:
+        span = min(chunk_slots, width or slots)
+        for start in range(0, width or slots, span):
+            if width and (runs is None or runs(lengths[at:at + take], start)):
+                ran[str(span)] = ran.get(str(span), 0) + 1
+                computed += take * span
+            else:
+                skipped += 1
+    return ran, skipped, computed
 
 
 def whole_rows(entry, columns: int):

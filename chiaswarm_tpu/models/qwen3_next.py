@@ -58,7 +58,6 @@ import jax.numpy as jnp
 
 from ..ops import dot_product_attention
 from ..ops.gated_delta_rule import gated_delta_chunks, gated_delta_step
-from .exaone import cached_attention
 from .experts import (
     dot,
     empty_load,
@@ -68,8 +67,8 @@ from .experts import (
     rms_norm,
     tally,
 )
-from .kimi import apply_rope
-from .prefill_chunks import prefill_by_length
+from .prefill_chunks import chunk_account, prefill_by_length
+from .text_model import apply_rope, cached_attention, decode_mask, rope_tables
 
 
 @dataclasses.dataclass(frozen=True)
@@ -220,20 +219,11 @@ def _norm(x, offset, cfg: Qwen3NextConfig):
 # --- gated attention ---------------------------------------------------------
 
 
-def rope_tables(cfg: Qwen3NextConfig, positions):
-    """cos and sin `[..., rotary dims / 2]` of whole-number `positions`."""
-    dim = cfg.rotary_dim
-    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
-    angles = (positions.astype(jnp.float32)[..., None]
-              / cfg.rope_theta ** exponent)
-    return jnp.cos(angles), jnp.sin(angles)
-
-
 def _rotated(x, offset, cfg: Qwen3NextConfig, positions):
     """A head's dims `x` [..., heads, head_dim] normed and its first
     `rotary_dim` rotated at `positions` [...]."""
     x = _norm(x, offset, cfg)
-    cos, sin = rope_tables(cfg, positions)
+    cos, sin = rope_tables(cfg.rotary_dim, cfg.rope_theta, positions)
     turned = apply_rope(x[..., :cfg.rotary_dim], cos[..., None, :],
                         sin[..., None, :])
     return jnp.concatenate([turned, x[..., cfg.rotary_dim:]], axis=-1)
@@ -450,6 +440,14 @@ def prefill_widths(slots: int, chunk_slots: int | None = None):
     return (slots,)
 
 
+def prefill_account(lengths, slots: int, chunk_rows: int, chunk_slots: int):
+    """The host's account of what `prefill` ran (models/text_model.py):
+    the rows that have a length, every span of them (a span no row
+    reaches is run all the same: the state and the tail pass through)."""
+    return chunk_account(lengths, slots, chunk_rows, chunk_slots,
+                         prefill_widths(slots, chunk_slots))
+
+
 def prefill(params, cfg: Qwen3NextConfig, ids, lengths, positions: int,
             chunk_rows: int, chunk_slots: int | None = None,
             interpret: bool = False):
@@ -490,9 +488,7 @@ def step(params, cfg: Qwen3NextConfig, tokens, lengths, number, slots: int,
     full_positions = next(
         (layer[0].shape[1] for layer, linear in zip(cache, cfg.linear_layers)
          if not linear), slots + 1)
-    columns = jnp.arange(full_positions)[None, :]
-    seen = (columns < lengths[:, None]) | (
-        (columns >= slots) & (columns <= slots + number))
+    seen = decode_mask(lengths, slots, full_positions, number)
     cache = list(cache)
     for index, (layer, linear) in enumerate(zip(params["layers"],
                                                 cfg.linear_layers)):

@@ -58,7 +58,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import dot_product_attention
-from .exaone import rope_tables, span_runs
 from .experts import (
     dot,
     empty_load,
@@ -68,8 +67,8 @@ from .experts import (
     rms_norm,
     tally,
 )
-from .kimi import apply_rope
-from .prefill_chunks import prefill_by_length
+from .prefill_chunks import chunk_account, prefill_by_length, span_runs
+from .text_model import apply_rope, rope_tables
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,7 +164,7 @@ def init_params(cfg: SdarConfig, key, dtype) -> dict:
 def _rotated(x, weight, cfg: SdarConfig, positions):
     """A head's dims `x` [..., heads, head_dim] RMS-normed under `weight`
     and rotated at `positions` [...]."""
-    cos, sin = rope_tables(cfg, positions)
+    cos, sin = rope_tables(cfg.head_dim, cfg.rope_theta, positions)
     return apply_rope(rms_norm(x, weight, cfg.rms_norm_eps),
                       cos[..., None, :], sin[..., None, :])
 
@@ -244,11 +243,11 @@ def new_cache(cfg: SdarConfig, rows: int, positions: int, dtype):
 
 
 def cache_bytes(cfg: SdarConfig, rows: int, positions: int,
-                itemsize: int) -> tuple[int, int]:
-    """(bytes of a pass's cache, the part of it that is rings of a window:
-    none)."""
+                itemsize: int) -> tuple[int, int, int]:
+    """(bytes of a pass's cache, the part of it that is rings of a window,
+    the part that is recurrent state: none of either)."""
     return (cfg.num_hidden_layers * rows * positions * cfg.position_bytes
-            * itemsize, 0)
+            * itemsize, 0, 0)
 
 
 # a prefill chunk may be a span of one row's positions (a chunk's edge is
@@ -316,6 +315,14 @@ def prefill_widths(slots: int, chunk_slots: int | None = None):
     made a worker's start 8 s (13 %) longer where a pass got 0.18 s
     shorter (PERF.md section 6, PR 43)."""
     return (slots,)
+
+
+def prefill_account(lengths, slots: int, chunk_rows: int, chunk_slots: int):
+    """The host's account of what `prefill` ran (models/text_model.py):
+    the rows that have a length, and of a chunk's spans those some row of
+    it reaches."""
+    return chunk_account(lengths, slots, chunk_rows, chunk_slots,
+                         prefill_widths(slots, chunk_slots), span_runs)
 
 
 def prefill(params, cfg: SdarConfig, ids, lengths, positions: int,

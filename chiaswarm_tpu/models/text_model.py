@@ -1,0 +1,127 @@
+"""What a text family's module under `models/` gives the pipeline, and the
+parts of attention the families share (models/experts.py has the second
+half of a layer, models/prefill_chunks.py how a pass's rows go through a
+prefill). No family's module imports another's.
+
+**The interface.** pipelines/text_generation.py asks a family's module
+(text_families.py names it, `family_module` hands it over) for these names
+and no others, and every module has all of them (`family_module` refuses
+one that does not, when the pipeline's module is imported, not in the
+middle of a pass):
+
+- `config_for(model_name)`: the config, a frozen dataclass (the tiny preset
+  for a name with `tiny`, else the chip's share at the published widths),
+  with `vocab_size`, `expert_layers`, `experts_held` and what
+  models/experts.py reads; `block_length` and `mask_token_id` besides where
+  the family decodes by blocks;
+- `param_shapes(cfg, dtype)`, `init_params(cfg, key, dtype)`: the parameter
+  tree as `jax.ShapeDtypeStruct`s, and seeded values for it;
+- `new_cache(cfg, rows, positions, dtype)`: a pass's cache, all zero;
+- `cache_bytes(cfg, rows, positions, itemsize)`: three numbers, (bytes of
+  that cache, the part of it that is rings of a window, the part that is
+  recurrent state: neither grows with the positions), 0 where the family
+  has none of a kind;
+- `empty_load(cfg)`: the routing's tally, all zero (models/experts.py);
+- `prefill(params, cfg, ids, lengths, positions, chunk_rows, chunk_slots)`:
+  `ids` [rows, slots] through every layer in chunks of `chunk_rows` rows x
+  `chunk_slots` positions; returns (the last prompt position's logits
+  [rows, vocab], the cache, the tally), and where the family decodes by
+  blocks (the cache, the tally);
+- `POSITION_CHUNKS`: whether a chunk may be a span of one row's positions
+  (else a long row goes through whole);
+- `prefill_account(lengths, slots, chunk_rows, chunk_slots)`: the host's
+  account of what `prefill` ran for rows of these `lengths`, without jax:
+  ({width: the chunks run at it}, the chunks not run, the slots computed),
+  by the rule the module's own `prefill` applies on the device
+  (models/prefill_chunks.py `chunk_account` over the module's plan of
+  chunks and its rule for a span);
+- one of the two ways to decode, as the family's row says (`block_length`):
+  `step(params, cfg, tokens, lengths, number, slots, cache, load, valid=)`,
+  every row's generated token `number` in, (logits [rows, vocab], cache,
+  tally) out; or `block_step(params, cfg, ids, lengths, block, slots,
+  cache, load, valid=, commit=, finished=)`, a block a row in, (logits
+  [rows, block, vocab], cache, tally) out, with `first_block(cfg, ids,
+  lengths)`, `unmask(ids, masked, drawn, confidence, count, threshold)`,
+  `blocks_of(cfg, new_tokens)` and `cache_positions(cfg, slots,
+  new_tokens)`.
+
+The forwards themselves (`_forward`, `prefill_rows`, `step`, `block_step`)
+are each module's own: the networks differ.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+
+from ..text_families import TEXT_FAMILIES
+
+INTERFACE = ("config_for", "param_shapes", "init_params", "new_cache",
+             "cache_bytes", "empty_load", "prefill", "POSITION_CHUNKS",
+             "prefill_account")
+# the two ways to decode: a module has one of them whole, and
+# tests/test_text_serving.py holds it to having nothing of the other
+BY_TOKEN = ("step",)
+BY_BLOCKS = ("block_step", "first_block", "unmask", "blocks_of",
+             "cache_positions")
+
+
+def family_module(family: str):
+    """The module of `family`'s row, held to the interface: a name it
+    lacks is an AttributeError that says which, of which module."""
+    row = TEXT_FAMILIES[family]
+    module = importlib.import_module(f"{__package__}.{row['module']}")
+    for name in INTERFACE + (BY_BLOCKS if row.get("block_length")
+                             else BY_TOKEN):
+        getattr(module, name)
+    return module
+
+
+# --- rotary ------------------------------------------------------------------
+
+
+def rope_tables(dim: int, theta: float, positions):
+    """cos and sin `[..., dim / 2]` of whole-number `positions`: plain
+    rotary over `dim` dims of a head (Kimi's YaRN tables are its own)."""
+    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    angles = positions.astype(jnp.float32)[..., None] / theta ** exponent
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x, cos, sin):
+    """Rotate the two halves of the last axis; `cos` / `sin` broadcast."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
+# --- a decode step against a cache of keys -----------------------------------
+
+
+def decode_mask(lengths, slots: int, positions: int, number, column=None):
+    """[R, positions]: what a row sees of a cache that keeps every position
+    at generated token `number`: its own prompt (the first `lengths`
+    columns) and the generated columns up to `slots + number`, which is
+    being written (`column`: that sum, where the caller has it)."""
+    columns = jnp.arange(positions)[None, :]
+    return (columns < lengths[:, None]) | (
+        (columns >= slots)
+        & (columns <= (slots + number if column is None else column)))
+
+
+def cached_attention(q, keys, values, mask, scale: float):
+    """One new token a row against its cache: `q` [R, heads, D], `keys` /
+    `values` [R, S, key heads, D], `mask` [R, S] the columns the row may
+    see. A group's query heads meet their one cached head in one batched
+    matmul; the cache is not repeated."""
+    rows, heads, d = q.shape
+    kv_heads = keys.shape[2]
+    q = q.reshape(rows, kv_heads, heads // kv_heads, d)
+    scores = jnp.einsum("rhgd,rshd->rhgs", q, keys,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
+    weights = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
+    out = jnp.einsum("rhgs,rshd->rhgd", weights, values,
+                     preferred_element_type=jnp.float32)
+    return out.astype(values.dtype).reshape(rows, heads * d)

@@ -1,11 +1,12 @@
 """Text completion from token ids: one jitted prefill and one jitted cached
 decode over a resident language model, whichever family the name resolves
-to (`_MODELS`: Kimi-K2, models/kimi.py; K-EXAONE, models/exaone.py; SDAR,
-models/sdar.py; Qwen3-Next, models/qwen3_next.py). There are two ways to
-decode, and the model's module says which is its own by what it has: a
-`step` (a token a row a forward, below)
-or a `block_step` (a block of tokens a row over several forwards, "By
-blocks" further down).
+to: a family is a row of text_families.py `TEXT_FAMILIES` (Kimi-K2,
+K-EXAONE, SDAR, Qwen3-Next) and the module under `models/` the row names,
+which gives what models/text_model.py says a family's module gives and is
+asked for nothing else. There are two ways to decode, and the family's row
+says which is its own (`block_length`): by `step` (a token a row a forward,
+below) or by `block_step` (a block of tokens a row over several forwards,
+"By blocks" further down).
 
 A pass is a set of rows (sequences), each a prompt of token ids, all
 generating the same number of new tokens. Rows are padded to a power-of-two
@@ -34,7 +35,7 @@ padding after it), so a pass is keyed by (rows, prompt slots, new tokens):
 - **step** is the decode step alone, given tokens in, logits out: what a
   comparison with the plain reference needs.
 
-**By blocks** (a model with `block_step`: block diffusion). Prefill caches
+**By blocks** (a family with a `block_length`: block diffusion). Prefill caches
 each row's whole prompt blocks and returns no logits. The decode program
 (`block_decode_program`) is a scan over blocks of `block_length` positions;
 a block starts as the mask id (the first one behind the `L mod B` ids of
@@ -72,8 +73,8 @@ a row, which do not grow with the positions): the whole is
 (`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
 that was computed all the same, and the slots of the bucket `skipped`:
 what lies past a chunk's width, and chunks the model's prefill did not
-run because none of their rows reached them, `chunk_plan` and
-`span_runs`) and the chunks it ran at each width
+run because none of their rows reached them: the module's own
+`prefill_account`) and the chunks it ran at each width
 (`swarm_prefill_chunks_total{model, width}`); how the routing fell comes
 back with the ids (`swarm_expert_pairs_total`, `swarm_routed_tokens_total`,
 `swarm_expert_pairs_max_total`, `swarm_expert_row_tiles_total`; the
@@ -105,12 +106,12 @@ import numpy as np
 
 from .. import telemetry
 from ..coalesce import checked_denoising_steps, prompt_slots
-from ..models import exaone, kimi, qwen3_next, sdar
-from ..models.prefill_chunks import chunk_plan
+from ..models.text_model import family_module
 from ..ops import platform
 from ..parallel.mesh import make_mesh, replicated
 from ..registry import _auto_family, register_family
 from ..telemetry import Span
+from ..text_families import TEXT_FAMILIES
 from ..weights import require_weights_present
 from .common import RESIDENT_PARAM_BYTES, pad_bucket, program_cache_cap
 
@@ -120,20 +121,6 @@ logger = logging.getLogger(__name__)
 # 18432-wide layer's three activations are 450 MB in bf16 and the
 # worst-case expert buffer 470 MB)
 PREFILL_CHUNK_TOKENS = 4096
-
-# family -> the module that has the model: `config_for`, `param_shapes`,
-# `init_params`, `prefill`, `empty_load`, `cache_bytes`, `POSITION_CHUNKS`,
-# where a chunk of its prefill is as wide as its rows need the widths it
-# may have (`prefill_widths`), where its prefill leaves out a span that is
-# all padding the rule it goes by (`span_runs`), and one of the two ways to
-# decode: `step` (prefill returns the last prompt position's logits, a
-# forward feeds a row one token and yields one) or `block_step` with
-# `first_block`, `unmask`, `blocks_of` and `cache_positions` (prefill
-# returns no logits, a forward feeds a row a block). The families are
-# chips/requirements.py `SEQUENCE_FAMILIES`' (tests/test_text_serving.py
-# holds the lists equal)
-_MODELS = {"kimi_k2": kimi, "exaone_moe": exaone, "sdar_moe": sdar,
-           "qwen3_next": qwen3_next}
 
 EXPERT_PAIRS = telemetry.counter(
     "swarm_expert_pairs_total",
@@ -223,10 +210,11 @@ class TextGenerationPipeline:
         of the seeded host init."""
         self.model_name = model_name
         self.chipset = chipset
-        self.model = _MODELS[_auto_family(model_name)]
+        family = _auto_family(model_name)
+        self.model = family_module(family)
         self.config = self.model.config_for(model_name)
-        # the way this model decodes: a block a row, or a token a row
-        self.by_blocks = hasattr(self.model, "block_step")
+        # the way this family decodes: a block a row, or a token a row
+        self.by_blocks = bool(TEXT_FAMILIES[family].get("block_length"))
         if dtype is None:
             dtype = (jnp.bfloat16 if jax.default_backend() == "tpu"
                      else jnp.float32)
@@ -280,10 +268,9 @@ class TextGenerationPipeline:
 
     def cache_bytes(self, rows: int, positions: int) -> tuple[int, int, int]:
         """(bytes of a pass's cache, the rings' part of it, the recurrent
-        states' part of it: none where the model's module names none)."""
-        whole, rings, *state = self.model.cache_bytes(
+        states' part of it)."""
+        return self.model.cache_bytes(
             self.config, rows, positions, self.dtype.itemsize)
-        return whole, rings, (state[0] if state else 0)
 
     def _program(self, key: tuple, build):
         with self._jit_lock:
@@ -588,33 +575,11 @@ class TextGenerationPipeline:
                     "pairs_max": int(sums[1]), "active": int(sums[2]),
                     "tiles": int(sums[3]), "calls": calls}
 
-        # the whole pass, and its two programs apart (decode's tally began
-        # where prefill's ended)
-        chunk_rows, chunk_slots = prefill_chunk(
-            rows, slots, self.model.POSITION_CHUNKS)
         # the chunks the prefill program ran and those it did not, by the
-        # model's own rules on the lengths it was given: which rows a
-        # chunk of whole rows takes and how wide it is (`chunk_plan` over
-        # its `prefill_widths`; else `chunk_rows` rows whatever their
-        # lengths), and of a chunk that is spans of positions those some
-        # row reaches (`span_runs`)
-        runs = getattr(self.model, "span_runs", None)
-        if hasattr(self.model, "prefill_widths"):
-            plan = chunk_plan(lengths, chunk_rows,
-                              self.model.prefill_widths(slots, chunk_slots))
-        else:
-            plan = [(at, chunk_rows, slots)
-                    for at in range(0, rows, chunk_rows)]
-        widths, skipped, computed = {}, 0, 0
-        for at, take, width in plan:
-            span = min(chunk_slots, width or slots)
-            for start in range(0, width or slots, span):
-                if width and (runs is None
-                              or runs(lengths[at:at + take], start)):
-                    widths[str(span)] = widths.get(str(span), 0) + 1
-                    computed += take * span
-                else:
-                    skipped += 1
+        # model's own rule on the lengths it was given
+        widths, skipped, computed = self.model.prefill_account(
+            lengths, slots, *prefill_chunk(
+                rows, slots, self.model.POSITION_CHUNKS))
         chunks = sum(widths.values())
         # expert-layer calls of either program: a block model's prefill
         # chunk, whose logits nobody reads, stops before its last layer's
@@ -624,6 +589,8 @@ class TextGenerationPipeline:
         forwards = denoise if self.by_blocks else new_tokens - 1
         prefill_calls = (layers - 1 if self.by_blocks else layers) * chunks
         decode_calls = layers * forwards
+        # the whole pass, and its two programs apart (decode's tally began
+        # where prefill's ended)
         routing = {
             **tally(pairs, sums, prefill_calls + decode_calls),
             "prefill": tally(before, before_sums, prefill_calls),
@@ -690,9 +657,7 @@ class TextGenerationPipeline:
         self.params = None
 
 
-def _build(model_name: str, chipset=None, **variant):
-    return TextGenerationPipeline(model_name, chipset, **variant)
-
-
-for _family in _MODELS:
-    register_family(_family)(_build)
+for _family in TEXT_FAMILIES:
+    # a module that lacks a name of the interface fails here, at import
+    family_module(_family)
+    register_family(_family)(TextGenerationPipeline)

@@ -20,7 +20,7 @@ import threading
 from collections import OrderedDict
 from typing import Callable
 
-from .coalesce import TEXT_FAMILIES, text_family_of
+from .text_families import TEXT_FAMILIES, text_family_of
 from .telemetry import Span
 
 logger = logging.getLogger(__name__)
@@ -71,7 +71,7 @@ PIPELINE_FAMILIES: dict[str, str] = {
     "StableVideoDiffusionPipeline": "svd",
     "BlipForConditionalGeneration": "blip",
     "BlipForQuestionAnswering": "blip",
-    # the text families' wire names (coalesce.py `TEXT_FAMILIES`)
+    # the text families' wire names (text_families.py `TEXT_FAMILIES`)
     **{what["wire"]: family for family, what in TEXT_FAMILIES.items()},
 }
 

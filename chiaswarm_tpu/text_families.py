@@ -1,0 +1,98 @@
+"""The text families: one row a language model whose jobs carry token ids
+and whose rows are sequences (pipelines/text_generation.py). Everything
+that has to know a family by name reads its row here, and nothing else in
+`chiaswarm_tpu/` spells a family's key: the hive and the worker through
+coalesce.py (which hands `TEXT_FAMILIES` and `text_family_of` on: this
+module is jax-free, as that one is), the registry its wire name, the
+admission its footprint (chips/requirements.py), the pipeline its module
+(models/text_model.py `family_module`, which also holds the module to what
+a family's module has to give). A new language model is a module under
+`models/` and a row here.
+
+A row:
+
+- `name`: the word of a model's name that tells the family;
+- `wire`: the wire name of its pipeline type;
+- `block_length`: only where the family decodes a block of positions at a
+  time (its module then has `block_step` and not `step`): the block's
+  length, which its config's `block_length` equals;
+- `module`: its module under `models/`;
+- the footprint admission reckons with, in bf16 on one chip. Admission is
+  the weights the chip holds (`params_gb`, GiB), a working set that does
+  not grow with the rows (`working_gb`: a prefill chunk's activations and
+  worst-case expert buffer, the logits), and a row's cache: a row of
+  `size` positions (prompt slots + new tokens) costs, a kind of layer,
+  `min(size, the kind's window or size)` positions of `bytes a position`
+  (`cache_layers`: (bytes a position summed over the layers of the kind,
+  the window they keep or 0 for every position)), and, where the family
+  has layers that keep a recurrent state and no keys, `row_bytes`: what
+  those layers hold a row whatever its positions, so that a row never
+  costs nothing however short it is. The bytes are what the module's own
+  `cache_bytes` reckons from its full-size config (tests/test_text_serving.py
+  holds the two equal); they stand here because this module imports no
+  jax.
+"""
+
+TEXT_FAMILIES: dict[str, dict] = {
+    # one chip's share of a 32-chip expert-parallel deployment
+    # (models/kimi.py KIMI_K2_EP32): 4.85 B parameters in bf16 = 9.70 GB; a
+    # position is 576 values x 2 bytes on each of 7 layers, all kept whole;
+    # the working set is what the compile for a described v5e counted for
+    # the 256-row programs beside weights and cache
+    # (benchmark/compile_check.py, PERF.md)
+    "kimi_k2": {
+        "name": "kimi", "wire": "KimiK2ForCausalLM", "module": "kimi",
+        "params_gb": 9.04, "working_gb": 3.0,
+        "cache_layers": ((8064.0, 0),)},
+    # one chip's share of an 8-chip deployment (models/exaone.py
+    # EXAONE_236B_EP8): 3.71 B parameters = 7.42 GB; a position is a key
+    # and a value on 8 heads of 128 x 2 bytes = 4096 B a layer, kept whole
+    # on the one full layer and as a ring of 128 on the four sliding ones;
+    # the working set is a 4096-token chunk's activations, its expert
+    # buffer and a 16384-slot row's keys beside them
+    "exaone_moe": {
+        "name": "exaone", "wire": "ExaoneMoeForCausalLM", "module": "exaone",
+        "params_gb": 6.91, "working_gb": 3.0,
+        "cache_layers": ((4096.0, 0), (16384.0, 128))},
+    # one stage of an 8-stage pipeline with every expert held
+    # (models/sdar.py SDAR_30B_PP8): 4.36 B parameters = 8.72 GB; a
+    # position is a key and a value on 4 heads of 128 x 2 bytes = 2048 B on
+    # each of 6 layers, all kept whole; the working set is what the compile
+    # for a described v5e counted for the 256-row block decode beside
+    # weights and cache (benchmark/compile_check.py, PERF.md: 4.14 GB of
+    # temporaries, a block step's float32 logits over the whole vocabulary,
+    # the sampler's copies of them and its random bits, the expert buffer;
+    # the columns a pass's last committed block may overhang `prompt slots
+    # + new tokens` by are in it too)
+    "sdar_moe": {
+        "name": "sdar", "wire": "SdarMoeForCausalLM", "module": "sdar",
+        "block_length": 4, "params_gb": 8.12, "working_gb": 3.9,
+        "cache_layers": ((12288.0, 0),)},
+    # one of 4 chips that share each layer, one of 6 pipeline stages
+    # (models/qwen3_next.py QWEN3_NEXT_80B_EP4): 3.667 B parameters =
+    # 7.33 GB; each of the 6 linear layers holds a row a float32 state of
+    # 32 heads x 128 x 128 (2,097,152 B) and a convolution's tail of 3 x
+    # 8192 values x 2 bytes (49,152 B): 12,877,824 B a row whatever its
+    # positions; a position is a key and a value on 2 heads of 256 x 2
+    # bytes = 2048 B on each of the 2 full layers, kept whole; the working
+    # set is what the compile for a described v5e counted beside weights
+    # and cache for the 256-row programs (1.36 GB of temporaries for the
+    # prefill, a chunk's float32 operands of the chunk rule among them,
+    # 0.60 GB for the decode: benchmark/compile_check.py, PERF.md; the
+    # chip's own peak lies 0.12 GB over weights and cache)
+    "qwen3_next": {
+        "name": "qwen3-next", "wire": "Qwen3NextForCausalLM",
+        "module": "qwen3_next", "params_gb": 6.83, "working_gb": 1.5,
+        "cache_layers": ((4096.0, 0),), "row_bytes": 12877824.0},
+}
+
+
+def text_family_of(model_name: str) -> str | None:
+    """The text family a model's name tells, None for any other model."""
+    name = model_name.lower()
+    for family, what in TEXT_FAMILIES.items():
+        # the family's word, or its key (a worker reckons a family's
+        # appetite with the key as the model's name)
+        if what["name"] in name or family in name:
+            return family
+    return None

@@ -648,9 +648,10 @@ class Worker:
             coalesce_rows_limit,
             flux_admissible,
             min_chips,
+            SEQUENCE_REFERENCE_POSITIONS,
             pass_positions_limit,
-            sequence_family_positions,
         )
+        from .text_families import TEXT_FAMILIES
         from .weights import UNCONVERTED_FAMILY_KEYWORDS
 
         caps = dict(self.allocator.capabilities())
@@ -708,17 +709,19 @@ class Worker:
         # a family whose rows are sequences has an appetite of its own,
         # from admission (the weights it holds and a row's cache bytes,
         # chips/requirements.py) and not from the job cap: a text job is
-        # dozens of rows and a pass worth running hundreds
+        # dozens of rows and a pass worth running hundreds (a family's key
+        # resolves to itself as a model's name, `_family_key`)
         caps["family_gang_rows"] = ",".join(
-            f"{family}:{coalesce_rows_limit(job_slice, family, positions)}"
-            for family, positions in sequence_family_positions().items())
+            f"{family}:" + str(coalesce_rows_limit(
+                job_slice, family, SEQUENCE_REFERENCE_POSITIONS))
+            for family in TEXT_FAMILIES)
         # ... and that number is the appetite at a reference length; the
         # cached positions a pass may hold let the hive reckon a gang at
         # the job's own prompt slots + new tokens, as the batcher here
         # does (`_coalesce_rows_limit`), so the hive's gang is the pass
         caps["family_gang_positions"] = ",".join(
             f"{family}:{pass_positions_limit(job_slice, family)}"
-            for family in sequence_family_positions())
+            for family in TEXT_FAMILIES)
         # preemption tolerance (ISSUE 18): a chunked, checkpoint-armed
         # worker can rehydrate a redelivered job from a hive-held
         # checkpoint; the hive attaches `resume` offers only to workers

@@ -26,7 +26,12 @@ import numpy as np
 import pytest
 
 from benchmark.reference import banded_kernels, gqa_window_moe, mla_moe
-from chiaswarm_tpu.models import exaone, experts
+from chiaswarm_tpu.models import (
+    exaone,
+    experts,
+    prefill_chunks,
+    text_model,
+)
 from chiaswarm_tpu.ops import dot_product_attention
 from chiaswarm_tpu.ops.banded_attention import (
     band_blocks,
@@ -67,7 +72,7 @@ def test_the_tiny_preset_is_the_cut_in_small():
     assert round(count / 1e9, 2) == 3.71
     # a 16512-position row: one whole layer and four rings of 128
     assert exaone.cache_bytes(full, 4, 16512, 2) == (
-        4 * 4096 * (16512 + 4 * 128), 4 * 4096 * 4 * 128)
+        4 * 4096 * (16512 + 4 * 128), 4 * 4096 * 4 * 128, 0)
 
 
 @pytest.mark.parametrize("slots, chunk_rows, chunk_slots, lengths", [
@@ -135,16 +140,17 @@ def _prefill_from_nan(every_span: bool, positions: int, chunk_rows: int,
     `every_span`, a rule that runs a span whatever the lengths (a device
     value all the same: the compiler is to keep the one program, and with
     it the order of every sum)."""
-    rule, made = exaone.span_runs, exaone.new_cache
+    rule, made = prefill_chunks.span_runs, exaone.new_cache
     exaone.new_cache = lambda *a: jax.tree_util.tree_map(
         lambda x: jnp.full_like(x, jnp.nan), made(*a))
     if every_span:
-        exaone.span_runs = lambda lengths, start: (lengths > -1).any()
+        prefill_chunks.span_runs = lambda lengths, start: (
+            lengths > -1).any()
     try:
         return exaone.prefill(p, CFG, ids, lengths, positions, chunk_rows,
                               chunk_slots)
     finally:
-        exaone.span_runs, exaone.new_cache = rule, made
+        prefill_chunks.span_runs, exaone.new_cache = rule, made
 
 
 _PREFILL = jax.jit(_prefill_from_nan, static_argnums=(0, 1, 2, 3))
@@ -180,10 +186,11 @@ def test_a_span_no_row_reaches_is_left_out_and_nothing_read_changes(
     left_out = np.zeros((rows, slots + new), bool)
     for at in range(0, rows, chunk_rows):
         for start in range(0, slots, span):
-            if not exaone.span_runs(lengths[at:at + chunk_rows], start):
+            if not prefill_chunks.span_runs(lengths[at:at + chunk_rows],
+                                            start):
                 left_out[at:at + chunk_rows, start:start + span] = True
     assert left_out.sum() == skipped * chunk_rows * span
-    seen, _ = exaone.decode_masks(CFG, lengths, new - 1, slots, slots + new)
+    seen = text_model.decode_mask(lengths, slots, slots + new, new - 1)
     assert not (np.asarray(seen) & left_out).any()
     for step in range(new + 1):
         (logits, cache, load), (logits_, cache_, load_) = got, want

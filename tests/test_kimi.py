@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import mla_moe, moe_kernels
-from chiaswarm_tpu.models import kimi
+from chiaswarm_tpu.models import experts, kimi, text_model
 from chiaswarm_tpu.ops import dot_product_attention
 from chiaswarm_tpu.ops.expert_matmul import buffer_rows, plan
 
@@ -61,8 +61,8 @@ def test_prefill_and_cached_decode_give_the_references_logits(params, absorb):
     step = jax.jit(lambda p, t, at, c, column, mask, tally: kimi.decode_step(
         p, CFG, t, at, c, column, mask, tally, absorb=absorb))
     for j in range(new):
-        mask = kimi.decode_mask(jnp.asarray(lengths), slots, positions,
-                                slots + j)
+        mask = text_model.decode_mask(jnp.asarray(lengths), slots,
+                                      positions, j)
         logits, cache, load = step(params, given[:, j], lengths + j, cache,
                                    slots + j, mask, load)
         got.append(logits)
@@ -155,13 +155,13 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(params):
         jax.random.key(10 + n), (32, *moe["experts"][name].shape[1:]))
         / np.sqrt(moe["experts"][name].shape[1])
         for n, name in enumerate(("gate", "up", "down"))}
-    shared = np.asarray(kimi.swiglu(moe["shared"], h))
+    shared = np.asarray(experts.swiglu(moe["shared"], h))
     total = np.zeros_like(shared)
     for share in range(4):
         cfg = dataclasses.replace(CFG, experts_held=(8 * share, 8))
         mine = dict(moe, experts={name: stack[8 * share:8 * share + 8]
                                   for name, stack in stacks.items()})
-        out, _ = kimi.expert_layer(mine, cfg, h)
+        out, _ = experts.expert_layer(mine, cfg, h)
         total += np.asarray(out) - shared
     want = mla_moe.experts(dict(moe, experts=stacks), SIZES, h,
                            whole.experts_held)
@@ -210,7 +210,7 @@ def test_a_rows_expert_output_is_bit_equal_among_other_batchmates(
                                   (others, CFG.hidden_size))
         batch = jnp.concatenate([mates[:others // 2], mine,
                                  mates[others // 2:]])
-        out, _ = jax.jit(lambda p, x: kimi.expert_layer(
+        out, _ = jax.jit(lambda p, x: experts.expert_layer(
             p, CFG, x, interpret=interpret))(moe, batch)
         outs.append(np.asarray(out)[others // 2:others // 2 + 3])
     assert np.array_equal(outs[0], outs[1])

@@ -69,7 +69,8 @@ def test_the_tiny_preset_is_the_cut_in_small():
     # 256 new tokens behind a tail of up to 3: 65 blocks, 64 committed
     assert sdar.blocks_of(full, 256) == 65
     assert sdar.cache_positions(full, 256, 256) == 512
-    assert sdar.cache_bytes(full, 256, 512, 2) == (6 * 256 * 512 * 2048, 0)
+    assert sdar.cache_bytes(full, 256, 512, 2) == (
+        6 * 256 * 512 * 2048, 0, 0)
     assert (CFG.block_length, CFG.scoring_func) == (4, "softmax")
     assert sdar.blocks_of(CFG, 6) == 3 and sdar.cache_positions(
         CFG, 16, 6) == 24

@@ -125,41 +125,136 @@ def test_the_formatter_hands_a_block_decode_its_two_parameters():
     assert "denoising_steps" not in args
 
 
-def test_the_lists_of_text_families_agree():
-    """One family missing from one of them would fail the first job of it:
-    the name table every side imports (coalesce.py), the costs
-    (chips/requirements.py), the models (the pipeline), and what the
-    registry and the admission make of a name."""
-    from chiaswarm_tpu import coalesce, registry
+@pytest.mark.parametrize(
+    "family", ["kimi_k2", "exaone_moe", "sdar_moe", "qwen3_next"])
+def test_a_text_family_is_a_row_and_a_module_that_gives_the_interface(
+        family, monkeypatch):
+    """ISSUE 45: what the four lists that had to agree were is one row of
+    `TEXT_FAMILIES`, and the module it names gives everything
+    models/text_model.py says a family's module gives: every name, one way
+    to decode and nothing of the other, three numbers for a pass's cache
+    whose bytes a row are the row's footprint, and a host-side account of
+    a pass's prefill chunks that is what `prefill` runs on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    from chiaswarm_tpu import coalesce, registry, text_families
+    from chiaswarm_tpu.models import text_model
     from chiaswarm_tpu.pipelines import text_generation
 
-    families = set(coalesce.TEXT_FAMILIES)
-    assert families == set(requirements.SEQUENCE_FAMILIES) == set(
-        text_generation._MODELS) == {"kimi_k2", "exaone_moe", "sdar_moe",
-                                     "qwen3_next"}
+    assert coalesce.TEXT_FAMILIES is text_families.TEXT_FAMILIES
+    what = text_families.TEXT_FAMILIES[family]
+    name = f"test/tiny-{what['name']}"
     registry._ensure_builtin_families()
-    assert families <= set(registry._FACTORIES)
-    for family, what in coalesce.TEXT_FAMILIES.items():
-        name = f"test/tiny-{what['name']}"
-        assert registry.family_of(what["wire"]) == family
-        assert registry._auto_family(name) == family
-        assert requirements._family_key(name) == family
-        assert coalesce.text_family_of(name.upper()) == family
-        # the key resolves to itself as a model's name: a worker reckons
-        # the appetite it advertises with it (on a chip by the family's
-        # own table, and not as a 1.8 GB diffusion model's)
-        assert requirements._family_key(family) == family
-        assert requirements.coalesce_rows_limit(
-            _Slice(), family, requirements.SEQUENCE_REFERENCE_POSITIONS
-        ) == 256
-        # a family decodes by blocks in both tables or in neither
-        model = text_generation._MODELS[family]
-        assert hasattr(model, "block_step") == ("block_length" in what)
-        assert hasattr(model, "block_step") != hasattr(model, "step")
-        if "block_length" in what:
-            assert model.config_for(name).block_length == model.config_for(
-                "test/whole").block_length == what["block_length"]
-    assert coalesce.text_family_of("test/tiny-sd") is None
+    assert family in registry._FACTORIES
+    assert registry.family_of(what["wire"]) == family
+    assert registry._auto_family(name) == family
+    assert requirements._family_key(name) == family
+    assert coalesce.text_family_of(name.upper()) == family
+    # the key resolves to itself as a model's name: a worker reckons
+    # the appetite it advertises with it (on a chip by the family's
+    # own table, and not as a 1.8 GB diffusion model's)
+    assert requirements._family_key(family) == family
+    assert requirements.coalesce_rows_limit(
+        _Slice(), family, requirements.SEQUENCE_REFERENCE_POSITIONS) == 256
+    # the interface: every name, and one way to decode as the row says
+    model = text_model.family_module(family)
+    assert model.__name__.endswith(f".models.{what['module']}")
+    by_blocks = "block_length" in what
+    mine, other = ((text_model.BY_BLOCKS, text_model.BY_TOKEN) if by_blocks
+                   else (text_model.BY_TOKEN, text_model.BY_BLOCKS))
+    assert all(hasattr(model, name_) for name_ in text_model.INTERFACE + mine)
+    assert not any(hasattr(model, name_) for name_ in other)
+    pipe = text_generation.TextGenerationPipeline(
+        name, allow_random_init=True)
+    assert pipe.model is model and pipe.by_blocks is by_blocks
+    cfg, whole = model.config_for(name), model.config_for("test/whole")
+    if by_blocks:
+        assert cfg.block_length == whole.block_length == what["block_length"]
+    # a module that lacks a name is refused when it is asked for
+    monkeypatch.delattr(model, "prefill_account")
+    with pytest.raises(AttributeError, match="prefill_account"):
+        text_model.family_module(family)
+    monkeypatch.undo()
+    # three numbers, and the whole of them a row is the table's footprint
+    # (from a window's length on: a ring is its window however short the row)
+    for positions in (128, 512, 16512):
+        sizes = model.cache_bytes(whole, 1, positions, 2)
+        assert len(sizes) == 3 and all(isinstance(n, int) for n in sizes)
+        assert sizes[0] == requirements.sequence_row_bytes(family, positions)
+        assert sizes[2] == what.get("row_bytes", 0)
+    # the host's account of a pass's chunks is what `prefill` runs: every
+    # chunk that runs calls `feed_forward` once a layer (a block model's
+    # prefill stops before the last layer's) with its rows x its width
+    seen = []
+    feed_forward = model.feed_forward
+
+    def counted(layer, cfg, h, *rest):
+        jax.debug.callback(lambda tokens: seen.append(int(tokens)),
+                           jnp.int32(h.shape[0]))
+        return feed_forward(layer, cfg, h, *rest)
+
+    monkeypatch.setattr(model, "feed_forward", counted)
+    params = model.init_params(cfg, jax.random.key(0), jnp.float32)
+    for chunk_tokens, slots, lengths in (
+            # whole rows, two a chunk: ragged, and rows of no length
+            (32, 16, [16, 13, 9, 8, 5, 2, 0, 0]),
+            # spans of 16 positions where the family's prefill takes them:
+            # a row past one span, one that ends with it, one of no length
+            (16, 32, [29, 16, 7, 0])):
+        monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS",
+                            chunk_tokens)
+        rows = len(lengths)
+        chunk = text_generation.prefill_chunk(rows, slots,
+                                              model.POSITION_CHUNKS)
+        assert chunk[1] == (16 if model.POSITION_CHUNKS else slots)
+        rng = np.random.default_rng(slots)
+        ids = rng.integers(1, cfg.vocab_size - 1, (rows, slots)).astype(
+            np.int32)
+        lengths = np.array(lengths, np.int32)
+        del seen[:]
+        jax.block_until_ready(jax.jit(
+            lambda p, i, n: model.prefill(p, cfg, i, n, slots + 4, *chunk))(
+            params, ids, lengths))
+        jax.effects_barrier()
+        calls = cfg.num_hidden_layers - by_blocks
+        ran = {}
+        for tokens in seen:
+            width = str(tokens // chunk[0])
+            ran[width] = ran.get(width, 0) + 1
+        assert all(count % calls == 0 for count in ran.values())
+        ran = {width: count // calls for width, count in ran.items()}
+        widths, skipped, computed = model.prefill_account(
+            lengths, slots, *chunk)
+        assert widths == ran and sum(ran.values()) > 1
+        assert skipped >= 1  # the rows of no length, at the least
+        assert lengths.sum() <= computed <= rows * slots - 16 * skipped
+
+
+def test_a_text_familys_key_is_spelt_in_one_file():
+    """ISSUE 45: the four keys stand as string literals in one file of the
+    package (text_families.py); whatever else has to know a family reads
+    its row."""
+    import ast
+    import pathlib
+
+    import chiaswarm_tpu
+    from chiaswarm_tpu.text_families import TEXT_FAMILIES
+
+    root = pathlib.Path(chiaswarm_tpu.__file__).parent
+    spelt = {}
+    for path in root.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Constant) and node.value in set(
+                    TEXT_FAMILIES):
+                spelt.setdefault(node.value, set()).add(
+                    str(path.relative_to(root)))
+    assert spelt == {family: {"text_families.py"}
+                     for family in ("kimi_k2", "exaone_moe", "sdar_moe",
+                                    "qwen3_next")}
+    from chiaswarm_tpu.coalesce import text_family_of
+
+    assert text_family_of("test/tiny-sd") is None
 
 
 def test_prompt_slots_are_powers_of_two_from_sixteen():
@@ -185,7 +280,7 @@ class _Slice:
 
 def test_admission_is_the_weights_held_and_a_rows_cache_bytes():
     name = "test/Kimi-K2.6"
-    costs = requirements.SEQUENCE_FAMILIES["kimi_k2"]
+    costs = requirements.TEXT_FAMILIES["kimi_k2"]
     # a position is 576 values x 2 bytes x 7 layers, every layer whole
     assert costs["cache_layers"] == ((576 * 2 * 7, 0),)
     free = 15.75 - costs["params_gb"] - costs["working_gb"]
@@ -224,7 +319,7 @@ def test_a_rows_bytes_are_reckoned_by_layer_kind():
     # shorter than the window: every layer keeps what there is
     assert requirements.sequence_row_bytes("exaone_moe", 100) == 5 * 4096 * 100
     assert requirements.sequence_row_bytes("kimi_k2", 512) == 8064 * 512
-    costs = requirements.SEQUENCE_FAMILIES["exaone_moe"]
+    costs = requirements.TEXT_FAMILIES["exaone_moe"]
     free = 15.75 - costs["params_gb"] - costs["working_gb"]
     per_row = (4096 * 16512 + 4 * 4096 * 128) / (1 << 30)
     assert requirements.fit_batch(_Slice(), name, 10 ** 9, 16512) == int(
@@ -250,7 +345,7 @@ def test_a_row_of_a_recurrent_state_costs_bytes_whatever_its_positions():
     4096 B a position."""
     name = "test/Qwen3-Next-80B-A3B-Instruct"
     assert requirements._family_key(name) == "qwen3_next"
-    costs = requirements.SEQUENCE_FAMILIES["qwen3_next"]
+    costs = requirements.TEXT_FAMILIES["qwen3_next"]
     state = 6 * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
     assert costs["row_bytes"] == state == 12877824
     assert costs["cache_layers"] == ((2 * 2 * 2 * 256 * 2, 0),)
