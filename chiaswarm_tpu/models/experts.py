@@ -2,7 +2,10 @@
 (models/kimi.py, models/exaone.py, models/sdar.py, models/qwen3_next.py):
 the norm, the SwiGLU, the router under either of two rules, the held
 experts' grouped matmul, the tally of how the routing fell, the seeded init
-of a parameter tree and the head.
+of a parameter tree and the head. A dense model (models/falcon_h1.py) takes
+the norm, `dot`, the init and the head from here, and the tally as its
+config makes it: with `expert_layers` 0 it has no row and nothing adds to
+it.
 
 `cfg` is the model's own config dataclass; what is read of it here is
 `scoring_func`, `num_experts_per_tok`, `routed_scaling_factor`,
@@ -50,12 +53,17 @@ def leaf_rule(path, shape) -> tuple[float, float]:
     forgotten `1 +` would pass every test), N(0, 0.01^2) for the router's
     correction bias, the embedding by its width, a unit normal for `A_log`
     (which `finish_leaf` maps to the published `log U(0, 16)`), ones for
-    `dt_bias`, else a normal scaled by fan-in (the rows of the one matrix:
-    a stack of experts is scaled expert by expert)."""
+    `dt_bias`, N(1, 0.1^2) for a state-space mixer's skip `D` (published
+    at one: seeded apart, a head that read another's would not pass) and
+    N(0, 0.1^2) for its convolution's bias, else a normal scaled by fan-in
+    (the rows of the one matrix: a stack of experts is scaled expert by
+    expert)."""
     name = leaf_name(path)
     if name.endswith("norm") or name == "dt_bias":
         return 0.0, 1.0
-    if name.endswith("norm_offset"):
+    if name == "D":
+        return 0.1, 1.0
+    if name.endswith("norm_offset") or name == "conv_bias":
         return 0.1, 0.0
     if name == "router_bias":
         return 0.01, 0.0
@@ -196,7 +204,9 @@ def feed_forward(layer, cfg, h, valid, interpret):
 def empty_load(cfg):
     """The routing's tally of a pass, all zero: pairs of each held expert
     of each expert layer, and (routed, fullest, active, row tiles) summed
-    over the expert layers' calls."""
+    over the expert layers' calls. A model without experts
+    (`expert_layers` 0, `experts_held` (0, 0)) has a tally of no rows: it
+    goes through a pass as it came, and every count read from it is 0."""
     return (jnp.zeros((cfg.expert_layers, cfg.experts_held[1]), jnp.int32),
             jnp.zeros((4,), jnp.int32))
 

@@ -12,8 +12,11 @@ middle of a pass):
 - `config_for(model_name)`: the config, a frozen dataclass (the tiny preset
   for a name with `tiny`, else the chip's share at the published widths),
   with `vocab_size`, `expert_layers`, `experts_held` and what
-  models/experts.py reads; `block_length` and `mask_token_id` besides where
-  the family decodes by blocks;
+  models/experts.py reads (a family without experts says `expert_layers`
+  0 and `experts_held` (0, 0): its tally then has no row, every count the
+  pipeline reads from it is 0, and nothing is divided by either);
+  `block_length` and `mask_token_id` besides where the family decodes by
+  blocks;
 - `param_shapes(cfg, dtype)`, `init_params(cfg, key, dtype)`: the parameter
   tree as `jax.ShapeDtypeStruct`s, and seeded values for it;
 - `new_cache(cfg, rows, positions, dtype)`: a pass's cache, all zero;
@@ -21,7 +24,9 @@ middle of a pass):
   that cache, the part of it that is rings of a window, the part that is
   recurrent state: neither grows with the positions), 0 where the family
   has none of a kind;
-- `empty_load(cfg)`: the routing's tally, all zero (models/experts.py);
+- `empty_load(cfg)`: the routing's tally, all zero (models/experts.py:
+  `[expert_layers, held]` pairs and four sums; a dense family's goes
+  through `prefill` and `step` as it came);
 - `prefill(params, cfg, ids, lengths, positions, chunk_rows, chunk_slots)`:
   `ids` [rows, slots] through every layer in chunks of `chunk_rows` rows x
   `chunk_slots` positions; returns (the last prompt position's logits

@@ -1,7 +1,8 @@
 """Text completion from token ids: one jitted prefill and one jitted cached
 decode over a resident language model, whichever family the name resolves
 to: a family is a row of text_families.py `TEXT_FAMILIES` (Kimi-K2,
-K-EXAONE, SDAR, Qwen3-Next) and the module under `models/` the row names,
+K-EXAONE, SDAR, Qwen3-Next, Falcon-H1) and the module under `models/` the
+row names,
 which gives what models/text_model.py says a family's module gives and is
 asked for nothing else. There are two ways to decode, and the family's row
 says which is its own (`block_length`): by `step` (a token a row a forward,
@@ -20,8 +21,8 @@ padding after it), so a pass is keyed by (rows, prompt slots, new tokens):
   spans before it cached), writes each layer's cache and returns the
   logits of every row's last prompt token. A row of a chunk of whole
   rows goes through at the narrowest of the widths its model offers that
-  holds it (Kimi: the bucket and its halvings; SDAR and Qwen3-Next: the
-  bucket), and a chunk takes rows of one width (models/prefill_chunks.py:
+  holds it (Kimi: the bucket and its halvings; SDAR, Qwen3-Next and
+  Falcon-H1: the bucket), and a chunk takes rows of one width (models/prefill_chunks.py:
   read from `lengths` on the device, so it is one program whatever a pass
   brings, and a row's bits do not depend on its batchmates); a pass hands
   its rows over longest first, so that rows of a width stand together,
@@ -66,7 +67,8 @@ a position a layer, K-EXAONE keys and values a position on its full
 layers and a ring of its window on the others, SDAR keys and values a
 position on every layer, Qwen3-Next keys and values a position on every
 fourth layer and on the others a recurrent state and a convolution's tail
-a row, which do not grow with the positions): the whole is
+a row, which do not grow with the positions, Falcon-H1 both on every
+layer): the whole is
 `swarm_pass_cache_bytes{model}`, the rings' part
 `swarm_pass_window_cache_bytes{model}`, the states' part
 `swarm_pass_state_bytes{model}`. A pass counts its prompt slots
@@ -82,12 +84,16 @@ envelope's `routing` has the same a program, with the experts that had a
 pair, `active`, and the calls: `tiles / active` is the row tiles an
 expert's matrices serve in a call, for which they cross from HBM once
 where the grouped kernel holds them in one block, `ops/expert_matmul.py`).
+A family without experts (Falcon-H1: `expert_layers` 0) hands over a tally
+of no rows (models/experts.py `empty_load`), which goes through both
+programs as it came: its `routing` reads 0 in every count, `calls`
+included, `pairs_by_expert` is empty, and the four counters stand at 0.
 
 No tokenizer: ids travel on the wire, and there is no stop token, every
 row generates `max_new_tokens`. `test/` names are seeded weights: `tiny` in
 the name is the family's tiny preset, any other the chip's share of the
 deployment at the published widths (`KIMI_K2_EP32`, `EXAONE_236B_EP8`,
-`SDAR_30B_PP8`, `QWEN3_NEXT_80B_EP4`;
+`SDAR_30B_PP8`, `QWEN3_NEXT_80B_EP4`, `FALCON_H1_34B_PP18`;
 `weights=` hands the tree in already on the chip, as `FluxPipeline` takes
 it: the host init of billions of parameters is minutes).
 """
@@ -311,7 +317,10 @@ class TextGenerationPipeline:
     def step_program(self, rows: int, slots: int, positions: int):
         """One decode step with given tokens: `(params, cache, tokens
         [rows], lengths [rows], step) -> (logits [rows, vocab], cache)`:
-        the tokens are each row's generated token number `step`."""
+        the tokens are each row's generated token number `step`. On a chip
+        the cache is donated, as the decode programs': a cache that is
+        most of the memory left beside the weights cannot stand there
+        twice."""
         cfg, model = self.config, self.model
 
         def step(params, cache, tokens, lengths, number):
@@ -320,8 +329,9 @@ class TextGenerationPipeline:
                 model.empty_load(cfg), valid=lengths > 0)
             return logits, cache
 
+        donate = (1,) if platform.trace_platform() == "tpu" else ()
         return self._program(("step", rows, slots, positions),
-                             lambda: jax.jit(step))
+                             lambda: jax.jit(step, donate_argnums=donate))
 
     def decode_program(self, rows: int, slots: int, new_tokens: int):
         """`(params, cache, logits, lengths, job_keys, job_of_row,

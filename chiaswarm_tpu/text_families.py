@@ -84,6 +84,20 @@ TEXT_FAMILIES: dict[str, dict] = {
         "name": "qwen3-next", "wire": "Qwen3NextForCausalLM",
         "module": "qwen3_next", "params_gb": 6.83, "working_gb": 1.5,
         "cache_layers": ((4096.0, 0),), "row_bytes": 12877824.0},
+    # one stage of an 18-stage pipeline, four whole layers and the whole
+    # vocabulary (models/falcon_h1.py FALCON_H1_34B_PP18): 4.394 B
+    # parameters = 8.79 GB; every layer holds a row BOTH a float32 state of
+    # 32 heads x 256 x 128 (4,194,304 B) with a convolution's tail of 3 x
+    # 5120 values x 2 bytes (30,720 B): 16,900,096 B a row over the 4
+    # layers whatever its positions, AND a key and a value a position on 4
+    # heads of 128 x 2 bytes = 2048 B a layer, kept whole; no experts; the
+    # working set is what the compile for a described v5e counted beside
+    # weights and cache for the 256-row programs
+    # (benchmark/compile_check.py, PERF.md section 6, PR 46)
+    "falcon_h1": {
+        "name": "falcon-h1", "wire": "FalconH1ForCausalLM",
+        "module": "falcon_h1", "params_gb": 8.19, "working_gb": 2.0,
+        "cache_layers": ((8192.0, 0),), "row_bytes": 16900096.0},
 }
 
 

@@ -239,6 +239,29 @@ def test_gated_delta_step_compiles_for_v5e(v5e, rows, heads, keys, values):
         heads * keys * values) // 8
 
 
+@pytest.mark.parametrize("rows, heads, size, dim, groups", [
+    pytest.param(256, 32, 256, 128, 2, id="falconh1-decode-256x32x256x128"),
+    pytest.param(8, 32, 256, 128, 2, id="falconh1-decode-8-rows"),
+])
+def test_ssd_step_compiles_for_v5e(v5e, rows, heads, size, dim, groups):
+    """The state-space step kernel as the decode calls it (ISSUE 46):
+    float32 state in and out under one buffer, a group's `B` and `C` as
+    columns fetched once a group."""
+    from chiaswarm_tpu.ops.ssd import _step_pallas
+
+    f32 = jnp.float32
+    compiled = _step_pallas.lower(
+        _shape(v5e, (rows, heads, dim), f32), _shape(v5e, (rows, heads), f32),
+        _shape(v5e, (heads,), f32), _shape(v5e, (rows, groups, size), f32),
+        _shape(v5e, (rows, groups, size), f32), _shape(v5e, (heads,), f32),
+        _shape(v5e, (rows, heads, size, dim), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssd_step" in text
+    # nothing of the state's size beside the state itself
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * (
+        heads * size * dim) // 8
+
+
 def test_flux_double_block_overlaps_its_collectives_on_four_v5e(
         v5e_slice, monkeypatch):
     """FLUX.1-dev's double block at `flux-backlog`'s shapes on [data=1,
@@ -369,6 +392,9 @@ def test_exaone_prefill_keeps_a_conditional_a_span_on_v5e(v5e, monkeypatch):
     ("kimi", "KIMI_K2_EP32", (256, 128, 64), 1.20e9),
     ("sdar", "SDAR_30B_PP8", (256,), 0.50e9),
     ("qwen3_next", "QWEN3_NEXT_80B_EP4", (256,), 1.40e9),
+    # ISSUE 46: no expert, so no grouped matmul at all; the chunk form's
+    # float32 operands and the 21,504-wide feed-forward's activations
+    ("falcon_h1", "FALCON_H1_34B_PP18", (256,), 0.80e9),
 ])
 def test_a_batch_decode_cells_prefill_has_a_branch_a_width_on_v5e(
         v5e, monkeypatch, module, preset, widths, temp):

@@ -126,7 +126,8 @@ def test_the_formatter_hands_a_block_decode_its_two_parameters():
 
 
 @pytest.mark.parametrize(
-    "family", ["kimi_k2", "exaone_moe", "sdar_moe", "qwen3_next"])
+    "family", ["kimi_k2", "exaone_moe", "sdar_moe", "qwen3_next",
+               "falcon_h1"])
 def test_a_text_family_is_a_row_and_a_module_that_gives_the_interface(
         family, monkeypatch):
     """ISSUE 45: what the four lists that had to agree were is one row of
@@ -251,7 +252,7 @@ def test_a_text_familys_key_is_spelt_in_one_file():
                     str(path.relative_to(root)))
     assert spelt == {family: {"text_families.py"}
                      for family in ("kimi_k2", "exaone_moe", "sdar_moe",
-                                    "qwen3_next")}
+                                    "qwen3_next", "falcon_h1")}
     from chiaswarm_tpu.coalesce import text_family_of
 
     assert text_family_of("test/tiny-sd") is None
@@ -391,11 +392,63 @@ def test_a_row_of_a_recurrent_state_costs_bytes_whatever_its_positions():
                                   512) == 64
 
 
+def test_a_row_of_two_mixers_costs_a_state_and_keys_on_every_layer():
+    """Falcon-H1's stage (ISSUE 46): each of the four layers holds a row
+    BOTH a float32 state with a convolution's tail whatever the row's
+    length AND 2048 B of keys and values a position; the row is 80 %
+    constant at the cell's 512 positions."""
+    import jax
+
+    from chiaswarm_tpu.models import falcon_h1
+
+    name = "test/Falcon-H1-34B-Instruct"
+    assert requirements._family_key(name) == "falcon_h1"
+    costs = requirements.TEXT_FAMILIES["falcon_h1"]
+    state = 4 * (32 * 256 * 128 * 4 + 3 * 5120 * 2)
+    assert costs["row_bytes"] == state == 16900096
+    assert costs["cache_layers"] == ((4 * 2 * 4 * 128 * 2, 0),)
+    for positions in (0, 1, 16, 512, 16512, 262144):
+        assert requirements.sequence_row_bytes("falcon_h1", positions) == (
+            state + 8192 * max(positions, 1)) > state
+    assert 0.80 < state / requirements.sequence_row_bytes(
+        "falcon_h1", 512) < 0.81
+    # what the model's own module counts for the cell's pass
+    cfg = falcon_h1.FALCON_H1_34B_PP18
+    whole, rings, kept = falcon_h1.cache_bytes(cfg, 256, 512, 2)
+    assert whole == 256 * requirements.sequence_row_bytes("falcon_h1", 512)
+    assert (rings, kept) == (0, 256 * state)
+    shapes = falcon_h1.param_shapes(cfg, jax.numpy.bfloat16)
+    held = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(shapes))
+    assert costs["params_gb"] == pytest.approx(held / 2 ** 30, abs=0.01)
+    # 256 rows of 512 fit beside weights and working set: the fullest
+    # pass the table admits, 283 rows at the most
+    free = 15.75 - costs["params_gb"] - costs["working_gb"]
+    per_row = (state + 8192 * 512) / (1 << 30)
+    assert requirements.fit_batch(_Slice(), name, 10 ** 9, 512) == int(
+        free / per_row) >= 256
+    assert requirements.fit_batch(_Slice(), name, 256, 512) == 256
+    assert requirements.coalesce_rows_limit(_Slice(), name, 512) == 256
+    assert requirements.coalesce_rows_limit(_Slice(), name, 16512) == 4
+    assert requirements.pass_positions_limit(_Slice(), "falcon_h1") == 131072
+    # a row of one position still costs its state
+    tight = _Slice(gib=costs["params_gb"] + costs["working_gb"] + 1.0)
+    assert requirements.fit_batch(tight, name, 10 ** 9, 1) == int(
+        (1 << 30) / (state + 8192)) == 63
+    assert 0 < requirements.pass_positions_limit(tight, "falcon_h1") < 131072
+    assert requirements.fit_batch(_Slice(), name, 512, 512) < 512
+    with pytest.raises(ValueError, match="does not fit"):
+        requirements.check_capacity(_Slice(gib=8), name, 4, 512)
+    assert requirements.fit_batch(_Slice(gib=1), "test/tiny-falcon-h1", 64,
+                                  512) == 64
+
+
 @pytest.mark.parametrize("name, family", [
     ("test/Kimi-K2.6", "kimi_k2"),
     ("test/K-EXAONE-236B-A23B", "exaone_moe"),
     ("test/SDAR-30B-A3B-Chat", "sdar_moe"),
     ("test/Qwen3-Next-80B-A3B-Instruct", "qwen3_next"),
+    ("test/Falcon-H1-34B-Instruct", "falcon_h1"),
 ])
 def test_a_full_size_test_name_is_no_stand_in(name, family):
     """Every text family gives a `test/` name its published widths, so
@@ -508,10 +561,11 @@ def test_a_worker_advertises_the_sequence_families_appetite(sdaas_root):
     caps = worker._capabilities()
     # not HBM on the CPU: the ceiling; the job cap stays what it was
     assert caps["family_gang_rows"] == (
-        "kimi_k2:256,exaone_moe:256,sdar_moe:256,qwen3_next:256")
+        "kimi_k2:256,exaone_moe:256,sdar_moe:256,qwen3_next:256,"
+        "falcon_h1:256")
     assert caps["family_gang_positions"] == (
         "kimi_k2:131072,exaone_moe:131072,sdar_moe:131072,"
-        "qwen3_next:131072")
+        "qwen3_next:131072,falcon_h1:131072")
     assert caps["gang_rows"] == 8
     # the batcher's own budget is the job's true positions
     assert worker._coalesce_rows_limit(_job(1, 2)) == 256
@@ -787,8 +841,12 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
         ("test/tiny-sdar", 32, [13, 2, 6, 5, 4], {"16": 3}, 2, 128 - 5 * 16),
         ("test/tiny-qwen3-next", 32, [16, 1, 9, 3, 2, 2], {"16": 3}, 1,
          128 - 6 * 16),
+        # ... and so does Falcon-H1: spans of 16 of a 32-slot row, a row
+        # a chunk, every span of a real row run (the state passes
+        # through), the row that only pads the pass not run
+        ("test/tiny-falcon-h1", 16, [29, 16, 7], {"16": 6}, 2, 32),
     ], ids=["spans", "whole_rows", "nothing_to_skip", "kimi", "one_chunk",
-            "narrowest", "sdar", "qwen3_next"])
+            "narrowest", "sdar", "qwen3_next", "falcon_h1"])
 def test_a_pass_counts_real_padding_and_skipped_slots(
         monkeypatch, model, chunk_tokens, lengths, widths, skipped,
         skipped_slots):
@@ -832,12 +890,14 @@ def test_a_pass_counts_real_padding_and_skipped_slots(
         prefill["calls"] + config["decode_steps"] * pipe.config.expert_layers)
     # the device's own tally: a call counts the held experts that had a
     # pair, so no more of them than the calls made hold
-    assert 0 < prefill["active"] <= (
-        prefill["calls"] * pipe.config.experts_held[1])
+    # (a dense model makes no call and holds nothing: every count is 0)
+    held = pipe.config.experts_held[1]
+    assert bool(held) == (prefill["active"] > 0)
+    assert prefill["active"] <= prefill["calls"] * held
     # padding is routed nowhere, run or not; SDAR routes whole blocks
     whole = [n // 4 * 4 for n in lengths] if pipe.by_blocks else lengths
-    assert prefill["routed"] == (
-        sum(whole) * pipe.config.num_experts_per_tok * layers)
+    assert prefill["routed"] == sum(whole) * layers * getattr(
+        pipe.config, "num_experts_per_tok", 0)
 
 
 @pytest.mark.parametrize("model, extra", [
