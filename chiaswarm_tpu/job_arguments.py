@@ -130,7 +130,11 @@ def format_txt2txt_args(args: dict):
     of rows, each a list of ids of the model's vocabulary),
     `max_new_tokens`, `temperature`, `seed` and, for a model that decodes
     by blocks, `denoising_steps` and `confidence_threshold`. The result is
-    JSON."""
+    JSON. An id is drawn from the softmax of its logits over `temperature`
+    (0: the largest logit's id) with one uniform number a position, from
+    `seed` folded by the row's number in the job and the step
+    (ops/sampling.py): the same job gives the same ids, whatever rides
+    beside it."""
     from .coalesce import (
         DEFAULT_NEW_TOKENS,
         DEFAULT_TEMPERATURE,

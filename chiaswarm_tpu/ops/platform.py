@@ -23,7 +23,8 @@ KERNEL_TRACES = telemetry.counter(
     "swarm_kernel_traces_total",
     "Kernel dispatch decisions taken while tracing, by op (attention | "
     "group_norm | expert_matmul | latent_attention | tensor_matmul | "
-    "gated_delta_step | ssd_step) and path (flash | banded | ring | fused | grouped | "
+    "gated_delta_step | ssd_step | sampler) and path (flash | banded | ring | "
+    "fused | grouped | "
     "absorbed | overlapped | reduced | pallas | reference)",
     ("op", "path"),
 )

@@ -32,7 +32,11 @@ padding after it), so a pass is keyed by (rows, prompt slots, new tokens):
   (the first token comes from prefill's logits, the last one is never fed):
   a step feeds every row its last token, and samples the next on the device
   from a key folded from the job's seed, the row's number in its job and
-  the step, so a row's ids do not depend on its batchmates;
+  the step, so a row's ids do not depend on its batchmates (the sampler,
+  ops/sampling.py `sample`, is both decodes': one uniform number a
+  position of that key over a running sum of the position's own
+  `exp(logit / temperature - max)` in float32, the whole vocabulary held,
+  nothing truncated; temperature 0 takes the largest logit's id);
 - **step** is the decode step alone, given tokens in, logits out: what a
   comparison with the plain reference needs.
 
@@ -113,7 +117,7 @@ import numpy as np
 from .. import telemetry
 from ..coalesce import checked_denoising_steps, prompt_slots
 from ..models.text_model import family_module
-from ..ops import platform
+from ..ops import platform, sampling
 from ..parallel.mesh import make_mesh, replicated
 from ..registry import _auto_family, register_family
 from ..telemetry import Span
@@ -343,10 +347,7 @@ class TextGenerationPipeline:
 
         def sample(logits, keys, step, temperature):
             keys = jax.vmap(lambda key: jax.random.fold_in(key, step))(keys)
-            drawn = jax.vmap(jax.random.categorical)(
-                keys, logits / jnp.maximum(temperature, 1e-6))
-            return jnp.where(temperature > 0, drawn,
-                             jnp.argmax(logits, axis=-1)).astype(jnp.int32)
+            return sampling.sample(keys, logits, temperature)[0]
 
         def decode(params, cache, logits, lengths, job_keys, job_of_row,
                    row_in_job, temperature, load):
@@ -409,18 +410,6 @@ class TextGenerationPipeline:
         blocks = model.blocks_of(cfg, new_tokens)
         count = length // denoising_steps
 
-        def draw(logits, keys, temperature):
-            """An id a position from its own logits, and the probability
-            it was drawn with (at temperature 0 the largest logit's)."""
-            scaled = logits / jnp.where(temperature > 0, temperature, 1.0)
-            drawn = jnp.where(
-                temperature > 0,
-                jax.vmap(jax.random.categorical)(keys, scaled),
-                jnp.argmax(logits, axis=-1)).astype(jnp.int32)
-            picked = jnp.take_along_axis(scaled, drawn[..., None], -1)[..., 0]
-            return drawn, jnp.exp(
-                picked - jax.nn.logsumexp(scaled, axis=-1))
-
         def decode(params, cache, ids, lengths, job_keys, job_of_row,
                    row_in_job, temperature, threshold, load):
             valid = lengths > 0
@@ -436,9 +425,12 @@ class TextGenerationPipeline:
                     load, valid=valid, finished=finished)
                 forward_keys = jax.vmap(lambda key: jax.random.fold_in(
                     jax.random.fold_in(key, number), forward))(keys)
-                drawn, confidence = draw(logits, forward_keys, temperature)
+                # an id a position from its own logits, and the probability
+                # it was drawn with (at temperature 0 the largest logit's)
+                drawn, log_p = sampling.sample(
+                    forward_keys, logits, temperature)
                 tokens, left = model.unmask(
-                    tokens, masked, drawn, confidence, count,
+                    tokens, masked, drawn, jnp.exp(log_p), count,
                     threshold if thresholded else None)
                 took = jnp.sum(masked & ~left)
                 fixed = jnp.sum(valid[:, None] & ~masked)

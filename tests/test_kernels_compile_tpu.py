@@ -239,6 +239,37 @@ def test_gated_delta_step_compiles_for_v5e(v5e, rows, heads, keys, values):
         heads * keys * values) // 8
 
 
+@pytest.mark.parametrize("positions, vocab", [
+    pytest.param(1024, 151936, id="sdar-block-decode-1024x151936"),
+    pytest.param(256, 261120, id="falconh1-decode-256x261120"),
+    pytest.param(256, 37984, id="qwen3next-decode-256x37984"),
+    pytest.param(4, 19200, id="exaone-decode-4x19200"),
+])
+def test_the_samplers_kernels_compile_for_v5e(v5e, positions, vocab):
+    """The sampler's two kernels as a decode step calls them (ISSUE 47):
+    the pass over the float32 logits, whole blocks of 1024 ids and a
+    shorter last one, and the fetch of a position's block; nothing as large
+    as the logits beside the logits."""
+    from chiaswarm_tpu.ops.sampling import (
+        _block_pallas,
+        _blocks,
+        _statistics_pallas,
+    )
+
+    f32 = jnp.float32
+    logits = _shape(v5e, (positions, vocab), f32)
+    statistics = _statistics_pallas.lower(
+        logits, _shape(v5e, (positions,), f32)).compile()
+    block = _block_pallas.lower(
+        logits, _shape(v5e, (positions,), jnp.int32)).compile()
+    for compiled, name in ((statistics, "sampler_statistics"),
+                           (block, "sampler_block")):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and name in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * (
+            positions * max(vocab // 16, 4 * _blocks(vocab)[0]))
+
+
 @pytest.mark.parametrize("rows, heads, size, dim, groups", [
     pytest.param(256, 32, 256, 128, 2, id="falconh1-decode-256x32x256x128"),
     pytest.param(8, 32, 256, 128, 2, id="falconh1-decode-8-rows"),

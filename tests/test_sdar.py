@@ -32,7 +32,7 @@ import pytest
 
 from benchmark.reference import block_diffusion_moe as reference
 from chiaswarm_tpu.models import experts, sdar
-from chiaswarm_tpu.ops import dot_product_attention
+from chiaswarm_tpu.ops import dot_product_attention, sampling
 from chiaswarm_tpu.ops.attention import reference_attention
 from chiaswarm_tpu.ops.banded_attention import band_blocks, banded_attention
 from chiaswarm_tpu.pipelines.text_generation import TextGenerationPipeline
@@ -360,8 +360,9 @@ def _plain_decode(pipe, requests, new_tokens, steps, temperature,
                 key = jax.random.fold_in(jax.random.fold_in(
                     jax.random.fold_in(requests[job]["rng"], number), block),
                     forward)
-                drawn = (np.asarray(jax.random.categorical(key, scaled[at]))
-                         if temperature else scaled[at].argmax(-1))
+                # the program's own sampler on the row's own key (ISSUE 47)
+                drawn = np.asarray(sampling.sample(
+                    key[None], logits[at][None], temperature)[0][0])
                 probs = np.asarray(jax.nn.softmax(scaled[at], axis=-1))
                 confidence = probs[np.arange(B), drawn]
                 new_ids, new_masked = reference.unmask(
