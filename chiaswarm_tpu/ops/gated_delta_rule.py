@@ -15,9 +15,14 @@ handful of operations a byte, so it is bound by the memory: on a TPU a
 Pallas kernel that reads each head's matrix once and writes it once
 (`pallas`), elsewhere the recurrence in `jax.numpy` (`reference`; as XLA
 einsums on a chip the state would go through four fusions);
-`interpret=True` runs the kernel interpreted, for tests. Routed by
-ops/platform.py and counted by `swarm_kernel_traces_total{op=
-"gated_delta_step", path}`.
+`interpret=True` runs the kernel interpreted, for tests. The kernel leaves
+the state in HBM and moves it itself (ops/state_rows.py): a grid step
+takes as many rows as two sets of buffers hold (Qwen3-Next: 8 rows of 32
+matrices of [128, 128], 16 MB a set), reads them a row (2 MB) at a time,
+computes a row in place as it lands, and writes the step's rows when the
+next step's have been read. A row's arithmetic does not know which rows
+share its step. Routed by ops/platform.py and counted by
+`swarm_kernel_traces_total{op="gated_delta_step", path}`.
 
 `gated_delta_chunks` is the same recurrence over `[rows, positions]` in
 chunks of 64 positions (the published chunk form: prefill), `jax.numpy`
@@ -44,13 +49,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import platform
+from . import platform, state_rows
 
 # positions of a chunk of the chunk form
 CHUNK = 64
-# a row's 32 matrices of [128, 128] float32 are 2 MB: in and out, two in
-# flight each
-_VMEM_LIMIT = 40 * 1024 * 1024
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -66,27 +68,38 @@ def step_reference(q, k, v, g, beta, state):
     return jnp.sum(state * q[..., :, None], axis=-2), state
 
 
-def _step_kernel(qk_ref, v_ref, decay_ref, beta_ref, state_ref, o_ref,
-                 out_ref, *, heads: int):
-    """One row's heads: `qk_ref` [1, K, 2 heads] (a head's query in lane
-    `h`, its key in lane `heads + h`: a column a head, so that it
-    broadcasts along the values' lanes), `v_ref`, `decay_ref`, `beta_ref`
-    [1, heads, V] (the two scalars a head already along the lanes),
-    `state_ref` / `out_ref` [1, heads, K, V]."""
-    for head in range(heads):
-        q = qk_ref[0, :, head:head + 1]
-        k = qk_ref[0, :, heads + head:heads + head + 1]
-        s = state_ref[0, head] * decay_ref[0, head:head + 1, :]
-        m = jnp.sum(s * k, axis=0, keepdims=True)
-        d = (v_ref[0, head:head + 1, :] - m) * beta_ref[0, head:head + 1, :]
-        s = s + k * d
-        out_ref[0, head] = s
-        o_ref[0, head:head + 1, :] = jnp.sum(s * q, axis=0, keepdims=True)
+def _step_kernel(qk_ref, v_ref, decay_ref, beta_ref, state_hbm, o_ref,
+                 out_hbm, held, reads, writes):
+    """A grid step's rows: `qk_ref` [rows, K, 2 H] (a head's query in lane
+    `h`, its key in lane `H + h`: a column a head, so that it broadcasts
+    along the values' lanes), `v_ref`, `decay_ref`, `beta_ref`, `o_ref`
+    [rows, H, V] (the two scalars a head already along the lanes); the
+    state stays in HBM and goes through `held` a row at a time
+    (`state_rows.stream`)."""
+    heads = v_ref.shape[1]
+
+    def update(held, row, _):  # a row is one chunk
+        for head in range(heads):
+            q = qk_ref[row, :, head:head + 1]
+            k = qk_ref[row, :, heads + head:heads + head + 1]
+            s = held[row, head] * decay_ref[row, head:head + 1, :]
+            m = jnp.sum(s * k, axis=0, keepdims=True)
+            d = ((v_ref[row, head:head + 1, :] - m)
+                 * beta_ref[row, head:head + 1, :])
+            s = s + k * d
+            held[row, head] = s
+            o_ref[row, head:head + 1, :] = jnp.sum(s * q, axis=0,
+                                                   keepdims=True)
+
+    state_rows.stream(state_hbm, out_hbm, held, reads, writes, update)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _step_pallas(q, k, v, g, beta, state, *, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _step_pallas(q, k, v, g, beta, state, *, block_rows: int | None = None,
+                 interpret: bool = False):
     rows, heads, keys, values = state.shape
+    if block_rows is None:
+        block_rows = state_rows.rows_a_step(rows, 4 * heads * keys * values)
     f32 = jnp.float32
     # a head's query and key as columns: [R, K, 2 heads]
     qk = jnp.concatenate([q.astype(f32), k.astype(f32)], axis=1).transpose(
@@ -94,24 +107,23 @@ def _step_pallas(q, k, v, g, beta, state, *, interpret: bool = False):
     along = (rows, heads, values)
     decay = jnp.broadcast_to(jnp.exp(g.astype(f32))[..., None], along)
     strength = jnp.broadcast_to(beta.astype(f32)[..., None], along)
-
-    def row(*trailing):
-        return pl.BlockSpec((1, *trailing),
-                            lambda r: (r,) + (0,) * len(trailing))
-
-    small = row(heads, values)
+    small = pl.BlockSpec((block_rows, heads, values), lambda r: (r, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     o, out = pl.pallas_call(
-        functools.partial(_step_kernel, heads=heads),
-        grid=(rows,),
-        in_specs=[row(keys, 2 * heads), small, small, small,
-                  row(heads, keys, values)],
-        out_specs=[small, row(heads, keys, values)],
+        _step_kernel,
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, keys, 2 * heads),
+                               lambda r: (r, 0, 0)),
+                  small, small, small, in_hbm],
+        out_specs=[small, in_hbm],
         out_shape=[jax.ShapeDtypeStruct(along, f32),
                    jax.ShapeDtypeStruct(state.shape, f32)],
+        scratch_shapes=state_rows.buffers(block_rows, heads, 1,
+                                          (keys, values)),
         input_output_aliases={4: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=state_rows.VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=7 * state.size, transcendentals=0,
             bytes_accessed=4 * (2 * state.size + 4 * rows * heads * values

@@ -21,7 +21,13 @@ operations a value, so it is bound by the memory: on a TPU a Pallas kernel
 that reads each head's matrix once and writes it once and fetches `B` and
 `C` a group and not a head (`pallas`), elsewhere the recurrence in
 `jax.numpy` (`reference`); `interpret=True` runs the kernel interpreted,
-for tests. Routed by ops/platform.py and counted by
+for tests. The kernel leaves the state in HBM and moves it itself
+(ops/state_rows.py): a grid step takes as many rows as two sets of
+buffers hold (Falcon-H1-34B: 4 rows of 32 matrices, 16 MB a set), reads
+them a row's group at a time (16 matrices, 2 MB), computes a group in
+place as it lands, and writes the step's rows when the next step's have
+been read. A row's arithmetic does not know which rows share its step.
+Routed by ops/platform.py and counted by
 `swarm_kernel_traces_total{op="ssd_step", path}`.
 
 `ssd_chunks` is the same recurrence over `[rows, positions]` in chunks of
@@ -49,11 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import platform
+from . import platform, state_rows
 
-# a group's 16 matrices of [256, 128] float32 are 2 MB: in and out, two in
-# flight each
-_VMEM_LIMIT = 40 * 1024 * 1024
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -72,27 +75,35 @@ def step_reference(x, dt, a, b, c, d, state):
     return y + d.astype(f32)[:, None] * x, state
 
 
-def _step_kernel(bc_ref, dtx_ref, decay_ref, state_ref, y_ref, out_ref, *,
-                 heads: int):
-    """One row's group: `bc_ref` [1, 1, N, 2] (the group's `B` in lane 0,
-    its `C` in lane 1: columns, so that they broadcast along the head
-    dim's lanes), `dtx_ref` and `decay_ref` [1, heads, P] (`dt x` a head,
-    and the head's decay already along the lanes), `state_ref` / `out_ref`
-    [1, heads, N, P]."""
-    b = bc_ref[0, 0, :, 0:1]
-    c = bc_ref[0, 0, :, 1:2]
-    for head in range(heads):
-        s = (state_ref[0, head] * decay_ref[0, head:head + 1, :]
-             + b * dtx_ref[0, head:head + 1, :])
-        out_ref[0, head] = s
-        y_ref[0, head:head + 1, :] = jnp.sum(s * c, axis=0, keepdims=True)
+def _step_kernel(bc_ref, dtx_ref, decay_ref, state_hbm, y_ref, out_hbm, held,
+                 reads, writes, *, per: int):
+    """A grid step's rows: `bc_ref` [rows, G, N, 2] (a group's `B` in lane
+    0, its `C` in lane 1: columns, so that they broadcast along the head
+    dim's lanes), `dtx_ref` and `decay_ref` [rows, H, P] (`dt x` a head,
+    and the head's decay already along the lanes), `y_ref` [rows, H, P];
+    the state stays in HBM and goes through `held` a row's group at a time
+    (`state_rows.stream`)."""
+
+    def update(held, row, group):
+        b = bc_ref[row, group, :, 0:1]
+        c = bc_ref[row, group, :, 1:2]
+        for head in range(group * per, (group + 1) * per):
+            s = (held[row, head] * decay_ref[row, head:head + 1, :]
+                 + b * dtx_ref[row, head:head + 1, :])
+            held[row, head] = s
+            y_ref[row, head:head + 1, :] = jnp.sum(s * c, axis=0,
+                                                   keepdims=True)
+
+    state_rows.stream(state_hbm, out_hbm, held, reads, writes, update)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _step_pallas(x, dt, a, b, c, d, state, *, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _step_pallas(x, dt, a, b, c, d, state, *, block_rows: int | None = None,
+                 interpret: bool = False):
     rows, heads, size, dim = state.shape
     groups = b.shape[1]
-    per = heads // groups
+    if block_rows is None:
+        block_rows = state_rows.rows_a_step(rows, 4 * heads * size * dim)
     f32 = jnp.float32
     x, dt = x.astype(f32), dt.astype(f32)
     along = (rows, heads, dim)
@@ -100,20 +111,23 @@ def _step_pallas(x, dt, a, b, c, d, state, *, interpret: bool = False):
     decay = jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[..., None], along)
     # a group's `B` and `C` as columns: [R, G, N, 2]
     bc = jnp.stack([b.astype(f32), c.astype(f32)], axis=-1)
-    small = pl.BlockSpec((1, per, dim), lambda r, g: (r, g, 0))
-    whole = pl.BlockSpec((1, per, size, dim), lambda r, g: (r, g, 0, 0))
+    small = pl.BlockSpec((block_rows, heads, dim), lambda r: (r, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     y, out = pl.pallas_call(
-        functools.partial(_step_kernel, heads=per),
-        grid=(rows, groups),
-        in_specs=[pl.BlockSpec((1, 1, size, 2), lambda r, g: (r, g, 0, 0)),
-                  small, small, whole],
-        out_specs=[small, whole],
+        functools.partial(_step_kernel, per=heads // groups),
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, groups, size, 2),
+                               lambda r: (r, 0, 0, 0)),
+                  small, small, in_hbm],
+        out_specs=[small, in_hbm],
         out_shape=[jax.ShapeDtypeStruct(along, f32),
                    jax.ShapeDtypeStruct(state.shape, f32)],
+        scratch_shapes=state_rows.buffers(block_rows, heads, groups,
+                                          (size, dim)),
         input_output_aliases={3: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=state_rows.VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=5 * state.size, transcendentals=0,
             bytes_accessed=4 * (2 * state.size + 3 * rows * heads * dim
