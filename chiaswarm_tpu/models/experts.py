@@ -1,5 +1,6 @@
 """What the language models with routed sparse experts share
-(models/kimi.py, models/exaone.py, models/sdar.py, models/qwen3_next.py):
+(models/kimi.py, models/exaone.py, models/sdar.py, models/qwen3_next.py,
+models/glm_moe_dsa.py):
 the norm, the SwiGLU, the router under either of two rules, the held
 experts' grouped matmul, the tally of how the routing fell, the seeded init
 of a parameter tree and the head. A dense model (models/falcon_h1.py) takes
@@ -53,7 +54,8 @@ def leaf_rule(path, shape) -> tuple[float, float]:
     forgotten `1 +` would pass every test), N(0, 0.01^2) for the router's
     correction bias, the embedding by its width, a unit normal for `A_log`
     (which `finish_leaf` maps to the published `log U(0, 16)`), ones for
-    `dt_bias`, N(1, 0.1^2) for a state-space mixer's skip `D` (published
+    `dt_bias`, N(0, 0.1^2) for a LayerNorm's bias (published at zero: seeded
+    apart, a norm that dropped it would not pass), N(1, 0.1^2) for a state-space mixer's skip `D` (published
     at one: seeded apart, a head that read another's would not pass) and
     N(0, 0.1^2) for its convolution's bias, else a normal scaled by fan-in
     (the rows of the one matrix: a stack of experts is scaled expert by
@@ -63,7 +65,7 @@ def leaf_rule(path, shape) -> tuple[float, float]:
         return 0.0, 1.0
     if name == "D":
         return 0.1, 1.0
-    if name.endswith("norm_offset") or name == "conv_bias":
+    if name.endswith(("norm_offset", "norm_bias")) or name == "conv_bias":
         return 0.1, 0.0
     if name == "router_bias":
         return 0.01, 0.0

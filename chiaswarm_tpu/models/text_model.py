@@ -48,7 +48,13 @@ middle of a pass):
   [rows, block, vocab], cache, tally) out, with `first_block(cfg, ids,
   lengths)`, `unmask(ids, masked, drawn, confidence, count, threshold)`,
   `blocks_of(cfg, new_tokens)` and `cache_positions(cfg, slots,
-  new_tokens)`.
+  new_tokens)`;
+- where the family's attention reads the keys a learned index selects (its
+  row says `selects`): `index_cache_bytes(cfg, rows, positions, itemsize)`,
+  the index keys' part of `cache_bytes`' first number, and a tally of
+  three leaves, the third the selection's, which `selection_counts(leaf)`
+  turns into (the positions the real queries saw, those attention read for
+  them) on the host.
 
 The forwards themselves (`_forward`, `prefill_rows`, `step`, `block_step`)
 are each module's own: the networks differ.
@@ -69,6 +75,8 @@ INTERFACE = ("config_for", "param_shapes", "init_params", "new_cache",
 BY_TOKEN = ("step",)
 BY_BLOCKS = ("block_step", "first_block", "unmask", "blocks_of",
              "cache_positions")
+# what a family that selects keys gives besides
+SELECTS = ("index_cache_bytes", "selection_counts")
 
 
 def family_module(family: str):
@@ -76,8 +84,9 @@ def family_module(family: str):
     lacks is an AttributeError that says which, of which module."""
     row = TEXT_FAMILIES[family]
     module = importlib.import_module(f"{__package__}.{row['module']}")
-    for name in INTERFACE + (BY_BLOCKS if row.get("block_length")
-                             else BY_TOKEN):
+    for name in (INTERFACE
+                 + (BY_BLOCKS if row.get("block_length") else BY_TOKEN)
+                 + (SELECTS if row.get("selects") else ())):
         getattr(module, name)
     return module
 

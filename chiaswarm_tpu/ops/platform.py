@@ -23,9 +23,10 @@ KERNEL_TRACES = telemetry.counter(
     "swarm_kernel_traces_total",
     "Kernel dispatch decisions taken while tracing, by op (attention | "
     "group_norm | expert_matmul | latent_attention | tensor_matmul | "
-    "gated_delta_step | ssd_step | sampler) and path (flash | banded | ring | "
-    "fused | grouped | "
-    "absorbed | overlapped | reduced | pallas | reference)",
+    "gated_delta_step | ssd_step | sampler | lightning_indexer | "
+    "index_select | sparse_latent_attention) and path (flash | banded | "
+    "ring | fused | grouped | absorbed | overlapped | reduced | pallas | "
+    "einsum | gathered | reference)",
     ("op", "path"),
 )
 

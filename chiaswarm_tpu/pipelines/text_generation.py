@@ -1,8 +1,8 @@
 """Text completion from token ids: one jitted prefill and one jitted cached
 decode over a resident language model, whichever family the name resolves
 to: a family is a row of text_families.py `TEXT_FAMILIES` (Kimi-K2,
-K-EXAONE, SDAR, Qwen3-Next, Falcon-H1) and the module under `models/` the
-row names,
+K-EXAONE, SDAR, Qwen3-Next, Falcon-H1, GLM-5) and the module under `models/`
+the row names,
 which gives what models/text_model.py says a family's module gives and is
 asked for nothing else. There are two ways to decode, and the family's row
 says which is its own (`block_length`): by `step` (a token a row a forward,
@@ -22,7 +22,8 @@ padding after it), so a pass is keyed by (rows, prompt slots, new tokens):
   logits of every row's last prompt token. A row of a chunk of whole
   rows goes through at the narrowest of the widths its model offers that
   holds it (Kimi: the bucket and its halvings; SDAR, Qwen3-Next and
-  Falcon-H1: the bucket), and a chunk takes rows of one width (models/prefill_chunks.py:
+  Falcon-H1: the bucket; K-EXAONE and GLM-5 run fixed chunks of their
+  own), and a chunk takes rows of one width (models/prefill_chunks.py:
   read from `lengths` on the device, so it is one program whatever a pass
   brings, and a row's bits do not depend on its batchmates); a pass hands
   its rows over longest first, so that rows of a width stand together,
@@ -72,10 +73,19 @@ layers and a ring of its window on the others, SDAR keys and values a
 position on every layer, Qwen3-Next keys and values a position on every
 fourth layer and on the others a recurrent state and a convolution's tail
 a row, which do not grow with the positions, Falcon-H1 both on every
-layer): the whole is
+layer, GLM-5 a latent AND an index key a position a layer, two caches side
+by side): the whole is
 `swarm_pass_cache_bytes{model}`, the rings' part
 `swarm_pass_window_cache_bytes{model}`, the states' part
-`swarm_pass_state_bytes{model}`. A pass counts its prompt slots
+`swarm_pass_state_bytes{model}`, and where the family selects keys (its
+row's `selects`: GLM-5) the index keys' part
+`swarm_pass_index_cache_bytes{model}`. Such a family's attention reads, a
+query, the `index_topk` positions its indexer scores highest and no other:
+the positions the real queries saw and those attention read for them are
+summed on the device beside the routing's tally and come back with the ids
+(`swarm_sparse_visible_positions_total{model, phase}`,
+`swarm_sparse_selected_positions_total{model, phase}`, `phase` `prefill` |
+`decode`; the envelope's `selection`). A pass counts its prompt slots
 (`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
 that was computed all the same, and the slots of the bucket `skipped`:
 what lies past a chunk's width, and chunks the model's prefill did not
@@ -97,7 +107,8 @@ No tokenizer: ids travel on the wire, and there is no stop token, every
 row generates `max_new_tokens`. `test/` names are seeded weights: `tiny` in
 the name is the family's tiny preset, any other the chip's share of the
 deployment at the published widths (`KIMI_K2_EP32`, `EXAONE_236B_EP8`,
-`SDAR_30B_PP8`, `QWEN3_NEXT_80B_EP4`, `FALCON_H1_34B_PP18`;
+`SDAR_30B_PP8`, `QWEN3_NEXT_80B_EP4`, `FALCON_H1_34B_PP18`, `GLM5_EP16`:
+`test/GLM-5`, `test/tiny-glm-5`;
 `weights=` hands the tree in already on the chip, as `FluxPipeline` takes
 it: the host init of billions of parameters is minutes).
 """
@@ -165,6 +176,22 @@ PASS_STATE_BYTES = telemetry.gauge(
     "The part of swarm_pass_cache_bytes that is recurrent state and "
     "convolution tail (rows x the linear-attention layers: it does not "
     "grow with the positions), by model", ("model",))
+PASS_INDEX_CACHE_BYTES = telemetry.gauge(
+    "swarm_pass_index_cache_bytes",
+    "The part of swarm_pass_cache_bytes that is index keys (rows x "
+    "positions x the index key's width x layers: what a lightning indexer "
+    "scores a query against), by model; only a family that selects keys "
+    "sets it", ("model",))
+SPARSE_VISIBLE = telemetry.counter(
+    "swarm_sparse_visible_positions_total",
+    "Cached positions the real queries of a family that selects keys could "
+    "see (a query at position t: t + 1), summed over layers, on the "
+    "device, by model and phase (prefill | decode)", ("model", "phase"))
+SPARSE_SELECTED = telemetry.counter(
+    "swarm_sparse_selected_positions_total",
+    "Of swarm_sparse_visible_positions_total, the positions the selection "
+    "picked and attention read (min(index_topk, visible) a query), by "
+    "model and phase", ("model", "phase"))
 BLOCK_FORWARD_ROWS = telemetry.counter(
     "swarm_block_forward_rows_total",
     "Real rows x forwards of a block decode, by model and kind (denoise: "
@@ -225,6 +252,8 @@ class TextGenerationPipeline:
         self.config = self.model.config_for(model_name)
         # the way this family decodes: a block a row, or a token a row
         self.by_blocks = bool(TEXT_FAMILIES[family].get("block_length"))
+        # whether its attention reads the keys a learned index selects
+        self.selects = bool(TEXT_FAMILIES[family].get("selects"))
         if dtype is None:
             dtype = (jnp.bfloat16 if jax.default_backend() == "tpu"
                      else jnp.float32)
@@ -313,6 +342,11 @@ class TextGenerationPipeline:
             PASS_CACHE_BYTES.set(whole, model=self.model_name)
             PASS_WINDOW_CACHE_BYTES.set(rings, model=self.model_name)
             PASS_STATE_BYTES.set(state, model=self.model_name)
+            if self.selects:
+                PASS_INDEX_CACHE_BYTES.set(
+                    model.index_cache_bytes(cfg, rows, positions,
+                                            self.dtype.itemsize),
+                    model=self.model_name)
             return jax.jit(lambda params, ids, lengths: model.prefill(
                 params, cfg, ids, lengths, positions, *chunk))
 
@@ -565,7 +599,9 @@ class TextGenerationPipeline:
         with Span("readback", timings):
             # back in the jobs' order
             out = np.asarray(out)[np.argsort(order)]
-            (pairs, sums), (before, before_sums) = (
+            # the routing's tally, and behind it the selection's where
+            # the family has one
+            (pairs, sums, *chose), (before, before_sums, *chose_before) = (
                 tuple(np.asarray(x) for x in tally)
                 for tally in (load, filled))
             if counts is not None:
@@ -600,6 +636,20 @@ class TextGenerationPipeline:
                             decode_calls),
             "pairs_by_expert": pairs.sum(axis=0).tolist()}
         label = {"model": self.model_name}
+        selection = {}
+        if self.selects:
+            # (visible, selected) of the pass and of its prefill: decode's
+            # tally began where prefill's ended
+            whole, first = (self.model.selection_counts(leaf)
+                            for leaf in (chose[0], chose_before[0]))
+            by_phase = [{"prefill": before, "decode": total - before}
+                        for total, before in zip(whole, first)]
+            for counter, phases in zip((SPARSE_VISIBLE, SPARSE_SELECTED),
+                                       by_phase):
+                for phase, count in phases.items():
+                    counter.inc(count, phase=phase, **label)
+            selection = {"selection": dict(zip(("visible", "selected"),
+                                               by_phase))}
         blocks = {}
         if self.by_blocks:
             BLOCK_FORWARD_ROWS.inc(real * denoise, kind="denoise", **label)
@@ -650,6 +700,7 @@ class TextGenerationPipeline:
                 "cache_bytes_window": cache_bytes_window,
                 "cache_bytes_state": cache_bytes_state,
                 "routing": routing,
+                **selection,
                 "timings": dict(timings)}))
             at += n
         return results

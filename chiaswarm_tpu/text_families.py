@@ -17,6 +17,9 @@ A row:
   time (its module then has `block_step` and not `step`): the block's
   length, which its config's `block_length` equals;
 - `module`: its module under `models/`;
+- `selects`: only where the family's attention reads the keys a learned
+  index selects (its module then has `index_cache_bytes` and
+  `selection_counts`, and its tally a third leaf: models/text_model.py);
 - the footprint admission reckons with, in bf16 on one chip. Admission is
   the weights the chip holds (`params_gb`, GiB), a working set that does
   not grow with the rows (`working_gb`: a prefill chunk's activations and
@@ -98,6 +101,21 @@ TEXT_FAMILIES: dict[str, dict] = {
         "name": "falcon-h1", "wire": "FalconH1ForCausalLM",
         "module": "falcon_h1", "params_gb": 8.19, "working_gb": 2.0,
         "cache_layers": ((8192.0, 0),), "row_bytes": 16900096.0},
+    # one of 16 chips that share each layer (models/glm_moe_dsa.py
+    # GLM5_EP16): one dense and four expert layers, experts 0-15 of 256, an
+    # eighth of the vocabulary: 3.910 B parameters = 7.82 GB; a position is
+    # a latent of 576 values AND an index key of 128 values x 2 bytes =
+    # 1408 B on each of 5 layers, both kept whole (attention reads 2048
+    # selected positions a query, the indexer scores every one); the
+    # working set is what the compile for a described v5e counted beside
+    # weights and cache for the 2-row, 32768-slot prefill program (a span's
+    # index scores [4096, 32768] float32, its mask, and the 64 heads' keys
+    # and values expanded from 32768 cached latents:
+    # benchmark/compile_check.py, PERF.md section 6, PR 49)
+    "glm_moe_dsa": {
+        "name": "glm-5", "wire": "GlmMoeDsaForCausalLM",
+        "module": "glm_moe_dsa", "selects": True, "params_gb": 7.28,
+        "working_gb": 4.0, "cache_layers": ((7040.0, 0),)},
 }
 
 

@@ -1,5 +1,5 @@
-"""The Pallas kernels (the UNet's two, the grouped expert matmul and the
-gated delta rule's step), one
+"""The Pallas kernels (the UNet's two, the grouped expert matmul, the
+gated delta rule's step and a learned key selection's three), one
 MMDiT block across the four chips of the slice, and K-EXAONE's prefill
 program at its cell's shape,
 compiled for a described v5e chip at the published widths (no chip attached: the TPU compiler is installed here and
@@ -285,6 +285,42 @@ def test_the_samplers_kernels_compile_for_v5e(v5e, positions, vocab):
         assert "tpu_custom_call" in text and name in text
         assert compiled.memory_analysis().temp_size_in_bytes < 4 * (
             positions * max(vocab // 16, 4 * _blocks(vocab)[0]))
+
+
+@pytest.mark.parametrize("sq,skv", [
+    pytest.param(4096, 32768, id="glm5-last-span"),
+    pytest.param(4096, 4096, id="glm5-first-span"),
+])
+def test_the_key_selections_three_kernels_compile_for_v5e(v5e, sq, skv):
+    """`glm5-long-context`'s prefill span (ISSUE 49): 32 index heads of
+    128 on one shared key, the radix select of 2048 over whole rows of
+    float32 scores in VMEM with an int8 mask out, and the flash kernel at
+    heads of 256 that reads the mask's block beside the keys, each with
+    the blocks its own rule gives, the span's first position a scalar
+    handed to the kernel (one compiled kernel a row's every span); no
+    operand is copied on its way in (keys and values come as `[rows, keys,
+    heads x 256]`, the layout one matmul from the latents leaves them
+    in)."""
+    from chiaswarm_tpu.ops.lightning_indexer import (
+        _indexer_pallas,
+        _select_pallas,
+    )
+    from chiaswarm_tpu.ops.sparse_latent_attention import _prefill_pallas
+
+    compiled = _indexer_pallas.lower(
+        _shape(v5e, (1, sq, 32, 128)), _shape(v5e, (1, sq, 32), jnp.float32),
+        _shape(v5e, (1, skv, 128)), _shape(v5e, (), jnp.int32)).compile()
+    assert "lightning_indexer" in compiled.as_text()
+    compiled = _select_pallas.lower(
+        _shape(v5e, (1, sq, skv), jnp.float32), topk=2048).compile()
+    assert "index_select" in compiled.as_text()
+    compiled = _prefill_pallas.lower(
+        _shape(v5e, (1, sq, 64 * 256)), _shape(v5e, (1, skv, 64 * 256)),
+        _shape(v5e, (1, skv, 64 * 256)), _shape(v5e, (1, sq, skv), jnp.int8),
+        scale=256 ** -0.5, heads=64,
+        offset=_shape(v5e, (), jnp.int32)).compile()
+    assert "sparse_latent_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("rows, heads, size, dim, groups", [

@@ -127,7 +127,7 @@ def test_the_formatter_hands_a_block_decode_its_two_parameters():
 
 @pytest.mark.parametrize(
     "family", ["kimi_k2", "exaone_moe", "sdar_moe", "qwen3_next",
-               "falcon_h1"])
+               "falcon_h1", "glm_moe_dsa"])
 def test_a_text_family_is_a_row_and_a_module_that_gives_the_interface(
         family, monkeypatch):
     """ISSUE 45: what the four lists that had to agree were is one row of
@@ -166,9 +166,14 @@ def test_a_text_family_is_a_row_and_a_module_that_gives_the_interface(
                    else (text_model.BY_TOKEN, text_model.BY_BLOCKS))
     assert all(hasattr(model, name_) for name_ in text_model.INTERFACE + mine)
     assert not any(hasattr(model, name_) for name_ in other)
+    # ... and what a family that selects keys gives besides, where its row
+    # says so and nowhere else
+    assert all(hasattr(model, name_) == bool(what.get("selects"))
+               for name_ in text_model.SELECTS)
     pipe = text_generation.TextGenerationPipeline(
         name, allow_random_init=True)
     assert pipe.model is model and pipe.by_blocks is by_blocks
+    assert pipe.selects is bool(what.get("selects"))
     cfg, whole = model.config_for(name), model.config_for("test/whole")
     if by_blocks:
         assert cfg.block_length == whole.block_length == what["block_length"]
@@ -252,7 +257,8 @@ def test_a_text_familys_key_is_spelt_in_one_file():
                     str(path.relative_to(root)))
     assert spelt == {family: {"text_families.py"}
                      for family in ("kimi_k2", "exaone_moe", "sdar_moe",
-                                    "qwen3_next", "falcon_h1")}
+                                    "qwen3_next", "falcon_h1",
+                                    "glm_moe_dsa")}
     from chiaswarm_tpu.coalesce import text_family_of
 
     assert text_family_of("test/tiny-sd") is None
@@ -449,6 +455,7 @@ def test_a_row_of_two_mixers_costs_a_state_and_keys_on_every_layer():
     ("test/SDAR-30B-A3B-Chat", "sdar_moe"),
     ("test/Qwen3-Next-80B-A3B-Instruct", "qwen3_next"),
     ("test/Falcon-H1-34B-Instruct", "falcon_h1"),
+    ("test/GLM-5", "glm_moe_dsa"),
 ])
 def test_a_full_size_test_name_is_no_stand_in(name, family):
     """Every text family gives a `test/` name its published widths, so
@@ -562,10 +569,10 @@ def test_a_worker_advertises_the_sequence_families_appetite(sdaas_root):
     # not HBM on the CPU: the ceiling; the job cap stays what it was
     assert caps["family_gang_rows"] == (
         "kimi_k2:256,exaone_moe:256,sdar_moe:256,qwen3_next:256,"
-        "falcon_h1:256")
+        "falcon_h1:256,glm_moe_dsa:256")
     assert caps["family_gang_positions"] == (
         "kimi_k2:131072,exaone_moe:131072,sdar_moe:131072,"
-        "qwen3_next:131072,falcon_h1:131072")
+        "qwen3_next:131072,falcon_h1:131072,glm_moe_dsa:131072")
     assert caps["gang_rows"] == 8
     # the batcher's own budget is the job's true positions
     assert worker._coalesce_rows_limit(_job(1, 2)) == 256
@@ -588,12 +595,15 @@ def test_four_text_jobs_are_one_gang_one_pass_and_seeded(sdaas_root,
         await swarm.start()
         try:
             first = [await swarm.submit(_job(n, 100 + n)) for n in range(4)]
-            swarm.add_worker("text-worker")
-            done = [await swarm.wait_done(i, timeout=300) for i in first]
-            # the first job again, among other batchmates of other sizes
+            # the first job again, among other batchmates of other sizes:
+            # all eight reach the hive before a worker can take any, so
+            # each poll finds a whole gang of four (submitted to a live
+            # worker, a poll could fall between two submits and split it)
             again = [await swarm.submit(dict(_job(0, 100), id="again"))] + [
                 await swarm.submit(dict(_job(n, n, rows=2), id=f"other-{n}"))
                 for n in range(5, 8)]
+            swarm.add_worker("text-worker")
+            done = [await swarm.wait_done(i, timeout=300) for i in first]
             done.append(await swarm.wait_done(again[0], timeout=300))
             blobs = [await swarm.artifact(
                 status["result"]["artifacts"]["primary"]["href"])
@@ -733,6 +743,117 @@ def test_block_decode_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
         4 * blocks - len(row) % 4 for row in _block_job()["prompt_ids"])
 
 
+def test_selected_key_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
+                                                               monkeypatch):
+    """`test/tiny-glm-5` (ISSUE 49) through hive, worker and pipeline with
+    no setting of its own: three jobs of one row each (33 to 64 ids, four
+    to eight times the 8 keys a query selects; the prefill constant shrunk
+    to 16 tokens: four spans a 64-slot row, each attending to the latents
+    and index keys the spans before it cached) are one gang and one pass;
+    the envelope says what the queries saw and what attention read, by
+    phase, and how much of the cache is index keys; one seed gives one
+    answer alone as among batchmates."""
+    from chiaswarm_tpu import telemetry
+    from chiaswarm_tpu import worker as worker_module
+    from chiaswarm_tpu.hive_server.harness import LocalSwarm
+    from chiaswarm_tpu.pipelines import text_generation
+    from chiaswarm_tpu.settings import Settings
+
+    monkeypatch.setattr(worker_module, "POLL_SECONDS", 0.1)
+    monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", 16)
+    model, new = "test/tiny-glm-5", 5
+    lengths = [41, 64, 33]
+
+    def job(number, **extra):
+        rng = np.random.default_rng(number)
+        return {"id": f"glm-{number}", "workflow": "txt2txt",
+                "model_name": model, "max_new_tokens": new,
+                "temperature": 1.0, "seed": 100 + number,
+                "prompt_ids": [rng.integers(0, 128, lengths[number]).tolist()],
+                **extra}
+
+    assert coalesce_key(job(0)) == (model, "glm_moe_dsa", "txt2txt", 64, new,
+                                    1.0)
+    label = {"model": model}
+    counters = (text_generation.SPARSE_VISIBLE,
+                text_generation.SPARSE_SELECTED)
+    before = [[counter.value(phase=phase, **label)
+               for phase in ("prefill", "decode")] for counter in counters]
+
+    async def scenario():
+        swarm = LocalSwarm(n_workers=0, settings=Settings(
+            sdaas_token="t", worker_name="w", hive_port=0, metrics_port=0))
+        await swarm.start()
+        try:
+            ids = [await swarm.submit(job(n)) for n in range(3)]
+            swarm.add_worker("text-worker")
+            done = [await swarm.wait_done(i, timeout=300) for i in ids]
+            again = await swarm.submit(job(0, id="again"))
+            done.append(await swarm.wait_done(again, timeout=300))
+            blobs = [await swarm.artifact(
+                status["result"]["artifacts"]["primary"]["href"])
+                for status in done]
+            return done, blobs
+        finally:
+            await swarm.stop()
+
+    done, blobs = asyncio.run(scenario())
+    configs = [status["result"]["pipeline_config"] for status in done]
+    assert all(status["status"] == "done" and status["attempts"] == 1
+               for status in done)
+    assert [config["pass_rows"] for config in configs] == [3, 3, 3, 1]
+    assert len({config["trace"]["gang"]["id"] for config in configs[:3]}) == 1
+    for blob in blobs:
+        (row,) = json.loads(blob)["token_ids"]
+        assert len(row) == new and all(0 <= i < 128 for i in row)
+    # one job, one seed: the same bytes alone as among batchmates
+    assert blobs[3] == blobs[0] and len(set(blobs[:3])) == 3
+    layers, topk = 3, 8
+
+    def counts(rows):
+        """(visible, selected) by phase of a pass of rows of these
+        lengths: a query at position t sees t + 1 and reads 8 at most; a
+        decode step feeds generated token n at position length + n."""
+        seen = {"prefill": [np.arange(1, n + 1) for n in rows],
+                "decode": [n + 1 + np.arange(new - 1) for n in rows]}
+        return [{phase: layers * sum(int(pick(s).sum()) for s in parts)
+                 for phase, parts in seen.items()}
+                for pick in (lambda s: s, lambda s: np.minimum(s, topk))]
+
+    for config, rows in ((configs[0], lengths), (configs[3], lengths[:1])):
+        visible, selected = counts(rows)
+        assert config["selection"] == {"visible": visible,
+                                       "selected": selected}
+        assert 2 * selected["prefill"] < visible["prefill"]
+        # spans of 16: every span of a real row that holds an id ran
+        spans = sum(-(-n // 16) for n in rows)
+        assert config["prefill_chunk_widths"] == {"16": spans}
+        assert config["routing"]["prefill"]["calls"] == spans * 2
+        # two caches a layer: latents of 24 and index keys of 16, float32
+        positions = 64 + new
+        assert config["cache_bytes"] == (
+            config["padded_rows"] * positions * (24 + 16) * 4 * layers)
+        assert (config["cache_bytes_window"],
+                config["cache_bytes_state"]) == (0, 0)
+    both = [counts(lengths), counts(lengths[:1])]
+    moved = [[counter.value(phase=phase, **label) - was
+              for phase, was in zip(("prefill", "decode"), start)]
+             for counter, start in zip(counters, before)]
+    assert moved == [[sum(run[kind][phase] for run in both)
+                      for phase in ("prefill", "decode")]
+                     for kind in (0, 1)]
+    # the last pass placed: one row of 69 positions
+    assert text_generation.PASS_INDEX_CACHE_BYTES.value(**label) == (
+        1 * 69 * 16 * 4 * layers)
+    rendered = telemetry.REGISTRY.render()
+    assert "swarm_sparse_selected_positions_total" in rendered
+    assert "swarm_pass_index_cache_bytes" in rendered
+    traces = text_generation.platform.KERNEL_TRACES
+    for op in ("lightning_indexer", "index_select",
+               "sparse_latent_attention"):
+        assert traces.value(op=op, path="reference") > 0
+
+
 def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
                                                       monkeypatch):
     """`test/tiny-exaone` through hive, worker and pipeline: a row longer
@@ -845,8 +966,13 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
         # a chunk, every span of a real row run (the state passes
         # through), the row that only pads the pass not run
         ("test/tiny-falcon-h1", 16, [29, 16, 7], {"16": 6}, 2, 32),
+        # ... and GLM-5, where a span attends to the latents and index
+        # keys the spans before it cached and one that no row reaches is
+        # not run: the second of the rows of 16 and 7, both of the row
+        # that only pads the pass
+        ("test/tiny-glm-5", 16, [29, 16, 7], {"16": 4}, 4, 64),
     ], ids=["spans", "whole_rows", "nothing_to_skip", "kimi", "one_chunk",
-            "narrowest", "sdar", "qwen3_next", "falcon_h1"])
+            "narrowest", "sdar", "qwen3_next", "falcon_h1", "glm_moe_dsa"])
 def test_a_pass_counts_real_padding_and_skipped_slots(
         monkeypatch, model, chunk_tokens, lengths, widths, skipped,
         skipped_slots):
