@@ -34,12 +34,17 @@ cached so far and its own, select, and attend to keys and values EXPANDED
 from the cached latents under the selection's mask
 (`ops.sparse_latent_attention`: every visible pair is computed, none of the
 selected keys is gathered). The spans of a row are ONE traced body in a
-loop, the span's first position a number the loop carries: the three
-kernels take it as data and skip what lies behind the span, so a row of
-eight spans compiles five layers and not forty (a span's keys and values
-are expanded over the bucket's whole width for it, the columns behind the
-span zeros nobody selects). The keys are expanded through one matrix `[c_kv
-| k_rope] -> [k_nope | k_rope]` a head (`_key_up`: `W_kvb`'s key half over
+loop, the span's first position a number the loop carries, so a row of
+eight spans compiles five layers and not forty; the span's END (`start +
+span`, data too) bounds the four of a span's key side (`attention_prefill`):
+the index scores' key blocks, the selection's counts, the expansion's row
+blocks and the attention kernel's key blocks all stop at it, so a row's
+first span does an eighth of the key-side work of its last. What the two
+caches, the scores, the mask and the expanded keys and values hold at or
+past a span's end is read by none of the four (a later span writes the
+caches there; the scores and the expansion are left unwritten). The keys
+are expanded through one matrix `[c_kv | k_rope] -> [k_nope | k_rope]` a
+head (`_key_up`: `W_kvb`'s key half over
 an identity for the rotary key), so the kernel's 256-wide keys are written
 once and never put together from two arrays. A decode step scores the
 row's whole index-key cache, picks `index_topk` columns and reads ONLY
@@ -47,9 +52,11 @@ those rows of the latent cache, in the absorbed form (`W_kvb` folded into
 the query and the context): the same function.
 
 The selection's tally rides beside the routing's (`empty_load`: a third
-leaf, `[2, 2]` int32: the positions the real queries saw and those
-attention read for them, each as (multiples of 2^20, the rest), so that a
-pass of long rows does not overflow 32 bits); `selection_counts` reads it.
+leaf, `[4, 2]` int32: the positions the real queries saw, those attention
+read for them, the key positions the prefill's spans walked (rows x the
+span's end, a layer) and spans x the bucket's width, each as (multiples of
+2^20, the rest), so that a pass of long rows does not overflow 32 bits);
+`selection_counts` reads it.
 
 Rotary pairs: the checkpoint interleaves the two halves of each rotary
 pair (`rope_interleave`, `indexer_rope_interleave`); the weights here are
@@ -67,6 +74,7 @@ import jax.numpy as jnp
 
 from ..ops.lightning_indexer import index_select, lightning_indexer
 from ..ops.sparse_latent_attention import (
+    expand_latents,
     sparse_decode_attention,
     sparse_prefill_attention,
 )
@@ -232,24 +240,28 @@ _LOW = 20  # bits of a count's second part
 
 def empty_load(cfg: GlmMoeDsaConfig):
     """The routing's tally (models/experts.py) and the selection's beside
-    it: `[2, 2]` int32, (visible, selected) x (multiples of 2^20, the
+    it: `[4, 2]` int32, (visible, selected, key positions the prefill's
+    spans walked, spans x the bucket's width) x (multiples of 2^20, the
     rest), all zero."""
-    return (*experts.empty_load(cfg), jnp.zeros((2, 2), jnp.int32))
+    return (*experts.empty_load(cfg), jnp.zeros((4, 2), jnp.int32))
 
 
 def tally(load, index: int, cfg: GlmMoeDsaConfig, told, seen):
     """`load` with a layer's routing `told` and its selection `seen` ((the
-    positions the real queries saw, those attention read): int32 [2],
-    under 2^30 a call) added."""
+    positions the real queries saw, those attention read, the key
+    positions a prefill span walked, the bucket's): int32 [4], under 2^30
+    a call) added."""
     low = load[2][:, 1] + seen
     counts = jnp.stack([load[2][:, 0] + (low >> _LOW),
                         low & ((1 << _LOW) - 1)], axis=1)
     return (*experts.tally(load[:2], index, cfg, told), counts)
 
 
-def selection_counts(counts) -> tuple[int, int]:
-    """(visible, selected) as whole numbers, on the host, of a tally's
-    third leaf."""
+def selection_counts(counts) -> tuple[int, int, int, int]:
+    """(visible, selected, walked, bucket) as whole numbers, on the host,
+    of a tally's third leaf: `walked / bucket` is the share of the
+    bucket's width the prefill's key side went over (a row of eight spans:
+    36 / 64; 1 says no span was bounded by its end)."""
     return tuple(int(high) * (1 << _LOW) + int(low) for high, low in counts)
 
 
@@ -334,30 +346,35 @@ def attention_prefill(p, cfg: GlmMoeDsaConfig, h, start, cache, real,
     dim]: the rows' slots, the spans before this one written), `real` [R,
     C] the positions that hold a prompt's id. Returns the output, the two
     caches with the span written and (positions the real queries saw,
-    those attention read for them) int32 [2]."""
-    positions = jnp.broadcast_to(start + jnp.arange(h.shape[1]),
-                                 h.shape[:2])
+    those attention read for them, the key positions the span walked: rows
+    x its end, the bucket's: rows x S) int32 [4]."""
+    rows, span = h.shape[:2]
+    positions = jnp.broadcast_to(start + jnp.arange(span), (rows, span))
     q_nope, q_rope, entry, index_q, index_w, index_k = _projections(
         p, cfg, h, positions)
     latents, keys = (
         jax.lax.dynamic_update_slice(whole, new.astype(whole.dtype),
                                      (0, start, 0))
         for whole, new in zip(cache, (entry, index_k)))
-    scores = lightning_indexer(index_q, index_w, keys, offset=start,
+    # the span's end bounds all four of the key side: nothing below reads
+    # a column of either cache, of the scores or of the mask at or past it
+    end = start + span
+    scores = lightning_indexer(index_q, index_w, keys, offset=start, end=end,
                                interpret=interpret)
-    mask, selected = index_select(scores, cfg.index_topk,
+    mask, selected = index_select(scores, cfg.index_topk, end=end,
                                   interpret=interpret)
     # every head's keys and values as columns, as one matmul leaves them
-    k = dot(latents, _key_up(p, cfg).reshape(cfg.cache_width, -1))
-    v = dot(latents[..., :cfg.kv_lora_rank],
-            _kv_up(p, cfg)[..., cfg.qk_nope_head_dim:].reshape(
-                cfg.kv_lora_rank, -1))
+    k, v = expand_latents(
+        latents, _key_up(p, cfg).reshape(cfg.cache_width, -1),
+        _kv_up(p, cfg)[..., cfg.qk_nope_head_dim:].reshape(
+            cfg.kv_lora_rank, -1), end, interpret=interpret)
     out = sparse_prefill_attention(
-        jnp.concatenate([q_nope, q_rope], axis=-1).reshape(
-            *h.shape[:-1], -1), k, v, mask, cfg.softmax_scale,
-        cfg.num_attention_heads, offset=start, interpret=interpret)
+        jnp.concatenate([q_nope, q_rope], axis=-1).reshape(rows, span, -1),
+        k, v, mask, cfg.softmax_scale, cfg.num_attention_heads, offset=start,
+        interpret=interpret)
     seen = jnp.stack([jnp.sum(jnp.where(real, positions + 1, 0)),
-                      jnp.sum(jnp.where(real, selected, 0))])
+                      jnp.sum(jnp.where(real, selected, 0)),
+                      rows * end, rows * latents.shape[1]])
     return dot(out, p["o"]), (latents, keys), seen.astype(jnp.int32)
 
 
@@ -389,8 +406,9 @@ def attention_decode(p, cfg: GlmMoeDsaConfig, h, positions, cache, column,
                      up[..., cfg.qk_nope_head_dim:],
                      preferred_element_type=jnp.float32).astype(h.dtype)
     rows = jnp.ones(h.shape[:1], bool) if valid is None else valid
+    # (a step walks the row's whole cache: no span, no extent)
     seen = jnp.stack([jnp.sum(mask & rows[:, None]),
-                      jnp.sum(chosen[:, 0] & rows[:, None])])
+                      jnp.sum(chosen[:, 0] & rows[:, None]), 0, 0])
     return (dot(out.reshape(h.shape[0], -1), p["o"]), (latents, keys),
             seen.astype(jnp.int32))
 

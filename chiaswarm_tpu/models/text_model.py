@@ -54,7 +54,8 @@ middle of a pass):
   the index keys' part of `cache_bytes`' first number, and a tally of
   three leaves, the third the selection's, which `selection_counts(leaf)`
   turns into (the positions the real queries saw, those attention read for
-  them) on the host.
+  them, the key positions the prefill's spans walked, spans x the bucket's
+  width) on the host.
 
 The forwards themselves (`_forward`, `prefill_rows`, `step`, `block_step`)
 are each module's own: the networks differ.

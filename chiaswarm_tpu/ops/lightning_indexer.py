@@ -18,8 +18,14 @@ their ReLU, weighs and adds them in float32 and writes the `[block_q,
 block_k]` block of `I` once; no `[heads, queries, keys]` array ever reaches
 HBM. A key block wholly in the future of its query block is neither fetched
 (its block index is the last needed one's: no new DMA) nor computed (the
-step writes `-inf`). A decode step's one query a row is a matrix-vector
-product the cache's read bounds: plain XLA (`einsum`).
+step writes `-inf`). With `end` (the span's end: the keys at or past it do
+not exist yet; data, as `offset`) the key-block axis of the grid is
+`cdiv(end, block_k)`, a grid bound the kernel is handed as it runs: a key
+block at or past `end` is no step at all and its scores are LEFT UNWRITTEN,
+so only `index_select` under the same `end` may read the result; without
+`end` every block is written and the result is whole. A decode step's one
+query a row is a matrix-vector product the cache's read bounds: plain XLA
+(`einsum`).
 
 **`index_select`**: the `topk` largest of a query's visible scores, ties
 to the lower position (as `jax.lax.top_k`), every visible position where
@@ -34,7 +40,13 @@ Two forms, as their readers need them:
   prefix with it: 32 counts over the row give the `topk`-th largest score
   `T`; `ceil(log2(Skv + 1))` more counts give the column `P` before which
   the ties at `T` that still fit lie. Selected: `s > -inf and (s > T or (s
-  == T and column < P))`. No sort, nothing leaves VMEM but the mask.
+  == T and column < P))`. No sort, nothing leaves VMEM but the mask. Every
+  count is a loop over chunks of `_SELECT_COLUMNS` columns whose trip count
+  comes from `end` (the columns that exist; data, a scalar the kernel is
+  handed before its grid runs; None: all of them): a span's 48 counts go
+  up to the span's end and not over the bucket, a column at or past `end`
+  counts as one no query sees whatever its score holds, and its mask is 0,
+  so the mask is whole and well defined under any `end`.
 - `form="indices"` (decode): `(columns int32 [R, Sq, k], chosen bool [R,
   Sq, k])`, `k = min(topk, Skv)`: the same mask, then the selected
   columns in rising order (`columns_of`: the rank of every selected
@@ -64,7 +76,9 @@ from .flash_attention import _LANES, _VMEM_SLACK, _pad_to, _round_up
 _BLOCK_Q = 256    # queries a step of the indexer
 _BLOCK_K = 1024   # keys a step of the indexer
 _SELECT_ROWS = 32  # whole rows of scores a step of the selection (int8 tile)
+_SELECT_COLUMNS = 2048  # columns of them a count takes at a time
 _INT_MIN = -2 ** 31
+_UNSEEN = -0x800000 ^ 0x7FFFFFFF  # the key of -inf (`_select_kernel`)
 
 
 # --- lightning_indexer --------------------------------------------------------
@@ -90,6 +104,15 @@ def indexer_reference(q, w, k, visible=None, offset=None):
 def _last_block(i, offset, block_q: int, block_k: int):
     """The last key block the queries of block `i` see."""
     return (offset + (i + 1) * block_q - 1) // block_k
+
+
+def _walked(end, block: int, blocks: int):
+    """The blocks of `block` an axis of `blocks` of them is walked over up
+    to `end`: all of them where there is no end (None), else a grid bound
+    that is data, one at the least."""
+    if end is None:
+        return blocks
+    return jnp.clip(pl.cdiv(jnp.asarray(end, jnp.int32), block), 1, blocks)
 
 
 def _indexer_kernel(offset_ref, q_ref, w_ref, k_ref, o_ref, *, heads: int,
@@ -124,7 +147,8 @@ def _indexer_kernel(offset_ref, q_ref, w_ref, k_ref, o_ref, *, heads: int,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _indexer_pallas(q, w, k, offset=None, interpret: bool = False):
+def _indexer_pallas(q, w, k, offset=None, end=None,
+                    interpret: bool = False):
     rows, sq, heads, dim = q.shape
     skv = k.shape[1]
     assert sq <= skv and (interpret or dim % _LANES == 0), (q.shape, k.shape)
@@ -147,7 +171,8 @@ def _indexer_pallas(q, w, k, offset=None, interpret: bool = False):
                           block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(rows, sq_pad // block_q, n_k),
+            # a key block at or past `end` is no step
+            grid=(rows, sq_pad // block_q, _walked(end, block_k, n_k)),
             in_specs=[
                 pl.BlockSpec((None, block_q, heads * dim),
                              lambda r, i, j, at: (r, i, 0)),
@@ -173,13 +198,17 @@ def _indexer_pallas(q, w, k, offset=None, interpret: bool = False):
 
 
 @functools.partial(jax.named_call, name="lightning_indexer")
-def lightning_indexer(q, w, k, visible=None, offset=None, *,
+def lightning_indexer(q, w, k, visible=None, offset=None, end=None, *,
                       interpret: bool = False):
     """`q` [R, Sq, heads, D], `w` [R, Sq, heads], `k` [R, Skv, D] -> the
     index scores [R, Sq, Skv] float32, `-inf` where the query does not see
     the key: query `i` at position `offset + i` (a number or a traced
     scalar; None: the queries are the last `Sq` positions of the keys), or
-    with `visible` [R, Skv] one position a row that sees what it says."""
+    with `visible` [R, Skv] one position a row that sees what it says.
+    `end` (a number or a traced scalar, no less than the last query's
+    position + 1): the keys at or past it do not exist yet; their scores
+    are NOT DEFINED (on the chip a key block at or past it is never
+    written), which `index_select` takes under the same `end`."""
     if visible is not None:
         assert q.shape[1] == 1, q.shape
         platform.KERNEL_TRACES.inc(
@@ -189,7 +218,7 @@ def lightning_indexer(q, w, k, visible=None, offset=None, *,
         return indexer_reference(q, w, k, visible)
     if interpret or platform.trace_platform() == "tpu":
         platform.KERNEL_TRACES.inc(op="lightning_indexer", path="pallas")
-        return _indexer_pallas(q, w, k, offset, interpret=interpret)
+        return _indexer_pallas(q, w, k, offset, end, interpret=interpret)
     platform.KERNEL_TRACES.inc(op="lightning_indexer", path="reference")
     return indexer_reference(q, w, k, offset=offset)
 
@@ -236,70 +265,115 @@ def columns_of(mask, k: int):
             chosen.reshape(*lead, k))
 
 
-def _count(condition):
-    """[rows, 1] float32: how many of a row's columns hold (exact: a row
-    has far fewer than 2^24 columns)."""
-    return jnp.sum(jnp.where(condition, 1.0, 0.0), axis=-1, keepdims=True)
-
-
-def _select_kernel(s_ref, m_ref, n_ref, *, topk: int):
-    """One (row, block of queries) step: s_ref [BQ, Skv] float32 whole
-    rows of scores; m_ref [BQ, Skv] int8 the mask, n_ref [BQ, 128] int32
-    the selected positions a query (lane-replicated)."""
-    scores = s_ref[...]
-    rows, columns = scores.shape
-    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    # an int32 that orders as the float does (no NaN comes here)
-    key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+def _select_kernel(end_ref, s_ref, m_ref, n_ref, key_ref, *, topk: int,
+                   chunk: int):
+    """One (row, block of queries) step: end_ref [1] the columns that
+    exist; s_ref [BQ, Skv] float32 whole rows of scores; m_ref [BQ, Skv]
+    int8 the mask, n_ref [BQ, 128] int32 the selected positions a query
+    (lane-replicated); key_ref [BQ, Skv] int32 the scores' keys. Every
+    pass over the rows goes `chunk` columns at a time up to `end` and no
+    further: a column at or past it counts as one no query sees, whatever
+    its score holds, and its mask is 0."""
+    rows, columns = s_ref.shape
+    end = end_ref[0]
+    walked = pl.cdiv(end, chunk)
     want = jnp.float32(topk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1)
+
+    def chunk_of(c):
+        """(chunk `c`'s columns [rows, chunk], where they lie in a ref)."""
+        at = pl.multiple_of(c * chunk, chunk)
+        return at + lane, (slice(None), pl.ds(at, chunk))
+
+    def to_key(c, _):
+        column, here = chunk_of(c)
+        bits = jax.lax.bitcast_convert_type(s_ref[here], jnp.int32)
+        # an int32 that orders as the float does (no NaN comes here)
+        key_ref[here] = jnp.where(
+            column < end, jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits),
+            _UNSEEN)
+
+    jax.lax.fori_loop(0, walked, to_key, None)
+
+    def count(test):
+        """[rows, 1] float32: how many of a row's columns below the end
+        `test(keys, column)` holds for (exact: a row has far fewer than
+        2^24 columns); a lane's sum first, the lanes' once."""
+        def add(c, lanes):
+            column, here = chunk_of(c)
+            hit = jnp.where(test(key_ref[here], column), 1.0, 0.0)
+            for part in range(chunk // _LANES):
+                lanes = lanes + hit[:, part * _LANES:(part + 1) * _LANES]
+            return lanes
+
+        return jnp.sum(jax.lax.fori_loop(
+            0, walked, add, jnp.zeros((rows, _LANES), jnp.float32)),
+            axis=-1, keepdims=True)
 
     def value_bit(number, prefix):
         # `prefix` walks the keys' order as unsigned numbers do: flipping
         # the top bit makes it the signed threshold
         with_bit = prefix | jnp.left_shift(jnp.int32(1), 31 - number)
-        enough = _count(key >= (with_bit ^ _INT_MIN)) >= want
+        enough = count(
+            lambda key, _: key >= (with_bit ^ _INT_MIN)) >= want
         return jnp.where(enough, with_bit, prefix)
 
     prefix = jax.lax.fori_loop(0, 32, value_bit,
                                jnp.zeros((rows, 1), jnp.int32))
     threshold = prefix ^ _INT_MIN  # the topk-th largest key (or the least)
-    above = key > threshold
-    ties = key == threshold
-    room = want - _count(above)  # ties that still fit
-    column = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    room = want - count(lambda key, _: key > threshold)  # ties that fit
     width = columns.bit_length()
 
     def column_bit(number, edge):
         with_bit = edge | jnp.left_shift(jnp.int32(1), width - 1 - number)
-        fits = _count(ties & (column < with_bit)) <= room
+        fits = count(lambda key, column: (key == threshold)
+                     & (column < with_bit)) <= room
         return jnp.where(fits, with_bit, edge)
 
     edge = jax.lax.fori_loop(0, width, column_bit,
                              jnp.zeros((rows, 1), jnp.int32))
-    chosen = (scores > -jnp.inf) & (above | (ties & (column < edge)))
-    m_ref[...] = chosen.astype(jnp.int8)
-    n_ref[...] = jnp.broadcast_to(_count(chosen).astype(jnp.int32),
+
+    def chosen(key, column):
+        return (key > _UNSEEN) & ((key > threshold) | (
+            (key == threshold) & (column < edge)))
+
+    def mark(c, _):
+        column, here = chunk_of(c)
+        m_ref[here] = chosen(key_ref[here], column).astype(jnp.int8)
+
+    def clear(c, _):
+        m_ref[chunk_of(c)[1]] = jnp.zeros((rows, chunk), jnp.int8)
+
+    jax.lax.fori_loop(0, walked, mark, None)
+    jax.lax.fori_loop(walked, columns // chunk, clear, None)
+    n_ref[...] = jnp.broadcast_to(count(chosen).astype(jnp.int32),
                                   n_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("topk", "interpret"))
-def _select_pallas(scores, topk: int, interpret: bool = False):
+def _select_pallas(scores, topk: int, end=None, interpret: bool = False):
     rows, sq, skv = scores.shape
     block_q = _SELECT_ROWS
-    sq_pad, skv_pad = _round_up(sq, block_q), _round_up(skv, _LANES)
+    chunk = min(_SELECT_COLUMNS, _round_up(skv, _LANES))
+    sq_pad, skv_pad = _round_up(sq, block_q), _round_up(skv, chunk)
     # a padded column is one no query sees; a padded query's row is cut
     scores = jnp.pad(scores, ((0, 0), (0, sq_pad - sq), (0, skv_pad - skv)),
                      constant_values=-jnp.inf)
-    vmem = block_q * skv_pad * (2 * 4 + 2 * 1 + 6 * 4)
+    end = jnp.clip(jnp.asarray(skv if end is None else end, jnp.int32),
+                   0, skv).reshape(1)
+    vmem = block_q * skv_pad * (2 * 4 + 2 * 1 + 4) + 12 * block_q * chunk * 4
     mask, count = pl.pallas_call(
-        functools.partial(_select_kernel, topk=topk),
-        grid=(rows, sq_pad // block_q),
-        in_specs=[pl.BlockSpec((None, block_q, skv_pad),
-                               lambda r, i: (r, i, 0))],
-        out_specs=[pl.BlockSpec((None, block_q, skv_pad),
-                                lambda r, i: (r, i, 0)),
-                   pl.BlockSpec((None, block_q, _LANES),
-                                lambda r, i: (r, i, 0))],
+        functools.partial(_select_kernel, topk=topk, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows, sq_pad // block_q),
+            in_specs=[pl.BlockSpec((None, block_q, skv_pad),
+                                   lambda r, i, end: (r, i, 0))],
+            out_specs=[pl.BlockSpec((None, block_q, skv_pad),
+                                    lambda r, i, end: (r, i, 0)),
+                       pl.BlockSpec((None, block_q, _LANES),
+                                    lambda r, i, end: (r, i, 0))],
+            scratch_shapes=[pltpu.VMEM((block_q, skv_pad), jnp.int32)]),
         out_shape=[jax.ShapeDtypeStruct((rows, sq_pad, skv_pad), jnp.int8),
                    jax.ShapeDtypeStruct((rows, sq_pad, _LANES), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
@@ -307,23 +381,28 @@ def _select_pallas(scores, topk: int, interpret: bool = False):
             vmem_limit_bytes=vmem + _VMEM_SLACK),
         name="index_select",
         interpret=interpret,
-    )(scores)
+    )(end, scores)
     return mask[:, :sq, :skv], count[:, :sq, 0]
 
 
 @functools.partial(jax.named_call, name="index_select")
-def index_select(scores, topk: int, form: str = "mask", *,
+def index_select(scores, topk: int, form: str = "mask", end=None, *,
                  interpret: bool = False):
     """The `topk` largest visible of `scores` [R, Sq, Skv] (`-inf`: not
     visible) a query, ties to the lower position; `form` `mask`: (int8
     [R, Sq, Skv], the selected a query [R, Sq]); `indices`: (columns [R,
-    Sq, k], which of them are visible [R, Sq, k])."""
+    Sq, k], which of them are visible [R, Sq, k]). `end` (a number or a
+    traced scalar; None: `Skv`): the columns at or past it do not exist
+    yet, so what the scores hold there is not read and the mask is 0."""
     assert form in ("mask", "indices"), form
     if interpret or platform.trace_platform() == "tpu":
         platform.KERNEL_TRACES.inc(op="index_select", path="pallas")
-        mask, count = _select_pallas(scores, topk, interpret=interpret)
+        mask, count = _select_pallas(scores, topk, end, interpret=interpret)
     else:
         platform.KERNEL_TRACES.inc(op="index_select", path="reference")
+        if end is not None:
+            scores = jnp.where(jnp.arange(scores.shape[-1]) < end, scores,
+                               -jnp.inf)
         mask, count = select_reference(scores, topk)
     if form == "indices":
         return columns_of(mask, min(topk, scores.shape[-1]))

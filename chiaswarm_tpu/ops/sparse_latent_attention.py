@@ -2,8 +2,18 @@
 (models/glm_moe_dsa.py; ops/lightning_indexer.py makes the selection): the
 softmax runs over a query's selected positions alone.
 
+**The expansion** (`expand_latents`): every head's keys and values of the
+cached positions BELOW `end`, the span's end, from the latents: `k =
+latents @ key_up`, `v = latents[..., :C] @ value_up`, both `[R, S, H * D]`,
+the heads as columns. A Pallas matmul whose row-block axis is a grid bound
+that is data (`cdiv(end, block)`): a row block at or past the span's end is
+no grid step, and its rows of the two results are left as the buffers were
+handed out (a `pallas_call`'s result is not zeroed), so a row's first span
+expands an eighth of what its last one does and nothing is initialised
+twice. Nothing may read those rows: the attention kernel below does not.
+
 **Prefill** (`sparse_prefill_attention`): a span's queries `q` [R, Sq, H *
-D] against keys and values EXPANDED from the cached latents (`k`, `v` [R,
+D] against those keys and values (`k`, `v` [R,
 Skv, H * D], the heads as columns, which is how one matmul from the
 latents leaves them: D 256 here, where `ops.attention` sends heads over 128 to XLA's
 path and `ops.banded_attention` builds its mask from the grid's indices),
@@ -16,9 +26,14 @@ array reaches HBM. Query `i` stands at position `offset + i` (data, a
 scalar the kernel is handed before its grid runs, so one compiled kernel
 serves every span of a row; left out, the queries are the last `Sq`
 positions of the keys), so a key block wholly in a query block's future
-holds no selected key and is neither fetched nor computed. It computes every visible pair under the
-mask and does not gather the selected keys: expanded, a visible pair costs
-`4 * D` operations a head where a query's gathered 2048 latents would cost
+holds no selected key and is neither fetched nor computed, and a key block
+at or past the span's end (`offset + Sq`) is no grid step at all: the key
+axis of the grid is `cdiv(offset + Sq, block)`, data too, and in the one
+block the span's end may cut the columns past it count as not selected and
+their values as zeros, so what keys, values and mask hold at or past the
+span's end is worth nothing to the result. It computes every visible pair
+under the mask and does not gather the selected keys: expanded, a visible
+pair costs `4 * D` operations a head where a query's gathered 2048 latents would cost
 a DMA a row and matmuls of 64 rows each (PERF.md section 7 has the count).
 Every query has a selected key (its own position while it sees `topk` at
 most, `topk` of them after).
@@ -31,7 +46,7 @@ latents in two batched matmuls. Returns the context and the rows it read.
 
 `swarm_kernel_traces_total{op="sparse_latent_attention"}`: `pallas` (the
 prefill kernel), `gathered` (the decode), `reference` (plain `jax.numpy`,
-off the chip).
+off the chip); `{op="latent_expansion"}`: `pallas` or `reference`.
 """
 
 from __future__ import annotations
@@ -53,13 +68,101 @@ from .flash_attention import (
     _pad_to,
     _round_up,
 )
-from .lightning_indexer import _last_block
+from .lightning_indexer import _last_block, _walked
 
 # v5e, a 4096-query span of 64 heads of 256 against 32768 keys, kernel
 # alone (my chip run, PR 49): 55.7 ms at 512 x 512, 52.1 at 1024 x 512,
 # 52.9 at 512 x 1024, 50.8 at 1024 x 1024 (173 TFLOP/s of the chip's 197)
 _BLOCK_Q = 1024
 _BLOCK_K = 1024
+
+
+# rows and columns a step of the expansion (v5e, [32768, 576] -> two
+# [32768, 16384]: section 6 of PERF.md, PR 50, has the timings)
+_EXPAND_ROWS = 512
+_EXPAND_COLUMNS = 2048
+
+
+def expand_reference(latents, key_up, value_up, end=None):
+    """Plain `jax.numpy`: the whole width, the rows at or past `end` from
+    zeros (whatever the latents hold there is not read)."""
+    if end is not None:
+        latents = jnp.where(
+            (jnp.arange(latents.shape[1]) < end)[None, :, None], latents, 0)
+    return tuple(
+        jnp.dot(x, up, preferred_element_type=jnp.float32).astype(x.dtype)
+        for x, up in ((latents, key_up),
+                      (latents[..., :value_up.shape[0]], value_up)))
+
+
+def _expand_kernel(x_ref, key_up_ref, value_up_ref, k_ref, v_ref):
+    """One (row, block of columns, block of positions) step: x_ref [BM,
+    C + P] latents, key_up_ref [C + P, BN], value_up_ref [C, BN]; k_ref /
+    v_ref [BM, BN]."""
+    x = x_ref[...]
+    for up_ref, o_ref in ((key_up_ref, k_ref), (value_up_ref, v_ref)):
+        o_ref[...] = jnp.dot(
+            x[:, :up_ref.shape[0]], up_ref[...],
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _expand_pallas(latents, key_up, value_up, end=None,
+                   interpret: bool = False):
+    rows, positions, width = latents.shape
+    columns = key_up.shape[1]
+    assert key_up.shape[0] == width and value_up.shape[1] == columns and (
+        value_up.shape[0] <= width), (latents.shape, key_up.shape,
+                                      value_up.shape)
+    block_m = min(_EXPAND_ROWS, _round_up(positions, 8))
+    block_n = min(_EXPAND_COLUMNS, columns)
+    assert columns % block_n == 0 and (
+        interpret or block_n % _LANES == 0), (columns, block_n)
+    itemsize = jnp.dtype(latents.dtype).itemsize
+    vmem = (2 * block_m * width * itemsize
+            + 2 * (width + value_up.shape[0]) * block_n * itemsize
+            + 2 * 2 * block_m * block_n * itemsize
+            + 2 * block_m * block_n * 4)
+    out_spec = pl.BlockSpec((None, block_m, block_n),
+                            lambda r, c, i: (r, i, c))
+    return pl.pallas_call(
+        _expand_kernel,
+        # positions innermost, up to `end` and no further: a block of the
+        # two matrices is fetched once a row, the latents (a thirtieth of
+        # what is written) once a block of columns
+        grid=(rows, columns // block_n,
+              _walked(end, block_m, pl.cdiv(positions, block_m))),
+        in_specs=[
+            pl.BlockSpec((None, block_m, width), lambda r, c, i: (r, i, 0)),
+            pl.BlockSpec((width, block_n), lambda r, c, i: (0, c)),
+            pl.BlockSpec((value_up.shape[0], block_n),
+                         lambda r, c, i: (0, c))],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((rows, positions, columns),
+                                        latents.dtype)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem + _VMEM_SLACK),
+        name="latent_expansion",
+        interpret=interpret,
+    )(latents, key_up, value_up)
+
+
+@functools.partial(jax.named_call, name="latent_expansion")
+def expand_latents(latents, key_up, value_up, end=None, *,
+                   interpret: bool = False):
+    """`latents` [R, S, C + P] (`c_kv | k_rope` a position), `key_up` [C +
+    P, H * D], `value_up` [C, H * D] -> (keys, values), [R, S, H * D] each:
+    `latents @ key_up` and `latents[..., :C] @ value_up` for the positions
+    below `end` (a number or a traced scalar; None: all of them). What the
+    two hold at or past `end` is not defined (on the chip those rows are
+    never written) and what the latents hold there is not read."""
+    if interpret or platform.trace_platform() == "tpu":
+        platform.KERNEL_TRACES.inc(op="latent_expansion", path="pallas")
+        return _expand_pallas(latents, key_up, value_up, end,
+                              interpret=interpret)
+    platform.KERNEL_TRACES.inc(op="latent_expansion", path="reference")
+    return expand_reference(latents, key_up, value_up, end)
 
 
 def prefill_reference(q, k, v, mask, scale: float, heads: int):
@@ -75,14 +178,18 @@ def prefill_reference(q, k, v, mask, scale: float, heads: int):
 
 
 def _prefill_kernel(offset_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref,
-                    l_ref, acc_ref, *, block_k: int, scale: float,
+                    l_ref, acc_ref, *, span: int, block_k: int, scale: float,
                     fold_scale: bool):
     """One (row, head, query block, key block) step: offset_ref [1] the
-    first query's position; q_ref / o_ref [BQ, D], k_ref / v_ref [BK, D],
-    mask_ref [BQ, BK] int8; the state [BQ, .] float32."""
+    first of the `span` queries' positions; q_ref / o_ref [BQ, D], k_ref /
+    v_ref [BK, D], mask_ref [BQ, BK] int8; the state [BQ, .] float32."""
     block_q, head_dim = q_ref.shape
     i, j = pl.program_id(2), pl.program_id(3)
     offset = offset_ref[0]
+    # the block's keys below the span's end: what lies past it (a later
+    # span writes it) may hold anything and counts for nothing
+    live = offset + span - j * block_k
+    seen = j <= _last_block(i, offset, block_q, block_k)
 
     @pl.when(j == 0)
     def _():
@@ -90,9 +197,14 @@ def _prefill_kernel(offset_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j <= _last_block(i, offset, block_q, block_k))
-    def _():
+    def step(whole: bool):
         q, v = q_ref[...], v_ref[...]
+        chosen = mask_ref[...].astype(jnp.float32) > 0.0
+        if not whole:
+            chosen &= jax.lax.broadcasted_iota(
+                jnp.int32, chosen.shape, 1) < live
+            v = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0) < live, v, jnp.zeros_like(v))
         if fold_scale:
             q = q * scale  # a power of two: exact
         s = jax.lax.dot_general(
@@ -100,7 +212,7 @@ def _prefill_kernel(offset_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref,
             preferred_element_type=jnp.float32)
         if not fold_scale:
             s = s * scale
-        s = jnp.where(mask_ref[...].astype(jnp.float32) > 0.0, s, _NEG_INF)
+        s = jnp.where(chosen, s, _NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_next = jnp.maximum(m_prev, jnp.broadcast_to(
             jnp.max(s, axis=-1, keepdims=True), (block_q, _LANES)))
@@ -116,6 +228,11 @@ def _prefill_kernel(offset_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref,
                 p.astype(v.dtype), v,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
+
+    # the block the span's end cuts (none where the span is whole blocks)
+    # is the one that pays for a second mask
+    pl.when(seen & (live >= block_k))(functools.partial(step, True))
+    pl.when(seen & (live < block_k))(functools.partial(step, False))
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -136,6 +253,8 @@ def _prefill_pallas(q, k, v, mask, scale: float, heads: int, offset=None,
     block_k = min(_BLOCK_K, _round_up(skv, _LANES))
     sq_pad, skv_pad = _round_up(sq, block_q), _round_up(skv, block_k)
     n_k = skv_pad // block_k
+    # the key blocks the grid walks: up to the span's end and no further
+    walked = _walked(None if offset is None else offset + sq, block_k, n_k)
     offset = jnp.asarray(skv - sq if offset is None else offset,
                          jnp.int32).reshape(1)
     # padding is masked out: a padded key is selected by nobody, a padded
@@ -158,11 +277,11 @@ def _prefill_pallas(q, k, v, mask, scale: float, heads: int, offset=None,
             + block_q * (2 * _LANES + dim) * 4)
     out = pl.pallas_call(
         functools.partial(
-            _prefill_kernel, block_k=block_k, scale=scale,
+            _prefill_kernel, span=sq, block_k=block_k, scale=scale,
             fold_scale=math.frexp(scale)[0] == 0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(rows, heads, sq_pad // block_q, n_k),
+            grid=(rows, heads, sq_pad // block_q, walked),
             in_specs=[q_spec, kv_spec, kv_spec,
                       pl.BlockSpec((None, block_q, block_k),
                                    lambda r, h, i, j, at: (

@@ -85,7 +85,11 @@ the positions the real queries saw and those attention read for them are
 summed on the device beside the routing's tally and come back with the ids
 (`swarm_sparse_visible_positions_total{model, phase}`,
 `swarm_sparse_selected_positions_total{model, phase}`, `phase` `prefill` |
-`decode`; the envelope's `selection`). A pass counts its prompt slots
+`decode`; the envelope's `selection`), and beside them the key positions
+the prefill's spans went over, each bounded by its own end, against spans x
+the bucket's width (`swarm_prefill_key_extent_total{model, extent}`,
+`extent` `walked` | `bucket`; the envelope's `selection` has them as
+`prefill_key_extent`). A pass counts its prompt slots
 (`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
 that was computed all the same, and the slots of the bucket `skipped`:
 what lies past a chunk's width, and chunks the model's prefill did not
@@ -192,6 +196,14 @@ SPARSE_SELECTED = telemetry.counter(
     "Of swarm_sparse_visible_positions_total, the positions the selection "
     "picked and attention read (min(index_topk, visible) a query), by "
     "model and phase", ("model", "phase"))
+PREFILL_KEY_EXTENT = telemetry.counter(
+    "swarm_prefill_key_extent_total",
+    "Key positions the prefill spans of a family that selects keys went "
+    "over, summed over rows and layers on the device, by model and extent "
+    "(walked: up to the span's end, what the key side of a span is bounded "
+    "by; bucket: the prompt slots' whole width a span): walked / bucket is "
+    "36 / 64 for a row of eight spans, and 1 says no span was bounded",
+    ("model", "extent"))
 BLOCK_FORWARD_ROWS = telemetry.counter(
     "swarm_block_forward_rows_total",
     "Real rows x forwards of a block decode, by model and kind (denoise: "
@@ -639,17 +651,22 @@ class TextGenerationPipeline:
         selection = {}
         if self.selects:
             # (visible, selected) of the pass and of its prefill: decode's
-            # tally began where prefill's ended
-            whole, first = (self.model.selection_counts(leaf)
-                            for leaf in (chose[0], chose_before[0]))
+            # tally began where prefill's ended; behind them the key
+            # positions the prefill's spans walked, and the bucket's
+            (*whole, _, _), (*first, walked, bucket) = (
+                self.model.selection_counts(leaf)
+                for leaf in (chose[0], chose_before[0]))
             by_phase = [{"prefill": before, "decode": total - before}
                         for total, before in zip(whole, first)]
             for counter, phases in zip((SPARSE_VISIBLE, SPARSE_SELECTED),
                                        by_phase):
                 for phase, count in phases.items():
                     counter.inc(count, phase=phase, **label)
-            selection = {"selection": dict(zip(("visible", "selected"),
-                                               by_phase))}
+            PREFILL_KEY_EXTENT.inc(walked, extent="walked", **label)
+            PREFILL_KEY_EXTENT.inc(bucket, extent="bucket", **label)
+            selection = {"selection": {
+                **dict(zip(("visible", "selected"), by_phase)),
+                "prefill_key_extent": {"walked": walked, "bucket": bucket}}}
         blocks = {}
         if self.by_blocks:
             BLOCK_FORWARD_ROWS.inc(real * denoise, kind="denoise", **label)

@@ -14,7 +14,13 @@ plain reference (benchmark/reference/dsa_mla_moe.py):
     `index_topk` and on a row shorter than its span;
 (d) the 16 shares of one sparse layer add up to the uncut layer;
 (e) the selection's tally: what the queries saw and what attention read,
-    by phase, past 32 bits.
+    by phase, past 32 bits;
+(f) a span's key side bounded by the span's end (ISSUE 50): each of the
+    four operations against its plain reference on the columns below the
+    end, at a first, a middle and the last span of a bucket of several
+    blocks; what the caches hold past the end changes nothing; called
+    without a bound the indexer and the selection give whole results; and
+    the key positions walked of the bucket's, through the pipeline.
 """
 
 import dataclasses
@@ -102,13 +108,20 @@ def test_spans_and_cached_decode_give_the_references_logits(
             params, SIZES, ids, held=CFG.experts_held, positions=at))
         assert _rel(got[row], want) < LIMIT
     # the selection's tally: every real query, every layer
-    visible, selected = glm_moe_dsa.selection_counts(np.asarray(load[2]))
+    visible, selected, *extent = glm_moe_dsa.selection_counts(
+        np.asarray(load[2]))
     before = glm_moe_dsa.selection_counts(np.asarray(filled[2]))
     layers, topk = CFG.num_hidden_layers, CFG.index_topk
     seen = [np.arange(1, length + 1) for length in lengths]
-    assert before == (
+    assert before[:2] == (
         layers * sum(int(s.sum()) for s in seen),
         layers * sum(int(np.minimum(s, topk).sum()) for s in seen))
+    # the key positions the prefill's spans walked, of spans x the bucket
+    # (spans of 8 in 16 slots: a row over 8 ids runs both, 8 + 16 of 2 x
+    # 16; a shorter one the first alone); a decode step adds none
+    ends = [[8, 16] if length > 8 else [8] for length in lengths]
+    assert list(before[2:]) == extent == [
+        layers * sum(map(sum, ends)), layers * 16 * sum(map(len, ends))]
     steps = [length + 1 + np.arange(new) for length in lengths]
     assert (visible - before[0], selected - before[1]) == (
         layers * sum(int(s.sum()) for s in steps),
@@ -225,6 +238,162 @@ def test_masked_attention_gives_the_references_output(interpret):
         assert float(jnp.max(jnp.abs(got[row] - dense))) > 0.05
 
 
+# a bucket of four spans of 96 positions over blocks of 128 (the kernels'
+# own blocks shrunk: 1024 keys, 2048 columns and 512 rows a step on the
+# chip): the first span's end cuts block 0 and walks one of three, the
+# second's cuts block 1, the last's falls on the bucket's
+BUCKET, SPAN = 384, 96
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """The four kernels at blocks of 128, traced anew (a jitted function
+    keeps what it traced under the module's constants of the time)."""
+    kernels = (indexer._indexer_pallas, indexer._select_pallas,
+               attention._expand_pallas, attention._prefill_pallas)
+    monkeypatch.setattr(indexer, "_BLOCK_K", 128)
+    monkeypatch.setattr(indexer, "_SELECT_COLUMNS", 128)
+    monkeypatch.setattr(attention, "_BLOCK_K", 128)
+    monkeypatch.setattr(attention, "_EXPAND_ROWS", 128)
+    for kernel in kernels:
+        kernel.clear_cache()
+    yield
+    for kernel in kernels:
+        kernel.clear_cache()
+
+
+@pytest.mark.parametrize("number", [0, 1, 3], ids=["first", "middle", "last"])
+def test_a_span_bounded_by_its_end_gives_the_references(small_blocks,
+                                                        number):
+    """Interpreted, where a result's blocks no grid step wrote hold NaN
+    (int8: -128) and an operation that read one would show it: the scores
+    past the walked key blocks, the expanded rows past the walked row
+    blocks."""
+    heads, dim, latent, rope, topk = 2, 32, 48, 16, 24
+    start, end = number * SPAN, (number + 1) * SPAN
+    q_i, w_i, k_i = _operands(SPAN, BUCKET, seed=7)
+    scores = indexer.lightning_indexer(q_i, w_i, k_i, offset=start, end=end,
+                                       interpret=True)
+    walked = -(-end // 128) * 128
+    assert np.isnan(np.asarray(scores[..., walked:])).all()
+    mask, count = indexer.index_select(scores, topk, end=end, interpret=True)
+    keys = jax.random.split(jax.random.key(8), 4)
+    latents = jax.random.normal(keys[0], (2, BUCKET, latent + rope))
+    key_up = jax.random.normal(keys[1], (latent + rope, heads * dim)) / 8
+    value_up = jax.random.normal(keys[2], (latent, heads * dim)) / 8
+    k, v = attention.expand_latents(latents, key_up, value_up, end,
+                                    interpret=True)
+    assert np.isnan(np.asarray(k[:, walked:])).all()
+    q = jax.random.normal(keys[3], (2, SPAN, heads * dim))
+    got = attention.sparse_prefill_attention(
+        q, k, v, mask, dim ** -0.5, heads, offset=start, interpret=True)
+    for row in range(2):
+        want = dsa_kernels.index_scores(q_i[row], w_i[row], k_i[row, :end])
+        seen = np.isfinite(np.asarray(want))
+        mine = np.asarray(scores[row, :, :end])
+        assert ((mine > -np.inf) == seen).all()
+        np.testing.assert_allclose(mine[seen], np.asarray(want)[seen],
+                                   atol=2e-5)
+        chosen = np.asarray(dsa_kernels.selection(scores[row, :, :end], topk))
+        assert (np.asarray(mask[row, :, :end] != 0) == chosen).all()
+        assert not np.asarray(mask[row, :, end:]).any()
+        assert (np.asarray(count[row]) == chosen.sum(-1)).all()
+        np.testing.assert_allclose(
+            np.asarray(k[row, :end]), np.asarray(latents[row, :end] @ key_up),
+            atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(v[row, :end]),
+            np.asarray(latents[row, :end, :latent] @ value_up), atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(got[row]), np.asarray(dsa_kernels.masked_attention(
+                q[row], latents[row, :end] @ key_up,
+                latents[row, :end, :latent] @ value_up, chosen, dim ** -0.5,
+                heads)), atol=2e-5)
+
+
+@PATHS
+@pytest.mark.parametrize("number", [0, 1], ids=["first", "middle"])
+def test_what_lies_past_a_spans_end_is_not_read(small_blocks, params,
+                                                number, interpret):
+    """NaN in both caches' columns past the span's end (a later span will
+    write there) leaves the output, the columns the span wrote and the
+    tally's part bit for bit what they are over zeros."""
+    layer = params["layers"][1]["attn"]
+    start, end = number * SPAN, (number + 1) * SPAN
+    keys = jax.random.split(jax.random.key(9), 3)
+    h = jax.random.normal(keys[0], (2, SPAN, CFG.hidden_size))
+    past = (jnp.arange(BUCKET) >= end)[None, :, None]
+    before = (jnp.arange(BUCKET) < start)[None, :, None]
+    cache = tuple(
+        jnp.where(before, jax.random.normal(key, (2, BUCKET, width)), 0.0)
+        for key, width in zip(keys[1:], (CFG.cache_width,
+                                         CFG.index_head_dim)))
+    real = jnp.arange(SPAN)[None, :] < jnp.array([[SPAN], [SPAN - 5]])
+    run = jax.jit(lambda cache: glm_moe_dsa.attention_prefill(
+        layer, CFG, h, jnp.int32(start), cache, real, interpret))
+    out, written, seen = run(cache)
+    again, poisoned, seen_again = run(
+        tuple(jnp.where(past, jnp.nan, whole) for whole in cache))
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(out))
+    np.testing.assert_array_equal(np.asarray(seen_again), np.asarray(seen))
+    for mine, clean in zip(poisoned, written):
+        np.testing.assert_array_equal(np.asarray(mine[:, :end]),
+                                      np.asarray(clean[:, :end]))
+    assert np.asarray(seen)[2:].tolist() == [2 * end, 2 * BUCKET]
+
+
+def test_without_a_bound_the_indexer_and_the_selection_are_whole(
+        small_blocks):
+    """As the benchmark's operation-level comparison calls them (no
+    offset, no end): `-inf` wherever a query does not see, a whole mask,
+    bit for bit `jax.lax.top_k`'s, over every block."""
+    q, w, k = _operands(SPAN, BUCKET, seed=11)
+    scores = indexer.lightning_indexer(q, w, k, interpret=True)
+    want = indexer.indexer_reference(q, w, k)
+    assert not np.isnan(np.asarray(scores)).any()
+    assert (np.isfinite(np.asarray(scores))
+            == np.isfinite(np.asarray(want))).all()
+    mask, count = indexer.index_select(scores, 24, interpret=True)
+    plain, plain_count = indexer.select_reference(scores, 24)
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(plain))
+    np.testing.assert_array_equal(np.asarray(count), np.asarray(plain_count))
+    columns, chosen = indexer.index_select(scores[:, -1:], 24, "indices",
+                                           interpret=True)
+    assert (np.asarray(columns[:, 0])[np.asarray(chosen[:, 0])]
+            == np.nonzero(np.asarray(mask[:, -1]))[1]).all()
+
+
+@pytest.mark.parametrize("length, tokens, share", [
+    (60, 8, (36, 64)), (7, 4096, (1, 1))], ids=["eight_spans", "one_span"])
+def test_the_pipeline_reads_back_the_key_positions_walked(
+        monkeypatch, length, tokens, share):
+    """A row of eight spans walks 1 + 2 + ... + 8 = 36 span widths of its
+    8 x 8, a row of one span its bucket: the envelope and the counter, a
+    layer each."""
+    from chiaswarm_tpu.pipelines import text_generation
+
+    monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", tokens)
+    pipe = text_generation.TextGenerationPipeline(
+        "test/tiny-glm-5", allow_random_init=True)
+    label = {"model": "test/tiny-glm-5"}
+    counter = text_generation.PREFILL_KEY_EXTENT
+    before = [counter.value(extent=extent, **label)
+              for extent in ("walked", "bucket")]
+    rows = [np.random.default_rng(5).integers(0, 128, length).tolist()]
+    ((_, config),) = pipe.run_batched(
+        [{"prompt_ids": rows, "rng": jax.random.key(1)}], max_new_tokens=2)
+    extent = config["selection"]["prefill_key_extent"]
+    spans = config["prefill_chunks"]
+    assert spans == (8 if length > tokens else 1)
+    slots, layers = config["prompt_slots"], CFG.num_hidden_layers
+    assert (extent["walked"] * share[1], extent["bucket"]) == (
+        extent["bucket"] * share[0], layers * spans * slots)
+    assert [counter.value(extent=name, **label) - was for name, was in zip(
+        ("walked", "bucket"), before)] == [extent["walked"],
+                                          extent["bucket"]]
+
+
 def test_a_decode_step_reads_the_selected_rows_alone():
     rows, positions, heads, latent, rope, topk = 3, 50, 4, 16, 8, 8
     keys = jax.random.split(jax.random.key(6), 4)
@@ -276,14 +445,14 @@ def test_the_sixteen_shares_of_a_sparse_layer_add_up_to_the_uncut_layer(
 def test_the_selections_tally_counts_past_32_bits():
     load = glm_moe_dsa.empty_load(CFG)
     assert [leaf.shape for leaf in load] == [
-        (CFG.expert_layers, CFG.experts_held[1]), (4,), (2, 2)]
+        (CFG.expert_layers, CFG.experts_held[1]), (4,), (4, 2)]
     add = jax.jit(lambda load, seen: glm_moe_dsa.tally(
         load, 0, CFG, None, seen))
-    seen = jnp.array([2 ** 30 - 1, 2 ** 29 + 7], jnp.int32)
+    seen = jnp.array([2 ** 30 - 1, 2 ** 29 + 7, 36, 64], jnp.int32)
     for _ in range(9):
         load = add(load, seen)
     assert glm_moe_dsa.selection_counts(np.asarray(load[2])) == (
-        9 * (2 ** 30 - 1), 9 * (2 ** 29 + 7))
+        9 * (2 ** 30 - 1), 9 * (2 ** 29 + 7), 9 * 36, 9 * 64)
     # both caches are counted, and the index keys' part is said apart
     whole = glm_moe_dsa.GLM5_EP16
     assert glm_moe_dsa.cache_bytes(whole, 2, 32896, 2) == (

@@ -291,7 +291,7 @@ def test_the_samplers_kernels_compile_for_v5e(v5e, positions, vocab):
     pytest.param(4096, 32768, id="glm5-last-span"),
     pytest.param(4096, 4096, id="glm5-first-span"),
 ])
-def test_the_key_selections_three_kernels_compile_for_v5e(v5e, sq, skv):
+def test_the_key_selections_kernels_compile_for_v5e(v5e, sq, skv):
     """`glm5-long-context`'s prefill span (ISSUE 49): 32 index heads of
     128 on one shared key, the radix select of 2048 over whole rows of
     float32 scores in VMEM with an int8 mask out, and the flash kernel at
@@ -299,26 +299,38 @@ def test_the_key_selections_three_kernels_compile_for_v5e(v5e, sq, skv):
     the blocks its own rule gives, the span's first position a scalar
     handed to the kernel (one compiled kernel a row's every span); no
     operand is copied on its way in (keys and values come as `[rows, keys,
-    heads x 256]`, the layout one matmul from the latents leaves them
-    in)."""
+    heads x 256]`, the layout the expansion from the latents leaves them
+    in). Since ISSUE 50 the span's END is traced too and bounds a loop of
+    each: the key-block axis of the indexer's and the attention's grids
+    and the row-block axis of the expansion's are grid bounds that are
+    data, the selection's counts a loop inside the kernel up to it."""
     from chiaswarm_tpu.ops.lightning_indexer import (
         _indexer_pallas,
         _select_pallas,
     )
-    from chiaswarm_tpu.ops.sparse_latent_attention import _prefill_pallas
+    from chiaswarm_tpu.ops.sparse_latent_attention import (
+        _expand_pallas,
+        _prefill_pallas,
+    )
 
+    traced = _shape(v5e, (), jnp.int32)
     compiled = _indexer_pallas.lower(
         _shape(v5e, (1, sq, 32, 128)), _shape(v5e, (1, sq, 32), jnp.float32),
-        _shape(v5e, (1, skv, 128)), _shape(v5e, (), jnp.int32)).compile()
+        _shape(v5e, (1, skv, 128)), traced, traced).compile()
     assert "lightning_indexer" in compiled.as_text()
     compiled = _select_pallas.lower(
-        _shape(v5e, (1, sq, skv), jnp.float32), topk=2048).compile()
+        _shape(v5e, (1, sq, skv), jnp.float32), topk=2048,
+        end=traced).compile()
     assert "index_select" in compiled.as_text()
+    compiled = _expand_pallas.lower(
+        _shape(v5e, (1, skv, 576)), _shape(v5e, (576, 64 * 256)),
+        _shape(v5e, (512, 64 * 256)), traced).compile()
+    assert "latent_expansion" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
     compiled = _prefill_pallas.lower(
         _shape(v5e, (1, sq, 64 * 256)), _shape(v5e, (1, skv, 64 * 256)),
         _shape(v5e, (1, skv, 64 * 256)), _shape(v5e, (1, sq, skv), jnp.int8),
-        scale=256 ** -0.5, heads=64,
-        offset=_shape(v5e, (), jnp.int32)).compile()
+        scale=256 ** -0.5, heads=64, offset=traced).compile()
     assert "sparse_latent_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 

@@ -822,11 +822,16 @@ def test_selected_key_jobs_go_through_hive_worker_and_pipeline(sdaas_root,
 
     for config, rows in ((configs[0], lengths), (configs[3], lengths[:1])):
         visible, selected = counts(rows)
-        assert config["selection"] == {"visible": visible,
-                                       "selected": selected}
+        # spans of 16: every span of a real row that holds an id ran, its
+        # key side up to its own end (ISSUE 50) of the 64 slots
+        ran = [-(-n // 16) for n in rows]
+        spans = sum(ran)
+        assert config["selection"] == {
+            "visible": visible, "selected": selected,
+            "prefill_key_extent": {
+                "walked": layers * sum(16 * n * (n + 1) // 2 for n in ran),
+                "bucket": layers * spans * 64}}
         assert 2 * selected["prefill"] < visible["prefill"]
-        # spans of 16: every span of a real row that holds an id ran
-        spans = sum(-(-n // 16) for n in rows)
         assert config["prefill_chunk_widths"] == {"16": spans}
         assert config["routing"]["prefill"]["calls"] == spans * 2
         # two caches a layer: latents of 24 and index keys of 16, float32
