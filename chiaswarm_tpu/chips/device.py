@@ -10,6 +10,7 @@ chip is fixed by the platform — so capability is advertised rather than gated.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import random
 import threading
@@ -146,6 +147,19 @@ def hbm_census() -> list[dict]:
         }
         out.append(row)
     return out
+
+
+@contextlib.contextmanager
+def _pass_span():
+    """Span "pass": the slice held, parent of every span the callback
+    stamps on this thread. The process's first one is also the two
+    startup marks `first_pass_start` / `first_pass_end`."""
+    telemetry.mark_startup("first_pass_start")
+    try:
+        with telemetry.Span("pass") as held:
+            yield held
+    finally:
+        telemetry.mark_startup("first_pass_end")
 
 
 class ChipSet:
@@ -349,9 +363,7 @@ class ChipSet:
             kwargs["rng"] = jax.random.key(seed)
             kwargs["chipset"] = self
 
-            # span "pass": the slice held, parent of every span the
-            # callback stamps on this thread
-            with telemetry.Span("pass") as held:
+            with _pass_span() as held:
                 artifacts, pipeline_config = func(
                     self.identifier(), model_name, **kwargs)
             _EXECUTE_SECONDS.observe(held.elapsed, kind="solo")
@@ -394,7 +406,7 @@ class ChipSet:
                 kw["rng"] = jax.random.key(seed)
                 kw["chipset"] = self
 
-            with telemetry.Span("pass") as held:
+            with _pass_span() as held:
                 results = func(self.identifier(), requests)
             if len(results) != len(requests):
                 raise RuntimeError(

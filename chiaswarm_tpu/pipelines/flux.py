@@ -408,7 +408,7 @@ class FluxPipeline:
         transformer = self.transformer
         latent_c = self.latent_channels
 
-        def run(params, init_rng, context, pooled, guidance):
+        def flux_denoise_decode(params, init_rng, context, pooled, guidance):
             latents = jax.random.normal(
                 init_rng, (batch, lh, lw, latent_c), jnp.float32
             )
@@ -437,7 +437,7 @@ class FluxPipeline:
             latents = unpatchify(img, lh, lw).astype(self.dtype)
             return self._decode_rows(params["vae"], latents)
 
-        program = jax.jit(run)
+        program = jax.jit(flux_denoise_decode)
         with self._jit_lock:
             self._programs[key] = program
             from .common import PROGRAM_EVICTED, program_cache_cap
@@ -694,7 +694,8 @@ class FluxPipeline:
         sigmas = jnp.asarray(scheduler.schedule(steps).sigmas)
         transformer = self.transformer
 
-        def run(params, latents, context, pooled, guidance):
+        def flux_batched_denoise_decode(params, latents, context, pooled,
+                                        guidance):
             img, img_ids = patchify(latents.astype(self.dtype))
             txt_ids = jnp.zeros((batch, txt_len, 3), jnp.int32)
 
@@ -720,7 +721,7 @@ class FluxPipeline:
             latents = unpatchify(img, lh, lw).astype(self.dtype)
             return self._decode_rows(params["vae"], latents)
 
-        program = jax.jit(run)
+        program = jax.jit(flux_batched_denoise_decode)
         with self._jit_lock:
             self._programs[key] = program
             from .common import PROGRAM_EVICTED, program_cache_cap

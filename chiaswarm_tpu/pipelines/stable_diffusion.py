@@ -1585,9 +1585,10 @@ class SDPipeline:
                 key, controlnet_module, mesh=mesh)
             run_steps = make_steps(hi - lo)
 
-            def run(params, init_rng, context, added, guidance_scale,
-                    image_guidance, image_latents, mask, rng, cn_params,
-                    control_cond, cn_scale, lora):
+            def sd_denoise_decode(params, init_rng, context, added,
+                                  guidance_scale, image_guidance,
+                                  image_latents, mask, rng, cn_params,
+                                  control_cond, cn_scale, lora):
                 latents, state = prep(params, init_rng, image_latents)
                 latents, _ = run_steps(
                     params, latents, state, context, added, guidance_scale,
@@ -1595,7 +1596,7 @@ class SDPipeline:
                     control_cond, cn_scale, lora, jnp.int32(lo))
                 return decode(params, latents)
 
-            return run
+            return sd_denoise_decode
 
         return self._program(
             self._sig_key(self._geo_key(key, geo), lora_sig), build,

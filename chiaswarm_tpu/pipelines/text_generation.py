@@ -359,8 +359,12 @@ class TextGenerationPipeline:
                     model.index_cache_bytes(cfg, rows, positions,
                                             self.dtype.itemsize),
                     model=self.model_name)
-            return jax.jit(lambda params, ids, lengths: model.prefill(
-                params, cfg, ids, lengths, positions, *chunk))
+
+            def text_prefill(params, ids, lengths):
+                return model.prefill(
+                    params, cfg, ids, lengths, positions, *chunk)
+
+            return jax.jit(text_prefill)
 
         return self._program(("prefill", rows, slots, positions), build)
 
@@ -373,15 +377,16 @@ class TextGenerationPipeline:
         twice."""
         cfg, model = self.config, self.model
 
-        def step(params, cache, tokens, lengths, number):
+        def text_step(params, cache, tokens, lengths, number):
             logits, cache, _ = model.step(
                 params, cfg, tokens, lengths, number, slots, cache,
                 model.empty_load(cfg), valid=lengths > 0)
             return logits, cache
 
         donate = (1,) if platform.trace_platform() == "tpu" else ()
-        return self._program(("step", rows, slots, positions),
-                             lambda: jax.jit(step, donate_argnums=donate))
+        return self._program(
+            ("step", rows, slots, positions),
+            lambda: jax.jit(text_step, donate_argnums=donate))
 
     def decode_program(self, rows: int, slots: int, new_tokens: int):
         """`(params, cache, logits, lengths, job_keys, job_of_row,
@@ -395,8 +400,8 @@ class TextGenerationPipeline:
             keys = jax.vmap(lambda key: jax.random.fold_in(key, step))(keys)
             return sampling.sample(keys, logits, temperature)[0]
 
-        def decode(params, cache, logits, lengths, job_keys, job_of_row,
-                   row_in_job, temperature, load):
+        def text_decode(params, cache, logits, lengths, job_keys,
+                        job_of_row, row_in_job, temperature, load):
             keys = jax.vmap(jax.random.fold_in)(
                 jax.random.wrap_key_data(job_keys)[job_of_row], row_in_job)
             first = sample(logits, keys, 0, temperature)
@@ -416,7 +421,7 @@ class TextGenerationPipeline:
         donate = (1,) if platform.trace_platform() == "tpu" else ()
         return self._program(
             ("decode", rows, slots, new_tokens),
-            lambda: jax.jit(decode, donate_argnums=donate))
+            lambda: jax.jit(text_decode, donate_argnums=donate))
 
     def block_program(self, rows: int, slots: int, positions: int,
                       commit: bool):
@@ -427,7 +432,7 @@ class TextGenerationPipeline:
         same buffers either way, as the decode programs')."""
         cfg, model = self.config, self.model
 
-        def forward(params, cache, ids, lengths, block):
+        def text_block_forward(params, cache, ids, lengths, block):
             logits, cache, _ = model.block_step(
                 params, cfg, ids, lengths, block, slots, cache,
                 model.empty_load(cfg), valid=lengths > 0, commit=commit)
@@ -436,7 +441,7 @@ class TextGenerationPipeline:
         donate = (1,) if platform.trace_platform() == "tpu" else ()
         return self._program(
             ("block", rows, slots, positions, commit),
-            lambda: jax.jit(forward, donate_argnums=donate))
+            lambda: jax.jit(text_block_forward, donate_argnums=donate))
 
     def block_decode_program(self, rows: int, slots: int, new_tokens: int,
                              denoising_steps: int, thresholded: bool):
@@ -456,8 +461,9 @@ class TextGenerationPipeline:
         blocks = model.blocks_of(cfg, new_tokens)
         count = length // denoising_steps
 
-        def decode(params, cache, ids, lengths, job_keys, job_of_row,
-                   row_in_job, temperature, threshold, load):
+        def text_block_decode(params, cache, ids, lengths, job_keys,
+                              job_of_row, row_in_job, temperature,
+                              threshold, load):
             valid = lengths > 0
             keys = jax.vmap(jax.random.fold_in)(
                 jax.random.wrap_key_data(job_keys)[job_of_row], row_in_job)
@@ -526,7 +532,7 @@ class TextGenerationPipeline:
         return self._program(
             ("block_decode", rows, slots, new_tokens, denoising_steps,
              thresholded),
-            lambda: jax.jit(decode, donate_argnums=donate))
+            lambda: jax.jit(text_block_decode, donate_argnums=donate))
 
     # --- a pass ---
 

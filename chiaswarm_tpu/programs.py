@@ -38,7 +38,7 @@ import threading
 import time
 from collections import OrderedDict
 
-from . import costs, telemetry
+from . import compile_cache, costs, telemetry
 
 # entries kept, live + evicted (popitem LRU below): big enough that a
 # program_cache_max=64 pipeline's full churn history fits, small enough
@@ -66,7 +66,10 @@ class ProgramEntry:
         self.key = repr(key) if key is not None else ""
         self.state = "registered"  # -> live (first call) -> evicted
         self.calls = 0
-        self.compile_s = None  # first call: trace + compile + execute
+        # first call: trace + compile + execute. What parts it is the
+        # table by function (`staging` in snapshot()): the site's row has
+        # its `trace_s`, `lower_s` and `compile_s` (of it `cache_read_s`)
+        self.compile_s = None
         self.analytic_flops = None
         self.xla = None  # {"flops", "bytes_accessed"} from cost_analysis
         self.memory = None  # byte breakdown from memory_analysis
@@ -272,8 +275,10 @@ def instrument(fn, *, model: str, kind: str, key=None,
 
 def snapshot() -> dict:
     """The GET /debug/programs payload: every ledger entry (live ones
-    first, registration order within each state) plus roll-up counts
-    and the per-model worst divergence."""
+    first, registration order within each state) plus roll-up counts,
+    the per-model worst divergence and `staging`, jax's own account of
+    every function it staged, ledger site or not
+    (`compile_cache.staging`)."""
     with _LOCK:
         entries = [e.as_dict() for e in _LEDGER.values()]
     entries.sort(key=lambda e: (e["state"] == "evicted",))
@@ -292,6 +297,7 @@ def snapshot() -> dict:
         "live": live,
         "evicted": len(entries) - live,
         "divergence": divergence,
+        "staging": compile_cache.staging(),
     }
 
 

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import bisect
 import contextvars
+import os
 import sys
 import threading
 import time
@@ -307,6 +308,51 @@ def gauge(name, help="", labelnames=()) -> Gauge:
 
 def histogram(name, help="", labelnames=(), buckets=DEFAULT_BUCKETS) -> Histogram:
     return REGISTRY.histogram(name, help, labelnames, buckets=buckets)
+
+
+# --- startup marks ---------------------------------------------------------
+
+_IMPORTED_WALL = time.time()
+
+
+def _process_start_wall() -> float:
+    """The wall-clock instant the OS started this process: its start in
+    clock ticks since boot (`/proc/self/stat`, field 22) against the
+    seconds since boot (`/proc/uptime`). Where that cannot be read, or
+    reads as later than now, the import of this module."""
+    try:
+        with open("/proc/self/stat") as stat:
+            # the command's name may hold spaces: count from its ")"
+            ticks = float(stat.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as uptime:
+            age = float(uptime.read().split()[0]) - ticks / os.sysconf(
+                "SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return _IMPORTED_WALL
+    return _IMPORTED_WALL - age if age >= 0 else _IMPORTED_WALL
+
+
+PROCESS_START_WALL = _process_start_wall()
+_STARTUP = gauge(
+    "swarm_startup_seconds",
+    "Seconds from the process's start, as the OS has it, to each mark of "
+    "a worker's start (worker_started, first_poll, first_pass_start, "
+    "first_pass_end); a mark is set once",
+    ("mark",),
+)
+_marked: set[str] = set()
+_mark_lock = threading.Lock()
+
+
+def mark_startup(mark: str) -> None:
+    """Stamp `swarm_startup_seconds{mark}` with the seconds since the
+    process's start, the first time `mark` is reached and never again."""
+    if mark in _marked:
+        return
+    with _mark_lock:
+        if mark not in _marked:
+            _marked.add(mark)
+            _STARTUP.set(time.time() - PROCESS_START_WALL, mark=mark)
 
 
 # --- spans -----------------------------------------------------------------

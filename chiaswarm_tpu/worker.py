@@ -431,6 +431,7 @@ class Worker:
         _SLICES_TOTAL.set(len(self.allocator))
         self._enable_compilation_cache()
         self._start_profiler_server()
+        telemetry.mark_startup("worker_started")
 
     async def _start_metrics_server(self) -> None:
         """Local telemetry endpoint (telemetry.py): GET /metrics in
@@ -881,6 +882,7 @@ class Worker:
             if heartbeat:
                 caps["cancel_only"] = 1
             _POLLS.inc(cause="heartbeat" if heartbeat else cause)
+            telemetry.mark_startup("first_poll")
             sent = time.time()
             # span "tick_wait": the worker could have asked and had not,
             # from the later of its last poll's end and the instant it

@@ -594,3 +594,52 @@ def test_worker_capture_profile_knob_and_output(sdaas_root, monkeypatch):
         await worker.hive.close()
 
     asyncio.run(scenario())
+
+
+# --- startup marks (ISSUE 52) ---
+
+
+def test_a_startup_mark_set_twice_keeps_its_first_value():
+    from chiaswarm_tpu import telemetry
+
+    marks = telemetry.REGISTRY.get("swarm_startup_seconds")
+    telemetry.mark_startup("test_mark_set_twice")
+    first = marks.value(mark="test_mark_set_twice")
+    # seconds since the process's start as the OS has it: this test did
+    # not run before its process existed, nor a day after
+    assert 0 < first < 86400
+    time.sleep(0.02)
+    telemetry.mark_startup("test_mark_set_twice")
+    assert marks.value(mark="test_mark_set_twice") == first
+    assert 'swarm_startup_seconds{mark="test_mark_set_twice"}' in (
+        telemetry.REGISTRY.render())
+
+
+def test_the_process_start_is_the_os_own_and_not_after_the_import():
+    from chiaswarm_tpu import telemetry
+
+    assert telemetry.PROCESS_START_WALL <= telemetry._IMPORTED_WALL
+    # pytest had been up a while when the first test imported the module:
+    # where /proc can be read the start lies before the import, not at it
+    import os
+
+    if os.path.exists("/proc/self/stat") and os.path.exists("/proc/uptime"):
+        assert telemetry.PROCESS_START_WALL < telemetry._IMPORTED_WALL
+
+
+def test_the_first_pass_span_sets_both_pass_marks_once():
+    from chiaswarm_tpu import telemetry
+    from chiaswarm_tpu.chips.device import _pass_span
+
+    marks = telemetry.REGISTRY.get("swarm_startup_seconds")
+    with _pass_span() as held:
+        time.sleep(0.01)
+    start = marks.value(mark="first_pass_start")
+    end = marks.value(mark="first_pass_end")
+    assert 0 < start < end
+    assert held.elapsed >= 0.01
+    with pytest.raises(RuntimeError):
+        with _pass_span():  # a failed pass ends too, and is no first one
+            raise RuntimeError("pass failed")
+    assert marks.value(mark="first_pass_start") == start
+    assert marks.value(mark="first_pass_end") == end
