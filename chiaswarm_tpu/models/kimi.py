@@ -32,9 +32,15 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import dot_product_attention
-from ..ops.latent_attention import latent_decode_attention
+from ..ops.latent_attention import (
+    column_block,
+    kernel_taken,
+    latent_decode_attention,
+    rows_a_step,
+)
 from .experts import (  # noqa: F401  (the benchmark takes three from here)
     dot,
     empty_load,
@@ -259,7 +265,7 @@ def attention_prefill(p, cfg: KimiConfig, h, positions):
 
 
 def attention_decode(p, cfg: KimiConfig, h, positions, cache, column, mask,
-                     absorb: bool = True):
+                     absorb: bool = True, interpret: bool = False):
     """One new token a row: `h` [R, hidden] at rotary `positions` [R],
     written to `cache` [R, S, cache_width] at `column`; `mask` [R, S] are
     the positions each row may see, its own included. `absorb` false
@@ -277,7 +283,8 @@ def attention_decode(p, cfg: KimiConfig, h, positions, cache, column, mask,
         q_lat = jnp.einsum("rhd,chd->rhc", q_nope, w_k,
                            preferred_element_type=jnp.float32).astype(h.dtype)
         context = latent_decode_attention(q_lat, q_rope, cache, mask,
-                                          cfg.softmax_scale)
+                                          cfg.softmax_scale,
+                                          interpret=interpret)
         out = jnp.einsum("rhc,chd->rhd", context, w_v,
                          preferred_element_type=jnp.float32).astype(h.dtype)
     else:
@@ -394,7 +401,7 @@ def decode_step(params, cfg: KimiConfig, tokens, positions, cache, column,
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps)
         out, cache[index] = attention_decode(
             layer["attn"], cfg, h, positions, cache[index], column, mask,
-            absorb)
+            absorb, interpret)
         x = x + out
         h = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps)
         out, told = feed_forward(layer, cfg, h, valid, interpret)
@@ -412,3 +419,31 @@ def step(params, cfg: KimiConfig, tokens, lengths, number, slots: int, cache,
         params, cfg, tokens, lengths + number, cache, column,
         decode_mask(lengths, slots, cache[0].shape[1], number, column), load,
         valid=valid)
+
+
+def decode_cache_blocks(cfg: KimiConfig, lengths, slots: int, positions: int,
+                        steps: int) -> tuple[int, int]:
+    """The host's account of the cache's column blocks a decode of `steps`
+    steps went over (models/text_model.py), without jax: (walked, rows x
+    the blocks of the cache's width), summed over rows, layers and steps.
+    A row at generated token `number` sees its prompt and the columns
+    `slots .. slots + number` (`decode_mask`), and the attention kernel
+    walks, for the rows of a grid step (the pass's order, `rows_a_step` at
+    a time), the blocks in which one of them sees a position
+    (`ops.latent_attention.live_blocks`, which tests/test_latent_attention.py
+    holds this count equal to at every step); the plain form walks them
+    all."""
+    lengths = np.asarray(lengths, np.int64)
+    block = column_block(positions)
+    bucket = lengths.size * -(-positions // block) * steps
+    if not kernel_taken(cfg.kv_lora_rank, lengths.size):
+        return cfg.num_hidden_layers * bucket, cfg.num_hidden_layers * bucket
+    # a step's rows walk what its longest prompt needs
+    together = rows_a_step(lengths.size)
+    prompt = -(-lengths.reshape(-1, together).max(axis=1) // block)
+    first = slots // block
+    generated = (slots + np.arange(steps)) // block - first + 1
+    # the block the generated columns start in may hold a prompt's end
+    walked = together * steps * int((prompt - (prompt > first)).sum()) + (
+        lengths.size * int(generated.sum()))
+    return cfg.num_hidden_layers * walked, cfg.num_hidden_layers * bucket

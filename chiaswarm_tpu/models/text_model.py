@@ -56,6 +56,12 @@ middle of a pass):
   turns into (the positions the real queries saw, those attention read for
   them, the key positions the prefill's spans walked, spans x the bucket's
   width) on the host.
+- where the family's decode attention is bounded by what a row's mask
+  shows (its row says `bounds_decode`): `decode_cache_blocks(cfg, lengths,
+  slots, positions, steps)`, the host's account, without jax, of the
+  cache's column blocks the decode's attention went over, by the rule the
+  module's own `step` applies on the device: (walked, rows x the blocks of
+  the cache's width), summed over rows, layers and steps.
 
 The forwards themselves (`_forward`, `prefill_rows`, `step`, `block_step`)
 are each module's own: the networks differ.
@@ -78,6 +84,8 @@ BY_BLOCKS = ("block_step", "first_block", "unmask", "blocks_of",
              "cache_positions")
 # what a family that selects keys gives besides
 SELECTS = ("index_cache_bytes", "selection_counts")
+# what a family whose decode attention is bounded by the mask gives besides
+BOUNDS_DECODE = ("decode_cache_blocks",)
 
 
 def family_module(family: str):
@@ -87,7 +95,8 @@ def family_module(family: str):
     module = importlib.import_module(f"{__package__}.{row['module']}")
     for name in (INTERFACE
                  + (BY_BLOCKS if row.get("block_length") else BY_TOKEN)
-                 + (SELECTS if row.get("selects") else ())):
+                 + (SELECTS if row.get("selects") else ())
+                 + (BOUNDS_DECODE if row.get("bounds_decode") else ())):
         getattr(module, name)
     return module
 

@@ -26,7 +26,10 @@ KERNEL_TRACES = telemetry.counter(
     "gated_delta_step | ssd_step | sampler | lightning_indexer | "
     "index_select | latent_expansion | sparse_latent_attention) and path (flash | banded | "
     "ring | fused | grouped | absorbed | overlapped | reduced | pallas | "
-    "einsum | gathered | reference)",
+    "einsum | gathered | reference); a traced latent_attention call bumps "
+    "absorbed whichever way it is computed (the form, which both ways "
+    "share and a deployment's expected paths name) and pallas beside it "
+    "where the kernel is what was traced",
     ("op", "path"),
 )
 

@@ -89,7 +89,13 @@ summed on the device beside the routing's tally and come back with the ids
 the prefill's spans went over, each bounded by its own end, against spans x
 the bucket's width (`swarm_prefill_key_extent_total{model, extent}`,
 `extent` `walked` | `bucket`; the envelope's `selection` has them as
-`prefill_key_extent`). A pass counts its prompt slots
+`prefill_key_extent`). Where the family's decode attention is bounded by
+what a row's mask shows (its row's `bounds_decode`: Kimi-K2), the cache's
+column blocks the decode went over against rows x the blocks of the
+cache's width are the module's own account on the host, from the lengths
+and the step count (`swarm_decode_cache_blocks_total{model, extent}`; the
+envelope's `decode_cache_blocks`, beside `decode_steps`). A pass counts its
+prompt slots
 (`swarm_prefill_slots_total{model, kind}`: `real` ids, the `padding`
 that was computed all the same, and the slots of the bucket `skipped`:
 what lies past a chunk's width, and chunks the model's prefill did not
@@ -204,6 +210,16 @@ PREFILL_KEY_EXTENT = telemetry.counter(
     "by; bucket: the prompt slots' whole width a span): walked / bucket is "
     "36 / 64 for a row of eight spans, and 1 says no span was bounded",
     ("model", "extent"))
+DECODE_CACHE_BLOCKS = telemetry.counter(
+    "swarm_decode_cache_blocks_total",
+    "Column blocks of the cache the decode attention of a family that "
+    "bounds it by the mask went over, summed over rows, layers and steps "
+    "on the host from the pass's lengths, by model and extent (walked: the "
+    "blocks in which one of a grid step's rows saw a position, which the "
+    "kernel fetches and computes for those rows and no others; bucket: "
+    "rows x the blocks of the cache's width): walked / bucket is 1 where "
+    "the plain form ran, which walks the width",
+    ("model", "extent"))
 BLOCK_FORWARD_ROWS = telemetry.counter(
     "swarm_block_forward_rows_total",
     "Real rows x forwards of a block decode, by model and kind (denoise: "
@@ -266,6 +282,9 @@ class TextGenerationPipeline:
         self.by_blocks = bool(TEXT_FAMILIES[family].get("block_length"))
         # whether its attention reads the keys a learned index selects
         self.selects = bool(TEXT_FAMILIES[family].get("selects"))
+        # whether its decode attention is bounded by what the mask shows
+        self.bounds_decode = bool(
+            TEXT_FAMILIES[family].get("bounds_decode"))
         if dtype is None:
             dtype = (jnp.bfloat16 if jax.default_backend() == "tpu"
                      else jnp.float32)
@@ -673,6 +692,14 @@ class TextGenerationPipeline:
             selection = {"selection": {
                 **dict(zip(("visible", "selected"), by_phase)),
                 "prefill_key_extent": {"walked": walked, "bucket": bucket}}}
+        bounded = {}
+        if self.bounds_decode:
+            walked, bucket = self.model.decode_cache_blocks(
+                cfg, lengths, slots, positions, forwards)
+            DECODE_CACHE_BLOCKS.inc(walked, extent="walked", **label)
+            DECODE_CACHE_BLOCKS.inc(bucket, extent="bucket", **label)
+            bounded = {"decode_cache_blocks": {"walked": walked,
+                                               "bucket": bucket}}
         blocks = {}
         if self.by_blocks:
             BLOCK_FORWARD_ROWS.inc(real * denoise, kind="denoise", **label)
@@ -714,6 +741,7 @@ class TextGenerationPipeline:
                     len(row) for row in request["prompt_ids"])),
                 "max_new_tokens": new_tokens,
                 "decode_steps": forwards,
+                **bounded,
                 "temperature": float(temperature),
                 **blocks,
                 "prefill_chunks": chunks,

@@ -20,6 +20,9 @@ A row:
 - `selects`: only where the family's attention reads the keys a learned
   index selects (its module then has `index_cache_bytes` and
   `selection_counts`, and its tally a third leaf: models/text_model.py);
+- `bounds_decode`: only where the family's decode attention is bounded by
+  what a row's mask shows and not by the cache's width (its module then
+  has `decode_cache_blocks`: models/text_model.py);
 - the footprint admission reckons with, in bf16 on one chip. Admission is
   the weights the chip holds (`params_gb`, GiB), a working set that does
   not grow with the rows (`working_gb`: a prefill chunk's activations and
@@ -45,7 +48,7 @@ TEXT_FAMILIES: dict[str, dict] = {
     # (benchmark/compile_check.py, PERF.md)
     "kimi_k2": {
         "name": "kimi", "wire": "KimiK2ForCausalLM", "module": "kimi",
-        "params_gb": 9.04, "working_gb": 3.0,
+        "bounds_decode": True, "params_gb": 9.04, "working_gb": 3.0,
         "cache_layers": ((8064.0, 0),)},
     # one chip's share of an 8-chip deployment (models/exaone.py
     # EXAONE_236B_EP8): 3.71 B parameters = 7.42 GB; a position is a key

@@ -1,5 +1,6 @@
 """The Pallas kernels (the UNet's two, the grouped expert matmul, the
-gated delta rule's step and a learned key selection's three), one
+gated delta rule's step, a learned key selection's three and Kimi's decode
+attention over its latent cache), one
 MMDiT block across the four chips of the slice, and K-EXAONE's prefill
 program at its cell's shape,
 compiled for a described v5e chip at the published widths (no chip attached: the TPU compiler is installed here and
@@ -333,6 +334,39 @@ def test_the_key_selections_kernels_compile_for_v5e(v5e, sq, skv):
         scale=256 ** -0.5, heads=64, offset=traced).compile()
     assert "sparse_latent_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("rows, positions, heads", [
+    # `kimi-batch-decode`'s pass: 256 rows, 256 prompt slots + 256 new
+    # tokens, 64 heads against a latent of 512 and a rotary key of 64
+    pytest.param(256, 512, 64, id="kimi-decode-256x512x64"),
+    # a pass whose new tokens leave the cache's last block ragged
+    pytest.param(8, 456, 64, id="kimi-decode-ragged-last-block"),
+])
+def test_latent_decode_attention_compiles_for_v5e(v5e, rows, positions,
+                                                   heads):
+    """Kimi's decode attention as one kernel (ISSUE 53): the cache read
+    once under a table of live blocks that is data, the scores in VMEM: no
+    `[rows, heads, positions]` float32 array is in the program, and the
+    call is compiled under the scoped memory the kernel declares, which is
+    inside the compiler's own 16 MiB default (what the admission test
+    above holds the fused GroupNorm to)."""
+    from chiaswarm_tpu.ops.latent_attention import (
+        _VMEM_LIMIT,
+        _decode_pallas,
+    )
+
+    compiled = _decode_pallas.lower(
+        _shape(v5e, (rows, heads, 512)), _shape(v5e, (rows, heads, 64)),
+        _shape(v5e, (rows, positions, 576)),
+        _shape(v5e, (rows, positions), jnp.bool_), scale=0.1352).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_attention" in text
+    assert f"f32[{rows},{heads},{positions}]" not in text
+    assert _VMEM_LIMIT <= 16 * 1024 * 1024
+    call, = (line for line in text.splitlines()
+             if "custom-call(" in line and "latent_attention" in line)
+    assert f'"size":"{_VMEM_LIMIT}"' in call
 
 
 @pytest.mark.parametrize("rows, heads, size, dim, groups", [

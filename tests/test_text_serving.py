@@ -174,6 +174,10 @@ def test_a_text_family_is_a_row_and_a_module_that_gives_the_interface(
         name, allow_random_init=True)
     assert pipe.model is model and pipe.by_blocks is by_blocks
     assert pipe.selects is bool(what.get("selects"))
+    # ... and the host's account of a decode bounded by the mask, likewise
+    assert all(hasattr(model, name_) == bool(what.get("bounds_decode"))
+               for name_ in text_model.BOUNDS_DECODE)
+    assert pipe.bounds_decode is bool(what.get("bounds_decode"))
     cfg, whole = model.config_for(name), model.config_for("test/whole")
     if by_blocks:
         assert cfg.block_length == whole.block_length == what["block_length"]
