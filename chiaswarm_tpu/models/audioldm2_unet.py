@@ -26,8 +26,12 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from .layers import (
+    Conv,
+    DeclaredParams,
+    Dense,
     FeedForward,
     FusedGroupNorm,
+    LayerNorm,
     ResnetBlock2D,
     TimestepEmbedding,
     timestep_embedding,
@@ -79,16 +83,16 @@ class MaskedTransformer2D(nn.Module):
             self.groups, epsilon=1e-6, dtype=self.dtype, name="norm"
         )(x)
         hidden = hidden.reshape(b, h * w, c)
-        hidden = nn.Dense(c, dtype=self.dtype, name="proj_in")(hidden)
+        hidden = Dense(c, dtype=self.dtype, name="proj_in")(hidden)
 
         def attention(q_in, kv_in, mask, name):
             inner = self.num_heads * self.head_dim
-            q = nn.Dense(inner, use_bias=False, dtype=self.dtype,
-                         name=f"{name}_to_q")(q_in)
-            k = nn.Dense(inner, use_bias=False, dtype=self.dtype,
-                         name=f"{name}_to_k")(kv_in)
-            v = nn.Dense(inner, use_bias=False, dtype=self.dtype,
-                         name=f"{name}_to_v")(kv_in)
+            q = Dense(inner, use_bias=False, dtype=self.dtype,
+                      name=f"{name}_to_q")(q_in)
+            k = Dense(inner, use_bias=False, dtype=self.dtype,
+                      name=f"{name}_to_k")(kv_in)
+            v = Dense(inner, use_bias=False, dtype=self.dtype,
+                      name=f"{name}_to_v")(kv_in)
             n, s = q.shape[1], k.shape[1]
             q = q.reshape(b, n, self.num_heads, self.head_dim)
             k = k.reshape(b, s, self.num_heads, self.head_dim)
@@ -103,26 +107,26 @@ class MaskedTransformer2D(nn.Module):
             out = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(
                 b, n, inner
             )
-            return nn.Dense(c, dtype=self.dtype, name=f"{name}_to_out_0")(
+            return Dense(c, dtype=self.dtype, name=f"{name}_to_out_0")(
                 out
             )
 
         blk = "transformer_blocks_0"
-        normed = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype,
-                              name=f"{blk}_norm1")(hidden)
+        normed = LayerNorm(epsilon=1e-5, dtype=self.dtype,
+                           name=f"{blk}_norm1")(hidden)
         hidden = hidden + attention(normed, normed, None, f"{blk}_attn1")
         hidden = hidden + attention(
-            nn.LayerNorm(epsilon=1e-5, dtype=self.dtype,
-                         name=f"{blk}_norm2")(hidden),
+            LayerNorm(epsilon=1e-5, dtype=self.dtype,
+                      name=f"{blk}_norm2")(hidden),
             jnp.asarray(context, self.dtype), context_mask,
             f"{blk}_attn2",
         )
-        h2 = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype,
-                          name=f"{blk}_norm3")(hidden)
+        h2 = LayerNorm(epsilon=1e-5, dtype=self.dtype,
+                       name=f"{blk}_norm3")(hidden)
         hidden = hidden + FeedForward(
             c, dtype=self.dtype, name=f"{blk}_ff"
         )(h2)
-        hidden = nn.Dense(c, dtype=self.dtype, name="proj_out")(hidden)
+        hidden = Dense(c, dtype=self.dtype, name="proj_out")(hidden)
         return hidden.reshape(b, h, w, c) + residual
 
 
@@ -147,7 +151,7 @@ class AudioLDM2UNet(nn.Module):
         )(timestep_embedding(timesteps, cfg.block_out_channels[0],
                              dtype=self.dtype))
 
-        x = nn.Conv(
+        x = Conv(
             cfg.block_out_channels[0], (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_in",
         )(jnp.asarray(sample, self.dtype))
@@ -171,7 +175,7 @@ class AudioLDM2UNet(nn.Module):
                         )(x, ctx, mask)
                 skips.append(x)
             if bidx != n - 1:
-                x = nn.Conv(
+                x = Conv(
                     out_ch, (3, 3), strides=(2, 2),
                     padding=((1, 1), (1, 1)), dtype=self.dtype,
                     name=f"down_{bidx}_downsample",
@@ -207,20 +211,20 @@ class AudioLDM2UNet(nn.Module):
                         )(x, ctx, mask)
             if bidx != n - 1:
                 x = jnp.repeat(jnp.repeat(x, 2, axis=1), 2, axis=2)
-                x = nn.Conv(
+                x = Conv(
                     out_ch, (3, 3), padding=((1, 1), (1, 1)),
                     dtype=self.dtype, name=f"up_{bidx}_upsample",
                 )(x)
 
         x = FusedGroupNorm(g, epsilon=1e-5, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        return nn.Conv(
+        return Conv(
             cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_out",
         )(x)
 
 
-class AudioLDM2Projection(nn.Module):
+class AudioLDM2Projection(DeclaredParams, nn.Module):
     """diffusers AudioLDM2ProjectionModel: per-tower Linear into the
     language-model width plus learned SOS/EOS vectors; output is the
     joint [sos|clap|eos|sos_1|t5|eos_1] GPT-2 input sequence + mask."""
@@ -232,10 +236,10 @@ class AudioLDM2Projection(nn.Module):
     def __call__(self, h0, m0, h1, m1):
         lm = self.language_model_dim
         b = h0.shape[0]
-        h0 = nn.Dense(lm, dtype=self.dtype, name="projection")(
+        h0 = Dense(lm, dtype=self.dtype, name="projection")(
             jnp.asarray(h0, self.dtype)
         )
-        h1 = nn.Dense(lm, dtype=self.dtype, name="projection_1")(
+        h1 = Dense(lm, dtype=self.dtype, name="projection_1")(
             jnp.asarray(h1, self.dtype)
         )
 

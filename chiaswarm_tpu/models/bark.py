@@ -24,6 +24,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Dense, Embed, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class BarkGPTConfig:
@@ -90,12 +92,12 @@ class _Block(nn.Module):
 
     def setup(self):
         cfg = self.config
-        self.ln1 = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype)
-        self.ln2 = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype)
-        self.qkv = nn.Dense(3 * cfg.d_model, dtype=self.dtype)
-        self.proj = nn.Dense(cfg.d_model, dtype=self.dtype)
-        self.fc = nn.Dense(4 * cfg.d_model, dtype=self.dtype)
-        self.fc_out = nn.Dense(cfg.d_model, dtype=self.dtype)
+        self.ln1 = LayerNorm(epsilon=1e-5, dtype=self.dtype)
+        self.ln2 = LayerNorm(epsilon=1e-5, dtype=self.dtype)
+        self.qkv = Dense(3 * cfg.d_model, dtype=self.dtype)
+        self.proj = Dense(cfg.d_model, dtype=self.dtype)
+        self.fc = Dense(4 * cfg.d_model, dtype=self.dtype)
+        self.fc_out = Dense(cfg.d_model, dtype=self.dtype)
 
     def _heads(self, x):
         b = x.shape[0]
@@ -154,28 +156,28 @@ class BarkGPT(nn.Module):
         cfg = self.config
         if cfg.n_codes_total:
             self.tok_embeds = [
-                nn.Embed(cfg.input_vocab, cfg.d_model, dtype=self.dtype,
-                         name=f"tok_embed_{i}")
+                Embed(cfg.input_vocab, cfg.d_model, dtype=self.dtype,
+                      name=f"tok_embed_{i}")
                 for i in range(cfg.n_codes_total)
             ]
             self.heads = [
-                nn.Dense(cfg.output_vocab, use_bias=False, dtype=self.dtype,
-                         name=f"head_{i}")
+                Dense(cfg.output_vocab, use_bias=False, dtype=self.dtype,
+                      name=f"head_{i}")
                 for i in range(cfg.n_codes_total - cfg.n_codes_given)
             ]
         else:
-            self.tok_embed = nn.Embed(
+            self.tok_embed = Embed(
                 cfg.input_vocab, cfg.d_model, dtype=self.dtype
             )
-            self.head = nn.Dense(
+            self.head = Dense(
                 cfg.output_vocab, use_bias=False, dtype=self.dtype
             )
-        self.pos_embed = nn.Embed(cfg.block_size, cfg.d_model, dtype=self.dtype)
+        self.pos_embed = Embed(cfg.block_size, cfg.d_model, dtype=self.dtype)
         self.blocks = [
             _Block(cfg, dtype=self.dtype, name=f"block_{i}")
             for i in range(cfg.n_layer)
         ]
-        self.ln_f = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype)
+        self.ln_f = LayerNorm(epsilon=1e-5, dtype=self.dtype)
 
     def _trunk(self, x):
         t = x.shape[1]

@@ -14,6 +14,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams, Dense, Embed, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class BlipConfig:
@@ -51,7 +53,7 @@ class _MHA(nn.Module):
         head_dim = self.dim // self.heads
         b, sq, _ = q_in.shape
         sk = kv_in.shape[1]
-        proj = lambda x, s, name: nn.Dense(self.dim, dtype=self.dtype, name=name)(
+        proj = lambda x, s, name: Dense(self.dim, dtype=self.dtype, name=name)(
             x
         ).reshape(b, s, self.heads, head_dim)
         q, k, v = proj(q_in, sq, "q"), proj(kv_in, sk, "k"), proj(kv_in, sk, "v")
@@ -60,10 +62,10 @@ class _MHA(nn.Module):
             logits = logits + mask
         weights = nn.softmax(logits.astype(jnp.float32), axis=-1).astype(self.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(b, sq, self.dim)
-        return nn.Dense(self.dim, dtype=self.dtype, name="out")(out)
+        return Dense(self.dim, dtype=self.dtype, name="out")(out)
 
 
-class VisionEncoder(nn.Module):
+class VisionEncoder(DeclaredParams, nn.Module):
     """BLIP ViT (pre-LN). Module names line up with the HF checkpoint graph
     (vision_model.*) so convert_blip is a mechanical rename + qkv split."""
 
@@ -74,7 +76,7 @@ class VisionEncoder(nn.Module):
     def __call__(self, pixels):
         """[B, H, W, 3] normalized -> [B, patches+1, D]."""
         cfg = self.config
-        x = nn.Conv(
+        x = Conv(
             cfg.vision_hidden, (cfg.patch_size, cfg.patch_size),
             strides=(cfg.patch_size, cfg.patch_size), dtype=self.dtype,
             name="patch_embed",
@@ -92,21 +94,21 @@ class VisionEncoder(nn.Module):
         x = x + pos
         eps = 1e-5  # HF BlipVisionConfig.layer_norm_eps
         for i in range(cfg.vision_layers):
-            y = nn.LayerNorm(epsilon=eps, dtype=self.dtype, name=f"ln1_{i}")(x)
+            y = LayerNorm(epsilon=eps, dtype=self.dtype, name=f"ln1_{i}")(x)
             x = x + _MHA(cfg.vision_heads, cfg.vision_hidden, dtype=self.dtype,
                          name=f"attn_{i}")(y, y)
-            y = nn.LayerNorm(epsilon=eps, dtype=self.dtype, name=f"ln2_{i}")(x)
-            y = nn.Dense(cfg.vision_hidden * 4, dtype=self.dtype, name=f"fc1_{i}")(y)
+            y = LayerNorm(epsilon=eps, dtype=self.dtype, name=f"ln2_{i}")(x)
+            y = Dense(cfg.vision_hidden * 4, dtype=self.dtype, name=f"fc1_{i}")(y)
             y = nn.gelu(y, approximate=False)
-            x = x + nn.Dense(cfg.vision_hidden, dtype=self.dtype, name=f"fc2_{i}")(y)
-        return nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="ln_post")(x)
+            x = x + Dense(cfg.vision_hidden, dtype=self.dtype, name=f"fc2_{i}")(y)
+        return LayerNorm(epsilon=eps, dtype=self.dtype, name="ln_post")(x)
 
 
 def _embed_text(module, cfg: BlipConfig, input_ids, dtype):
     """Word + learned-position embeddings with BERT embedding LN (shared by
     the decoder and the VQA question encoder; identical param names)."""
     s = input_ids.shape[1]
-    x = nn.Embed(
+    x = Embed(
         cfg.vocab_size, cfg.text_hidden, dtype=dtype, name="word_embeddings"
     )(input_ids)
     pos = module.param(
@@ -114,7 +116,7 @@ def _embed_text(module, cfg: BlipConfig, input_ids, dtype):
         (cfg.max_positions, cfg.text_hidden),
     ).astype(dtype)
     x = x + pos[None, :s]
-    return nn.LayerNorm(epsilon=1e-12, dtype=dtype, name="embed_ln")(x)
+    return LayerNorm(epsilon=1e-12, dtype=dtype, name="embed_ln")(x)
 
 
 def _bert_layer(cfg: BlipConfig, dtype, i: int, x, context,
@@ -127,14 +129,14 @@ def _bert_layer(cfg: BlipConfig, dtype, i: int, x, context,
     eps = 1e-12  # BERT layer_norm_eps
     y = _MHA(cfg.text_heads, cfg.text_hidden, dtype=dtype,
              name=f"self_{i}")(x, x, self_mask)
-    x = nn.LayerNorm(epsilon=eps, dtype=dtype, name=f"self_ln_{i}")(x + y)
+    x = LayerNorm(epsilon=eps, dtype=dtype, name=f"self_ln_{i}")(x + y)
     y = _MHA(cfg.text_heads, cfg.text_hidden, dtype=dtype,
              name=f"cross_{i}")(x, context, context_mask)
-    x = nn.LayerNorm(epsilon=eps, dtype=dtype, name=f"cross_ln_{i}")(x + y)
-    y = nn.Dense(cfg.text_hidden * 4, dtype=dtype, name=f"fc1_{i}")(x)
+    x = LayerNorm(epsilon=eps, dtype=dtype, name=f"cross_ln_{i}")(x + y)
+    y = Dense(cfg.text_hidden * 4, dtype=dtype, name=f"fc1_{i}")(x)
     y = nn.gelu(y, approximate=False)
-    y = nn.Dense(cfg.text_hidden, dtype=dtype, name=f"fc2_{i}")(y)
-    return nn.LayerNorm(epsilon=eps, dtype=dtype, name=f"ffn_ln_{i}")(x + y)
+    y = Dense(cfg.text_hidden, dtype=dtype, name=f"fc2_{i}")(y)
+    return LayerNorm(epsilon=eps, dtype=dtype, name=f"ffn_ln_{i}")(x + y)
 
 
 def _additive_mask(attention_mask, dtype):
@@ -174,10 +176,10 @@ class TextDecoder(nn.Module):
         )
         for i in range(cfg.text_layers):
             x = _bert_layer(cfg, self.dtype, i, x, ctx, causal, ctx_mask)
-        y = nn.Dense(cfg.text_hidden, dtype=self.dtype, name="head_dense")(x)
+        y = Dense(cfg.text_hidden, dtype=self.dtype, name="head_dense")(x)
         y = nn.gelu(y, approximate=False)
-        y = nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="head_ln")(y)
-        return nn.Dense(cfg.vocab_size, dtype=self.dtype, name="lm_head")(y)
+        y = LayerNorm(epsilon=eps, dtype=self.dtype, name="head_ln")(y)
+        return Dense(cfg.vocab_size, dtype=self.dtype, name="lm_head")(y)
 
 
 def greedy_decode(decoder_apply, params, image_embeds, config: BlipConfig,

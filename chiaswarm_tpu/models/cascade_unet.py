@@ -32,6 +32,8 @@ import math
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams, Dense, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class CascadeUNetConfig:
@@ -127,7 +129,7 @@ def timestep_ratio_embedding(r, dim: int, max_positions: float = 10000.0):
 
 def _ln(x, dtype):
     """The family's LayerNorm: last-axis, no affine, eps 1e-6."""
-    return nn.LayerNorm(
+    return LayerNorm(
         epsilon=1e-6, use_scale=False, use_bias=False, dtype=dtype
     )(x)
 
@@ -181,7 +183,7 @@ def interpolate_bilinear_align_corners(x, out_h: int, out_w: int):
     return top * (1 - yf)[None, :, None, None] + bot * yf[None, :, None, None]
 
 
-class GlobalResponseNorm(nn.Module):
+class GlobalResponseNorm(DeclaredParams, nn.Module):
     """ConvNeXt-v2 GRN over NHWC (spatial L2 per channel, mean-normalised)."""
 
     dim: int
@@ -211,7 +213,7 @@ class CascadeResBlock(nn.Module):
     def __call__(self, x, x_skip=None):
         res = x
         k = self.kernel_size
-        h = nn.Conv(
+        h = Conv(
             self.channels,
             (k, k),
             padding=((k // 2, k // 2), (k // 2, k // 2)),
@@ -222,12 +224,12 @@ class CascadeResBlock(nn.Module):
         h = _ln(h, self.dtype)
         if x_skip is not None:
             h = jnp.concatenate([h, x_skip.astype(h.dtype)], axis=-1)
-        h = nn.Dense(self.channels * 4, dtype=self.dtype, name="channelwise_0")(h)
+        h = Dense(self.channels * 4, dtype=self.dtype, name="channelwise_0")(h)
         h = nn.gelu(h, approximate=False)
         h = GlobalResponseNorm(
             self.channels * 4, dtype=self.dtype, name="channelwise_2"
         )(h)
-        h = nn.Dense(self.channels, dtype=self.dtype, name="channelwise_4")(h)
+        h = Dense(self.channels, dtype=self.dtype, name="channelwise_4")(h)
         return h + res
 
 
@@ -241,10 +243,10 @@ class CascadeTimestepBlock(nn.Module):
     @nn.compact
     def __call__(self, x, t_embed):
         chunks = jnp.split(t_embed, 1 + len(self.conds), axis=1)
-        ab = nn.Dense(self.channels * 2, dtype=self.dtype, name="mapper")(chunks[0])
+        ab = Dense(self.channels * 2, dtype=self.dtype, name="mapper")(chunks[0])
         a, b = jnp.split(ab, 2, axis=1)
         for i, cname in enumerate(self.conds):
-            abc = nn.Dense(
+            abc = Dense(
                 self.channels * 2, dtype=self.dtype, name=f"mapper_{cname}"
             )(chunks[i + 1])
             ac, bc = jnp.split(abc, 2, axis=1)
@@ -267,25 +269,25 @@ class CascadeAttnBlock(nn.Module):
         from ..ops import dot_product_attention
 
         b, h, w, c = x.shape
-        kvm = nn.Dense(c, dtype=self.dtype, name="kv_mapper_1")(nn.silu(kv))
+        kvm = Dense(c, dtype=self.dtype, name="kv_mapper_1")(nn.silu(kv))
         nx = _ln(x, self.dtype).reshape(b, h * w, c)
         kv_full = jnp.concatenate([nx, kvm], axis=1) if self.self_attn else kvm
 
         head_dim = c // self.num_heads
-        q = nn.Dense(c, dtype=self.dtype, name="attention_to_q")(nx)
-        k = nn.Dense(c, dtype=self.dtype, name="attention_to_k")(kv_full)
-        v = nn.Dense(c, dtype=self.dtype, name="attention_to_v")(kv_full)
+        q = Dense(c, dtype=self.dtype, name="attention_to_q")(nx)
+        k = Dense(c, dtype=self.dtype, name="attention_to_k")(kv_full)
+        v = Dense(c, dtype=self.dtype, name="attention_to_v")(kv_full)
         sk = kv_full.shape[1]
         out = dot_product_attention(
             q.reshape(b, h * w, self.num_heads, head_dim),
             k.reshape(b, sk, self.num_heads, head_dim),
             v.reshape(b, sk, self.num_heads, head_dim),
         ).reshape(b, h * w, c)
-        out = nn.Dense(c, dtype=self.dtype, name="attention_to_out_0")(out)
+        out = Dense(c, dtype=self.dtype, name="attention_to_out_0")(out)
         return x + out.reshape(b, h, w, c)
 
 
-class ConvTransposed2D(nn.Module):
+class ConvTransposed2D(DeclaredParams, nn.Module):
     """torch ConvTranspose2d equivalent (kernel k, stride s, padding p) via
     an input-dilated forward convolution. The kernel param is stored
     ALREADY flipped/transposed to [kh, kw, in, out] forward-conv layout
@@ -352,7 +354,7 @@ class StableCascadeUNet(nn.Module):
         t_embed = t_embed.astype(self.dtype)
 
         # --- CLIP conditioning tokens: [text, image, pooled] order ---
-        ctp = nn.Dense(
+        ctp = Dense(
             cfg.conditioning_dim * cfg.clip_seq,
             dtype=self.dtype,
             name="clip_txt_pooled_mapper",
@@ -360,7 +362,7 @@ class StableCascadeUNet(nn.Module):
         ctp = ctp.reshape(b, -1, cfg.conditioning_dim)
         if cfg.clip_text_in_channels and clip_text is not None:
             pieces = [
-                nn.Dense(
+                Dense(
                     cfg.conditioning_dim, dtype=self.dtype, name="clip_txt_mapper"
                 )(clip_text.astype(self.dtype))
             ]
@@ -369,7 +371,7 @@ class StableCascadeUNet(nn.Module):
                     clip_img = jnp.zeros(
                         (b, 1, cfg.clip_image_in_channels), self.dtype
                     )
-                ci = nn.Dense(
+                ci = Dense(
                     cfg.conditioning_dim * cfg.clip_seq,
                     dtype=self.dtype,
                     name="clip_img_mapper",
@@ -382,13 +384,13 @@ class StableCascadeUNet(nn.Module):
 
         # --- input embedding: pixel-unshuffle + 1x1 conv + LN ---
         x = pixel_unshuffle(sample.astype(self.dtype), cfg.patch_size)
-        x = nn.Conv(
+        x = Conv(
             cfg.block_out_channels[0], (1, 1), dtype=self.dtype, name="embedding_1"
         )(x)
         x = _ln(x, self.dtype)
 
         if cfg.effnet_in_channels and effnet is not None:
-            e = nn.Conv(
+            e = Conv(
                 cfg.block_out_channels[0] * 4,
                 (1, 1),
                 dtype=self.dtype,
@@ -399,7 +401,7 @@ class StableCascadeUNet(nn.Module):
                 )
             )
             e = nn.gelu(e, approximate=False)
-            e = nn.Conv(
+            e = Conv(
                 cfg.block_out_channels[0],
                 (1, 1),
                 dtype=self.dtype,
@@ -409,14 +411,14 @@ class StableCascadeUNet(nn.Module):
         if cfg.pixel_mapper_in_channels:
             if pixels is None:
                 pixels = jnp.zeros((b, 8, 8, cfg.pixel_mapper_in_channels))
-            p = nn.Conv(
+            p = Conv(
                 cfg.block_out_channels[0] * 4,
                 (1, 1),
                 dtype=self.dtype,
                 name="pixels_mapper_0",
             )(pixels.astype(self.dtype))
             p = nn.gelu(p, approximate=False)
-            p = nn.Conv(
+            p = Conv(
                 cfg.block_out_channels[0],
                 (1, 1),
                 dtype=self.dtype,
@@ -501,7 +503,7 @@ class StableCascadeUNet(nn.Module):
                 x = _ln(x, self.dtype)
                 if cfg.switch_level is not None:
                     # 1x1 mapping conv, then optional bilinear downscale
-                    x = nn.Conv(
+                    x = Conv(
                         cfg.block_out_channels[i],
                         (1, 1),
                         dtype=self.dtype,
@@ -514,7 +516,7 @@ class StableCascadeUNet(nn.Module):
                 else:
                     # torch Conv2d(k=2, s=2) has padding=0: VALID, so odd
                     # grids floor (flax SAME would zero-pad and diverge)
-                    x = nn.Conv(
+                    x = Conv(
                         cfg.block_out_channels[i],
                         (2, 2),
                         strides=(2, 2),
@@ -529,7 +531,7 @@ class StableCascadeUNet(nn.Module):
             for r in range(n_rep):
                 x = run_blocks(blocks, x)
                 if r < n_rep - 1:
-                    x = nn.Conv(
+                    x = Conv(
                         cfg.block_out_channels[i],
                         (1, 1),
                         dtype=self.dtype,
@@ -550,7 +552,7 @@ class StableCascadeUNet(nn.Module):
             for r in range(n_rep):
                 x = run_blocks(blocks, x, skip=skip)
                 if r < n_rep - 1:
-                    x = nn.Conv(
+                    x = Conv(
                         cfg.block_out_channels[i],
                         (1, 1),
                         dtype=self.dtype,
@@ -563,7 +565,7 @@ class StableCascadeUNet(nn.Module):
                         x = interpolate_bilinear_align_corners(
                             x, x.shape[1] * 2, x.shape[2] * 2
                         )
-                    x = nn.Conv(
+                    x = Conv(
                         cfg.block_out_channels[i - 1],
                         (1, 1),
                         dtype=self.dtype,
@@ -580,7 +582,7 @@ class StableCascadeUNet(nn.Module):
 
         # --- classifier head: LN + 1x1 conv + pixel-shuffle ---
         x = _ln(x, self.dtype)
-        x = nn.Conv(
+        x = Conv(
             cfg.out_channels * cfg.patch_size**2,
             (1, 1),
             dtype=self.dtype,

@@ -17,6 +17,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Dense, Embed, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class ClapTextConfig:
@@ -51,9 +53,9 @@ class _SelfAttention(nn.Module):
         def heads(t):
             return t.reshape(t.shape[0], t.shape[1], h, d)
 
-        q = heads(nn.Dense(cfg.hidden_size, dtype=self.dtype, name="query")(x))
-        k = heads(nn.Dense(cfg.hidden_size, dtype=self.dtype, name="key")(x))
-        v = heads(nn.Dense(cfg.hidden_size, dtype=self.dtype, name="value")(x))
+        q = heads(Dense(cfg.hidden_size, dtype=self.dtype, name="query")(x))
+        k = heads(Dense(cfg.hidden_size, dtype=self.dtype, name="key")(x))
+        v = heads(Dense(cfg.hidden_size, dtype=self.dtype, name="value")(x))
         att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (d**-0.5)
         att = att + (1.0 - mask[:, None, None, :]) * -1e9
         att = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(q.dtype)
@@ -71,16 +73,16 @@ class _Layer(nn.Module):
     def __call__(self, x, mask):
         cfg = self.config
         att = _SelfAttention(cfg, dtype=self.dtype, name="self_attn")(x, mask)
-        att = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="attn_out")(att)
-        x = nn.LayerNorm(
+        att = Dense(cfg.hidden_size, dtype=self.dtype, name="attn_out")(att)
+        x = LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="attn_norm"
         )(x + att)
-        h = nn.Dense(
+        h = Dense(
             cfg.intermediate_size, dtype=self.dtype, name="intermediate"
         )(x)
         h = nn.gelu(h, approximate=False)
-        h = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="output")(h)
-        return nn.LayerNorm(
+        h = Dense(cfg.hidden_size, dtype=self.dtype, name="output")(h)
+        return LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="output_norm"
         )(x + h)
 
@@ -107,20 +109,20 @@ class ClapTextEncoder(nn.Module):
             + cfg.pad_token_id
         )
         x = (
-            nn.Embed(
+            Embed(
                 cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
                 name="word_embeddings",
             )(input_ids)
-            + nn.Embed(
+            + Embed(
                 cfg.max_positions, cfg.hidden_size, dtype=self.dtype,
                 name="position_embeddings",
             )(positions)
-            + nn.Embed(
+            + Embed(
                 cfg.type_vocab_size, cfg.hidden_size, dtype=self.dtype,
                 name="token_type_embeddings",
             )(jnp.zeros_like(input_ids))
         )
-        x = nn.LayerNorm(
+        x = LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="embed_norm"
         )(x)
         for i in range(cfg.num_layers):
@@ -128,10 +130,10 @@ class ClapTextEncoder(nn.Module):
                 x, attention_mask
             )
         pooled = jnp.tanh(
-            nn.Dense(cfg.hidden_size, dtype=self.dtype, name="pooler")(x[:, 0])
+            Dense(cfg.hidden_size, dtype=self.dtype, name="pooler")(x[:, 0])
         )
         # ClapProjectionLayer: linear -> relu -> linear
-        p = nn.Dense(cfg.projection_dim, dtype=self.dtype, name="proj_1")(pooled)
+        p = Dense(cfg.projection_dim, dtype=self.dtype, name="proj_1")(pooled)
         p = nn.relu(p)
-        p = nn.Dense(cfg.projection_dim, dtype=self.dtype, name="proj_2")(p)
+        p = Dense(cfg.projection_dim, dtype=self.dtype, name="proj_2")(p)
         return {"hidden_states": x, "pooled": p}

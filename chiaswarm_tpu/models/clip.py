@@ -12,6 +12,8 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .layers import DeclaredParams, Dense, Embed, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class CLIPTextConfig:
@@ -48,7 +50,7 @@ class CLIPAttention(nn.Module):
         b, s, _ = hidden.shape
 
         def heads(name):
-            return nn.Dense(cfg.hidden_size, dtype=self.dtype, name=name)(
+            return Dense(cfg.hidden_size, dtype=self.dtype, name=name)(
                 hidden
             ).reshape(b, s, cfg.num_heads, head_dim)
 
@@ -57,7 +59,7 @@ class CLIPAttention(nn.Module):
         logits = logits + mask
         weights = nn.softmax(logits.astype(jnp.float32), axis=-1).astype(self.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(b, s, cfg.hidden_size)
-        return nn.Dense(cfg.hidden_size, dtype=self.dtype, name="out_proj")(out)
+        return Dense(cfg.hidden_size, dtype=self.dtype, name="out_proj")(out)
 
 
 class CLIPLayer(nn.Module):
@@ -68,18 +70,18 @@ class CLIPLayer(nn.Module):
     def __call__(self, hidden, mask):
         cfg = self.config
         hidden = hidden + CLIPAttention(cfg, dtype=self.dtype, name="self_attn")(
-            nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="layer_norm1")(hidden),
+            LayerNorm(epsilon=1e-5, dtype=self.dtype, name="layer_norm1")(hidden),
             mask,
         )
-        mlp_in = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="layer_norm2")(hidden)
-        h = nn.Dense(
+        mlp_in = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="layer_norm2")(hidden)
+        h = Dense(
             cfg.hidden_size * cfg.intermediate_mult, dtype=self.dtype, name="fc1"
         )(mlp_in)
         h = _act(cfg.hidden_act)(h)
-        return hidden + nn.Dense(cfg.hidden_size, dtype=self.dtype, name="fc2")(h)
+        return hidden + Dense(cfg.hidden_size, dtype=self.dtype, name="fc2")(h)
 
 
-class CLIPTextEncoder(nn.Module):
+class CLIPTextEncoder(DeclaredParams, nn.Module):
     config: CLIPTextConfig
     dtype: jnp.dtype = jnp.float32
 
@@ -101,7 +103,7 @@ class CLIPTextEncoder(nn.Module):
         cfg = self.config
         b, s = input_ids.shape
 
-        tok = nn.Embed(
+        tok = Embed(
             cfg.vocab_size, cfg.hidden_size, dtype=self.dtype, name="token_embedding"
         )(jnp.minimum(input_ids, cfg.vocab_size - 1))
         if extra_embeddings is not None:
@@ -133,7 +135,7 @@ class CLIPTextEncoder(nn.Module):
             collected.append(hidden)
             hidden = CLIPLayer(cfg, dtype=self.dtype, name=f"layers_{i}")(hidden, causal)
         pre_ln = hidden
-        final = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="final_layer_norm")(
+        final = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="final_layer_norm")(
             hidden
         )
         collected.append(final)  # index -1
@@ -153,7 +155,7 @@ class CLIPTextEncoder(nn.Module):
         )
         pooled = final[jnp.arange(b), eos_idx]
         if cfg.projection_dim:
-            pooled = nn.Dense(
+            pooled = Dense(
                 cfg.projection_dim, use_bias=False, dtype=self.dtype,
                 name="text_projection",
             )(pooled)

@@ -15,7 +15,7 @@ from __future__ import annotations
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .layers import TimestepEmbedding, timestep_embedding
+from .layers import Conv, TimestepEmbedding, timestep_embedding
 from .unet2d import CrossAttnDownBlock, UNet2DConfig, UNetMidBlock
 
 
@@ -35,25 +35,25 @@ class ControlNetConditioningEmbedding(nn.Module):
     def __call__(self, cond):
         n_down = max((self.downscale - 1).bit_length(), 1)  # log2, >= 1
         block_channels = ((16, 32, 96, 256) * 2)[: n_down + 1]
-        x = nn.Conv(
+        x = Conv(
             block_channels[0], (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_in",
         )(cond)
         x = nn.silu(x)
         for i in range(len(block_channels) - 1):
-            x = nn.Conv(
+            x = Conv(
                 block_channels[i], (3, 3), padding=((1, 1), (1, 1)),
                 dtype=self.dtype, name=f"blocks_{2 * i}",
             )(x)
             x = nn.silu(x)
-            x = nn.Conv(
+            x = Conv(
                 block_channels[i + 1], (3, 3), strides=(2, 2),
                 padding=((1, 1), (1, 1)), dtype=self.dtype,
                 name=f"blocks_{2 * i + 1}",
             )(x)
             x = nn.silu(x)
         # zero conv: starts as identity-off
-        return nn.Conv(
+        return Conv(
             self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
             kernel_init=nn.initializers.zeros, bias_init=nn.initializers.zeros,
             dtype=self.dtype, name="conv_out",
@@ -61,7 +61,7 @@ class ControlNetConditioningEmbedding(nn.Module):
 
 
 def _zero_conv(channels, dtype, name):
-    return nn.Conv(
+    return Conv(
         channels, (1, 1), kernel_init=nn.initializers.zeros,
         bias_init=nn.initializers.zeros, dtype=dtype, name=name,
     )
@@ -110,7 +110,7 @@ class ControlNetModel(nn.Module):
                 temb_dim, dtype=self.dtype, name="add_embedding"
             )(add_feat)
 
-        x = nn.Conv(
+        x = Conv(
             cfg.block_out_channels[0], (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_in",
         )(sample)

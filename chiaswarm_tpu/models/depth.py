@@ -22,6 +22,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams, Dense, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class DPTConfig:
@@ -54,19 +56,19 @@ class _ViTBlock(nn.Module):
     def __call__(self, x):
         b, s, _ = x.shape
         hd = self.hidden // self.heads
-        y = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
-        q = nn.Dense(self.hidden, dtype=self.dtype, name="q")(y)
-        k = nn.Dense(self.hidden, dtype=self.dtype, name="k")(y)
-        v = nn.Dense(self.hidden, dtype=self.dtype, name="v")(y)
+        y = LayerNorm(dtype=self.dtype, name="ln1")(x)
+        q = Dense(self.hidden, dtype=self.dtype, name="q")(y)
+        k = Dense(self.hidden, dtype=self.dtype, name="k")(y)
+        v = Dense(self.hidden, dtype=self.dtype, name="v")(y)
         q, k, v = (t.reshape(b, s, self.heads, hd) for t in (q, k, v))
         from ..ops import dot_product_attention
 
         attn = dot_product_attention(q, k, v).reshape(b, s, self.hidden)
-        x = x + nn.Dense(self.hidden, dtype=self.dtype, name="out")(attn)
-        y = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
-        y = nn.Dense(4 * self.hidden, dtype=self.dtype, name="fc1")(y)
+        x = x + Dense(self.hidden, dtype=self.dtype, name="out")(attn)
+        y = LayerNorm(dtype=self.dtype, name="ln2")(x)
+        y = Dense(4 * self.hidden, dtype=self.dtype, name="fc1")(y)
         y = nn.gelu(y, approximate=False)
-        return x + nn.Dense(self.hidden, dtype=self.dtype, name="fc2")(y)
+        return x + Dense(self.hidden, dtype=self.dtype, name="fc2")(y)
 
 
 class _ResidualConvUnit(nn.Module):
@@ -76,11 +78,11 @@ class _ResidualConvUnit(nn.Module):
     @nn.compact
     def __call__(self, x):
         y = nn.relu(x)
-        y = nn.Conv(self.channels, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv1")(y)
+        y = Conv(self.channels, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv1")(y)
         y = nn.relu(y)
-        y = nn.Conv(self.channels, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv2")(y)
+        y = Conv(self.channels, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv2")(y)
         return x + y
 
 
@@ -89,7 +91,7 @@ def _resize2x(x):
     return jax.image.resize(x, (b, 2 * h, 2 * w, c), "bilinear")
 
 
-class DPTDepthModel(nn.Module):
+class DPTDepthModel(DeclaredParams, nn.Module):
     config: DPTConfig
     dtype: jnp.dtype = jnp.float32
 
@@ -97,7 +99,7 @@ class DPTDepthModel(nn.Module):
     def __call__(self, pixels):
         """pixels [B, H, W, 3] normalized -> inverse depth [B, H, W]."""
         cfg = self.config
-        x = nn.Conv(
+        x = Conv(
             cfg.hidden_size, (cfg.patch_size, cfg.patch_size),
             strides=(cfg.patch_size, cfg.patch_size), dtype=self.dtype,
             name="patch_embed",
@@ -131,14 +133,14 @@ class DPTDepthModel(nn.Module):
                 [tokens, jnp.broadcast_to(cls_tok, tokens.shape)], axis=-1
             )
             tokens = nn.gelu(
-                nn.Dense(cfg.hidden_size, dtype=self.dtype,
-                         name=f"reassemble_{k}_readout")(readout),
+                Dense(cfg.hidden_size, dtype=self.dtype,
+                      name=f"reassemble_{k}_readout")(readout),
                 approximate=False,
             )
             fmap = tokens.reshape(b, gh, gw, cfg.hidden_size)
             ch = cfg.reassemble_channels[k]
-            fmap = nn.Conv(ch, (1, 1), dtype=self.dtype,
-                           name=f"reassemble_{k}_project")(fmap)
+            fmap = Conv(ch, (1, 1), dtype=self.dtype,
+                        name=f"reassemble_{k}_project")(fmap)
             if k == 0:  # /16 -> /4
                 fmap = nn.ConvTranspose(
                     ch, (4, 4), strides=(4, 4), dtype=self.dtype,
@@ -150,11 +152,11 @@ class DPTDepthModel(nn.Module):
                     name="reassemble_1_resize",
                 )(fmap)
             elif k == 3:  # /16 -> /32
-                fmap = nn.Conv(
+                fmap = Conv(
                     ch, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)),
                     dtype=self.dtype, name="reassemble_3_resize",
                 )(fmap)
-            fmap = nn.Conv(
+            fmap = Conv(
                 cfg.fusion_dim, (3, 3), padding=((1, 1), (1, 1)),
                 use_bias=False, dtype=self.dtype, name=f"conv_{k}",
             )(fmap)
@@ -178,17 +180,17 @@ class DPTDepthModel(nn.Module):
                 cfg.fusion_dim, dtype=self.dtype, name=f"fusion_{k}_rcu2"
             )(hidden)
             hidden = _resize2x(hidden)
-            fused = nn.Conv(
+            fused = Conv(
                 cfg.fusion_dim, (1, 1), dtype=self.dtype,
                 name=f"fusion_{k}_project",
             )(hidden)
 
-        y = nn.Conv(cfg.fusion_dim // 2, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="head_conv1")(fused)
+        y = Conv(cfg.fusion_dim // 2, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="head_conv1")(fused)
         y = _resize2x(y)
-        y = nn.Conv(cfg.head_dim, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="head_conv2")(y)
+        y = Conv(cfg.head_dim, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="head_conv2")(y)
         y = nn.relu(y)
-        y = nn.Conv(1, (1, 1), dtype=self.dtype, name="head_conv3")(y)
+        y = Conv(1, (1, 1), dtype=self.dtype, name="head_conv3")(y)
         y = nn.relu(y)
         return y[..., 0]

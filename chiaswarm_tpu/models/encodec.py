@@ -24,6 +24,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams
+
 
 @dataclasses.dataclass(frozen=True)
 class EncodecConfig:
@@ -66,7 +68,7 @@ class _CausalConv(nn.Module):
             mode = "reflect" if self.pad_mode == "reflect" else "constant"
             # reflect needs T > pad; generated audio always has many frames
             x = jnp.pad(x, ((0, 0), (pad, 0), (0, 0)), mode=mode)
-        return nn.Conv(
+        return Conv(
             self.out_channels, (self.kernel_size,),
             kernel_dilation=(self.dilation,), padding="VALID",
             dtype=self.dtype, name="conv",
@@ -92,7 +94,7 @@ class _CausalConvTranspose(nn.Module):
         return y[:, : y.shape[1] - trim] if trim else y
 
 
-class _LSTM(nn.Module):
+class _LSTM(DeclaredParams, nn.Module):
     """torch-layout LSTM stack with residual (EncodecLSTM semantics).
 
     Parameters keep the torch names/shapes (weight_ih_l0 [4H, H], gate
@@ -214,7 +216,7 @@ class _Decoder(nn.Module):
         )(x)
 
 
-class EncodecDecoderModel(nn.Module):
+class EncodecDecoderModel(DeclaredParams, nn.Module):
     """RVQ codes [B, K, T] -> waveform [B, T * hop] (hop = prod(ratios))."""
 
     config: EncodecConfig

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from ..ops.platform import KERNEL_TRACES, active_mesh
 from ..parallel import tensor
+from .layers import DeclaredParams, Dense, LayerNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,7 +209,7 @@ def _whole(x):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-class _ProductDense(nn.Module):
+class _ProductDense(DeclaredParams, nn.Module):
     """`nn.Dense`, its parameters under the same names, with `product` (one
     of `parallel.tensor`'s pair) as its matmul."""
 
@@ -241,7 +242,7 @@ def _parallel_dense(mesh, dtype, product, features: int, name: str):
         KERNEL_TRACES.inc(op="tensor_matmul",
                           path="reduced" if mesh is None else "overlapped")
     if mesh is None:
-        return nn.Dense(features, dtype=dtype, name=name)
+        return Dense(features, dtype=dtype, name=name)
     return _ProductDense(features, functools.partial(product, mesh),
                          dtype=dtype, name=name)
 
@@ -256,7 +257,7 @@ def _split_qkv(out, heads: int, head_dim: int, groups: int):
                  for i in range(3))
 
 
-class QKNorm(nn.Module):
+class QKNorm(DeclaredParams, nn.Module):
     """Per-head RMS normalization of q and k (Flux stabilization)."""
 
     dtype: jnp.dtype = jnp.float32
@@ -279,9 +280,9 @@ class MLPEmbedder(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        x = nn.Dense(self.hidden, dtype=self.dtype, name="in_layer")(x)
+        x = Dense(self.hidden, dtype=self.dtype, name="in_layer")(x)
         x = nn.silu(x)
-        return nn.Dense(self.hidden, dtype=self.dtype, name="out_layer")(x)
+        return Dense(self.hidden, dtype=self.dtype, name="out_layer")(x)
 
 
 class Modulation(nn.Module):
@@ -293,7 +294,7 @@ class Modulation(nn.Module):
 
     @nn.compact
     def __call__(self, vec):
-        out = _whole(nn.Dense(
+        out = _whole(Dense(
             self.n * self.hidden, dtype=self.dtype, name="lin")(nn.silu(vec)))
         return jnp.split(out[:, None, :], self.n, axis=-1)
 
@@ -334,7 +335,7 @@ class DoubleStreamBlock(nn.Module):
         txt_mod = stream("txt")(vec)
 
         def norm(x):
-            return nn.LayerNorm(
+            return LayerNorm(
                 use_bias=False, use_scale=False, epsilon=1e-6, dtype=self.dtype
             )(x)
 
@@ -390,7 +391,7 @@ class SingleStreamBlock(nn.Module):
         shift, scale, gate = Modulation(
             cfg.hidden_size, 3, dtype=self.dtype, name="modulation"
         )(vec)
-        y = nn.LayerNorm(
+        y = LayerNorm(
             use_bias=False, use_scale=False, epsilon=1e-6, dtype=self.dtype
         )(x)
         y = y * (1 + scale) + shift
@@ -430,8 +431,8 @@ class FluxTransformer(nn.Module):
         """img [B, S_img, in_channels] patchified latents; txt [B, S_txt,
         context_dim]; ids [B, S, 3]; -> [B, S_img, in_channels]."""
         cfg = self.config
-        img = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="img_in")(img)
-        txt = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="txt_in")(txt)
+        img = Dense(cfg.hidden_size, dtype=self.dtype, name="img_in")(img)
+        txt = Dense(cfg.hidden_size, dtype=self.dtype, name="txt_in")(txt)
 
         vec = MLPEmbedder(cfg.hidden_size, dtype=self.dtype, name="time_in")(
             timestep_embedding(timesteps, 256).astype(self.dtype)
@@ -479,15 +480,15 @@ class FluxTransformer(nn.Module):
             x = tensor.last_token_shards(mesh, x, img.shape[1])
 
         shift, scale = jnp.split(
-            _whole(nn.Dense(2 * cfg.hidden_size, dtype=self.dtype,
-                            name="final_layer_mod")(nn.silu(vec)))[:, None, :],
+            _whole(Dense(2 * cfg.hidden_size, dtype=self.dtype,
+                         name="final_layer_mod")(nn.silu(vec)))[:, None, :],
             2, axis=-1,
         )
-        x = nn.LayerNorm(
+        x = LayerNorm(
             use_bias=False, use_scale=False, epsilon=1e-6, dtype=self.dtype
         )(x)
         x = x * (1 + scale) + shift
-        out = nn.Dense(
+        out = Dense(
             cfg.in_channels, dtype=self.dtype, name="final_layer_linear"
         )(x)
         # from each chip's quarter of the image tokens: kilobytes
@@ -509,8 +510,8 @@ class FluxHead(nn.Module):
     @nn.compact
     def __call__(self, img, txt, timesteps, pooled, guidance=None):
         cfg = self.config
-        img = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="img_in")(img)
-        txt = nn.Dense(cfg.hidden_size, dtype=self.dtype, name="txt_in")(txt)
+        img = Dense(cfg.hidden_size, dtype=self.dtype, name="img_in")(img)
+        txt = Dense(cfg.hidden_size, dtype=self.dtype, name="txt_in")(txt)
         vec = MLPEmbedder(cfg.hidden_size, dtype=self.dtype, name="time_in")(
             timestep_embedding(timesteps, 256).astype(self.dtype)
         )
@@ -536,15 +537,15 @@ class FluxFinal(nn.Module):
     def __call__(self, x, vec):
         cfg = self.config
         shift, scale = jnp.split(
-            _whole(nn.Dense(2 * cfg.hidden_size, dtype=self.dtype,
-                            name="final_layer_mod")(nn.silu(vec)))[:, None, :],
+            _whole(Dense(2 * cfg.hidden_size, dtype=self.dtype,
+                         name="final_layer_mod")(nn.silu(vec)))[:, None, :],
             2, axis=-1,
         )
-        x = nn.LayerNorm(
+        x = LayerNorm(
             use_bias=False, use_scale=False, epsilon=1e-6, dtype=self.dtype
         )(x)
         x = x * (1 + scale) + shift
-        return nn.Dense(
+        return Dense(
             cfg.in_channels, dtype=self.dtype, name="final_layer_linear"
         )(x)
 

@@ -22,6 +22,8 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .layers import DeclaredParams, Dense, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
@@ -46,9 +48,9 @@ class _Block(nn.Module):
         b, s, d = x.shape
         heads = cfg.num_heads
         hd = d // heads
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=self.dtype,
-                         name="ln_1")(x)
-        qkv = nn.Dense(3 * d, dtype=self.dtype, name="c_attn")(h)
+        h = LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=self.dtype,
+                      name="ln_1")(x)
+        qkv = Dense(3 * d, dtype=self.dtype, name="c_attn")(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(b, s, heads, hd)
         k = k.reshape(b, s, heads, hd)
@@ -57,15 +59,15 @@ class _Block(nn.Module):
         logits = logits * (hd ** -0.5) + bias
         weights = nn.softmax(logits, axis=-1).astype(self.dtype)
         attn = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(b, s, d)
-        x = x + nn.Dense(d, dtype=self.dtype, name="c_proj")(attn)
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=self.dtype,
-                         name="ln_2")(x)
-        h = nn.Dense(4 * d, dtype=self.dtype, name="c_fc")(h)
+        x = x + Dense(d, dtype=self.dtype, name="c_proj")(attn)
+        h = LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=self.dtype,
+                      name="ln_2")(x)
+        h = Dense(4 * d, dtype=self.dtype, name="c_fc")(h)
         h = nn.gelu(h, approximate=True)  # gelu_new
-        return x + nn.Dense(d, dtype=self.dtype, name="mlp_c_proj")(h)
+        return x + Dense(d, dtype=self.dtype, name="mlp_c_proj")(h)
 
 
-class GPT2Model(nn.Module):
+class GPT2Model(DeclaredParams, nn.Module):
     """[B, S, hidden] input embeddings (+ optional [B, S] 1-keep padding
     mask) -> [B, S, hidden] final hidden states (causal)."""
 
@@ -90,6 +92,6 @@ class GPT2Model(nn.Module):
             )
         for i in range(cfg.num_layers):
             x = _Block(cfg, dtype=self.dtype, name=f"h_{i}")(x, bias)
-        return nn.LayerNorm(
+        return LayerNorm(
             epsilon=cfg.layer_norm_epsilon, dtype=self.dtype, name="ln_f"
         )(x)

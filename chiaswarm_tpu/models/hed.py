@@ -16,6 +16,8 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams
+
 
 @dataclasses.dataclass(frozen=True)
 class HEDConfig:
@@ -34,14 +36,14 @@ class _Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         for i in range(self.n_convs):
-            x = nn.Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                        dtype=self.dtype, name=f"convs_{i}")(x)
+            x = Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                     dtype=self.dtype, name=f"convs_{i}")(x)
             x = nn.relu(x)
-        proj = nn.Conv(1, (1, 1), dtype=self.dtype, name="projection")(x)
+        proj = Conv(1, (1, 1), dtype=self.dtype, name="projection")(x)
         return x, proj
 
 
-class HEDNet(nn.Module):
+class HEDNet(DeclaredParams, nn.Module):
     """[B, H, W, 3] raw RGB in 0..255 -> list of per-stage edge logit maps
     (each [B, H/2^i, W/2^i, 1])."""
 

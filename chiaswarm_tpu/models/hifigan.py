@@ -17,6 +17,8 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams
+
 
 @dataclasses.dataclass(frozen=True)
 class HifiGanConfig:
@@ -55,12 +57,12 @@ class _ResBlock(nn.Module):
     def __call__(self, x):
         for i, d in enumerate(self.dilations):
             h = nn.leaky_relu(x, self.slope)
-            h = nn.Conv(
+            h = Conv(
                 self.channels, (self.kernel_size,), kernel_dilation=(d,),
                 dtype=self.dtype, name=f"convs1_{i}",
             )(h)
             h = nn.leaky_relu(h, self.slope)
-            h = nn.Conv(
+            h = Conv(
                 self.channels, (self.kernel_size,), dtype=self.dtype,
                 name=f"convs2_{i}",
             )(h)
@@ -68,7 +70,7 @@ class _ResBlock(nn.Module):
         return x
 
 
-class HifiGanGenerator(nn.Module):
+class HifiGanGenerator(DeclaredParams, nn.Module):
     config: HifiGanConfig
     dtype: jnp.dtype = jnp.float32
 
@@ -84,7 +86,7 @@ class HifiGanGenerator(nn.Module):
                 "scale", nn.initializers.ones, (cfg.model_in_dim,)
             )
             mel = (mel - mean) / scale
-        x = nn.Conv(
+        x = Conv(
             cfg.upsample_initial_channel, (7,), dtype=self.dtype,
             name="conv_pre",
         )(mel.astype(self.dtype))
@@ -117,5 +119,5 @@ class HifiGanGenerator(nn.Module):
                 acc = r if acc is None else acc + r
             x = acc / n_kernels
         x = nn.leaky_relu(x, cfg.leaky_relu_slope)
-        x = nn.Conv(1, (7,), dtype=self.dtype, name="conv_post")(x)
+        x = Conv(1, (7,), dtype=self.dtype, name="conv_post")(x)
         return jnp.tanh(x)[..., 0]

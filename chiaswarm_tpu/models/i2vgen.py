@@ -25,7 +25,13 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .layers import TimestepEmbedding, timestep_embedding
+from .layers import (
+    Conv,
+    Dense,
+    LayerNorm,
+    TimestepEmbedding,
+    timestep_embedding,
+)
 from .unet3d import UNet3DConfig, unet3d_backbone
 
 
@@ -104,13 +110,13 @@ class _TemporalEncoder(nn.Module):
     def __call__(self, tokens):
         b, f, d = tokens.shape
         head_dim = max(1, self.dim // self.heads)
-        h = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(tokens)
-        q = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
-                     name="attn1_to_q")(h)
-        k = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
-                     name="attn1_to_k")(h)
-        v = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
-                     name="attn1_to_v")(h)
+        h = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(tokens)
+        q = Dense(self.dim, use_bias=False, dtype=self.dtype,
+                  name="attn1_to_q")(h)
+        k = Dense(self.dim, use_bias=False, dtype=self.dtype,
+                  name="attn1_to_k")(h)
+        v = Dense(self.dim, use_bias=False, dtype=self.dtype,
+                  name="attn1_to_v")(h)
         q = q.reshape(b, f, self.heads, head_dim)
         k = k.reshape(b, f, self.heads, head_dim)
         v = v.reshape(b, f, self.heads, head_dim)
@@ -119,14 +125,14 @@ class _TemporalEncoder(nn.Module):
         attn = jnp.einsum(
             "bhqk,bkhd->bqhd", weights.astype(self.dtype), v
         ).reshape(b, f, self.dim)
-        attn = nn.Dense(self.dim, dtype=self.dtype, name="attn1_to_out_0")(
+        attn = Dense(self.dim, dtype=self.dtype, name="attn1_to_out_0")(
             attn
         )
         tokens = tokens + attn
-        ff = nn.Dense(4 * self.dim, dtype=self.dtype,
-                      name="ff_net_0_proj")(tokens)
+        ff = Dense(4 * self.dim, dtype=self.dtype,
+                   name="ff_net_0_proj")(tokens)
         ff = nn.gelu(ff, approximate=False)
-        ff = nn.Dense(self.dim, dtype=self.dtype, name="ff_net_2")(ff)
+        ff = Dense(self.dim, dtype=self.dtype, name="ff_net_2")(ff)
         return tokens + ff
 
 
@@ -164,28 +170,28 @@ class I2VGenXLUNet(nn.Module):
         first = image_latents.reshape(
             b, num_frames, *image_latents.shape[1:]
         )[:, 0]
-        y = nn.Conv(
+        y = Conv(
             8 * cfg.in_channels, (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="image_latents_context_embedding_0",
         )(jnp.asarray(first, self.dtype))
         y = adaptive_avg_pool(nn.silu(y), 32)
-        y = nn.Conv(
+        y = Conv(
             16 * cfg.in_channels, (3, 3), strides=(2, 2),
             padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="image_latents_context_embedding_3",
         )(y)
-        y = nn.Conv(
+        y = Conv(
             cfg.cross_attention_dim, (3, 3), strides=(2, 2),
             padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="image_latents_context_embedding_5",
         )(nn.silu(y))
         latent_tokens = y.reshape(b, -1, cfg.cross_attention_dim)
 
-        img = nn.Dense(temb_dim, dtype=self.dtype,
-                       name="context_embedding_0")(
+        img = Dense(temb_dim, dtype=self.dtype,
+                    name="context_embedding_0")(
             jnp.asarray(image_embeddings, self.dtype)
         )
-        img = nn.Dense(
+        img = Dense(
             cfg.in_channels * cfg.cross_attention_dim, dtype=self.dtype,
             name="context_embedding_2",
         )(nn.silu(img))
@@ -202,15 +208,15 @@ class I2VGenXLUNet(nn.Module):
         ctx = jnp.repeat(ctx, num_frames, axis=0)  # [B*F, S+HW/16+C, D]
 
         # per-frame image-latents stream -> channel concat with the noise
-        il = nn.Conv(
+        il = Conv(
             4 * cfg.in_channels, (1, 1), dtype=self.dtype,
             name="image_latents_proj_in_0",
         )(jnp.asarray(image_latents, self.dtype))
-        il = nn.Conv(
+        il = Conv(
             4 * cfg.in_channels, (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="image_latents_proj_in_2",
         )(nn.silu(il))
-        il = nn.Conv(
+        il = Conv(
             cfg.in_channels, (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="image_latents_proj_in_4",
         )(nn.silu(il))

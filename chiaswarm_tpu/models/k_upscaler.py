@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .layers import Conv, DeclaredParams, Dense, LayerNorm
+
 
 @dataclasses.dataclass(frozen=True)
 class KUpscalerConfig:
@@ -121,7 +123,7 @@ class AdaGroupNorm(nn.Module):
     @nn.compact
     def __call__(self, x, temb):
         c = x.shape[-1]
-        emb = nn.Dense(2 * c, dtype=self.dtype, name="linear")(temb)
+        emb = Dense(2 * c, dtype=self.dtype, name="linear")(temb)
         scale, shift = jnp.split(emb[:, None, None, :], 2, axis=-1)
         x = nn.GroupNorm(
             self.groups, epsilon=1e-5, use_bias=False, use_scale=False,
@@ -145,7 +147,7 @@ class KResnetBlock(nn.Module):
             max(1, in_ch // self.group_size), dtype=self.dtype, name="norm1"
         )(x, temb)
         h = nn.gelu(h, approximate=False)
-        h = nn.Conv(
+        h = Conv(
             self.out_channels, (3, 3), dtype=self.dtype, name="conv1"
         )(h)
         h = AdaGroupNorm(
@@ -153,11 +155,11 @@ class KResnetBlock(nn.Module):
             name="norm2",
         )(h, temb)
         h = nn.gelu(h, approximate=False)
-        h = nn.Conv(
+        h = Conv(
             self.out_channels, (3, 3), dtype=self.dtype, name="conv2"
         )(h)
         if in_ch != self.out_channels:
-            x = nn.Conv(
+            x = Conv(
                 self.out_channels, (1, 1), use_bias=False, dtype=self.dtype,
                 name="conv_shortcut",
             )(x)
@@ -180,16 +182,16 @@ class KAttention(nn.Module):
         dim = self.inner // heads
         b, n, _ = q_in.shape
         if self.cross_norm:
-            kv_in = nn.LayerNorm(
+            kv_in = LayerNorm(
                 epsilon=1e-5, dtype=self.dtype, name="norm_cross"
             )(kv_in)
         s = kv_in.shape[1]
-        q = nn.Dense(self.inner, use_bias=self.use_bias, dtype=self.dtype,
-                     name="to_q")(q_in)
-        k = nn.Dense(self.inner, use_bias=self.use_bias, dtype=self.dtype,
-                     name="to_k")(kv_in)
-        v = nn.Dense(self.inner, use_bias=self.use_bias, dtype=self.dtype,
-                     name="to_v")(kv_in)
+        q = Dense(self.inner, use_bias=self.use_bias, dtype=self.dtype,
+                  name="to_q")(q_in)
+        k = Dense(self.inner, use_bias=self.use_bias, dtype=self.dtype,
+                  name="to_k")(kv_in)
+        v = Dense(self.inner, use_bias=self.use_bias, dtype=self.dtype,
+                  name="to_v")(kv_in)
         q = q.reshape(b, n, heads, dim)
         k = k.reshape(b, s, heads, dim)
         v = v.reshape(b, s, heads, dim)
@@ -200,7 +202,7 @@ class KAttention(nn.Module):
         out = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(
             b, n, self.inner
         )
-        return nn.Dense(self.inner, dtype=self.dtype, name="to_out_0")(out)
+        return Dense(self.inner, dtype=self.dtype, name="to_out_0")(out)
 
 
 class KAttentionBlock(nn.Module):
@@ -236,7 +238,7 @@ class KAttentionBlock(nn.Module):
         return x + attn.reshape(b, h, w, c)
 
 
-class KUpscalerUNet(nn.Module):
+class KUpscalerUNet(DeclaredParams, nn.Module):
     """[B,H,W,8] (noise latents + conditioning latents) + [B] continuous
     timesteps (log(sigma)/4) + [B,S,cross] CLIP states + [B,896]
     timestep_cond -> [B,H,W,out_channels]."""
@@ -264,21 +266,21 @@ class KUpscalerUNet(nn.Module):
         t_emb = jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
         t_emb = t_emb.astype(self.dtype)
         # TimestepEmbedding with cond_proj + gelu act AND post-act
-        t_emb = t_emb + nn.Dense(
+        t_emb = t_emb + Dense(
             2 * c0, use_bias=False, dtype=self.dtype,
             name="time_embedding_cond_proj",
         )(jnp.asarray(timestep_cond, self.dtype))
-        t_emb = nn.Dense(
+        t_emb = Dense(
             2 * c0, dtype=self.dtype, name="time_embedding_linear_1"
         )(t_emb)
         t_emb = nn.gelu(t_emb, approximate=False)
-        t_emb = nn.Dense(
+        t_emb = Dense(
             2 * c0, dtype=self.dtype, name="time_embedding_linear_2"
         )(t_emb)
         temb = nn.gelu(t_emb, approximate=False)
 
         context = jnp.asarray(encoder_hidden_states, self.dtype)
-        x = nn.Conv(
+        x = Conv(
             c0, (1, 1), dtype=self.dtype, name="conv_in"
         )(jnp.asarray(sample, self.dtype))
 
@@ -326,6 +328,6 @@ class KUpscalerUNet(nn.Module):
             if lvl != n - 1:
                 x = KUpsample2D(dtype=self.dtype)(x)
 
-        return nn.Conv(
+        return Conv(
             cfg.out_channels, (1, 1), dtype=self.dtype, name="conv_out"
         )(x)

@@ -22,6 +22,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams
+
 
 @dataclasses.dataclass(frozen=True)
 class LineartConfig:
@@ -42,11 +44,11 @@ def instance_norm(x, eps: float = 1e-5):
 
 def _reflect_conv(x, features, kernel, pad, dtype, name):
     x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode="reflect")
-    return nn.Conv(features, (kernel, kernel), padding="VALID",
-                   dtype=dtype, name=name)(x)
+    return Conv(features, (kernel, kernel), padding="VALID",
+                dtype=dtype, name=name)(x)
 
 
-class _UpConv(nn.Module):
+class _UpConv(DeclaredParams, nn.Module):
     """ConvTranspose2d(3, stride=2, padding=1, output_padding=1) as an
     input-dilated conv; the kernel arrives pre-flipped from conversion."""
 
@@ -82,13 +84,13 @@ class LineartGenerator(nn.Module):
         x = jnp.asarray(x, self.dtype)
         x = _reflect_conv(x, c, 7, 3, self.dtype, "model0_conv")
         x = nn.relu(instance_norm(x))
-        x = nn.Conv(2 * c, (3, 3), strides=(2, 2),
-                    padding=((1, 1), (1, 1)), dtype=self.dtype,
-                    name="model1_conv0")(x)
+        x = Conv(2 * c, (3, 3), strides=(2, 2),
+                 padding=((1, 1), (1, 1)), dtype=self.dtype,
+                 name="model1_conv0")(x)
         x = nn.relu(instance_norm(x))
-        x = nn.Conv(4 * c, (3, 3), strides=(2, 2),
-                    padding=((1, 1), (1, 1)), dtype=self.dtype,
-                    name="model1_conv1")(x)
+        x = Conv(4 * c, (3, 3), strides=(2, 2),
+                 padding=((1, 1), (1, 1)), dtype=self.dtype,
+                 name="model1_conv1")(x)
         x = nn.relu(instance_norm(x))
         for i in range(cfg.n_residual_blocks):
             h = _reflect_conv(x, 4 * c, 3, 1, self.dtype,

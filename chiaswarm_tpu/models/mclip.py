@@ -20,6 +20,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from .clap import ClapTextConfig, _Layer
+from .layers import Dense, Embed, LayerNorm
 
 # xlm-roberta-large geometry; serving reads the checkpoint config.json
 MCLIP_XLMR_LARGE = ClapTextConfig(
@@ -62,16 +63,16 @@ class MCLIPTextEncoder(nn.Module):
             + cfg.pad_token_id
         )
         x = (
-            nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
-                     name="word_embeddings")(input_ids)
-            + nn.Embed(cfg.max_positions, cfg.hidden_size, dtype=self.dtype,
-                       name="position_embeddings")(positions)
-            + nn.Embed(cfg.type_vocab_size, cfg.hidden_size, dtype=self.dtype,
-                       name="token_type_embeddings")(
+            Embed(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
+                  name="word_embeddings")(input_ids)
+            + Embed(cfg.max_positions, cfg.hidden_size, dtype=self.dtype,
+                    name="position_embeddings")(positions)
+            + Embed(cfg.type_vocab_size, cfg.hidden_size, dtype=self.dtype,
+                    name="token_type_embeddings")(
                 jnp.zeros_like(input_ids))
         )
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
-                         name="embed_norm")(x)
+        x = LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
+                      name="embed_norm")(x)
         for i in range(cfg.num_layers):
             x = _Layer(cfg, dtype=self.dtype, name=f"layers_{i}")(
                 x, attention_mask
@@ -80,6 +81,6 @@ class MCLIPTextEncoder(nn.Module):
         pooled = (x * attention_mask[..., None]).sum(axis=1) / denom.astype(
             x.dtype
         )
-        proj = nn.Dense(cfg.projection_dim, dtype=self.dtype,
-                        name="transformation")(pooled)
+        proj = Dense(cfg.projection_dim, dtype=self.dtype,
+                     name="transformation")(pooled)
         return {"hidden_states": x, "pooled_proj": proj}

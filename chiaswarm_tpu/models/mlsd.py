@@ -23,6 +23,8 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .layers import Conv
+
 
 # MobileNetV2 inverted-residual plan the MLSD trunk uses: (t, c, n, s)
 MBV2_SETTING = ((1, 16, 1, 1), (6, 24, 2, 2), (6, 32, 3, 2),
@@ -61,7 +63,7 @@ class _ConvRelu6(nn.Module):
     @nn.compact
     def __call__(self, x):
         pad = (self.kernel - 1) // 2
-        x = nn.Conv(
+        x = Conv(
             self.features, (self.kernel, self.kernel),
             strides=(self.stride, self.stride),
             padding=((pad, pad), (pad, pad)),
@@ -89,8 +91,8 @@ class _InvertedResidual(nn.Module):
             hidden, kernel=3, stride=self.stride, groups=hidden,
             dtype=self.dtype, name="depthwise",
         )(h)
-        h = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype,
-                    name="project")(h)
+        h = Conv(self.out_channels, (1, 1), dtype=self.dtype,
+                 name="project")(h)
         if self.stride == 1 and in_ch == self.out_channels:
             h = x + h
         return h
@@ -106,11 +108,11 @@ class _BlockA(nn.Module):
 
     @nn.compact
     def __call__(self, lateral, carried):
-        b = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype,
-                    name="conv1")(carried)
+        b = Conv(self.out_channels, (1, 1), dtype=self.dtype,
+                 name="conv1")(carried)
         b = nn.relu(b)
-        a = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype,
-                    name="conv2")(lateral)
+        a = Conv(self.out_channels, (1, 1), dtype=self.dtype,
+                 name="conv2")(lateral)
         a = nn.relu(a)
         if self.upscale:
             b = resize_align_corners_2x(b)
@@ -125,11 +127,11 @@ class _BlockB(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        h = nn.Conv(x.shape[-1], (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv1")(x)
+        h = Conv(x.shape[-1], (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv1")(x)
         x = nn.relu(h) + x
-        x = nn.Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv2")(x)
+        x = Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv2")(x)
         return nn.relu(x)
 
 
@@ -142,15 +144,15 @@ class _BlockC(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = x.shape[-1]
-        x = nn.Conv(c, (3, 3), padding=((5, 5), (5, 5)),
-                    kernel_dilation=(5, 5), dtype=self.dtype,
-                    name="conv1")(x)
+        x = Conv(c, (3, 3), padding=((5, 5), (5, 5)),
+                 kernel_dilation=(5, 5), dtype=self.dtype,
+                 name="conv1")(x)
         x = nn.relu(x)
-        x = nn.Conv(c, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
-                    name="conv2")(x)
+        x = Conv(c, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
+                 name="conv2")(x)
         x = nn.relu(x)
-        return nn.Conv(self.out_channels, (1, 1), dtype=self.dtype,
-                       name="conv3")(x)
+        return Conv(self.out_channels, (1, 1), dtype=self.dtype,
+                    name="conv3")(x)
 
 
 class MLSDNet(nn.Module):

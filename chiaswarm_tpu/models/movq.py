@@ -24,7 +24,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .layers import FusedGroupNorm
+from .layers import Conv, Dense, FusedGroupNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,8 +85,8 @@ class SpatialNorm(nn.Module):
         )
         norm = FusedGroupNorm(self.groups, epsilon=1e-6, dtype=self.dtype,
                               name="norm_layer")(f)
-        y = nn.Conv(self.channels, (1, 1), dtype=self.dtype, name="conv_y")(zq)
-        bb = nn.Conv(self.channels, (1, 1), dtype=self.dtype, name="conv_b")(zq)
+        y = Conv(self.channels, (1, 1), dtype=self.dtype, name="conv_y")(zq)
+        bb = Conv(self.channels, (1, 1), dtype=self.dtype, name="conv_b")(zq)
         return norm * y + bb
 
 
@@ -109,14 +109,14 @@ class VQResnet(nn.Module):
                                   name=name)(h)
 
         h = nn.silu(norm("norm1", x))
-        h = nn.Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv1")(h)
+        h = Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv1")(h)
         h = nn.silu(norm("norm2", h))
-        h = nn.Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv2")(h)
+        h = Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv2")(h)
         if x.shape[-1] != self.out_channels:
-            x = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype,
-                        name="conv_shortcut")(x)
+            x = Conv(self.out_channels, (1, 1), dtype=self.dtype,
+                     name="conv_shortcut")(x)
         return x + h
 
 
@@ -138,15 +138,15 @@ class VQAttention(nn.Module):
             norm = FusedGroupNorm(self.groups, epsilon=1e-6, dtype=self.dtype,
                                   name="group_norm")(x)
         tokens = norm.reshape(b, h * w, c)
-        q = nn.Dense(c, dtype=self.dtype, name="to_q")(tokens)
-        k = nn.Dense(c, dtype=self.dtype, name="to_k")(tokens)
-        v = nn.Dense(c, dtype=self.dtype, name="to_v")(tokens)
+        q = Dense(c, dtype=self.dtype, name="to_q")(tokens)
+        k = Dense(c, dtype=self.dtype, name="to_k")(tokens)
+        v = Dense(c, dtype=self.dtype, name="to_v")(tokens)
         from ..ops import dot_product_attention
 
         out = dot_product_attention(
             q[:, :, None, :], k[:, :, None, :], v[:, :, None, :]
         )[:, :, 0, :]
-        out = nn.Dense(c, dtype=self.dtype, name="to_out_0")(out)
+        out = Dense(c, dtype=self.dtype, name="to_out_0")(out)
         return x + out.reshape(b, h, w, c)
 
 
@@ -158,9 +158,9 @@ class MoVQEncoder(nn.Module):
     def __call__(self, pixels):
         cfg = self.config
         g = cfg.norm_num_groups
-        x = nn.Conv(cfg.block_out_channels[0], (3, 3),
-                    padding=((1, 1), (1, 1)), dtype=self.dtype,
-                    name="conv_in")(pixels)
+        x = Conv(cfg.block_out_channels[0], (3, 3),
+                 padding=((1, 1), (1, 1)), dtype=self.dtype,
+                 name="conv_in")(pixels)
         for b, out_ch in enumerate(cfg.block_out_channels):
             for i in range(cfg.layers_per_block):
                 x = VQResnet(out_ch, groups=g, dtype=self.dtype,
@@ -168,7 +168,7 @@ class MoVQEncoder(nn.Module):
             if b != len(cfg.block_out_channels) - 1:
                 # Downsample2D(use_conv=True): asymmetric (0,1) pad, stride 2
                 x = jnp.pad(x, ((0, 0), (0, 1), (0, 1), (0, 0)))
-                x = nn.Conv(
+                x = Conv(
                     out_ch, (3, 3), strides=(2, 2), padding="VALID",
                     dtype=self.dtype,
                     name=f"down_blocks_{b}_downsamplers_0_conv",
@@ -182,8 +182,8 @@ class MoVQEncoder(nn.Module):
                      name="mid_block_resnets_1")(x)
         x = FusedGroupNorm(g, epsilon=1e-6, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        return nn.Conv(cfg.latent_channels, (3, 3), padding=((1, 1), (1, 1)),
-                       dtype=self.dtype, name="conv_out")(x)
+        return Conv(cfg.latent_channels, (3, 3), padding=((1, 1), (1, 1)),
+                    dtype=self.dtype, name="conv_out")(x)
 
 
 class MoVQDecoder(nn.Module):
@@ -198,8 +198,8 @@ class MoVQDecoder(nn.Module):
         g = cfg.norm_num_groups
         rev = tuple(reversed(cfg.block_out_channels))
         ch = rev[0]
-        x = nn.Conv(ch, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
-                    name="conv_in")(x)
+        x = Conv(ch, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
+                 name="conv_in")(x)
         x = VQResnet(ch, groups=g, spatial=True, dtype=self.dtype,
                      name="mid_block_resnets_0")(x, zq)
         x = VQAttention(ch, groups=g, spatial=True, dtype=self.dtype,
@@ -212,7 +212,7 @@ class MoVQDecoder(nn.Module):
                              name=f"up_blocks_{b}_resnets_{i}")(x, zq)
             if b != len(rev) - 1:
                 x = jnp.repeat(jnp.repeat(x, 2, axis=1), 2, axis=2)
-                x = nn.Conv(
+                x = Conv(
                     out_ch, (3, 3), padding=((1, 1), (1, 1)),
                     dtype=self.dtype,
                     name=f"up_blocks_{b}_upsamplers_0_conv",
@@ -220,8 +220,8 @@ class MoVQDecoder(nn.Module):
         x = SpatialNorm(rev[-1], groups=g, dtype=self.dtype,
                         name="conv_norm_out")(x, zq)
         x = nn.silu(x)
-        return nn.Conv(cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                       dtype=self.dtype, name="conv_out")(x)
+        return Conv(cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                    dtype=self.dtype, name="conv_out")(x)
 
 
 class MoVQ(nn.Module):
@@ -235,10 +235,10 @@ class MoVQ(nn.Module):
     def setup(self):
         self.encoder = MoVQEncoder(self.config, dtype=self.dtype)
         self.decoder = MoVQDecoder(self.config, dtype=self.dtype)
-        self.quant_conv = nn.Conv(self.config.vq_embed_dim, (1, 1),
-                                  dtype=self.dtype)
-        self.post_quant_conv = nn.Conv(self.config.latent_channels, (1, 1),
-                                       dtype=self.dtype)
+        self.quant_conv = Conv(self.config.vq_embed_dim, (1, 1),
+                               dtype=self.dtype)
+        self.post_quant_conv = Conv(self.config.latent_channels, (1, 1),
+                                    dtype=self.dtype)
 
     def __call__(self, pixels):
         return self.decode(self.encode(pixels))

@@ -21,6 +21,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from .cascade_unet import ConvTransposed2D, pixel_shuffle
+from .layers import Conv, DeclaredParams, Dense, LayerNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,7 +45,7 @@ TINY_PAELLA_VQ = PaellaVQConfig(
 )
 
 
-class MixingResidualBlock(nn.Module):
+class MixingResidualBlock(DeclaredParams, nn.Module):
     """LN-modulated depthwise (edge-padded 3x3) + channel MLP, with six
     learned per-block gammas gating each branch (Paella block)."""
 
@@ -57,13 +58,13 @@ class MixingResidualBlock(nn.Module):
         mods = self.param("gammas", nn.initializers.zeros, (6,)).astype(x.dtype)
 
         def ln(v):
-            return nn.LayerNorm(
+            return LayerNorm(
                 epsilon=1e-6, use_scale=False, use_bias=False, dtype=self.dtype
             )(v)
 
         h = ln(x) * (1 + mods[0]) + mods[1]
         h = jnp.pad(h, ((0, 0), (1, 1), (1, 1), (0, 0)), mode="edge")
-        h = nn.Conv(
+        h = Conv(
             self.channels,
             (3, 3),
             padding="VALID",
@@ -73,9 +74,9 @@ class MixingResidualBlock(nn.Module):
         )(h)
         x = x + h * mods[2]
         h = ln(x) * (1 + mods[3]) + mods[4]
-        h = nn.Dense(self.embed_dim, dtype=self.dtype, name="channelwise_0")(h)
+        h = Dense(self.embed_dim, dtype=self.dtype, name="channelwise_0")(h)
         h = nn.gelu(h, approximate=False)
-        h = nn.Dense(self.channels, dtype=self.dtype, name="channelwise_2")(h)
+        h = Dense(self.channels, dtype=self.dtype, name="channelwise_2")(h)
         return x + h * mods[5]
 
 
@@ -91,7 +92,7 @@ class PaellaVQDecoder(nn.Module):
         c_levels = cfg.c_levels()
         x = latents.astype(self.dtype)
         idx = 0
-        x = nn.Conv(
+        x = Conv(
             c_levels[-1], (1, 1), dtype=self.dtype, name=f"up_blocks_{idx}_0"
         )(x)
         idx += 1
@@ -112,7 +113,7 @@ class PaellaVQDecoder(nn.Module):
                     name=f"up_blocks_{idx}",
                 )(x)
                 idx += 1
-        x = nn.Conv(
+        x = Conv(
             cfg.out_channels * cfg.up_down_scale_factor**2,
             (1, 1),
             dtype=self.dtype,

@@ -22,6 +22,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Conv
+
 # the released table5_pidinet config: (cd, ad, rd, cv) per stage
 CARV4 = ("cd", "ad", "rd", "cv") * 4
 STAGE_PLANES = (60, 120, 240, 240)
@@ -44,18 +46,18 @@ class _PDCBlock(nn.Module):
             x = nn.max_pool(x, (2, 2), strides=(2, 2))
         k = 5 if self.pdc == "rd" else 3
         p = k // 2
-        y = nn.Conv(
+        y = Conv(
             in_ch, (k, k), padding=((p, p), (p, p)),
             feature_group_count=in_ch, use_bias=False, dtype=self.dtype,
             name="conv1",
         )(x)
         y = nn.relu(y)
-        y = nn.Conv(
+        y = Conv(
             self.out_channels, (1, 1), use_bias=False, dtype=self.dtype,
             name="conv2",
         )(y)
         if self.stride > 1:
-            x = nn.Conv(
+            x = Conv(
                 self.out_channels, (1, 1), dtype=self.dtype, name="shortcut"
             )(x)
         return y + x
@@ -71,11 +73,11 @@ class _CDCM(nn.Module):
     @nn.compact
     def __call__(self, x):
         x = nn.relu(x)
-        x = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype,
-                    name="conv1")(x)
+        x = Conv(self.out_channels, (1, 1), dtype=self.dtype,
+                 name="conv1")(x)
         out = 0
         for i, d in enumerate((5, 7, 9, 11)):
-            out = out + nn.Conv(
+            out = out + Conv(
                 self.out_channels, (3, 3), padding=((d, d), (d, d)),
                 kernel_dilation=(d, d), use_bias=False, dtype=self.dtype,
                 name=f"conv2_{i + 1}",
@@ -91,9 +93,9 @@ class _CSAM(nn.Module):
     @nn.compact
     def __call__(self, x):
         y = nn.relu(x)
-        y = nn.Conv(4, (1, 1), dtype=self.dtype, name="conv1")(y)
-        y = nn.Conv(1, (3, 3), padding=((1, 1), (1, 1)), use_bias=False,
-                    dtype=self.dtype, name="conv2")(y)
+        y = Conv(4, (1, 1), dtype=self.dtype, name="conv1")(y)
+        y = Conv(1, (3, 3), padding=((1, 1), (1, 1)), use_bias=False,
+                 dtype=self.dtype, name="conv2")(y)
         return x * nn.sigmoid(y)
 
 
@@ -106,7 +108,7 @@ class PiDiNet(nn.Module):
     @nn.compact
     def __call__(self, x):
         b, h, w, _ = x.shape
-        x = nn.Conv(
+        x = Conv(
             STAGE_PLANES[0], (3, 3), padding=((1, 1), (1, 1)),
             use_bias=False, dtype=self.dtype, name="init_block",
         )(jnp.asarray(x, self.dtype))
@@ -130,14 +132,14 @@ class PiDiNet(nn.Module):
         for i, xi in enumerate(stage_outs):
             y = _CDCM(DIL, dtype=self.dtype, name=f"dilations_{i}")(xi)
             y = _CSAM(dtype=self.dtype, name=f"attentions_{i}")(y)
-            y = nn.Conv(1, (1, 1), dtype=self.dtype,
-                        name=f"conv_reduces_{i}")(y)
+            y = Conv(1, (1, 1), dtype=self.dtype,
+                     name=f"conv_reduces_{i}")(y)
             logits.append(
                 jax.image.resize(
                     y.astype(jnp.float32), (b, h, w, 1), "bilinear"
                 )
             )
-        fused = nn.Conv(1, (1, 1), dtype=self.dtype, name="classifier")(
+        fused = Conv(1, (1, 1), dtype=self.dtype, name="classifier")(
             jnp.concatenate(logits, axis=-1).astype(self.dtype)
         )
         return nn.sigmoid(fused.astype(jnp.float32))

@@ -17,6 +17,8 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .layers import Conv
+
 # COCO-18 keypoint scheme (the openpose body model's output order)
 N_KEYPOINTS = 18
 # limb connectivity for skeleton rendering (keypoint index pairs)
@@ -44,8 +46,8 @@ class _ResBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        h = nn.relu(nn.Conv(self.width, (3, 3), dtype=self.dtype)(x))
-        h = nn.Conv(self.width, (3, 3), dtype=self.dtype)(h)
+        h = nn.relu(Conv(self.width, (3, 3), dtype=self.dtype)(x))
+        h = Conv(self.width, (3, 3), dtype=self.dtype)(h)
         return nn.relu(x + h)
 
 
@@ -60,11 +62,11 @@ class PoseNet(nn.Module):
         x = pixels
         for w in self.config.widths:
             x = nn.relu(
-                nn.Conv(w, (3, 3), strides=(2, 2), dtype=self.dtype)(x)
+                Conv(w, (3, 3), strides=(2, 2), dtype=self.dtype)(x)
             )
         for _ in range(self.config.trunk_blocks):
             x = _ResBlock(self.config.widths[-1], dtype=self.dtype)(x)
-        heat = nn.Conv(
+        heat = Conv(
             self.config.n_keypoints, (1, 1), dtype=self.dtype, name="heatmaps"
         )(x)
         return nn.sigmoid(heat)
@@ -121,7 +123,7 @@ class OpenposeBody(nn.Module):
                 for kind, args in outer:
                     if kind == "conv":
                         name, ch, k = args
-                        x = nn.Conv(
+                        x = Conv(
                             ch, (k, k),
                             padding=((k // 2, k // 2), (k // 2, k // 2)),
                             dtype=self.dtype, name=name,

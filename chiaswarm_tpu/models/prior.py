@@ -24,7 +24,13 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .layers import TimestepEmbedding, timestep_embedding
+from .layers import (
+    DeclaredParams,
+    Dense,
+    LayerNorm,
+    TimestepEmbedding,
+    timestep_embedding,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,8 +68,8 @@ class PriorBlock(nn.Module):
         h, hd = cfg.num_heads, cfg.head_dim
         inner = cfg.hidden_size
         b, s, _ = x.shape
-        y = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(x)
-        proj = lambda name: nn.Dense(inner, dtype=self.dtype, name=name)(
+        y = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(x)
+        proj = lambda name: Dense(inner, dtype=self.dtype, name=name)(
             y
         ).reshape(b, s, h, hd)
         q, k, v = proj("to_q"), proj("to_k"), proj("to_v")
@@ -72,14 +78,14 @@ class PriorBlock(nn.Module):
             logits = logits + mask
         w = nn.softmax(logits.astype(jnp.float32), axis=-1).astype(self.dtype)
         attn = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(b, s, inner)
-        x = x + nn.Dense(inner, dtype=self.dtype, name="to_out_0")(attn)
-        y = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm3")(x)
-        y = nn.Dense(4 * inner, dtype=self.dtype, name="ff_proj")(y)
+        x = x + Dense(inner, dtype=self.dtype, name="to_out_0")(attn)
+        y = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm3")(x)
+        y = Dense(4 * inner, dtype=self.dtype, name="ff_proj")(y)
         y = nn.gelu(y, approximate=False)
-        return x + nn.Dense(inner, dtype=self.dtype, name="ff_out")(y)
+        return x + Dense(inner, dtype=self.dtype, name="ff_out")(y)
 
 
-class DiffusionPrior(nn.Module):
+class DiffusionPrior(DeclaredParams, nn.Module):
     config: PriorConfig
     dtype: jnp.dtype = jnp.float32
 
@@ -98,15 +104,15 @@ class DiffusionPrior(nn.Module):
         time_tok = TimestepEmbedding(inner, dtype=self.dtype,
                                      name="time_embedding")(t_feat)
         tokens = [
-            nn.Dense(inner, dtype=self.dtype,
-                     name="encoder_hidden_states_proj")(
+            Dense(inner, dtype=self.dtype,
+                  name="encoder_hidden_states_proj")(
                 text_hiddens.astype(self.dtype)
             ),
-            nn.Dense(inner, dtype=self.dtype, name="embed_proj")(
+            Dense(inner, dtype=self.dtype, name="embed_proj")(
                 text_embed.astype(self.dtype)
             )[:, None],
             time_tok[:, None],
-            nn.Dense(inner, dtype=self.dtype, name="proj_in")(
+            Dense(inner, dtype=self.dtype, name="proj_in")(
                 noisy_embed.astype(self.dtype)
             )[:, None],
             jnp.broadcast_to(
@@ -139,7 +145,7 @@ class DiffusionPrior(nn.Module):
         for i in range(cfg.num_layers):
             x = PriorBlock(cfg, dtype=self.dtype,
                            name=f"transformer_blocks_{i}")(x, mask)
-        x = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm_out")(x)
+        x = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm_out")(x)
         # the learned prd token carries the prediction
-        return nn.Dense(cfg.embed_dim, dtype=self.dtype,
-                        name="proj_to_clip_embeddings")(x[:, -1])
+        return Dense(cfg.embed_dim, dtype=self.dtype,
+                     name="proj_to_clip_embeddings")(x[:, -1])

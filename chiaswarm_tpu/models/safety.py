@@ -20,6 +20,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from .clip import _act
+from .layers import Conv, DeclaredParams, Dense, LayerNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,7 +44,7 @@ TINY_SAFETY = SafetyConfig(
 )
 
 
-class CLIPVisionEncoder(nn.Module):
+class CLIPVisionEncoder(DeclaredParams, nn.Module):
     config: SafetyConfig
     dtype: jnp.dtype = jnp.float32
 
@@ -51,7 +52,7 @@ class CLIPVisionEncoder(nn.Module):
     def __call__(self, pixels):
         """[B, H, W, 3] normalized -> projected image embeds [B, P]."""
         cfg = self.config
-        x = nn.Conv(
+        x = Conv(
             cfg.hidden_size, (cfg.patch_size, cfg.patch_size),
             strides=(cfg.patch_size, cfg.patch_size), use_bias=False,
             dtype=self.dtype, name="patch_embed",
@@ -70,36 +71,36 @@ class CLIPVisionEncoder(nn.Module):
             (gh * gw + 1, cfg.hidden_size),
         ).astype(self.dtype)
         x = x + pos[None]
-        x = nn.LayerNorm(dtype=self.dtype, name="pre_ln")(x)
+        x = LayerNorm(dtype=self.dtype, name="pre_ln")(x)
         hd = cfg.hidden_size // cfg.num_heads
         for i in range(cfg.num_layers):
             blk = f"layer_{i}"
-            y = nn.LayerNorm(dtype=self.dtype, name=f"{blk}_ln1")(x)
-            q = nn.Dense(cfg.hidden_size, dtype=self.dtype, name=f"{blk}_q")(y)
-            k = nn.Dense(cfg.hidden_size, dtype=self.dtype, name=f"{blk}_k")(y)
-            v = nn.Dense(cfg.hidden_size, dtype=self.dtype, name=f"{blk}_v")(y)
+            y = LayerNorm(dtype=self.dtype, name=f"{blk}_ln1")(x)
+            q = Dense(cfg.hidden_size, dtype=self.dtype, name=f"{blk}_q")(y)
+            k = Dense(cfg.hidden_size, dtype=self.dtype, name=f"{blk}_k")(y)
+            v = Dense(cfg.hidden_size, dtype=self.dtype, name=f"{blk}_v")(y)
             s = y.shape[1]
             q, k, v = (t.reshape(b, s, cfg.num_heads, hd) for t in (q, k, v))
             from ..ops import dot_product_attention
 
             attn = dot_product_attention(q, k, v).reshape(b, s, cfg.hidden_size)
-            x = x + nn.Dense(
+            x = x + Dense(
                 cfg.hidden_size, dtype=self.dtype, name=f"{blk}_out"
             )(attn)
-            y = nn.LayerNorm(dtype=self.dtype, name=f"{blk}_ln2")(x)
-            y = nn.Dense(4 * cfg.hidden_size, dtype=self.dtype,
-                         name=f"{blk}_fc1")(y)
+            y = LayerNorm(dtype=self.dtype, name=f"{blk}_ln2")(x)
+            y = Dense(4 * cfg.hidden_size, dtype=self.dtype,
+                      name=f"{blk}_fc1")(y)
             y = _act(cfg.hidden_act)(y)
-            x = x + nn.Dense(cfg.hidden_size, dtype=self.dtype,
-                             name=f"{blk}_fc2")(y)
-        pooled = nn.LayerNorm(dtype=self.dtype, name="post_ln")(x[:, 0])
-        return nn.Dense(
+            x = x + Dense(cfg.hidden_size, dtype=self.dtype,
+                          name=f"{blk}_fc2")(y)
+        pooled = LayerNorm(dtype=self.dtype, name="post_ln")(x[:, 0])
+        return Dense(
             cfg.projection_dim, use_bias=False, dtype=self.dtype,
             name="projection",
         )(pooled)
 
 
-class SafetyChecker(nn.Module):
+class SafetyChecker(DeclaredParams, nn.Module):
     """Full checker: vision embed -> per-image NSFW boolean."""
 
     config: SafetyConfig

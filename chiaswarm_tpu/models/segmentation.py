@@ -21,6 +21,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .layers import Conv, DeclaredParams, Dense
+
 
 @dataclasses.dataclass(frozen=True)
 class UperNetConfig:
@@ -59,7 +61,7 @@ def _ln(x, scale, bias, eps):
     return (x - mean) / jnp.sqrt(var + eps) * scale + bias
 
 
-class _ChannelsLN(nn.Module):
+class _ChannelsLN(DeclaredParams, nn.Module):
     """LayerNorm over the channel axis of an NHWC map (torch's
     ConvNextLayerNorm data_format=channels_first, transposed)."""
 
@@ -77,21 +79,21 @@ class _ChannelsLN(nn.Module):
         )
 
 
-class _ConvNextLayer(nn.Module):
+class _ConvNextLayer(DeclaredParams, nn.Module):
     dim: int
     eps: float
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        h = nn.Conv(
+        h = Conv(
             self.dim, (7, 7), padding=((3, 3), (3, 3)),
             feature_group_count=self.dim, dtype=self.dtype, name="dwconv",
         )(x)
         h = _ChannelsLN(self.eps, dtype=self.dtype, name="norm")(h)
-        h = nn.Dense(4 * self.dim, dtype=self.dtype, name="pwconv1")(h)
+        h = Dense(4 * self.dim, dtype=self.dtype, name="pwconv1")(h)
         h = nn.gelu(h, approximate=False)
-        h = nn.Dense(self.dim, dtype=self.dtype, name="pwconv2")(h)
+        h = Dense(self.dim, dtype=self.dtype, name="pwconv2")(h)
         gamma = self.param(
             "layer_scale", nn.initializers.ones, (self.dim,)
         )
@@ -109,7 +111,7 @@ class _ConvRelu(nn.Module):
     def __call__(self, x):
         p = self.kernel // 2
         return nn.relu(
-            nn.Conv(
+            Conv(
                 self.channels, (self.kernel, self.kernel),
                 padding=((p, p), (p, p)), dtype=self.dtype, name="conv",
             )(x)
@@ -147,7 +149,7 @@ class UperNetSegmenter(nn.Module):
         cfg = self.config
         eps = cfg.layer_norm_eps
 
-        x = nn.Conv(
+        x = Conv(
             cfg.hidden_sizes[0], (4, 4), strides=(4, 4), dtype=self.dtype,
             name="patch_embeddings",
         )(pixels)
@@ -159,7 +161,7 @@ class UperNetSegmenter(nn.Module):
                 x = _ChannelsLN(
                     eps, dtype=self.dtype, name=f"downsample_norm_{s}"
                 )(x)
-                x = nn.Conv(
+                x = Conv(
                     dim, (2, 2), strides=(2, 2), dtype=self.dtype,
                     name=f"downsample_conv_{s}",
                 )(x)
@@ -207,7 +209,7 @@ class UperNetSegmenter(nn.Module):
         fused = _ConvRelu(
             cfg.hidden_size, 3, dtype=self.dtype, name="fpn_bottleneck"
         )(jnp.concatenate(outs, axis=-1))
-        logits = nn.Conv(
+        logits = Conv(
             cfg.num_labels, (1, 1), dtype=self.dtype, name="classifier"
         )(fused)
         return _resize(

@@ -35,8 +35,12 @@ import jax.numpy as jnp
 
 from .layers import (
     BasicTransformerBlock,
+    Conv,
+    DeclaredParams,
+    Dense,
     Downsample2D,
     FusedGroupNorm,
+    LayerNorm,
     TimestepEmbedding,
     Upsample2D,
     timestep_embedding,
@@ -71,7 +75,7 @@ TINY_SVD_UNET = SVDUNetConfig(
 )
 
 
-class AlphaBlender(nn.Module):
+class AlphaBlender(DeclaredParams, nn.Module):
     """Learned spatial/temporal mix: alpha = sigmoid(mix_factor); frames
     flagged image-only take the spatial branch outright."""
 
@@ -108,7 +112,7 @@ class TemporalResnetBlock(nn.Module):
         residual = x
         h = FusedGroupNorm(32, epsilon=self.eps, dtype=self.dtype,
                            act="silu", name="norm1")(x)
-        h = nn.Conv(
+        h = Conv(
             self.out_channels,
             (3, 1, 1),
             padding=((1, 1), (0, 0), (0, 0)),
@@ -117,13 +121,13 @@ class TemporalResnetBlock(nn.Module):
         )(h)
         if self.has_temb and temb is not None:
             # temb [B, F, C_t] -> per-frame shift
-            proj = nn.Dense(
+            proj = Dense(
                 self.out_channels, dtype=self.dtype, name="time_emb_proj"
             )(nn.silu(temb))
             h = h + proj[:, :, None, None, :]
         h = FusedGroupNorm(32, epsilon=self.eps, dtype=self.dtype,
                            act="silu", name="norm2")(h)
-        h = nn.Conv(
+        h = Conv(
             self.out_channels,
             (3, 1, 1),
             padding=((1, 1), (0, 0), (0, 0)),
@@ -131,7 +135,7 @@ class TemporalResnetBlock(nn.Module):
             name="conv2",
         )(h)
         if residual.shape[-1] != self.out_channels:
-            residual = nn.Conv(
+            residual = Conv(
                 self.out_channels, (1, 1, 1), dtype=self.dtype,
                 name="conv_shortcut",
             )(residual)
@@ -211,7 +215,7 @@ class TemporalBasicTransformerBlock(nn.Module):
         hidden = hidden.reshape(b * s, num_frames, c)
 
         residual = hidden
-        h = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm_in")(hidden)
+        h = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm_in")(hidden)
         h = FeedForward(self.dim, dtype=self.dtype, name="ff_in")(h)
         hidden = h + residual  # is_res: dim == time_mix_inner_dim in SVD
 
@@ -220,7 +224,7 @@ class TemporalBasicTransformerBlock(nn.Module):
             name="attn1",
         )
         hidden = hidden + attn(
-            nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(hidden)
+            LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(hidden)
         )
         if self.cross:
             cross_attn = Attention(
@@ -228,13 +232,13 @@ class TemporalBasicTransformerBlock(nn.Module):
                 name="attn2",
             )
             hidden = hidden + cross_attn(
-                nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm2")(
+                LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm2")(
                     hidden
                 ),
                 context,
             )
         hidden = hidden + FeedForward(self.dim, dtype=self.dtype, name="ff")(
-            nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm3")(hidden)
+            LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm3")(hidden)
         )
         hidden = hidden.reshape(b, s, num_frames, c).transpose(0, 2, 1, 3)
         return hidden.reshape(bf, s, c)
@@ -260,7 +264,7 @@ class TransformerSpatioTemporal(nn.Module):
         hidden = FusedGroupNorm(32, epsilon=1e-6, dtype=self.dtype,
                                 name="norm")(x)
         hidden = hidden.reshape(bf, hh * ww, c)
-        hidden = nn.Dense(inner, dtype=self.dtype, name="proj_in")(hidden)
+        hidden = Dense(inner, dtype=self.dtype, name="proj_in")(hidden)
 
         # frame-position embedding added before each temporal block
         frame_ids = jnp.tile(jnp.arange(num_frames), (b,))
@@ -298,7 +302,7 @@ class TransformerSpatioTemporal(nn.Module):
             hidden = _blend_tokens(
                 blender, hidden, mix, image_only_indicator, b, num_frames
             )
-        hidden = nn.Dense(c, dtype=self.dtype, name="proj_out")(hidden)
+        hidden = Dense(c, dtype=self.dtype, name="proj_out")(hidden)
         return hidden.reshape(bf, hh, ww, c) + residual
 
 
@@ -316,11 +320,11 @@ def _blend_tokens(blender, spatial, temporal, image_only_indicator, b, f):
 def _time_pos_embed(t_feat, in_channels, dtype):
     """diffusers TimestepEmbedding(in_channels, in_channels*4,
     out_dim=in_channels): asymmetric in/out widths, so inline Denses."""
-    h = nn.Dense(in_channels * 4, dtype=dtype, name="time_pos_embed_linear_1")(
+    h = Dense(in_channels * 4, dtype=dtype, name="time_pos_embed_linear_1")(
         t_feat
     )
     h = nn.silu(h)
-    return nn.Dense(in_channels, dtype=dtype, name="time_pos_embed_linear_2")(h)
+    return Dense(in_channels, dtype=dtype, name="time_pos_embed_linear_2")(h)
 
 
 class UNetSpatioTemporalConditionModel(nn.Module):
@@ -368,7 +372,7 @@ class UNetSpatioTemporalConditionModel(nn.Module):
             encoder_hidden_states.astype(self.dtype), num_frames, axis=0
         )
 
-        x = nn.Conv(
+        x = Conv(
             cfg.block_out_channels[0], (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_in",
         )(x.astype(self.dtype))
@@ -421,7 +425,7 @@ class UNetSpatioTemporalConditionModel(nn.Module):
 
         x = FusedGroupNorm(32, epsilon=1e-5, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        x = nn.Conv(
+        x = Conv(
             cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="conv_out",
         )(x)

@@ -21,7 +21,7 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .layers import FusedGroupNorm, Upsample2D
+from .layers import Conv, FusedGroupNorm, Upsample2D
 from .svd_unet import SpatioTemporalResBlock
 from .vae import Encoder, VAEAttention, VAEConfig
 
@@ -71,7 +71,7 @@ class TemporalDecoder(nn.Module):
                 name=name,
             )(h, None, num_frames)
 
-        x = nn.Conv(
+        x = Conv(
             mid_ch, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="conv_in",
         )(latents)
@@ -90,14 +90,14 @@ class TemporalDecoder(nn.Module):
 
         x = FusedGroupNorm(32, epsilon=1e-6, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        x = nn.Conv(
+        x = Conv(
             cfg.in_channels, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="conv_out",
         )(x)
         # final temporal smoothing conv over the frame axis
         bf, hh, ww, c = x.shape
         x = x.reshape(bf // num_frames, num_frames, hh, ww, c)
-        x = nn.Conv(
+        x = Conv(
             cfg.in_channels,
             (3, 1, 1),
             padding=((1, 1), (0, 0), (0, 0)),
@@ -114,7 +114,7 @@ class AutoencoderKLTemporalDecoder(nn.Module):
     def setup(self):
         self.encoder = Encoder(self.config.encoder_config(), dtype=self.dtype)
         self.decoder = TemporalDecoder(self.config, dtype=self.dtype)
-        self.quant_conv = nn.Conv(
+        self.quant_conv = Conv(
             2 * self.config.latent_channels, (1, 1), dtype=self.dtype
         )
         # NB: no post_quant_conv in this family

@@ -17,6 +17,8 @@ import flax.linen as nn
 import jax.numpy as jnp
 import numpy as np
 
+from .layers import DeclaredParams, Dense, Embed
+
 
 @dataclasses.dataclass(frozen=True)
 class T5Config:
@@ -64,7 +66,7 @@ def t5_config_from_json(cj: dict | None) -> T5Config:
     )
 
 
-class RMSNorm(nn.Module):
+class RMSNorm(DeclaredParams, nn.Module):
     epsilon: float = 1e-6
     dtype: jnp.dtype = jnp.float32
 
@@ -102,7 +104,7 @@ def relative_position_buckets(qlen: int, klen: int, num_buckets: int,
     return buckets
 
 
-class T5Attention(nn.Module):
+class T5Attention(DeclaredParams, nn.Module):
     config: T5Config
     dtype: jnp.dtype = jnp.float32
     has_relative_bias: bool = False
@@ -114,9 +116,9 @@ class T5Attention(nn.Module):
         inner = cfg.num_heads * cfg.d_kv
         # T5 projections carry no bias and no 1/sqrt(d) scaling (folded into
         # the stored weights at training time)
-        q = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="q")(x)
-        k = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="k")(x)
-        v = nn.Dense(inner, use_bias=False, dtype=self.dtype, name="v")(x)
+        q = Dense(inner, use_bias=False, dtype=self.dtype, name="q")(x)
+        k = Dense(inner, use_bias=False, dtype=self.dtype, name="k")(x)
+        v = Dense(inner, use_bias=False, dtype=self.dtype, name="v")(x)
         q = q.reshape(b, s, cfg.num_heads, cfg.d_kv)
         k = k.reshape(b, s, cfg.num_heads, cfg.d_kv)
         v = v.reshape(b, s, cfg.num_heads, cfg.d_kv)
@@ -148,7 +150,7 @@ class T5Attention(nn.Module):
             )
         weights = nn.softmax(logits, axis=-1).astype(self.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(b, s, inner)
-        return nn.Dense(
+        return Dense(
             cfg.d_model, use_bias=False, dtype=self.dtype, name="o"
         )(out), position_bias
 
@@ -169,10 +171,10 @@ class T5Block(nn.Module):
         x = x + y
         y = RMSNorm(cfg.layer_norm_epsilon, dtype=self.dtype, name="ff_norm")(x)
         # gated-GELU FFN (T5 v1.1 / XXL): gelu(wi_0(x)) * wi_1(x) -> wo
-        gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=self.dtype, name="wi_0")(y)
-        value = nn.Dense(cfg.d_ff, use_bias=False, dtype=self.dtype, name="wi_1")(y)
+        gate = Dense(cfg.d_ff, use_bias=False, dtype=self.dtype, name="wi_0")(y)
+        value = Dense(cfg.d_ff, use_bias=False, dtype=self.dtype, name="wi_1")(y)
         y = nn.gelu(gate, approximate=True) * value
-        y = nn.Dense(cfg.d_model, use_bias=False, dtype=self.dtype, name="wo")(y)
+        y = Dense(cfg.d_model, use_bias=False, dtype=self.dtype, name="wo")(y)
         return x + y, position_bias
 
 
@@ -184,7 +186,7 @@ class T5Encoder(nn.Module):
     def __call__(self, input_ids, attention_mask=None):
         """[B, S] int32 (+ [B, S] 1-keep mask) -> [B, S, d_model]."""
         cfg = self.config
-        x = nn.Embed(
+        x = Embed(
             cfg.vocab_size, cfg.d_model, dtype=self.dtype, name="token_embedding"
         )(input_ids)
         position_bias = None

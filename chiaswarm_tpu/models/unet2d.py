@@ -18,6 +18,8 @@ import jax.numpy as jnp
 
 from .layers import (
     BasicTransformerBlock,
+    Conv,
+    Dense,
     Downsample2D,
     FusedGroupNorm,
     ResnetBlock2D,
@@ -189,7 +191,7 @@ class UNet2DConditionModel(nn.Module):
             )(add_feat)
 
         if cfg.class_embed_dim:
-            class_emb = nn.Dense(
+            class_emb = Dense(
                 temb_dim, dtype=self.dtype, name="class_embedding"
             )(class_labels.astype(self.dtype))
             if cfg.class_embeddings_concat:
@@ -197,7 +199,7 @@ class UNet2DConditionModel(nn.Module):
             else:
                 temb = temb + class_emb
 
-        x = nn.Conv(
+        x = Conv(
             cfg.block_out_channels[0], (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_in",
         )(sample)
@@ -247,7 +249,7 @@ class UNet2DConditionModel(nn.Module):
 
         x = FusedGroupNorm(32, epsilon=1e-5, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        return nn.Conv(
+        return Conv(
             cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="conv_out",
         )(x)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 
 from .layers import (
     BasicTransformerBlock,
+    Conv,
+    Dense,
     Downsample2D,
     FusedGroupNorm,
     ResnetBlock2D,
@@ -79,7 +81,7 @@ class TemporalConvLayer(nn.Module):
                 self.groups, epsilon=1e-5, dtype=self.dtype, act="silu",
                 name=f"conv{i}_norm",
             )(hidden)
-            hidden = nn.Conv(
+            hidden = Conv(
                 self.channels, (3, 1, 1),
                 padding=((1, 1), (0, 0), (0, 0)),
                 kernel_init=(
@@ -118,13 +120,13 @@ class TransformerTemporal(nn.Module):
         hidden = hidden.transpose(0, 2, 1, 3).reshape(
             b * h * w, num_frames, c
         )
-        hidden = nn.Dense(inner, dtype=self.dtype, name="proj_in")(hidden)
+        hidden = Dense(inner, dtype=self.dtype, name="proj_in")(hidden)
         for i in range(self.num_layers):
             hidden = BasicTransformerBlock(
                 inner, self.num_heads, self.head_dim, dtype=self.dtype,
                 name=f"transformer_blocks_{i}",
             )(hidden, None)
-        hidden = nn.Dense(c, dtype=self.dtype, name="proj_out")(hidden)
+        hidden = Dense(c, dtype=self.dtype, name="proj_out")(hidden)
         hidden = hidden.reshape(b, h * w, num_frames, c).transpose(0, 2, 1, 3)
         return hidden.reshape(bf, h, w, c) + residual
 
@@ -138,7 +140,7 @@ def unet3d_backbone(cfg: UNet3DConfig, dtype, sample, temb, ctx,
     which differ only in the conditioning assembled around this trunk."""
     g = cfg.norm_num_groups
     heads_of = lambda ch: ch // cfg.attention_head_dim
-    x = nn.Conv(
+    x = Conv(
         cfg.block_out_channels[0], (3, 3), padding=((1, 1), (1, 1)),
         dtype=dtype, name="conv_in",
     )(sample)
@@ -231,7 +233,7 @@ def unet3d_backbone(cfg: UNet3DConfig, dtype, sample, temb, ctx,
 
     x = FusedGroupNorm(g, epsilon=1e-5, dtype=dtype, act="silu",
                        name="conv_norm_out")(x)
-    return nn.Conv(
+    return Conv(
         cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)),
         dtype=dtype, name="conv_out",
     )(x)

@@ -23,7 +23,15 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .layers import FusedGroupNorm, TimestepEmbedding, timestep_embedding
+from .layers import (
+    Conv,
+    DeclaredParams,
+    Dense,
+    FusedGroupNorm,
+    LayerNorm,
+    TimestepEmbedding,
+    timestep_embedding,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,23 +149,23 @@ class KResnetBlock(nn.Module):
         elif self.up:
             x = jnp.repeat(jnp.repeat(x, 2, axis=1), 2, axis=2)
             h = jnp.repeat(jnp.repeat(h, 2, axis=1), 2, axis=2)
-        h = nn.Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv1")(h)
+        h = Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv1")(h)
         # scale_shift AdaGN: the projection emits [scale | shift]; the temb
         # nonlinearity is the BLOCK's act (diffusers ResnetBlock2D applies
         # self.nonlinearity to temb, so IF uses gelu here too)
-        t = nn.Dense(2 * self.out_channels, dtype=self.dtype,
-                     name="time_emb_proj")(act(temb))
+        t = Dense(2 * self.out_channels, dtype=self.dtype,
+                  name="time_emb_proj")(act(temb))
         scale, shift = jnp.split(t[:, None, None, :], 2, axis=-1)
         h = FusedGroupNorm(self.groups, epsilon=1e-5, dtype=self.dtype,
                            name="norm2")(h)
         h = h * (1.0 + scale) + shift
         h = act(h)
-        h = nn.Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="conv2")(h)
+        h = Conv(self.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="conv2")(h)
         if x.shape[-1] != self.out_channels:
-            x = nn.Conv(self.out_channels, (1, 1), dtype=self.dtype,
-                        name="conv_shortcut")(x)
+            x = Conv(self.out_channels, (1, 1), dtype=self.dtype,
+                     name="conv_shortcut")(x)
         return x + h
 
 
@@ -182,13 +190,13 @@ class KAttention(nn.Module):
         norm = FusedGroupNorm(self.groups, epsilon=1e-5, dtype=self.dtype,
                               name="group_norm")(tokens)
         inner = self.heads * self.head_dim
-        q = nn.Dense(inner, dtype=self.dtype, name="to_q")(norm)
-        k_self = nn.Dense(inner, dtype=self.dtype, name="to_k")(norm)
-        v_self = nn.Dense(inner, dtype=self.dtype, name="to_v")(norm)
-        k_add = nn.Dense(inner, dtype=self.dtype, name="add_k_proj")(
+        q = Dense(inner, dtype=self.dtype, name="to_q")(norm)
+        k_self = Dense(inner, dtype=self.dtype, name="to_k")(norm)
+        v_self = Dense(inner, dtype=self.dtype, name="to_v")(norm)
+        k_add = Dense(inner, dtype=self.dtype, name="add_k_proj")(
             context.astype(self.dtype)
         )
-        v_add = nn.Dense(inner, dtype=self.dtype, name="add_v_proj")(
+        v_add = Dense(inner, dtype=self.dtype, name="add_v_proj")(
             context.astype(self.dtype)
         )
         k = jnp.concatenate([k_add, k_self], axis=1)
@@ -198,7 +206,7 @@ class KAttention(nn.Module):
 
         out = dot_product_attention(shape4(q), shape4(k), shape4(v))
         out = out.reshape(b, h * w, inner)
-        out = nn.Dense(self.channels, dtype=self.dtype, name="to_out_0")(out)
+        out = Dense(self.channels, dtype=self.dtype, name="to_out_0")(out)
         return x + out.reshape(b, h, w, self.channels)
 
 
@@ -270,7 +278,7 @@ class KUpBlock(nn.Module):
         return x
 
 
-class AttentionPooling(nn.Module):
+class AttentionPooling(DeclaredParams, nn.Module):
     """diffusers AttentionPooling (IF's TextTimeEmbedding pool): a mean+
     positional class token attends the sequence; its attention output is
     the pooled vector."""
@@ -289,9 +297,9 @@ class AttentionPooling(nn.Module):
         seq = jnp.concatenate([cls, x], axis=1)
         hd = width // self.num_heads
         shape = lambda t: t.reshape(b, t.shape[1], self.num_heads, hd)
-        q = shape(nn.Dense(width, dtype=self.dtype, name="q_proj")(cls))
-        k = shape(nn.Dense(width, dtype=self.dtype, name="k_proj")(seq))
-        v = shape(nn.Dense(width, dtype=self.dtype, name="v_proj")(seq))
+        q = shape(Dense(width, dtype=self.dtype, name="q_proj")(cls))
+        k = shape(Dense(width, dtype=self.dtype, name="k_proj")(seq))
+        v = shape(Dense(width, dtype=self.dtype, name="v_proj")(seq))
         scale = hd**-0.5
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
         w = nn.softmax(logits.astype(jnp.float32), axis=-1).astype(self.dtype)
@@ -325,16 +333,16 @@ class K22UNet(nn.Module):
         if cfg.conditioning == "image":
             # addition_embed_type="image" (ImageTimeEmbedding): the image
             # embed joins the timestep embedding additively
-            aug = nn.Dense(temb_dim, dtype=self.dtype, name="aug_emb_proj")(cond)
-            aug = nn.LayerNorm(dtype=self.dtype, name="aug_emb_norm")(aug)
+            aug = Dense(temb_dim, dtype=self.dtype, name="aug_emb_proj")(cond)
+            aug = LayerNorm(dtype=self.dtype, name="aug_emb_norm")(aug)
             temb = temb + aug
             # encoder_hid_dim_type="image_proj" (ImageProjection): the image
             # embed also becomes the cross-attention token sequence
-            ctx = nn.Dense(
+            ctx = Dense(
                 cfg.image_proj_tokens * cfg.cross_attention_dim,
                 dtype=self.dtype, name="hid_proj",
             )(cond).reshape(-1, cfg.image_proj_tokens, cfg.cross_attention_dim)
-            ctx = nn.LayerNorm(dtype=self.dtype, name="hid_proj_norm")(ctx)
+            ctx = LayerNorm(dtype=self.dtype, name="hid_proj_norm")(ctx)
         elif cfg.conditioning == "text_image":
             # K2.1: `cond` is a dict {"text_states" [B,S,Dt], "text_embeds"
             # [B,Dt'], "image_embeds" [B,Di]}.
@@ -343,35 +351,35 @@ class K22UNet(nn.Module):
             text_states = cond["text_states"].astype(self.dtype)
             text_embeds = cond["text_embeds"].astype(self.dtype)
             image_embeds = cond["image_embeds"].astype(self.dtype)
-            aug_text = nn.LayerNorm(dtype=self.dtype, name="aug_emb_text_norm")(
-                nn.Dense(temb_dim, dtype=self.dtype,
-                         name="aug_emb_text_proj")(text_embeds)
+            aug_text = LayerNorm(dtype=self.dtype, name="aug_emb_text_norm")(
+                Dense(temb_dim, dtype=self.dtype,
+                      name="aug_emb_text_proj")(text_embeds)
             )
-            aug_img = nn.Dense(temb_dim, dtype=self.dtype,
-                               name="aug_emb_image_proj")(image_embeds)
+            aug_img = Dense(temb_dim, dtype=self.dtype,
+                            name="aug_emb_image_proj")(image_embeds)
             temb = temb + aug_text + aug_img
             # encoder_hid_dim_type="text_image_proj" (TextImageProjection):
             # image tokens prepended to the projected text sequence (no LN)
-            img_tokens = nn.Dense(
+            img_tokens = Dense(
                 cfg.image_proj_tokens * cfg.cross_attention_dim,
                 dtype=self.dtype, name="hid_proj_image",
             )(image_embeds).reshape(
                 -1, cfg.image_proj_tokens, cfg.cross_attention_dim
             )
-            txt_tokens = nn.Dense(cfg.cross_attention_dim, dtype=self.dtype,
-                                  name="hid_proj_text")(text_states)
+            txt_tokens = Dense(cfg.cross_attention_dim, dtype=self.dtype,
+                               name="hid_proj_text")(text_states)
             ctx = jnp.concatenate([img_tokens, txt_tokens], axis=1)
         else:
             # IF: addition_embed_type="text" (TextTimeEmbedding = LN ->
             # attention pool -> proj -> LN), encoder_hid_dim_type="text_proj"
-            aug = nn.LayerNorm(dtype=self.dtype, name="aug_emb_norm1")(cond)
+            aug = LayerNorm(dtype=self.dtype, name="aug_emb_norm1")(cond)
             aug = AttentionPooling(cfg.addition_embed_heads, dtype=self.dtype,
                                    name="aug_emb_pool")(aug)
-            aug = nn.Dense(temb_dim, dtype=self.dtype, name="aug_emb_proj")(aug)
-            aug = nn.LayerNorm(dtype=self.dtype, name="aug_emb_norm2")(aug)
+            aug = Dense(temb_dim, dtype=self.dtype, name="aug_emb_proj")(aug)
+            aug = LayerNorm(dtype=self.dtype, name="aug_emb_norm2")(aug)
             temb = temb + aug
-            ctx = nn.Dense(cfg.cross_attention_dim, dtype=self.dtype,
-                           name="hid_proj")(cond)
+            ctx = Dense(cfg.cross_attention_dim, dtype=self.dtype,
+                        name="hid_proj")(cond)
         if cfg.class_embed_timestep:
             # IF-II: the SR noise level rides a second timestep embedding
             if class_labels is None:
@@ -383,9 +391,9 @@ class K22UNet(nn.Module):
                 temb_dim, dtype=self.dtype, name="class_embedding"
             )(c_feat)
 
-        x = nn.Conv(cfg.block_out_channels[0], (3, 3),
-                    padding=((1, 1), (1, 1)), dtype=self.dtype,
-                    name="conv_in")(sample)
+        x = Conv(cfg.block_out_channels[0], (3, 3),
+                 padding=((1, 1), (1, 1)), dtype=self.dtype,
+                 name="conv_in")(sample)
 
         skips = [x]
         for b, out_ch in enumerate(cfg.block_out_channels):
@@ -420,5 +428,5 @@ class K22UNet(nn.Module):
         x = FusedGroupNorm(cfg.norm_num_groups, epsilon=1e-5,
                            dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        return nn.Conv(cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)),
-                       dtype=self.dtype, name="conv_out")(x)
+        return Conv(cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)),
+                    dtype=self.dtype, name="conv_out")(x)

@@ -27,7 +27,15 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .layers import FusedGroupNorm, TimestepEmbedding, timestep_embedding
+from .layers import (
+    Conv,
+    DeclaredParams,
+    Dense,
+    FusedGroupNorm,
+    LayerNorm,
+    TimestepEmbedding,
+    timestep_embedding,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,7 +83,7 @@ class ConditionalGroupNorm(nn.Module):
     @nn.compact
     def __call__(self, x, temb):
         c = x.shape[-1]
-        ctx = nn.Dense(2 * c, dtype=self.dtype, name="context_mlp_1")(
+        ctx = Dense(2 * c, dtype=self.dtype, name="context_mlp_1")(
             nn.silu(temb)
         )
         scale, shift = jnp.split(ctx[:, None, None, :], 2, axis=-1)
@@ -86,7 +94,7 @@ class ConditionalGroupNorm(nn.Module):
         return x * (scale + 1.0) + shift
 
 
-class ConvTranspose2x2(nn.Module):
+class ConvTranspose2x2(DeclaredParams, nn.Module):
     """torch ConvTranspose2d(kernel=2, stride=2): stride equals kernel so
     every input pixel maps to a disjoint 2x2 output block — an einsum, not
     a real transposed convolution. Kernel layout (2, 2, in, out)."""
@@ -124,12 +132,12 @@ class K3Attention(nn.Module):
         dim = self.inner // heads
         b, n, _ = q_in.shape
         s = kv_in.shape[1]
-        q = nn.Dense(self.inner, use_bias=False, dtype=self.dtype,
-                     name="to_q")(q_in)
-        k = nn.Dense(self.inner, use_bias=False, dtype=self.dtype,
-                     name="to_k")(kv_in)
-        v = nn.Dense(self.inner, use_bias=False, dtype=self.dtype,
-                     name="to_v")(kv_in)
+        q = Dense(self.inner, use_bias=False, dtype=self.dtype,
+                  name="to_q")(q_in)
+        k = Dense(self.inner, use_bias=False, dtype=self.dtype,
+                  name="to_k")(kv_in)
+        v = Dense(self.inner, use_bias=False, dtype=self.dtype,
+                  name="to_v")(kv_in)
         q = q.reshape(b, n, heads, dim)
         k = k.reshape(b, s, heads, dim)
         v = v.reshape(b, s, heads, dim)
@@ -144,7 +152,7 @@ class K3Attention(nn.Module):
         out = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(
             b, n, self.inner
         )
-        return nn.Dense(
+        return Dense(
             self.inner, use_bias=False, dtype=self.dtype, name="to_out_0"
         )(out)
 
@@ -158,11 +166,11 @@ class K3EncoderProj(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        x = nn.Dense(
+        x = Dense(
             self.cross_attention_dim, use_bias=False, dtype=self.dtype,
             name="projection_linear",
         )(x)
-        return nn.LayerNorm(
+        return LayerNorm(
             epsilon=1e-5, dtype=self.dtype, name="projection_norm"
         )(x)
 
@@ -206,7 +214,7 @@ class K3Block(nn.Module):
                 x.shape[-1], dtype=self.dtype, name="up_sample"
             )(x)
         pad = "SAME" if self.kernel_size > 1 else "VALID"
-        x = nn.Conv(
+        x = Conv(
             self.out_channels,
             (self.kernel_size, self.kernel_size),
             padding=pad,
@@ -214,7 +222,7 @@ class K3Block(nn.Module):
             name="projection",
         )(x)
         if self.up_resolution is False:
-            x = nn.Conv(
+            x = Conv(
                 self.out_channels, (2, 2), strides=(2, 2), padding="VALID",
                 dtype=self.dtype, name="down_sample",
             )(x)
@@ -250,12 +258,12 @@ class K3ResNetBlock(nn.Module):
                 in_channels, dtype=self.dtype, name="shortcut_up_sample"
             )(x)
         if in_channels != self.out_channels:
-            x = nn.Conv(
+            x = Conv(
                 self.out_channels, (1, 1), dtype=self.dtype,
                 name="shortcut_projection",
             )(x)
         if False in self.up_resolutions:
-            x = nn.Conv(
+            x = Conv(
                 self.out_channels, (2, 2), strides=(2, 2), padding="VALID",
                 dtype=self.dtype, name="shortcut_down_sample",
             )(x)
@@ -288,11 +296,11 @@ class K3AttentionBlock(nn.Module):
         out = ConditionalGroupNorm(
             self.groups, dtype=self.dtype, name="out_norm"
         )(x, temb)
-        ff = nn.Conv(
+        ff = Conv(
             self.expansion_ratio * c, (1, 1), use_bias=False,
             dtype=self.dtype, name="feed_forward_0",
         )(out)
-        ff = nn.Conv(
+        ff = Conv(
             c, (1, 1), use_bias=False, dtype=self.dtype,
             name="feed_forward_2",
         )(nn.silu(ff))
@@ -428,7 +436,7 @@ class Kandinsky3UNet(nn.Module):
             dtype=self.dtype, name="add_time_condition",
         )(temb, context, encoder_attention_mask)
 
-        x = nn.Conv(
+        x = Conv(
             init_ch, (3, 3), dtype=self.dtype, name="conv_in"
         )(jnp.asarray(sample, self.dtype))
 
@@ -466,6 +474,6 @@ class Kandinsky3UNet(nn.Module):
             cfg.groups, epsilon=1e-5, dtype=self.dtype, act="silu",
             name="conv_norm_out",
         )(x)
-        return nn.Conv(
+        return Conv(
             cfg.in_channels, (3, 3), dtype=self.dtype, name="conv_out"
         )(x)

@@ -13,7 +13,14 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .layers import Downsample2D, FusedGroupNorm, ResnetBlock2D, Upsample2D
+from .layers import (
+    Conv,
+    Dense,
+    Downsample2D,
+    FusedGroupNorm,
+    ResnetBlock2D,
+    Upsample2D,
+)
 from ..ops import dot_product_attention
 
 
@@ -42,13 +49,13 @@ class VAEAttention(nn.Module):
         hidden = FusedGroupNorm(32, epsilon=1e-6, dtype=self.dtype,
                                 name="group_norm")(x)
         hidden = hidden.reshape(b, h * w, c)
-        q = nn.Dense(c, dtype=self.dtype, name="to_q")(hidden)
-        k = nn.Dense(c, dtype=self.dtype, name="to_k")(hidden)
-        v = nn.Dense(c, dtype=self.dtype, name="to_v")(hidden)
+        q = Dense(c, dtype=self.dtype, name="to_q")(hidden)
+        k = Dense(c, dtype=self.dtype, name="to_k")(hidden)
+        v = Dense(c, dtype=self.dtype, name="to_v")(hidden)
         out = dot_product_attention(
             q[:, :, None, :], k[:, :, None, :], v[:, :, None, :]
         )[:, :, 0, :]
-        out = nn.Dense(c, dtype=self.dtype, name="to_out_0")(out)
+        out = Dense(c, dtype=self.dtype, name="to_out_0")(out)
         return out.reshape(b, h, w, c) + residual
 
 
@@ -59,7 +66,7 @@ class Encoder(nn.Module):
     @nn.compact
     def __call__(self, pixels):
         cfg = self.config
-        x = nn.Conv(
+        x = Conv(
             cfg.block_out_channels[0], (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_in",
         )(pixels)
@@ -84,7 +91,7 @@ class Encoder(nn.Module):
         x = FusedGroupNorm(32, epsilon=1e-6, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
         # moments: mean + logvar
-        return nn.Conv(
+        return Conv(
             2 * cfg.latent_channels, (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_out",
         )(x)
@@ -98,7 +105,7 @@ class Decoder(nn.Module):
     def __call__(self, latents):
         cfg = self.config
         mid_ch = cfg.block_out_channels[-1]
-        x = nn.Conv(
+        x = Conv(
             mid_ch, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype, name="conv_in"
         )(latents)
 
@@ -117,7 +124,7 @@ class Decoder(nn.Module):
 
         x = FusedGroupNorm(32, epsilon=1e-6, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        return nn.Conv(
+        return Conv(
             cfg.in_channels, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="conv_out",
         )(x)
@@ -131,10 +138,10 @@ class AutoencoderKL(nn.Module):
         self.encoder = Encoder(self.config, dtype=self.dtype)
         self.decoder = Decoder(self.config, dtype=self.dtype)
         if self.config.use_quant_conv:
-            self.quant_conv = nn.Conv(
+            self.quant_conv = Conv(
                 2 * self.config.latent_channels, (1, 1), dtype=self.dtype
             )
-            self.post_quant_conv = nn.Conv(
+            self.post_quant_conv = Conv(
                 self.config.latent_channels, (1, 1), dtype=self.dtype
             )
         else:  # Flux layout: encoder/decoder connect directly to the latents

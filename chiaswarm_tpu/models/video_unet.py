@@ -25,9 +25,12 @@ import jax.numpy as jnp
 
 from .layers import (
     Attention,
+    Conv,
+    Dense,
     Downsample2D,
     FeedForward,
     FusedGroupNorm,
+    LayerNorm,
     ResnetBlock2D,
     TimestepEmbedding,
     Transformer2DModel,
@@ -73,15 +76,15 @@ class _TemporalBlock(nn.Module):
     def __call__(self, hidden, pos):
         c = self.channels
         hd = c // self.num_heads
-        y = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(hidden)
+        y = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm1")(hidden)
         hidden = hidden + Attention(
             self.num_heads, hd, c, dtype=self.dtype, name="attn1"
         )(y + pos[None])
-        y = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm2")(hidden)
+        y = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm2")(hidden)
         hidden = hidden + Attention(
             self.num_heads, hd, c, dtype=self.dtype, name="attn2"
         )(y + pos[None])
-        y = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm3")(hidden)
+        y = LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm3")(hidden)
         return hidden + FeedForward(c, dtype=self.dtype, name="ff")(y)
 
 
@@ -118,7 +121,7 @@ class TemporalTransformer(nn.Module):
         # [B, F, H, W, C] -> [B*H*W, F, C]
         hidden = hidden.reshape(b, num_frames, h, w, c)
         hidden = hidden.transpose(0, 2, 3, 1, 4).reshape(b * h * w, num_frames, c)
-        hidden = nn.Dense(c, dtype=self.dtype, name="proj_in")(hidden)
+        hidden = Dense(c, dtype=self.dtype, name="proj_in")(hidden)
 
         pos = _sinusoidal_pe(num_frames, c, self.dtype)
         heads = self.num_heads if c % self.num_heads == 0 else max(
@@ -131,7 +134,7 @@ class TemporalTransformer(nn.Module):
 
         # zero-init output projection: an unconverted motion module is a
         # no-op on the spatial model (AnimateDiff init convention)
-        hidden = nn.Dense(
+        hidden = Dense(
             c, kernel_init=nn.initializers.zeros, dtype=self.dtype,
             name="proj_out",
         )(hidden)
@@ -170,7 +173,7 @@ class VideoUNet(nn.Module):
             t_feat
         )
 
-        x = nn.Conv(
+        x = Conv(
             cfg.block_out_channels[0], (3, 3), padding=((1, 1), (1, 1)),
             dtype=self.dtype, name="conv_in",
         )(sample)
@@ -232,7 +235,7 @@ class VideoUNet(nn.Module):
 
         x = FusedGroupNorm(32, epsilon=1e-5, dtype=self.dtype, act="silu",
                            name="conv_norm_out")(x)
-        return nn.Conv(
+        return Conv(
             cfg.out_channels, (3, 3), padding=((1, 1), (1, 1)), dtype=self.dtype,
             name="conv_out",
         )(x)

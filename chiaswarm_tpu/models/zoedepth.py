@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .cascade_unet import interpolate_bilinear_align_corners
+from .layers import Conv, DeclaredParams, Dense, LayerNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,7 +114,7 @@ def beit_relative_position_index(window: int) -> np.ndarray:
     return index
 
 
-class _BeitSelfAttention(nn.Module):
+class _BeitSelfAttention(DeclaredParams, nn.Module):
     config: ZoeConfig
     dtype: jnp.dtype = jnp.float32
 
@@ -123,9 +124,9 @@ class _BeitSelfAttention(nn.Module):
         b, s, d = x.shape
         heads = cfg.num_heads
         hd = d // heads
-        q = nn.Dense(d, dtype=self.dtype, name="query")(x)
-        k = nn.Dense(d, use_bias=False, dtype=self.dtype, name="key")(x)
-        v = nn.Dense(d, dtype=self.dtype, name="value")(x)
+        q = Dense(d, dtype=self.dtype, name="query")(x)
+        k = Dense(d, use_bias=False, dtype=self.dtype, name="key")(x)
+        v = Dense(d, dtype=self.dtype, name="value")(x)
         q = q.reshape(b, s, heads, hd)
         k = k.reshape(b, s, heads, hd)
         v = v.reshape(b, s, heads, hd)
@@ -161,13 +162,13 @@ class _BeitAttention(nn.Module):
 
             @nn.compact
             def __call__(self, h):
-                return nn.Dense(h.shape[-1], dtype=self.dtype,
-                                name="dense")(h)
+                return Dense(h.shape[-1], dtype=self.dtype,
+                             name="dense")(h)
 
         return _Out(dtype=self.dtype, name="output")(y)
 
 
-class _BeitLayer(nn.Module):
+class _BeitLayer(DeclaredParams, nn.Module):
     config: ZoeConfig
     dtype: jnp.dtype = jnp.float32
 
@@ -176,13 +177,13 @@ class _BeitLayer(nn.Module):
         cfg = self.config
         d = cfg.hidden_size
         attn = _BeitAttention(cfg, dtype=self.dtype, name="attention")(
-            nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
-                         name="layernorm_before")(x)
+            LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
+                      name="layernorm_before")(x)
         )
         lambda_1 = self.param("lambda_1", nn.initializers.ones, (d,))
         x = x + attn * jnp.asarray(lambda_1, self.dtype)
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
-                         name="layernorm_after")(x)
+        h = LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype,
+                      name="layernorm_after")(x)
 
         class _Mid(nn.Module):
             width: int
@@ -191,7 +192,7 @@ class _BeitLayer(nn.Module):
             @nn.compact
             def __call__(self, z):
                 return nn.gelu(
-                    nn.Dense(self.width, dtype=self.dtype, name="dense")(z),
+                    Dense(self.width, dtype=self.dtype, name="dense")(z),
                     approximate=False,
                 )
 
@@ -201,8 +202,8 @@ class _BeitLayer(nn.Module):
 
             @nn.compact
             def __call__(self, z):
-                return nn.Dense(self.width, dtype=self.dtype,
-                                name="dense")(z)
+                return Dense(self.width, dtype=self.dtype,
+                             name="dense")(z)
 
         h = _Mid(cfg.intermediate_size, dtype=self.dtype,
                  name="intermediate")(h)
@@ -224,7 +225,7 @@ class BeitBackbone(nn.Module):
         b = pixels.shape[0]
         p = cfg.patch_size
 
-        class _Embeddings(nn.Module):
+        class _Embeddings(DeclaredParams, nn.Module):
             dtype: jnp.dtype = jnp.float32
 
             @nn.compact
@@ -234,7 +235,7 @@ class BeitBackbone(nn.Module):
 
                     @nn.compact
                     def __call__(self, z):
-                        return nn.Conv(
+                        return Conv(
                             cfg.hidden_size, (p, p), strides=(p, p),
                             padding="VALID", dtype=self.dtype,
                             name="projection",
@@ -271,7 +272,7 @@ class BeitBackbone(nn.Module):
         return _Encoder(dtype=self.dtype, name="encoder")(x)
 
 
-class _ConvTransposeSame(nn.Module):
+class _ConvTransposeSame(DeclaredParams, nn.Module):
     """torch ConvTranspose2d(kernel=k, stride=k): disjoint k x k output
     blocks — an einsum. Kernel layout (k, k, in, out)."""
 
@@ -300,11 +301,11 @@ class _PreActResidual(nn.Module):
     @nn.compact
     def __call__(self, x):
         h = nn.relu(x)
-        h = nn.Conv(self.width, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="convolution1")(h)
+        h = Conv(self.width, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="convolution1")(h)
         h = nn.relu(h)
-        h = nn.Conv(self.width, (3, 3), padding=((1, 1), (1, 1)),
-                    dtype=self.dtype, name="convolution2")(h)
+        h = Conv(self.width, (3, 3), padding=((1, 1), (1, 1)),
+                 dtype=self.dtype, name="convolution2")(h)
         return x + h
 
 
@@ -325,8 +326,8 @@ class _FusionLayer(nn.Module):
                             name="residual_layer2")(x)
         b, h, w, c = x.shape
         x = interpolate_bilinear_align_corners(x, 2 * h, 2 * w)
-        return nn.Conv(self.width, (1, 1), dtype=self.dtype,
-                       name="projection")(x)
+        return Conv(self.width, (1, 1), dtype=self.dtype,
+                    name="projection")(x)
 
 
 def _log_binom(n, k, eps=1e-7):
@@ -348,9 +349,9 @@ class _ConditionalLogBinomial(nn.Module):
         cfg = self.config
         x = jnp.concatenate([main, condition], axis=-1)
         bottleneck = x.shape[-1] // 2
-        x = nn.Conv(bottleneck, (1, 1), dtype=self.dtype, name="mlp_0")(x)
+        x = Conv(bottleneck, (1, 1), dtype=self.dtype, name="mlp_0")(x)
         x = nn.gelu(x, approximate=False)
-        x = nn.Conv(4, (1, 1), dtype=self.dtype, name="mlp_2")(x)
+        x = Conv(4, (1, 1), dtype=self.dtype, name="mlp_2")(x)
         x = nn.softplus(x.astype(jnp.float32))
         eps = 1e-4
         prob = x[..., :2] + eps
@@ -380,9 +381,9 @@ class _Mlp1x1(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        x = nn.Conv(self.mid, (1, 1), dtype=self.dtype, name="conv1")(x)
+        x = Conv(self.mid, (1, 1), dtype=self.dtype, name="conv1")(x)
         x = nn.relu(x)
-        x = nn.Conv(self.out, (1, 1), dtype=self.dtype, name="conv2")(x)
+        x = Conv(self.out, (1, 1), dtype=self.dtype, name="conv2")(x)
         if self.trailing == "softplus":
             x = nn.softplus(x.astype(jnp.float32)).astype(x.dtype)
         return x
@@ -426,8 +427,8 @@ class ZoeDepthModel(nn.Module):
                             # (a bare digit child would collide with the
                             # digit-merge rename)
                             return nn.gelu(
-                                nn.Dense(cfg.hidden_size, dtype=self.dtype,
-                                         name="proj")(z),
+                                Dense(cfg.hidden_size, dtype=self.dtype,
+                                      name="proj")(z),
                                 approximate=False,
                             )
 
@@ -441,8 +442,8 @@ class ZoeDepthModel(nn.Module):
 
                         @nn.compact
                         def __call__(self, z):
-                            z = nn.Conv(ch, (1, 1), dtype=self.dtype,
-                                        name="projection")(z)
+                            z = Conv(ch, (1, 1), dtype=self.dtype,
+                                     name="projection")(z)
                             if factor > 1:
                                 z = _ConvTransposeSame(
                                     ch, int(factor), dtype=self.dtype,
@@ -450,7 +451,7 @@ class ZoeDepthModel(nn.Module):
                                 )(z)
                             elif factor < 1:
                                 s = int(1 / factor)
-                                z = nn.Conv(
+                                z = Conv(
                                     ch, (3, 3), strides=(s, s),
                                     padding=((1, 1), (1, 1)),
                                     dtype=self.dtype, name="resize",
@@ -469,9 +470,9 @@ class ZoeDepthModel(nn.Module):
                 feats = _Reassemble(dtype=self.dtype,
                                     name="reassemble_stage")(taps)
                 feats = [
-                    nn.Conv(cfg.fusion_hidden_size, (3, 3),
-                            padding=((1, 1), (1, 1)), use_bias=False,
-                            dtype=self.dtype, name=f"convs_{i}")(f)
+                    Conv(cfg.fusion_hidden_size, (3, 3),
+                         padding=((1, 1), (1, 1)), use_bias=False,
+                         dtype=self.dtype, name=f"convs_{i}")(f)
                     for i, f in enumerate(feats)
                 ]
 
@@ -504,17 +505,17 @@ class ZoeDepthModel(nn.Module):
 
             @nn.compact
             def __call__(self, h):
-                h = nn.Conv(cfg.fusion_hidden_size // 2, (3, 3),
-                            padding=((1, 1), (1, 1)), dtype=self.dtype,
-                            name="conv1")(h)
+                h = Conv(cfg.fusion_hidden_size // 2, (3, 3),
+                         padding=((1, 1), (1, 1)), dtype=self.dtype,
+                         name="conv1")(h)
                 bb, hh, ww, _ = h.shape
                 h = interpolate_bilinear_align_corners(h, 2 * hh, 2 * ww)
-                h = nn.Conv(cfg.num_relative_features, (3, 3),
-                            padding=((1, 1), (1, 1)), dtype=self.dtype,
-                            name="conv2")(h)
+                h = Conv(cfg.num_relative_features, (3, 3),
+                         padding=((1, 1), (1, 1)), dtype=self.dtype,
+                         name="conv2")(h)
                 h = nn.relu(h)
                 features = h
-                h = nn.Conv(1, (1, 1), dtype=self.dtype, name="conv3")(h)
+                h = Conv(1, (1, 1), dtype=self.dtype, name="conv3")(h)
                 h = nn.relu(h)
                 return h[..., 0], features
 
@@ -529,8 +530,8 @@ class ZoeDepthModel(nn.Module):
             @nn.compact
             def __call__(self, outconv, bottleneck, feature_blocks,
                          relative_depth):
-                x = nn.Conv(cfg.bottleneck_features, (1, 1),
-                            dtype=self.dtype, name="conv2")(bottleneck)
+                x = Conv(cfg.bottleneck_features, (1, 1),
+                         dtype=self.dtype, name="conv2")(bottleneck)
                 seed = _Mlp1x1(
                     cfg.seed_mlp_dim, cfg.n_bins,
                     trailing="softplus", dtype=self.dtype,
@@ -559,11 +560,11 @@ class ZoeDepthModel(nn.Module):
                                 prev_emb, hh, ww
                             )
                             z = emb + prev_emb
-                            z = nn.Conv(cfg.bin_embedding_dim, (1, 1),
-                                        dtype=self.dtype, name="conv1")(z)
+                            z = Conv(cfg.bin_embedding_dim, (1, 1),
+                                     dtype=self.dtype, name="conv1")(z)
                             z = nn.relu(z)
-                            z = nn.Conv(self.n_attr, (1, 1),
-                                        dtype=self.dtype, name="conv2")(z)
+                            z = Conv(self.n_attr, (1, 1),
+                                     dtype=self.dtype, name="conv2")(z)
                             attractors = nn.softplus(
                                 z.astype(jnp.float32)
                             )
