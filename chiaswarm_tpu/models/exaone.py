@@ -25,7 +25,7 @@ sliding layer keeps a ring of `sliding_window` columns, position `p` at
 column `p mod window`, so it holds the window's keys whatever the length.
 A key is cached as attention reads it: normed and, on a sliding layer,
 rotated. What a row sees of either follows from its own length and the
-step (`decode_mask`, `ring_mask`).
+step (models/text_model.py `decode_mask`, `ring_mask`).
 
 Prefill goes in chunks of positions (`prefill`): a chunk's queries attend
 to the keys cached so far and its own through `ops.attention` (causal,
@@ -57,7 +57,14 @@ from .experts import (
     rms_norm,
     tally,
 )
-from .text_model import apply_rope, cached_attention, decode_mask, rope_tables
+from .text_model import (
+    apply_rope,
+    cached_attention,
+    decode_mask,
+    ring_fill,
+    ring_mask,
+    rope_tables,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -190,31 +197,6 @@ def _heads(p, cfg: ExaoneConfig, h, positions, window: int):
         q = apply_rope(q, cos[..., None, :], sin[..., None, :])
         k = apply_rope(k, cos[..., None, :], sin[..., None, :])
     return q, k, v
-
-
-def ring_fill(ring, entry, start: int, lengths):
-    """A sliding layer's ring [R, W, ...] after a chunk: `entry` [R, C,
-    ...] are the chunk's positions `start .. start + C`; column `j` takes
-    the row's last real position congruent to `j` if the chunk holds it
-    (a row's real positions are the first `lengths`)."""
-    window, chunk = ring.shape[1], entry.shape[1]
-    end = jnp.minimum(lengths, start + chunk)[:, None]  # [R, 1]
-    column = jnp.arange(window)[None, :]
-    position = end - 1 - jnp.mod(end - 1 - column, window)  # [R, W]
-    mine = position >= start
-    index = jnp.clip(position - start, 0, chunk - 1)
-    picked = jnp.take_along_axis(entry, index[:, :, None, None], axis=1)
-    return jnp.where(mine[:, :, None, None], picked, ring)
-
-
-def ring_mask(cfg: ExaoneConfig, lengths, number):
-    """[R, window]: what a row sees of a ring at generated token `number`:
-    column `j` holds the last position congruent to `j` up to the token's
-    own, seen if there is one (of a full layer's cache it sees what
-    models/text_model.py `decode_mask` says)."""
-    at = (lengths + number)[:, None]
-    return at - jnp.mod(at - jnp.arange(cfg.sliding_window)[None, :],
-                        cfg.sliding_window) >= 0
 
 
 # --- prefill and decode ------------------------------------------------------
@@ -402,7 +384,7 @@ def step(params, cfg: ExaoneConfig, tokens, lengths, number, slots: int,
         (layer[0].shape[1] for layer, window in zip(cache, cfg.windows)
          if not window), slots + 1)
     see_full = decode_mask(lengths, slots, full_positions, number)
-    see_ring = ring_mask(cfg, lengths, number)
+    see_ring = ring_mask(cfg.sliding_window, lengths, number)
     cache = list(cache)
     for index, (layer, window) in enumerate(zip(params["layers"],
                                                 cfg.windows)):

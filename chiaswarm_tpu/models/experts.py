@@ -1,6 +1,6 @@
 """What the language models with routed sparse experts share
 (models/kimi.py, models/exaone.py, models/sdar.py, models/qwen3_next.py,
-models/glm_moe_dsa.py):
+models/glm_moe_dsa.py, models/mimo_v2.py):
 the norm, the SwiGLU, the router under either of two rules, the held
 experts' grouped matmul, the tally of how the routing fell, the seeded init
 of a parameter tree and the head. A dense model (models/falcon_h1.py) takes
@@ -52,7 +52,11 @@ def leaf_rule(path, shape) -> tuple[float, float]:
     norm's weight and N(0, 0.1^2) for a norm's offset from one (a
     zero-centred norm multiplies by `1 + offset`: seeded at zero, a
     forgotten `1 +` would pass every test), N(0, 0.01^2) for the router's
-    correction bias, the embedding by its width, a unit normal for `A_log`
+    correction bias, N(2, 1) for an attention `sink` (a logit a head beside
+    scores of unit deviation: at a window of 128 keys a sink of 0 holds a
+    hundredth of a row's weight and one that is left out moves the logits
+    less than bfloat16 does; around 2 it holds a twentieth and a softmax
+    that left it out does not pass), the embedding by its width, a unit normal for `A_log`
     (which `finish_leaf` maps to the published `log U(0, 16)`), ones for
     `dt_bias`, N(0, 0.1^2) for a LayerNorm's bias (published at zero: seeded
     apart, a norm that dropped it would not pass), N(1, 0.1^2) for a state-space mixer's skip `D` (published
@@ -69,6 +73,8 @@ def leaf_rule(path, shape) -> tuple[float, float]:
         return 0.1, 0.0
     if name == "router_bias":
         return 0.01, 0.0
+    if name == "sink":
+        return 1.0, 2.0
     if name == "A_log":
         return 1.0, 0.0
     if name == "embed":

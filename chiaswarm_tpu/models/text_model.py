@@ -1,5 +1,6 @@
 """What a text family's module under `models/` gives the pipeline, and the
-parts of attention the families share (models/experts.py has the second
+parts of attention the families share: rotary, a decode step against a
+cache of keys, a window layer's ring (models/experts.py has the second
 half of a layer, models/prefill_chunks.py how a pass's rows go through a
 prefill). No family's module imports another's.
 
@@ -62,6 +63,13 @@ middle of a pass):
   cache's column blocks the decode's attention went over, by the rule the
   module's own `step` applies on the device: (walked, rows x the blocks of
   the cache's width), summed over rows, layers and steps.
+- where the key side of the family's prefill spans is bounded by the span's
+  end and nothing on the device counts it (its row says `bounds_prefill`):
+  `prefill_key_extent(cfg, lengths, slots, chunk_rows, chunk_slots)`, the
+  host's account, without jax, of the key positions the spans' full
+  attention went over, by the rule the module's own `prefill` applies:
+  (walked: rows x the span's end, bucket: rows x the prompt slots' width),
+  summed over the spans that ran and the layers that keep every position.
 
 The forwards themselves (`_forward`, `prefill_rows`, `step`, `block_step`)
 are each module's own: the networks differ.
@@ -86,6 +94,9 @@ BY_BLOCKS = ("block_step", "first_block", "unmask", "blocks_of",
 SELECTS = ("index_cache_bytes", "selection_counts")
 # what a family whose decode attention is bounded by the mask gives besides
 BOUNDS_DECODE = ("decode_cache_blocks",)
+# what a family whose prefill spans' key side is bounded by the span's end,
+# and which has no selection's tally to count it on the device, gives besides
+BOUNDS_PREFILL = ("prefill_key_extent",)
 
 
 def family_module(family: str):
@@ -96,7 +107,8 @@ def family_module(family: str):
     for name in (INTERFACE
                  + (BY_BLOCKS if row.get("block_length") else BY_TOKEN)
                  + (SELECTS if row.get("selects") else ())
-                 + (BOUNDS_DECODE if row.get("bounds_decode") else ())):
+                 + (BOUNDS_DECODE if row.get("bounds_decode") else ())
+                 + (BOUNDS_PREFILL if row.get("bounds_prefill") else ())):
         getattr(module, name)
     return module
 
@@ -134,18 +146,60 @@ def decode_mask(lengths, slots: int, positions: int, number, column=None):
         & (columns <= (slots + number if column is None else column)))
 
 
-def cached_attention(q, keys, values, mask, scale: float):
-    """One new token a row against its cache: `q` [R, heads, D], `keys` /
-    `values` [R, S, key heads, D], `mask` [R, S] the columns the row may
-    see. A group's query heads meet their one cached head in one batched
-    matmul; the cache is not repeated."""
+def cached_attention(q, keys, values, mask, scale: float, sink=None):
+    """One new token a row against its cache: `q` [R, heads, D], `keys`
+    [R, S, key heads, D], `values` [R, S, key heads, Dv] (`Dv` the values'
+    own width: MiMo-V2 keeps keys of 192 on values of 128), `mask` [R, S]
+    the columns the row may see. A group's query heads meet their one
+    cached head in one batched matmul; the cache is not repeated. `sink`
+    [heads] float32, where the layer has one, is a learned logit a query
+    head that joins the softmax's denominator and has no value: one more
+    column of the scores, dropped after the softmax, so a row's weights
+    add up to less than one. Returns [R, heads * Dv]."""
     rows, heads, d = q.shape
     kv_heads = keys.shape[2]
     q = q.reshape(rows, kv_heads, heads // kv_heads, d)
     scores = jnp.einsum("rhgd,rshd->rhgs", q, keys,
                         preferred_element_type=jnp.float32) * scale
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
-    weights = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
+    if sink is None:
+        weights = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, kv_heads, -1, 1),
+            (*scores.shape[:-1], 1))
+        weights = jax.nn.softmax(
+            jnp.concatenate([scores, column], axis=-1),
+            axis=-1)[..., :-1].astype(values.dtype)
     out = jnp.einsum("rhgs,rshd->rhgd", weights, values,
                      preferred_element_type=jnp.float32)
-    return out.astype(values.dtype).reshape(rows, heads * d)
+    # the values' width, which is the keys' in every family but MiMo-V2
+    return out.astype(values.dtype).reshape(rows, heads * values.shape[-1])
+
+
+# --- a window layer's ring ---------------------------------------------------
+
+
+def ring_fill(ring, entry, start, lengths):
+    """A window layer's ring [R, W, ...] after a chunk: `entry` [R, C,
+    ...] are the chunk's positions `start .. start + C` (`start` a number
+    or a traced scalar); column `j` takes the row's last real position
+    congruent to `j` if the chunk holds it (a row's real positions are the
+    first `lengths`)."""
+    window, chunk = ring.shape[1], entry.shape[1]
+    end = jnp.minimum(lengths, start + chunk)[:, None]  # [R, 1]
+    column = jnp.arange(window)[None, :]
+    position = end - 1 - jnp.mod(end - 1 - column, window)  # [R, W]
+    mine = position >= start
+    index = jnp.clip(position - start, 0, chunk - 1)
+    picked = jnp.take_along_axis(entry, index[:, :, None, None], axis=1)
+    return jnp.where(mine[:, :, None, None], picked, ring)
+
+
+def ring_mask(window: int, lengths, number):
+    """[R, window]: what a row sees of a ring at generated token `number`:
+    column `j` holds the last position congruent to `j` up to the token's
+    own, seen if there is one (of a full layer's cache it sees what
+    `decode_mask` says)."""
+    at = (lengths + number)[:, None]
+    return at - jnp.mod(at - jnp.arange(window)[None, :], window) >= 0

@@ -36,6 +36,13 @@ keys without a window, and twice the window's 128-multiple with one: what
 a step computes outside the band is what the blocks overhang it.
 One kernel of its own name: `flash_attention` has no mask and its name,
 operands and layout are the benchmark's yardstick for the image families.
+This one takes keys and values of one width, a head of whole 128-lane
+blocks, and a softmax over keys alone; keys wider than values, a head of
+192, a group of 16, a learned sink in the softmax and a span at an offset
+that is data are its sibling's, `ops/wide_key_attention.py` (MiMo-V2),
+under a name of its own for the same reason: this kernel's name, operand
+layout and `queries[n] keys[n] window[n]` metadata are K-EXAONE's and
+SDAR's yardstick (`benchmark/costs/banded_attention.py` `call_of`).
 """
 
 from __future__ import annotations

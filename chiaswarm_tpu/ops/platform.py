@@ -24,7 +24,7 @@ KERNEL_TRACES = telemetry.counter(
     "Kernel dispatch decisions taken while tracing, by op (attention | "
     "group_norm | expert_matmul | latent_attention | tensor_matmul | "
     "gated_delta_step | ssd_step | sampler | lightning_indexer | "
-    "index_select | latent_expansion | sparse_latent_attention) and path (flash | banded | "
+    "index_select | latent_expansion | sparse_latent_attention) and path (flash | banded | wide_key | "
     "ring | fused | grouped | absorbed | overlapped | reduced | pallas | "
     "einsum | gathered | reference); a traced latent_attention call bumps "
     "absorbed whichever way it is computed (the form, which both ways "

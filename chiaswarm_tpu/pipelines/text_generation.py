@@ -1,7 +1,8 @@
 """Text completion from token ids: one jitted prefill and one jitted cached
 decode over a resident language model, whichever family the name resolves
 to: a family is a row of text_families.py `TEXT_FAMILIES` (Kimi-K2,
-K-EXAONE, SDAR, Qwen3-Next, Falcon-H1, GLM-5) and the module under `models/`
+K-EXAONE, SDAR, Qwen3-Next, Falcon-H1, GLM-5, MiMo-V2) and the module under
+`models/`
 the row names,
 which gives what models/text_model.py says a family's module gives and is
 asked for nothing else. There are two ways to decode, and the family's row
@@ -22,8 +23,8 @@ padding after it), so a pass is keyed by (rows, prompt slots, new tokens):
   logits of every row's last prompt token. A row of a chunk of whole
   rows goes through at the narrowest of the widths its model offers that
   holds it (Kimi: the bucket and its halvings; SDAR, Qwen3-Next and
-  Falcon-H1: the bucket; K-EXAONE and GLM-5 run fixed chunks of their
-  own), and a chunk takes rows of one width (models/prefill_chunks.py:
+  Falcon-H1: the bucket; K-EXAONE, GLM-5 and MiMo-V2 run fixed chunks of
+  their own), and a chunk takes rows of one width (models/prefill_chunks.py:
   read from `lengths` on the device, so it is one program whatever a pass
   brings, and a row's bits do not depend on its batchmates); a pass hands
   its rows over longest first, so that rows of a width stand together,
@@ -74,7 +75,8 @@ position on every layer, Qwen3-Next keys and values a position on every
 fourth layer and on the others a recurrent state and a convolution's tail
 a row, which do not grow with the positions, Falcon-H1 both on every
 layer, GLM-5 a latent AND an index key a position a layer, two caches side
-by side): the whole is
+by side, MiMo-V2 keys of 192 on values of 128 a position on its full
+layers' 4 key heads and a ring of its window on the others' 8): the whole is
 `swarm_pass_cache_bytes{model}`, the rings' part
 `swarm_pass_window_cache_bytes{model}`, the states' part
 `swarm_pass_state_bytes{model}`, and where the family selects keys (its
@@ -89,7 +91,10 @@ summed on the device beside the routing's tally and come back with the ids
 the prefill's spans went over, each bounded by its own end, against spans x
 the bucket's width (`swarm_prefill_key_extent_total{model, extent}`,
 `extent` `walked` | `bucket`; the envelope's `selection` has them as
-`prefill_key_extent`). Where the family's decode attention is bounded by
+`prefill_key_extent`; a family whose full layers' spans are bounded so
+and which has no selection, its row's `bounds_prefill`: MiMo-V2, counts the
+same two on the host, its module's `prefill_key_extent`, and its envelope
+has `prefill_key_extent` alone). Where the family's decode attention is bounded by
 what a row's mask shows (its row's `bounds_decode`: Kimi-K2), the cache's
 column blocks the decode went over against rows x the blocks of the
 cache's width are the module's own account on the host, from the lengths
@@ -118,7 +123,8 @@ row generates `max_new_tokens`. `test/` names are seeded weights: `tiny` in
 the name is the family's tiny preset, any other the chip's share of the
 deployment at the published widths (`KIMI_K2_EP32`, `EXAONE_236B_EP8`,
 `SDAR_30B_PP8`, `QWEN3_NEXT_80B_EP4`, `FALCON_H1_34B_PP18`, `GLM5_EP16`:
-`test/GLM-5`, `test/tiny-glm-5`;
+`test/GLM-5`, `test/tiny-glm-5`; `MIMO_V25_EP16`: `test/MiMo-V2.5`,
+`test/tiny-mimo`;
 `weights=` hands the tree in already on the chip, as `FluxPipeline` takes
 it: the host init of billions of parameters is minutes).
 """
@@ -205,7 +211,9 @@ SPARSE_SELECTED = telemetry.counter(
 PREFILL_KEY_EXTENT = telemetry.counter(
     "swarm_prefill_key_extent_total",
     "Key positions the prefill spans of a family that selects keys went "
-    "over, summed over rows and layers on the device, by model and extent "
+    "over, summed over rows and layers on the device (on the host, from the "
+    "pass's lengths, for a family whose row says bounds_prefill: the layers "
+    "that keep every position), by model and extent "
     "(walked: up to the span's end, what the key side of a span is bounded "
     "by; bucket: the prompt slots' whole width a span): walked / bucket is "
     "36 / 64 for a row of eight spans, and 1 says no span was bounded",
@@ -285,6 +293,9 @@ class TextGenerationPipeline:
         # whether its decode attention is bounded by what the mask shows
         self.bounds_decode = bool(
             TEXT_FAMILIES[family].get("bounds_decode"))
+        # whether its prefill spans' key side is bounded by the span's end
+        self.bounds_prefill = bool(
+            TEXT_FAMILIES[family].get("bounds_prefill"))
         if dtype is None:
             dtype = (jnp.bfloat16 if jax.default_backend() == "tpu"
                      else jnp.float32)
@@ -693,13 +704,21 @@ class TextGenerationPipeline:
                 **dict(zip(("visible", "selected"), by_phase)),
                 "prefill_key_extent": {"walked": walked, "bucket": bucket}}}
         bounded = {}
+        if self.bounds_prefill:
+            walked, bucket = self.model.prefill_key_extent(
+                cfg, lengths, slots, *prefill_chunk(
+                    rows, slots, self.model.POSITION_CHUNKS))
+            PREFILL_KEY_EXTENT.inc(walked, extent="walked", **label)
+            PREFILL_KEY_EXTENT.inc(bucket, extent="bucket", **label)
+            bounded = {"prefill_key_extent": {"walked": walked,
+                                              "bucket": bucket}}
         if self.bounds_decode:
             walked, bucket = self.model.decode_cache_blocks(
                 cfg, lengths, slots, positions, forwards)
             DECODE_CACHE_BLOCKS.inc(walked, extent="walked", **label)
             DECODE_CACHE_BLOCKS.inc(bucket, extent="bucket", **label)
-            bounded = {"decode_cache_blocks": {"walked": walked,
-                                               "bucket": bucket}}
+            bounded["decode_cache_blocks"] = {"walked": walked,
+                                              "bucket": bucket}
         blocks = {}
         if self.by_blocks:
             BLOCK_FORWARD_ROWS.inc(real * denoise, kind="denoise", **label)

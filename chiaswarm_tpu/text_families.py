@@ -23,6 +23,9 @@ A row:
 - `bounds_decode`: only where the family's decode attention is bounded by
   what a row's mask shows and not by the cache's width (its module then
   has `decode_cache_blocks`: models/text_model.py);
+- `bounds_prefill`: only where the key side of the family's prefill spans
+  is bounded by the span's end and nothing on the device counts it (its
+  module then has `prefill_key_extent`: models/text_model.py);
 - the footprint admission reckons with, in bf16 on one chip. Admission is
   the weights the chip holds (`params_gb`, GiB), a working set that does
   not grow with the rows (`working_gb`: a prefill chunk's activations and
@@ -119,6 +122,23 @@ TEXT_FAMILIES: dict[str, dict] = {
         "name": "glm-5", "wire": "GlmMoeDsaForCausalLM",
         "module": "glm_moe_dsa", "selects": True, "params_gb": 7.28,
         "working_gb": 4.0, "cache_layers": ((7040.0, 0),)},
+    # one of 16 chips that share each layer (models/mimo_v2.py
+    # MIMO_V25_EP16): layer 0 (full, dense) and one whole period behind it
+    # (five window layers, one full), experts 0-15 of 256, an eighth of the
+    # vocabulary: 3.430 B parameters = 6.86 GB; a position is a key of 192
+    # and a value of 128 x 2 bytes a key head: 4 heads = 2560 B on each of
+    # the 2 full layers, kept whole, 8 heads = 5120 B on each of the 5
+    # window layers, kept as a ring of 128; the working set is over what the
+    # compile for a described v5e counted beside weights and cache for the
+    # 2-row, 32768-slot prefill program (1.19 GB: a span's queries and a
+    # row's keys padded to the kernel's 256 lanes, the span's expert buffer:
+    # benchmark/compile_check.py; the chip's own peak lies 0.75 GB over
+    # weights and cache: PERF.md section 6, PR 57), with the siblings' room
+    # for a pass of many short rows, which no cell runs
+    "mimo_v2": {
+        "name": "mimo", "wire": "MiMoV2ForCausalLM", "module": "mimo_v2",
+        "bounds_prefill": True, "params_gb": 6.39, "working_gb": 3.0,
+        "cache_layers": ((5120.0, 0), (25600.0, 128))},
 }
 
 

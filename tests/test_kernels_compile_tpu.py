@@ -1,6 +1,7 @@
 """The Pallas kernels (the UNet's two, the grouped expert matmul, the
-gated delta rule's step, a learned key selection's three and Kimi's decode
-attention over its latent cache), one
+gated delta rule's step, a learned key selection's three, Kimi's decode
+attention over its latent cache and MiMo-V2's prefill attention with keys
+wider than values and a sink), one
 MMDiT block across the four chips of the slice, and K-EXAONE's prefill
 program at its cell's shape,
 compiled for a described v5e chip at the published widths (no chip attached: the TPU compiler is installed here and
@@ -334,6 +335,32 @@ def test_the_key_selections_kernels_compile_for_v5e(v5e, sq, skv):
         scale=256 ** -0.5, heads=64, offset=traced).compile()
     assert "sparse_latent_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("kv_heads, skv, window", [
+    pytest.param(4, 32768, 0, id="mimo-full-layer-span"),
+    pytest.param(8, 4096 + 128, 128, id="mimo-window-layer-span-and-tail"),
+])
+def test_wide_key_attention_compiles_for_v5e(v5e, kv_heads, skv, window):
+    """`mimo-long-context`'s two calls (ISSUE 57): a 4096-query span of 64
+    query heads of 192 on values of 128. A full layer's, 16 query heads a
+    key head against the row's whole 32768-slot cache, the span's first
+    position a scalar handed to the kernel and the key-block axis of the
+    grid a bound that is data (one compiled kernel a row's every span); a
+    window layer's, 8 a key head against the span and the 128 keys before
+    it, with the sink and the floor that masks an empty tail."""
+    from chiaswarm_tpu.ops.wide_key_attention import _wide_key_pallas
+
+    traced = _shape(v5e, (), jnp.int32)
+    compiled = _wide_key_pallas.lower(
+        _shape(v5e, (1, 4096, 64, 192)), _shape(v5e, (1, skv, kv_heads, 192)),
+        _shape(v5e, (1, skv, kv_heads, 128)),
+        _shape(v5e, (64,), jnp.float32) if window else None,
+        None if window else traced, traced if window else None,
+        window=window).compile()
+    text = compiled.as_text()
+    assert "wide_key_attention" in text
+    assert f"queries[4096] keys[{skv}] window[{window}]" in text
 
 
 @pytest.mark.parametrize("rows, positions, heads", [

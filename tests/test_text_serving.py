@@ -127,7 +127,7 @@ def test_the_formatter_hands_a_block_decode_its_two_parameters():
 
 @pytest.mark.parametrize(
     "family", ["kimi_k2", "exaone_moe", "sdar_moe", "qwen3_next",
-               "falcon_h1", "glm_moe_dsa"])
+               "falcon_h1", "glm_moe_dsa", "mimo_v2"])
 def test_a_text_family_is_a_row_and_a_module_that_gives_the_interface(
         family, monkeypatch):
     """ISSUE 45: what the four lists that had to agree were is one row of
@@ -178,6 +178,10 @@ def test_a_text_family_is_a_row_and_a_module_that_gives_the_interface(
     assert all(hasattr(model, name_) == bool(what.get("bounds_decode"))
                for name_ in text_model.BOUNDS_DECODE)
     assert pipe.bounds_decode is bool(what.get("bounds_decode"))
+    # ... and of prefill spans whose key side is bounded by the span's end
+    assert all(hasattr(model, name_) == bool(what.get("bounds_prefill"))
+               for name_ in text_model.BOUNDS_PREFILL)
+    assert pipe.bounds_prefill is bool(what.get("bounds_prefill"))
     cfg, whole = model.config_for(name), model.config_for("test/whole")
     if by_blocks:
         assert cfg.block_length == whole.block_length == what["block_length"]
@@ -262,7 +266,7 @@ def test_a_text_familys_key_is_spelt_in_one_file():
     assert spelt == {family: {"text_families.py"}
                      for family in ("kimi_k2", "exaone_moe", "sdar_moe",
                                     "qwen3_next", "falcon_h1",
-                                    "glm_moe_dsa")}
+                                    "glm_moe_dsa", "mimo_v2")}
     from chiaswarm_tpu.coalesce import text_family_of
 
     assert text_family_of("test/tiny-sd") is None
@@ -460,6 +464,7 @@ def test_a_row_of_two_mixers_costs_a_state_and_keys_on_every_layer():
     ("test/Qwen3-Next-80B-A3B-Instruct", "qwen3_next"),
     ("test/Falcon-H1-34B-Instruct", "falcon_h1"),
     ("test/GLM-5", "glm_moe_dsa"),
+    ("test/MiMo-V2.5", "mimo_v2"),
 ])
 def test_a_full_size_test_name_is_no_stand_in(name, family):
     """Every text family gives a `test/` name its published widths, so
@@ -573,10 +578,11 @@ def test_a_worker_advertises_the_sequence_families_appetite(sdaas_root):
     # not HBM on the CPU: the ceiling; the job cap stays what it was
     assert caps["family_gang_rows"] == (
         "kimi_k2:256,exaone_moe:256,sdar_moe:256,qwen3_next:256,"
-        "falcon_h1:256,glm_moe_dsa:256")
+        "falcon_h1:256,glm_moe_dsa:256,mimo_v2:256")
     assert caps["family_gang_positions"] == (
         "kimi_k2:131072,exaone_moe:131072,sdar_moe:131072,"
-        "qwen3_next:131072,falcon_h1:131072,glm_moe_dsa:131072")
+        "qwen3_next:131072,falcon_h1:131072,glm_moe_dsa:131072,"
+        "mimo_v2:131072")
     assert caps["gang_rows"] == 8
     # the batcher's own budget is the job's true positions
     assert worker._coalesce_rows_limit(_job(1, 2)) == 256
@@ -947,6 +953,94 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
     assert "swarm_pass_window_cache_bytes" in telemetry.REGISTRY.render()
 
 
+def test_sink_and_wide_key_jobs_go_through_hive_worker_and_pipeline(
+        sdaas_root, monkeypatch):
+    """`test/tiny-mimo` (ISSUE 57) through hive, worker and pipeline with no
+    setting of its own: three jobs of one row each (33 to 64 ids, eight to
+    sixteen times the window of 4; the prefill constant shrunk to 16
+    tokens: four spans a 64-slot row as one traced body, a full layer's
+    span attending to the row's cache up to its own end, a window layer's
+    to the span before's last four keys, with the sink) are one gang and
+    one pass; the envelope says how much of the cache is rings and how far
+    the full layers' spans walked; one seed gives one answer alone as among
+    batchmates."""
+    from chiaswarm_tpu import worker as worker_module
+    from chiaswarm_tpu.hive_server.harness import LocalSwarm
+    from chiaswarm_tpu.pipelines import text_generation
+    from chiaswarm_tpu.settings import Settings
+
+    monkeypatch.setattr(worker_module, "POLL_SECONDS", 0.1)
+    monkeypatch.setattr(text_generation, "PREFILL_CHUNK_TOKENS", 16)
+    model, new = "test/tiny-mimo", 5
+    lengths = [41, 64, 33]
+
+    def job(number, **extra):
+        rng = np.random.default_rng(number)
+        return {"id": f"mimo-{number}", "workflow": "txt2txt",
+                "model_name": model, "max_new_tokens": new,
+                "temperature": 1.0, "seed": 100 + number,
+                "prompt_ids": [rng.integers(0, 128, lengths[number]).tolist()],
+                **extra}
+
+    assert coalesce_key(job(0)) == (model, "mimo_v2", "txt2txt", 64, new,
+                                    1.0)
+    label = {"model": model}
+    before = {extent: text_generation.PREFILL_KEY_EXTENT.value(
+        extent=extent, **label) for extent in ("walked", "bucket")}
+
+    async def scenario():
+        swarm = LocalSwarm(n_workers=0, settings=Settings(
+            sdaas_token="t", worker_name="w", hive_port=0, metrics_port=0))
+        await swarm.start()
+        try:
+            ids = [await swarm.submit(job(n)) for n in range(3)]
+            swarm.add_worker("text-worker")
+            done = [await swarm.wait_done(i, timeout=300) for i in ids]
+            again = await swarm.submit(job(0, id="again"))
+            done.append(await swarm.wait_done(again, timeout=300))
+            blobs = [await swarm.artifact(
+                status["result"]["artifacts"]["primary"]["href"])
+                for status in done]
+            return done, blobs
+        finally:
+            await swarm.stop()
+
+    done, blobs = asyncio.run(scenario())
+    configs = [status["result"]["pipeline_config"] for status in done]
+    assert all(status["status"] == "done" and status["attempts"] == 1
+               for status in done)
+    assert [config["pass_rows"] for config in configs] == [3, 3, 3, 1]
+    assert len({config["trace"]["gang"]["id"] for config in configs[:3]}) == 1
+    for blob in blobs:
+        (row,) = json.loads(blob)["token_ids"]
+        assert len(row) == new and all(0 <= i < 128 for i in row)
+    # one job, one seed: the same bytes alone as among batchmates
+    assert blobs[3] == blobs[0] and len(set(blobs[:3])) == 3
+    full, windows = 2, 5
+    total = {"walked": 0, "bucket": 0}
+    for config, rows in ((configs[0], lengths), (configs[3], lengths[:1])):
+        # spans of 16: every span of a real row that holds an id ran, a
+        # full layer's key side up to the span's own end of the 64 slots
+        ran = [-(-n // 16) for n in rows]
+        extent = {"walked": full * sum(16 * n * (n + 1) // 2 for n in ran),
+                  "bucket": full * sum(ran) * 64}
+        assert config["prefill_key_extent"] == extent
+        assert "selection" not in config
+        total = {key: total[key] + extent[key] for key in total}
+        assert config["prefill_chunk_widths"] == {"16": sum(ran)}
+        # two geometries: a full layer one key head of 24 + 16 a position,
+        # a window layer a ring of 4 columns of two key heads, float32
+        rings = config["padded_rows"] * 4 * 2 * (24 + 16) * 4 * windows
+        assert config["cache_bytes_window"] == rings
+        assert config["cache_bytes"] == rings + (
+            config["padded_rows"] * (64 + new) * (24 + 16) * 4 * full)
+        assert config["cache_bytes_state"] == 0
+    assert {extent: text_generation.PREFILL_KEY_EXTENT.value(
+        extent=extent, **label) - was
+            for extent, was in before.items()} == total
+    assert text_generation.PASS_WINDOW_CACHE_BYTES.value(**label) > 0
+
+
 @pytest.mark.parametrize(
     "model, chunk_tokens, lengths, widths, skipped, skipped_slots", [
         # spans of 16 of a 64-slot row, a row a chunk: 1 + 2 + 3 spans past
@@ -980,8 +1074,13 @@ def test_one_long_row_goes_through_in_position_chunks(sdaas_root,
         # not run: the second of the rows of 16 and 7, both of the row
         # that only pads the pass
         ("test/tiny-glm-5", 16, [29, 16, 7], {"16": 4}, 4, 64),
+        # ... and MiMo-V2, whose spans are one traced body too: a full
+        # layer's span attends to the row's cache up to its own end, a
+        # window layer's to the four keys the span before left
+        ("test/tiny-mimo", 16, [29, 16, 7], {"16": 4}, 4, 64),
     ], ids=["spans", "whole_rows", "nothing_to_skip", "kimi", "one_chunk",
-            "narrowest", "sdar", "qwen3_next", "falcon_h1", "glm_moe_dsa"])
+            "narrowest", "sdar", "qwen3_next", "falcon_h1", "glm_moe_dsa",
+            "mimo_v2"])
 def test_a_pass_counts_real_padding_and_skipped_slots(
         monkeypatch, model, chunk_tokens, lengths, widths, skipped,
         skipped_slots):
