@@ -24,12 +24,19 @@ layer between the poll loop and the slice workers:
   `put_gang()` — the hive already did the waiting — flushing as one
   group with reason "gang". The linger is never shorter than
   LINGER_PASS_SHARE of the key's shortest pass: a batchmate that is a poll
-  away is worth 1/40 of a ten-second pass, and a linger shorter than the
-  poll period can only ever join jobs of one reply. A group the linger
-  formed says so in each member's trace context (`trace.gang`, `by:
-  worker`), as a hive gang does, so whoever reads the envelopes sees the
-  passes that ran. Groups cap at Settings.max_coalesce jobs and at the slice's
-  capacity limit in images (rows_limit, wired to
+  away is worth 1/40 of a ten-second pass. That is the LONGEST a group
+  waits, not how long: a batchmate reaches the scheduler only in a /work
+  reply, so once the poll loop has admitted a reply whole and said before
+  which instant its timer sends no further poll (`reply_admitted`), every
+  open group whose linger would end before that instant is released at
+  once (`swarm_batch_releases_total{cause="no_poll_due"}`) — no reply can
+  reach it before its timer would have released it anyway. A group whose
+  linger outlasts the poll period keeps waiting and is joined by the next
+  reply's jobs; nothing is released between two put()s of one reply. A
+  group the linger formed says so in each member's trace context
+  (`trace.gang`, `by: worker`), as a hive gang does, so whoever reads the
+  envelopes sees the passes that ran. Groups cap at Settings.max_coalesce
+  jobs and at the slice's capacity limit in images (rows_limit, wired to
   chips/requirements.fit_batch by the worker), so a coalesced batch is
   always admissible without rejection.
 - The dispatch board is the placement layer (round 8): released work
@@ -79,9 +86,10 @@ from .coalesce import (  # noqa: F401  (re-exports)
 logger = logging.getLogger(__name__)
 
 # why a work item left the scheduler: "solo" (unbatchable / coalescing
-# off), "linger" (timer expired), "size" (hit max_coalesce), "rows" (hit
-# the slice's image capacity), "slots" (hit the distinct-adapter cap,
-# ISSUE 13), "priority" (interactive fast-path),
+# off), "linger" (timer expired), "no_poll_due" (its reply is in and no
+# poll is due before the timer's end, see reply_admitted()), "size" (hit
+# max_coalesce), "rows" (hit the slice's image capacity), "slots" (hit the
+# distinct-adapter cap, ISSUE 13), "priority" (interactive fast-path),
 # "preempt" (an interactive job in a DIFFERENT group flushed this one —
 # slice contention, see put()), "gang" (pre-batched by the hive's gang
 # scheduler — no linger, see put_gang()), "shutdown" (flush_all)
@@ -90,6 +98,20 @@ _FLUSHES = telemetry.counter(
     "Work items released by the batch scheduler, by flush reason",
     ("reason",),
 )
+# what ended a linger that was left to itself, of the reasons above: the
+# group filled ("full": size, rows, slots), its timer ran out ("timer"), or
+# no batchmate could reach it before the timer would ("no_poll_due"). A
+# solo, a hive gang and a priority, preempt or shutdown flush are none of
+# the three and count under their reason alone
+_RELEASES = telemetry.counter(
+    "swarm_batch_releases_total",
+    "Lingering groups released, by cause (full | timer | no_poll_due)",
+    ("cause",),
+)
+_RELEASE_CAUSE = {"size": "full", "rows": "full", "slots": "full",
+                  "linger": "timer", "no_poll_due": "no_poll_due"}
+for _cause in set(_RELEASE_CAUSE.values()):
+    _RELEASES.inc(0, cause=_cause)  # all three on /metrics from the start
 _GROUP_JOBS = telemetry.histogram(
     "swarm_batch_group_jobs",
     "Jobs per released work item (coalesce factor; 1 = solo dispatch)",
@@ -130,7 +152,9 @@ class BatchScheduler:
     put() admits raw hive jobs; released work items are LISTS of jobs —
     a singleton for unbatchable jobs (immediately), a coalesced group
     for compatible ones (after the linger window, or sooner when the
-    group hits max_coalesce jobs or the slice's capacity in images).
+    group hits max_coalesce jobs or the slice's capacity in images, or
+    when reply_admitted() finds that no poll is due before the window's
+    end).
     Slice workers consume via claim() (placement-aware, residency
     routing + stealing) or the plain FIFO get(). task_done() mirrors
     asyncio.Queue so the worker's poll gating (full()) keeps bounding
@@ -245,10 +269,13 @@ class BatchScheduler:
                 SPANS, [])).record(released, waited)
 
     def linger_for(self, key: tuple) -> float:
-        """Seconds a new group of this key waits for batchmates: the fixed
-        linger, or LINGER_PASS_SHARE of the key's shortest pass if that is
-        longer. A key's first pass carries its compile, so the share
-        counts only once two passes have been seen."""
+        """Seconds a new group of this key waits for batchmates at most:
+        the fixed linger, or LINGER_PASS_SHARE of the key's shortest pass
+        if that is longer. A key's first pass carries its compile, so the
+        share counts only once two passes have been seen. The wait ends
+        sooner where the group fills, and where the reply that brought
+        its jobs is in and the poll loop's next poll is due only after
+        these seconds are over (`reply_admitted`)."""
         passes, least_s, _ = self._pass_s.get(key, (0, 0.0, None))
         if passes < 2:
             return self.linger_s
@@ -439,6 +466,19 @@ class BatchScheduler:
         elif group["cap"] is not None and group["rows"] >= group["cap"]:
             self._flush(key, reason="rows")
 
+    def reply_admitted(self, no_poll_before: float) -> None:
+        """The poll loop has put every job of a /work reply, and its timer
+        sends no further poll before `no_poll_before`, an instant on the
+        event loop's clock: an open group whose linger timer fires before
+        that can be joined by nobody, so it goes to the board now. One
+        whose linger outlasts the poll period stays open for the next
+        reply's jobs. A poll sent early on new capacity
+        (worker._wait_to_poll) is not reckoned with: its reply joins the
+        groups it finds still open."""
+        for key, group in list(self._pending.items()):
+            if group["timer"].when() < no_poll_before:
+                self._flush(key, reason="no_poll_due")
+
     async def put_gang(self, jobs: list[dict]) -> None:
         """Admit a hive-pre-batched gang (jobs sharing one `trace.gang`
         id on the wire): flush immediately as one group with reason
@@ -541,6 +581,8 @@ class BatchScheduler:
             return
         group["timer"].cancel()
         _FLUSHES.inc(reason=reason)
+        if reason in _RELEASE_CAUSE:
+            _RELEASES.inc(cause=_RELEASE_CAUSE[reason])
         _GROUP_JOBS.observe(len(group["jobs"]))
         _GROUP_ROWS.observe(group["rows"])
         gang_id = uuid.uuid4().hex[:12]

@@ -951,6 +951,11 @@ class Worker:
                     self._stage_queue.put_nowait(item)
                 else:
                     await self.batcher.put(item)
+            # the reply is in whole, and the timer's next poll goes out
+            # POLL_SECONDS from here at the earliest: a group whose linger
+            # ends before that is waiting for nobody
+            self.batcher.reply_admitted(
+                asyncio.get_running_loop().time() + POLL_SECONDS)
             # lease revocations piggybacked on this reply: route
             # each to wherever the job currently lives (batcher
             # -> dropped outright; executing slice -> cancel

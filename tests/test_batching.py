@@ -476,6 +476,57 @@ def test_flush_reason_counters_cover_release_paths():
     run(scenario())
 
 
+@pytest.mark.parametrize("linger_s, max_coalesce, replies, causes", [
+    # (jobs the reply brings, seconds to the poll loop's next timed poll,
+    # jobs still lingering once the reply is admitted)
+    pytest.param(60.0, 8, [(1, 61.0, 0)], {"no_poll_due": 1},
+                 id="a-lone-job-no-poll-can-reach-is-on-the-board-at-once"),
+    pytest.param(60.0, 8, [(4, 61.0, 0)], {"no_poll_due": 1},
+                 id="a-reply-of-four-is-one-group-of-four"),
+    pytest.param(60.0, 8, [(1, 0.1, 1), (1, 61.0, 0)], {"no_poll_due": 1},
+                 id="a-linger-that-outlasts-the-poll-is-joined-by-the-next"),
+    pytest.param(0.05, 8, [(1, 0.01, 1)], {"timer": 1},
+                 id="a-linger-that-outlasts-the-poll-ends-at-its-timer"),
+    pytest.param(60.0, 2, [(2, 61.0, 0)], {"full": 1},
+                 id="a-full-group-is-released-on-put"),
+])
+def test_a_linger_ends_where_no_poll_can_bring_a_batchmate(
+        linger_s, max_coalesce, replies, causes):
+    """ISSUE 58: `linger_s` is the longest a group waits. Once the poll loop
+    has put a whole reply (`reply_admitted`), a group whose linger ends
+    before the next timed poll goes to the board at once; one whose linger
+    outlasts the poll period stays open; nothing leaves between two puts
+    of one reply unless the group fills; and each release counts once
+    under its cause."""
+    from chiaswarm_tpu.batching import _FLUSHES, _RELEASES
+
+    async def scenario():
+        counted = {c: _RELEASES.value(cause=c)
+                   for c in ("full", "timer", "no_poll_due")}
+        by_reason = _FLUSHES.value(reason="no_poll_due")
+        b = BatchScheduler(linger_s=linger_s, max_coalesce=max_coalesce)
+        put = 0
+        for brings, next_poll_in, lingering in replies:
+            for _ in range(brings):
+                await b.put(job(id=f"j{put}", prompt=str(put)))
+                put += 1
+                assert b.ready_jobs == 0 or "full" in causes
+            b.reply_admitted(
+                asyncio.get_running_loop().time() + next_poll_in)
+            assert b.pending_jobs == lingering
+        # well inside the 60 s lingers: no timer brought these
+        group = await asyncio.wait_for(b.get(), 1.0)
+        assert [j["id"] for j in group] == [f"j{i}" for i in range(put)]
+        assert b.pending_jobs == b.ready_jobs == 0
+        for cause, before in counted.items():
+            assert _RELEASES.value(cause=cause) == before + causes.get(
+                cause, 0), cause
+        assert _FLUSHES.value(reason="no_poll_due") == by_reason + causes.get(
+            "no_poll_due", 0)
+
+    run(scenario())
+
+
 # --- dispatch board: placement-aware claiming (residency routing) ---
 
 

@@ -160,6 +160,30 @@ def test_one_jobs_life_is_named_from_admit_to_settle(sdaas_root):
     assert {s["stage"] for s in trace["worker"]["stages"]} >= {"tick_wait"}
 
 
+@pytest.mark.parametrize("period", ["a-tenth", "default"])
+def test_a_lone_job_at_a_quiet_hive_does_not_linger(
+        sdaas_root, monkeypatch, period):
+    """ISSUE 58: no poll is due before the 50 ms linger's end, at a 0.1 s
+    period as at the shipped one, so the one job a reply brought is on the
+    board once the reply is admitted: its `linger` is the admission, the
+    release counts under `no_poll_due`, and `queue_wait` is still tiled."""
+    from chiaswarm_tpu.batching import _RELEASES
+
+    monkeypatch.delenv("CHIASWARM_POLL_SECONDS", raising=False)
+    monkeypatch.setattr(
+        worker_mod, "POLL_SECONDS",
+        0.1 if period == "a-tenth" else worker_mod._env_poll_seconds())
+    counted = {cause: _RELEASES.value(cause=cause)
+               for cause in ("full", "timer", "no_poll_due")}
+    [(envelope, trace)] = run_swarm([tiny_job(f"lone-{period}")], sdaas_root)
+    assert "error" not in envelope["pipeline_config"]
+    assert named(envelope, "linger")["seconds"] < 0.005
+    assert_chain(envelope, trace)
+    counted = {cause: _RELEASES.value(cause=cause) - before
+               for cause, before in counted.items()}
+    assert counted == {"full": 0, "timer": 0, "no_poll_due": 1}
+
+
 def test_a_gangs_members_share_the_wait_and_have_their_own_handoff(
         sdaas_root):
     jobs = [tiny_job(f"gang-life-{i}") for i in range(3)]
